@@ -44,21 +44,26 @@ from llm_in_practise_tpu.peft import (
 )
 
 
-def build_tokenizer(records, name, author, path):
+def train_tokenizer(records, name, author):
     """Train a ChatML-aware BPE on the rendered SFT texts (the reference uses
     the pretrained Qwen3 tokenizer; in-tree BPE keeps this hermetic)."""
-    if os.path.exists(path):
-        return BPETokenizer.load(path)
     system = f"You are a helpful assistant named {name}, trained by {author}."
     texts = [
         render_chatml(to_chat_messages(r, system))
         for r in substitute_placeholders(records, name, author)
     ]
-    tok = BPETokenizer.train(
+    return BPETokenizer.train(
         texts, vocab_size=800,
         special_tokens=("[PAD]", "[UNK]", IM_START, IM_END),
         min_frequency=1,
     )
+
+
+def build_tokenizer(records, name, author, path):
+    """:func:`train_tokenizer`, cached at ``path``."""
+    if os.path.exists(path):
+        return BPETokenizer.load(path)
+    tok = train_tokenizer(records, name, author)
     tok.save(path)
     return tok
 

@@ -13,6 +13,7 @@ Run: ``python examples/serve_openai.py [--port 8000]`` then
 """
 
 import argparse
+import functools
 import os
 import sys
 
@@ -127,7 +128,7 @@ def validate_args(args, error) -> None:
               "the layout mismatch after the full checkpoint restore")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--model_path", default="/tmp/qwen3_merged/model.msgpack")
     p.add_argument("--tokenizer_path", default="/tmp/qwen3_sft_bpe.json")
@@ -289,21 +290,13 @@ def main():
                         "compiles ONE block — flat compile time for deep "
                         "models (packed 4-bit weights ride the scan as "
                         "sideband inputs); Qwen3-family only")
-    args = p.parse_args()
-    validate_args(args, p.error)
+    return p
 
-    tok = BPETokenizer.load(args.tokenizer_path)
 
-    # the mesh exists BEFORE the model loads: a packed QuantizedModel
-    # needs it at construction (mesh -> the SPMD-partitionable XLA
-    # dequant path; Pallas custom calls are opaque to the partitioner)
-    mesh = None
-    if args.tp > 1:
-        from llm_in_practise_tpu.parallel import strategy as S
-
-        strat = S.tensor_parallel(model=args.tp, data=1)
-        mesh = strat.build_mesh(jax.devices()[: args.tp])
-
+def load_checkpoint(args, mesh):
+    """``(model, params)`` from the command line's checkpoint: a packed
+    4-bit export (``--quantized_dir``) or a merged msgpack
+    (``--model_path``)."""
     if args.quantized_dir:
         from llm_in_practise_tpu.quant import io as quant_io
         from llm_in_practise_tpu.serve.quantized import QuantizedModel
@@ -323,6 +316,27 @@ def main():
         params, meta = ckpt.restore_checkpoint(args.model_path)
         model = Qwen3(Qwen3Config.from_dict(meta["config"]))
         print(f"model: {args.model_path} | devices: {jax.devices()}")
+    return model, params
+
+
+def build_server(args, tok, load_model, error) -> OpenAIServer:
+    """Everything between a validated command line and ``serve()``:
+    mesh, model, layout, sharding, KV tiers, adapters, sessions, the
+    engine and the HTTP server around it. ``load_model(mesh)`` returns
+    ``(model, params)`` — :func:`load_checkpoint` for the CLI, a seeded
+    tree for ``chip_smoke.py``, which drives this same function.
+    ``error`` is ``parser.error``."""
+    # the mesh exists BEFORE the model loads: a packed QuantizedModel
+    # needs it at construction (mesh -> the SPMD-partitionable XLA
+    # dequant path; Pallas custom calls are opaque to the partitioner)
+    mesh = None
+    if args.tp > 1:
+        from llm_in_practise_tpu.parallel import strategy as S
+
+        strat = S.tensor_parallel(model=args.tp, data=1)
+        mesh = strat.build_mesh(jax.devices()[: args.tp])
+
+    model, params = load_model(mesh)
 
     from llm_in_practise_tpu.data.sft import IM_END
 
@@ -336,7 +350,7 @@ def main():
 
         inner = model.model if isinstance(model, _QM) else model
         if not isinstance(inner, Qwen3):
-            p.error("--scan-layers requires a Qwen3-family model")
+            error("--scan-layers requires a Qwen3-family model")
         scfg = inner.cfg.replace(scan_layers=True)
         params = stack_layer_params_jitted(params, scfg.n_layer)
         model = (_QM(Qwen3(scfg)) if isinstance(model, _QM)
@@ -505,9 +519,18 @@ def main():
         get_tracer().set_trace_file(args.trace_file)
         print(f"chrome trace events -> {args.trace_file} "
               "(open in Perfetto)")
-    server = OpenAIServer(engine, tok, model_name=args.model_name,
-                          adapters=adapters, role=args.role,
-                          handoff=handoff)
+    return OpenAIServer(engine, tok, model_name=args.model_name,
+                        adapters=adapters, role=args.role,
+                        handoff=handoff)
+
+
+def main():
+    p = build_parser()
+    args = p.parse_args()
+    validate_args(args, p.error)
+    tok = BPETokenizer.load(args.tokenizer_path)
+    server = build_server(args, tok,
+                          functools.partial(load_checkpoint, args), p.error)
     print(f"serving on {args.host}:{args.port} "
           f"(/v1/chat/completions, /v1/models, /health, /metrics, "
           f"/debug/traces)")

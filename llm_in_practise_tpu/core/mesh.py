@@ -105,6 +105,21 @@ def single_device_mesh() -> Mesh:
     return build_mesh(MeshSpec(data=1), devices=jax.devices()[:1])
 
 
+def require_tpu() -> jax.Device:
+    """The first attached device, which must be a TPU.
+
+    For processes that asked for the chip (``chip_smoke.py``,
+    ``bench.py``, ``tools/tpu_*``): they fail here, naming what JAX
+    found, instead of reporting the CPU backend's numbers as a
+    device's."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"this entry point needs a TPU; JAX found platform "
+            f"{dev.platform!r} (device_kind {dev.device_kind!r})")
+    return dev
+
+
 def batch_sharding(mesh: Mesh, seq_sharded: bool = False) -> NamedSharding:
     """Sharding for a per-step batch: leading dim split over data×fsdp.
 

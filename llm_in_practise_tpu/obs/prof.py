@@ -154,16 +154,16 @@ class CompileMeter:
             self.compile_seconds += float(seconds)
 
     def wrap(self, fn):
-        cache_size = getattr(fn, "_cache_size", None)
-        if cache_size is None:  # older/newer jax without the
-            # introspection hook: degrade to no compile accounting
-            return fn
-
+        # reach ``_cache_size`` through ``fn`` on every call: the bound
+        # C++ method object is invisible to the cycle collector, and a
+        # closure over it pins the jitted function — and through its
+        # bound-method target the whole engine, weights, KV and
+        # compiled programs — for the life of the process
         def counted(*args, **kwargs):
-            before = cache_size()
+            before = fn._cache_size()
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
-            if cache_size() > before:
+            if fn._cache_size() > before:
                 self.note(time.perf_counter() - t0)
             return out
 

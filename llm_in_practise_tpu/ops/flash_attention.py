@@ -33,16 +33,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_in_practise_tpu.ops.attention import interpret_default
+
 NEG_INF = -1e30
 _LANE = 128
 _SUBLANE = 8  # lse/delta carry a replicated sublane dim to satisfy TPU tiling
-
-
-def _interpret_default() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
 
 
 def _positions(block_q, block_k):
@@ -319,7 +314,7 @@ def flash_attention(
         raise ValueError("flash kernel requires identical q/k/v shapes")
     scale = scale if scale is not None else d ** -0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
 
     L_pad = max(_LANE, -(-L // _LANE) * _LANE)
     block_q, block_k = min(block_q, L_pad), min(block_k, L_pad)

@@ -31,7 +31,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_in_practise_tpu.ops.nf4_matmul import _interpret_default, _pick_block
+from llm_in_practise_tpu.ops.attention import interpret_default
+from llm_in_practise_tpu.ops.nf4_matmul import XLA_FALLBACKS, _pick_block
 from llm_in_practise_tpu.quant import int4
 from llm_in_practise_tpu.quant.int4 import Int4Tensor
 
@@ -135,12 +136,13 @@ def int4_matmul(x, t: Int4Tensor, out_dtype=None, interpret=None):
 
 def _int4_matmul_fwd(x, t, out_dtype, interpret):
     out_dtype = out_dtype or x.dtype
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     *lead, k = x.shape
     n = t.shape[1]
     m = int(np.prod(lead)) if lead else 1
     plan = _plan(t, m)
     if plan is None:
+        XLA_FALLBACKS["int4"] += 1
         out = x @ int4.decode(t, jnp.bfloat16).astype(x.dtype)
         return out.astype(out_dtype), (x.shape, jnp.zeros((0,), x.dtype), t, None)
     bm, bn, bkh, gh = plan
@@ -177,7 +179,7 @@ def _int4_matmul_fwd(x, t, out_dtype, interpret):
 def _int4_matmul_bwd(out_dtype, interpret, res, dy):
     x_shape, dtype_carrier, t, plan = res
     x_dtype = dtype_carrier.dtype
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     *lead, k = x_shape
     n = t.shape[1]
     if plan is None:

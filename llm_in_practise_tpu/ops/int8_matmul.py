@@ -42,7 +42,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_in_practise_tpu.ops.nf4_matmul import _interpret_default, _pick_block
+from llm_in_practise_tpu.ops.attention import interpret_default
+from llm_in_practise_tpu.ops.nf4_matmul import _pick_block
 from llm_in_practise_tpu.quant import int8
 from llm_in_practise_tpu.quant.int8 import Int8Tensor
 
@@ -118,7 +119,7 @@ def int8_matmul(x, t: Int8Tensor, out_dtype=None, interpret=None):
 
 def _int8_matmul_fwd(x, t, out_dtype, interpret):
     out_dtype = out_dtype or x.dtype
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     *lead, k = x.shape
     n = t.shape[1]
     m = int(np.prod(lead)) if lead else 1
@@ -154,7 +155,7 @@ def _int8_matmul_fwd(x, t, out_dtype, interpret):
 def _int8_matmul_bwd(out_dtype, interpret, res, dy):
     x_shape, dtype_carrier, t, plan = res
     x_dtype = dtype_carrier.dtype
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     *lead, k = x_shape
     n = t.shape[1]
     if plan is None:

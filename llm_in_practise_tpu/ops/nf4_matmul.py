@@ -28,11 +28,13 @@ right before the MXU dot, shaped by what Mosaic actually lowers:
 
 On non-TPU backends the kernels run in Pallas interpreter mode (same
 logic, CPU-testable); :func:`nf4_matmul` falls back to dequant+matmul for
-flat-layout tensors and shapes the tiling can't cover.
+flat-layout tensors and shapes the tiling can't cover, and counts each
+such trace in :data:`XLA_FALLBACKS`.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -41,17 +43,19 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_in_practise_tpu.ops.attention import interpret_default
 from llm_in_practise_tpu.quant import nf4
 from llm_in_practise_tpu.quant.nf4 import NF4Tensor
 
 _NF4_VALS = tuple(float(v) for v in np.asarray(nf4.NF4_CODE))
 
 
-def _interpret_default() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
+#: Traces that left a fused kernel for plain XLA dequant+matmul because
+#: the tiling plan was ``None`` (flat-layout tensors, dims that no
+#: 128-multiple block divides), by kernel name. A transformer matmul at
+#: published widths must never land here: ``chip_smoke.py`` asserts it
+#: stays empty.
+XLA_FALLBACKS: collections.Counter = collections.Counter()
 
 
 def _codes_to_vals(codes):
@@ -226,12 +230,13 @@ def nf4_matmul(x, t: NF4Tensor, out_dtype=None, blocks=None, interpret=None):
 
 def _nf4_matmul_fwd(x, t, out_dtype, blocks, interpret):
     out_dtype = out_dtype or x.dtype
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     *lead, k = x.shape
     n = t.shape[1]
     m = int(np.prod(lead)) if lead else 1
     plan = _plan(t, blocks, m)
     if plan is None:
+        XLA_FALLBACKS["nf4"] += 1
         out = x @ nf4.dequantize(t, jnp.bfloat16).astype(x.dtype)
         return out.astype(out_dtype), (x.shape, jnp.zeros((0,), x.dtype), t, None)
     bm, bnh, bk = plan
@@ -248,7 +253,7 @@ def _nf4_matmul_fwd(x, t, out_dtype, blocks, interpret):
 def _nf4_matmul_bwd(out_dtype, blocks, interpret, res, dy):
     x_shape, dtype_carrier, t, plan = res
     x_dtype = dtype_carrier.dtype
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     *lead, k = x_shape
     n = t.shape[1]
     if plan is None:

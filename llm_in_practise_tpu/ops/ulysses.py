@@ -32,15 +32,11 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from llm_in_practise_tpu.core import mesh as mesh_lib
 from llm_in_practise_tpu.ops.attention import dense_attention
-
-try:  # jax>=0.4.35 stable location
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def ulysses_attention(

@@ -11,7 +11,7 @@ bf16 (4x versus f32) at a bounded quantization error.
 
 XLA's SPMD partitioner emits the plain all-reduce on its own and cannot
 be told to quantize it, so this is the one place the serving stack
-drops to :func:`jax.experimental.shard_map.shard_map` — everywhere else
+drops to :func:`jax.shard_map` — everywhere else
 (ISSUE 10 tentpole) ``jax.jit`` + ``NamedSharding`` lets the
 partitioner schedule the collectives itself. The quantized all-reduce
 is the ZeRO++ two-hop:
@@ -40,7 +40,7 @@ import functools
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 #: Dense module names whose kernels the serving rule table shards
@@ -105,7 +105,7 @@ def row_parallel_matmul(x, kernel, mesh, *, axis: str = "model",
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(*xin), P(axis, None)), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def body(xs, ks):
         part = jnp.einsum("...k,kn->...n", xs, ks)
         if quantized:

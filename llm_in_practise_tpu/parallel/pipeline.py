@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from llm_in_practise_tpu.core import mesh as mesh_lib
@@ -178,7 +178,7 @@ def make_pipeline_loss_fn(cfg, mesh: Mesh, n_micro: int):
         mesh=mesh,
         in_specs=(P(), P(AXIS), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def loss_fn(stem, stacked_blocks, x, y):

@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from llm_in_practise_tpu.parallel.pipeline import AXIS, _gpt_fns
@@ -150,7 +150,7 @@ def make_pipeline_forward(cfg, mesh: Mesh, n_micro: int):
         mesh=mesh,
         in_specs=(P(), P(AXIS), P(AXIS), P(AXIS), P(), P()),
         out_specs=(P(), P(AXIS), P(AXIS)),
-        check_rep=False,
+        check_vma=False,
     )
 
     def forward(stem, stacked_blocks, cache, tokens, index):

@@ -1,0 +1,103 @@
+"""The main path's Pallas kernels, compiled at real widths for a TPU v5e
+that is described and not attached (``jax.experimental.topologies``).
+
+Interpret-mode tests cannot see what the chip's compiler refuses: a
+slice not aligned to the tiling, more fast memory than a kernel may use.
+These compiles can, cost no chip time, and guard every later PR. Nothing
+runs, so they say nothing about results; ``chip_smoke.py`` does that on
+the chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from llm_in_practise_tpu.ops.flash_attention import flash_attention
+from llm_in_practise_tpu.ops.int4_matmul import int4_matmul
+from llm_in_practise_tpu.ops.nf4_matmul import nf4_matmul
+from llm_in_practise_tpu.quant import int4, nf4
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one device of a described v5e 2x2 host, with the
+    persistent compile cache off around the module: such an entry can
+    be written but never read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache.compilation_cache import (
+        reset_cache,
+    )
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", saved)
+    reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _x(m, k, chip):
+    return jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=chip)
+
+
+@pytest.mark.parametrize("k,n", [(4096, 12288), (12288, 4096)])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_nf4_matmul_compiles(chip, k, n, grad):
+    t = _shapes(jax.eval_shape(
+        nf4.quantize, jax.ShapeDtypeStruct((k, n), jnp.float32)), chip)
+
+    def fwd(x, t):
+        return nf4_matmul(x, t, jnp.bfloat16, None, False)
+
+    def bwd(x, t):
+        return jax.grad(lambda x: fwd(x, t).astype(jnp.float32).sum())(x)
+
+    # forward at decode rows, gradient at training rows
+    _compile(bwd if grad else fwd, _x(1024 if grad else 16, k, chip), t)
+
+
+def test_int4_matmul_compiles(chip):
+    k, n = 4096, 12288
+    t = _shapes(jax.eval_shape(
+        int4.rtn_quantize, jax.ShapeDtypeStruct((k, n), jnp.float32)), chip)
+    _compile(lambda x, t: int4_matmul(x, t, jnp.bfloat16, False),
+             _x(16, k, chip), t)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_attention_compiles(chip, grad):
+    q = jax.ShapeDtypeStruct((1, 2048, 32, 128), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(bwd if grad else fwd, q, q, q)
+    # forward + dK/dV + dQ kernels in the backward program
+    assert text.count("tpu_custom_call") >= (3 if grad else 1)

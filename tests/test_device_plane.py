@@ -104,24 +104,36 @@ def test_bench_reexports_are_the_cost_module():
     assert bench.matmul_param_count is cost.matmul_param_count
     assert bench.chip_peak is cost.chip_peak
     assert bench.PEAKS is cost.PEAKS
-    # and the former hand-rolled copy in probe_timing is gone (read the
-    # source as text — importing it would execute the probe)
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "tools", "probe_timing.py")) as f:
-        src = f.read()
-    assert "6 * n_params" not in src and "197e12" not in src
-    assert "flops_per_token" in src
 
 
-def test_peaks_tables_and_fallbacks():
+@pytest.mark.parametrize("kind,raises", [("cpu", False),
+                                         ("weird-accelerator", True)])
+def test_peaks_tables_and_fallbacks(kind, raises, monkeypatch):
+    """Table rows resolve by substring; the CPU backend keeps a
+    rendering; an accelerator kind in no row raises instead of
+    borrowing a v5e's rates — and ``CostModel.from_model`` does not
+    eat the raise."""
+    import types
+
     assert cost._lookup("TPU v5 lite", cost.PEAKS, 0) == 197e12
     assert cost._lookup("TPU v6e", cost.HBM_BW, 0) == 1640e9
-    assert cost._lookup("weird-device", cost.PEAKS,
-                        cost.FALLBACK_PEAK) == cost.FALLBACK_PEAK
-    kind, peak = cost.chip_peak()        # CPU backend: fallback, no raise
-    assert peak > 0 and cost.chip_hbm_bw(kind) > 0
+    model = types.SimpleNamespace(config=types.SimpleNamespace(
+        n_layer=2, vocab_size=64, embed_dim=32, mlp_ratio=4))
+    if raises:
+        for fn in (cost.chip_hbm_bw, cost.chip_ici_bw):
+            with pytest.raises(ValueError, match="matches no row"):
+                fn(kind)
+        monkeypatch.setattr(
+            jax, "devices",
+            lambda: [types.SimpleNamespace(device_kind=kind)])
+        with pytest.raises(ValueError, match="matches no row"):
+            cost.CostModel.from_model(model, {})
+    else:
+        got_kind, peak = cost.chip_peak()    # the test backend IS cpu
+        assert got_kind == kind and peak > 0
+        assert cost.chip_hbm_bw(kind) > 0 and cost.chip_ici_bw(kind) > 0
+        cm = cost.CostModel.from_model(model, {})
+        assert cm.device_kind == kind and cm.peak_flops == peak
 
 
 def test_device_memory_stats_fail_open():

@@ -165,7 +165,6 @@ def test_attention_impl_crossover_heuristic(monkeypatch):
     from llm_in_practise_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
-    monkeypatch.setattr(A, "_flash_available", lambda: True)
 
     class Q:
         def __init__(self, shape):

@@ -96,7 +96,7 @@ def test_zero1_shards_opt_state_only(devices):
 
 
 @pytest.mark.skipif(not envcaps.shard_map_has_check_vma(),
-                    reason=envcaps.OLD_XLA_CPU_NUMERICS_REASON)
+                    reason=envcaps.SHARD_MAP_CHECK_VMA_REASON)
 def test_sharded_matches_single_device(devices):
     """The load-bearing guarantee: every strategy computes the SAME training
     trajectory as one device — sharding is placement, not math."""

@@ -51,7 +51,7 @@ def test_pipeline_loss_matches_reference(setup, n_stages, n_micro):
 
 
 @pytest.mark.skipif(not envcaps.shard_map_has_check_vma(),
-                    reason=envcaps.SHARD_MAP_SPEC_REASON)
+                    reason=envcaps.SHARD_MAP_CHECK_VMA_REASON)
 def test_pipeline_grads_match_reference(setup):
     cfg, model, params, x, y = setup
     mesh = pp.pipeline_mesh(4)

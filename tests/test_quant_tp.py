@@ -67,7 +67,7 @@ def test_nf4_component_shardings_follow_rule_table(devices):
 
 
 @pytest.mark.skipif(not envcaps.shard_map_has_check_vma(),
-                    reason=envcaps.OLD_SHARD_MAP_TP_REASON)
+                    reason=envcaps.SHARD_MAP_CHECK_VMA_REASON)
 def test_nf4_tp_serving_matches_single_device(devices):
     model, params = _model_and_params()
     qtree = quantize_base(params, min_size=4096)

@@ -39,7 +39,7 @@ def _loss_and_grads(model, params, x, y):
     "gpt",
     pytest.param("qwen3", marks=pytest.mark.skipif(
         not envcaps.shard_map_has_check_vma(),
-        reason=envcaps.OLD_XLA_CPU_NUMERICS_REASON)),
+        reason=envcaps.SHARD_MAP_CHECK_VMA_REASON)),
 ])
 def test_remat_grads_exact(rng, family):
     if family == "gpt":

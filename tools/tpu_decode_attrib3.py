@@ -73,6 +73,9 @@ def multi_step(model, qparams, cache0, use_kernels=False):
 
 
 def main() -> None:
+    from llm_in_practise_tpu.core.mesh import require_tpu
+
+    require_tpu()
     results = {"slots": SLOTS, "steps": STEPS, "geom": "d4096 (8B layer)"}
 
     def flush():

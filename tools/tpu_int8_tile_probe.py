@@ -69,6 +69,9 @@ def timeit(fn, n=3):
 
 
 def main() -> None:
+    from llm_in_practise_tpu.core.mesh import require_tpu
+
+    require_tpu()
     cfg = Qwen3Config(
         vocab_size=151936, max_seq_len=1024, rope_theta=1e6,
         tie_word_embeddings=True, remat=False, compute_dtype="bfloat16",

@@ -140,6 +140,9 @@ def time_variant(name: str, peak: float, **kw) -> dict:
 
 
 def main() -> None:
+    from llm_in_practise_tpu.core.mesh import require_tpu
+
+    require_tpu()
     kind, peak = chip_peak()
     print(f"device {kind}", flush=True)
     rows = [

@@ -46,6 +46,9 @@ VOCAB = 151936
 
 
 def main() -> None:
+    from llm_in_practise_tpu.core.mesh import require_tpu
+
+    require_tpu()
     from llm_in_practise_tpu.core.compile_cache import (
         enable_compilation_cache,
     )

@@ -32,6 +32,9 @@ OUT = os.path.join(REPO, "QLORA_14B.json")
 
 
 def main() -> None:
+    from llm_in_practise_tpu.core.mesh import require_tpu
+
+    require_tpu()
     kind, peak = chip_peak()
     print(f"device {kind} peak {peak/1e12:.0f} TF", flush=True)
     result, errors = _fused_scale_proof(

@@ -45,6 +45,9 @@ OUT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 
 
 def main() -> None:
+    from llm_in_practise_tpu.core.mesh import require_tpu
+
+    require_tpu()
     # A real-ish model: GPTLike 6L/512d bf16 (the reference's from-scratch
     # architecture), random weights — acceptance depends on output
     # self-similarity, which repetitive prompts provide regardless of

@@ -64,6 +64,9 @@ PROBES = {
 
 def run_probe(vocab: int, vocab_chunk: int | None, use_head: bool,
               base_mode: str) -> dict:
+    from llm_in_practise_tpu.core.mesh import require_tpu
+
+    require_tpu()
     import jax
     import jax.numpy as jnp
     import numpy as np
