@@ -779,6 +779,7 @@ def host_gap_snapshot(engine=None) -> dict | None:
     snap["host_seconds"] = {k: round(v, 6)
                             for k, v in snap["host_seconds"].items()}
     for k in ("step_wall_seconds_total", "device_seconds_total",
+              "dispatch_issue_seconds_total", "dispatch_wait_seconds_total",
               "device_busy_fraction", "host_gap_fraction", "coverage"):
         snap[k] = round(snap[k], 6)
     snap["coverage_ok"] = snap["coverage"] >= 0.95

@@ -9,29 +9,47 @@ refactor must drive to zero. The recorder turns "the chip never waits
 on Python" from a hope into a gated, regression-tested quantity:
 
 - :class:`StepTrace` — a bounded ring of per-step records. The engine
-  brackets each ``step()`` with :meth:`step_begin`/:meth:`step_end` and
-  marks named host activities with :meth:`scope`; every device dispatch
-  already reports its forced wall time through the engine's
-  ``_note_device_phase``, which feeds :meth:`note_device`. At step end
-  the record partitions the step's wall clock into
-  ``{activity: seconds}`` + device-busy seconds + an ``other``
-  remainder, so coverage (1 − other/wall) is a first-class number the
-  serve benches gate on (≥ 95 %).
+  brackets each ``step()`` with :meth:`step_begin`/:meth:`step_end`,
+  marks named host activities with :meth:`scope`, and brackets every
+  dispatch with :meth:`window_begin` / :meth:`window_issued` /
+  :meth:`window_end`. At step end the record partitions the step's
+  wall clock into ``{activity: seconds}`` + dispatch-window seconds
+  (``device_s``) + an ``other`` remainder, so coverage
+  (1 − other/wall) is a first-class number the serve benches gate on
+  (≥ 95 %).
+- **A dispatch window is host time too.** It runs from the first line
+  that prepares a dispatch to the instant its results are on the host,
+  and has two parts: ``issue`` (argument building, host-to-device
+  copies, COW forks, the Python dispatch — the device may be idle) and
+  ``wait`` (the fetch the code already blocks on). ``device_s`` is
+  their sum: an UPPER bound on device-busy time on the host's clock,
+  not a device measurement. ``issue_s`` / ``wait_s`` say how much of it
+  the host spent before the device could start.
+- **One timeline.** Every record carries ``segments``: ``(name, t0,
+  t1)`` for each scope and each window part on the ``time.time()`` axis
+  of ``start_s`` (one ``perf_counter`` read per edge plus the step's
+  clock offset), so a device idle gap from a profile can be laid over
+  what the engine thread was doing at that instant. The same edges open
+  and close ``jax.profiler.TraceAnnotation``s (``engine_step``,
+  ``engine:<activity>``, ``engine:issue:<phase>``,
+  ``engine:wait:<phase>``), so any profile holds them on the
+  profiler's own clock above the device ops.
 - **Scopes nest**: entering an inner scope pauses the enclosing one, so
   ``index_build`` inside ``admit`` is attributed once, not twice.
-  Device time reported mid-scope is deducted from the surrounding host
-  activity (the ``dispatch_wait`` leftover is then the *host-side*
-  overhead of the dispatch window: argument conversion, fetch slack).
+  A window closed mid-scope is deducted from the surrounding host
+  activity (the ``dispatch_wait`` leftover is what the scope holds
+  outside the window: cost-model arithmetic, booking).
 - **Single-writer**: every mutation happens on the engine thread.
   Scrape threads read :meth:`snapshot` — an atomically swapped dict
   rebuilt once per step — so ``/metrics`` callbacks can never see a
   half-updated step (the torn-read class graftlint's lock pass flags).
 - **Dual-lane Perfetto export**: with a Chrome-JSONL sink attached to
   the tracer (``--trace-file`` / ``LLM_TPU_TRACE_FILE``), each step's
-  host segments and device dispatch windows are written as trace
-  events on two synthetic threads ("engine host lane" / "device lane"),
-  so the gaps between device slices are *visible* in Perfetto instead
-  of inferred from counters.
+  segments are written as trace events on two synthetic threads:
+  "engine host lane" (the activities) and "dispatch window lane (host
+  clock)" (each window as an ``issue`` and a ``wait`` slice). Both are
+  HOST time; where the device was busy is in a ``jax.profiler``
+  capture, under the annotations above.
 
 ``LLM_TPU_STEPTRACE=off`` disables recording entirely (every hook
 degrades to an attribute check; golden tokens are identical either way
@@ -56,7 +74,7 @@ Activity glossary (docs/observability.md "Host timeline"):
 ``adapter_gather`` per-dispatch assembly of the multi-LoRA bank args —
                    slot→row index build + bank snapshot handoff
                    (serve/multi_lora.py, ISSUE 15)
-``dispatch_wait``  jitted-dispatch windows net of the device-booked time
+``dispatch_wait``  the dispatch scopes net of their windows' booked time
 ``sample_commit``  per-token commit/emit loops + prefill finalization
 ``publish``        handoff entry gather/queue on the engine thread
 ``other``          unattributed remainder (the coverage gate bounds it)
@@ -70,6 +88,8 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 ACTIVITIES = ("queue_drain", "admit", "plan", "index_build",
               "draft_propose", "grammar_compile", "grammar_mask",
               "adapter_gather", "dispatch_wait", "sample_commit",
@@ -78,9 +98,13 @@ ACTIVITIES = ("queue_drain", "admit", "plan", "index_build",
 # synthetic Chrome-trace thread ids for the dual-lane view; request
 # spans use real thread idents (< 2^31), so these can't collide
 HOST_LANE_TID = (1 << 31) + 1
-DEVICE_LANE_TID = (1 << 31) + 2
+WINDOW_LANE_TID = (1 << 31) + 2
 
-_MAX_SEGMENTS_PER_STEP = 256    # timeline-capture bound per step
+_MAX_SEGMENTS_PER_STEP = 256    # bound on one record's ``segments``
+
+
+def _close(annotation) -> None:
+    annotation.__exit__(None, None, None)
 
 
 def _enabled_from_env() -> bool:
@@ -125,7 +149,7 @@ _NOOP_SCOPE = _NoopScope()
 class StepTrace:
     """Bounded per-step flight recorder for the engine loop.
 
-    Thread model: ``step_begin``/``step_end``/``scope``/``note_device``
+    Thread model: ``step_begin``/``step_end``/``scope``/``window_*``
     run on the engine thread only (single writer). The ring is guarded
     for the ``/debug``-style readers; cumulative totals and fractions
     are published through an atomically swapped snapshot dict that
@@ -140,13 +164,25 @@ class StepTrace:
         self._lock = threading.Lock()
         # --- engine-thread state (single writer, no lock) ---
         self._scopes = {name: _Scope(self, name) for name in ACTIVITIES}
-        self._stack: list[list] = []      # [name, last_perf, acc, deduct]
+        # [name, last_perf, acc, deduct, enter_perf, annotation]
+        self._stack: list[list] = []
         self._step_t0: float | None = None
         self._step_wall0 = 0.0
+        self._step_ann = None
         self._acts: dict[str, float] = {}
         self._device_s = 0.0
+        self._issue_s = 0.0
         self._dispatches = 0
-        self._segments: list[tuple] | None = None  # timeline capture
+        self._segments: list[tuple] = []   # (name, t0, t1) on perf_counter
+        self._lock_wait_s = 0.0
+        self._gap_before_s = 0.0
+        self._last_end: float | None = None   # previous step_end, perf
+        # the open dispatch window (``_win_t0`` None: none open); the
+        # annotation is that of the part under way, issue then wait
+        self._win_phase = ""
+        self._win_t0: float | None = None
+        self._win_issued: float | None = None
+        self._win_ann = None
         self._seq = 0
         # --- cumulative totals (engine-thread writes; scrapes read the
         # swapped snapshot, never these) ---
@@ -154,18 +190,23 @@ class StepTrace:
         self._steps_total = 0
         self._step_wall_total = 0.0
         self._device_seconds_total = 0.0
+        self._issue_seconds_total = 0.0
         # rolling fractions over the last `window` steps (cached floats,
         # same convention as DispatchMeter.per_step)
         self._window = window
         self._busy_roll: deque = deque(maxlen=window)  # (wall, device)
         self._snap = self._build_snapshot()
 
+    @property
+    def _recording(self) -> bool:
+        return self.enabled and self._step_t0 is not None
+
     # -- engine-thread hooks --------------------------------------------------
 
     def scope(self, name: str):
         """``with st.scope("admit"):`` — attribute the enclosed wall
-        time (minus inner scopes and device time) to ``name``."""
-        if not self.enabled or self._step_t0 is None:
+        time (minus inner scopes and dispatch windows) to ``name``."""
+        if not self._recording:
             return _NOOP_SCOPE
         return self._scopes[name]
 
@@ -174,91 +215,148 @@ class StepTrace:
         if self._stack:
             top = self._stack[-1]
             top[2] += now - top[1]
-        self._stack.append([name, now, 0.0, 0.0])
-        if self._segments is not None:
-            # timeline capture: remember the wall start; duration fills
-            # in at exit (host lane shows gross spans — nesting is
-            # visible as containment, like any flame chart)
-            self._stack[-1].append(time.time())
+        self._stack.append([name, now, 0.0, 0.0, now,
+                            TraceAnnotation("engine:" + name)])
 
     def _exit(self) -> None:
         now = time.perf_counter()
-        frame = self._stack.pop()
-        name, last, acc, deduct = frame[0], frame[1], frame[2], frame[3]
+        name, last, acc, deduct, entered, ann = self._stack.pop()
+        _close(ann)
         host = max(0.0, acc + (now - last) - deduct)
         self._acts[name] = self._acts.get(name, 0.0) + host
         if self._stack:
             self._stack[-1][1] = now
-        if (self._segments is not None and len(frame) > 4
-                and len(self._segments) < _MAX_SEGMENTS_PER_STEP):
-            # GROSS span (enter → exit wall clock): Perfetto nests
-            # overlapping same-tid slices, so inner scopes render as
-            # children; device windows overlap from the device lane
-            self._segments.append(
-                ("host", name, frame[4], time.time() - frame[4]))
+        # GROSS span (enter → exit): nesting shows as containment, like
+        # any flame chart; the net seconds are in ``activities``
+        self._segment(name, entered, now)
 
-    def note_device(self, duration_s: float, phase: str = "dispatch") -> None:
-        """Book one dispatch's forced wall time to the device lane and
-        deduct it from the current host activity (the engine measures
-        ``duration_s`` inside a host scope, so without the deduction the
-        same wall clock would count twice)."""
-        if not self.enabled or self._step_t0 is None:
+    def _segment(self, name: str, t0: float, t1: float) -> None:
+        if len(self._segments) < _MAX_SEGMENTS_PER_STEP:
+            self._segments.append((name, t0, t1))
+
+    def window_begin(self, phase: str) -> None:
+        """Open a dispatch window: the first line that prepares the
+        dispatch. Always stamps (the engine needs the durations for its
+        own books); records and annotates only inside a recorded step."""
+        self._win_phase = phase
+        self._win_ann = (TraceAnnotation("engine:issue:" + phase)
+                         if self._recording else None)
+        self._win_issued = None
+        self._win_t0 = time.perf_counter()
+
+    def window_issued(self) -> None:
+        """The jitted call(s) have returned: everything after is the
+        fetch the code already blocks on. No synchronisation here."""
+        self._win_issued = time.perf_counter()
+        if self._win_ann is not None:
+            _close(self._win_ann)
+            self._win_ann = TraceAnnotation(
+                "engine:wait:" + self._win_phase)
+
+    def window_end(self) -> tuple[float, float]:
+        """Results are on the host. Returns ``(window_s, issue_s)`` and
+        books the window (see :meth:`note_device`)."""
+        now = time.perf_counter()
+        t0, self._win_t0 = self._win_t0, None
+        issued = now if self._win_issued is None else self._win_issued
+        if self._win_ann is not None:
+            _close(self._win_ann)
+            self._win_ann = None
+        if self._recording:
+            self._book_window(self._win_phase, t0, issued, now)
+        return now - t0, issued - t0
+
+    def note_device(self, duration_s: float, phase: str = "dispatch",
+                    issue_s: float = 0.0) -> None:
+        """Book a dispatch window of ``duration_s`` that ended now (its
+        first ``issue_s`` seconds the issue part) and deduct it from the
+        current host activity: the window is measured inside a host
+        scope, so without the deduction the same wall clock would count
+        twice."""
+        if not self._recording:
             return
-        self._device_s += float(duration_s)
+        now = time.perf_counter()
+        t0 = now - float(duration_s)
+        self._book_window(phase, t0, t0 + float(issue_s), now)
+
+    def _book_window(self, phase: str, t0: float, issued: float,
+                     t1: float) -> None:
+        self._device_s += t1 - t0
+        self._issue_s += issued - t0
         self._dispatches += 1
         if self._stack:
-            self._stack[-1][3] += float(duration_s)
-        if (self._segments is not None
-                and len(self._segments) < _MAX_SEGMENTS_PER_STEP):
-            self._segments.append(
-                ("device", f"device.{phase}",
-                 time.time() - duration_s, duration_s))
+            self._stack[-1][3] += t1 - t0
+        self._segment("issue:" + phase, t0, issued)
+        self._segment("wait:" + phase, issued, t1)
 
-    def step_begin(self, *, timeline: bool = False) -> None:
-        """Open a step record. ``timeline=True`` additionally captures
-        per-segment (start, duration) intervals for the Perfetto
-        dual-lane export (only worth paying when a JSONL sink exists)."""
+    def step_begin(self, *, lock_wait_s: float = 0.0) -> None:
+        """Open a step record. ``lock_wait_s``: what the caller waited
+        for the engine's step lock before this call (a record field, not
+        an activity: it lies before the step's wall clock, inside
+        ``gap_before_s``)."""
         if not self.enabled:
             return
+        self._step_ann = TraceAnnotation("engine_step")
         self._step_t0 = time.perf_counter()
         self._step_wall0 = time.time()
+        self._lock_wait_s = float(lock_wait_s)
+        self._gap_before_s = (self._step_t0 - self._last_end
+                              if self._last_end is not None else 0.0)
         self._acts = {}
         self._device_s = 0.0
+        self._issue_s = 0.0
         self._dispatches = 0
         self._stack = []
-        self._segments = [] if timeline else None
+        self._segments = []
+
+    def _close_open(self) -> None:
+        """Close what an exception left open, so that neither a scope's
+        time nor an annotation leaks out of the step."""
+        if self._win_t0 is not None:
+            self.window_end()
+        while self._stack:
+            self._exit()
 
     def step_abort(self) -> None:
         """Discard the open record (idle background-loop polls must not
         decay the fractions to meaninglessness — same rule as
         ``DispatchMeter.note_step``)."""
+        if self._step_t0 is None:
+            return
+        self._close_open()
+        _close(self._step_ann)
         self._step_t0 = None
-        self._segments = None
 
     def step_end(self, tracer=None) -> dict | None:
         """Close the record: derive ``other``, append to the ring,
         refresh the cumulative totals + the scrape snapshot, and (with a
         sink-carrying ``tracer``) emit the dual-lane Chrome events.
         Returns the record dict (bench/test introspection)."""
-        if not self.enabled or self._step_t0 is None:
+        if not self._recording:
             return None
-        wall = time.perf_counter() - self._step_t0
-        # a scope left open by an exception would leak its time into
-        # `other`; close anything still on the stack so the record
-        # stays a partition
-        while self._stack:
-            self._exit()
+        self._close_open()
+        end = time.perf_counter()
+        _close(self._step_ann)
+        wall = end - self._step_t0
         attributed = sum(self._acts.values()) + self._device_s
         other = max(0.0, wall - attributed)
         self._acts["other"] = self._acts.get("other", 0.0) + other
         self._seq += 1
+        # perf_counter edges → the time.time() axis of start_s
+        off = self._step_wall0 - self._step_t0
         rec = {
             "seq": self._seq,
             "start_s": self._step_wall0,
             "wall_s": wall,
             "device_s": self._device_s,
+            "issue_s": self._issue_s,
+            "wait_s": self._device_s - self._issue_s,
+            "lock_wait_s": self._lock_wait_s,
+            "gap_before_s": self._gap_before_s,
             "dispatches": self._dispatches,
             "activities": dict(self._acts),
+            "segments": [(name, t0 + off, t1 + off)
+                         for name, t0, t1 in self._segments],
         }
         with self._lock:
             self._ring.append(rec)
@@ -267,13 +365,13 @@ class StepTrace:
         self._steps_total += 1
         self._step_wall_total += wall
         self._device_seconds_total += self._device_s
+        self._issue_seconds_total += self._issue_s
         self._busy_roll.append((wall, self._device_s))
-        segments = self._segments
         self._step_t0 = None
-        self._segments = None
+        self._last_end = end
         self._snap = self._build_snapshot()
-        if tracer is not None and segments:
-            self._emit_timeline(tracer, segments)
+        if tracer is not None:
+            self._emit_timeline(tracer, rec["segments"])
         return rec
 
     # -- scrape-side reads ----------------------------------------------------
@@ -290,6 +388,9 @@ class StepTrace:
             "steps": self._steps_total,
             "step_wall_seconds_total": wall,
             "device_seconds_total": dev,
+            # the dispatch windows' two parts (issue + wait = device)
+            "dispatch_issue_seconds_total": self._issue_seconds_total,
+            "dispatch_wait_seconds_total": dev - self._issue_seconds_total,
             "host_seconds": dict(self._host_seconds),
             # rolling over the last `window` steps — the live dial. A
             # recorder that measured nothing (fresh, idle, or disabled)
@@ -335,13 +436,15 @@ class StepTrace:
         sink = getattr(tracer, "file_sink_path", None) or "<sink>"
         if sink != self._meta_sink:
             self._meta_sink = sink
-            for tid, label in ((HOST_LANE_TID, "engine host lane"),
-                               (DEVICE_LANE_TID, "device lane")):
+            for tid, label in (
+                    (HOST_LANE_TID, "engine host lane"),
+                    (WINDOW_LANE_TID, "dispatch window lane (host clock)")):
                 write({"ph": "M", "pid": pid, "tid": tid,
                        "name": "thread_name", "args": {"name": label}})
-        for lane, name, start_wall, dur in segments:
+        for name, t0, t1 in segments:
+            window = name.startswith(("issue:", "wait:"))
             write({
                 "ph": "X", "cat": "steptrace", "name": name,
-                "ts": start_wall * 1e6, "dur": dur * 1e6, "pid": pid,
-                "tid": HOST_LANE_TID if lane == "host" else DEVICE_LANE_TID,
+                "ts": t0 * 1e6, "dur": (t1 - t0) * 1e6, "pid": pid,
+                "tid": WINDOW_LANE_TID if window else HOST_LANE_TID,
             })

@@ -292,7 +292,7 @@ class Tracer:
         """Write one raw Chrome trace event to the JSONL sink only — no
         ring entry. The steptrace dual-lane timeline
         (:mod:`llm_in_practise_tpu.obs.steptrace`) rides here: per-step
-        host/device lane slices would evict real request spans if they
+        host/window lane slices would evict real request spans if they
         went through the bounded ring."""
         if self._file is None:  # graftlint: disable=guarded-by
             return

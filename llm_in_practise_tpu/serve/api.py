@@ -344,6 +344,9 @@ class OpenAIServer:
 
     def handle_chat(self, body: dict, send_json, send_stream, trace=None,
                     session_id: str | None = None):
+        # the body has been read (and JSON-decoded): the front end's
+        # api_pre_submit overlay starts here
+        t_body = time.monotonic()
         try:
             req = schemas.ChatCompletionRequest.from_dict(body)
         except schemas.ValidationError as e:
@@ -446,6 +449,11 @@ class OpenAIServer:
             handle = engine.submit(prompt_ids, params, kv_entry=kv_entry,
                                    trace=span.context(),
                                    session_id=session_id)
+            # front-end instants: plain float attributes, which the
+            # engine's finish funnel turns into the api_* overlays of
+            # the request's critical path (never a cp insert from here)
+            handle.api_body_time = t_body
+            api_times = {"api_pre_submit_s": handle.submit_time - t_body}
             req_id = schemas.completion_id()
 
             def queue_full_429(message):
@@ -544,7 +552,13 @@ class OpenAIServer:
                         yield schemas.chat_completion_chunk(
                             req_id=req_id, model=req.model, delta=None
                         )
-                        flush_s += time.monotonic() - t
+                        # resumed: the first event is with the socket
+                        now = time.monotonic()
+                        handle.api_first_flush_time = now
+                        if handle.first_token_time is not None:
+                            api_times["api_first_flush_s"] = (
+                                now - handle.first_token_time)
+                        flush_s += now - t
                         n_chunks += 1
                         tokens, prev_text = [], ""
 
@@ -615,16 +629,16 @@ class OpenAIServer:
                         if exc is None:
                             span.end(status=200,
                                      finish_reason=handle.finish_reason
-                                     or "stop")
+                                     or "stop", **api_times)
                         elif isinstance(exc, GeneratorExit):
                             span.end(status=200,
                                      finish_reason="client_disconnect",
-                                     chunks_sent=n_chunks)
+                                     chunks_sent=n_chunks, **api_times)
                         else:
                             span.end(status=200,
                                      finish_reason="stream_error",
                                      error=type(exc).__name__,
-                                     chunks_sent=n_chunks)
+                                     chunks_sent=n_chunks, **api_times)
                 return send_stream(chunks())
 
             try:
@@ -651,7 +665,7 @@ class OpenAIServer:
                 except (ValueError, KeyError, TypeError):
                     tool_calls = None
             span.end(status=200, finish_reason=handle.finish_reason or "stop",
-                     completion_tokens=len(out_ids))
+                     completion_tokens=len(out_ids), **api_times)
             return send_json(200, schemas.chat_completion_response(
                 req_id=req_id, model=req.model, text=text,
                 finish_reason=handle.finish_reason or "stop", usage=usage,
@@ -852,11 +866,23 @@ class OpenAIServer:
             "llm_engine_steps_total",
             lambda: stp.snapshot()["steps"],
             "non-idle engine step() iterations recorded")
+        reg.counter_func(
+            "llm_dispatch_issue_seconds_total",
+            lambda: stp.snapshot()["dispatch_issue_seconds_total"],
+            "dispatch windows' issue part: argument building, "
+            "host-to-device copies and the Python dispatch, until the "
+            "jitted call returned (host time; the device may be idle)")
+        reg.counter_func(
+            "llm_dispatch_wait_seconds_total",
+            lambda: stp.snapshot()["dispatch_wait_seconds_total"],
+            "dispatch windows' wait part: from the jitted call's return "
+            "to its results on the host")
         reg.gauge_func(
             "llm_device_busy_fraction",
             lambda: stp.snapshot()["device_busy_fraction"],
-            "rolling fraction of step wall time the device was busy "
-            "(forced dispatch windows / step wall, last 50 steps)")
+            "rolling fraction of step wall time inside dispatch windows "
+            "(issue + wait on the host clock, last 50 steps): an upper "
+            "bound on device-busy time, not a device measurement")
         reg.gauge_func(
             "llm_host_gap_fraction",
             lambda: stp.snapshot()["host_gap_fraction"],

@@ -88,15 +88,34 @@ _FINISH = object()  # sentinel closing a request's token queue
 # Per-request critical-path segments (ISSUE 11): every finished
 # request's wall time decomposes into these bins — surfaced per request
 # at GET /debug/requests and aggregated into
-# llm_request_critical_path_seconds_total{segment=…}. ``host_gap`` is
-# the residual none of the attributed segments claim (the
-# between-dispatch host time the steptrace recorder measures per step);
-# ``stream_flush`` is the API-side SSE write tail, measured on the
-# handler thread CONCURRENTLY with decode, so it is reported alongside
-# the engine segments but excluded from the wall-clock partition.
+# llm_request_critical_path_seconds_total{segment=…}.
+#
+# The booking rule for dispatch windows: EVERY window is booked to EVERY
+# request that holds a slot during it, under the segment that names the
+# request's relation to the window — ``prefill_dispatch`` (it advanced
+# this request's own prompt), ``decode_dispatch`` (a plain decode window
+# this request rode), ``prefill_stall`` (it advanced someone else's
+# prompt — one-shot, chunk or fused mixed step — while this request sat
+# in its slot) and ``decode_interleave`` (a plain decode window while
+# this request was mid-prefill). ``host_gap``, the residual none of the
+# attributed segments claim, is thereby host time only: the engine
+# thread between windows.
+#
+# Overlays are reported alongside and excluded from the wall-clock
+# partition: ``stream_flush`` (the API-side SSE write tail, concurrent
+# with decode), ``dispatch_issue`` (the issue parts of the windows
+# booked to the request: host time inside them), ``api_pre_submit``
+# (HTTP body read → the request entering ``submit``: JSON, chat
+# template, BPE; before the wall clock starts) and ``api_first_flush``
+# (first token on the engine thread → first SSE event handed to the
+# socket).
 CP_SEGMENTS = ("queue_wait", "admission", "prefill_dispatch",
-               "decode_dispatch", "host_gap", "handoff_wire",
-               "preempt_recompute", "stream_flush")
+               "decode_dispatch", "prefill_stall", "decode_interleave",
+               "host_gap", "handoff_wire", "preempt_recompute",
+               "stream_flush", "dispatch_issue", "api_pre_submit",
+               "api_first_flush")
+CP_OVERLAYS = frozenset(("stream_flush", "dispatch_issue",
+                         "api_pre_submit", "api_first_flush"))
 # re-admission after a page-pool preemption re-pays these segments; the
 # re-pay is charged to preempt_recompute so a preempted request's
 # breakdown says "recompute", not "a second mysterious prefill"
@@ -204,6 +223,19 @@ class Request:
     # handoff namespace when one is wired) so the next turn starts warm;
     # admission consults the store's pending fleet pulls under this id.
     session_id: str | None = None
+    # seconds of dispatch windows booked to this request so far, under
+    # whatever segment (engine thread only): admission's bookkeeping
+    # share is the admit wall minus what this grew by meanwhile
+    cp_window_s: float = dataclasses.field(
+        default=0.0, repr=False, compare=False)
+    # the HTTP handler's two instants (``time.monotonic``; plain floats,
+    # never a ``cp`` insert from a handler thread): the body was read /
+    # the first SSE event had been handed to the socket. The finish
+    # funnel turns them into the ``api_*`` overlays.
+    api_body_time: float | None = dataclasses.field(
+        default=None, repr=False, compare=False)
+    api_first_flush_time: float | None = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     def cp_add(self, seg: str, dt: float) -> None:
         """Accumulate ``dt`` seconds into critical-path segment ``seg``.
@@ -216,6 +248,14 @@ class Request:
         if self.requeue_time is not None and seg in _CP_RECOMPUTE_SEGS:
             seg = "preempt_recompute"
         self.cp[seg] = self.cp.get(seg, 0.0) + float(dt)
+
+    def cp_window(self, seg: str, dt: float, issue_s: float) -> None:
+        """Book one dispatch window this request sat through (see
+        CP_SEGMENTS for the rule that picks ``seg``)."""
+        self.cp_add(seg, dt)
+        self.cp_window_s += dt
+        self.cp["dispatch_issue"] = (self.cp.get("dispatch_issue", 0.0)
+                                     + issue_s)
 
     def next_item(self, poll_s: float = 1.0):
         """Next queue item — a token id or the internal finish sentinel
@@ -2047,30 +2087,35 @@ class InferenceEngine:
             req.cache_outcome = "partial"
 
     @staticmethod
-    def _cp_pf_spent(req: Request) -> float:
-        """Prefill-attributed critical-path seconds booked so far —
-        the admission segment is the admit wall MINUS what the inner
-        prefill dispatches already claimed."""
-        return (req.cp.get("prefill_dispatch", 0.0)
-                + req.cp.get("preempt_recompute", 0.0))
-
-    def _cp_admission(self, req: Request, dt: float, pre: float) -> None:
-        req.cp_add("admission",
-                   max(0.0, dt - (self._cp_pf_spent(req) - pre)))
+    def _cp_admission(req: Request, dt: float, pre: float) -> None:
+        """The admission segment: the admit wall ``dt`` MINUS the
+        dispatch windows booked meanwhile (``cp_window_s`` was ``pre``
+        before: the request's own prefill, and other requests' windows
+        it sat through)."""
+        req.cp_add("admission", max(0.0, dt - (req.cp_window_s - pre)))
 
     def _record_finished(self, req: Request) -> None:
         """Finalize the request's critical-path breakdown and remember
         it for ``GET /debug/requests``. ``host_gap`` is the residual
-        wall time no attributed segment claims — exactly the
-        between-dispatch host time the steptrace recorder measures per
-        step, here per request. Runs on whichever thread finishes the
-        request (engine, publisher, HTTP shed path)."""
+        wall time no attributed segment claims; every dispatch window
+        the request sat through is attributed (CP_SEGMENTS), so the
+        residual is the engine thread between windows. Runs on
+        whichever thread finishes the request (engine, publisher, HTTP
+        shed path)."""
         wall = (req.finish_time or time.monotonic()) - req.submit_time
         if req.finish_reason == "queue_full" and not req.cp:
             # a shed spent its whole life waiting; say so
             req.cp["queue_wait"] = wall
+        # the front end's overlays, from the handler's two instants
+        if req.api_body_time is not None:
+            req.cp["api_pre_submit"] = max(
+                0.0, req.submit_time - req.api_body_time)
+        if (req.api_first_flush_time is not None
+                and req.first_token_time is not None):
+            req.cp["api_first_flush"] = max(
+                0.0, req.api_first_flush_time - req.first_token_time)
         attributed = sum(v for k, v in req.cp.items()
-                         if k != "stream_flush")
+                         if k not in CP_OVERLAYS)
         req.cp["host_gap"] = max(0.0, wall - attributed)
         with self.stats.lock:
             cp = self.stats.critical_path
@@ -2100,19 +2145,38 @@ class InferenceEngine:
                 reg.note_tokens(req.adapter, req.n_generated)
         self.finished.append(req)
 
+    def _window_close(self, kind: str, own=()) -> tuple[float, float]:
+        """Close the dispatch window ``steptrace.window_begin`` opened:
+        its results are on the host. Books the window in the step record
+        and to EVERY request holding a slot (the rule above
+        CP_SEGMENTS). ``kind``: ``"prefill"`` — the window advanced the
+        prompts of the ``own`` requests (one-shot, chunk, or the fused
+        mixed step) — or ``"decode"`` — a plain decode window the
+        ``own`` requests rode. Returns ``(window_s, issue_s)``."""
+        dt, issue_s = self.steptrace.window_end()
+        mine, other = (("prefill_dispatch", "prefill_stall")
+                       if kind == "prefill"
+                       else ("decode_dispatch", "decode_interleave"))
+        booked = set()
+        for req in own:
+            req.cp_window(mine, dt, issue_s)
+            booked.add(req.uid)
+        for req in self.slot_req:
+            if req is not None and req.uid not in booked:
+                req.cp_window(other, dt, issue_s)
+                booked.add(req.uid)
+        return dt, issue_s
+
     def _note_device_phase(self, phase: str, *, tokens: int,
                            attended_keys: float, weight_passes: float,
                            kv_read_tokens: float, dt: float) -> None:
         """Book one dispatch's device-plane sample (obs/cost.py → the
         llm_dispatch_mfu / llm_dispatch_hbm_bw_util gauges). ``dt`` is
-        dispatch-issue + result-fetch wall time on this thread; with no
-        cost model only tokens-per-dispatch is recorded. Draft-model
-        dispatches are not booked (the cost model covers the target
-        model; the draft's work would inflate both utilizations)."""
-        # host-gap recorder: the forced dispatch window is device-busy
-        # time; it is deducted from the surrounding host activity so the
-        # step partition never double-counts this wall clock
-        self.steptrace.note_device(dt, phase)
+        the dispatch window (issue + result fetch) on this thread, as
+        ``_window_close`` returned it; with no cost model only
+        tokens-per-dispatch is recorded. Draft-model dispatches are not
+        booked (the cost model covers the target model; the draft's
+        work would inflate both utilizations)."""
         cm = self.cost_model
         mfu = bw = None
         if cm is not None and dt > 0:
@@ -2251,7 +2315,7 @@ class InferenceEngine:
                 path = ("kv_direct_insert"
                         if hit is not None and hit.length == plen
                         else "prefill")
-                pre = self._cp_pf_spent(req)
+                pre = req.cp_window_s
                 self._begin_prefill(req, slot, plen, hit=hit)
                 dt = time.monotonic() - t0
                 self._trace_phase(req, "engine.admit", dt, slot=slot,
@@ -2260,7 +2324,7 @@ class InferenceEngine:
             admitted = True
         if batch:
             t0 = time.monotonic()
-            pre = {req.uid: self._cp_pf_spent(req) for _, req, _ in batch}
+            pre = {req.uid: req.cp_window_s for _, req, _ in batch}
             self._prefill_batch(batch)
             dt = time.monotonic() - t0
             for slot, req, plen in batch:
@@ -2270,7 +2334,7 @@ class InferenceEngine:
                 self._cp_admission(req, dt, pre[req.uid])
         for slot, req, plen in deferred:
             t0 = time.monotonic()
-            pre = self._cp_pf_spent(req)
+            pre = req.cp_window_s
             self._begin_prefill(req, slot, plen)  # fresh lookup: now a hit
             dt = time.monotonic() - t0
             self._trace_phase(req, "engine.admit", dt,
@@ -2329,14 +2393,15 @@ class InferenceEngine:
                     for j, (_, req, plen) in enumerate(part):
                         ids[j, :plen] = req.prompt_ids
                         lens[j] = plen
-                # per-REQUEST adapter rows (the one dispatch whose batch
-                # dim is requests, not the slot plane)
-                lora = self._lora_args_for(
-                    [r.adapter for _, r, _ in part])
-                kw = {} if lora is None else {"lora": lora}
-                pf = self._prefill if lora is None else self._prefill_lora
+                    # per-REQUEST adapter rows (the one dispatch whose
+                    # batch dim is requests, not the slot plane)
+                    lora = self._lora_args_for(
+                        [r.adapter for _, r, _ in part])
+                    kw = {} if lora is None else {"lora": lora}
+                    pf = (self._prefill if lora is None
+                          else self._prefill_lora)
                 with self.steptrace.scope("dispatch_wait"):
-                    t0 = time.monotonic()
+                    self.steptrace.window_begin("prefill")
                     last, pre = pf(
                         self.params, jnp.asarray(ids), jnp.asarray(lens),
                         **kw)
@@ -2362,7 +2427,7 @@ class InferenceEngine:
                         logits = logits + self._grammar_mask_rows(
                             [self._ensure_constraint(r)
                              for _, r, _ in part])
-                    first = np.asarray(sample_token_batched(
+                    first = sample_token_batched(
                         sub, logits,
                         temperature=jnp.asarray(
                             [r.params.temperature for _, r, _ in part],
@@ -2375,22 +2440,22 @@ class InferenceEngine:
                             jnp.float32),
                         greedy=jnp.asarray(
                             [r.params.greedy for _, r, _ in part], bool),
-                    ))
+                    )
+                    self.steptrace.window_issued()
+                    first = np.asarray(first)   # forces the chain
+                    # every member waited the whole batched dispatch
+                    dt, _ = self._window_close(
+                        "prefill", [r for _, r, _ in part])
                     # device plane: useful (un-padded) tokens only, so
-                    # bucket padding shows up as lost MFU — which it is.
-                    # (dt is honest: np.asarray above forced the chain.)
+                    # bucket padding shows up as lost MFU — which it is
                     keys = sum(CostModel.chunk_keys(p, 0)
                                for _, _, p in part)
-                    dt = time.monotonic() - t0
                     self._note_device_phase(
                         "prefill",
                         tokens=sum(p for _, _, p in part),
                         attended_keys=keys,
                         weight_passes=1, kv_read_tokens=keys,
                         dt=dt)
-                for _, req, _ in part:
-                    # every member waited the whole batched dispatch
-                    req.cp_add("prefill_dispatch", dt)
                 with self.steptrace.scope("sample_commit"):
                     for j, (slot, req, plen) in enumerate(part):
                         if self.paged is not None:
@@ -2901,24 +2966,24 @@ class InferenceEngine:
                 gidx = self.paged.gather_idx(W)
         kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
-            t0 = time.monotonic()
+            self.steptrace.window_begin("prefill")
             fn = self._pg_chunk if lora is None else self._pg_chunk_lora
             last, self.paged.kv = fn(
                 self.params, self.paged.kv, jnp.asarray(gidx),
                 jnp.asarray(tok), jnp.asarray(starts), jnp.asarray(lens),
                 jnp.asarray(sidx), **kw)
             out = last[0:1] if one else last[slot:slot + 1]
-            # force + stamp dt exactly like _prefill_into_slot (the
-            # logits feed the first-token sample on this same call path
-            # anyway)
+            self.steptrace.window_issued()
+            # force before the window closes, exactly like
+            # _prefill_into_slot (the logits feed the first-token sample
+            # on this same call path anyway)
             jax.block_until_ready(out)
-            dt = time.monotonic() - t0
+            dt, _ = self._window_close(
+                "prefill", () if req is None else (req,))
             keys = CostModel.chunk_keys(len(suffix), done)
             self._note_device_phase(
                 "prefill", tokens=len(suffix), attended_keys=keys,
                 weight_passes=1, kv_read_tokens=keys, dt=dt)
-        if req is not None:
-            req.cp_add("prefill_dispatch", dt)
         return out
 
     _UNSET = object()
@@ -2995,30 +3060,30 @@ class InferenceEngine:
                     chunk = st["req"].prompt_ids[
                         st["done"]: st["done"] + self.chunked_prefill]
                     entries.append((slot, st, chunk))
-            C = self.chunked_prefill
-            # whole-cache batching needs every row's C-wide write window
-            # inside cache_len — a clamped scatter on a near-full ACTIVE
-            # row would overwrite attended KV. Rare tail case: fall back
-            # to sequential single-slot chunks. (The paged layout is
-            # always batchable: discarded writes are routed to the
-            # trash page by the host-built scatter indices, so there is
-            # no clamp hazard to dodge.)
-            batchable = self.paged is not None or (
-                len(entries) > 1 and all(
-                    int(self.slot_len[s]) + C <= self.cache_len
-                    for s in range(self.max_slots)
-                    if s not in self.slot_prefill
-                    and self.slot_req[s] is not None  # free rows are dead
-                ))
-            # device-plane accounting reads each chunk's pre-advance
-            # context; compute before the branches mutate st["done"]
-            pf_tokens = sum(len(c) for _, _, c in entries)
-            pf_keys = sum(CostModel.chunk_keys(len(c), st["done"])
-                          for _, st, c in entries)
-            lora = self._lora_args()   # slot-plane (batched chunk rows)
-            kw = {} if lora is None else {"lora": lora}
+                C = self.chunked_prefill
+                # whole-cache batching needs every row's C-wide write window
+                # inside cache_len — a clamped scatter on a near-full ACTIVE
+                # row would overwrite attended KV. Rare tail case: fall back
+                # to sequential single-slot chunks. (The paged layout is
+                # always batchable: discarded writes are routed to the
+                # trash page by the host-built scatter indices, so there is
+                # no clamp hazard to dodge.)
+                batchable = self.paged is not None or (
+                    len(entries) > 1 and all(
+                        int(self.slot_len[s]) + C <= self.cache_len
+                        for s in range(self.max_slots)
+                        if s not in self.slot_prefill
+                        and self.slot_req[s] is not None  # free rows are dead
+                    ))
+                # device-plane accounting reads each chunk's pre-advance
+                # context; compute before the branches mutate st["done"]
+                pf_tokens = sum(len(c) for _, _, c in entries)
+                pf_keys = sum(CostModel.chunk_keys(len(c), st["done"])
+                              for _, st, c in entries)
+                lora = self._lora_args()   # slot-plane (batched chunk rows)
+                kw = {} if lora is None else {"lora": lora}
             with self.steptrace.scope("dispatch_wait"):
-                t0 = time.monotonic()
+                self.steptrace.window_begin("prefill")
                 if self.paged is not None:
                     self._paged_chunk_dispatch(entries, lora=lora)
                 elif batchable:
@@ -3048,18 +3113,21 @@ class InferenceEngine:
                             **skw,
                         )
                         st["done"] += len(chunk)
-                # force the chunks' last-logits before stamping dt: on
-                # an async backend issue time alone would inflate the
-                # prefill MFU/BW gauges ~device-time/dispatch-time-fold
-                # (the decode and fused paths force every dispatch the
-                # same way). The logits are consumed at activation
-                # regardless; KV writes land in the same program, so
-                # this waits only for work the next chunk depends on
-                # anyway.
+                self.steptrace.window_issued()
+                # force the chunks' last-logits before the window
+                # closes: on an async backend issue time alone would
+                # inflate the prefill MFU/BW gauges
+                # ~device-time/dispatch-time-fold (the decode and fused
+                # paths force every dispatch the same way). The logits
+                # are consumed at activation regardless; KV writes land
+                # in the same program, so this waits only for work the
+                # next chunk depends on anyway.
                 jax.block_until_ready([st["last_logits"]
                                        for _, st, _ in entries])
-                dt = time.monotonic() - t0
-                self._trace_chunks(entries, dt, batched=batchable)
+                # every mid-prefill request waited the whole dispatch
+                dt, issue_s = self._window_close(
+                    "prefill", [st["req"] for _, st, _ in entries])
+                self._trace_chunks(entries, dt, issue_s, batched=batchable)
                 self._note_device_phase(
                     "prefill", tokens=pf_tokens, attended_keys=pf_keys,
                     weight_passes=1 if batchable else len(entries),
@@ -3070,18 +3138,18 @@ class InferenceEngine:
                 self._finalize_prefills()
         return progressed
 
-    def _trace_chunks(self, entries, dt: float, *, batched: bool,
-                      fused: bool = False) -> None:
-        """One ``engine.prefill_chunk`` span per traced mid-prefill row
-        (the duration is dispatch-issue time — on an async backend the
-        device compute may still be in flight, see docs/observability.md)."""
+    def _trace_chunks(self, entries, dt: float, issue_s: float, *,
+                      batched: bool, fused: bool = False) -> None:
+        """One ``engine.prefill_chunk`` span per traced mid-prefill row.
+        The duration is the whole dispatch window (the results were
+        forced before it closed); ``issue_s`` is the part of it before
+        the jitted call returned, as ``/debug/requests`` books it under
+        ``dispatch_issue``."""
         for slot, st, chunk in entries:
             self._trace_phase(st["req"], "engine.prefill_chunk", dt,
                               slot=slot, done=st["done"],
                               chunk_tokens=len(chunk), batched=batched,
-                              fused=fused)
-            # every mid-prefill request waited the whole chunk dispatch
-            st["req"].cp_add("prefill_dispatch", dt)
+                              fused=fused, issue_s=issue_s)
 
     def _chunk_batch_rows(self, entries):
         """Host arrays (tok, starts, lens) for a whole-cache batched
@@ -3226,7 +3294,7 @@ class InferenceEngine:
     def _prefill_into_slot_timed(self, req, slot, plen, hit):
         lora = self._lora_args_for([req.adapter])
         kw = {} if lora is None else {"lora": lora}
-        t0 = time.monotonic()
+        self.steptrace.window_begin("prefill")
         if hit is not None:
             suffix = req.prompt_ids[hit.length:]
             sbucket = self._bucket_for(len(suffix))
@@ -3249,18 +3317,18 @@ class InferenceEngine:
                 jnp.asarray([plen], jnp.int32), **kw
             )
             new, start = plen, 0
-        # force + stamp dt BEFORE the insert/prefix-store work so this
-        # sample covers exactly the prefill forward, same boundary as
-        # the chunked/fused paths (async-backend honesty — see
-        # _advance_prefills); the logits feed the first-token sample on
-        # this same call path anyway
+        self.steptrace.window_issued()
+        # force + close the window BEFORE the insert/prefix-store work
+        # so this sample covers exactly the prefill forward, same
+        # boundary as the chunked/fused paths (async-backend honesty —
+        # see _advance_prefills); the logits feed the first-token
+        # sample on this same call path anyway
         jax.block_until_ready(last_logits)
-        dt = time.monotonic() - t0
+        dt, _ = self._window_close("prefill", (req,))
         keys = CostModel.chunk_keys(new, start)
         self._note_device_phase(
             "prefill", tokens=new, attended_keys=keys,
             weight_passes=1, kv_read_tokens=keys, dt=dt)
-        req.cp_add("prefill_dispatch", dt)
         self._finish_prefill(req, slot, plen, pre_cache, last_logits)
         return last_logits
 
@@ -3283,7 +3351,10 @@ class InferenceEngine:
                 req, "engine.decode",
                 req.finish_time - req.first_token_time,
                 slot=slot, tokens=req.n_generated,
-                finish_reason=req.finish_reason)
+                finish_reason=req.finish_reason,
+                # the issue parts of every window booked to the request,
+                # as /debug/requests shows them under dispatch_issue
+                issue_s=req.cp.get("dispatch_issue", 0.0))
         if self.paged is not None:
             hist = self.slot_hist[slot]
             if hist:
@@ -3473,13 +3544,15 @@ class InferenceEngine:
         # rejects grammar-forbidden drafts like argmax mismatches.
         # (_plan_block capped the block at 1 for constrained actives,
         # so m == 0 here whenever gmasks is not None.)
-        gmasks = self._grammar_spec_masks(active, tokens, k, drafts)
-        # multi-LoRA: the verify IS the target forward, so the adapter
-        # delta rides the spec twins; the drafts above stayed base-model
-        lora = self._lora_args()
-        kw = {} if lora is None else {"lora": lora}
+        with self.steptrace.scope("index_build"):
+            gmasks = self._grammar_spec_masks(active, tokens, k, drafts)
+            # multi-LoRA: the verify IS the target forward, so the
+            # adapter delta rides the spec twins; the drafts above
+            # stayed base-model
+            lora = self._lora_args()
+            kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
-            t0 = time.monotonic()
+            self.steptrace.window_begin("decode")
             if self.paged is not None:
                 W = self._paged_width(
                     max(int(self.slot_len[s]) for s in active)
@@ -3536,9 +3609,12 @@ class InferenceEngine:
                 out, n_acc, extra, self.cache = fn(
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(base), jnp.asarray(mask), m=m, **kw)
+            self.steptrace.window_issued()
             out_host = np.asarray(out)
             acc_host = np.asarray(n_acc)
             extra_host = np.asarray(extra)
+            dt, _ = self._window_close(
+                "decode", [self.slot_req[s] for s in active])
             # the verify is ONE wide forward over k+1 positions per slot
             # plus m single-token extension passes (that width
             # amortizing the weight read is the whole spec bet — the
@@ -3551,15 +3627,12 @@ class InferenceEngine:
             keys = sum(CostModel.block_keys(useful[s],
                                             int(self.slot_len[s]))
                        for s in active)
-            dt = time.monotonic() - t0
             self._note_device_phase(
                 "decode", tokens=sum(useful.values()),
                 attended_keys=keys, weight_passes=1 + m,
                 kv_read_tokens=keys, dt=dt)
         self.spec_rounds += 1
         with self.steptrace.scope("sample_commit"):
-            for s in active:
-                self.slot_req[s].cp_add("decode_dispatch", dt)
             for s in active:
                 n_acc_s = int(acc_host[s])
                 # metrics over real drafted positions only — zero
@@ -3596,7 +3669,7 @@ class InferenceEngine:
         self._constraint_commit(slot, cs, tok)
 
     def _update_active_stats(self) -> None:
-        with self.stats.lock:
+        with self.steptrace.scope("sample_commit"), self.stats.lock:
             self.stats.active_slots = sum(
                 r is not None for r in self.slot_req)
 
@@ -3813,33 +3886,33 @@ class InferenceEngine:
             tok, starts, lens = self._chunk_batch_rows(entries)
             advance = np.zeros((self.max_slots,), np.int32)
             advance[active] = n
-        # constrained decoding: the decode half of the fused step masks
-        # each grammar slot's logits (n == 1 then, by _plan_block);
-        # mid-prefill rows need nothing — their first token samples at
-        # finalization, where _activate applies the start-state mask
-        gmask = self._grammar_masks(active)
-        # multi-LoRA: slot-plane adapter rows cover BOTH halves of the
-        # fused program (prefill rows and decode rows are the same
-        # max_slots plane)
-        lora = self._lora_args()
-        kw = {} if lora is None else {"lora": lora}
-        # per-phase device accounting for the ONE fused dispatch: the
-        # wall time is split between prefill and decode in proportion
-        # to each half's FLOPs (token-count fallback without a cost
-        # model) — arxiv 2311.03687's phase dissection must survive the
-        # fusion that merged the phases into one program
-        pf_tokens = sum(len(c) for _, _, c in entries)
-        pf_keys = sum(CostModel.chunk_keys(len(c), st["done"])
-                      for _, st, c in entries)
-        dc_tokens = n * len(active)
-        dc_keys = sum(CostModel.block_keys(n, int(self.slot_len[s]))
-                      for s in active)
+            # constrained decoding: the decode half of the fused step masks
+            # each grammar slot's logits (n == 1 then, by _plan_block);
+            # mid-prefill rows need nothing — their first token samples at
+            # finalization, where _activate applies the start-state mask
+            gmask = self._grammar_masks(active)
+            # multi-LoRA: slot-plane adapter rows cover BOTH halves of the
+            # fused program (prefill rows and decode rows are the same
+            # max_slots plane)
+            lora = self._lora_args()
+            kw = {} if lora is None else {"lora": lora}
+            # per-phase device accounting for the ONE fused dispatch: the
+            # wall time is split between prefill and decode in proportion
+            # to each half's FLOPs (token-count fallback without a cost
+            # model) — arxiv 2311.03687's phase dissection must survive the
+            # fusion that merged the phases into one program
+            pf_tokens = sum(len(c) for _, _, c in entries)
+            pf_keys = sum(CostModel.chunk_keys(len(c), st["done"])
+                          for _, st, c in entries)
+            dc_tokens = n * len(active)
+            dc_keys = sum(CostModel.block_keys(n, int(self.slot_len[s]))
+                          for s in active)
         # one scope spans through the two note_device_phase calls below
         # (their dt shares must land inside it so the device deduction
         # balances) — and the dispatch calls themselves, so a raising
         # dispatch can't leak an open scope frame
         with self.steptrace.scope("dispatch_wait"):
-            t0 = time.monotonic()
+            self.steptrace.window_begin("mixed")
             self.rng, sub = jax.random.split(self.rng)
             if self.paged is not None:
                 # view must hold: each prefill row's chunk + the scan's
@@ -3927,13 +4000,19 @@ class InferenceEngine:
                     jnp.asarray(self._greedy),
                     n=n, **kw,
                 )
+            self.steptrace.window_issued()
             toks_host = np.asarray(toks)  # forces the dispatch's results
-            dt = time.monotonic() - t0
+            # the window advanced the mid-prefill rows' prompts; every
+            # decode member sat through the whole fused dispatch for
+            # them (prefill_stall, not decode_dispatch)
+            dt, issue_s = self._window_close(
+                "prefill", [st["req"] for _, st, _ in entries])
             self.mixed_blocks += 1
             for slot, st, chunk in entries:
                 st["last_logits"] = chunk_last[slot:slot + 1]
                 st["done"] += len(chunk)
-            self._trace_chunks(entries, dt, batched=True, fused=True)
+            self._trace_chunks(entries, dt, issue_s, batched=True,
+                               fused=True)
             cm = self.cost_model
             if cm is not None:
                 pf, df = (cm.step_flops(pf_tokens, pf_keys),
@@ -3949,10 +4028,6 @@ class InferenceEngine:
                 weight_passes=n, kv_read_tokens=dc_keys,
                 dt=dt * (1 - share))
         with self.steptrace.scope("sample_commit"):
-            # decode members waited the whole fused dispatch, like the
-            # prefill members booked in _trace_chunks
-            for s in active:
-                self.slot_req[s].cp_add("decode_dispatch", dt)
             self._finalize_prefills()
             self._commit_block(active, toks_host, n)
         return True
@@ -3973,13 +4048,14 @@ class InferenceEngine:
 
     def step(self) -> bool:
         """One engine iteration. Returns False when fully idle."""
+        t_lock = time.perf_counter()
         with self._lock:
             before = self.dispatch_meter.total
-            # the flight recorder brackets the WHOLE step; the timeline
-            # (per-segment intervals for the Perfetto dual-lane view)
-            # is only captured while a Chrome-JSONL sink is attached
+            # the flight recorder brackets the WHOLE step; what the
+            # thread waited for the lock (submitters hold it briefly)
+            # lies before the record and is a field of it
             self.steptrace.step_begin(
-                timeline=getattr(self.tracer, "has_file_sink", False))
+                lock_wait_s=time.perf_counter() - t_lock)
             busy = False
             try:
                 busy = self._step_locked()
@@ -3992,7 +4068,8 @@ class InferenceEngine:
                 # server and the metric stops meaning anything (the
                 # steptrace ring follows the same rule)
                 if busy or spent:
-                    self.dispatch_meter.note_step(spent)
+                    with self.steptrace.scope("sample_commit"):
+                        self.dispatch_meter.note_step(spent)
                     self.steptrace.step_end(self.tracer)
                 else:
                     self.steptrace.step_abort()
@@ -4001,7 +4078,6 @@ class InferenceEngine:
         with self.steptrace.scope("admit"):
             self._admit()
         budget = self.prefill_budget
-        active = self._ready_slots()
         # A speculative engine at decode_steps=1 keeps speculating
         # while prompts prefill (the r5 composition): its verify step
         # yields 1+accepted tokens per dispatch, strictly more than the
@@ -4019,6 +4095,7 @@ class InferenceEngine:
         # non-greedy traffic on a spec engine must not lose the fused
         # step too.
         with self.steptrace.scope("plan"):
+            active = self._ready_slots()
             spec_composes = (
                 (self.decode_steps == 1 or self.role == "decode")
                 and self._spec_applicable(active)
@@ -4050,7 +4127,8 @@ class InferenceEngine:
                 # step's decode block (sequential-path parity)
                 pre_progress = self._advance_prefills(budget - 1)
                 budget = 1
-                active = self._ready_slots()
+                with self.steptrace.scope("plan"):
+                    active = self._ready_slots()
             if self.slot_prefill and active:
                 with self.steptrace.scope("plan"):
                     n = self._plan_block(active)
@@ -4080,7 +4158,6 @@ class InferenceEngine:
                     # paged page reservation drained one half of the
                     # mixed sets: run this step's remainder on the
                     # sequential paths
-                    active = self._ready_slots()
                 else:
                     # log each fallback KIND once (the detail after ':'
                     # varies per occurrence; keying the dedup on it
@@ -4092,13 +4169,16 @@ class InferenceEngine:
                             "fused mixed step fell back to sequential "
                             "dispatches: %s", why)
         progressed = self._advance_prefills(budget) or pre_progress
-        active = self._ready_slots()
+        with self.steptrace.scope("plan"):
+            active = self._ready_slots()
         if not active:
             return progressed or bool(self.slot_prefill)
         if self._try_speculative(active):
             self._update_active_stats()
             return True
-        self.rng, sub = jax.random.split(self.rng)
+        with self.steptrace.scope("index_build"):
+            # the step's sampling key: a small eager device program
+            self.rng, sub = jax.random.split(self.rng)
         with self.steptrace.scope("plan"):
             n = self._plan_block(active)
             use_multi = (
@@ -4117,10 +4197,11 @@ class InferenceEngine:
                     active = self._paged_reserve_active(active, n)
                 if not active:
                     return True  # reservation finished/preempted them all
-            lora = self._lora_args()
-            kw = {} if lora is None else {"lora": lora}
+            with self.steptrace.scope("index_build"):
+                lora = self._lora_args()
+                kw = {} if lora is None else {"lora": lora}
             with self.steptrace.scope("dispatch_wait"):
-                t0 = time.monotonic()
+                self.steptrace.window_begin("decode")
                 if self.paged is not None:
                     toks = self._paged_decode_dispatch(active, n, sub,
                                                        lora=lora)
@@ -4137,16 +4218,16 @@ class InferenceEngine:
                         jnp.asarray(self._greedy),
                         n=n, **kw,
                     )
+                self.steptrace.window_issued()
                 toks_host = np.asarray(toks)
+                dt, _ = self._window_close(
+                    "decode", [self.slot_req[s] for s in active])
                 keys = sum(CostModel.block_keys(n, int(self.slot_len[s]))
                            for s in active)
-                dt = time.monotonic() - t0
                 self._note_device_phase(
                     "decode", tokens=n * len(active), attended_keys=keys,
                     weight_passes=n, kv_read_tokens=keys, dt=dt)
             with self.steptrace.scope("sample_commit"):
-                for s in active:
-                    self.slot_req[s].cp_add("decode_dispatch", dt)
                 self._commit_block(active, toks_host, n)
             self._update_active_stats()
             return True
@@ -4157,11 +4238,12 @@ class InferenceEngine:
                 return True
         # constrained decoding: per-slot grammar mask rows, applied by
         # the masked twin program in the SAME single dispatch
-        gmask = self._grammar_masks(active)
-        lora = self._lora_args()
-        kw = {} if lora is None else {"lora": lora}
+        with self.steptrace.scope("index_build"):
+            gmask = self._grammar_masks(active)
+            lora = self._lora_args()
+            kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
-            t0 = time.monotonic()
+            self.steptrace.window_begin("decode")
             if self.paged is not None:
                 next_tok = self._paged_decode_dispatch(active, 1, sub,
                                                        gmask=gmask,
@@ -4192,16 +4274,16 @@ class InferenceEngine:
                     jnp.asarray(self._greedy),
                     **kw,
                 )
+            self.steptrace.window_issued()
             next_host = np.asarray(next_tok)
+            dt, _ = self._window_close(
+                "decode", [self.slot_req[s] for s in active])
             keys = sum(CostModel.block_keys(1, int(self.slot_len[s]))
                        for s in active)
-            dt = time.monotonic() - t0
             self._note_device_phase(
                 "decode", tokens=len(active), attended_keys=keys,
                 weight_passes=1, kv_read_tokens=keys, dt=dt)
         with self.steptrace.scope("sample_commit"):
-            for s in active:
-                self.slot_req[s].cp_add("decode_dispatch", dt)
             for slot in active:
                 self._commit_token(slot, int(next_host[slot]))
         self._update_active_stats()

@@ -4,16 +4,21 @@ critical-path plane (ISSUE 11).
 Pins:
 
 - recorder mechanics: ring bound, scope nesting/pause semantics, device
-  deduction, snapshot consistency, kill switch;
+  deduction, snapshot consistency, kill switch; the dispatch window's
+  two parts (issue + wait = device), segments on the step's clock,
+  TraceAnnotations on the same edges (ISSUE 27);
 - live engine integration: activity sums ≈ step wall (the partition
   invariant), coverage >= 0.95 on contiguous AND paged paths, the
   /metrics families strict-parse with live values;
 - per-request critical path: /debug/requests breakdown sums ≈ request
-  wall, warm-vs-cold TTFT labels from the admission outcome;
+  wall, warm-vs-cold TTFT labels from the admission outcome; every
+  window booked to every slot holder (prefill_stall /
+  decode_interleave), overlays outside the residual, the front end's
+  api_* overlays (ISSUE 27);
 - golden-token parity with the recorder OFF (LLM_TPU_STEPTRACE=off),
   and an overhead smoke (recorder primitives bounded + TPOT A/B);
 - the kv-pool's kvpool_handoff_wire_seconds server-side cross-check;
-- the Perfetto dual-lane export (host + device lane events);
+- the Perfetto dual-lane export (host + dispatch-window lane events);
 - the checked-in BENCH_HOST_GAP artifact's coverage gate.
 """
 
@@ -28,13 +33,19 @@ import jax.numpy as jnp
 import pytest
 
 from llm_in_practise_tpu.models.gpt import GPT, GPTConfig
+from llm_in_practise_tpu.obs import steptrace
 from llm_in_practise_tpu.obs.steptrace import (
     ACTIVITIES,
-    DEVICE_LANE_TID,
     HOST_LANE_TID,
+    WINDOW_LANE_TID,
     StepTrace,
 )
-from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
+from llm_in_practise_tpu.serve.engine import (
+    CP_OVERLAYS,
+    InferenceEngine,
+    Request,
+    SamplingParams,
+)
 from tests.promparse import parse_exposition
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -50,13 +61,28 @@ def model_params():
     return model, params
 
 
+_ENGINES: list = []
+
+
 def _engine(model, params, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("cache_len", 192)
     kw.setdefault("cache_dtype", jnp.float32)
     kw.setdefault("chunked_prefill", 8)
     kw.setdefault("decode_steps", 4)
-    return InferenceEngine(model, params, **kw)
+    eng = InferenceEngine(model, params, **kw)
+    _ENGINES.append(eng)
+    return eng
+
+
+@pytest.fixture(autouse=True)
+def _stop_engines():
+    """An engine returns its HBM-ledger bytes only in ``stop()``; one
+    left running would leak them into whatever test file shares this
+    worker (``test_hbm_ledger`` reads the process-wide accounts)."""
+    yield
+    while _ENGINES:
+        _ENGINES.pop().stop()
 
 
 SHORT = ([3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8])
@@ -126,6 +152,167 @@ def test_snapshot_has_every_activity_from_birth():
     assert set(st.snapshot()["host_seconds"]) == set(ACTIVITIES)
 
 
+class _Clock:
+    """A clock the test moves by hand: ``perf_counter`` and ``time``
+    advance together, ``time`` from another origin."""
+
+    def __init__(self, perf: float = 100.0, wall: float = 5000.0):
+        self.perf, self.offset = perf, wall - perf
+
+    def tick(self, dt: float) -> None:
+        self.perf += dt
+
+    def perf_counter(self) -> float:
+        return self.perf
+
+    def time(self) -> float:
+        return self.perf + self.offset
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = _Clock()
+    monkeypatch.setattr(steptrace, "time", c)
+    return c
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: the activity
+    starts at construction and ends at ``__exit__``."""
+
+    def __init__(self):
+        self.opened, self.open_now = [], []
+
+    def __call__(self, name):
+        self.opened.append(name)
+        self.open_now.append(name)
+        outer = self
+
+        class _A:
+            def __exit__(self, *exc):
+                outer.open_now.remove(name)
+
+        return _A()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    a = _Annotations()
+    monkeypatch.setattr(steptrace, "TraceAnnotation", a)
+    return a
+
+
+def test_window_parts_sum_to_device(clock):
+    """issue_s + wait_s == device_s, each where the clock put it; the
+    window is deducted from the scope it sits in, so the partition of
+    wall_s stands."""
+    st = StepTrace(enabled=True)
+    st.step_begin(lock_wait_s=0.25)
+    with st.scope("dispatch_wait"):
+        clock.tick(0.001)                 # scope, before the window
+        st.window_begin("decode")
+        clock.tick(0.004)                 # issue
+        st.window_issued()
+        clock.tick(0.040)                 # wait
+        assert st.window_end() == pytest.approx((0.044, 0.004))
+        clock.tick(0.002)                 # scope, after the window
+    with st.scope("sample_commit"):
+        clock.tick(0.003)
+    clock.tick(0.0005)                    # unscoped: other
+    rec = st.step_end()
+    assert rec["issue_s"] == pytest.approx(0.004)
+    assert rec["wait_s"] == pytest.approx(0.040)
+    assert rec["issue_s"] + rec["wait_s"] == pytest.approx(rec["device_s"])
+    assert rec["wall_s"] == pytest.approx(0.0505)
+    assert rec["lock_wait_s"] == 0.25
+    acts = rec["activities"]
+    assert acts["dispatch_wait"] == pytest.approx(0.003)
+    assert acts["sample_commit"] == pytest.approx(0.003)
+    assert acts["other"] == pytest.approx(0.0005)
+    assert sum(acts.values()) + rec["device_s"] == pytest.approx(rec["wall_s"])
+    snap = st.snapshot()
+    assert snap["dispatch_issue_seconds_total"] == pytest.approx(0.004)
+    assert snap["dispatch_wait_seconds_total"] == pytest.approx(0.040)
+    # note_device without an issue part: the whole window is wait
+    st.step_begin()
+    clock.tick(0.01)
+    st.note_device(0.01)
+    rec = st.step_end()
+    assert (rec["issue_s"], rec["wait_s"]) == pytest.approx((0.0, 0.01))
+
+
+def test_segments_on_the_steps_clock_and_nested(clock):
+    """Every segment lies inside its step on start_s's axis; an inner
+    scope lies inside the outer one; a window is an issue and a wait
+    segment that meet; gap_before_s is the previous step's end to this
+    step's begin."""
+    st = StepTrace(enabled=True)
+    st.step_begin()
+    clock.tick(0.01)
+    st.step_end()
+    clock.tick(0.5)                       # between steps
+    st.step_begin()
+    with st.scope("admit"):
+        clock.tick(0.001)
+        with st.scope("index_build"):
+            clock.tick(0.002)
+        clock.tick(0.001)
+    with st.scope("dispatch_wait"):
+        st.window_begin("prefill")
+        clock.tick(0.003)
+        st.window_issued()
+        clock.tick(0.02)
+        st.window_end()
+    rec = st.step_end()
+    assert rec["gap_before_s"] == pytest.approx(0.5)
+    assert rec["start_s"] == pytest.approx(5000.51)
+    segs = {name: (t0, t1) for name, t0, t1 in rec["segments"]}
+    assert set(segs) == {"admit", "index_build", "dispatch_wait",
+                         "issue:prefill", "wait:prefill"}
+    lo, hi = rec["start_s"], rec["start_s"] + rec["wall_s"]
+    for t0, t1 in segs.values():
+        assert lo - 1e-9 <= t0 <= t1 <= hi + 1e-9
+    assert segs["admit"][0] <= segs["index_build"][0]
+    assert segs["index_build"][1] <= segs["admit"][1]
+    assert segs["index_build"] == pytest.approx((5000.511, 5000.513))
+    assert segs["issue:prefill"][1] == segs["wait:prefill"][0]
+    assert segs["wait:prefill"][1] - segs["issue:prefill"][0] \
+        == pytest.approx(rec["device_s"])
+
+
+def test_annotations_follow_the_edges(clock, annotations):
+    """Step, scopes and the window's two parts open and close
+    TraceAnnotations; a disabled recorder opens none."""
+    st = StepTrace(enabled=True)
+    st.step_begin()
+    with st.scope("plan"):
+        pass
+    with st.scope("dispatch_wait"):
+        st.window_begin("decode")
+        assert annotations.open_now[-1] == "engine:issue:decode"
+        st.window_issued()
+        assert annotations.open_now[-1] == "engine:wait:decode"
+        st.window_end()
+    st.step_end()
+    assert annotations.opened == [
+        "engine_step", "engine:plan", "engine:dispatch_wait",
+        "engine:issue:decode", "engine:wait:decode"]
+    assert annotations.open_now == []
+    # an aborted (idle) step closes its annotation too
+    st.step_begin()
+    st.step_abort()
+    assert annotations.open_now == []
+    n = len(annotations.opened)
+    off = StepTrace(enabled=False)
+    off.step_begin()
+    with off.scope("plan"):
+        off.window_begin("decode")
+        off.window_issued()
+        dt, issue = off.window_end()      # still timed for the engine
+    off.step_end()
+    assert len(annotations.opened) == n and dt >= issue >= 0.0
+
+
 # --- live engine integration -------------------------------------------------
 
 
@@ -141,7 +328,21 @@ def test_activity_sums_match_step_wall(model_params, kv_layout):
     for rec in recs:
         total = sum(rec["activities"].values()) + rec["device_s"]
         assert total == pytest.approx(rec["wall_s"], rel=1e-6, abs=1e-6)
+        # the windows' two parts, and every segment inside its step
+        assert rec["issue_s"] + rec["wait_s"] == pytest.approx(
+            rec["device_s"], abs=1e-9)
+        assert rec["issue_s"] >= 0 and rec["wait_s"] >= 0
+        assert rec["lock_wait_s"] >= 0 and rec["gap_before_s"] >= 0
+        lo, hi = rec["start_s"], rec["start_s"] + rec["wall_s"]
+        for _, t0, t1 in rec["segments"]:
+            assert lo - 1e-6 <= t0 <= t1 <= hi + 1e-6
+        if rec["dispatches"]:
+            parts = {name.split(":")[0] for name, _, _ in rec["segments"]}
+            assert {"issue", "wait"} <= parts
     snap = eng.steptrace.snapshot()
+    assert snap["dispatch_issue_seconds_total"] \
+        + snap["dispatch_wait_seconds_total"] \
+        == pytest.approx(snap["device_seconds_total"])
     assert snap["coverage"] >= 0.95
     assert 0.0 <= snap["host_gap_fraction"] <= 1.0
     assert snap["device_busy_fraction"] + snap["host_gap_fraction"] \
@@ -191,6 +392,13 @@ def test_metrics_families_strict_parse_live(model_params):
     assert next(iter(wall.samples.values())) > 0
     steps = fams["llm_engine_steps_total"]
     assert next(iter(steps.samples.values())) > 0
+    issue = next(iter(
+        fams["llm_dispatch_issue_seconds_total"].samples.values()))
+    wait = next(iter(
+        fams["llm_dispatch_wait_seconds_total"].samples.values()))
+    assert issue > 0 and wait > 0
+    assert issue + wait == pytest.approx(
+        eng.steptrace.snapshot()["device_seconds_total"])
     frac = fams["llm_host_gap_fraction"]
     busy = fams["llm_device_busy_fraction"]
     fv = next(iter(frac.samples.values()))
@@ -230,29 +438,25 @@ def test_ttft_cache_labels_hit_and_cold(model_params):
     assert stats.ttft_by_cache["hit"].count >= 1
 
 
-def test_debug_requests_breakdown_sums_to_wall(model_params):
-    """HTTP GET /debug/requests: every finished request's engine
-    segments (incl. the derived host_gap residual) partition its wall
-    clock; stream_flush is excluded (API-side, concurrent)."""
+class _ByteTok:
+    def encode(self, t):
+        return [b % 64 for b in t.encode()][:32]
+
+    def decode(self, ids):
+        return " ".join(map(str, ids))
+
+
+def _chat_then_debug_requests(eng, *, stream: bool) -> dict:
+    """One chat completion over HTTP, then GET /debug/requests."""
     from llm_in_practise_tpu.serve.api import OpenAIServer
 
-    model, params = model_params
-    eng = _engine(model, params)
-
-    class _Tok:
-        def encode(self, t):
-            return [b % 64 for b in t.encode()][:32]
-
-        def decode(self, ids):
-            return " ".join(map(str, ids))
-
-    srv = OpenAIServer(eng, _Tok(), model_name="steptrace-test")
+    srv = OpenAIServer(eng, _ByteTok(), model_name="steptrace-test")
     port = srv.serve(host="127.0.0.1", port=0, background=True)
     try:
         body = json.dumps({
             "model": "steptrace-test",
             "messages": [{"role": "user", "content": "hello host gap"}],
-            "max_tokens": 12, "temperature": 0.0, "stream": True,
+            "max_tokens": 12, "temperature": 0.0, "stream": stream,
         }).encode()
         req = urllib.request.Request(
             f"http://127.0.0.1:{port}/v1/chat/completions", data=body,
@@ -262,15 +466,24 @@ def test_debug_requests_breakdown_sums_to_wall(model_params):
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{port}/debug/requests",
                 timeout=30) as resp:
-            payload = json.loads(resp.read().decode())
+            return json.loads(resp.read().decode())
     finally:
         srv.shutdown()
+
+
+def test_debug_requests_breakdown_sums_to_wall(model_params):
+    """HTTP GET /debug/requests: every finished request's engine
+    segments (incl. the derived host_gap residual) partition its wall
+    clock; the overlays are excluded (concurrent with, inside, or
+    before the partitioned time)."""
+    model, params = model_params
+    payload = _chat_then_debug_requests(_engine(model, params), stream=True)
     assert payload["capacity"] == 128
     assert payload["finished"], "the finished ring must hold the request"
     for rec in payload["finished"]:
         segs = rec["segments"]
         engine_sum = sum(v for k, v in segs.items()
-                        if k != "stream_flush")
+                        if k not in CP_OVERLAYS)
         assert engine_sum == pytest.approx(rec["wall_s"], abs=2e-3)
         assert all(v >= 0 for v in segs.values())
         assert rec["cache"] in ("hit", "partial", "cold")
@@ -282,18 +495,112 @@ def test_debug_requests_breakdown_sums_to_wall(model_params):
     assert agg["stream_flush"] >= 0
 
 
-def test_recorder_off_golden_parity(model_params, monkeypatch):
-    """LLM_TPU_STEPTRACE=off: zero records, identical greedy tokens."""
+@pytest.mark.parametrize("stream", [True, False])
+def test_http_request_carries_api_overlays(model_params, stream):
+    """The handler's two instants become overlays at the finish funnel:
+    a streamed request has both, a non-stream one api_pre_submit only;
+    the dispatch_issue overlay is within the windows it is part of."""
+    model, params = model_params
+    payload = _chat_then_debug_requests(_engine(model, params),
+                                        stream=stream)
+    (rec,) = payload["finished"]
+    segs = rec["segments"]
+    assert segs["api_pre_submit"] > 0
+    assert ("api_first_flush" in segs) == stream
+    if stream:
+        assert segs["api_first_flush"] >= 0
+    windows = sum(segs.get(k, 0.0) for k in (
+        "prefill_dispatch", "decode_dispatch", "prefill_stall",
+        "decode_interleave"))
+    assert 0 < segs["dispatch_issue"] <= windows + 1e-6
+    assert set(segs) <= set(payload["segments"])
+
+
+@pytest.mark.parametrize("mixed_step", [True, False],
+                         ids=["fused", "no-mixed-step"])
+def test_every_window_booked_to_every_slot_holder(model_params, mixed_step):
+    """One long prompt admitted while two requests decode: the decoding
+    requests book the windows that advanced its prompt as prefill_stall
+    (the fused mixed step included: not decode_dispatch), the prefilling
+    one books plain decode windows between its chunks as
+    decode_interleave, and host_gap is what is left: segments + residual
+    = wall for all three."""
+    model, params = model_params
+    eng = _engine(model, params, mixed_step=mixed_step)
+    sp = SamplingParams(greedy=True, max_tokens=24)
+    short = [eng.submit(p, sp) for p in SHORT]
+    eng.step()
+    long = eng.submit(LONG, SamplingParams(greedy=True, max_tokens=8))
+    while eng.step():
+        pass
+    for r in (*short, long):
+        r.result()
+        wall = r.finish_time - r.submit_time
+        parts = sum(v for k, v in r.cp.items() if k not in CP_OVERLAYS)
+        assert parts == pytest.approx(wall, abs=1e-6)
+        assert r.cp["dispatch_issue"] > 0
+    for r in short:
+        # five chunks of the long prompt, each a window they sat through
+        assert r.cp["prefill_stall"] > 0
+        assert r.cp["decode_dispatch"] > 0
+        assert "decode_interleave" not in r.cp
+    assert long.cp["prefill_dispatch"] > 0
+    assert long.cp.get("decode_interleave", 0.0) >= 0
+    if not mixed_step:
+        # sequential: a decode window follows every chunk window
+        assert long.cp["decode_interleave"] > 0
+    assert long.cp["decode_dispatch"] > 0
+    if mixed_step:
+        assert eng.mixed_blocks > 0
+    # the one-shot prefill of the two short prompts was ONE window both
+    # waited for: own prompt, so prefill_dispatch for both
+    assert all(r.cp["prefill_dispatch"] > 0 for r in short)
+
+
+def test_overlays_never_enter_the_residual(model_params):
+    """host_gap = wall − Σ non-overlay segments, whatever the overlays
+    hold; the api_* overlays come from the handler's instants."""
+    model, params = model_params
+    eng = _engine(model, params)
+    req = Request(uid=10_000, prompt_ids=[1, 2, 3],
+                  params=SamplingParams(), submit_time=100.0)
+    req.first_token_time, req.finish_time = 100.5, 102.0
+    req.api_body_time, req.api_first_flush_time = 99.75, 100.625
+    req.cp.update(queue_wait=0.25, prefill_dispatch=0.25,
+                  decode_dispatch=1.0, prefill_stall=0.125,
+                  stream_flush=50.0, dispatch_issue=60.0)
+    eng._record_finished(req)
+    assert req.cp["host_gap"] == pytest.approx(0.375)
+    assert req.cp["api_pre_submit"] == pytest.approx(0.25)
+    assert req.cp["api_first_flush"] == pytest.approx(0.125)
+    assert CP_OVERLAYS == {"stream_flush", "dispatch_issue",
+                           "api_pre_submit", "api_first_flush"}
+
+
+def test_recorder_off_golden_parity(model_params, monkeypatch,
+                                    annotations):
+    """LLM_TPU_STEPTRACE=off: zero records, no annotation, identical
+    greedy tokens — and the requests' critical paths are still booked
+    (the windows are timed either way)."""
     model, params = model_params
     on = _engine(model, params)
     out_on = _run_mixed_load(on)
+    live = set(annotations.opened)
+    assert {"engine_step", "engine:admit", "engine:sample_commit",
+            "engine:issue:prefill", "engine:wait:prefill",
+            "engine:issue:decode", "engine:wait:decode"} <= live
+    assert annotations.open_now == []
+    n = len(annotations.opened)
     monkeypatch.setenv("LLM_TPU_STEPTRACE", "off")
     off = _engine(model, params)
     out_off = _run_mixed_load(off)
     assert not off.steptrace.enabled
     assert len(off.steptrace) == 0
     assert off.steptrace.snapshot()["steps"] == 0
+    assert len(annotations.opened) == n
     assert out_on == out_off
+    for r in off.finished:
+        assert r.cp["decode_dispatch"] > 0 and r.cp["dispatch_issue"] > 0
 
 
 def test_recorder_overhead_bounded(model_params, monkeypatch):
@@ -387,8 +694,7 @@ def test_perfetto_dual_lane(model_params, tmp_path):
     eng = _engine(model, params, tracer=tracer)
     _run_mixed_load(eng)
     tracer.set_trace_file(None)
-    tids = {"host": 0, "device": 0}
-    names = set()
+    lanes = {HOST_LANE_TID: [], WINDOW_LANE_TID: []}
     meta = set()
     with open(path, encoding="utf-8") as f:
         for line in f:
@@ -397,14 +703,26 @@ def test_perfetto_dual_lane(model_params, tmp_path):
                 meta.add(ev["args"]["name"])
             if ev.get("cat") != "steptrace" or ev.get("ph") != "X":
                 continue
-            if ev["tid"] == HOST_LANE_TID:
-                tids["host"] += 1
-                names.add(ev["name"])
-            elif ev["tid"] == DEVICE_LANE_TID:
-                tids["device"] += 1
-    assert tids["host"] > 0 and tids["device"] > 0
-    assert {"engine host lane", "device lane"} <= meta
-    assert "admit" in names and "dispatch_wait" in names
+            lanes[ev["tid"]].append(ev)
+    assert {"engine host lane",
+            "dispatch window lane (host clock)"} <= meta
+    assert not any("device" in m for m in meta)
+    host = {ev["name"] for ev in lanes[HOST_LANE_TID]}
+    assert "admit" in host and "dispatch_wait" in host
+    assert not any(n.startswith(("issue:", "wait:")) for n in host)
+    # each window is an issue slice and the wait slice that follows it
+    windows = sorted(lanes[WINDOW_LANE_TID], key=lambda ev: ev["ts"])
+    assert {ev["name"] for ev in windows} >= {
+        "issue:prefill", "wait:prefill", "issue:decode", "wait:decode"}
+    assert len(windows) % 2 == 0
+    for a, b in zip(windows[::2], windows[1::2]):
+        assert a["name"].startswith("issue:")
+        assert b["name"] == "wait:" + a["name"][len("issue:"):]
+        assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1.0)
+    # the same segments the records carry
+    recs = eng.steptrace.records()
+    assert sum(len(r["segments"]) for r in recs) \
+        == len(windows) + len(lanes[HOST_LANE_TID])
 
 
 # --- bench artifact + smoke --------------------------------------------------
@@ -426,7 +744,7 @@ def test_bench_host_gap_artifact_coverage():
         assert set(block["host_seconds"]) == set(ACTIVITIES)
         assert 0.0 <= leg["live_host_gap_fraction"] <= 1.0
         assert leg["perfetto"]["host_events"] > 0
-        assert leg["perfetto"]["device_events"] > 0
+        assert leg["perfetto"]["window_events"] > 0
     spec_leg = next(leg for leg in artifact["legs"]
                     if leg["leg"] == "paged_spec")
     assert spec_leg["spec_rounds"] > 0

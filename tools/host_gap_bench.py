@@ -15,7 +15,7 @@ drive toward zero. Each leg:
   wall time (``tests/test_steptrace.py`` re-asserts the artifact),
 - scrapes ``llm_host_gap_fraction`` LIVE from ``/metrics`` over HTTP,
 - writes a Perfetto dual-lane Chrome-JSONL file and verifies BOTH lanes
-  (engine host lane + device lane) carry events.
+  (engine host lane + dispatch window lane) carry events.
 
 Run: ``JAX_PLATFORMS=cpu python tools/host_gap_bench.py``
 Writes ``BENCH_HOST_GAP_r09.json`` at the repo root. The tier-1 smoke
@@ -104,11 +104,11 @@ def _drive(engine, *, concurrency: int, n_requests: int,
 
 def _perfetto_lanes(path: str) -> dict:
     from llm_in_practise_tpu.obs.steptrace import (
-        DEVICE_LANE_TID,
         HOST_LANE_TID,
+        WINDOW_LANE_TID,
     )
 
-    host = device = 0
+    host = window = 0
     with open(path, encoding="utf-8") as f:
         for line in f:
             try:
@@ -119,9 +119,9 @@ def _perfetto_lanes(path: str) -> dict:
                 continue
             if ev.get("tid") == HOST_LANE_TID:
                 host += 1
-            elif ev.get("tid") == DEVICE_LANE_TID:
-                device += 1
-    return {"host_events": host, "device_events": device}
+            elif ev.get("tid") == WINDOW_LANE_TID:
+                window += 1
+    return {"host_events": host, "window_events": window}
 
 
 def run_leg(name: str, *, kv_layout: str, spec: bool, workdir: str,
@@ -172,7 +172,7 @@ def run_leg(name: str, *, kv_layout: str, spec: bool, workdir: str,
         srv.shutdown()
     tracer.set_trace_file(None)   # flush + close the JSONL sink
     lanes = _perfetto_lanes(trace_path)
-    if not (lanes["host_events"] and lanes["device_events"]):
+    if not (lanes["host_events"] and lanes["window_events"]):
         raise SystemExit(
             f"leg {name}: Perfetto file {trace_path} is missing a lane "
             f"({lanes})")
