@@ -176,6 +176,8 @@ class StepTrace:
         self._segments: list[tuple] = []   # (name, t0, t1) on perf_counter
         self._lock_wait_s = 0.0
         self._gap_before_s = 0.0
+        self._chunk_rows = 0
+        self._chunk_row_slots = 0
         self._last_end: float | None = None   # previous step_end, perf
         # the open dispatch window (``_win_t0`` None: none open); the
         # annotation is that of the part under way, issue then wait
@@ -289,6 +291,14 @@ class StepTrace:
         self._segment("issue:" + phase, t0, issued)
         self._segment("wait:" + phase, issued, t1)
 
+    def note_chunk_rows(self, rows: int, row_slots: int) -> None:
+        """A chunk dispatch of this step advanced ``rows`` prompts and
+        computed ``row_slots`` rows for them (the contiguous
+        slot plane's idle rows included): the record's ``chunk_rows`` / ``chunk_row_slots``."""
+        if self._recording:
+            self._chunk_rows += rows
+            self._chunk_row_slots += row_slots
+
     def step_begin(self, *, lock_wait_s: float = 0.0) -> None:
         """Open a step record. ``lock_wait_s``: what the caller waited
         for the engine's step lock before this call (a record field, not
@@ -302,6 +312,8 @@ class StepTrace:
         self._lock_wait_s = float(lock_wait_s)
         self._gap_before_s = (self._step_t0 - self._last_end
                               if self._last_end is not None else 0.0)
+        self._chunk_rows = 0
+        self._chunk_row_slots = 0
         self._acts = {}
         self._device_s = 0.0
         self._issue_s = 0.0
@@ -354,6 +366,8 @@ class StepTrace:
             "lock_wait_s": self._lock_wait_s,
             "gap_before_s": self._gap_before_s,
             "dispatches": self._dispatches,
+            "chunk_rows": self._chunk_rows,
+            "chunk_row_slots": self._chunk_row_slots,
             "activities": dict(self._acts),
             "segments": [(name, t0 + off, t1 + off)
                          for name, t0, t1 in self._segments],

@@ -728,6 +728,13 @@ class OpenAIServer:
         reg.counter_func("llm_mixed_blocks_total",
                          lambda: eng.mixed_blocks,
                          "fused prefill+decode dispatches")
+        reg.counter_func("llm_prefill_chunk_rows_total",
+                         lambda: eng.prefill_chunk_rows,
+                         "prompt rows that advanced one prefill chunk")
+        reg.counter_func("llm_prefill_chunk_row_slots_total",
+                         lambda: eng.prefill_chunk_row_slots,
+                         "rows the device computed for those chunks "
+                         "(the contiguous slot plane's idle rows included)")
         # device plane (obs/cost.py + DispatchMeter.note_phase): live
         # per-phase MFU / HBM-bandwidth-utilization / tokens-per-
         # dispatch — the compute-vs-bandwidth-bound dial. Phases appear

@@ -63,6 +63,7 @@ from llm_in_practise_tpu.serve.mixed_step import (
     plan_spec_extension,
     spec_verify_block,
 )
+from llm_in_practise_tpu.serve.multi_lora import current_lora, lora_context
 
 
 @dataclasses.dataclass(frozen=True)
@@ -768,6 +769,10 @@ class InferenceEngine:
         # serve/mixed_step.py and docs/perf.md Finding 17).
         self.mixed_step = bool(mixed_step)
         self.mixed_blocks = 0
+        # chunk dispatches: prompts that advanced a chunk, and the rows
+        # the device computed for them (_note_chunk_rows)
+        self.prefill_chunk_rows = 0
+        self.prefill_chunk_row_slots = 0
         self._log = get_logger("serve.engine")
         # request tracing (obs/trace.py): spans parent to each request's
         # TraceContext; the process default keeps a single-process stack
@@ -967,6 +972,7 @@ class InferenceEngine:
             self._pg_spec = _c(jax.jit(self._paged_spec_fn,
                                        donate_argnums=(1,),
                                        static_argnames=("m",)))
+            self._chunk_last_like = None   # _paged_chunk_fn
             self._pg_chunk = _c(jax.jit(self._paged_chunk_fn,
                                         donate_argnums=(1,)))
             self._pg_mixed = _c(jax.jit(self._paged_mixed_fn,
@@ -1550,22 +1556,75 @@ class InferenceEngine:
         return out, n_acc, extra, self._paged_writeback(
             pool, view, sidx, index_vec)
 
-    def _paged_chunk_fn(self, params, pool, gidx, chunk_ids, starts,
-                        lens, sidx):
-        view = self._paged_view(pool, gidx, starts)
-        last, view = batched_chunk(self.model, params, view, chunk_ids,
-                                   starts, lens)
-        return last, self._paged_writeback(pool, view, sidx, starts)
+    def _paged_chunk_fn(self, params, pool, slots, gidx, chunk_ids,
+                        starts, lens, sidx, n_rows):
+        """Advance the listed mid-prefill ROWS one chunk each against
+        the page pool: the device work follows the number of rows that
+        chunk, not ``max_slots``.
 
-    def _paged_mixed_fn(self, params, pool, gidx, chunk_ids, starts,
-                        lens, advance, tokens, rng, temperature, top_k,
-                        top_p, greedy, sidx, *, n):
-        view = self._paged_view(pool, gidx, starts)
-        chunk_last, toks, view = self._mixed_raw(
-            params, view, chunk_ids, starts, lens, advance, tokens,
-            rng, temperature, top_k, top_p, greedy, n=n)
+        ``slots`` (R,) names each row's slot, ``gidx`` (R, W) / ``sidx``
+        (R, C) are its pool-row gather and window-scatter indices,
+        ``chunk_ids`` (R, C), ``starts`` / ``lens`` (R,) as in
+        :func:`batched_chunk`. R is fixed (``max_slots``, or 1 for a
+        suffix) whatever the number of rows that chunk, so the compile
+        key holds no row count: a loop with the TRACED trip count
+        ``n_rows`` takes the first ``n_rows`` rows one a trip — gather
+        the row's pages, run the shared ``batched_chunk`` body on that
+        one-row view, scatter its chunk window back — and never visits
+        the padding behind them. One row a trip because a chip timing
+        found it the fastest per row at every row count (PERF.md, PR
+        28). Returns ``((max_slots, vocab) last-position logits by
+        slot, pool)``."""
+        lora = current_lora()
+
+        def forward(pool, i):
+            slot, r_gidx, r_ids, r_starts, r_lens, r_sidx = (
+                jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)
+                for a in (slots, gidx, chunk_ids, starts, lens, sidx))
+            view = self._paged_view(pool, r_gidx, r_starts)
+            # the adapter index rides the SLOT plane: this row's entry
+            mine = None if lora is None else dict(lora, idx={
+                rb: jnp.take(ix, slot) for rb, ix in lora["idx"].items()})
+            with lora_context(mine):
+                last, view = batched_chunk(self.model, params, view,
+                                           r_ids, r_starts, r_lens)
+            return (self._paged_writeback(pool, view, r_sidx, r_starts),
+                    slot[0], last)
+
+        def trip(i, carry):
+            pool, out = carry
+            pool, slot, last = forward(pool, i)
+            return pool, jax.lax.dynamic_update_slice_in_dim(
+                out, last, slot, axis=0)
+
+        # a row's logits shape, by one abstract trace a process (it
+        # depends on neither the view width nor the adapters)
+        if self._chunk_last_like is None:
+            self._chunk_last_like = jax.eval_shape(
+                lambda p: forward(p, 0)[2], pool)
+        like = self._chunk_last_like
+        out = jnp.zeros((self.max_slots,) + like.shape[1:], like.dtype)
+        pool, out = jax.lax.fori_loop(0, n_rows, trip, (pool, out))
+        return out, pool
+
+    def _paged_mixed_fn(self, params, pool, slots, pgidx, chunk_ids,
+                        starts, lens, psidx, n_rows, gidx, index_vec,
+                        sidx, tokens, rng, temperature, top_k, top_p,
+                        greedy, *, n, gmask=None):
+        """The paged fused mixed step, ONE dispatch: the prefill half
+        is :meth:`_paged_chunk_fn`'s loop over the rows that chunk; the
+        decode half is :meth:`_paged_multi_fn`'s body over the slot
+        plane (mid-prefill and idle rows decode garbage into the trash
+        page). No decode row receives a chunk write."""
+        chunk_last, pool = self._paged_chunk_fn(
+            params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
+            n_rows)
+        view = self._paged_view(pool, gidx, index_vec)
+        toks, view = decode_scan(self.model, params, view, tokens, rng,
+                                 temperature, top_k, top_p, greedy, n=n,
+                                 gmask=gmask)
         return chunk_last, toks, self._paged_writeback(
-            pool, view, sidx, starts)
+            pool, view, sidx, index_vec)
 
     def _paged_decode_masked_fn(self, params, pool, gidx, index_vec,
                                 sidx, tokens, rng, temperature, top_k,
@@ -1588,16 +1647,18 @@ class InferenceEngine:
         return out, n_acc, extra, self._paged_writeback(
             pool, view, sidx, index_vec)
 
-    def _paged_mixed_masked_fn(self, params, pool, gidx, chunk_ids,
-                               starts, lens, advance, tokens, rng,
-                               temperature, top_k, top_p, greedy,
-                               gmask, sidx, *, n):
-        view = self._paged_view(pool, gidx, starts)
-        chunk_last, toks, view = self._mixed_masked_raw(
-            params, view, chunk_ids, starts, lens, advance, tokens,
-            rng, temperature, top_k, top_p, greedy, gmask, n=n)
-        return chunk_last, toks, self._paged_writeback(
-            pool, view, sidx, starts)
+    def _paged_mixed_masked_fn(self, params, pool, slots, pgidx,
+                               chunk_ids, starts, lens, psidx, n_rows,
+                               gidx, index_vec, sidx, tokens, rng,
+                               temperature, top_k, top_p, greedy, gmask,
+                               *, n):
+        """Grammar-masked twin of :meth:`_paged_mixed_fn` (the mask
+        applies to the decode half only): a separate program, so
+        unconstrained steps never carry the mask."""
+        return self._paged_mixed_fn(
+            params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
+            n_rows, gidx, index_vec, sidx, tokens, rng, temperature,
+            top_k, top_p, greedy, n=n, gmask=gmask)
 
     def _paged_write_rows_fn(self, pool, rows, sidx):
         """Scatter B bucket-width row sets (one-shot prefill output, a
@@ -1807,6 +1868,20 @@ class InferenceEngine:
         peak ROADMAP item 1's in-place paged attention reclaims."""
         self._hbm.pulse("transient_view", self.paged.view_bytes(W, n_slots))
 
+    def _paged_decode_plan(self, active: list[int], n: int, W: int):
+        """Index arguments ``(gidx, index_vec, sidx)`` of a slot-plane
+        decode block of ``n`` tokens at view width ``W``: every slot's
+        pages gathered, ``active`` rows' ``n`` new rows scattered back
+        (everything else to the trash page). Forks shared pages the
+        writes would touch."""
+        idxv = self._paged_index_vec(W, n)
+        valid = np.zeros((self.max_slots,), np.int32)
+        for s in active:
+            valid[s] = n
+            self._paged_cow_fork(s, int(self.slot_len[s]), n)
+        return (jnp.asarray(self.paged.gather_idx(W)), jnp.asarray(idxv),
+                jnp.asarray(self.paged.scatter_idx(idxv, valid, n)))
+
     def _paged_decode_dispatch(self, active: list[int], n: int, sub,
                                gmask=None, lora=None):
         """Issue one paged decode dispatch (single-token via the
@@ -1820,14 +1895,7 @@ class InferenceEngine:
         W = self._paged_width(
             max(int(self.slot_len[s]) for s in active) + n)
         self._pulse_view(W)
-        idxv = self._paged_index_vec(W, n)
-        valid = np.zeros((self.max_slots,), np.int32)
-        for s in active:
-            valid[s] = n
-            self._paged_cow_fork(s, int(self.slot_len[s]), n)
-        gidx = jnp.asarray(self.paged.gather_idx(W))
-        sidx = jnp.asarray(self.paged.scatter_idx(idxv, valid, n))
-        idxv = jnp.asarray(idxv)
+        gidx, idxv, sidx = self._paged_decode_plan(active, n, W)
         tokens = jnp.asarray(self.slot_last_token)
         args = (jnp.asarray(self._temperature),
                 jnp.asarray(self._top_k),
@@ -2926,53 +2994,25 @@ class InferenceEngine:
         logits row. ``req``: books the dispatch into the request's
         critical-path breakdown when given."""
         C = self._bucket_for(len(suffix))
-        # slot-plane adapters: the LoRA chunk program indexes the full
-        # slot plane, so adapters keep the all-slots dispatch; the plain
-        # path runs a SINGLE-ROW chunk — gathering only the owning
-        # slot's pages instead of a W-wide view of every slot, which is
+        # a ONE-row call of the paged chunk program: it gathers the
+        # owning slot's pages, not a W-wide view of every slot, which is
         # what makes a warm follow-up turn cheaper than its cold
         # re-prefill (the view gather, not the attention, dominates a
-        # short suffix over a long prefix)
+        # short suffix over a long prefix). Adapters ride along: the
+        # program picks the row's index out of the slot plane.
         lora = self._lora_args()
-        one = lora is None
         with self.steptrace.scope("index_build"):
             W = self._paged_width(done + C)
-            # the single-row path gathers ONE slot's pages, not a
-            # W-wide view of every slot — pulse what it actually costs
-            self._pulse_view(W, 1 if one else None)
-            if one:
-                tok = np.zeros((1, C), np.int32)
-                tok[0, :len(suffix)] = suffix
-                starts = np.array([done], np.int32)
-                lens = np.array([len(suffix)], np.int32)
-                self._paged_cow_fork(slot, done, len(suffix))
-                fs = np.zeros((self.max_slots,), np.int32)
-                fs[slot] = done
-                fv = np.zeros((self.max_slots,), np.int32)
-                fv[slot] = len(suffix)
-                sidx = self.paged.scatter_idx(fs, fv, C)[slot:slot + 1]
-                gidx = self.paged.row_gather_idx(slot, W)
-            else:
-                tok = np.zeros((self.max_slots, C), np.int32)
-                tok[slot, :len(suffix)] = suffix
-                starts = self._paged_index_vec(W, C)
-                starts[slot] = done
-                lens = np.zeros((self.max_slots,), np.int32)
-                lens[slot] = len(suffix)
-                valid = np.zeros((self.max_slots,), np.int32)
-                valid[slot] = len(suffix)
-                self._paged_cow_fork(slot, done, len(suffix))
-                sidx = self.paged.scatter_idx(starts, valid, C)
-                gidx = self.paged.gather_idx(W)
+            self._pulse_view(W, 1)
+            rows = self._paged_chunk_rows(
+                [(slot, done, suffix)], W, C, n_rows=1)
         kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("prefill")
             fn = self._pg_chunk if lora is None else self._pg_chunk_lora
             last, self.paged.kv = fn(
-                self.params, self.paged.kv, jnp.asarray(gidx),
-                jnp.asarray(tok), jnp.asarray(starts), jnp.asarray(lens),
-                jnp.asarray(sidx), **kw)
-            out = last[0:1] if one else last[slot:slot + 1]
+                self.params, self.paged.kv, *rows, **kw)
+            out = last[slot:slot + 1]
             self.steptrace.window_issued()
             # force before the window closes, exactly like
             # _prefill_into_slot (the logits feed the first-token sample
@@ -3088,6 +3128,7 @@ class InferenceEngine:
                     self._paged_chunk_dispatch(entries, lora=lora)
                 elif batchable:
                     tok, starts, lens = self._chunk_batch_rows(entries)
+                    self._note_chunk_rows(len(entries), self.max_slots)
                     fn = (self._chunk_batch if lora is None
                           else self._chunk_batch_lora)
                     last, self.cache = fn(
@@ -3097,6 +3138,7 @@ class InferenceEngine:
                         st["last_logits"] = last[slot:slot + 1]
                         st["done"] += len(chunk)
                 else:
+                    self._note_chunk_rows(len(entries), len(entries))
                     for slot, st, chunk in entries:
                         # the 1-row program wants a 1-row index array
                         sl = self._lora_args_for([st["req"].adapter])
@@ -3130,7 +3172,9 @@ class InferenceEngine:
                 self._trace_chunks(entries, dt, issue_s, batched=batchable)
                 self._note_device_phase(
                     "prefill", tokens=pf_tokens, attended_keys=pf_keys,
-                    weight_passes=1 if batchable else len(entries),
+                    # the paged loop streams the weights once a row
+                    weight_passes=(1 if batchable and self.paged is None
+                                   else len(entries)),
                     kv_read_tokens=pf_keys, dt=dt)
             budget -= 1
             progressed = True
@@ -3153,8 +3197,11 @@ class InferenceEngine:
 
     def _chunk_batch_rows(self, entries):
         """Host arrays (tok, starts, lens) for a whole-cache batched
-        chunk dispatch — shared by the sequential batched path and the
-        fused mixed step. Non-prefill rows get zero tokens at their own
+        chunk dispatch of the CONTIGUOUS layout — shared by its
+        sequential batched path and its fused mixed step (the paged
+        layout computes only the rows that chunk and has no dead
+        writes: :meth:`_paged_chunk_rows`). Non-prefill rows get zero
+        tokens at their own
         index: garbage KV beyond it, overwritten in order before any
         query attends it; min() keeps the dead write window of FREE
         rows inside the cache (occupied rows already fit by the
@@ -3174,34 +3221,72 @@ class InferenceEngine:
             lens[slot] = len(chunk)
         return tok, starts, lens
 
+    def _paged_chunk_rows(self, rows, W: int, C: int, n_rows=None):
+        """Host arguments of :meth:`_paged_chunk_fn` for ``rows`` =
+        ``[(slot, done, chunk tokens), ...]``: each row's slot id, pool
+        gather / window-scatter indices, padded tokens, ``starts`` and
+        ``lens``, and the trip count ``len(rows)``. The arrays hold
+        ``n_rows`` rows (default ``max_slots``) whatever ``len(rows)``
+        is, so the compile key holds no row count; the program never
+        visits the padding. Forks any shared page a row's window
+        touches, so read ``self.paged.kv`` AFTER this call."""
+        S = self.max_slots
+        R = S if n_rows is None else n_rows
+        k = len(rows)
+        slots = np.zeros((R,), np.int32)
+        tok = np.zeros((R, C), np.int32)
+        starts = np.zeros((R,), np.int32)
+        lens = np.zeros((R,), np.int32)
+        gidx = np.zeros((R, W), np.int32)
+        sidx = np.zeros((R, C), np.int32)
+        plane_starts = np.zeros((S,), np.int32)
+        plane_valid = np.zeros((S,), np.int32)
+        for i, (slot, done, chunk) in enumerate(rows):
+            slots[i] = slot
+            tok[i, :len(chunk)] = chunk
+            starts[i] = plane_starts[slot] = done
+            lens[i] = plane_valid[slot] = len(chunk)
+            self._paged_cow_fork(slot, done, len(chunk))
+        gidx[:k] = self.paged.gather_idx(W)[slots[:k]]
+        sidx[:k] = self.paged.scatter_idx(
+            plane_starts, plane_valid, C)[slots[:k]]
+        return tuple(jnp.asarray(a) for a in (
+            slots, gidx, tok, starts, lens, sidx, np.int32(k)))
+
+    def _paged_entry_rows(self, entries, W: int):
+        """:meth:`_paged_chunk_rows` for the mid-prefill ``entries`` of a
+        chunk or fused mixed dispatch, booked in the chunk-row counters
+        (the device computes exactly the rows that chunk)."""
+        self._note_chunk_rows(len(entries), len(entries))
+        return self._paged_chunk_rows(
+            [(slot, st["done"], chunk) for slot, st, chunk in entries],
+            W, self.chunked_prefill)
+
+    def _note_chunk_rows(self, rows: int, row_slots: int) -> None:
+        """Book one chunk dispatch: ``rows`` prompts advanced a chunk,
+        the device computed ``row_slots`` rows for them (the
+        contiguous slot plane's idle rows included). Their ratio is the useful share of the
+        prefill plane."""
+        self.prefill_chunk_rows += rows
+        self.prefill_chunk_row_slots += row_slots
+        self.steptrace.note_chunk_rows(rows, row_slots)
+
     def _paged_chunk_dispatch(self, entries, lora=None) -> None:
         """Advance every mid-prefill row one chunk against the PAGE
-        POOL in a single dispatch: gather a bucketed contiguous view,
-        run the shared ``batched_chunk`` body, scatter each prefill
-        row's real chunk window back to its pages (everything else —
-        idle rows' dead windows, padding — lands in the trash page)."""
+        POOL in a single dispatch: the program gathers one chunking
+        row's pages at a time, runs the shared ``batched_chunk`` body
+        on that view and scatters the row's real chunk window back to
+        its pages. Rows that do not chunk cost nothing."""
         C = self.chunked_prefill
-        tok, starts, lens = self._chunk_batch_rows(entries)
         W = self._paged_width(
             max(st["done"] for _, st, _ in entries) + C)
-        self._pulse_view(W)
-        # non-prefill rows' dead C-wide in-view writes must stay inside
-        # the view; their view copy is discarded (windows are trash),
-        # so the clamp is harmless — prefill rows stay exact
-        starts = np.minimum(starts, W - C)
-        valid = np.zeros((self.max_slots,), np.int32)
-        for slot, st, chunk in entries:
-            starts[slot] = st["done"]
-            valid[slot] = len(chunk)
-            self._paged_cow_fork(slot, st["done"], len(chunk))
-        sidx = self.paged.scatter_idx(starts, valid, C)
-        gidx = self.paged.gather_idx(W)
+        self._pulse_view(W, 1)
         kw = {} if lora is None else {"lora": lora}
         fn = self._pg_chunk if lora is None else self._pg_chunk_lora
-        last, self.paged.kv = fn(
-            self.params, self.paged.kv, jnp.asarray(gidx),
-            jnp.asarray(tok), jnp.asarray(starts), jnp.asarray(lens),
-            jnp.asarray(sidx), **kw)
+        # a statement of its own: building the rows may fork a shared
+        # page, which REBINDS the (donated) pool read below
+        rows = self._paged_entry_rows(entries, W)
+        last, self.paged.kv = fn(self.params, self.paged.kv, *rows, **kw)
         for slot, st, chunk in entries:
             st["last_logits"] = last[slot:slot + 1]
             st["done"] += len(chunk)
@@ -3844,10 +3929,21 @@ class InferenceEngine:
                     "prefill row near the cache end: "
                     f"slot {slot} done {st['done']} + chunk {C} + "
                     f"block {n} > cache_len {self.cache_len}")
+        if self.paged is not None:
+            # no decode row receives a chunk write in this layout; the
+            # block's own n rows must fit
+            for s in active:
+                if int(self.slot_len[s]) + n > self.cache_len:
+                    return False, (
+                        "decode row lacks the block's write window: "
+                        f"slot {s} len {int(self.slot_len[s])} + block "
+                        f"{n} > cache_len {self.cache_len}")
+            return True, ""
         for s in range(self.max_slots):
-            # every occupied non-prefill row receives the dead chunk
-            # write at its own index (free rows clamp; occupied rows
-            # must fit exactly) — same bound as the batched chunk path
+            # contiguous layout: every occupied non-prefill row receives
+            # the dead chunk write at its own index (free rows clamp;
+            # occupied rows must fit exactly) — same bound as the
+            # batched chunk path
             if s in self.slot_prefill or self.slot_req[s] is None:
                 continue
             if int(self.slot_len[s]) + C > self.cache_len:
@@ -3883,17 +3979,18 @@ class InferenceEngine:
                 st = self.slot_prefill[slot]
                 chunk = st["req"].prompt_ids[st["done"]: st["done"] + C]
                 entries.append((slot, st, chunk))
-            tok, starts, lens = self._chunk_batch_rows(entries)
-            advance = np.zeros((self.max_slots,), np.int32)
-            advance[active] = n
+            if self.paged is None:
+                tok, starts, lens = self._chunk_batch_rows(entries)
+                advance = np.zeros((self.max_slots,), np.int32)
+                advance[active] = n
             # constrained decoding: the decode half of the fused step masks
             # each grammar slot's logits (n == 1 then, by _plan_block);
             # mid-prefill rows need nothing — their first token samples at
             # finalization, where _activate applies the start-state mask
             gmask = self._grammar_masks(active)
             # multi-LoRA: slot-plane adapter rows cover BOTH halves of the
-            # fused program (prefill rows and decode rows are the same
-            # max_slots plane)
+            # fused program (the paged prefill half picks its rows' out
+            # of the plane)
             lora = self._lora_args()
             kw = {} if lora is None else {"lora": lora}
             # per-phase device accounting for the ONE fused dispatch: the
@@ -3914,92 +4011,52 @@ class InferenceEngine:
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("mixed")
             self.rng, sub = jax.random.split(self.rng)
+            sampling = (jnp.asarray(self.slot_last_token), sub,
+                        jnp.asarray(self._temperature),
+                        jnp.asarray(self._top_k),
+                        jnp.asarray(self._top_p),
+                        jnp.asarray(self._greedy))
+            if gmask is not None:
+                sampling += (jnp.asarray(gmask),)
             if self.paged is not None:
-                # view must hold: each prefill row's chunk + the scan's
-                # n garbage rows above it (done+C+n), and each occupied
-                # decode row's dead chunk window (len+C; the scan's
-                # real n rows overwrite its head) — the same extents
-                # _mixed_feasible bounds against cache_len
+                # ONE view width for both halves: each prefill row's
+                # chunk + the block (done+C+n), and each occupied decode
+                # row's len+C, capped at cache_len — no decode row
+                # receives a chunk write any more, but the warm-up
+                # builds the widths THIS rule gives (narrower decode
+                # views are a change of their own)
                 need = max(
                     [st["done"] + C + n for _, st, _ in entries]
-                    + [int(self.slot_len[s]) + C
+                    + [min(int(self.slot_len[s]) + C, self.cache_len)
                        for s in range(self.max_slots)
                        if s not in self.slot_prefill
                        and self.slot_req[s] is not None] + [C + n])
                 W = self._paged_width(need)
                 self._pulse_view(W)
-                starts = np.minimum(starts, W - C)
-                valid = np.zeros((self.max_slots,), np.int32)
-                for slot, st, chunk in entries:
-                    starts[slot] = st["done"]
-                    valid[slot] = len(chunk)
-                    self._paged_cow_fork(slot, st["done"], len(chunk))
-                for s in active:
-                    valid[s] = n
-                    self._paged_cow_fork(s, int(self.slot_len[s]), n)
                 if gmask is not None:
                     fn = (self._pg_mixed_masked if lora is None
                           else self._pg_mixed_masked_lora)
-                    chunk_last, toks, self.paged.kv = fn(
-                        self.params, self.paged.kv,
-                        jnp.asarray(self.paged.gather_idx(W)),
-                        jnp.asarray(tok), jnp.asarray(starts),
-                        jnp.asarray(lens), jnp.asarray(advance),
-                        jnp.asarray(self.slot_last_token), sub,
-                        jnp.asarray(self._temperature),
-                        jnp.asarray(self._top_k),
-                        jnp.asarray(self._top_p),
-                        jnp.asarray(self._greedy),
-                        jnp.asarray(gmask),
-                        jnp.asarray(self.paged.scatter_idx(
-                            starts, valid, C)),
-                        n=n, **kw,
-                    )
                 else:
                     fn = (self._pg_mixed if lora is None
                           else self._pg_mixed_lora)
-                    chunk_last, toks, self.paged.kv = fn(
-                        self.params, self.paged.kv,
-                        jnp.asarray(self.paged.gather_idx(W)),
-                        jnp.asarray(tok), jnp.asarray(starts),
-                        jnp.asarray(lens), jnp.asarray(advance),
-                        jnp.asarray(self.slot_last_token), sub,
-                        jnp.asarray(self._temperature),
-                        jnp.asarray(self._top_k),
-                        jnp.asarray(self._top_p),
-                        jnp.asarray(self._greedy),
-                        jnp.asarray(self.paged.scatter_idx(
-                            starts, valid, C)),
-                        n=n, **kw,
-                    )
-            elif gmask is not None:
-                fn = (self._mixed_masked if lora is None
-                      else self._mixed_masked_lora)
-                chunk_last, toks, self.cache = fn(
-                    self.params, self.cache, jnp.asarray(tok),
-                    jnp.asarray(starts), jnp.asarray(lens),
-                    jnp.asarray(advance),
-                    jnp.asarray(self.slot_last_token), sub,
-                    jnp.asarray(self._temperature),
-                    jnp.asarray(self._top_k),
-                    jnp.asarray(self._top_p),
-                    jnp.asarray(self._greedy),
-                    jnp.asarray(gmask),
-                    n=n, **kw,
-                )
+                # statements of their own: either may fork a shared
+                # page, which REBINDS the (donated) pool read below
+                rows = self._paged_entry_rows(entries, W)
+                plan = self._paged_decode_plan(active, n, W)
+                chunk_last, toks, self.paged.kv = fn(
+                    self.params, self.paged.kv, *rows, *plan, *sampling,
+                    n=n, **kw)
             else:
-                fn = self._mixed if lora is None else self._mixed_lora
+                self._note_chunk_rows(len(entries), self.max_slots)
+                if gmask is not None:
+                    fn = (self._mixed_masked if lora is None
+                          else self._mixed_masked_lora)
+                else:
+                    fn = self._mixed if lora is None else self._mixed_lora
                 chunk_last, toks, self.cache = fn(
                     self.params, self.cache, jnp.asarray(tok),
                     jnp.asarray(starts), jnp.asarray(lens),
-                    jnp.asarray(advance),
-                    jnp.asarray(self.slot_last_token), sub,
-                    jnp.asarray(self._temperature),
-                    jnp.asarray(self._top_k),
-                    jnp.asarray(self._top_p),
-                    jnp.asarray(self._greedy),
-                    n=n, **kw,
-                )
+                    jnp.asarray(advance), *sampling, n=n, **kw)
             self.steptrace.window_issued()
             toks_host = np.asarray(toks)  # forces the dispatch's results
             # the window advanced the mid-prefill rows' prompts; every
@@ -4022,7 +4079,9 @@ class InferenceEngine:
                 share = pf_tokens / max(pf_tokens + dc_tokens, 1)
             self._note_device_phase(
                 "prefill", tokens=pf_tokens, attended_keys=pf_keys,
-                weight_passes=1, kv_read_tokens=pf_keys, dt=dt * share)
+                # the paged loop streams the weights once a row
+                weight_passes=1 if self.paged is None else len(entries),
+                kv_read_tokens=pf_keys, dt=dt * share)
             self._note_device_phase(
                 "decode", tokens=dc_tokens, attended_keys=dc_keys,
                 weight_passes=n, kv_read_tokens=dc_keys,

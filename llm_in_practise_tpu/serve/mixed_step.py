@@ -33,9 +33,11 @@ sequential two-dispatch path with a logged reason):
 - prefill rows: ``done + chunk + n <= cache_len`` — both the chunk
   scatter and the garbage scan rows must land inside the cache (a
   clamped scatter would shift backward over attended prompt KV).
-- decode rows: ``slot_len + chunk <= cache_len`` — the dead chunk
-  write window must fit (same bound as the batched chunk path); the
-  scan's real writes fit a fortiori since ``n <= chunk``.
+- decode rows, CONTIGUOUS layout only: ``slot_len + chunk <=
+  cache_len`` — the dead chunk write window must fit (same bound as
+  the batched chunk path); the scan's real writes fit a fortiori since
+  ``n <= chunk``. The paged layout asks ``slot_len + n <= cache_len``
+  instead (see below).
 - free rows: dead either way; the caller clamps their pinned index to
   ``cache_len - chunk`` so even the dead window stays in bounds.
 
@@ -43,6 +45,18 @@ Token-exactness: part (a) is bit-identical to ``_chunk_batch_fn`` (same
 pinning arithmetic) and part (b) to ``_decode_multi_fn`` (same scan
 body, same per-step key split), so greedy outputs equal the sequential
 path's exactly — pinned by ``tests/test_mixed_step.py``.
+
+The PAGED layout (``engine._paged_mixed_fn``) keeps the one dispatch
+and the two shared bodies, but its part (a) runs
+:func:`batched_chunk` over the rows that are mid-prefill only, one
+row a trip of a loop with a traced trip count
+(``engine._paged_chunk_fn``), and its part (b) is the paged decode
+body over the slot plane. The prefill half's device work follows the
+number of chunking rows, not ``max_slots``, and no decode row
+receives a chunk write: idle and mid-prefill rows' decode garbage
+goes to the trash page through the host-built scatter indices. The
+functions built by :func:`make_mixed_step` serve the contiguous
+layout alone.
 """
 
 from __future__ import annotations

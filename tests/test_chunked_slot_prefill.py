@@ -157,3 +157,88 @@ def test_batched_multi_slot_chunks_match_isolated(models, layout):
         pass
     outs = [h.result() for h in handles]
     assert outs == refs
+
+
+# --- paged layout: the chunk program computes only the rows that chunk ------
+#
+# (PR 28) Nothing decodes when the rows start: the chunk-only program
+# (``_paged_chunk_fn``'s loop) carries them, one of them from a prefix
+# hit. Reference: each prompt ALONE on a one-slot contiguous engine,
+# whose chunks go through the one-row ``_chunk_slot_fn``.
+
+_ALONE: dict = {}
+SHARED = _rng_prompt(32, seed=99)          # two full pages of 16
+
+
+def _prompt(i):
+    body = _rng_prompt(100 + 8 * i, seed=40 + i)
+    return SHARED + body[32:] if i == 1 else body
+
+
+def _alone(model, params, i):
+    """(tokens, KV rows of slot 0) of prompt ``i`` by itself."""
+    if i not in _ALONE:
+        eng = InferenceEngine(model, params, max_slots=1, cache_len=160,
+                              chunked_prefill=16,
+                              cache_dtype=jnp.float32)
+        out = eng.generate(_prompt(i), SamplingParams(greedy=True,
+                                                      max_tokens=6))
+        rows = [{k: np.asarray(v)[0] for k, v in layer.items()
+                 if k != "index"} for layer in eng.cache]
+        eng.stop()
+        _ALONE[i] = (out, rows)
+    return _ALONE[i]
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 5])
+def test_paged_chunk_rows_match_one_row_path(models, k):
+    """k rows among 8 slots chunk together with no row decoding, each
+    at another ``done`` (arrivals a step apart, the second from a
+    prefix hit): tokens equal each prompt's own one-row run exactly,
+    the rows' KV to float32's last bits, and the device computed one
+    row a chunk for them, not 8."""
+    mu, pu, _, _ = models
+    eng = InferenceEngine(mu, pu, max_slots=8, cache_len=160,
+                          chunked_prefill=16, kv_layout="paged",
+                          prefix_cache=True, cache_dtype=jnp.float32)
+    eng.generate(SHARED + [5, 6, 7], SamplingParams(greedy=True,
+                                                    max_tokens=2))
+    sp = SamplingParams(greedy=True, max_tokens=6)
+    handles = []
+    for i in range(k):
+        handles.append(eng.submit(_prompt(i), sp))
+        eng.step()
+        assert eng.mixed_blocks == 0           # nothing decodes yet
+    assert len(eng.slot_prefill) == k
+    assert len({st["done"] for st in eng.slot_prefill.values()}) == k
+    if k > 1:
+        assert handles[1].cache_outcome == "partial"
+    assert eng.prefill_chunk_row_slots == eng.prefill_chunk_rows
+    while eng.slot_prefill:
+        eng.step()
+    snap = {}
+    for s, req in enumerate(eng.slot_req):
+        if req is not None:
+            n = int(eng.slot_len[s])
+            flat = eng.paged.row_gather_idx(s, n)[0]
+            snap[req.uid] = (n, [{key: np.asarray(buf)[flat]
+                                  for key, buf in layer.items()}
+                                 for layer in eng.paged.kv])
+    while eng.step():
+        pass
+    eng.stop()          # returns the pool's bytes to the process's HBM ledger
+    compared = 0
+    for i, h in enumerate(handles):
+        out, rows = _alone(mu, pu, i)
+        assert h.result() == out
+        if h.uid in snap:
+            n, got = snap[h.uid]
+            for a, b in zip(got, rows):
+                for key in a:
+                    # float32 through another program shape (one row
+                    # of a W-wide view against a slot slice): values of
+                    # order 1 over <= 256-term sums, a few ulps
+                    np.testing.assert_allclose(a[key], b[key][:n],
+                                               rtol=1e-5, atol=1e-5)
+            compared += 1
+    assert compared >= 1

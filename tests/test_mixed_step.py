@@ -295,3 +295,246 @@ def test_plan_decode_block_policy():
     assert plan_decode_block(decode_steps=1, queue_depth=3,
                              soonest_finish=9, chunk=4,
                              prefill_headroom=9) == 1
+
+
+# --- paged layout: the prefill half computes only the rows that chunk -------
+#
+# (PR 28) ``_paged_chunk_fn`` loops over the mid-prefill rows, one a
+# trip, with a traced trip count, inside the chunk program and inside
+# the fused mixed step. Reference: the same schedule on a
+# CONTIGUOUS engine with the fused step off — the slot-plane
+# ``batched_chunk`` body and a separate decode dispatch, code this
+# change does not touch.
+
+PAGED = dict(max_slots=8, chunked_prefill=8, decode_steps=1)
+SEED_PROMPT = [(i * 5 + 2) % 64 for i in range(40)]   # 2 full pages of 16
+
+
+def _long(i):
+    return [(j * (3 + 2 * i) + i) % 64 for j in range(44 + 6 * i)]
+
+
+def paged_rows(eng, slot, n):
+    """Rows ``[0, n)`` of ``slot``, per layer and key, out of the pool."""
+    flat = eng.paged.row_gather_idx(slot, n)[0]
+    return [{k: np.asarray(buf)[flat] for k, buf in layer.items()}
+            for layer in eng.paged.kv]
+
+
+def contiguous_rows(eng, slot, n):
+    return [{k: np.asarray(buf)[slot, :n] for k, buf in layer.items()
+             if k != "index"} for layer in eng.cache]
+
+
+def run_schedule(eng, k, n_decoding, *, prefix_hit):
+    """``n_decoding`` short prompts decode; ``k`` long prompts arrive one
+    engine step apart (so every row has another ``done``), the second
+    one starting on a prefix hit where the engine has the pages.
+    Returns (handles, their tokens, snapshot of every live row once all
+    decode)."""
+    if prefix_hit:
+        eng.generate(SEED_PROMPT, SamplingParams(greedy=True, max_tokens=2))
+    hs = [eng.submit(p, SamplingParams(greedy=True, max_tokens=60))
+          for p in SHORT[:n_decoding]]
+    eng.step()
+    for i in range(k):
+        prompt = _long(i)
+        if i == 1:
+            prompt = SEED_PROMPT[:32] + prompt     # two shared pages
+        hs.append(eng.submit(prompt, SamplingParams(greedy=True,
+                                                    max_tokens=24)))
+        eng.step()
+    most = 0
+    while eng.slot_prefill:
+        most = max(most, len(eng.slot_prefill))
+        eng.step()
+    assert most == k or k == 1
+    rows = paged_rows if eng.paged is not None else contiguous_rows
+    live = {h.uid: (s, int(eng.slot_len[s]))
+            for s, r in enumerate(eng.slot_req) if r is not None
+            for h in hs if h is r}
+    snap = {uid: (n, rows(eng, s, n)) for uid, (s, n) in live.items()}
+    while eng.step():
+        pass
+    eng.stop()          # returns the engine's bytes to the HBM ledger
+    return hs, [h.result() for h in hs], snap
+
+
+def assert_same_rows(snap_a, snap_b, uids_a, uids_b):
+    compared = 0
+    for ua, ub in zip(uids_a, uids_b):
+        if ua not in snap_a or ub not in snap_b:
+            continue
+        (na, la), (nb, lb) = snap_a[ua], snap_b[ub]
+        n = min(na, nb)      # a prefix hit activates a few steps sooner
+        for a, b in zip(la, lb):
+            for key in a:
+                # float32, another batch shape (one row a trip against
+                # the 8-row plane): the last bit may differ (measured
+                # 6e-8 absolute), nothing more. Tokens are exact.
+                np.testing.assert_allclose(a[key][:n], b[key][:n],
+                                           rtol=1e-5, atol=1e-6)
+        compared += 1
+    assert compared >= 1
+
+
+_REF_RUNS: dict = {}
+
+
+@pytest.mark.parametrize("k,n_decoding", [(1, 2), (2, 2), (3, 2), (5, 2),
+                                          (3, 1), (5, 1)])
+def test_paged_rows_match_sequential(model_params, k, n_decoding):
+    """k rows chunk beside decoding rows among 8 slots (fused mixed
+    step): greedy tokens equal the contiguous sequential path's
+    exactly, and every live row's KV to float32's last bits."""
+    model, params = model_params
+    paged = _engine(model, params, kv_layout="paged", prefix_cache=True,
+                    **PAGED)
+    hp, out_p, snap_p = run_schedule(paged, k, n_decoding, prefix_hit=True)
+    if (k, n_decoding) not in _REF_RUNS:
+        ref = _engine(model, params, mixed_step=False, **PAGED)
+        _REF_RUNS[k, n_decoding] = run_schedule(ref, k, n_decoding,
+                                                prefix_hit=False)
+        assert ref.mixed_blocks == 0
+    hr, out_r, snap_r = _REF_RUNS[k, n_decoding]
+    assert out_p == out_r
+    assert paged.mixed_blocks > 0
+    if k > 1:                                  # a row began on a hit
+        assert hp[n_decoding + 1].cache_outcome == "partial"
+    assert_same_rows(snap_p, snap_r, [h.uid for h in hp],
+                     [h.uid for h in hr])
+    assert not paged._mixed_fallbacks_logged
+    # the device computed exactly the rows that chunked
+    assert paged.prefill_chunk_row_slots == paged.prefill_chunk_rows
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_shared_page_under_chunk_window_forks(model_params, fused):
+    """The defensive COW fork inside a chunk dispatch: a page under a
+    mid-prefill row's NEXT chunk window (and, fused, under the decoding
+    row's next token) gains a phantom reader. The dispatch must fork
+    the page and hand the program the forked pool — the fork rebinds
+    the donated ``paged.kv`` — and every token must equal the unshared
+    run's, through the chunk-only program and through the fused step."""
+    model, params = model_params
+    sp = SamplingParams(greedy=True, max_tokens=12)
+    outs = []
+    for share in (False, True):
+        eng = _engine(model, params, kv_layout="paged", **PAGED)
+        hs = []
+        if fused:
+            hs.append(eng.submit(SHORT[0], sp))
+            eng.step()
+        hs.append(eng.submit(LONG, sp))
+        eng.step()                             # the first chunk of 5
+        (slot, st), = eng.slot_prefill.items()
+        assert 0 < st["done"] < len(LONG)
+        if share:
+            pool, bt = eng.paged.pool, eng.paged.block_tables
+            at = {slot: st["done"]}
+            if fused:
+                at.update((s, int(eng.slot_len[s]))
+                          for s, r in enumerate(eng.slot_req)
+                          if r is hs[0])
+            held = {s: int(bt[s, pos // eng.paged.page_size])
+                    for s, pos in at.items()}
+            pool.share(list(held.values()))
+        eng.step()
+        assert eng.mixed_blocks == 2 * int(fused)
+        # one engine program, plus one page copy a fork
+        assert eng.dispatch_meter.last_step == 1 + (len(held) if share
+                                                    else 0)
+        if share:
+            for s, page in held.items():       # forked, sharer untouched
+                assert int(bt[s, at[s] // eng.paged.page_size]) != page
+                assert pool.refcount(page) == 1
+            pool.release(list(held.values()))
+        while eng.step():
+            pass
+        outs.append([h.result() for h in hs])
+        eng.paged.pool.check_leaks(0)
+        eng.stop()
+    assert outs[0] == outs[1]
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_decode_row_near_cache_end(model_params, layout):
+    """A decode row past ``cache_len - chunk``: the contiguous fused
+    step falls back to two dispatches (its dead chunk write would
+    clamp over attended KV), the paged one has no such write and stays
+    ONE dispatch. Tokens equal the sequential path's either way."""
+    model, params = model_params
+    outs, engines = [], {}
+    for mixed in (False, True):
+        eng = engines[mixed] = _engine(
+            model, params, cache_len=64, mixed_step=mixed, decode_steps=1,
+            kv_layout=layout)
+        a = eng.submit(SHORT[0], SamplingParams(greedy=True,
+                                                max_tokens=100))
+        while a.n_generated < 52:             # slot_len 57 > 64 - 8
+            eng.step()
+        b = eng.submit(LONG[:20], SamplingParams(greedy=True,
+                                                 max_tokens=4))
+        fused_steps = 0
+        while eng.step():
+            if eng.slot_prefill and a.finish_reason is None:
+                fused_steps += eng.dispatch_meter.last_step == 1
+        assert a.finish_reason == "cache"
+        outs.append((a.result(), b.result()))
+        eng.stop()
+    assert outs[0] == outs[1]
+    fused = engines[True]
+    if layout == "paged":
+        assert not fused._mixed_fallbacks_logged
+        assert fused.mixed_blocks >= 2
+    else:
+        assert fused._mixed_fallbacks_logged == {
+            "decode row lacks the chunk dead-write window"}
+        assert fused.mixed_blocks == 0
+
+
+# --- counts (never times) of the paged prefill loop -------------------------
+
+
+def test_chunk_row_counters_and_one_compile(model_params):
+    """One row chunking among 8 slots books ONE computed row a chunk,
+    not 8; a k = 1 step and a k = 5 step at one view width share ONE
+    compiled program; the fused step stays one dispatch; the chunk
+    dispatch's ledger pulse is a one-row view."""
+    from llm_in_practise_tpu.obs.hbm import get_ledger
+
+    model, params = model_params
+    eng = _engine(model, params, kv_layout="paged", **PAGED)
+    sp = SamplingParams(greedy=True, max_tokens=80)
+    eng.submit(SHORT[0], sp)
+    eng.step()
+    # k = 1 beside a decoding row: fused, one dispatch, one row a chunk
+    eng.submit(_long(0), sp)                     # 44 tokens: 6 chunks
+    eng.step()
+    assert eng.dispatch_meter.last_step == 1 and eng.mixed_blocks == 1
+    assert (eng.prefill_chunk_rows, eng.prefill_chunk_row_slots) == (1, 1)
+    rec = eng.steptrace.records(limit=1)[-1]
+    assert (rec["chunk_rows"], rec["chunk_row_slots"]) == (1, 1)
+    while eng.slot_prefill:
+        eng.step()
+    assert eng.prefill_chunk_rows == eng.prefill_chunk_row_slots == 6
+    # k = 5 at the same view width (192 = cache_len): nothing compiles
+    built = eng.compile_meter.compile_events
+    for i in range(5):
+        eng.submit(_long(i), sp)
+    eng.step()
+    assert len(eng.slot_prefill) == 5
+    assert eng.dispatch_meter.last_step == 1
+    assert eng.compile_meter.compile_events == built
+    assert eng.prefill_chunk_rows == eng.prefill_chunk_row_slots == 6 + 5
+    # the chunk-only program: its transient view is ONE row's
+    alone = _engine(model, params, kv_layout="paged", **PAGED)
+    alone.submit(_long(3), sp)
+    alone.step()
+    assert alone.slot_prefill and alone.mixed_blocks == 0
+    W = alone._paged_width(8)
+    tv = get_ledger().snapshot()["accounts"]["transient_view"]
+    assert tv["last_pulse_bytes"] == alone.paged.view_bytes(W, 1)
+    assert tv["last_pulse_bytes"] < alone.paged.view_bytes(W)
+    eng.stop()
+    alone.stop()

@@ -140,6 +140,35 @@ def test_mixed_adapter_parity(world, refs, layout, spec):
     assert reg.stats()["tenant_tokens"] == {"t1": len(o1), "t2": len(o2)}
 
 
+@pytest.mark.parametrize("fused", [True, False])
+def test_adapters_chunk_beside_base_decoding(world, refs, fused):
+    """(PR 28) The paged prefill loop picks each chunking row's adapter
+    out of the slot plane: two adapters chunk-prefill while a base row
+    decodes, in the same fused dispatch and (``mixed_step`` off) in the
+    chunk-only program beside a decode dispatch, and every stream still
+    matches its merged-weight engine."""
+    model, params, *_ = world
+    base_ref, m1_ref, m2_ref = refs
+    eng = _engine(model, params, adapter_registry=_registry(world),
+                  kv_layout="paged", chunked_prefill=4, mixed_step=fused)
+    r0 = eng.submit(P0, SP)
+    while r0.n_generated < 2:
+        eng.step()
+    r1 = eng.submit(P0, SP, adapter="t1")      # 4 chunks of 4
+    r2 = eng.submit(P1, SP, adapter="t2")      # 3 chunks of 4
+    eng.step()
+    assert len(eng.slot_prefill) == 2
+    assert (eng.mixed_blocks >= 1) == fused
+    assert eng.dispatch_meter.last_step == (1 if fused else 2)
+    while eng.step():
+        pass
+    assert r0.result() == base_ref
+    assert r1.result() == m1_ref
+    assert r2.result() == m2_ref
+    assert eng.prefill_chunk_rows == 4 + 4 + 3     # r0's chunks too
+    eng.stop()
+
+
 def test_unknown_adapter_rejected_at_submit(world):
     model, params, *_ = world
     eng = _engine(model, params, adapter_registry=_registry(world))
