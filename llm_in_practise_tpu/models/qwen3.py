@@ -63,6 +63,10 @@ class Qwen3Config:
     # n_layer/unroll) — amortizes per-iteration loop mechanics at a
     # bounded compile-time cost. n_layer must be divisible by it.
     scan_unroll: int = 1
+    # Block length of the attention mask: 1 is causal; block-diffusion
+    # models (models/sdar_moe.py) attend bidirectionally inside blocks
+    # of this many absolute positions (ops/attention.py::causal_mask).
+    attn_block: int = 1
 
     def replace(self, **kw) -> "Qwen3Config":
         return dataclasses.replace(self, **kw)
@@ -213,7 +217,7 @@ class Qwen3Attention(nn.Module):
         out = dot_product_attention(
             q, k, v,
             causal=True, q_offset=q_offset,
-            impl=cfg.attn_impl,
+            impl=cfg.attn_impl, block=cfg.attn_block,
         )
         out = out.reshape(b, l, cfg.n_head * cfg.head_dim)
         return dense(cfg.hidden_size, "out_proj")(out), cache

@@ -251,8 +251,8 @@ def geometry_from_config(cfg) -> Geometry | None:
         return None
     n_layer = getattr(cfg, "n_layer", None)
     vocab = getattr(cfg, "vocab_size", None)
-    if not n_layer or not vocab:
-        return None
+    if not n_layer or not vocab or hasattr(cfg, "n_experts"):
+        return None     # routed experts: the work follows the routing
     if hasattr(cfg, "hidden_size") and hasattr(cfg, "n_kv_head"):
         d = cfg.hidden_size
         q = cfg.n_head * cfg.head_dim

@@ -178,6 +178,7 @@ class StepTrace:
         self._gap_before_s = 0.0
         self._chunk_rows = 0
         self._chunk_row_slots = 0
+        self._block = [0, 0, 0, 0]   # rows, commits, revealed, committed
         self._last_end: float | None = None   # previous step_end, perf
         # the open dispatch window (``_win_t0`` None: none open); the
         # annotation is that of the part under way, issue then wait
@@ -299,6 +300,17 @@ class StepTrace:
             self._chunk_rows += rows
             self._chunk_row_slots += row_slots
 
+    def note_block_pass(self, rows: int, commits: int, revealed: int,
+                        committed: int) -> None:
+        """A block-diffusion pass of this step (serve/block_step.py):
+        ``rows`` rows really advanced, ``commits`` of them committed their
+        block, the others revealed ``revealed`` positions, and
+        ``committed`` tokens streamed out: the record's ``block_rows`` /
+        ``block_commits`` / ``tokens_revealed`` / ``tokens_committed``."""
+        if self._recording:
+            for i, v in enumerate((rows, commits, revealed, committed)):
+                self._block[i] += v
+
     def step_begin(self, *, lock_wait_s: float = 0.0) -> None:
         """Open a step record. ``lock_wait_s``: what the caller waited
         for the engine's step lock before this call (a record field, not
@@ -314,6 +326,7 @@ class StepTrace:
                               if self._last_end is not None else 0.0)
         self._chunk_rows = 0
         self._chunk_row_slots = 0
+        self._block = [0, 0, 0, 0]
         self._acts = {}
         self._device_s = 0.0
         self._issue_s = 0.0
@@ -368,6 +381,10 @@ class StepTrace:
             "dispatches": self._dispatches,
             "chunk_rows": self._chunk_rows,
             "chunk_row_slots": self._chunk_row_slots,
+            "block_rows": self._block[0],
+            "block_commits": self._block[1],
+            "tokens_revealed": self._block[2],
+            "tokens_committed": self._block[3],
             "activities": dict(self._acts),
             "segments": [(name, t0 + off, t1 + off)
                          for name, t0, t1 in self._segments],

@@ -25,9 +25,16 @@ NEG_INF = -1e30
 
 
 def causal_mask(
-    q_len: int, kv_len: int, dtype=jnp.float32, q_offset: jax.Array | int | None = None
+    q_len: int, kv_len: int, dtype=jnp.float32,
+    q_offset: jax.Array | int | None = None, block: int = 1,
 ) -> jax.Array:
     """Additive causal mask of shape (1|B, 1, q_len, kv_len).
+
+    ``block`` > 1 gives the block-causal mask of block-diffusion models
+    (models/sdar_moe.py): query ``i`` sees key ``j`` iff
+    ``j // block <= i // block`` — bidirectional inside a block of
+    absolute positions, causal across blocks. ``block`` = 1 is the plain
+    causal mask, traced exactly as before.
 
     ``q_offset`` is the absolute position of the first query. Default places
     the query block at the end of the kv sequence (plain decode); a KV-cached
@@ -41,9 +48,13 @@ def causal_mask(
     q_offset = jnp.asarray(q_offset)
     if q_offset.ndim == 1:  # per-batch offsets -> (B, q_len) query positions
         q_pos = jnp.arange(q_len)[None, :] + q_offset[:, None]
+        if block > 1:   # a query sees up to the last position of its block
+            q_pos = q_pos - q_pos % block + (block - 1)
         allowed = jnp.arange(kv_len)[None, None, :] <= q_pos[:, :, None]
         return jnp.where(allowed, 0.0, NEG_INF).astype(dtype)[:, None]
     q_pos = jnp.arange(q_len)[:, None] + q_offset
+    if block > 1:
+        q_pos = q_pos - q_pos % block + (block - 1)
     kv_pos = jnp.arange(kv_len)[None, :]
     allowed = kv_pos <= q_pos
     return jnp.where(allowed, 0.0, NEG_INF).astype(dtype)[None, None]
@@ -61,11 +72,13 @@ def dense_attention(
     dropout_rng: jax.Array | None = None,
     scale: float | None = None,
     q_offset: jax.Array | int | None = None,
+    block: int = 1,
 ) -> jax.Array:
     """Reference XLA attention. q: (B, Lq, H, D), k/v: (B, Lk, H, D).
 
     ``kv_length``: optional (B,) valid kv lengths (for padded KV caches).
     ``q_offset``: absolute position of the first query (KV-cached prefill).
+    ``block``: block length of the causal mask (see :func:`causal_mask`).
     """
     b, q_len, n_head, head_dim = q.shape
     kv_len, n_kv = k.shape[1], k.shape[2]
@@ -89,7 +102,7 @@ def dense_attention(
             preferred_element_type=jnp.float32) * scale
         if causal:
             logits = logits + causal_mask(
-                q_len, kv_len, q_offset=q_offset)[:, :, None]
+                q_len, kv_len, q_offset=q_offset, block=block)[:, :, None]
         if kv_length is not None:
             kv_pos = jnp.arange(kv_len)[None, None, None, None, :]
             valid = kv_pos < kv_length[:, None, None, None, None]
@@ -106,7 +119,8 @@ def dense_attention(
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     if causal:
-        logits = logits + causal_mask(q_len, kv_len, q_offset=q_offset)
+        logits = logits + causal_mask(q_len, kv_len, q_offset=q_offset,
+                                      block=block)
     if kv_length is not None:
         kv_pos = jnp.arange(kv_len)[None, None, None, :]
         valid = kv_pos < kv_length[:, None, None, None]
@@ -134,8 +148,14 @@ def dot_product_attention(
     scale: float | None = None,
     q_offset: jax.Array | int | None = None,
     impl: str = "auto",
+    block: int = 1,
 ) -> jax.Array:
-    """Attention entry point used by every model in the framework."""
+    """Attention entry point used by every model in the framework.
+
+    ``block`` > 1 (block-causal mask) always takes the dense path: the
+    flash and sequence-parallel kernels mask by position, not by block."""
+    if block > 1:
+        impl = "dense"
     if impl == "auto":
         impl = _pick_impl(q, k, bias, kv_length, dropout_rate, causal)
     if impl in ("ring", "ulysses"):
@@ -176,7 +196,7 @@ def dot_product_attention(
         q, k, v,
         causal=causal, bias=bias, kv_length=kv_length,
         dropout_rate=dropout_rate, dropout_rng=dropout_rng, scale=scale,
-        q_offset=q_offset,
+        q_offset=q_offset, block=block,
     )
 
 

@@ -383,6 +383,14 @@ class OpenAIServer:
             return send_json(422, {"error": {
                 "message": str(e), "type": "invalid_request_error",
                 "code": "invalid_constraint"}})
+        if (automaton is not None
+                and getattr(engine, "block", None) is not None):
+            return send_json(422, {"error": {
+                "message": "structured output is not supported by a "
+                           "block-diffusion model (a block reveals "
+                           "positions out of order)",
+                "type": "invalid_request_error",
+                "code": "invalid_constraint"}})
         if automaton is not None:
             constraint_kind = automaton.kind
             self._note_structured(constraint_kind)
@@ -735,6 +743,34 @@ class OpenAIServer:
                          lambda: eng.prefill_chunk_row_slots,
                          "rows the device computed for those chunks "
                          "(the contiguous slot plane's idle rows included)")
+        blk = getattr(eng, "block", None)
+        if blk is not None:
+            # block-diffusion decoding (serve/block_step.py): passes are
+            # not tokens, so each has a counter of its own; the expert
+            # load is what the block program's routing output counted
+            for name, attr, doc in (
+                    ("llm_block_passes_total", "passes",
+                     "dispatches of the block-diffusion pass program"),
+                    ("llm_block_row_passes_total", "row_passes",
+                     "rows that really advanced in those passes"),
+                    ("llm_blocks_committed_total", "blocks_committed",
+                     "blocks whose K/V were stored and tokens streamed"),
+                    ("llm_block_tokens_committed_total", "tokens_committed",
+                     "tokens streamed out of committed blocks"),
+                    ("llm_moe_assignments_total", "moe_assignments",
+                     "(token, expert) pairs the block passes computed, "
+                     "idle rows of the plane included"),
+                    ("llm_moe_experts_touched_total", "moe_experts_touched",
+                     "distinct experts that received a token, summed over "
+                     "layers and passes"),
+                    ("llm_moe_max_expert_load_total", "moe_max_load",
+                     "the busiest expert's assignments, summed over "
+                     "layers and passes"),
+                    ("llm_moe_mean_expert_load_total", "moe_mean_load",
+                     "assignments / experts held, summed over layers and "
+                     "passes (max / mean = the routing's imbalance)")):
+                reg.counter_func(name,
+                                 lambda a=attr: getattr(blk, a), doc)
         # device plane (obs/cost.py + DispatchMeter.note_phase): live
         # per-phase MFU / HBM-bandwidth-utilization / tokens-per-
         # dispatch — the compute-vs-bandwidth-bound dial. Phases appear

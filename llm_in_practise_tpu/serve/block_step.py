@@ -1,0 +1,471 @@
+"""Block-diffusion decoding in the engine: a pass reveals part of a block,
+it does not commit one token.
+
+A model with ``block_length`` B > 1 (models/sdar_moe.py) generates a block
+of B positions at a time. A slot's decode input is that block: some
+positions revealed, the others ``<|MASK|>``. One **denoise pass** runs the
+block against the slot's stored K/V under the block-causal mask, takes a
+candidate token and its confidence at every masked position, and reveals
+some of them (``low_confidence_static``: the ``B / T`` most confident at
+each of ``T`` steps; ``low_confidence_dynamic``: every one above the
+threshold, or the ``B / T`` most confident if fewer clear it). When the
+input block holds no mask, the pass is a **commit pass**: its K/V are
+stored, its tokens stream to the client, the slot advances by B and opens
+an all-mask block. So passes != tokens: a block costs 2 to ``T + 1``
+passes, tokens reach the client B at a time, and TTFT is "first block
+committed".
+
+:class:`BlockDecoder` is what ``InferenceEngine`` becomes for such a
+model: the engine keeps admission, buckets, the page pool, one-shot and
+chunked prefill (over the prompt's WHOLE blocks; the ``P mod B``
+remaining prompt tokens open the first block as already-revealed
+positions) and the finish funnel; this class owns the per-slot block
+state and the one jitted program of a dispatch (``_paged_block_fn``).
+Rows are at independent phases: in one dispatch some rows commit and the
+others reveal.
+
+The K/V of a denoise pass are used inside the pass and not kept: the
+host routes their write-back to the trash page (``valid`` = 0), so only a
+commit pass's window reaches the slot's pages.
+
+The engine keeps each block's revealed flags ITSELF and never infers
+them from ``token == mask id``: a prompt that happens to hold that id is
+served like any other.
+
+What a block-diffusion engine refuses, at build or at submit, is listed
+in :meth:`BlockDecoder.check_engine` and :meth:`BlockDecoder.check_submit`.
+"""
+
+from __future__ import annotations
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from llm_in_practise_tpu.infer.sampling import sample_token_batched
+from llm_in_practise_tpu.models.sdar_moe import REMASKING
+
+
+def unwrap(model):
+    """The model behind the serving facades (each holds it as ``inner``
+    or ``model``)."""
+    while True:
+        inner = next((m for m in (getattr(model, a, None)
+                                  for a in ("inner", "model"))
+                      if hasattr(m, "apply")), None)
+        if inner is None:
+            return model
+        model = inner
+
+
+def block_length_of(model) -> int:
+    return int(getattr(unwrap(model), "block_length", 1))
+
+
+def reveal_quota(block: int, steps: int, step: int) -> int:
+    """Positions the static rule reveals at denoise step ``step`` (0-based)
+    of ``steps``: ``block // steps``, the remainder spread over the first
+    steps (the family's ``get_num_transfer_tokens``)."""
+    return block // steps + (1 if step < block % steps else 0)
+
+
+def reveal(cand, conf, tokens, revealed, quota, threshold, dynamic):
+    """The reveal rule on the device, all rows at once. ``cand`` / ``conf``
+    (S, B): candidate token and its confidence at every position;
+    ``tokens`` / ``revealed`` (S, B): the input block; ``quota`` (S,)
+    int, ``threshold`` (S,) float, ``dynamic`` (S,) bool per row. Returns
+    the block after the pass ``(tokens, revealed)``. A row whose block
+    held no mask comes back unchanged."""
+    masked = ~revealed
+    conf_m = jnp.where(masked, conf, -jnp.inf)
+    # rank of each position among its row's masked ones, most confident
+    # first (ties: the earlier position)
+    rank = jnp.argsort(jnp.argsort(-conf_m, axis=1, stable=True), axis=1)
+    top = masked & (rank < quota[:, None])
+    high = masked & (conf > threshold[:, None])
+    use_high = dynamic & (jnp.sum(high, axis=1) >= quota)
+    now = jnp.where(use_high[:, None], high, top)
+    return jnp.where(now, cand, tokens), revealed | now
+
+
+class BlockDecoder:
+    """Per-slot block state + the block step of one ``InferenceEngine``."""
+
+    def __init__(self, engine):
+        self.eng = engine
+        core = unwrap(engine.model)
+        self.B = int(core.block_length)
+        self.mask_id = int(core.mask_token_id)
+        cfg = core.config
+        self.default_steps = int(getattr(cfg, "denoising_steps", self.B))
+        self.default_rule = getattr(cfg, "remasking", REMASKING[0])
+        self.default_threshold = float(
+            getattr(cfg, "confidence_threshold", 0.9))
+        self.n_experts = int(getattr(cfg, "n_experts", 0))  # 0: no routing
+        S, B = engine.max_slots, self.B
+        self.tok = np.zeros((S, B), np.int32)
+        self.rev = np.zeros((S, B), bool)
+        self.passes_in_block = np.zeros((S,), np.int32)
+        self.block_no = np.zeros((S,), np.int32)
+        # leading positions of the slot's FIRST block that are prompt
+        # remainder: revealed from the start and never emitted
+        self.keep = np.zeros((S,), np.int32)
+        self.steps = np.full((S,), self.default_steps, np.int32)
+        self.dynamic = np.zeros((S,), bool)
+        self.threshold = np.full((S,), self.default_threshold, np.float32)
+        # lifetime counters (engine-thread writes, scrape-side reads of
+        # monotone numbers: the spec_* counter convention)
+        self.passes = 0             # dispatches of the block program
+        self.row_passes = 0         # rows that really advanced in them
+        self.blocks_committed = 0
+        self.tokens_committed = 0   # tokens streamed out of commits
+        self.tokens_revealed = 0
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
+        self.moe_max_load = 0
+        self.moe_mean_load = 0.0
+        # reference comparisons (tests, the benchmark's check) set this
+        # to a list: the step then also FETCHES the pass's logits (the
+        # one program returns them always, as a device array nothing else
+        # reads), and each advancing row appends {uid, block, pass,
+        # commit, logits (B, vocab), experts (layers, B, k) | None}.
+        # None: nothing kept.
+        self.capture = None
+        # device copies of the per-row sampling and schedule arrays: they
+        # change at activation only, not every pass
+        self._row_params = None
+        _c = lambda fn: engine.dispatch_meter.wrap(  # noqa: E731
+            engine.compile_meter.wrap(fn))
+        self._pg_block = _c(jax.jit(
+            self._paged_block_fn, donate_argnums=(1,)))
+
+    # --- what a block-diffusion engine refuses -------------------------------
+
+    @staticmethod
+    def check_engine(engine) -> None:
+        """Build-time refusals: every engine feature whose meaning rests
+        on "one pass = one committed token, causal attention" and that
+        has no block form yet."""
+        B = block_length_of(engine.model)
+
+        def no(what: str, why: str):
+            raise ValueError(
+                f"block-diffusion model (block_length={B}): {what} is not "
+                f"supported — {why}")
+
+        if engine.paged is None:
+            no("kv_layout='contiguous'",
+               "the block step is written against the page pool; use "
+               "kv_layout='paged'")
+        if engine.speculative_k is not None or engine.draft_model is not None:
+            no("speculative decoding",
+               "a draft verifies next-token guesses; a block pass reveals "
+               "positions of a block")
+        if engine.decode_steps != 1:
+            no(f"decode_steps={engine.decode_steps}",
+               "several passes under one lax.scan is future work "
+               "(PERF.md, Open questions)")
+        if engine.prefix_cache is not None or engine.kv_pool is not None:
+            no("prefix caching / tiered KV",
+               "pages are shared per 16 causal positions; block-causal "
+               "K/V of a partial block depend on the block's other tokens")
+        if engine.session_store is not None:
+            no("the session store", "it pins prefix pages (see above)")
+        if engine.adapter_registry is not None:
+            no("multi-LoRA", "the block program has no adapter twin")
+        if engine.role != "both" or engine.handoff is not None:
+            no("disaggregated prefill/decode",
+               "a handed-off entry ends in last-position logits, which a "
+               "block-diffusion decode never samples from")
+        if engine.tp > 1:
+            no("tensor parallelism",
+               "the grouped expert kernel is not partitioned; experts "
+               "sharded over chips are future work")
+        pool_tokens = engine.paged.pool.capacity * engine.paged.page_size
+        if pool_tokens < engine.max_slots * engine.cache_len:
+            no(f"a page pool of {pool_tokens} tokens, below max_slots x "
+               "cache_len", "a dry pool preempts by recompute, which "
+               "resumes from ONE pending token, not a half-revealed block")
+        if engine.cache_len % B:
+            no(f"cache_len={engine.cache_len}",
+               f"it must be a multiple of the block length {B}")
+        if engine.chunked_prefill is not None and engine.chunked_prefill % B:
+            no(f"chunked_prefill={engine.chunked_prefill}",
+               f"a chunk must be a multiple of the block length {B}")
+        for b in engine.buckets:
+            if b % B:
+                no(f"prefill bucket {b}",
+                   f"buckets must be multiples of the block length {B}")
+
+    def check_submit(self, params, *, kv_entry, handoff_id, adapter,
+                     session_id) -> None:
+        """Submit-time refusals (raised on the caller's thread, before
+        anything is queued)."""
+        def no(what: str):
+            raise ValueError(
+                f"block-diffusion model (block_length={self.B}): {what}")
+
+        if params.constraint is not None:
+            no("grammar-constrained decoding is not supported (the mask "
+               "encodes one automaton state per next token; a block "
+               "reveals positions out of order)")
+        if kv_entry is not None or handoff_id is not None:
+            no("handed-off KV / prefill-only requests are not supported")
+        if adapter is not None:
+            no("LoRA adapters are not supported")
+        if session_id is not None:
+            no("sessions are not supported")
+        steps, rule, _ = self._schedule(params)
+        if not 1 <= steps <= self.B:
+            no(f"denoising_steps must be in [1, {self.B}], got {steps}")
+        if rule not in REMASKING:
+            no(f"remasking must be one of {REMASKING}, got {rule!r}")
+
+    def _schedule(self, params):
+        steps = (self.default_steps if params.denoising_steps is None
+                 else int(params.denoising_steps))
+        rule = params.remasking or self.default_rule
+        thr = (self.default_threshold if params.confidence_threshold is None
+               else float(params.confidence_threshold))
+        return steps, rule, thr
+
+    # --- the jitted program ---------------------------------------------------
+
+    def _paged_block_fn(self, params, pool, gidx, index_vec, sidx, tokens,
+                        revealed, rng, temperature, top_k, top_p, greedy,
+                        quota, threshold, dynamic):
+        """One pass over the slot plane, ONE dispatch: gather every
+        slot's pages into a view pinned at its committed length (always a
+        multiple of B), forward the (slots, B) blocks (unrevealed
+        positions fed as the mask id), sample a candidate and its
+        confidence at every position, apply the reveal rule, and write
+        the B-wide window back — to the slot's pages for rows that commit,
+        to the trash page for all others (the host built ``sidx`` so).
+        Returns ``(tokens, revealed, experts, logits, pool)``: the
+        blocks after the pass, the experts every position of the plane
+        chose in every layer, ``(layers, slots * B, k)``, and the float32
+        logits ``(slots, B, vocab)``, which stay on the device unless a
+        reference comparison fetches them (``capture``): there is ONE
+        program, so what is compared is what serves."""
+        eng = self.eng
+        S, B = tokens.shape
+        view = eng._paged_view(pool, gidx, index_vec)
+        ids = jnp.where(revealed, tokens, self.mask_id)
+        (logits, view), aux = eng.model.apply(
+            {"params": params}, ids, deterministic=True, cache=view,
+            mutable=["routing"])
+        logits = logits.astype(jnp.float32)
+        flat = logits.reshape(S * B, -1)
+        rep = lambda a: jnp.repeat(a, B)  # noqa: E731
+        cand = jax.lax.cond(
+            # when every LIVE row (quota > 0) is greedy the pass skips the
+            # sampler's full-vocabulary sort; an idle row's flag is
+            # whatever its last request left, or the initial False
+            jnp.all(greedy | (quota == 0)),
+            lambda: jnp.argmax(flat, axis=-1),
+            lambda: sample_token_batched(
+                rng, flat, temperature=rep(temperature), top_k=rep(top_k),
+                top_p=rep(top_p), greedy=rep(greedy)),
+        ).astype(jnp.int32).reshape(S, B)
+        # confidence: the candidate's probability under softmax(logits)
+        picked = jnp.take_along_axis(logits, cand[..., None], axis=-1)[..., 0]
+        conf = jnp.exp(picked - jax.nn.logsumexp(logits, axis=-1))
+        new_tok, new_rev = reveal(cand, conf, tokens, revealed, quota,
+                                  threshold, dynamic)
+        routing = aux.get("routing", {})
+        chosen = [routing[name]["moe"]["experts"][0]
+                  for name in sorted(routing, key=lambda n: int(
+                      n.rsplit("_", 1)[1]))]
+        experts = (jnp.stack(chosen) if chosen
+                   else jnp.zeros((0, S * B, 1), jnp.int32))
+        pool = eng._paged_writeback(pool, view, sidx, index_vec)
+        return new_tok, new_rev, experts, logits, pool
+
+    # --- slot life cycle ------------------------------------------------------
+
+    def split_prompt(self, prompt_ids: list[int]):
+        """(whole blocks to prefill, remainder that opens the first
+        generated block)."""
+        whole = len(prompt_ids) // self.B * self.B
+        return prompt_ids[:whole], prompt_ids[whole:]
+
+    def activate(self, slot: int, req, plen: int) -> None:
+        """The prompt's whole blocks are in the slot's pages: open the
+        first generated block. Nothing is sampled and nothing emitted;
+        the request's first token arrives with its first commit."""
+        eng = self.eng
+        steps, rule, thr = self._schedule(req.params)
+        eng.slot_req[slot] = req
+        eng.slot_ready[slot] = True
+        eng.slot_len[slot] = plen
+        eng.slot_budget[slot] = req.params.max_tokens
+        eng._temperature[slot] = req.params.temperature
+        eng._top_k[slot] = req.params.top_k
+        eng._top_p[slot] = req.params.top_p
+        eng._greedy[slot] = req.params.greedy
+        eng.slot_hist[slot] = None
+        eng.slot_constraint[slot] = None
+        self.steps[slot] = steps
+        self.dynamic[slot] = rule == REMASKING[1]
+        self.threshold[slot] = thr
+        self._row_params = None
+        self.block_no[slot] = 0
+        self._open_block(slot, req.block_open)
+
+    def _open_block(self, slot: int, opening=()) -> None:
+        """A fresh block: ``opening`` tokens revealed at its head (the
+        prompt's remainder, never emitted), the rest masked."""
+        r = len(opening)
+        self.tok[slot] = 0
+        self.tok[slot, :r] = opening
+        self.rev[slot] = np.arange(self.B) < r
+        self.keep[slot] = r
+        self.passes_in_block[slot] = 0
+
+    # --- the step ---------------------------------------------------------------
+
+    def step(self, active: list[int]) -> None:
+        """One block pass over every ready slot (the caller holds the
+        engine's step lock and has advanced the prefills)."""
+        eng, B = self.eng, self.B
+        st = eng.steptrace
+        with st.scope("admit"):
+            for s in list(active):
+                if int(eng.slot_len[s]) + B > eng.cache_len:
+                    eng._finish_slot(s, "cache")    # no room for a block
+                    active.remove(s)
+            active = eng._paged_reserve_active(active, B)
+        if not active:
+            return
+        with st.scope("index_build"):
+            eng.rng, sub = jax.random.split(eng.rng)
+            W = eng._paged_width(
+                max(int(eng.slot_len[s]) for s in active) + B)
+            eng._pulse_view(W)
+            idxv = eng._paged_index_vec(W, B)
+            commits = [s for s in active if self.rev[s].all()]
+            valid = np.zeros((eng.max_slots,), np.int32)
+            valid[commits] = B
+            quota = np.zeros((eng.max_slots,), np.int32)    # 0: idle row
+            for s in active:
+                quota[s] = reveal_quota(B, int(self.steps[s]),
+                                        int(self.passes_in_block[s]))
+            capture = self.capture is not None
+            if self._row_params is None:
+                self._row_params = tuple(jnp.asarray(a) for a in (
+                    eng._temperature, eng._top_k, eng._top_p, eng._greedy,
+                    self.threshold, self.dynamic))
+            *sampling, threshold, dynamic = self._row_params
+        with st.scope("dispatch_wait"):
+            st.window_begin("decode")
+            out = self._pg_block(
+                eng.params, eng.paged.kv,
+                jnp.asarray(eng.paged.gather_idx(W)), jnp.asarray(idxv),
+                jnp.asarray(eng.paged.scatter_idx(idxv, valid, B)),
+                jnp.asarray(self.tok), jnp.asarray(self.rev), sub,
+                *sampling, jnp.asarray(quota), threshold, dynamic)
+            new_tok, new_rev, experts, logits, eng.paged.kv = out
+            st.window_issued()
+            # the pass's result: the one fetch the step blocks on
+            new_tok, new_rev, experts = jax.device_get(  # graftlint: disable=host-sync
+                (new_tok, new_rev, experts))
+            if capture:     # reference comparisons only
+                logits = np.asarray(logits)  # graftlint: disable=host-sync
+            dt, _ = eng._window_close(
+                "decode", [eng.slot_req[s] for s in active])
+            eng.dispatch_meter.note_phase(
+                "block", tokens=B * len(active), duration_s=dt, mfu=None,
+                hbm_bw_util=None)
+        with st.scope("sample_commit"):
+            self._book_routing(experts)
+            n_rev = n_out = 0
+            for s in active:
+                req = eng.slot_req[s]
+                req.block_passes += 1
+                if capture:
+                    self.capture.append({
+                        "uid": req.uid, "block": int(self.block_no[s]),
+                        "pass": int(self.passes_in_block[s]),
+                        "commit": s in commits,
+                        "logits": logits[s].copy(),
+                        "experts": (experts[:, s * B:(s + 1) * B].copy()
+                                    if experts.shape[0] else None)})
+                if s in commits:
+                    n_out += self._commit(s)
+                    continue
+                newly = new_rev[s] & ~self.rev[s]
+                for j in np.flatnonzero(newly):
+                    req.reveal_log.append(
+                        (int(self.block_no[s]), int(self.passes_in_block[s]),
+                         int(j), int(new_tok[s, j])))
+                n_rev += int(newly.sum())
+                self.tok[s] = new_tok[s]
+                self.rev[s] = new_rev[s]
+                self.passes_in_block[s] += 1
+            self.passes += 1
+            self.row_passes += len(active)
+            self.blocks_committed += len(commits)
+            self.tokens_revealed += n_rev
+            self.tokens_committed += n_out
+            st.note_block_pass(len(active), len(commits), n_rev, n_out)
+
+    def _commit(self, slot: int) -> int:
+        """The slot's finished block has its K/V stored: stream its
+        tokens (not the prompt's remainder), up to ``max_tokens`` or EOS,
+        then open the next block. Returns the tokens streamed."""
+        eng, B = self.eng, self.B
+        req = eng.slot_req[slot]
+        eng.slot_len[slot] += B
+        sent = 0
+        for j in range(int(self.keep[slot]), B):
+            tok = int(self.tok[slot, j])
+            if eng.eos_id is not None and tok == eng.eos_id:
+                eng._finish_slot(slot, "stop")
+                return sent
+            if req.first_token_time is None:
+                req.first_token_time = time.monotonic()
+            req.tokens.put(tok)
+            req.n_generated += 1
+            sent += 1
+            eng.slot_budget[slot] -= 1
+            if eng.slot_budget[slot] <= 0:
+                eng._finish_slot(slot, "length")
+                return sent
+        if int(eng.slot_len[slot]) + B > eng.cache_len:
+            eng._finish_slot(slot, "cache")
+            return sent
+        self.block_no[slot] += 1
+        self._open_block(slot)
+        return sent
+
+    def _book_routing(self, experts: np.ndarray) -> None:
+        """Expert load of one pass, over the WHOLE plane the device
+        computed (idle rows route too, and stream their experts'
+        weights): assignments, distinct experts that received a token,
+        and the busiest expert's load, summed over layers."""
+        layers, n_exp = experts.shape[0], self.n_experts
+        if not layers or not n_exp:
+            return
+        counts = np.bincount(
+            (experts.reshape(layers, -1)
+             + np.arange(layers)[:, None] * n_exp).ravel(),
+            minlength=layers * n_exp).reshape(layers, n_exp)
+        self.moe_assignments += int(counts.sum())
+        self.moe_experts_touched += int((counts > 0).sum())
+        self.moe_max_load += int(counts.max(axis=1).sum())
+        self.moe_mean_load += float(counts.sum()) / n_exp
+
+    def counters(self) -> dict:
+        return {
+            "block_passes": self.passes,
+            "block_row_passes": self.row_passes,
+            "blocks_committed": self.blocks_committed,
+            "block_tokens_committed": self.tokens_committed,
+            "block_tokens_revealed": self.tokens_revealed,
+            "moe_assignments": self.moe_assignments,
+            "moe_experts_touched": self.moe_experts_touched,
+            "moe_max_expert_load": self.moe_max_load,
+            "moe_mean_expert_load": self.moe_mean_load,
+        }

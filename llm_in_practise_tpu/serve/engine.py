@@ -82,6 +82,14 @@ class SamplingParams:
     # dispatch. None = unconstrained (the exact pre-constraint
     # programs run; golden tokens are bit-identical).
     constraint: Any = None
+    # Block-diffusion models only (serve/block_step.py); None = the
+    # model's own default. ``denoising_steps``: denoise passes a block of
+    # B positions takes under the static rule (1..B); ``remasking``:
+    # "low_confidence_static" | "low_confidence_dynamic";
+    # ``confidence_threshold``: the dynamic rule's reveal threshold.
+    denoising_steps: int | None = None
+    remasking: str | None = None
+    confidence_threshold: float | None = None
 
 
 _FINISH = object()  # sentinel closing a request's token queue
@@ -237,6 +245,20 @@ class Request:
         default=None, repr=False, compare=False)
     api_first_flush_time: float | None = dataclasses.field(
         default=None, repr=False, compare=False)
+    # Block-diffusion decoding (serve/block_step.py). ``prompt_ids``
+    # then holds the prompt's WHOLE blocks (what prefill stores) and
+    # ``block_open`` the ``P mod B`` tokens left over, which open the
+    # first generated block as revealed positions. ``reveal_log``: one
+    # ``(block, pass, position, token)`` per position a denoise pass
+    # revealed (a few ints, always on: the reference comparison replays
+    # it); ``block_passes``: dispatches of the block program this
+    # request rode.
+    block_open: list = dataclasses.field(
+        default_factory=list, repr=False, compare=False)
+    reveal_log: list = dataclasses.field(
+        default_factory=list, repr=False, compare=False)
+    block_passes: int = dataclasses.field(
+        default=0, repr=False, compare=False)
 
     def cp_add(self, seg: str, dt: float) -> None:
         """Accumulate ``dt`` seconds into critical-path segment ``seg``.
@@ -1064,6 +1086,21 @@ class InferenceEngine:
                 self._pg_mixed_masked_lora = _c(jax.jit(
                     lora_wrap(self._paged_mixed_masked_fn),
                     donate_argnums=(1,), static_argnames=("n",)))
+        # Block-diffusion decoding (serve/block_step.py): a model with
+        # block_length > 1 reveals part of a block a pass instead of
+        # committing one token. The engine keeps admission, prefill and
+        # the finish funnel; the decoder owns the block state and the
+        # one block program. Everything that has no block form yet is
+        # refused here, with its reason.
+        from llm_in_practise_tpu.serve.block_step import (
+            BlockDecoder,
+            block_length_of,
+        )
+
+        self.block = None
+        if block_length_of(model) > 1:
+            BlockDecoder.check_engine(self)
+            self.block = BlockDecoder(self)
 
     # --- jitted pieces -------------------------------------------------------
 
@@ -2017,9 +2054,15 @@ class InferenceEngine:
         max_prompt = self.cache_len - 2
         if len(prompt_ids) > max_prompt:  # sliding-window crop (reference
             prompt_ids = prompt_ids[-max_prompt:]  # minigpt/generate.py:18-20)
+        block_open = []
+        if self.block is not None:
+            self.block.check_submit(params, kv_entry=kv_entry,
+                                    handoff_id=handoff_id, adapter=adapter,
+                                    session_id=session_id)
+            prompt_ids, block_open = self.block.split_prompt(prompt_ids)
         req = Request(next(self._uid), prompt_ids, params, engine=self,
                       handoff_id=handoff_id, trace=trace, adapter=adapter,
-                      session_id=session_id)
+                      session_id=session_id, block_open=block_open)
         if session_id is not None and self.session_store is not None:
             self.session_store.touch(session_id)
         if (self.paged is not None
@@ -2485,32 +2528,39 @@ class InferenceEngine:
                         self.cache = self._insert_batch(
                             self.cache, pre, jnp.asarray(slot_ids),
                             jnp.asarray(lens))
-                    self.rng, sub = jax.random.split(self.rng)
-                    logits = last.astype(jnp.float32)
-                    if any(r.params.constraint is not None
-                           for _, r, _ in part):
-                        # constrained members' first tokens obey their
-                        # grammar start states; zero rows leave the
-                        # rest of the batch untouched
-                        logits = logits + self._grammar_mask_rows(
-                            [self._ensure_constraint(r)
-                             for _, r, _ in part])
-                    first = sample_token_batched(
-                        sub, logits,
-                        temperature=jnp.asarray(
-                            [r.params.temperature for _, r, _ in part],
-                            jnp.float32),
-                        top_k=jnp.asarray(
-                            [r.params.top_k for _, r, _ in part],
-                            jnp.int32),
-                        top_p=jnp.asarray(
-                            [r.params.top_p for _, r, _ in part],
-                            jnp.float32),
-                        greedy=jnp.asarray(
-                            [r.params.greedy for _, r, _ in part], bool),
-                    )
+                    first = None
+                    if self.block is None:
+                        self.rng, sub = jax.random.split(self.rng)
+                        logits = last.astype(jnp.float32)
+                        if any(r.params.constraint is not None
+                               for _, r, _ in part):
+                            # constrained members' first tokens obey their
+                            # grammar start states; zero rows leave the
+                            # rest of the batch untouched
+                            logits = logits + self._grammar_mask_rows(
+                                [self._ensure_constraint(r)
+                                 for _, r, _ in part])
+                        first = sample_token_batched(
+                            sub, logits,
+                            temperature=jnp.asarray(
+                                [r.params.temperature for _, r, _ in part],
+                                jnp.float32),
+                            top_k=jnp.asarray(
+                                [r.params.top_k for _, r, _ in part],
+                                jnp.int32),
+                            top_p=jnp.asarray(
+                                [r.params.top_p for _, r, _ in part],
+                                jnp.float32),
+                            greedy=jnp.asarray(
+                                [r.params.greedy for _, r, _ in part], bool),
+                        )
                     self.steptrace.window_issued()
-                    first = np.asarray(first)   # forces the chain
+                    if first is None:
+                        # nothing is sampled from a block-diffusion
+                        # prefill: the first block opens all-mask
+                        jax.block_until_ready(last)
+                    else:
+                        first = np.asarray(first)   # forces the chain
                     # every member waited the whole batched dispatch
                     dt, _ = self._window_close(
                         "prefill", [r for _, r, _ in part])
@@ -2548,6 +2598,8 @@ class InferenceEngine:
                             self._complete_handoff(slot, req, plen,
                                                    last[j:j + 1],
                                                    rows=row_slices)
+                        elif first is None:
+                            self.block.activate(slot, req, plen)
                         else:
                             self._activate_with_token(slot, req, plen,
                                                       int(first[j]))
@@ -2667,6 +2719,8 @@ class InferenceEngine:
         if req.handoff_id is not None:
             return self._complete_handoff(slot, req, plen, last_logits,
                                           rows=rows)
+        if self.block is not None:
+            return self.block.activate(slot, req, plen)
         if req.resume_last is not None:
             # preemption resume: the "next" token was already emitted
             # before the preempt — no sampling, no rng split (the
@@ -4137,6 +4191,18 @@ class InferenceEngine:
         with self.steptrace.scope("admit"):
             self._admit()
         budget = self.prefill_budget
+        if self.block is not None:
+            # block-diffusion model: chunks and block rows take two
+            # dispatches (no fused mixed block step yet), and one pass
+            # over the ready rows replaces the decode families below
+            progressed = self._advance_prefills(budget)
+            with self.steptrace.scope("plan"):
+                active = self._ready_slots()
+            if not active:
+                return progressed or bool(self.slot_prefill)
+            self.block.step(active)
+            self._update_active_stats()
+            return True
         # A speculative engine at decode_steps=1 keeps speculating
         # while prompts prefill (the r5 composition): its verify step
         # yields 1+accepted tokens per dispatch, strictly more than the

@@ -101,3 +101,24 @@ def test_flash_attention_compiles(chip, grad):
     text = _compile(bwd if grad else fwd, q, q, q)
     # forward + dK/dV + dQ kernels in the backward program
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("tokens", [64, 4096])
+def test_grouped_expert_layer_compiles(chip, tokens, monkeypatch):
+    """The dropless expert layer at SDAR-30B-A3B's widths (128 experts of
+    2048 x 768, top-8): a block-decode pass's 64 tokens and a batched
+    prefill's 4,096. The kernel's whole-K, whole-N tiles must fit the
+    chip's fast memory."""
+    from llm_in_practise_tpu.ops import grouped_experts as ge
+
+    # the CPU backend would pick interpret mode; compile the kernel itself
+    monkeypatch.setattr(ge, "interpret_default", lambda: False)
+    e, h, w, k = 128, 2048, 768, 8
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    text = _compile(
+        ge.grouped_expert_ffn, s((tokens, h), jnp.bfloat16),
+        s((tokens, k), jnp.int32), s((tokens, k), jnp.float32),
+        s((e, h, w), jnp.bfloat16), s((e, h, w), jnp.bfloat16),
+        s((e, w, h), jnp.bfloat16))
+    assert text.count("tpu_custom_call") >= 3
