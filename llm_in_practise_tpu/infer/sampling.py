@@ -43,6 +43,26 @@ def sample_token(
     return jax.random.categorical(rng, logits, axis=-1)
 
 
+SAMPLER_TIERS = ("argmax", "plain", "filtered")
+
+
+def sampler_tier(greedy, top_k, top_p):
+    """Index into :data:`SAMPLER_TIERS` of the cheapest body of
+    :func:`sample_token_batched` that gives these rows their tokens: 0
+    when every row is greedy, 1 when no sampled row filters, else 2. The
+    one place that decides: the sampler switches on it inside the
+    compiled program, and the engine books the same function of the same
+    flags (numpy arrays, no device fetch: :func:`sampler_tier_name`) into
+    its step records."""
+    filters = ~greedy & ((top_k > 0) | (top_p < 1.0))
+    return (~greedy).any().astype("int32") + filters.any().astype("int32")
+
+
+def sampler_tier_name(greedy, top_k, top_p) -> str:
+    """:func:`sampler_tier` by name, for host-side (numpy) flags."""
+    return SAMPLER_TIERS[int(sampler_tier(greedy, top_k, top_p))]
+
+
 def sample_token_batched(
     rng: jax.Array,
     logits: jax.Array,
@@ -58,7 +78,34 @@ def sample_token_batched(
     settings, so all params are ``(B,)`` vectors: ``temperature`` floats,
     ``top_k`` ints (0 disables), ``top_p`` floats (>=1.0 disables),
     ``greedy`` bools. logits: ``(B, vocab)``. Jittable, static shapes.
+
+    The rows' own flags pick the body (:func:`sampler_tier`); every body
+    returns, bit for bit, what ``filtered`` returns for the same input,
+    so a row's token does not depend on which one its neighbours forced:
+    a greedy row's is ``argmax(logits)``, and a row without filters
+    passes ``scaled`` through both of ``filtered``'s masks untouched into
+    the same ``categorical`` draw (the noise depends on the key and the
+    plane's shape alone). A caller that wants rows ignored (idle slots)
+    passes them as greedy.
     """
+    return jax.lax.switch(
+        sampler_tier(greedy, top_k, top_p), (_argmax, _plain, _filtered),
+        rng, logits, temperature, top_k, top_p, greedy)
+
+
+def _argmax(rng, logits, temperature, top_k, top_p, greedy):
+    return jnp.argmax(logits, axis=-1)
+
+
+def _plain(rng, logits, temperature, top_k, top_p, greedy):
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    sampled = jax.random.categorical(rng, scaled, axis=-1)
+    return jnp.where(greedy, jnp.argmax(logits, axis=-1), sampled)
+
+
+def _filtered(rng, logits, temperature, top_k, top_p, greedy):
+    """Temperature, row-wise top-k and top-p over one full-vocabulary
+    sort, then the draw: the reference the cheaper bodies must equal."""
     n_vocab = logits.shape[-1]
     scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
 

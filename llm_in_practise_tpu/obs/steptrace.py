@@ -179,6 +179,7 @@ class StepTrace:
         self._chunk_rows = 0
         self._chunk_row_slots = 0
         self._block = [0, 0, 0, 0]   # rows, commits, revealed, committed
+        self._sampler_tier: str | None = None
         self._last_end: float | None = None   # previous step_end, perf
         # the open dispatch window (``_win_t0`` None: none open); the
         # annotation is that of the part under way, issue then wait
@@ -194,6 +195,7 @@ class StepTrace:
         self._step_wall_total = 0.0
         self._device_seconds_total = 0.0
         self._issue_seconds_total = 0.0
+        self._sampler_steps: dict[str, int] = {}
         # rolling fractions over the last `window` steps (cached floats,
         # same convention as DispatchMeter.per_step)
         self._window = window
@@ -311,6 +313,15 @@ class StepTrace:
             for i, v in enumerate((rows, commits, revealed, committed)):
                 self._block[i] += v
 
+    def note_sampler_tier(self, tier: str) -> None:
+        """This step's decode, fused mixed or block program runs the
+        sampler's ``tier`` body (``infer/sampling.py::SAMPLER_TIERS``),
+        as the host worked out from the flags it hands the program: the
+        record's ``sampler_tier``, ``None`` for a step that dispatched
+        no such program."""
+        if self._recording:
+            self._sampler_tier = tier
+
     def step_begin(self, *, lock_wait_s: float = 0.0) -> None:
         """Open a step record. ``lock_wait_s``: what the caller waited
         for the engine's step lock before this call (a record field, not
@@ -327,6 +338,7 @@ class StepTrace:
         self._chunk_rows = 0
         self._chunk_row_slots = 0
         self._block = [0, 0, 0, 0]
+        self._sampler_tier = None
         self._acts = {}
         self._device_s = 0.0
         self._issue_s = 0.0
@@ -385,6 +397,7 @@ class StepTrace:
             "block_commits": self._block[1],
             "tokens_revealed": self._block[2],
             "tokens_committed": self._block[3],
+            "sampler_tier": self._sampler_tier,
             "activities": dict(self._acts),
             "segments": [(name, t0 + off, t1 + off)
                          for name, t0, t1 in self._segments],
@@ -397,6 +410,9 @@ class StepTrace:
         self._step_wall_total += wall
         self._device_seconds_total += self._device_s
         self._issue_seconds_total += self._issue_s
+        if self._sampler_tier is not None:
+            self._sampler_steps[self._sampler_tier] = (
+                self._sampler_steps.get(self._sampler_tier, 0) + 1)
         self._busy_roll.append((wall, self._device_s))
         self._step_t0 = None
         self._last_end = end
@@ -423,6 +439,7 @@ class StepTrace:
             "dispatch_issue_seconds_total": self._issue_seconds_total,
             "dispatch_wait_seconds_total": dev - self._issue_seconds_total,
             "host_seconds": dict(self._host_seconds),
+            "sampler_steps": dict(self._sampler_steps),
             # rolling over the last `window` steps — the live dial. A
             # recorder that measured nothing (fresh, idle, or disabled)
             # reports 0 host gap, NOT 1 − busy = 1.0: "the chip waits

@@ -42,6 +42,7 @@ from http.server import ThreadingHTTPServer
 import numpy as np
 
 from llm_in_practise_tpu.data.sft import IM_START, render_chatml
+from llm_in_practise_tpu.infer.sampling import SAMPLER_TIERS
 from llm_in_practise_tpu.obs.hbm import (
     get_ledger,
     host_entry_bytes,
@@ -909,6 +910,14 @@ class OpenAIServer:
             "llm_engine_steps_total",
             lambda: stp.snapshot()["steps"],
             "non-idle engine step() iterations recorded")
+        reg.counter_func(
+            "llm_sampler_steps_total",
+            lambda: [({"tier": t}, stp.snapshot()["sampler_steps"].get(t, 0))
+                     for t in SAMPLER_TIERS],
+            "engine steps whose decode, fused mixed or block program ran "
+            "the sampler, by the body its live rows' flags chose (argmax: "
+            "all greedy; plain: temperature only; filtered: the "
+            "full-vocabulary sort for top-k / top-p)")
         reg.counter_func(
             "llm_dispatch_issue_seconds_total",
             lambda: stp.snapshot()["dispatch_issue_seconds_total"],

@@ -44,7 +44,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from llm_in_practise_tpu.infer.sampling import sample_token_batched
+from llm_in_practise_tpu.infer.sampling import (
+    sample_token_batched,
+    sampler_tier_name,
+)
 from llm_in_practise_tpu.models.sdar_moe import REMASKING
 
 
@@ -259,15 +262,12 @@ class BlockDecoder:
         logits = logits.astype(jnp.float32)
         flat = logits.reshape(S * B, -1)
         rep = lambda a: jnp.repeat(a, B)  # noqa: E731
-        cand = jax.lax.cond(
-            # when every LIVE row (quota > 0) is greedy the pass skips the
-            # sampler's full-vocabulary sort; an idle row's flag is
-            # whatever its last request left, or the initial False
-            jnp.all(greedy | (quota == 0)),
-            lambda: jnp.argmax(flat, axis=-1),
-            lambda: sample_token_batched(
-                rng, flat, temperature=rep(temperature), top_k=rep(top_k),
-                top_p=rep(top_p), greedy=rep(greedy)),
+        # idle rows (quota 0) go in as greedy: what a finished request left
+        # in their flags must not choose the sampler's body, and when every
+        # LIVE row is greedy the pass skips the full-vocabulary sort
+        cand = sample_token_batched(
+            rng, flat, temperature=rep(temperature), top_k=rep(top_k),
+            top_p=rep(top_p), greedy=rep(greedy | (quota == 0)),
         ).astype(jnp.int32).reshape(S, B)
         # confidence: the candidate's probability under softmax(logits)
         picked = jnp.take_along_axis(logits, cand[..., None], axis=-1)[..., 0]
@@ -358,6 +358,8 @@ class BlockDecoder:
                     eng._temperature, eng._top_k, eng._top_p, eng._greedy,
                     self.threshold, self.dynamic))
             *sampling, threshold, dynamic = self._row_params
+            st.note_sampler_tier(sampler_tier_name(
+                eng._greedy | (quota == 0), eng._top_k, eng._top_p))
         with st.scope("dispatch_wait"):
             st.window_begin("decode")
             out = self._pg_block(

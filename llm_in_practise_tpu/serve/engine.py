@@ -44,7 +44,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from llm_in_practise_tpu.infer.generate import max_positions
-from llm_in_practise_tpu.infer.sampling import sample_token_batched
+from llm_in_practise_tpu.infer.sampling import (
+    sample_token_batched,
+    sampler_tier_name,
+)
 from llm_in_practise_tpu.obs.cost import CostModel, tree_bytes
 from llm_in_practise_tpu.obs.hbm import get_ledger, host_entry_bytes
 from llm_in_practise_tpu.obs.logging import get_logger
@@ -93,6 +96,17 @@ class SamplingParams:
 
 
 _FINISH = object()  # sentinel closing a request's token queue
+
+# Prompt tokens one step's chunks may hold in the PAGED layout, whose
+# chunk and fused mixed programs pay one trip a chunking row. While the
+# mid-prefill rows' chunks fit, all of them advance together. A burst
+# that does not fit advances the rows with the FEWEST CHUNKS LEFT (ties:
+# the oldest), so the prompts nearest their first token get it before a
+# longer one takes a trip, and no step stalls its decode rows for more
+# than 2048 / chunk trips. A row waits only while that many others are
+# nearer their end. The contiguous layout computes the whole slot plane
+# whatever chunks, so there every mid-prefill row advances.
+CHUNK_TOKENS_PER_STEP = 2048
 
 # Per-request critical-path segments (ISSUE 11): every finished
 # request's wall time decomposes into these bins — surfaced per request
@@ -1536,7 +1550,12 @@ class InferenceEngine:
         for layer in pool:
             d = {"index": index_vec.astype(jnp.int32)}
             for key, buf in layer.items():
-                d[key] = jnp.take(buf, flat, axis=0).reshape(
+                # clip, not take's default fill: the host builds every
+                # index inside the pool (unmapped pages read the trash
+                # page), and fill's out-of-bounds select is one more
+                # pass over the whole view (with it an 8B decode program
+                # at 16 x 1,024 takes 35 ms, without it 19: PERF.md §6)
+                d[key] = jnp.take(buf, flat, axis=0, mode="clip").reshape(
                     (S, W) + buf.shape[1:])
             view.append(d)
         return view
@@ -1934,10 +1953,7 @@ class InferenceEngine:
         self._pulse_view(W)
         gidx, idxv, sidx = self._paged_decode_plan(active, n, W)
         tokens = jnp.asarray(self.slot_last_token)
-        args = (jnp.asarray(self._temperature),
-                jnp.asarray(self._top_k),
-                jnp.asarray(self._top_p),
-                jnp.asarray(self._greedy))
+        args = self._sampling_args(active)
         kw = {} if lora is None else {"lora": lora}
         if gmask is not None:
             if n != 1:
@@ -2773,6 +2789,20 @@ class InferenceEngine:
             self._emit(slot, first_id)
             self._constraint_commit(slot, cs, first_id)
 
+    def _sampling_args(self, rows: list[int]):
+        """The sampler's ``(temperature, top_k, top_p, greedy)`` arrays
+        for a dispatch in which ``rows`` decode. Every other row of the
+        plane goes in as greedy: its token is discarded, and what a
+        finished request left in its flags (or the initial ``False``)
+        must not choose the sampler's body for the live rows
+        (``infer/sampling.py::sampler_tier``). Books the body chosen."""
+        greedy = np.ones((self.max_slots,), bool)
+        greedy[rows] = self._greedy[rows]
+        self.steptrace.note_sampler_tier(
+            sampler_tier_name(greedy, self._top_k, self._top_p))
+        return (jnp.asarray(self._temperature), jnp.asarray(self._top_k),
+                jnp.asarray(self._top_p), jnp.asarray(greedy))
+
     def _chunk_span(self, rem: int) -> int:
         """Padded length the chunked path would write for ``rem`` tokens."""
         c = self.chunked_prefill
@@ -3131,15 +3161,37 @@ class InferenceEngine:
         last_logits = self._prefill_into_slot(req, slot, plen, hit)
         self._activate(slot, req, plen, last_logits)
 
+    def _chunk_entries(self) -> list:
+        """``(slot, state, next chunk)`` of the mid-prefill rows one
+        chunk dispatch advances, in slot order: all of them, unless the
+        paged layout's :data:`CHUNK_TOKENS_PER_STEP` holds fewer chunks;
+        then those with the fewest chunks left (``slot_prefill`` keeps
+        admission order and the sort is stable: ties go to the
+        oldest)."""
+        C = self.chunked_prefill
+        slots = list(self.slot_prefill)
+        rows = max(1, CHUNK_TOKENS_PER_STEP // C)
+        if self.paged is not None and len(slots) > rows:
+            def chunks_left(slot):
+                st = self.slot_prefill[slot]
+                return -(-(st["plen"] - st["done"]) // C)
+            slots = sorted(slots, key=chunks_left)[:rows]
+        entries = []
+        for slot in sorted(slots):
+            st = self.slot_prefill[slot]
+            entries.append(
+                (slot, st, st["req"].prompt_ids[st["done"]: st["done"] + C]))
+        return entries
+
     def _advance_prefills(self, budget: int = 1) -> bool:
-        """Advance every in-flight chunked prefill by one chunk per
-        budget unit, then finalize finished prompts. Multiple mid-
-        prefill slots advance TOGETHER in one batched dispatch
-        (:meth:`_chunk_batch_fn`) — concurrent long prompts no longer
-        serialize per slot — while a single prefill keeps the 1-slot
-        program (and, with budget > 1, gets several chunks per step, so
-        ``prefill_budget`` still bounds a lone prompt's TTFT at
-        ~chunks/budget steps)."""
+        """Advance the in-flight chunked prefills (:meth:`_chunk_entries`)
+        by one chunk per budget unit, then finalize finished prompts.
+        Multiple mid-prefill slots advance TOGETHER in one batched
+        dispatch (:meth:`_chunk_batch_fn`) — concurrent long prompts
+        no longer serialize per slot — while a single prefill keeps
+        the 1-slot program (and, with budget > 1, gets several chunks
+        per step, so ``prefill_budget`` still bounds a lone prompt's
+        TTFT at ~chunks/budget steps)."""
         progressed = False
         while budget > 0 and self.slot_prefill:
             # paged layout: no per-chunk page reservation is needed —
@@ -3148,12 +3200,7 @@ class InferenceEngine:
             # chunk write is already covered; only decode GROWTH
             # allocates on demand (_paged_reserve_active)
             with self.steptrace.scope("index_build"):
-                entries = []
-                for slot in sorted(self.slot_prefill):
-                    st = self.slot_prefill[slot]
-                    chunk = st["req"].prompt_ids[
-                        st["done"]: st["done"] + self.chunked_prefill]
-                    entries.append((slot, st, chunk))
+                entries = self._chunk_entries()
                 C = self.chunked_prefill
                 # whole-cache batching needs every row's C-wide write window
                 # inside cache_len — a clamped scatter on a near-full ACTIVE
@@ -3326,7 +3373,7 @@ class InferenceEngine:
         self.steptrace.note_chunk_rows(rows, row_slots)
 
     def _paged_chunk_dispatch(self, entries, lora=None) -> None:
-        """Advance every mid-prefill row one chunk against the PAGE
+        """Advance ``entries``' rows one chunk against the PAGE
         POOL in a single dispatch: the program gathers one chunking
         row's pages at a time, runs the shared ``batched_chunk`` body
         on that view and scatters the row's real chunk window back to
@@ -4008,8 +4055,9 @@ class InferenceEngine:
         return True, ""
 
     def _mixed_dispatch(self, active: list[int], n: int) -> bool:
-        """Issue the fused mixed-batch program: every mid-prefill row
-        advances one chunk AND every ready row decodes an ``n``-block,
+        """Issue the fused mixed-batch program: the step's mid-prefill
+        rows (:meth:`_chunk_entries`) advance one chunk AND every ready
+        row decodes an ``n``-block,
         in ONE device dispatch (serve/mixed_step.py). Host bookkeeping
         mirrors the sequential paths exactly: chunk results feed
         ``slot_prefill``/finalization, block tokens commit per slot.
@@ -4028,11 +4076,7 @@ class InferenceEngine:
             if not active or not self.slot_prefill:
                 return False
         with self.steptrace.scope("index_build"):
-            entries = []
-            for slot in sorted(self.slot_prefill):
-                st = self.slot_prefill[slot]
-                chunk = st["req"].prompt_ids[st["done"]: st["done"] + C]
-                entries.append((slot, st, chunk))
+            entries = self._chunk_entries()
             if self.paged is None:
                 tok, starts, lens = self._chunk_batch_rows(entries)
                 advance = np.zeros((self.max_slots,), np.int32)
@@ -4066,10 +4110,7 @@ class InferenceEngine:
             self.steptrace.window_begin("mixed")
             self.rng, sub = jax.random.split(self.rng)
             sampling = (jnp.asarray(self.slot_last_token), sub,
-                        jnp.asarray(self._temperature),
-                        jnp.asarray(self._top_k),
-                        jnp.asarray(self._top_p),
-                        jnp.asarray(self._greedy))
+                        *self._sampling_args(active))
             if gmask is not None:
                 sampling += (jnp.asarray(gmask),)
             if self.paged is not None:
@@ -4337,10 +4378,7 @@ class InferenceEngine:
                         self.params, self.cache,
                         jnp.asarray(self.slot_last_token),
                         sub,
-                        jnp.asarray(self._temperature),
-                        jnp.asarray(self._top_k),
-                        jnp.asarray(self._top_p),
-                        jnp.asarray(self._greedy),
+                        *self._sampling_args(active),
                         n=n, **kw,
                     )
                 self.steptrace.window_issued()
@@ -4381,10 +4419,7 @@ class InferenceEngine:
                     self.params, self.cache,
                     jnp.asarray(self.slot_last_token),
                     sub,
-                    jnp.asarray(self._temperature),
-                    jnp.asarray(self._top_k),
-                    jnp.asarray(self._top_p),
-                    jnp.asarray(self._greedy),
+                    *self._sampling_args(active),
                     jnp.asarray(gmask), **kw,
                 )
             else:
@@ -4393,10 +4428,7 @@ class InferenceEngine:
                     self.params, self.cache,
                     jnp.asarray(self.slot_last_token),
                     sub,
-                    jnp.asarray(self._temperature),
-                    jnp.asarray(self._top_k),
-                    jnp.asarray(self._top_p),
-                    jnp.asarray(self._greedy),
+                    *self._sampling_args(active),
                     **kw,
                 )
             self.steptrace.window_issued()

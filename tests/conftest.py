@@ -33,3 +33,23 @@ def devices():
 @pytest.fixture()
 def rng():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture
+def tiers_run(monkeypatch):
+    """The bodies of ``infer/sampling.py::sample_token_batched`` that
+    really ran on the device, in order: a debug callback in each
+    (programs traced after this fixture see them)."""
+    from llm_in_practise_tpu.infer import sampling
+
+    ran = []
+    for name in sampling.SAMPLER_TIERS:
+        def spied(*args, _stock=getattr(sampling, "_" + name), _name=name):
+            jax.debug.callback(lambda: ran.append(_name))
+            return _stock(*args)
+        monkeypatch.setattr(sampling, "_" + name, spied)
+
+    def read():
+        jax.effects_barrier()
+        return ran
+    return read

@@ -237,29 +237,26 @@ def test_capture_fetches_from_the_one_program(model, params):
     assert list(seen.tokens.queue) == list(plain.tokens.queue)
 
 
-@pytest.mark.parametrize("greedy,sorts", [(True, False), (False, True)])
+@pytest.mark.parametrize("greedy,tier", [(True, "argmax"), (False, "filtered")])
 def test_idle_rows_do_not_send_a_greedy_plane_to_the_sampler(
-        model, params, monkeypatch, greedy, sorts):
+        model, params, tiers_run, greedy, tier):
     """``engine._greedy`` starts False and is written at activation only:
     one greedy request on a fresh 4-slot engine must still take the
-    argmax branch (the sampler's full-vocabulary sort is decided on LIVE
-    rows); a sampling request takes the sampler."""
-    from llm_in_practise_tpu.serve import block_step
-    stock, calls = block_step.sample_token_batched, []
-
-    def counted(rng, logits, **kw):
-        jax.debug.callback(lambda: calls.append(1))
-        return stock(rng, logits, **kw)
-
-    monkeypatch.setattr(block_step, "sample_token_batched", counted)
+    sampler's ``argmax`` body (the block program hands idle rows to the
+    shared switch of ``infer/sampling.py`` as greedy, so the
+    full-vocabulary sort is decided on LIVE rows); a request with a top-k
+    takes ``filtered``. The step records say the same."""
     engine = make_engine(model, params)
     sp = (SamplingParams(max_tokens=5, **GREEDY) if greedy
           else SamplingParams(max_tokens=5, temperature=0.8, top_k=5))
     req = engine.submit(prompt_of(9, seed=1), sp)
     drain(engine)
-    jax.effects_barrier()
     assert req.n_generated == 5 and engine._greedy.sum() == int(greedy)
-    assert bool(calls) == sorts
+    assert set(tiers_run()) == {tier}
+    booked = {r["sampler_tier"] for r in engine.steptrace.records()}
+    assert booked - {None} == {tier}
+    assert engine.steptrace.snapshot()["sampler_steps"] == {
+        tier: engine.block.passes}
 
 
 def generate_loop(model, params, prompt, n_new, steps, dynamic, threshold):
