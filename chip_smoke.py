@@ -461,8 +461,7 @@ def phase_serve(plan: Plan, seed: int, base: dict) -> None:
             engine_prefill_logits(engine, probe),
             reference_logits(params, cfg, probe), "serve prefill")
         emit(phase="serve", seconds=round(time.perf_counter() - t0, 1),
-             layout={"kv": args.kv_layout, "scan_layers": args.scan_layers,
-                     "mixed_step": args.mixed_step,
+             layout={"kv": args.kv_layout, "mixed_step": args.mixed_step,
                      "chunked_prefill": args.chunked_prefill,
                      "kv_cache_dtype": args.kv_cache_dtype},
              n_layer=cfg.n_layer, quantize_s=round(quant_s, 1),

@@ -39,20 +39,11 @@ def validate_args(args, error) -> None:
     ISSUE 10 deleted the ``--tensor-parallel-size`` fail-fasts against
     ``--quantized_dir`` (packed leaves now shard via
     quant/sharding.py component shardings) and ``--draft-model-path``
-    (the small draft replicates across the mesh). ``--scan-layers``
-    keeps its TP error: the stacked layout serves contiguous-only
-    (no paged pool, no per-block TP rule table) and stays the
-    single-chip flat-compile-time path.
+    (the small draft replicates across the mesh).
     """
     if args.quantized_dir and args.lora_modules:
         error("--lora-modules with --quantized_dir is not supported "
               "(adapters cannot merge into packed 4-bit kernels)")
-    if args.scan_layers and args.tp > 1:
-        error("--scan-layers with --tensor-parallel-size is not "
-              "supported: the stacked scan layout is contiguous-only "
-              "(no paged pool, no stacked TP rule table — "
-              "docs/serving-tp.md 'Limitations'); serve deep models "
-              "sharded with the unrolled layout instead")
     if args.tp_quantized_collectives and args.tp <= 1:
         error("--tp-quantized-collectives requires "
               "--tensor-parallel-size > 1 (there is no collective to "
@@ -62,11 +53,6 @@ def validate_args(args, error) -> None:
               "supported: packed trees run their matmuls through the "
               "fused dequant interceptor, which the quantized-"
               "collective interceptor does not compose with")
-    if args.scan_layers and args.lora_modules:
-        error("--lora-modules with --scan-layers is not supported: "
-              "adapters merge by unrolled block_i/... kernel paths, "
-              "which do not exist in the stacked tree (they would "
-              "silently serve base weights)")
     if args.lora_modules:
         # fail fast at the CLI — a typo'd spec or missing checkpoint
         # should not surface as a traceback after the (slow) base
@@ -93,10 +79,6 @@ def validate_args(args, error) -> None:
         error(f"--role {args.role} requires --kv-remote: the KV handoff "
               "between the prefill and decode pools travels through the "
               "shared kv_pool server")
-    if args.scan_layers and args.kv_layout == "paged":
-        error("--scan-layers serves with --kv-layout contiguous only "
-              "(the paged pool supports the unrolled cache layout; "
-              "pass --kv-layout contiguous explicitly)")
     # a draft model still needs an EXPLICIT K (checked before the
     # decode-role default below resolves one, or the requirement would
     # be silently bypassed on --role decode)
@@ -121,11 +103,6 @@ def validate_args(args, error) -> None:
         error("--draft-model-path with --speculative 0 is "
               "contradictory: drop the draft model or pass a "
               "positive K")
-    if args.draft_model_path and args.scan_layers:
-        error("--draft-model-path with --scan-layers is not supported "
-              "yet: the draft loads unstacked (cache slot axis 0) while "
-              "the stacked target uses axis 1 — the engine would reject "
-              "the layout mismatch after the full checkpoint restore")
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -283,13 +260,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "compressed-tensors serving parity; composes "
                         "with --tensor-parallel-size via "
                         "quant/sharding.py component shardings)")
-    p.add_argument("--scan-layers", dest="scan_layers",
-                   action="store_true",
-                   help="serve in the scan-layers layout: params and KV "
-                        "cache stacked over depth, every engine program "
-                        "compiles ONE block — flat compile time for deep "
-                        "models (packed 4-bit weights ride the scan as "
-                        "sideband inputs); Qwen3-family only")
     return p
 
 
@@ -339,24 +309,6 @@ def build_server(args, tok, load_model, error) -> OpenAIServer:
     model, params = load_model(mesh)
 
     from llm_in_practise_tpu.data.sft import IM_END
-
-    if args.scan_layers:
-        from llm_in_practise_tpu.models.qwen3 import (
-            stack_layer_params_jitted,
-        )
-        from llm_in_practise_tpu.serve.quantized import (
-            QuantizedModel as _QM,
-        )
-
-        inner = model.model if isinstance(model, _QM) else model
-        if not isinstance(inner, Qwen3):
-            error("--scan-layers requires a Qwen3-family model")
-        scfg = inner.cfg.replace(scan_layers=True)
-        params = stack_layer_params_jitted(params, scfg.n_layer)
-        model = (_QM(Qwen3(scfg)) if isinstance(model, _QM)
-                 else Qwen3(scfg))
-        print(f"scan-layers serving: {scfg.n_layer} layers, "
-              "one compiled block per engine program")
 
     shard_fn = None
     if args.tp > 1:

@@ -11,9 +11,7 @@ Since ISSUE 15 this module is a thin compatibility shim over
 and returns engine-shaped :class:`~.multi_lora.AdapterHandle` views, so
 every adapter rides the same fused dispatch and the base weights live in
 HBM exactly once. The legacy engine-per-adapter merged-weight path is
-kept (with a warning) only for the cases the batched-BGMV twins cannot
-serve: scan-layers models (stacked cache layout, no per-block module
-paths for the interceptor) and callers passing per-adapter engine
+kept (with a warning) only for callers passing per-adapter engine
 kwargs (``engine_kw_for`` — separate kv pools / handoff namespaces imply
 separate weight sets). Adapters are the ``adapter.msgpack`` +
 ``adapter.json`` pairs written by ``examples/qwen3_lora_sft.py`` /
@@ -73,12 +71,8 @@ def build_adapter_engines(
     and base HBM is paid once regardless of the adapter count.
 
     Legacy (merged-weight engine-per-adapter) fallback, warned:
-
-    - scan-layers models (``cache_slot_axis == 1``): the stacked scan
-      body has no per-block module paths for the LoRA interceptor, so
-      the adapter merges into the stacked kernels instead
-    - ``engine_kw_for`` given: per-adapter kwargs (kv pools, handoff
-      namespaces) assume one weight set per engine
+    ``engine_kw_for`` given — per-adapter kwargs (kv pools, handoff
+    namespaces) assume one weight set per engine.
 
     ``param_transform`` (optional) post-processes the params handed to
     each built engine — e.g. :func:`..serve.engine.shard_params_for_serving`
@@ -88,15 +82,12 @@ def build_adapter_engines(
     ``engine_kw_for(name)`` (optional, legacy-only) returns per-adapter
     kwargs merged over ``engine_kw``.
     """
-    scan_layers = int(getattr(model, "cache_slot_axis", 0)) == 1
-    if scan_layers or engine_kw_for is not None:
-        why = ("scan-layers model serves contiguous stacked kernels"
-               if scan_layers else "per-adapter engine kwargs requested")
+    if engine_kw_for is not None:
         _log.warning(
-            "legacy engine-per-adapter path (%s): each of the %d "
-            "adapter(s) pays full base-model HBM — the batched "
-            "multi-LoRA registry (serve/multi_lora.py) shares one "
-            "engine across adapters", why, len(modules))
+            "legacy engine-per-adapter path (per-adapter engine kwargs "
+            "requested): each of the %d adapter(s) pays full base-model "
+            "HBM — the batched multi-LoRA registry (serve/multi_lora.py) "
+            "shares one engine across adapters", len(modules))
 
         def prep(path):
             merged = load_adapter(base_params, path)
@@ -104,9 +95,7 @@ def build_adapter_engines(
 
         return {
             name: InferenceEngine(
-                model, prep(path),
-                **{**engine_kw,
-                   **(engine_kw_for(name) if engine_kw_for else {})})
+                model, prep(path), **{**engine_kw, **engine_kw_for(name)})
             for name, path in modules.items()
         }
 

@@ -1003,9 +1003,9 @@ class OpenAIServer:
             "fleet warm-path events (published / publish_failed / "
             "claimed / lost)")
         # read eng.prefix_cache LIVE at scrape time: benches and serving
-        # setups attach/replace the cache after server construction
-        # (e.g. tools/tpu_serve_qwen3_bench.py), and the pre-registry
-        # exposition tracked that; no cache → family present, no samples
+        # setups attach/replace the cache after server construction,
+        # and the pre-registry exposition tracked that; no cache →
+        # family present, no samples
         def _pc(attr):
             def read():
                 pc = eng.prefix_cache

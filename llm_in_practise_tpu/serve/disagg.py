@@ -216,9 +216,10 @@ def usable_for_engine(host: HostEntry, prompt_ids, engine) -> str | None:
     if host.length != plen:
         return (f"length mismatch: entry {host.length} vs prompt {plen} "
                 "(tokenizer/crop drift between replicas?)")
-    if getattr(host, "slot_axis", 0) != engine._sax:
-        return (f"cache layout mismatch: entry slot_axis "
-                f"{getattr(host, 'slot_axis', 0)} vs engine {engine._sax}")
+    if getattr(host, "slot_axis", 0) != 0:
+        return (f"cache layout mismatch: entry slot_axis {host.slot_axis} "
+                "is the stacked layout an older replica wrote; this "
+                "engine serves slot_axis 0")
     if getattr(engine, "paged", None) is None:
         # a contiguous consumer inserts the FULL (post-pow2-padding)
         # bucket width — bound that, or the scatter clamps and corrupts

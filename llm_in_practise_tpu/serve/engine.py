@@ -464,6 +464,17 @@ class InferenceEngine:
         )
 
         enable_compilation_cache()
+        # Every KV buffer here is (slot, position, ...): the unrolled
+        # per-layer layout. The stacked layout (Qwen3 ``scan_layers``:
+        # axis 0 is the layer) trains and decodes through
+        # infer/generate.py; the engine does not serve it.
+        for which, m in (("model", model), ("draft_model", draft_model)):
+            if m is not None and int(getattr(m, "cache_slot_axis", 0)) != 0:
+                raise ValueError(
+                    f"{which} has the stacked (scan_layers) cache layout; "
+                    "the engine serves the unrolled layout only "
+                    "(cache_slot_axis == 0): build the model with "
+                    "scan_layers=False over unstack_layer_params(params)")
         # Batched multi-LoRA (serve/multi_lora.py, ISSUE 15): wrap the
         # model in the gathered-BGMV facade BEFORE anything below closes
         # over it (mixed-step builders, PagedKV, init_cache, the cost
@@ -480,14 +491,6 @@ class InferenceEngine:
             model = LoRAServingModel(model)
         self.model = model
         self.params = params
-        # Cache layout: which axis of each KV buffer indexes the slot.
-        # 0 = unrolled per-layer dicts (GPT/DeepSeek/unrolled Qwen3);
-        # 1 = stacked scan layout (axis 0 is the layer — Qwen3
-        # ``scan_layers``, whose init_cache wraps the stacked dict in a
-        # one-element list so both layouts iterate identically here).
-        # Width (sequence) axis is always slot_axis + 1.
-        self._sax = int(getattr(model, "cache_slot_axis", 0))
-        self._wax = self._sax + 1
         # Tensor-parallel serving (vLLM --tensor-parallel-size parity):
         # pass a mesh and params already placed by
         # :func:`shard_params_for_serving`; the KV cache shards its heads
@@ -761,11 +764,6 @@ class InferenceEngine:
                     self.draft_cache,
                     jax.tree_util.tree_map(lambda _: rep,
                                            self.draft_cache))
-            dax = int(getattr(draft_model, "cache_slot_axis", 0))
-            if dax != self._sax:
-                raise ValueError(
-                    "draft_model cache layout differs from the target's "
-                    f"(slot axis {dax} vs {self._sax})")
             for layer in self.draft_cache:
                 layer["index"] = jnp.zeros((self.max_slots,), jnp.int32)
             self._draft_sync = np.zeros((max_slots,), np.int64)
@@ -1228,7 +1226,7 @@ class InferenceEngine:
                 if key == "index":
                     continue
                 new[key] = jax.lax.dynamic_update_slice_in_dim(
-                    buf, rows[key].astype(buf.dtype), 0, axis=self._wax
+                    buf, rows[key].astype(buf.dtype), 0, axis=1
                 )
             primed.append(new)
         return primed
@@ -1269,7 +1267,6 @@ class InferenceEngine:
         exists at a time, however many prefills are in flight.
         ``model`` is a parameter so the draft-model cache (speculative
         decoding) reuses the same machinery."""
-        sax, wax = self._sax, self._wax
         mini = []
         for layer in cache:
             m = {}
@@ -1278,7 +1275,7 @@ class InferenceEngine:
                     m["index"] = jnp.full((1,), done, jnp.int32)
                 else:
                     m[key] = jax.lax.dynamic_slice_in_dim(
-                        buf, slot, 1, axis=sax)
+                        buf, slot, 1, axis=0)
             mini.append(m)
         logits, mini = model.apply(
             {"params": params}, chunk_ids, deterministic=True, cache=mini
@@ -1292,12 +1289,11 @@ class InferenceEngine:
                     out["index"] = buf.at[slot].set(done + chunk_len)
                 else:
                     rows = jax.lax.dynamic_slice_in_dim(
-                        m2[key], done, width, axis=wax)
-                    starts = [jnp.zeros((), jnp.int32)] * buf.ndim
-                    starts[sax] = slot
-                    starts[wax] = done
+                        m2[key], done, width, axis=1)
+                    zero = jnp.zeros((), jnp.int32)
                     out[key] = jax.lax.dynamic_update_slice(
-                        buf, rows.astype(buf.dtype), tuple(starts))
+                        buf, rows.astype(buf.dtype),
+                        (slot, done) + (zero,) * (buf.ndim - 2))
             new.append(out)
         last = jnp.take_along_axis(
             logits, (chunk_len - 1)[None, None, None], axis=1
@@ -1462,22 +1458,19 @@ class InferenceEngine:
             for key, buf in layer.items():
                 if key == "index":
                     continue
-                s = jax.lax.dynamic_slice_in_dim(
-                    buf, slot, 1, axis=self._sax)
-                r[key] = jax.lax.slice_in_dim(
-                    s, 0, bucket, axis=self._wax)
+                s = jax.lax.dynamic_slice_in_dim(buf, slot, 1, axis=0)
+                r[key] = jax.lax.slice_in_dim(s, 0, bucket, axis=1)
             rows.append(r)
         return rows
 
-    def _slot_write(self, eng, rows, slot, width):
+    @staticmethod
+    def _slot_write(eng, rows, slot):
         """Write ``rows`` (slot-axis size 1 or B) into ``eng`` at
-        ``slot`` (scalar or (B,) vector), first ``width`` positions of
-        the sequence axis — in either cache layout."""
+        ``slot`` (scalar or (B,) vector), over the rows' own width of
+        the sequence axis."""
         rows = rows.astype(eng.dtype)
         single = isinstance(slot, int)  # one slot: drop rows' slot axis
-        if self._sax == 0:
-            return eng.at[slot, :width].set(rows[0] if single else rows)
-        return eng.at[:, slot, :width].set(rows[:, 0] if single else rows)
+        return eng.at[slot, :rows.shape[1]].set(rows[0] if single else rows)
 
     def _insert_fn(self, engine_cache, prefill_cache, slot: int, length):
         """Copy a prefilled request's cache rows into ``slot``. The
@@ -1490,9 +1483,8 @@ class InferenceEngine:
                 if key == "index":
                     layer["index"] = eng["index"].at[slot].set(length)
                 else:
-                    width = pre[key].shape[self._wax]
                     layer[key] = self._slot_write(
-                        eng[key], pre[key], slot, width)
+                        eng[key], pre[key], slot)
             new.append(layer)
         return new
 
@@ -1507,9 +1499,8 @@ class InferenceEngine:
                 if key == "index":
                     layer["index"] = eng["index"].at[slot_ids].set(lengths)
                 else:
-                    width = pre[key].shape[self._wax]
                     layer[key] = self._slot_write(
-                        eng[key], pre[key], slot_ids, width)
+                        eng[key], pre[key], slot_ids)
             new.append(layer)
         return new
 
@@ -1522,9 +1513,8 @@ class InferenceEngine:
                 if key == "index":
                     layer["index"] = eng["index"].at[slot].set(length)
                 else:
-                    bucket = layer_rows[key].shape[self._wax]
                     layer[key] = self._slot_write(
-                        eng[key], layer_rows[key], slot, bucket)
+                        eng[key], layer_rows[key], slot)
             new.append(layer)
         return new
 
@@ -2005,7 +1995,7 @@ class InferenceEngine:
         gidx = self.paged.row_gather_idx(slot, width)
         rows = self._pg_gather_rows(self.paged.kv, jnp.asarray(gidx))
         return pc.PrefixEntry(length=plen, bucket=width, rows=rows,
-                              last_logits=last_logits, slot_axis=0,
+                              last_logits=last_logits,
                               page_size=self.paged.page_size)
 
     def _paged_insert_entry(self, slot: int, entry, length: int) -> None:
@@ -2600,10 +2590,8 @@ class InferenceEngine:
                             self._paged_store_prefix(req, plen, slot,
                                                      last[j:j + 1])
                         else:
-                            sl = ((slice(None),) * self._sax
-                                  + (slice(j, j + 1),))
                             row_slices = [
-                                {k: v[sl] for k, v in layer.items()
+                                {k: v[j:j + 1] for k, v in layer.items()
                                  if k != "index"} for layer in pre]
                             self._store_prefix(req, plen, row_slices,
                                                last[j:j + 1])
@@ -2657,8 +2645,7 @@ class InferenceEngine:
                 # which frees right here
                 entry = pc.PrefixEntry(length=plen, bucket=bucket,
                                        rows=rows,
-                                       last_logits=last_logits,
-                                       slot_axis=self._sax)
+                                       last_logits=last_logits)
             self.slot_req[slot] = None
             self.slot_ready[slot] = False
             self.slot_budget[slot] = 0
@@ -2857,10 +2844,10 @@ class InferenceEngine:
             return ext
 
         def usable(entry) -> bool:
-            # rows from another engine (shared pool / restart) may be in
-            # the other cache layout — their shapes are transposed
-            # relative to this engine's writes and would scatter garbage
-            if getattr(entry, "slot_axis", 0) != self._sax:
+            # rows an older replica wrote to a shared pool may be in the
+            # stacked layout — their shapes are transposed relative to
+            # this engine's writes and would scatter garbage
+            if getattr(entry, "slot_axis", 0) != 0:
                 return False
             # rows from another engine (shared pool) may be padded to a
             # bucket this engine's cache can't hold — the insert/suffix
@@ -3450,10 +3437,8 @@ class InferenceEngine:
         entry = pc.PrefixEntry(
             length=plen, bucket=bucket,
             rows=(pre_cache if rows_ready
-                  else pc.slice_cache_rows(pre_cache, bucket,
-                                           axis=self._wax)),
+                  else pc.slice_cache_rows(pre_cache, bucket)),
             last_logits=last_logits,
-            slot_axis=self._sax,
         )
         key_ids = self._ns_ids(req.adapter, req.prompt_ids)
         self.prefix_cache.put(key_ids, entry)

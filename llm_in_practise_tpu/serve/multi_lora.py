@@ -178,10 +178,6 @@ class LoRAServingModel:
     def config(self):
         return self.inner.config
 
-    @property
-    def cache_slot_axis(self) -> int:
-        return getattr(self.inner, "cache_slot_axis", 0)
-
     def init_cache(self, *args, **kwargs):
         return self.inner.init_cache(*args, **kwargs)
 

@@ -288,10 +288,6 @@ class PagedKV:
     """Device-side paged KV state for one engine: per-layer flat pools
     + per-slot block tables + the host-side index-array builders the
     jitted paged programs consume.
-
-    Only the unrolled cache layout (slot axis 0) is supported — the
-    stacked scan layout keeps ``kv_layout="contiguous"`` (see
-    docs/paged-kv.md, "Limitations").
     """
 
     def __init__(self, model, *, max_slots: int, cache_len: int,
@@ -300,11 +296,6 @@ class PagedKV:
         import jax
         import jax.numpy as jnp
 
-        if int(getattr(model, "cache_slot_axis", 0)) != 0:
-            raise ValueError(
-                "kv_layout='paged' supports the unrolled cache layout "
-                "only (cache_slot_axis == 0); scan-layers engines must "
-                "use kv_layout='contiguous'")
         self.page_size = int(page_size)
         self.cache_len = int(cache_len)
         self.max_slots = int(max_slots)

@@ -40,11 +40,10 @@ class PrefixEntry:
     bucket: int           # padded length of the stored rows
     rows: list            # per-layer {key: (1, bucket, ...) device array}
     last_logits: object   # (1, vocab) logits at the final prefix position
-    # Cache layout the rows were sliced from: the KV buffers' slot axis
-    # (0 = unrolled per-layer dicts, 1 = stacked scan layout). An engine
-    # must not consume rows from the other layout — the shapes are
-    # transposed relative to its writes (shared kv_pool / restart with
-    # the layout toggled) — so lookup filters on this.
+    # The KV buffers' slot axis. Every engine writes 0 (the unrolled
+    # layout it serves); the field stays on the kv_pool wire because a
+    # shared pool may hold stacked rows (1) an older replica wrote,
+    # whose shapes are transposed — readers refuse anything but 0.
     slot_axis: int = 0
     # Page-wise entries (kv_layout="paged" producers): rows span
     # ceil(length / page_size) * page_size positions — only live pages,
@@ -393,15 +392,13 @@ class PagedPrefixIndex:
             self.pool.release(pages)
 
 
-def slice_cache_rows(prefill_cache, bucket: int, *, axis: int = 1) -> list:
+def slice_cache_rows(prefill_cache, bucket: int) -> list:
     """Keep only the first ``bucket`` rows of each layer's KV buffers
-    (drop the per-layer index — the entry carries the true length).
-    ``axis`` is the sequence axis: 1 in the unrolled cache layout, 2 in
-    the stacked scan layout (engine passes its ``_wax``)."""
+    (drop the per-layer index — the entry carries the true length)."""
     rows = []
     for layer in prefill_cache:
         rows.append({
-            k: jax.lax.slice_in_dim(v, 0, bucket, axis=axis)
+            k: jax.lax.slice_in_dim(v, 0, bucket, axis=1)
             for k, v in layer.items() if k != "index"
         })
     return rows

@@ -10,7 +10,7 @@ overwrite-before-attend invariant — every garbage row is overwritten by
 the chunk that owns its range (or by real decode, in order) before any
 query can attend it. These tests pin that invariant from the outside:
 chunked output under heavy interleaving must equal unchunked output,
-in both cache layouts, including the prefix-store/reuse path.
+including the prefix-store/reuse path.
 """
 
 import jax
@@ -18,9 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from llm_in_practise_tpu.models.qwen3 import (
-    Qwen3, qwen3_config, stack_layer_params,
-)
+from llm_in_practise_tpu.models.qwen3 import Qwen3, qwen3_config
 from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
 
 
@@ -29,18 +27,15 @@ def models():
     cfg = qwen3_config(vocab_size=128, compute_dtype="float32")
     pu = Qwen3(cfg).init(jax.random.PRNGKey(0),
                          jnp.ones((1, 8), jnp.int32))["params"]
-    ps = stack_layer_params(pu, cfg.n_layer)
-    return Qwen3(cfg), pu, Qwen3(cfg.replace(scan_layers=True)), ps
+    return Qwen3(cfg), pu
 
 
 def _rng_prompt(n, seed=7):
     return list(map(int, np.random.default_rng(seed).integers(0, 128, n)))
 
 
-@pytest.mark.parametrize("layout", ["unrolled", "scan"])
-def test_chunked_equals_oneshot_under_decode_load(models, layout):
-    mu, pu, ms, ps = models
-    model, params = (mu, pu) if layout == "unrolled" else (ms, ps)
+def test_chunked_equals_oneshot_under_decode_load(models):
+    model, params = models
     long_prompt = _rng_prompt(70)
     sp = SamplingParams(greedy=True, max_tokens=10)
 
@@ -64,7 +59,7 @@ def test_chunked_equals_oneshot_under_decode_load(models, layout):
 def test_chunked_prefix_store_and_reuse(models):
     """The chunked path stores its prefix from the slot rows; a repeat
     prompt must hit it and produce identical output."""
-    mu, pu, _, _ = models
+    mu, pu = models
     long_prompt = _rng_prompt(60)
     sp = SamplingParams(greedy=True, max_tokens=8)
     eng = InferenceEngine(mu, pu, max_slots=2, cache_len=160,
@@ -88,7 +83,7 @@ def test_chunked_prefix_store_and_reuse(models):
 def test_chunked_with_speculative_interleave(models):
     """Speculation writes k+1 rows into every slot per verify dispatch —
     the reserved slot's garbage must still be overwritten before use."""
-    mu, pu, _, _ = models
+    mu, pu = models
     long_prompt = _rng_prompt(70)
     sp = SamplingParams(greedy=True, max_tokens=10)
     ref_eng = InferenceEngine(mu, pu, max_slots=2, cache_len=160)
@@ -109,7 +104,7 @@ def test_chunked_with_speculative_interleave(models):
 def test_many_concurrent_chunked_prefills(models):
     """Several prompts mid-prefill at once: the shared-transient design
     must keep each one's rows isolated in its own slot."""
-    mu, pu, _, _ = models
+    mu, pu = models
     prompts = [_rng_prompt(50 + 8 * i, seed=i) for i in range(4)]
     sp = SamplingParams(greedy=True, max_tokens=6)
     refs = []
@@ -127,14 +122,12 @@ def test_many_concurrent_chunked_prefills(models):
     assert outs == refs
 
 
-@pytest.mark.parametrize("layout", ["unrolled", "scan"])
-def test_batched_multi_slot_chunks_match_isolated(models, layout):
+def test_batched_multi_slot_chunks_match_isolated(models):
     """Round 5: concurrent chunked prefills advance in ONE batched
     dispatch (engine._chunk_batch_fn). Exactness bar: three long
     prompts prefilling simultaneously (including a pow2 padding row,
     since 3 pads to 4) must generate exactly what each does alone."""
-    mu, pu, ms, ps = models
-    model, params = (mu, pu) if layout == "unrolled" else (ms, ps)
+    model, params = models
     prompts = [_rng_prompt(60 + 7 * i, seed=20 + i) for i in range(3)]
     sp = SamplingParams(greedy=True, max_tokens=8)
 
@@ -197,7 +190,7 @@ def test_paged_chunk_rows_match_one_row_path(models, k):
     prefix hit): tokens equal each prompt's own one-row run exactly,
     the rows' KV to float32's last bits, and the device computed one
     row a chunk for them, not 8."""
-    mu, pu, _, _ = models
+    mu, pu = models
     eng = InferenceEngine(mu, pu, max_slots=8, cache_len=160,
                           chunked_prefill=16, kv_layout="paged",
                           prefix_cache=True, cache_dtype=jnp.float32)

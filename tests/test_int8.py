@@ -186,11 +186,12 @@ def test_int8_tp_serving_matches_single_device(devices):
 
 def test_quantized_scan_serving_int8(rng):
     """Int8 under the decode scan: stacked q/scale ride the sideband and
-    the engine's scan output equals the unrolled engine's exactly."""
+    the stacked tree's greedy tokens (``infer/generate.py``, the path
+    that decodes a stacked model) equal the unrolled tree's exactly."""
+    from llm_in_practise_tpu.infer.generate import generate
     from llm_in_practise_tpu.models.qwen3 import (
         Qwen3, qwen3_config, stack_layer_params,
     )
-    from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
     from llm_in_practise_tpu.serve.quantized import QuantizedModel
 
     cfg_u = qwen3_config(vocab_size=128, compute_dtype="float32")
@@ -202,16 +203,17 @@ def test_quantized_scan_serving_int8(rng):
     qs = stack_layer_params(qu, cfg_u.n_layer)
 
     def run(model, params):
-        eng = InferenceEngine(
+        out = generate(
             QuantizedModel(model, compute_dtype=jnp.float32,
                            use_kernels=False),
-            params, max_slots=2, cache_len=64, cache_dtype=jnp.float32)
-        return eng.generate(list(range(1, 9)),
-                            SamplingParams(greedy=True, max_tokens=8))
+            params, jnp.asarray([list(range(1, 9))], jnp.int32),
+            max_new_tokens=8, greedy=True, cache_len=64,
+            cache_dtype=jnp.float32)
+        return np.asarray(out).tolist()
 
     a = run(Qwen3(cfg_u), qu)
     b = run(Qwen3(cfg_u.replace(scan_layers=True)), qs)
-    assert a == b
+    assert a == b and len(a[0]) == 16
 
 
 def test_kernel_matmul_on_tpu():

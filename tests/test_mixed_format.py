@@ -10,6 +10,7 @@ on-TPU latency evidence is the round-5 14B serve ladder artifact.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from llm_in_practise_tpu.peft.fused import _is_quant
 from llm_in_practise_tpu.peft.qlora import (
@@ -97,13 +98,12 @@ def test_mixed_tree_serves_greedy_close_to_bf16():
 
 
 def test_mixed_stacked_scan_matches_unrolled():
-    """Mixed quantization commutes with the scan layout: quantize-
-    then-stack equals serving the stacked tree (engine exactness)."""
+    """Mixed quantization commutes with the scan layout: the
+    quantize-then-stack tree decodes (``infer/generate.py``) the
+    unrolled tree's greedy tokens exactly."""
+    from llm_in_practise_tpu.infer.generate import generate
     from llm_in_practise_tpu.models.qwen3 import (
         Qwen3, qwen3_config, stack_layer_params,
-    )
-    from llm_in_practise_tpu.serve.engine import (
-        InferenceEngine, SamplingParams,
     )
     from llm_in_practise_tpu.serve.quantized import QuantizedModel
 
@@ -115,13 +115,14 @@ def test_mixed_stacked_scan_matches_unrolled():
     qs = stack_layer_params(qu, cfg.n_layer)
 
     def run(model, p):
-        eng = InferenceEngine(
+        out = generate(
             QuantizedModel(model, compute_dtype=jnp.float32,
                            use_kernels=False),
-            p, max_slots=2, cache_len=64, cache_dtype=jnp.float32)
-        return eng.generate(list(range(1, 9)),
-                            SamplingParams(greedy=True, max_tokens=8))
+            p, jnp.asarray([list(range(1, 9))], jnp.int32),
+            max_new_tokens=8, greedy=True, cache_len=64,
+            cache_dtype=jnp.float32)
+        return np.asarray(out).tolist()
 
     a = run(Qwen3(cfg), qu)
     b = run(Qwen3(cfg.replace(scan_layers=True)), qs)
-    assert a == b
+    assert a == b and len(a[0]) == 16

@@ -1,18 +1,16 @@
-"""Scan-layers cached decode: stacked KV cache == unrolled, incl. engine.
+"""Scan-layers cached decode: stacked KV cache == unrolled.
 
-Round 3 feature: ``scan_layers=True`` previously served training only
-(cached decode raised). Now ``init_cache`` returns a stacked
-``[{k: (L, B, T, H, D), v: ..., index}]`` cache and decode scans one
-block over the depth axis — the serving program compiles O(1) in
-``n_layer`` instead of O(n) (the same property the training path got in
-round 2). The reference never needs this (HF/vLLM handle its deep
-models); on TPU through an AOT compile service it is what makes serving
-a 36-layer model's engine programs compile in seconds.
+``scan_layers=True`` is the training layout, and its cached forward is
+what post-training sampling (``infer/generate.py``) decodes through:
+``init_cache`` returns a stacked ``[{k: (L, B, T, H, D), v: ...,
+index}]`` cache and decode scans one block over the depth axis.
 
-These tests pin exact equality between the two layouts at every level:
-raw prefill/decode, vector (per-slot) indices, and the full engine with
-chunked prefill, prefix-cache reuse, batched admission, multi-step
-decode, and ngram speculation.
+These tests pin exact equality between the two layouts at the model
+level: raw prefill/decode, vector (per-slot) indices, and greedy
+generation over packed weights. The serving engine serves the unrolled
+layout only (PR 31): it refuses a stacked model at construction, and
+its readers refuse the stacked rows an older replica may have left in
+a shared KV pool.
 """
 
 import jax
@@ -82,84 +80,92 @@ def test_vector_index_per_slot_depth(models):
     assert float(jnp.abs(cs2[0]["k"][:, 1, 3]).sum()) == 0
 
 
-def _run_engine(model, params, **kw):
-    eng = InferenceEngine(model, params, max_slots=4, cache_len=128,
-                          chunked_prefill=16, prefix_cache=True, **kw)
-    eng.start()
-    rng = np.random.default_rng(1)
-    prompts = [list(map(int, rng.integers(0, 128, n)))
-               for n in (5, 23, 40, 7, 40)]
-    reqs = [eng.submit(p, SamplingParams(greedy=True, max_tokens=12))
-            for p in prompts]
-    outs = [r.result() for r in reqs]
-    eng.stop()
-    return outs
-
-
-def test_engine_scan_equals_unrolled(models):
-    """Full engine: bucketed + batched + chunked prefill, prefix-cache
-    hit (two identical 40-token prompts), slot insert/activate."""
-    mu, pu, ms, ps = models
-    assert _run_engine(mu, pu) == _run_engine(ms, ps)
-
-
-def test_engine_scan_multistep_and_spec(models):
-    mu, pu, ms, ps = models
-    base = _run_engine(mu, pu)
-    assert base == _run_engine(ms, ps, decode_steps=4)
-    assert base == _run_engine(ms, ps, speculative_k=3)
-
-
 def test_quantized_scan_serving_equals_unrolled(models):
-    """NF4 serving under scan: stacked quant components ride the scan as
+    """NF4 decode under scan: stacked quant components ride the scan as
     sideband inputs (layers.scan_sideband) and the fused interceptor
-    serves each layer's slice — W4 serving programs that compile O(1) in
-    depth. XLA dequant path here (Pallas kernels need the TPU)."""
+    serves each layer's slice — greedy tokens of the stacked tree equal
+    the unrolled tree's. XLA dequant path here (Pallas kernels need the
+    TPU)."""
+    from llm_in_practise_tpu.infer.generate import generate
     from llm_in_practise_tpu.peft.qlora import quantize_base
     from llm_in_practise_tpu.serve.quantized import QuantizedModel
 
     mu, pu, ms, _ = models
     qu = quantize_base(pu)
     qs = stack_layer_params(qu, mu.cfg.n_layer)
-    a = _run_engine(QuantizedModel(mu, compute_dtype=jnp.float32,
-                                   use_kernels=False), qu)
-    b = _run_engine(QuantizedModel(ms, compute_dtype=jnp.float32,
-                                   use_kernels=False), qs)
-    assert a == b
+    rng = np.random.default_rng(1)
+    prompts = jnp.asarray(rng.integers(0, 128, (3, 23)), jnp.int32)
+
+    def run(model, params):
+        return np.asarray(generate(
+            QuantizedModel(model, compute_dtype=jnp.float32,
+                           use_kernels=False),
+            params, prompts, max_new_tokens=12, greedy=True,
+            cache_len=64, cache_dtype=jnp.float32))
+
+    a, b = run(mu, qu), run(ms, qs)
+    assert a.shape == (3, 35)
+    np.testing.assert_array_equal(a, b)
 
 
-def test_prefix_entries_layout_tagged(models):
-    """A scan engine must not consume unrolled-layout prefix rows from a
-    shared pool (their shapes are transposed relative to its writes) —
-    entries carry slot_axis and lookup filters on it."""
-    from llm_in_practise_tpu.serve.kv_pool import (
-        HostKVPool, TieredKV, decode_entry, encode_entry, entry_to_host,
-    )
+def test_engine_refuses_stacked_model(models):
+    """One decision, one place: a model whose KV buffers put the layer
+    on axis 0 is refused when the engine is built — bare, wrapped
+    (``QuantizedModel`` passes ``cache_slot_axis`` through), as the
+    draft, and whatever the KV layout — and the message names the
+    layout the engine does serve."""
+    from llm_in_practise_tpu.serve.quantized import QuantizedModel
 
     mu, pu, ms, ps = models
+    with pytest.raises(ValueError, match="unrolled layout"):
+        InferenceEngine(ms, ps, max_slots=2, cache_len=64)
+    with pytest.raises(ValueError, match="unrolled layout"):
+        InferenceEngine(ms, ps, max_slots=2, cache_len=64,
+                        kv_layout="paged")
+    with pytest.raises(ValueError, match="unrolled layout"):
+        InferenceEngine(QuantizedModel(ms, compute_dtype=jnp.float32,
+                                       use_kernels=False),
+                        ps, max_slots=2, cache_len=64)
+    with pytest.raises(ValueError, match="draft_model.*unrolled layout"):
+        InferenceEngine(mu, pu, max_slots=2, cache_len=64,
+                        speculative_k=2, draft_model=ms, draft_params=ps)
+
+
+@pytest.mark.parametrize("kv_layout", ["contiguous", "paged"])
+def test_stacked_rows_in_a_shared_pool_are_refused(models, kv_layout):
+    """``slot_axis`` stays on the kv-pool wire: rows an older (stacked)
+    replica wrote are transposed relative to this engine's writes, so
+    the reader must pass them by — same tokens, no pool hit — where
+    the same rows tagged 0 are reused."""
+    from llm_in_practise_tpu.serve.kv_pool import (
+        HostKVPool, TieredKV, decode_entry, encode_entry,
+    )
+
+    mu, pu, _, _ = models
     pool = HostKVPool(max_tokens=1 << 16)
     prompt = list(range(40))
 
-    def serve_one(model, params):
-        eng = InferenceEngine(
-            model, params, max_slots=2, cache_len=128, prefix_cache=True,
-            kv_pool=TieredKV(host_pool=pool, async_offload=False))
+    def serve_one():
+        tiers = TieredKV(host_pool=pool, async_offload=False)
+        eng = InferenceEngine(mu, pu, max_slots=2, cache_len=128,
+                              prefix_cache=True, kv_layout=kv_layout,
+                              kv_pool=tiers)
         eng.start()
-        out = eng.submit(prompt, SamplingParams(
-            greedy=True, max_tokens=4)).result()
+        req = eng.submit(prompt, SamplingParams(greedy=True, max_tokens=4))
+        out = req.result()
         eng.stop()
-        return out
+        return out, req.cache_outcome
 
-    a = serve_one(mu, pu)          # unrolled engine seeds the pool
+    first, outcome = serve_one()            # seeds the pool, tagged 0
     hosts = list(pool._entries.values())
-    assert hosts and all(h.slot_axis == 0 for h in hosts)
-    b = serve_one(ms, ps)          # scan engine: must NOT reuse those rows
-    assert a == b
-    # serialization round-trips the tag
-    again = decode_entry(encode_entry(hosts[0]))
-    assert again.slot_axis == hosts[0].slot_axis == 0
-    # the scan engine's own write-through is tagged with ITS layout
-    assert any(h.slot_axis == 1 for h in pool._entries.values())
+    assert outcome == "cold" and hosts
+    assert all(h.slot_axis == 0 for h in hosts)
+    assert serve_one() == (first, "hit")    # a restarted engine reuses it
+    for h in hosts:
+        h.slot_axis = 1
+    # the tag survives the wire
+    assert decode_entry(encode_entry(hosts[0])).slot_axis == 1
+    assert serve_one() == (first, "cold")
 
 
 def test_quantized_scan_no_cache_forward(models):
@@ -179,26 +185,3 @@ def test_quantized_scan_no_cache_forward(models):
                        use_kernels=False).apply({"params": qs}, x)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-4, atol=1e-4)
-
-
-def test_quantized_scan_speculative_equals_plain(models):
-    """Speculative decode over a quantized scan model (the 8B int8
-    serving combo): spec + sideband + stacked KV must stay token-exact
-    vs the same engine without speculation."""
-    from llm_in_practise_tpu.peft.qlora import quantize_base
-    from llm_in_practise_tpu.serve.quantized import QuantizedModel
-
-    mu, pu, ms, _ = models
-    qs = stack_layer_params(quantize_base(pu), mu.cfg.n_layer)
-    qm = QuantizedModel(ms, compute_dtype=jnp.float32, use_kernels=False)
-    # repetitive prompts so drafts actually fire
-    def run(**kw):
-        eng = InferenceEngine(qm, qs, max_slots=2, cache_len=128, **kw)
-        out = eng.generate([3, 7, 11] * 8,
-                           SamplingParams(greedy=True, max_tokens=16))
-        return out, getattr(eng, "spec_proposed", 0)
-
-    plain, _ = run()
-    spec, proposed = run(speculative_k=4)
-    assert spec == plain
-    assert proposed > 0

@@ -287,7 +287,10 @@ def _store(**kw):
     store = SessionStore(**kw)
     store.attach(types.SimpleNamespace(
         handoff=None,
-        paged=types.SimpleNamespace(pool=pool, page_size=16)))
+        # page_bytes 0: a fake pool's pins book nothing to the
+        # process-wide HBM ledger
+        paged=types.SimpleNamespace(pool=pool, page_size=16,
+                                    page_bytes=0)))
     return store, pool
 
 
@@ -340,7 +343,10 @@ def test_store_reclaim_chains_after_prior_hook():
     store = SessionStore(ttl_s=100.0)
     store.attach(types.SimpleNamespace(
         handoff=None,
-        paged=types.SimpleNamespace(pool=pool, page_size=16)))
+        # page_bytes 0: a fake pool's pins book nothing to the
+        # process-wide HBM ledger
+        paged=types.SimpleNamespace(pool=pool, page_size=16,
+                                    page_bytes=0)))
     store.note_finish("s", list(range(64)), [1, 2, 3, 4])
     assert pool.reclaim(3) == 3                 # 2 prior + 1 session pin
     assert store.lookup("s").pages == [1, 2, 3]

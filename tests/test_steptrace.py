@@ -669,10 +669,17 @@ def test_kvpool_handoff_wire_seconds():
         client.handoff_put("hg-1", entry)
         got = client.handoff_claim("hg-1")
         assert got is not None
-        fams = parse_exposition(server.metrics_text())
-        wire = fams["kvpool_handoff_wire_seconds"]
-        counts = {dict(k[1])["op"]: v for k, v in wire.samples.items()
-                  if k[0] == "kvpool_handoff_wire_seconds_count"}
+        # the sidecar books an op AFTER its reply is sent, so the
+        # client can be back before ``hclaim`` is counted: wait for it
+        deadline = time.monotonic() + 10.0
+        while True:
+            fams = parse_exposition(server.metrics_text())
+            wire = fams["kvpool_handoff_wire_seconds"]
+            counts = {dict(k[1])["op"]: v for k, v in wire.samples.items()
+                      if k[0] == "kvpool_handoff_wire_seconds_count"}
+            if counts["hclaim"] >= 1 or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert counts["hput"] >= 1
         assert counts["hclaim"] >= 1
         sums = {dict(k[1])["op"]: v for k, v in wire.samples.items()

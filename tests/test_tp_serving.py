@@ -22,8 +22,8 @@ over the device mesh —
   idiom) matches psum within its error bound and the golden-token
   check gates the opt-in;
 - serve_openai's validation: the quantized_dir/draft fail-fasts are
-  deleted, the scan-layers error survives and names the
-  contiguous-only limitation;
+  deleted, and so is the ``--scan-layers`` flag (the server builds
+  the unrolled layout only);
 - `llm_collective_{bytes,seconds}_total` and `llm_tp_size` render at
   /metrics with live values;
 - the XLA_FLAGS recipe works from a clean subprocess (no harness
@@ -310,10 +310,10 @@ def _validate(**kw):
     sys.path.insert(0, "examples")
     from examples.serve_openai import validate_args
 
-    defaults = dict(quantized_dir=None, lora_modules=[], scan_layers=False,
-                    tp=1, tp_quantized_collectives=False, role="both",
-                    kv_remote=None, kv_layout="paged",
-                    draft_model_path=None, speculative=None)
+    defaults = dict(quantized_dir=None, lora_modules=[], tp=1,
+                    tp_quantized_collectives=False, role="both",
+                    kv_remote=None, draft_model_path=None,
+                    speculative=None)
     defaults.update(kw)
     args = types.SimpleNamespace(**defaults)
 
@@ -334,11 +334,17 @@ def test_cli_tp_fail_fasts_deleted():
     assert args.speculative == 4
 
 
-def test_cli_scan_layers_tp_error_names_the_limitation():
-    """scan-layers × TP keeps failing fast, and the message points at
-    the contiguous-only limitation (the tested contract)."""
-    with pytest.raises(_CliError, match="contiguous-only"):
-        _validate(tp=2, scan_layers=True, kv_layout="contiguous")
+def test_cli_scan_layers_flag_is_gone(capsys):
+    """The server builds one kind of engine: ``--scan-layers`` is an
+    unknown argument, and the parsed namespace carries no such key."""
+    sys.path.insert(0, "examples")
+    from examples.serve_openai import build_parser
+
+    with pytest.raises(SystemExit) as exc:
+        build_parser().parse_args(["--scan-layers"])
+    assert exc.value.code == 2
+    assert "unrecognized arguments: --scan-layers" in capsys.readouterr().err
+    assert not hasattr(build_parser().parse_args([]), "scan_layers")
 
 
 def test_cli_quantized_collectives_combos():

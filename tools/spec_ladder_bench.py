@@ -7,8 +7,7 @@ setup: ``decode_steps > 1``, paged KV, greedy traffic):
   round must beat);
 - **ngram** — prompt-lookup speculation (no extra weights);
 - **draft** — draft-MODEL speculation (a smaller trained model
-  proposes; ``tools/tpu_spec_draft_8b.py`` is the 8B-scale variant of
-  this leg).
+  proposes).
 
 The thing under test is the **fused spec round**
 (``serve/mixed_step.spec_verify_block``): the engine verifies the k

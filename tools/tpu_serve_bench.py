@@ -84,8 +84,7 @@ def main() -> None:
     # speculation leg (ISSUE 9 / ROADMAP item 4): SERVE_SPEC=ngram runs
     # the prompt-lookup proposer, SERVE_SPEC=draft a SELF-speculative
     # draft — the target's first SERVE_SPEC_DRAFT_LAYERS blocks sharing
-    # the stem/head, the tools/tpu_spec_draft_8b.py config at this
-    # model scale. Either way the fused spec round verifies the k
+    # the stem/head. Either way the fused spec round verifies the k
     # drafts inside the decode-steps block's dispatch; the dedicated
     # cross-leg A/B artifact is tools/spec_ladder_bench.py
     # (BENCH_SPEC_LADDER_r07.json).
