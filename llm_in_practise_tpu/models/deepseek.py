@@ -366,9 +366,23 @@ class DeepSeekLike(nn.Module):
         deterministic: bool = True,
         cache: list[Cache] | None = None,
         positions: jax.Array | None = None,
+        # the forward in two halves (see models/qwen3.py): final-norm
+        # hidden states out, or ``idx`` IS such states and the output
+        # head alone runs
+        return_hidden: bool = False,
+        head_only: bool = False,
     ):
         cfg = self.config
         compute_dtype = jnp.dtype(cfg.compute_dtype)
+
+        def head(x):
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False,
+                kernel_init=layers.dense_init, name="lm_head",
+            )(x)
+
+        if head_only:
+            return head(idx)
         x = nn.Embed(
             cfg.vocab_size, cfg.embed_dim,
             embedding_init=layers.dense_init, name="tok_embed",
@@ -396,10 +410,9 @@ class DeepSeekLike(nn.Module):
                 new_cache.append(layer_cache)
 
         x = nn.LayerNorm(name="ln_f")(x.astype(jnp.float32))
-        logits = nn.Dense(
-            cfg.vocab_size, use_bias=False, kernel_init=layers.dense_init,
-            name="lm_head",
-        )(x)
+        if return_hidden:
+            return (x, new_cache) if cache is not None else x
+        logits = head(x)
         if cache is not None:
             return logits, new_cache
         return logits

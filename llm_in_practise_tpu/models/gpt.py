@@ -69,15 +69,28 @@ class GPT(nn.Module):
         cache: list[layers.Cache] | None = None,
         positions: jax.Array | None = None,
         return_hidden: bool = False,
+        # ``idx`` IS final-norm hidden states: apply the output head
+        # alone (the other half of ``return_hidden``; see models/qwen3.py)
+        head_only: bool = False,
     ):
         cfg = self.config
-        b, l = idx.shape
         compute_dtype = jnp.dtype(cfg.compute_dtype)
 
         embed = nn.Embed(
             cfg.vocab_size, cfg.embed_dim,
             embedding_init=layers.dense_init, name="tok_embed",
         )
+
+        def head(x):
+            if cfg.tie_weights:
+                return embed.attend(x)
+            return nn.Dense(
+                cfg.vocab_size, kernel_init=layers.dense_init, name="lm_head"
+            )(x)
+
+        if head_only:
+            return head(idx)
+        b, l = idx.shape
         x = embed(idx)
 
         if positions is None:
@@ -126,12 +139,7 @@ class GPT(nn.Module):
             # the HF_Basics sequence-classification demos); the LM head's
             # params are simply never created in this configuration
             return (x, new_cache) if cache is not None else x
-        if cfg.tie_weights:
-            logits = embed.attend(x)
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, kernel_init=layers.dense_init, name="lm_head"
-            )(x)
+        logits = head(x)
         if cache is not None:
             return logits, new_cache
         return logits

@@ -122,6 +122,44 @@ def cache_update(buf: jax.Array, new: jax.Array, index: jax.Array) -> jax.Array:
     return jax.lax.dynamic_update_slice(buf, new, (0, index, *trailing))
 
 
+# --- a prefill's tail: one row of logits a prompt ---------------------------
+# A program that feeds a prompt (or a chunk of one) reads the logits of one
+# position a row, so it takes the final-norm hidden state there BEFORE the
+# output head: the head over every position is the widest matmul of the
+# forward, (L, hidden) x (hidden, vocab), for rows nobody reads. The two
+# halves are keywords of every in-tree model's one compact ``__call__``
+# (``return_hidden`` / ``head_only``), so both pass through whatever
+# interceptors the serving facades install (packed weights, adapters,
+# collectives). These are the only callers of the pair in ``serve/``.
+
+
+def last_position_hidden(model, params, ids, lens, cache):
+    """The trunk over ``ids`` (B, L) against ``cache``; each row's
+    final-norm hidden state at its last real position ``lens - 1``.
+    Returns ``((B, hidden), cache)``."""
+    hidden, cache = model.apply(
+        {"params": params}, ids, deterministic=True, cache=cache,
+        return_hidden=True)
+    last = jnp.take_along_axis(
+        hidden, jnp.maximum(lens - 1, 0).reshape(-1, 1, 1), axis=1)[:, 0, :]
+    return last, cache
+
+
+def head_logits(model, params, hidden):
+    """(B, hidden) final-norm states -> (B, vocab) logits through the
+    model's own output head."""
+    return model.apply(
+        {"params": params}, hidden[:, None, :], deterministic=True,
+        head_only=True)[:, 0, :]
+
+
+def last_position_logits(model, params, ids, lens, cache):
+    """``model.apply(ids)``'s logits at ``lens - 1`` for every row,
+    computed from that position alone. Returns ``((B, vocab), cache)``."""
+    last, cache = last_position_hidden(model, params, ids, lens, cache)
+    return head_logits(model, params, last), cache
+
+
 def init_cache(
     batch: int, max_len: int, n_kv_head: int, head_dim: int, n_layer: int,
     dtype=jnp.bfloat16,

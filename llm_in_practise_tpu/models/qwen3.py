@@ -373,6 +373,11 @@ class Qwen3(nn.Module):
         cache: list[Cache] | None = None,
         positions: jax.Array | None = None,
         return_hidden: bool = False,  # final-norm hidden states (embedder use)
+        # ``idx`` IS final-norm hidden states (..., hidden): apply the
+        # output head alone. With ``return_hidden`` it splits the forward
+        # in two, so a caller can pick positions before the head
+        # (``layers.last_position_logits``: a prefill's one row of logits)
+        head_only: bool = False,
         # Per-layer side inputs for the scan paths (leading n_layer axis;
         # e.g. stacked packed quantized weights, stacked LoRA factors) —
         # scanned alongside each layer's slice and published to
@@ -391,6 +396,16 @@ class Qwen3(nn.Module):
             cfg.vocab_size, cfg.hidden_size,
             embedding_init=nn.initializers.normal(0.02), name="tok_embed",
         )
+
+        def head(x):
+            if cfg.tie_word_embeddings:
+                return embed.attend(x.astype(jnp.float32))
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False, name="lm_head"
+            )(x.astype(jnp.float32))
+
+        if head_only:
+            return head(idx)
         x = embed(idx).astype(compute_dtype)
         # One table pair per forward; constant-folded under jit.
         rope_tables = rope_ops.precompute_cos_sin(
@@ -453,12 +468,7 @@ class Qwen3(nn.Module):
             # with a cache the refreshed cache must come back too, or the
             # caller's KV writes are dead code and get eliminated
             return (x, new_caches) if cache is not None else x
-        if cfg.tie_word_embeddings:
-            logits = embed.attend(x.astype(jnp.float32))
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, name="lm_head"
-            )(x.astype(jnp.float32))
+        logits = head(x)
         if cache is not None:
             return logits, new_caches
         return logits

@@ -198,9 +198,21 @@ class SDARMoE(nn.Module):
     @nn.compact
     def __call__(self, idx: jax.Array, *, deterministic: bool = True,
                  cache: list[Cache] | None = None,
-                 positions: jax.Array | None = None):
+                 positions: jax.Array | None = None,
+                 return_hidden: bool = False, head_only: bool = False):
+        # ``return_hidden`` / ``head_only``: the forward in two halves
+        # (see models/qwen3.py)
         cfg = self.cfg
         compute = jnp.dtype(cfg.compute_dtype)
+
+        def head(x):
+            w = self.param("lm_head", nn.initializers.normal(0.02),
+                           (cfg.hidden_size, cfg.vocab_size))
+            return jnp.dot(x.astype(compute), w.astype(compute),
+                           preferred_element_type=jnp.float32)
+
+        if head_only:
+            return head(idx)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                          embedding_init=nn.initializers.normal(0.02),
                          name="tok_embed")
@@ -216,10 +228,9 @@ class SDARMoE(nn.Module):
             if new_caches is not None:
                 new_caches.append(layer_cache)
         x = RMSNorm(cfg.rms_norm_eps, name="ln_f")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.hidden_size, cfg.vocab_size))
-        logits = jnp.dot(x.astype(compute), head.astype(compute),
-                         preferred_element_type=jnp.float32)
+        if return_hidden:
+            return (x, new_caches) if cache is not None else x
+        logits = head(x)
         if cache is not None:
             return logits, new_caches
         return logits

@@ -180,6 +180,7 @@ class StepTrace:
         self._chunk_row_slots = 0
         self._block = [0, 0, 0, 0]   # rows, commits, revealed, committed
         self._sampler_tier: str | None = None
+        self._first_tokens = {"program": 0, "host": 0}
         self._last_end: float | None = None   # previous step_end, perf
         # the open dispatch window (``_win_t0`` None: none open); the
         # annotation is that of the part under way, issue then wait
@@ -322,6 +323,14 @@ class StepTrace:
         if self._recording:
             self._sampler_tier = tier
 
+    def note_first_token(self, path: str) -> None:
+        """A prompt that finished in a chunk, mixed or suffix program of
+        this step got its first token from the ``program`` or from the
+        ``host`` fallback: the record's ``first_tokens_program`` /
+        ``first_tokens_host``."""
+        if self._recording:
+            self._first_tokens[path] += 1
+
     def step_begin(self, *, lock_wait_s: float = 0.0) -> None:
         """Open a step record. ``lock_wait_s``: what the caller waited
         for the engine's step lock before this call (a record field, not
@@ -339,6 +348,7 @@ class StepTrace:
         self._chunk_row_slots = 0
         self._block = [0, 0, 0, 0]
         self._sampler_tier = None
+        self._first_tokens = {"program": 0, "host": 0}
         self._acts = {}
         self._device_s = 0.0
         self._issue_s = 0.0
@@ -398,6 +408,8 @@ class StepTrace:
             "tokens_revealed": self._block[2],
             "tokens_committed": self._block[3],
             "sampler_tier": self._sampler_tier,
+            "first_tokens_program": self._first_tokens["program"],
+            "first_tokens_host": self._first_tokens["host"],
             "activities": dict(self._acts),
             "segments": [(name, t0 + off, t1 + off)
                          for name, t0, t1 in self._segments],

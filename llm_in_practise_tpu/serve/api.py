@@ -744,6 +744,14 @@ class OpenAIServer:
                          lambda: eng.prefill_chunk_row_slots,
                          "rows the device computed for those chunks "
                          "(the contiguous slot plane's idle rows included)")
+        reg.counter_func(
+            "llm_first_tokens_total",
+            lambda: [({"path": p}, n) for p, n in eng.first_tokens.items()],
+            "prompts that finished in a chunk, fused mixed or suffix "
+            "program, by where their first token was sampled: in that "
+            "program, or by the host fallback from its logits (a "
+            "grammar's start state, a resumed stream, the contiguous "
+            "layout)")
         blk = getattr(eng, "block", None)
         if blk is not None:
             # block-diffusion decoding (serve/block_step.py): passes are

@@ -301,10 +301,7 @@ class BlockDecoder:
         eng.slot_ready[slot] = True
         eng.slot_len[slot] = plen
         eng.slot_budget[slot] = req.params.max_tokens
-        eng._temperature[slot] = req.params.temperature
-        eng._top_k[slot] = req.params.top_k
-        eng._top_p[slot] = req.params.top_p
-        eng._greedy[slot] = req.params.greedy
+        eng._slot_sampling(slot, req.params)
         eng.slot_hist[slot] = None
         eng.slot_constraint[slot] = None
         self.steps[slot] = steps

@@ -48,6 +48,11 @@ from llm_in_practise_tpu.infer.sampling import (
     sample_token_batched,
     sampler_tier_name,
 )
+from llm_in_practise_tpu.models.layers import (
+    head_logits,
+    last_position_hidden,
+    last_position_logits,
+)
 from llm_in_practise_tpu.obs.cost import CostModel, tree_bytes
 from llm_in_practise_tpu.obs.hbm import get_ledger, host_entry_bytes
 from llm_in_practise_tpu.obs.logging import get_logger
@@ -58,6 +63,7 @@ from llm_in_practise_tpu.obs.steptrace import StepTrace
 from llm_in_practise_tpu.obs.trace import get_tracer
 from llm_in_practise_tpu.serve.mixed_step import (
     batched_chunk,
+    batched_chunk_hidden,
     decode_scan,
     make_masked_mixed_step,
     make_mixed_step,
@@ -807,6 +813,9 @@ class InferenceEngine:
         # the device computed for them (_note_chunk_rows)
         self.prefill_chunk_rows = 0
         self.prefill_chunk_row_slots = 0
+        # prompts that finished in a chunk, mixed or suffix program, by
+        # where their first token was sampled (_note_first_token)
+        self.first_tokens = {"program": 0, "host": 0}
         self._log = get_logger("serve.engine")
         # request tracing (obs/trace.py): spans parent to each request's
         # TraceContext; the process default keeps a single-process stack
@@ -939,6 +948,14 @@ class InferenceEngine:
                            tree_bytes(self.draft_params))
             self._hbm_book("kv.draft", tree_bytes(self.draft_cache))
 
+        # Every prefill program takes a row's last hidden state BEFORE
+        # the output head (models/layers.py); a model that cannot split
+        # its forward there is refused here, not left to run the head
+        # over every position of every chunk.
+        self._hidden_like = self._split_head_probe(model, self.params)
+        if draft_model is not None:
+            self._split_head_probe(draft_model, self.draft_params)
+
         # Dispatch accounting: every jitted engine program is wrapped so
         # /metrics (llm_dispatches_*) and the mixed-step tests can assert
         # dispatches/step instead of inferring it from wall-clock. The
@@ -958,6 +975,7 @@ class InferenceEngine:
                                        static_argnames=("m",)))
         self._prefill = _c(jax.jit(self._prefill_fn))
         self._prefill_suffix = _c(jax.jit(self._prefill_suffix_fn))
+        self._sample_first = _c(jax.jit(self._sample_first_fn))
         self._insert = _c(jax.jit(self._insert_fn, donate_argnums=(0,),
                                   static_argnames=("slot",)))
         self._insert_batch = _c(jax.jit(self._insert_batch_fn,
@@ -1006,7 +1024,6 @@ class InferenceEngine:
             self._pg_spec = _c(jax.jit(self._paged_spec_fn,
                                        donate_argnums=(1,),
                                        static_argnames=("m",)))
-            self._chunk_last_like = None   # _paged_chunk_fn
             self._pg_chunk = _c(jax.jit(self._paged_chunk_fn,
                                         donate_argnums=(1,)))
             self._pg_mixed = _c(jax.jit(self._paged_mixed_fn,
@@ -1116,6 +1133,38 @@ class InferenceEngine:
 
     # --- jitted pieces -------------------------------------------------------
 
+    def _split_head_probe(self, model, params):
+        """One abstract trace of the two halves every prefill program
+        is built from (``models/layers.py``). Returns the shape and dtype
+        of a row's final-norm hidden state, ``(1, hidden)``."""
+        def halves(p):
+            last, _ = last_position_hidden(
+                model, p, jnp.zeros((1, 8), jnp.int32),
+                jnp.ones((1,), jnp.int32),
+                model.init_cache(1, 8, dtype=self.cache_dtype))
+            return last, head_logits(model, p, last)
+
+        try:
+            return jax.eval_shape(halves, params)[0]
+        except TypeError as e:
+            raise ValueError(
+                f"{type(model).__name__} cannot split its forward before "
+                "the output head: the engine's prefill programs need the "
+                "`return_hidden` and `head_only` keywords on its "
+                f"__call__ (models/layers.py): {e}") from e
+
+    def _sample_first_fn(self, rng, logits, temperature, top_k, top_p,
+                         greedy, *gmask):
+        """First tokens from (B, vocab) prefill logits ON THE HOST PATH:
+        a one-shot admission batch, and a chunked prompt whose logits
+        the host reads first (a grammar's start state: ``gmask``, at
+        most one additive (B, vocab) mask). One program a batch size;
+        the sampler is the decode programs'."""
+        logits = sum(gmask, logits.astype(jnp.float32))
+        return sample_token_batched(
+            rng, logits, temperature=temperature, top_k=top_k,
+            top_p=top_p, greedy=greedy).astype(jnp.int32)
+
     def _vectorize_cache_index(self):
         """Scalar per-layer cache index -> (max_slots,) vector."""
         for layer in self.cache:
@@ -1209,13 +1258,8 @@ class InferenceEngine:
         properly for short prompts)."""
         B, bucket = prompt_ids.shape
         cache = self.model.init_cache(B, bucket, dtype=self.cache_dtype)
-        logits, cache = self.model.apply(
-            {"params": params}, prompt_ids, deterministic=True, cache=cache
-        )
-        last = jnp.take_along_axis(
-            logits, (length - 1)[:, None, None], axis=1
-        )[:, 0, :]
-        return last, cache
+        return last_position_logits(self.model, params, prompt_ids,
+                                    length, cache)
 
     def _primed(self, cache, prefix_rows, prefix_len):
         """Fresh 1-slot cache with prefix KV rows inserted, index offset."""
@@ -1240,14 +1284,9 @@ class InferenceEngine:
         equals a cold prefill of the full prompt.
         """
         cache = self.model.init_cache(1, self.cache_len, dtype=self.cache_dtype)
-        logits, cache = self.model.apply(
-            {"params": params}, suffix_ids, deterministic=True,
-            cache=self._primed(cache, prefix_rows, prefix_len)
-        )
-        last = jnp.take_along_axis(
-            logits, (suffix_len - 1)[None, None, None], axis=1
-        )[:, 0, :]
-        return last, cache
+        return last_position_logits(
+            self.model, params, suffix_ids, suffix_len,
+            self._primed(cache, prefix_rows, prefix_len))
 
     def _chunk_slot_fn(self, params, cache, chunk_ids, slot, done,
                        chunk_len):
@@ -1277,9 +1316,8 @@ class InferenceEngine:
                     m[key] = jax.lax.dynamic_slice_in_dim(
                         buf, slot, 1, axis=0)
             mini.append(m)
-        logits, mini = model.apply(
-            {"params": params}, chunk_ids, deterministic=True, cache=mini
-        )
+        last, mini = last_position_logits(model, params, chunk_ids,
+                                          chunk_len, mini)
         width = chunk_ids.shape[1]
         new = []
         for layer, m2 in zip(cache, mini):
@@ -1295,9 +1333,6 @@ class InferenceEngine:
                         buf, rows.astype(buf.dtype),
                         (slot, done) + (zero,) * (buf.ndim - 2))
             new.append(out)
-        last = jnp.take_along_axis(
-            logits, (chunk_len - 1)[None, None, None], axis=1
-        )[:, 0, :]
         return last, new
 
     # shared pin/advance idiom of the batched chunk, draft, and fused
@@ -1348,19 +1383,14 @@ class InferenceEngine:
         next round's catch-up (overwrite-before-attend, as everywhere
         else in this engine)."""
         model = self.draft_model
-        logits, cache2 = model.apply(
-            {"params": params}, catchup, deterministic=True,
-            cache=self._pin_index(cache, starts)
-        )
+        last, cache2 = last_position_logits(
+            model, params, catchup, lens, self._pin_index(cache, starts))
         # the catch-up apply advanced every row's index by the PADDED
         # width W; re-pin to the true filled length before rolling, or
         # draft tokens 2..k decode at wrong RoPE positions and write
         # their KV above the watermark (review r5: draft quality
         # collapsed to ~1 usable token whenever the gap < W)
         cache2 = self._pin_index(cache2, starts + lens)
-        last = jnp.take_along_axis(
-            logits, jnp.maximum(lens - 1, 0)[:, None, None], axis=1
-        )[:, 0, :]
         first = jnp.argmax(last, axis=-1).astype(jnp.int32)
 
         def body(carry, _):
@@ -1603,9 +1633,11 @@ class InferenceEngine:
             pool, view, sidx, index_vec)
 
     def _paged_chunk_fn(self, params, pool, slots, gidx, chunk_ids,
-                        starts, lens, sidx, n_rows):
+                        starts, lens, sidx, n_rows, finish, rng,
+                        temperature, top_k, top_p, greedy):
         """Advance the listed mid-prefill ROWS one chunk each against
-        the page pool: the device work follows the number of rows that
+        the page pool, and end the prompts that finish here in their
+        first token: the device work follows the number of rows that
         chunk, not ``max_slots``.
 
         ``slots`` (R,) names each row's slot, ``gidx`` (R, W) / ``sidx``
@@ -1615,15 +1647,23 @@ class InferenceEngine:
         suffix) whatever the number of rows that chunk, so the compile
         key holds no row count: a loop with the TRACED trip count
         ``n_rows`` takes the first ``n_rows`` rows one a trip — gather
-        the row's pages, run the shared ``batched_chunk`` body on that
-        one-row view, scatter its chunk window back — and never visits
-        the padding behind them. One row a trip because a chip timing
-        found it the fastest per row at every row count (PERF.md, PR
-        28). Returns ``((max_slots, vocab) last-position logits by
-        slot, pool)``."""
+        the row's pages, run the shared ``batched_chunk_hidden`` body
+        on that one-row view, scatter its chunk window back — and never
+        visits the padding behind them. One row a trip because a chip
+        timing found it the fastest per row at every row count (PERF.md,
+        PR 28). A trip stops BEFORE the output head: the loop carries
+        each row's last-position hidden state by slot, and
+        :meth:`_prefill_tail` runs the head once over that plane, and
+        the sampler on its logits, when ``finish`` says some row's
+        prompt ends in this program (``rng`` and the slot plane's
+        sampling arrays are the sampler's, as in the decode programs).
+        Returns ``((max_slots,) first tokens, (max_slots, vocab)
+        last-position logits, pool)``, both by slot, meaningful for the
+        rows that finish, zeros when none does."""
         lora = current_lora()
 
-        def forward(pool, i):
+        def trip(i, carry):
+            pool, out = carry
             slot, r_gidx, r_ids, r_starts, r_lens, r_sidx = (
                 jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)
                 for a in (slots, gidx, chunk_ids, starts, lens, sidx))
@@ -1632,44 +1672,65 @@ class InferenceEngine:
             mine = None if lora is None else dict(lora, idx={
                 rb: jnp.take(ix, slot) for rb, ix in lora["idx"].items()})
             with lora_context(mine):
-                last, view = batched_chunk(self.model, params, view,
-                                           r_ids, r_starts, r_lens)
+                last, view = batched_chunk_hidden(
+                    self.model, params, view, r_ids, r_starts, r_lens)
             return (self._paged_writeback(pool, view, r_sidx, r_starts),
-                    slot[0], last)
+                    jax.lax.dynamic_update_slice_in_dim(
+                        out, last, slot[0], axis=0))
 
-        def trip(i, carry):
-            pool, out = carry
-            pool, slot, last = forward(pool, i)
-            return pool, jax.lax.dynamic_update_slice_in_dim(
-                out, last, slot, axis=0)
-
-        # a row's logits shape, by one abstract trace a process (it
-        # depends on neither the view width nor the adapters)
-        if self._chunk_last_like is None:
-            self._chunk_last_like = jax.eval_shape(
-                lambda p: forward(p, 0)[2], pool)
-        like = self._chunk_last_like
-        out = jnp.zeros((self.max_slots,) + like.shape[1:], like.dtype)
+        like = self._hidden_like
+        out = jnp.zeros((self.max_slots, like.shape[-1]), like.dtype)
         pool, out = jax.lax.fori_loop(0, n_rows, trip, (pool, out))
-        return out, pool
+        first, last = self._prefill_tail(
+            params, out, finish, rng, temperature, top_k, top_p, greedy)
+        return first, last, pool
+
+    def _prefill_tail(self, params, hidden, finish, rng, temperature,
+                      top_k, top_p, greedy):
+        """The tail of a paged prefill program: ``hidden``
+        (max_slots, hidden), the last-position states by slot, through
+        ONE pass of the output head and the decode programs' sampler,
+        under a ``cond`` on the traced ``finish``: three chunks in four
+        end no prompt and need neither. A block-diffusion engine
+        samples nothing from a prefill (its first block opens all-mask)
+        and gets zeros for tokens. Returns ``(first tokens, logits)``."""
+        def tail(hidden):
+            logits = head_logits(self.model, params, hidden)
+            if self.block is not None:
+                return jnp.zeros((self.max_slots,), jnp.int32), logits
+            first = sample_token_batched(
+                rng, logits.astype(jnp.float32), temperature=temperature,
+                top_k=top_k, top_p=top_p, greedy=greedy)
+            return first.astype(jnp.int32), logits
+
+        like = jax.eval_shape(tail, hidden)
+        return jax.lax.cond(
+            finish, tail,
+            lambda _: jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype), like),
+            hidden)
 
     def _paged_mixed_fn(self, params, pool, slots, pgidx, chunk_ids,
-                        starts, lens, psidx, n_rows, gidx, index_vec,
-                        sidx, tokens, rng, temperature, top_k, top_p,
-                        greedy, *, n, gmask=None):
+                        starts, lens, psidx, n_rows, finish, first_rng,
+                        gidx, index_vec, sidx, tokens, rng, temperature,
+                        top_k, top_p, greedy, *, n, gmask=None):
         """The paged fused mixed step, ONE dispatch: the prefill half
-        is :meth:`_paged_chunk_fn`'s loop over the rows that chunk; the
-        decode half is :meth:`_paged_multi_fn`'s body over the slot
+        is :meth:`_paged_chunk_fn`'s loop over the rows that chunk and
+        its tail (the slot plane's sampling arrays serve both halves;
+        ``first_rng`` is the tail's key, ``rng`` the decode block's);
+        the decode half is :meth:`_paged_multi_fn`'s body over the slot
         plane (mid-prefill and idle rows decode garbage into the trash
-        page). No decode row receives a chunk write."""
-        chunk_last, pool = self._paged_chunk_fn(
+        page). No decode row receives a chunk write. Returns ``(first
+        tokens, last-position logits, (max_slots, n) decode tokens,
+        pool)``."""
+        first, chunk_last, pool = self._paged_chunk_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
-            n_rows)
+            n_rows, finish, first_rng, temperature, top_k, top_p, greedy)
         view = self._paged_view(pool, gidx, index_vec)
         toks, view = decode_scan(self.model, params, view, tokens, rng,
                                  temperature, top_k, top_p, greedy, n=n,
                                  gmask=gmask)
-        return chunk_last, toks, self._paged_writeback(
+        return first, chunk_last, toks, self._paged_writeback(
             pool, view, sidx, index_vec)
 
     def _paged_decode_masked_fn(self, params, pool, gidx, index_vec,
@@ -1695,16 +1756,16 @@ class InferenceEngine:
 
     def _paged_mixed_masked_fn(self, params, pool, slots, pgidx,
                                chunk_ids, starts, lens, psidx, n_rows,
-                               gidx, index_vec, sidx, tokens, rng,
-                               temperature, top_k, top_p, greedy, gmask,
-                               *, n):
+                               finish, first_rng, gidx, index_vec, sidx,
+                               tokens, rng, temperature, top_k, top_p,
+                               greedy, gmask, *, n):
         """Grammar-masked twin of :meth:`_paged_mixed_fn` (the mask
         applies to the decode half only): a separate program, so
         unconstrained steps never carry the mask."""
         return self._paged_mixed_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
-            n_rows, gidx, index_vec, sidx, tokens, rng, temperature,
-            top_k, top_p, greedy, n=n, gmask=gmask)
+            n_rows, finish, first_rng, gidx, index_vec, sidx, tokens, rng,
+            temperature, top_k, top_p, greedy, n=n, gmask=gmask)
 
     def _paged_write_rows_fn(self, pool, rows, sidx):
         """Scatter B bucket-width row sets (one-shot prefill output, a
@@ -2536,30 +2597,8 @@ class InferenceEngine:
                             jnp.asarray(lens))
                     first = None
                     if self.block is None:
-                        self.rng, sub = jax.random.split(self.rng)
-                        logits = last.astype(jnp.float32)
-                        if any(r.params.constraint is not None
-                               for _, r, _ in part):
-                            # constrained members' first tokens obey their
-                            # grammar start states; zero rows leave the
-                            # rest of the batch untouched
-                            logits = logits + self._grammar_mask_rows(
-                                [self._ensure_constraint(r)
-                                 for _, r, _ in part])
-                        first = sample_token_batched(
-                            sub, logits,
-                            temperature=jnp.asarray(
-                                [r.params.temperature for _, r, _ in part],
-                                jnp.float32),
-                            top_k=jnp.asarray(
-                                [r.params.top_k for _, r, _ in part],
-                                jnp.int32),
-                            top_p=jnp.asarray(
-                                [r.params.top_p for _, r, _ in part],
-                                jnp.float32),
-                            greedy=jnp.asarray(
-                                [r.params.greedy for _, r, _ in part], bool),
-                        )
+                        first = self._first_tokens(
+                            [r for _, r, _ in part], last)
                     self.steptrace.window_issued()
                     if first is None:
                         # nothing is sampled from a block-diffusion
@@ -2713,10 +2752,58 @@ class InferenceEngine:
             req.tokens.put(_FINISH)
             self.stats.observe_finished(req)
 
+    def _first_from_program(self, req: Request) -> bool:
+        """Does the paged chunk / mixed program that ends ``req``'s
+        prompt sample its first token? Not where the host reads the
+        logits first (a grammar's start state), where there is nothing
+        to sample (a resumed stream's next token is already out; a
+        handed-off prompt decodes elsewhere), nor in a block-diffusion
+        engine."""
+        return (self.block is None and req.handoff_id is None
+                and req.resume_last is None
+                and req.params.constraint is None)
+
+    def _keeps_prefill_logits(self, req: Request) -> bool:
+        """Does the host read the last-position logits of ``req``'s
+        chunked prefill? The contiguous layout samples from them and
+        stores them in its prefix entries; the paged layout only off the
+        program path (:meth:`_first_from_program`) and for a
+        write-through pool entry."""
+        if self.block is not None:
+            return False
+        return (self.paged is None or not self._first_from_program(req)
+                or (self.kv_pool is not None
+                    and getattr(self.kv_pool, "offload_on_put", False)))
+
+    def _first_tokens(self, reqs, last_logits):
+        """First tokens of ``reqs`` from their (len(reqs), vocab)
+        prefill logits, issued as ONE jitted call (a device array: the
+        caller fetches it). Constrained members' tokens obey their
+        grammar start states; zero mask rows leave the rest untouched."""
+        self.rng, sub = jax.random.split(self.rng)
+        p = [r.params for r in reqs]
+        args = (np.array([q.temperature for q in p], np.float32),
+                np.array([q.top_k for q in p], np.int32),
+                np.array([q.top_p for q in p], np.float32),
+                np.array([q.greedy for q in p], bool))
+        if any(q.constraint is not None for q in p):
+            args += (self._grammar_mask_rows(
+                [self._ensure_constraint(r) for r in reqs]),)
+        return self._sample_first(sub, last_logits, *args)
+
+    def _note_first_token(self, path: str) -> None:
+        """A prompt that finished in a chunk, mixed or suffix program
+        got its first token from the ``program`` or from the ``host``
+        fallback (``llm_first_tokens_total{path}``)."""
+        self.first_tokens[path] += 1
+        self.steptrace.note_first_token(path)
+
     def _activate(self, slot: int, req: Request, plen: int, last_logits,
-                  rows=None):
-        """Slot bookkeeping once the prompt's KV is in place; samples the
-        first token from the prefill logits. ``rows`` forwards
+                  rows=None, first: int | None = None):
+        """Slot bookkeeping once the prompt's KV is in place. ``first``
+        is the token the prefill program sampled for this row
+        (:meth:`_first_from_program`); without one the first token is
+        sampled here from the prefill logits. ``rows`` forwards
         already-gathered KV rows to the handoff path (chunked prefill
         gathers them for the prefix store anyway)."""
         if req.handoff_id is not None:
@@ -2729,21 +2816,10 @@ class InferenceEngine:
             # before the preempt — no sampling, no rng split (the
             # stream must not fork from what the client saw)
             return self._activate_with_token(slot, req, plen, 0)
-        self.rng, sub = jax.random.split(self.rng)
-        logits = last_logits.astype(jnp.float32)
-        cs = self._ensure_constraint(req)
-        if cs is not None:
-            # the FIRST generated token is sampled from the prefill
-            # logits — it must obey the grammar's start state too
-            logits = logits + self._grammar_mask_rows([cs])
-        first = sample_token_batched(
-            sub, logits,
-            temperature=jnp.asarray([req.params.temperature], jnp.float32),
-            top_k=jnp.asarray([req.params.top_k], jnp.int32),
-            top_p=jnp.asarray([req.params.top_p], jnp.float32),
-            greedy=jnp.asarray([req.params.greedy], bool),
-        )
-        self._activate_with_token(slot, req, plen, int(first[0]))
+        if first is None:
+            first = int(np.asarray(
+                self._first_tokens([req], last_logits))[0])
+        self._activate_with_token(slot, req, plen, first)
 
     def _activate_with_token(self, slot: int, req: Request, plen: int,
                              first_id: int):
@@ -2763,10 +2839,7 @@ class InferenceEngine:
         self.slot_len[slot] = plen
         self.slot_budget[slot] = (req.resume_budget if resumed
                                   else req.params.max_tokens - 1)
-        self._temperature[slot] = req.params.temperature
-        self._top_k[slot] = req.params.top_k
-        self._top_p[slot] = req.params.top_p
-        self._greedy[slot] = req.params.greedy
+        self._slot_sampling(slot, req.params)
         self.slot_hist[slot] = list(req.prompt_ids) + [first_id]
         # constrained decoding: install the request's grammar cursor
         # (resume keeps the preempt-time position — already advanced
@@ -2776,17 +2849,31 @@ class InferenceEngine:
             self._emit(slot, first_id)
             self._constraint_commit(slot, cs, first_id)
 
-    def _sampling_args(self, rows: list[int]):
+    def _slot_sampling(self, slot: int, params: SamplingParams) -> None:
+        """``slot``'s row of the sampling plane. Written when the slot
+        starts prefilling, not at activation: the program that ends its
+        prompt samples its first token from this row."""
+        self._temperature[slot] = params.temperature
+        self._top_k[slot] = params.top_k
+        self._top_p[slot] = params.top_p
+        self._greedy[slot] = params.greedy
+
+    def _sampling_args(self, rows: list[int], *, runs: bool = True):
         """The sampler's ``(temperature, top_k, top_p, greedy)`` arrays
-        for a dispatch in which ``rows`` decode. Every other row of the
+        for a dispatch in which ``rows`` are sampled: the rows that
+        decode and, in a paged chunk or mixed program, the rows whose
+        prompt ends there. Every other row of the
         plane goes in as greedy: its token is discarded, and what a
         finished request left in its flags (or the initial ``False``)
         must not choose the sampler's body for the live rows
-        (``infer/sampling.py::sampler_tier``). Books the body chosen."""
+        (``infer/sampling.py::sampler_tier``). Books the body chosen,
+        unless the program will not run its sampler (``runs``: a chunk
+        program that ends no prompt)."""
         greedy = np.ones((self.max_slots,), bool)
         greedy[rows] = self._greedy[rows]
-        self.steptrace.note_sampler_tier(
-            sampler_tier_name(greedy, self._top_k, self._top_p))
+        if runs:
+            self.steptrace.note_sampler_tier(
+                sampler_tier_name(greedy, self._top_k, self._top_p))
         return (jnp.asarray(self._temperature), jnp.asarray(self._top_k),
                 jnp.asarray(self._top_p), jnp.asarray(greedy))
 
@@ -3043,27 +3130,30 @@ class InferenceEngine:
                 self._activate(slot, req, plen, hit.last_logits)
                 return
         rem = plen - done
+        self._slot_sampling(slot, req.params)
         if self._should_chunk(done, rem):
             self.slot_req[slot] = req
             self.slot_ready[slot] = False
             self.slot_prefill[slot] = {"req": req, "plen": plen,
                                        "done": done, "last_logits": None}
             return
-        last_logits = self._paged_suffix(slot, req.prompt_ids[done:],
-                                         done, req=req)
+        first, last_logits = self._paged_suffix(
+            slot, req.prompt_ids[done:], done, req)
         # store the finished prompt like every other completion path:
         # register its pages for sharing + tier write-through (the
         # contiguous twin does this in _finish_prefill)
         self._paged_store_prefix(req, plen, slot, last_logits)
-        self._activate(slot, req, plen, last_logits)
+        self._activate_prefilled(slot, req, plen, last_logits, first=first)
 
-    def _paged_suffix(self, slot: int, suffix, done: int, req=None):
-        """One-shot prefill of ``suffix`` into ``slot`` at ``done``
-        through the paged chunk program (the dedicated contiguous
-        ``_prefill_suffix`` program has no paged twin — the chunk body
-        is the same pinned-index math). Returns the last-position
-        logits row. ``req``: books the dispatch into the request's
-        critical-path breakdown when given."""
+    def _paged_suffix(self, slot: int, suffix, done: int, req: Request):
+        """One-shot prefill of ``req``'s ``suffix`` into ``slot`` at
+        ``done`` through the paged chunk program (the dedicated
+        contiguous ``_prefill_suffix`` program has no paged twin — the
+        chunk body is the same pinned-index math). The suffix ends the
+        prompt, so the program samples its first token where it may
+        (:meth:`_first_from_program`). Returns ``(first token or None,
+        the last-position logits row or None)``; the dispatch is booked
+        into the request's critical-path breakdown."""
         C = self._bucket_for(len(suffix))
         # a ONE-row call of the paged chunk program: it gathers the
         # owning slot's pages, not a W-wide view of every slot, which is
@@ -3077,25 +3167,49 @@ class InferenceEngine:
             self._pulse_view(W, 1)
             rows = self._paged_chunk_rows(
                 [(slot, done, suffix)], W, C, n_rows=1)
+            tail, sampled = self._tail_key([(slot, req)])
         kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("prefill")
             fn = self._pg_chunk if lora is None else self._pg_chunk_lora
-            last, self.paged.kv = fn(
-                self.params, self.paged.kv, *rows, **kw)
-            out = last[slot:slot + 1]
+            first, last, self.paged.kv = fn(
+                self.params, self.paged.kv, *rows, *tail,
+                *self._sampling_args(sampled), **kw)
             self.steptrace.window_issued()
             # force before the window closes, exactly like
-            # _prefill_into_slot (the logits feed the first-token sample
-            # on this same call path anyway)
-            jax.block_until_ready(out)
-            dt, _ = self._window_close(
-                "prefill", () if req is None else (req,))
+            # _prefill_into_slot
+            first = np.asarray(first)
+            dt, _ = self._window_close("prefill", (req,))
             keys = CostModel.chunk_keys(len(suffix), done)
             self._note_device_phase(
                 "prefill", tokens=len(suffix), attended_keys=keys,
                 weight_passes=1, kv_read_tokens=keys, dt=dt)
-        return out
+        return (int(first[slot]) if sampled else None,
+                last[slot:slot + 1] if self._keeps_prefill_logits(req)
+                else None)
+
+    def _tail_key(self, finishing) -> tuple:
+        """``(finish, key)`` of a paged chunk program's tail
+        (:meth:`_prefill_tail`) for the rows ``finishing`` = ``[(slot,
+        request), ...]`` whose prompt ends in it, and the slots among
+        them whose first token the program samples. The key is split
+        off the engine's only then: nothing is drawn from it
+        otherwise."""
+        sampled = [slot for slot, req in finishing
+                   if self._first_from_program(req)]
+        key = self.rng
+        if sampled:
+            self.rng, key = jax.random.split(self.rng)
+        return (jnp.asarray(bool(finishing)), key), sampled
+
+    def _activate_prefilled(self, slot: int, req: Request, plen: int,
+                            last_logits, *, first: int | None,
+                            rows=None) -> None:
+        """:meth:`_activate` for a prompt that a chunk, mixed or suffix
+        program finished, booked by where its first token comes from."""
+        if req.handoff_id is None and self.block is None:
+            self._note_first_token("host" if first is None else "program")
+        self._activate(slot, req, plen, last_logits, rows=rows, first=first)
 
     _UNSET = object()
 
@@ -3142,6 +3256,7 @@ class InferenceEngine:
                     jnp.asarray(done, jnp.int32))
             self.slot_req[slot] = req   # slot reserved, not yet decodable
             self.slot_ready[slot] = False
+            self._slot_sampling(slot, req.params)
             self.slot_prefill[slot] = {"req": req, "plen": plen, "done": done,
                                        "last_logits": None}
             return
@@ -3212,8 +3327,9 @@ class InferenceEngine:
                 kw = {} if lora is None else {"lora": lora}
             with self.steptrace.scope("dispatch_wait"):
                 self.steptrace.window_begin("prefill")
+                first = None
                 if self.paged is not None:
-                    self._paged_chunk_dispatch(entries, lora=lora)
+                    first = self._paged_chunk_dispatch(entries, lora=lora)
                 elif batchable:
                     tok, starts, lens = self._chunk_batch_rows(entries)
                     self._note_chunk_rows(len(entries), self.max_slots)
@@ -3222,9 +3338,7 @@ class InferenceEngine:
                     last, self.cache = fn(
                         self.params, self.cache, jnp.asarray(tok),
                         jnp.asarray(starts), jnp.asarray(lens), **kw)
-                    for slot, st, chunk in entries:
-                        st["last_logits"] = last[slot:slot + 1]
-                        st["done"] += len(chunk)
+                    self._chunks_done(entries, last)
                 else:
                     self._note_chunk_rows(len(entries), len(entries))
                     for slot, st, chunk in entries:
@@ -3243,17 +3357,20 @@ class InferenceEngine:
                             **skw,
                         )
                         st["done"] += len(chunk)
+                    last = [st["last_logits"] for _, st, _ in entries]
                 self.steptrace.window_issued()
-                # force the chunks' last-logits before the window
+                # force the programs' results before the window
                 # closes: on an async backend issue time alone would
                 # inflate the prefill MFU/BW gauges
                 # ~device-time/dispatch-time-fold (the decode and fused
-                # paths force every dispatch the same way). The logits
-                # are consumed at activation regardless; KV writes land
-                # in the same program, so this waits only for work the
-                # next chunk depends on anyway.
-                jax.block_until_ready([st["last_logits"]
-                                       for _, st, _ in entries])
+                # paths force every dispatch the same way). KV writes
+                # land in the same program, so this waits only for work
+                # the next chunk depends on anyway. The paged program's
+                # first tokens are the fetch.
+                if first is not None:
+                    first = np.asarray(first)
+                else:
+                    jax.block_until_ready(last)
                 # every mid-prefill request waited the whole dispatch
                 dt, issue_s = self._window_close(
                     "prefill", [st["req"] for _, st, _ in entries])
@@ -3267,7 +3384,7 @@ class InferenceEngine:
             budget -= 1
             progressed = True
             with self.steptrace.scope("sample_commit"):
-                self._finalize_prefills()
+                self._finalize_prefills(first)
         return progressed
 
     def _trace_chunks(self, entries, dt: float, issue_s: float, *,
@@ -3359,12 +3476,14 @@ class InferenceEngine:
         self.prefill_chunk_row_slots += row_slots
         self.steptrace.note_chunk_rows(rows, row_slots)
 
-    def _paged_chunk_dispatch(self, entries, lora=None) -> None:
+    def _paged_chunk_dispatch(self, entries, lora=None):
         """Advance ``entries``' rows one chunk against the PAGE
         POOL in a single dispatch: the program gathers one chunking
-        row's pages at a time, runs the shared ``batched_chunk`` body
-        on that view and scatters the row's real chunk window back to
-        its pages. Rows that do not chunk cost nothing."""
+        row's pages at a time, runs the shared ``batched_chunk_hidden``
+        body on that view and scatters the row's real chunk window back
+        to its pages. Rows that do not chunk cost nothing. Returns the
+        program's first tokens by slot (a device array), which
+        :meth:`_finalize_prefills` reads for the rows that finish."""
         C = self.chunked_prefill
         W = self._paged_width(
             max(st["done"] for _, st, _ in entries) + C)
@@ -3374,14 +3493,40 @@ class InferenceEngine:
         # a statement of its own: building the rows may fork a shared
         # page, which REBINDS the (donated) pool read below
         rows = self._paged_entry_rows(entries, W)
-        last, self.paged.kv = fn(self.params, self.paged.kv, *rows, **kw)
-        for slot, st, chunk in entries:
-            st["last_logits"] = last[slot:slot + 1]
+        finishing = self._finishing(entries)
+        tail, sampled = self._tail_key(finishing)
+        first, last, self.paged.kv = fn(
+            self.params, self.paged.kv, *rows, *tail,
+            *self._sampling_args(sampled, runs=bool(finishing)), **kw)
+        self._chunks_done(entries, last)
+        return first
+
+    @staticmethod
+    def _finishing(entries) -> list:
+        """``(slot, request)`` of the ``entries`` whose next chunk is
+        their prompt's last (read BEFORE the chunk is booked)."""
+        return [(slot, st["req"]) for slot, st, chunk in entries
+                if st["done"] + len(chunk) >= st["plen"]]
+
+    def _chunks_done(self, entries, last) -> None:
+        """Book one chunk each of ``entries`` as fed. ``last``
+        (max_slots, vocab) are the dispatch's last-position logits by
+        slot: a row is sliced out of them only for a prompt that this
+        chunk finishes and whose logits the host reads
+        (:meth:`_keeps_prefill_logits`)."""
+        for slot, req in self._finishing(entries):
+            if self._keeps_prefill_logits(req):
+                self.slot_prefill[slot]["last_logits"] = last[slot:slot + 1]
+        for _, st, chunk in entries:
             st["done"] += len(chunk)
 
-    def _finalize_prefills(self) -> None:
+    def _finalize_prefills(self, first=None) -> None:
         """Activate every chunked prefill whose prompt is fully fed —
-        shared tail of the sequential and fused mixed-step paths."""
+        shared tail of the sequential and fused mixed-step paths.
+        ``first``: the paged program's first tokens by slot, ON THE HOST
+        (the step's one fetch); a row takes its token from there where
+        the program sampled it (:meth:`_first_from_program`), and
+        nothing is dispatched for it here."""
         for slot in list(self.slot_prefill):
             st = self.slot_prefill[slot]
             if st["done"] < st["plen"]:
@@ -3401,9 +3546,12 @@ class InferenceEngine:
                 self._store_prefix(req, plen, rows,
                                    st["last_logits"],
                                    rows_ready=True)
+            token = (int(first[slot]) if first is not None
+                     and self._first_from_program(req) else None)
             # the gathered rows ride through to the handoff path so a
             # chunked handoff doesn't pay the gather dispatch twice
-            self._activate(slot, req, plen, st["last_logits"], rows=rows)
+            self._activate_prefilled(slot, req, plen, st["last_logits"],
+                                     first=token, rows=rows)
 
     def _paged_store_prefix(self, req: Request, plen: int, slot: int,
                             last_logits) -> None:
@@ -4068,8 +4216,9 @@ class InferenceEngine:
                 advance[active] = n
             # constrained decoding: the decode half of the fused step masks
             # each grammar slot's logits (n == 1 then, by _plan_block);
-            # mid-prefill rows need nothing — their first token samples at
-            # finalization, where _activate applies the start-state mask
+            # mid-prefill rows need nothing — a constrained row's first
+            # token samples at finalization, where the host applies the
+            # start-state mask (_first_from_program)
             gmask = self._grammar_masks(active)
             # multi-LoRA: slot-plane adapter rows cover BOTH halves of the
             # fused program (the paged prefill half picks its rows' out
@@ -4094,10 +4243,17 @@ class InferenceEngine:
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("mixed")
             self.rng, sub = jax.random.split(self.rng)
+            # the paged program samples the first token of the rows
+            # whose prompt it ends: they choose the sampler's body with
+            # the rows that decode
+            tail, sampled = ((), [])
+            if self.paged is not None:
+                tail, sampled = self._tail_key(self._finishing(entries))
             sampling = (jnp.asarray(self.slot_last_token), sub,
-                        *self._sampling_args(active))
+                        *self._sampling_args(active + sampled))
             if gmask is not None:
                 sampling += (jnp.asarray(gmask),)
+            first = None
             if self.paged is not None:
                 # ONE view width for both halves: each prefill row's
                 # chunk + the block (done+C+n), and each occupied decode
@@ -4123,9 +4279,9 @@ class InferenceEngine:
                 # page, which REBINDS the (donated) pool read below
                 rows = self._paged_entry_rows(entries, W)
                 plan = self._paged_decode_plan(active, n, W)
-                chunk_last, toks, self.paged.kv = fn(
-                    self.params, self.paged.kv, *rows, *plan, *sampling,
-                    n=n, **kw)
+                first, chunk_last, toks, self.paged.kv = fn(
+                    self.params, self.paged.kv, *rows, *tail, *plan,
+                    *sampling, n=n, **kw)
             else:
                 self._note_chunk_rows(len(entries), self.max_slots)
                 if gmask is not None:
@@ -4138,16 +4294,16 @@ class InferenceEngine:
                     jnp.asarray(starts), jnp.asarray(lens),
                     jnp.asarray(advance), *sampling, n=n, **kw)
             self.steptrace.window_issued()
-            toks_host = np.asarray(toks)  # forces the dispatch's results
+            # ONE fetch forces the dispatch's results: the decode
+            # block's tokens and the finished prompts' first tokens
+            first, toks_host = jax.device_get((first, toks))
             # the window advanced the mid-prefill rows' prompts; every
             # decode member sat through the whole fused dispatch for
             # them (prefill_stall, not decode_dispatch)
             dt, issue_s = self._window_close(
                 "prefill", [st["req"] for _, st, _ in entries])
             self.mixed_blocks += 1
-            for slot, st, chunk in entries:
-                st["last_logits"] = chunk_last[slot:slot + 1]
-                st["done"] += len(chunk)
+            self._chunks_done(entries, chunk_last)
             self._trace_chunks(entries, dt, issue_s, batched=True,
                                fused=True)
             cm = self.cost_model
@@ -4167,7 +4323,7 @@ class InferenceEngine:
                 weight_passes=n, kv_read_tokens=dc_keys,
                 dt=dt * (1 - share))
         with self.steptrace.scope("sample_commit"):
-            self._finalize_prefills()
+            self._finalize_prefills(first)
             self._commit_block(active, toks_host, n)
         return True
 

@@ -48,8 +48,8 @@ path's exactly — pinned by ``tests/test_mixed_step.py``.
 
 The PAGED layout (``engine._paged_mixed_fn``) keeps the one dispatch
 and the two shared bodies, but its part (a) runs
-:func:`batched_chunk` over the rows that are mid-prefill only, one
-row a trip of a loop with a traced trip count
+:func:`batched_chunk_hidden` over the rows that are mid-prefill only,
+one row a trip of a loop with a traced trip count
 (``engine._paged_chunk_fn``), and its part (b) is the paged decode
 body over the slot plane. The prefill half's device work follows the
 number of chunking rows, not ``max_slots``, and no decode row
@@ -57,6 +57,23 @@ receives a chunk write: idle and mid-prefill rows' decode garbage
 goes to the trash page through the host-built scatter indices. The
 functions built by :func:`make_mixed_step` serve the contiguous
 layout alone.
+
+What a prefill body returns (PR 32). Every body stops the forward at
+the final norm and takes each row's state at its last real position
+BEFORE the output head (``models/layers.py``), so the head runs on one
+position a row, never on the chunk's width. The contiguous bodies
+(:func:`batched_chunk`, the two ``make_*mixed_step`` functions) return
+``(B, vocab)`` last-position logits as before, and the host samples a
+finished prompt's first token from them with one jitted call. The paged
+programs end a prompt in its first token themselves: the row loop
+carries ``(max_slots, hidden)`` states by slot, ONE head pass over that
+plane and the decode programs' sampler run after the loop, under a
+``cond`` on "some row's prompt ends here", and the programs return
+``(first tokens (max_slots,), last-position logits (max_slots, vocab),
+[decode tokens (max_slots, n),] pool)``. The host reads a finishing
+row's token from the step's one fetch; the logits stay an output for
+the three things that read them on the host (a grammar's start-state
+mask, a stored prefix entry, a handoff).
 """
 
 from __future__ import annotations
@@ -65,6 +82,10 @@ import jax
 import jax.numpy as jnp
 
 from llm_in_practise_tpu.infer.sampling import sample_token_batched
+from llm_in_practise_tpu.models.layers import (
+    head_logits,
+    last_position_hidden,
+)
 
 
 def pin_index(cache, index_vec):
@@ -121,21 +142,27 @@ def decode_scan(model, params, cache, tokens, rng, temperature, top_k,
     return toks.T, cache                                     # (B, n)
 
 
-def batched_chunk(model, params, cache, chunk_ids, starts, lens):
+def batched_chunk_hidden(model, params, cache, chunk_ids, starts, lens):
     """Advance every row one pinned-index prefill chunk against the
-    whole cache — the SHARED body of ``engine._chunk_batch_fn`` and the
-    fused mixed step (see that method's docstring for the invariants).
-    Returns ``((B, vocab) last-real-position logits, cache)`` with the
-    cache index pinned to ``starts + lens``."""
-    logits, cache = model.apply(
-        {"params": params}, chunk_ids, deterministic=True,
-        cache=pin_index(cache, starts)
-    )
-    cache = pin_index(cache, starts + lens)
-    last = jnp.take_along_axis(
-        logits, jnp.maximum(lens - 1, 0)[:, None, None], axis=1
-    )[:, 0, :]
-    return last, cache
+    whole cache, up to the output head: ``((B, hidden) final-norm state
+    of each row's last real position, cache)`` with the cache index
+    pinned to ``starts + lens``. The paged row loop carries these and
+    runs ONE head pass over the slot plane after its last trip
+    (``engine._paged_chunk_fn``)."""
+    last, cache = last_position_hidden(
+        model, params, chunk_ids, lens, pin_index(cache, starts))
+    return last, pin_index(cache, starts + lens)
+
+
+def batched_chunk(model, params, cache, chunk_ids, starts, lens):
+    """:func:`batched_chunk_hidden` through the output head — the
+    SHARED body of ``engine._chunk_batch_fn`` and the contiguous fused
+    mixed step (see that method's docstring for the invariants).
+    Returns ``((B, vocab) last-real-position logits, cache)``; the head
+    runs on that one position a row, not on the chunk's width."""
+    last, cache = batched_chunk_hidden(
+        model, params, cache, chunk_ids, starts, lens)
+    return head_logits(model, params, last), cache
 
 
 def spec_verify_block(model, params, cache, tokens, base, mask, *, m,

@@ -19,6 +19,8 @@ program and on its own per-row flags, between three bodies: ``argmax``
 
 CPU, small vocabulary, seconds."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -195,13 +197,15 @@ def engine_of(model_params, layout, mode):
 
 def serve(eng, mode, sampled):
     """``sampled`` requests decode together; in ``mixed`` mode a long
-    greedy prompt joins after the first step, chunk-prefills beside them
-    and ends with its first token (sampled eagerly: an ``argmax`` that
-    the device sees and no step books). Returns the requests' tokens."""
+    prompt with the first request's sampling joins after the first step,
+    chunk-prefills beside them and ends with its first token: sampled by
+    the paged program that ends its prompt, whose step books the body
+    (PR 32), or by the contiguous layout's jitted host sampler, which
+    the device sees and no step books. Returns the requests' tokens."""
     reqs = [eng.submit(p, sp) for p, sp in zip(SHORT, sampled)]
     eng.step()
     if mode == "mixed":
-        eng.submit(LONG, SamplingParams(greedy=True, max_tokens=1))
+        eng.submit(LONG, dataclasses.replace(sampled[0], max_tokens=1))
     while eng.step():
         pass
     return [r.result() for r in reqs]
@@ -221,7 +225,7 @@ def test_engine_takes_the_tier_its_live_rows_ask_for(
         return engine_of(model_params, layout, mode)
 
     def on_device():
-        return set(tiers_run()) - ({"argmax"} if mode == "mixed" else set())
+        return set(tiers_run())
 
     # one greedy request on a fresh engine: three rows never held a
     # request (their flag is the initial False) and still the plane is
