@@ -122,6 +122,13 @@ def cache_update(buf: jax.Array, new: jax.Array, index: jax.Array) -> jax.Array:
     return jax.lax.dynamic_update_slice(buf, new, (0, index, *trailing))
 
 
+# Entries a routed model fills in a serving program's transient cache view
+# (serve/step_stats.py; models/deepseek_v3.py): a (4,) int32 running
+# [layer passes, held assignments, held experts touched, busiest held
+# expert's load], and the (rows, k) experts each row's last position chose.
+LOAD_KEY, ROUTE_KEY = "moe_load", "moe_route"
+
+
 # --- a prefill's tail: one row of logits a prompt ---------------------------
 # A program that feeds a prompt (or a chunk of one) reads the logits of one
 # position a row, so it takes the final-norm hidden state there BEFORE the

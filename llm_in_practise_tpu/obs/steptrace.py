@@ -181,6 +181,7 @@ class StepTrace:
         self._block = [0, 0, 0, 0]   # rows, commits, revealed, committed
         self._sampler_tier: str | None = None
         self._first_tokens = {"program": 0, "host": 0}
+        self._extra: dict[str, int] = {}
         self._last_end: float | None = None   # previous step_end, perf
         # the open dispatch window (``_win_t0`` None: none open); the
         # annotation is that of the part under way, issue then wait
@@ -323,6 +324,16 @@ class StepTrace:
         if self._recording:
             self._sampler_tier = tier
 
+    def note_extra(self, **counts: int) -> None:
+        """Counts only some models' steps have (serve/step_stats.py: a
+        latent cache's ``latent_tokens_attended`` / ``view_tokens``, a
+        routed model's ``moe_*``): summed over the step and written into
+        its record under their own names; a step without them has no
+        such fields."""
+        if self._recording:
+            for k, v in counts.items():
+                self._extra[k] = self._extra.get(k, 0) + v
+
     def note_first_token(self, path: str) -> None:
         """A prompt that finished in a chunk, mixed or suffix program of
         this step got its first token from the ``program`` or from the
@@ -349,6 +360,7 @@ class StepTrace:
         self._block = [0, 0, 0, 0]
         self._sampler_tier = None
         self._first_tokens = {"program": 0, "host": 0}
+        self._extra = {}
         self._acts = {}
         self._device_s = 0.0
         self._issue_s = 0.0
@@ -410,6 +422,7 @@ class StepTrace:
             "sampler_tier": self._sampler_tier,
             "first_tokens_program": self._first_tokens["program"],
             "first_tokens_host": self._first_tokens["host"],
+            **self._extra,
             "activities": dict(self._acts),
             "segments": [(name, t0 + off, t1 + off)
                          for name, t0, t1 in self._segments],

@@ -11,6 +11,8 @@ Layout: q/k are ``(batch, length, heads, head_dim)``.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -79,3 +81,64 @@ def sinusoidal_embeddings(max_len: int, dim: int) -> jax.Array:
     pe = pe.at[:, 0::2].set(jnp.sin(position * div_term))
     pe = pe.at[:, 1::2].set(jnp.cos(position * div_term))
     return pe
+
+
+# --- YaRN (arXiv:2309.00071) as DeepSeek-V3's modeling code applies it ------
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``yarn_get_mscale``: ``0.1 * mscale * ln(factor) + 1`` (1 at
+    ``factor`` <= 1)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, *, factor: float,
+                  original_max_len: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> jax.Array:
+    """The ``dim // 2`` rotary frequencies under YaRN: ``f_i =
+    theta^(-2i/dim)`` where a dimension turns more than ``beta_fast``
+    times over ``original_max_len`` positions (kept), ``f_i / factor``
+    where it turns fewer than ``beta_slow`` times (interpolated), and
+    the linear ramp between the two correction dimensions in between
+    (``yarn_find_correction_range`` / ``yarn_linear_ramp_mask``)."""
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(original_max_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001       # the published guard against a zero-width ramp
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    inter = extra / factor
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp       # 1: the frequency stays as it is
+    return inter * (1.0 - keep) + extra * keep
+
+
+def precompute_yarn_cos_sin(
+    dim: int, max_seq_len: int, theta: float, *, factor: float,
+    original_max_len: int, beta_fast: float = 32.0, beta_slow: float = 1.0,
+    mscale: float = 1.0, mscale_all_dim: float = 0.0,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`precompute_cos_sin` with YaRN's frequencies; the tables are
+    scaled by ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)`` (1 where the two are equal, as in DeepSeek-V3)."""
+    inv_freq = yarn_inv_freq(dim, theta, factor=factor,
+                             original_max_len=original_max_len,
+                             beta_fast=beta_fast, beta_slow=beta_slow)
+    freqs = jnp.outer(jnp.arange(max_seq_len, dtype=jnp.float32), inv_freq)
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return jnp.cos(freqs) * m, jnp.sin(freqs) * m
+
+
+def yarn_attention_scale(qk_dim: int, factor: float,
+                         mscale_all_dim: float) -> float:
+    """The softmax scale that goes with those tables: ``qk_dim^-1/2 *
+    m^2`` with ``m = yarn_mscale(factor, mscale_all_dim)`` (``m`` = 1,
+    the plain scale, when ``mscale_all_dim`` is 0)."""
+    m = yarn_mscale(factor, mscale_all_dim) if mscale_all_dim else 1.0
+    return qk_dim ** -0.5 * m * m

@@ -765,21 +765,52 @@ class OpenAIServer:
                     ("llm_blocks_committed_total", "blocks_committed",
                      "blocks whose K/V were stored and tokens streamed"),
                     ("llm_block_tokens_committed_total", "tokens_committed",
-                     "tokens streamed out of committed blocks"),
-                    ("llm_moe_assignments_total", "moe_assignments",
-                     "(token, expert) pairs the block passes computed, "
-                     "idle rows of the plane included"),
-                    ("llm_moe_experts_touched_total", "moe_experts_touched",
-                     "distinct experts that received a token, summed over "
+                     "tokens streamed out of committed blocks")):
+                reg.counter_func(name,
+                                 lambda a=attr: getattr(blk, a), doc)
+        load = getattr(eng, "routing_load", None)
+        if load is not None:
+            # expert load of a routed model (serve/step_stats.py): what
+            # the block passes' routing output counted, or what the
+            # decode / chunk / mixed programs of a model that holds a
+            # share of its experts counted on the device
+            for name, attr, doc in (
+                    ("llm_moe_layer_passes_total", "layer_passes",
+                     "runs of one routed layer over one batch of tokens "
+                     "(a block pass, a decode step, a chunk row), summed "
+                     "over layers"),
+                    ("llm_moe_assignments_total", "assignments",
+                     "(token, expert) pairs computed by the experts held "
+                     "here, idle rows of the plane included"),
+                    ("llm_moe_experts_touched_total", "experts_touched",
+                     "distinct held experts that received a token, summed "
+                     "over layers and passes"),
+                    ("llm_moe_max_expert_load_total", "max_load",
+                     "the busiest held expert's assignments, summed over "
                      "layers and passes"),
-                    ("llm_moe_max_expert_load_total", "moe_max_load",
-                     "the busiest expert's assignments, summed over "
-                     "layers and passes"),
-                    ("llm_moe_mean_expert_load_total", "moe_mean_load",
+                    ("llm_moe_mean_expert_load_total", "mean_load",
                      "assignments / experts held, summed over layers and "
                      "passes (max / mean = the routing's imbalance)")):
                 reg.counter_func(name,
-                                 lambda a=attr: getattr(blk, a), doc)
+                                 lambda a=attr: getattr(load, a), doc)
+        stats = getattr(eng, "step_stats", None)
+        if stats is not None:
+            reg.counter_func(
+                "llm_latent_tokens_attended_total",
+                lambda: stats.latent_tokens_attended,
+                "cache rows the decode steps' attention needed (each "
+                "active row's true length)")
+            reg.counter_func(
+                "llm_latent_view_tokens_total",
+                lambda: stats.latent_view_tokens,
+                "cache rows those steps' gathered views held (slots x "
+                "pow2 width): attended / view is the share read for "
+                "something")
+        if getattr(eng, "paged", None) is not None:
+            reg.gauge_func(
+                "llm_kv_row_bytes", lambda: eng.paged.row_bytes,
+                "pool bytes of one token position over all layers (k and "
+                "v heads, or one latent row a layer)")
         # device plane (obs/cost.py + DispatchMeter.note_phase): live
         # per-phase MFU / HBM-bandwidth-utilization / tokens-per-
         # dispatch — the compute-vs-bandwidth-bound dial. Phases appear

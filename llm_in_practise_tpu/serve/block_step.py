@@ -49,6 +49,7 @@ from llm_in_practise_tpu.infer.sampling import (
     sampler_tier_name,
 )
 from llm_in_practise_tpu.models.sdar_moe import REMASKING
+from llm_in_practise_tpu.serve.step_stats import RoutingLoad
 
 
 def unwrap(model):
@@ -125,10 +126,7 @@ class BlockDecoder:
         self.blocks_committed = 0
         self.tokens_committed = 0   # tokens streamed out of commits
         self.tokens_revealed = 0
-        self.moe_assignments = 0
-        self.moe_experts_touched = 0
-        self.moe_max_load = 0
-        self.moe_mean_load = 0.0
+        self.routing = RoutingLoad(self.n_experts)
         # reference comparisons (tests, the benchmark's check) set this
         # to a list: the step then also FETCHES the pass's logits (the
         # one program returns them always, as a device array nothing else
@@ -447,14 +445,10 @@ class BlockDecoder:
         layers, n_exp = experts.shape[0], self.n_experts
         if not layers or not n_exp:
             return
-        counts = np.bincount(
+        self.routing.book_counts(np.bincount(
             (experts.reshape(layers, -1)
              + np.arange(layers)[:, None] * n_exp).ravel(),
-            minlength=layers * n_exp).reshape(layers, n_exp)
-        self.moe_assignments += int(counts.sum())
-        self.moe_experts_touched += int((counts > 0).sum())
-        self.moe_max_load += int(counts.max(axis=1).sum())
-        self.moe_mean_load += float(counts.sum()) / n_exp
+            minlength=layers * n_exp).reshape(layers, n_exp))
 
     def counters(self) -> dict:
         return {
@@ -463,8 +457,6 @@ class BlockDecoder:
             "blocks_committed": self.blocks_committed,
             "block_tokens_committed": self.tokens_committed,
             "block_tokens_revealed": self.tokens_revealed,
-            "moe_assignments": self.moe_assignments,
-            "moe_experts_touched": self.moe_experts_touched,
-            "moe_max_expert_load": self.moe_max_load,
-            "moe_mean_expert_load": self.moe_mean_load,
+            **{k: v for k, v in self.routing.counters().items()
+               if k != "moe_layer_passes"},
         }

@@ -1130,6 +1130,21 @@ class InferenceEngine:
         if block_length_of(model) > 1:
             BlockDecoder.check_engine(self)
             self.block = BlockDecoder(self)
+        # A model that counts what its steps did (a held share of routed
+        # experts, a latent cache: serve/step_stats.py) has the paged
+        # programs return those counts; what it cannot meet yet is
+        # refused here, with its reason.
+        from llm_in_practise_tpu.serve.step_stats import (
+            StepStats,
+            stats_model,
+        )
+
+        core = stats_model(model)
+        self.step_stats = None if core is None else StepStats(self, core)
+        # expert load, whoever books it (/metrics llm_moe_*_total)
+        self.routing_load = (self.block.routing if self.block is not None
+                             else self.step_stats.load
+                             if self.step_stats is not None else None)
 
     # --- jitted pieces -------------------------------------------------------
 
@@ -1567,8 +1582,12 @@ class InferenceEngine:
         S, W = gidx.shape
         flat = gidx.reshape(-1)
         view = []
-        for layer in pool:
-            d = {"index": index_vec.astype(jnp.int32)}
+        # a model that counts what its steps did (serve/step_stats.py)
+        # gets its zeroed entries beside each layer's gathered rows
+        extra = ([{}] * len(pool) if self.step_stats is None
+                 else self.step_stats.view_entries(S))
+        for layer, more in zip(pool, extra):
+            d = {"index": index_vec.astype(jnp.int32), **more}
             for key, buf in layer.items():
                 # clip, not take's default fill: the host builds every
                 # index inside the pool (unmapped pages read the trash
@@ -1602,12 +1621,19 @@ class InferenceEngine:
             new.append(d)
         return new
 
+    def _view_stats(self, view) -> tuple:
+        """What a program returns behind its pool: one part of step
+        statistics read off the view its body returned, or nothing."""
+        return (() if self.step_stats is None
+                else (self.step_stats.of_view(view),))
+
     def _paged_decode_fn(self, params, pool, gidx, index_vec, sidx,
                          tokens, rng, temperature, top_k, top_p, greedy):
         view = self._paged_view(pool, gidx, index_vec)
         tok, view = self._decode_fn(params, view, tokens, rng,
                                     temperature, top_k, top_p, greedy)
-        return tok, self._paged_writeback(pool, view, sidx, index_vec)
+        return (tok, self._paged_writeback(pool, view, sidx, index_vec),
+                *self._view_stats(view))
 
     def _paged_multi_fn(self, params, pool, gidx, index_vec, sidx,
                         tokens, rng, temperature, top_k, top_p, greedy,
@@ -1615,7 +1641,8 @@ class InferenceEngine:
         view = self._paged_view(pool, gidx, index_vec)
         toks, view = decode_scan(self.model, params, view, tokens, rng,
                                  temperature, top_k, top_p, greedy, n=n)
-        return toks, self._paged_writeback(pool, view, sidx, index_vec)
+        return (toks, self._paged_writeback(pool, view, sidx, index_vec),
+                *self._view_stats(view))
 
     def _paged_spec_fn(self, params, pool, gidx, index_vec, sidx, tokens,
                        mask, *, m):
@@ -1659,11 +1686,13 @@ class InferenceEngine:
         sampling arrays are the sampler's, as in the decode programs).
         Returns ``((max_slots,) first tokens, (max_slots, vocab)
         last-position logits, pool)``, both by slot, meaningful for the
-        rows that finish, zeros when none does."""
+        rows that finish, zeros when none does (and, behind the pool, a
+        part of step statistics for a model that counts them)."""
         lora = current_lora()
+        stats = self.step_stats
 
         def trip(i, carry):
-            pool, out = carry
+            pool, out, acc = carry
             slot, r_gidx, r_ids, r_starts, r_lens, r_sidx = (
                 jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)
                 for a in (slots, gidx, chunk_ids, starts, lens, sidx))
@@ -1674,16 +1703,22 @@ class InferenceEngine:
             with lora_context(mine):
                 last, view = batched_chunk_hidden(
                     self.model, params, view, r_ids, r_starts, r_lens)
+            if stats is not None:
+                acc = (stats.add_row(acc[0], stats.of_view(view), slot[0]),)
             return (self._paged_writeback(pool, view, r_sidx, r_starts),
                     jax.lax.dynamic_update_slice_in_dim(
-                        out, last, slot[0], axis=0))
+                        out, last, slot[0], axis=0), acc)
 
         like = self._hidden_like
         out = jnp.zeros((self.max_slots, like.shape[-1]), like.dtype)
-        pool, out = jax.lax.fori_loop(0, n_rows, trip, (pool, out))
+        # step statistics, where the model counts them: summed over the
+        # trips, a part of their own behind the pool (else nothing)
+        acc = () if stats is None else (stats.zero_rows(),)
+        pool, out, acc = jax.lax.fori_loop(0, n_rows, trip,
+                                           (pool, out, acc))
         first, last = self._prefill_tail(
             params, out, finish, rng, temperature, top_k, top_p, greedy)
-        return first, last, pool
+        return first, last, pool, *acc
 
     def _prefill_tail(self, params, hidden, finish, rng, temperature,
                       top_k, top_p, greedy):
@@ -1723,15 +1758,15 @@ class InferenceEngine:
         page). No decode row receives a chunk write. Returns ``(first
         tokens, last-position logits, (max_slots, n) decode tokens,
         pool)``."""
-        first, chunk_last, pool = self._paged_chunk_fn(
+        first, chunk_last, pool, *acc = self._paged_chunk_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
             n_rows, finish, first_rng, temperature, top_k, top_p, greedy)
         view = self._paged_view(pool, gidx, index_vec)
         toks, view = decode_scan(self.model, params, view, tokens, rng,
                                  temperature, top_k, top_p, greedy, n=n,
                                  gmask=gmask)
-        return first, chunk_last, toks, self._paged_writeback(
-            pool, view, sidx, index_vec)
+        return (first, chunk_last, toks, self._paged_writeback(
+            pool, view, sidx, index_vec), *acc, *self._view_stats(view))
 
     def _paged_decode_masked_fn(self, params, pool, gidx, index_vec,
                                 sidx, tokens, rng, temperature, top_k,
@@ -1986,6 +2021,8 @@ class InferenceEngine:
         for s in active:
             valid[s] = n
             self._paged_cow_fork(s, int(self.slot_len[s]), n)
+        if self.step_stats is not None:
+            self.step_stats.note_decode_view(active, n, W)
         return (jnp.asarray(self.paged.gather_idx(W)), jnp.asarray(idxv),
                 jnp.asarray(self.paged.scatter_idx(idxv, valid, n)))
 
@@ -2018,14 +2055,17 @@ class InferenceEngine:
             return tok[:, None]
         if n == 1:
             fn = self._pg_decode if lora is None else self._pg_decode_lora
-            tok, self.paged.kv = fn(
+            tok, self.paged.kv, *counted = fn(
                 self.params, self.paged.kv, gidx, idxv, sidx, tokens,
                 sub, *args, **kw)
-            return tok[:, None]
-        fn = self._pg_multi if lora is None else self._pg_multi_lora
-        toks, self.paged.kv = fn(
-            self.params, self.paged.kv, gidx, idxv, sidx, tokens, sub,
-            *args, n=n, **kw)
+            toks = tok[:, None]
+        else:
+            fn = self._pg_multi if lora is None else self._pg_multi_lora
+            toks, self.paged.kv, *counted = fn(
+                self.params, self.paged.kv, gidx, idxv, sidx, tokens, sub,
+                *args, n=n, **kw)
+        if self.step_stats is not None:
+            self.step_stats.pend("decode", counted)
         return toks
 
     def _paged_register_pages(self, token_ids, slot: int,
@@ -3172,9 +3212,12 @@ class InferenceEngine:
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("prefill")
             fn = self._pg_chunk if lora is None else self._pg_chunk_lora
-            first, last, self.paged.kv = fn(
+            first, last, self.paged.kv, *counted = fn(
                 self.params, self.paged.kv, *rows, *tail,
                 *self._sampling_args(sampled), **kw)
+            if self.step_stats is not None:
+                self.step_stats.pend("chunk", counted, last,
+                                     [(slot, req)])
             self.steptrace.window_issued()
             # force before the window closes, exactly like
             # _prefill_into_slot
@@ -3463,6 +3506,8 @@ class InferenceEngine:
         chunk or fused mixed dispatch, booked in the chunk-row counters
         (the device computes exactly the rows that chunk)."""
         self._note_chunk_rows(len(entries), len(entries))
+        if self.step_stats is not None:
+            self.step_stats.note_chunk_rows(entries)
         return self._paged_chunk_rows(
             [(slot, st["done"], chunk) for slot, st, chunk in entries],
             W, self.chunked_prefill)
@@ -3495,9 +3540,11 @@ class InferenceEngine:
         rows = self._paged_entry_rows(entries, W)
         finishing = self._finishing(entries)
         tail, sampled = self._tail_key(finishing)
-        first, last, self.paged.kv = fn(
+        first, last, self.paged.kv, *counted = fn(
             self.params, self.paged.kv, *rows, *tail,
             *self._sampling_args(sampled, runs=bool(finishing)), **kw)
+        if self.step_stats is not None:
+            self.step_stats.pend("chunk", counted, last, finishing)
         self._chunks_done(entries, last)
         return first
 
@@ -4279,9 +4326,12 @@ class InferenceEngine:
                 # page, which REBINDS the (donated) pool read below
                 rows = self._paged_entry_rows(entries, W)
                 plan = self._paged_decode_plan(active, n, W)
-                first, chunk_last, toks, self.paged.kv = fn(
+                first, chunk_last, toks, self.paged.kv, *counted = fn(
                     self.params, self.paged.kv, *rows, *tail, *plan,
                     *sampling, n=n, **kw)
+                if self.step_stats is not None:
+                    self.step_stats.pend("mixed", counted, chunk_last,
+                                         self._finishing(entries))
             else:
                 self._note_chunk_rows(len(entries), self.max_slots)
                 if gmask is not None:
@@ -4365,6 +4415,8 @@ class InferenceEngine:
                 if busy or spent:
                     with self.steptrace.scope("sample_commit"):
                         self.dispatch_meter.note_step(spent)
+                        if self.step_stats is not None:
+                            self.step_stats.book()
                     self.steptrace.step_end(self.tracer)
                 else:
                     self.steptrace.step_abort()

@@ -1,0 +1,223 @@
+"""Step statistics of models whose programs count what they did.
+
+Two things the ordinary decode / chunk / fused mixed programs cannot tell
+the host by their tokens alone, for two kinds of model:
+
+- a ROUTED model outside the block step (``models/deepseek_v3.py``): how
+  the step's tokens loaded the experts held here. The model counts that on
+  the device; the counts leave the program through the transient cache
+  VIEW: the model's ``step_stats(rows)`` gives zeroed entries per layer,
+  the paged programs add them to the view they gather
+  (``InferenceEngine._paged_view``), the routed layers fill them, and the
+  programs return them beside their tokens (:meth:`StepStats.of_view`).
+  They are no part of the page pool, and a model without ``step_stats``
+  gets none of this: its programs lower exactly as before.
+- a LATENT model (a cache row that is one latent, no ``k`` / ``v``): how
+  many cache rows a decode step's attention really needed against how
+  many the pow2 view made it read.
+
+:class:`RoutingLoad` is the one place expert load is booked; the
+block-diffusion decoder (``serve/block_step.py``) books its passes into
+one too, and ``/metrics`` reads whichever the engine has.
+"""
+
+from __future__ import annotations
+
+import jax
+import numpy as np
+
+from llm_in_practise_tpu.models.layers import LOAD_KEY, ROUTE_KEY
+
+
+class RoutingLoad:
+    """Lifetime expert-load counters (engine-thread writes, scrape-side
+    reads of monotone numbers). ``layer_passes`` counts (layer, pass)
+    pairs: one run of one routed layer over one batch of tokens, the
+    unit that streams the touched experts' weights once."""
+
+    def __init__(self, n_experts: int):
+        self.n_experts = int(n_experts)     # experts held here
+        self.layer_passes = 0
+        self.assignments = 0
+        self.experts_touched = 0
+        self.max_load = 0
+        self.mean_load = 0.0
+
+    def book(self, layer_passes: int, assignments: int, touched: int,
+             max_load: int) -> None:
+        """``touched`` / ``max_load``: distinct experts that received a
+        token and the busiest expert's load, each summed over the
+        (layer, pass) pairs."""
+        self.layer_passes += int(layer_passes)
+        self.assignments += int(assignments)
+        self.experts_touched += int(touched)
+        self.max_load += int(max_load)
+        if self.n_experts:
+            self.mean_load += float(assignments) / self.n_experts
+
+    def book_counts(self, counts: np.ndarray) -> None:
+        """``counts`` (layer passes, experts): assignments per expert."""
+        self.book(counts.shape[0], counts.sum(), (counts > 0).sum(),
+                  counts.max(axis=1).sum())
+
+    def counters(self) -> dict:
+        return {"moe_layer_passes": self.layer_passes,
+                "moe_assignments": self.assignments,
+                "moe_experts_touched": self.experts_touched,
+                "moe_max_expert_load": self.max_load,
+                "moe_mean_expert_load": self.mean_load}
+
+
+def stats_model(model):
+    """The core model if it exports step statistics or keeps a latent
+    cache, else None."""
+    core = getattr(model, "inner", model)
+    return core if hasattr(core, "step_stats") else None
+
+
+class StepStats:
+    """One engine's step statistics: the traced helpers its paged
+    programs call, and the host-side booking of what they return."""
+
+    def __init__(self, engine, core):
+        self.eng, self.core = engine, core
+        self.check_engine(engine)
+        tpl = core.step_stats(1)
+        self.routed = [i for i, d in enumerate(tpl) if d]
+        self.load = RoutingLoad(core.config.held[1])
+        self.latent_tokens_attended = 0
+        self.latent_view_tokens = 0
+        self.prefill_qk_pairs = 0
+        self.prefill_keys_read = 0
+        # reference comparisons (tests, the benchmark's check) set this
+        # to a list: every booked program then appends {"kind", "uids":
+        # {slot: request uid} at the dispatch, "route": per part (routed
+        # layers, rows, k) experts each row's last position chose,
+        # "last_logits": {slot: (vocab,)} of the prompts a chunk or mixed
+        # program finished}. None: nothing kept.
+        self.capture = None
+        self._pending: list[tuple] = []
+
+    @staticmethod
+    def check_engine(engine) -> None:
+        """Build-time refusals: what a latent cache and a held share of
+        routed experts cannot meet yet, each by name."""
+        def no(what: str, why: str):
+            raise ValueError(f"latent / routed model: {what} is not "
+                             f"supported — {why}")
+
+        if engine.paged is None:
+            no("kv_layout='contiguous'",
+               "the step statistics ride the paged programs' view; use "
+               "kv_layout='paged'")
+        if engine.mesh is not None:
+            no("a device mesh (tensor parallelism)",
+               "the grouped expert kernel is not partitioned and a latent "
+               "row has no head axis to shard; experts exchanged between "
+               "chips are future work (ROADMAP M6)")
+        if engine.speculative_k is not None or engine.draft_model is not None:
+            no("speculative decoding",
+               "the speculative round's programs return no routing "
+               "statistics, and no self-draft layer is built (ROADMAP M5)")
+        if engine.adapter_registry is not None:
+            no("multi-LoRA", "the adapter twins know dense projections only")
+        if engine.kv_pool is not None or engine.session_store is not None:
+            no("tiered KV / the session store",
+               "their entries and byte accounting assume k / v rows")
+        if engine.role != "both" or engine.handoff is not None:
+            no("disaggregated prefill/decode",
+               "a handed-off entry is k / v rows")
+
+    # --- inside the jitted programs ------------------------------------------
+
+    def view_entries(self, rows: int) -> list[dict]:
+        return self.core.step_stats(rows)
+
+    def of_view(self, view) -> list[dict]:
+        """The routed layers' entries of a cache view a body returned."""
+        return [{k: view[i][k] for k in (LOAD_KEY, ROUTE_KEY)}
+                for i in self.routed]
+
+    def zero_rows(self) -> list[dict]:
+        """The row loop's accumulator: loads summed over its trips,
+        routes by slot."""
+        return self.of_view(self.core.step_stats(self.eng.max_slots))
+
+    @staticmethod
+    def add_row(acc, one, slot):
+        """``acc`` after a trip whose one-row view returned ``one``."""
+        return [{LOAD_KEY: a[LOAD_KEY] + o[LOAD_KEY],
+                 ROUTE_KEY: jax.lax.dynamic_update_slice_in_dim(
+                     a[ROUTE_KEY], o[ROUTE_KEY], slot, axis=0)}
+                for a, o in zip(acc, one)]
+
+    # --- on the host -----------------------------------------------------------
+
+    def note_decode_view(self, active, n: int, width: int) -> None:
+        """A decode (or mixed step's decode half) of ``n`` tokens over
+        ``active`` at view width ``width``: the rows its attention needed
+        against the rows of the slot plane's view."""
+        eng = self.eng
+        attended = sum(int(eng.slot_len[s]) + n for s in active)
+        view = eng.max_slots * int(width)
+        self.latent_tokens_attended += attended
+        self.latent_view_tokens += view
+        eng.steptrace.note_extra(latent_tokens_attended=attended,
+                                 view_tokens=view)
+
+    def note_chunk_rows(self, entries) -> None:
+        """A chunk or mixed dispatch advances ``entries`` ((slot, state,
+        chunk) triples): the (query, key) pairs its causal attention
+        covers (query ``i`` of a chunk that starts at ``done`` sees
+        ``done + i + 1`` keys) and the cache rows it reads."""
+        pairs = sum(len(c) * st["done"] + len(c) * (len(c) + 1) // 2
+                    for _, st, c in entries)
+        keys = sum(st["done"] + len(c) for _, st, c in entries)
+        self.prefill_qk_pairs += pairs
+        self.prefill_keys_read += keys
+        self.eng.steptrace.note_extra(prefill_qk_pairs=pairs,
+                                      prefill_keys_read=keys)
+
+    def pend(self, kind: str, stats, last=None, finishing=()) -> None:
+        """Keep a program's statistics output (device arrays) until the
+        step's end; ``last`` / ``finishing``: a chunk or mixed program's
+        last-position logits and the (slot, request) pairs it finished,
+        read only under ``capture``."""
+        if not stats:
+            return      # a program without the output (a masked twin)
+        kept = None
+        if self.capture is not None:
+            kept = (last, [slot for slot, _ in finishing],
+                    {s: r.uid for s, r in enumerate(self.eng.slot_req)
+                     if r is not None})
+        self._pending.append((kind, stats, kept))
+
+    def book(self) -> None:
+        """End of step: fetch what its programs counted (they have
+        completed: the step fetched their tokens) and book it."""
+        if not self._pending:
+            return
+        pending, self._pending = self._pending, []
+        fetched = jax.device_get([p[1] for p in pending])   # graftlint: disable=host-sync
+        for (kind, _, kept), parts in zip(pending, fetched):
+            # parts: one per trunk the program ran (a mixed program's
+            # chunk rows, then its decode half), each a list of the
+            # routed layers' entries
+            loads = np.sum([layer[LOAD_KEY] for part in parts
+                            for layer in part], axis=0)
+            self.load.book(*(int(v) for v in loads))
+            self.eng.steptrace.note_extra(
+                moe_layer_passes=int(loads[0]),
+                moe_assignments_held=int(loads[1]),
+                moe_experts_touched=int(loads[2]),
+                moe_max_expert_load=int(loads[3]))
+            if kept is not None and self.capture is not None:
+                last, slots, uids = kept
+                self.capture.append({
+                    "kind": kind, "uids": uids,
+                    "route": [np.stack([layer[ROUTE_KEY] for layer in part])
+                              for part in parts],
+                    # reference comparisons only
+                    "last_logits": {
+                        s: np.asarray(last[s])  # graftlint: disable=host-sync
+                        for s in slots}})

@@ -122,3 +122,42 @@ def test_grouped_expert_layer_compiles(chip, tokens, monkeypatch):
         s((e, h, w), jnp.bfloat16), s((e, h, w), jnp.bfloat16),
         s((e, w, h), jnp.bfloat16))
     assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("tokens", [16, 2048])
+def test_held_expert_share_compiles(chip, tokens, monkeypatch):
+    """One chip's share of DeepSeek-V3's routed layer (16 of 256 experts
+    of 7168 x 2048, top-8): a decode step's 16 tokens and a chunk row's
+    2,048, both branches of the rows-buffer ``cond``. The weight tiles
+    (1792 x 1024 and 2048 x 1024) must fit the chip's fast memory."""
+    from llm_in_practise_tpu.ops import grouped_experts as ge
+
+    monkeypatch.setattr(ge, "interpret_default", lambda: False)
+    assert ge._tile(2048, 768) == (2048, 768)       # SDAR's: whole
+    assert ge._tile(7168, 2048) == (1792, 1024)
+    assert ge._tile(2048, 7168) == (2048, 1024)
+    e, h, w, k = 16, 7168, 2048, 8
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    text = _compile(
+        lambda *a: ge.grouped_expert_ffn(*a, held=(0, e), n_experts=256),
+        s((tokens, h), jnp.bfloat16), s((tokens, k), jnp.int32),
+        s((tokens, k), jnp.float32), s((e, h, w), jnp.bfloat16),
+        s((e, h, w), jnp.bfloat16), s((e, w, h), jnp.bfloat16))
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("keys", [4096, 16384])
+def test_mla_prefill_attention_compiles(chip, keys, monkeypatch):
+    """The blocked MLA prefill form at the published widths: a
+    2,048-query chunk over a latent view, 128 heads of 192-wide keys and
+    128-wide values decompressed 4,096 keys at a time."""
+    from llm_in_practise_tpu.ops import mla_attention as mla
+
+    monkeypatch.setattr(mla, "interpret_default", lambda: False)
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    _compile(
+        lambda *a: mla.prefill_attention(*a, rank=512, scale=0.1),
+        s((1, 2048, 128, 128)), s((1, 2048, 128, 64)), s((1, keys, 576)),
+        s((1,), jnp.int32), s((512, 128, 256)))

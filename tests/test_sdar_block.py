@@ -459,12 +459,12 @@ def test_counters_add_up(model, params):
     assert sum(r["tokens_revealed"] for r in records) == blk.tokens_revealed
     # expert load: every pass routes the whole plane through every layer
     per_pass = engine.max_slots * B * CFG.n_experts_per_tok * CFG.n_layer
-    assert blk.moe_assignments == blk.passes * per_pass
-    assert (blk.passes * CFG.n_layer <= blk.moe_experts_touched
+    assert blk.routing.assignments == blk.passes * per_pass
+    assert (blk.passes * CFG.n_layer <= blk.routing.experts_touched
             <= blk.passes * CFG.n_layer * CFG.n_experts)
-    assert blk.moe_max_load * CFG.n_experts >= blk.moe_assignments
-    assert blk.moe_mean_load == pytest.approx(
-        blk.moe_assignments / CFG.n_experts)
+    assert blk.routing.max_load * CFG.n_experts >= blk.routing.assignments
+    assert blk.routing.mean_load == pytest.approx(
+        blk.routing.assignments / CFG.n_experts)
     # every dispatch window is booked to the requests that rode it
     assert all(r.cp.get("decode_dispatch", 0.0) > 0 for r in reqs)
 
