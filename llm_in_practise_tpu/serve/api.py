@@ -809,8 +809,15 @@ class OpenAIServer:
         if getattr(eng, "paged", None) is not None:
             reg.gauge_func(
                 "llm_kv_row_bytes", lambda: eng.paged.row_bytes,
-                "pool bytes of one token position over all layers (k and "
-                "v heads, or one latent row a layer)")
+                "pool bytes one token position holds over all layers, as "
+                "STORED (k and v heads; or one latent row a layer, padded "
+                "to whole lane tiles where the pool is stored by pages)")
+            reg.counter_func(
+                "llm_kv_view_pages_gathered_total",
+                lambda: eng.view_pages_gathered,
+                "pages the paged programs' views gathered whole (a pool "
+                "stored by pages: a latent cache); 0 for a flat pool, "
+                "whose views gather rows")
         # device plane (obs/cost.py + DispatchMeter.note_phase): live
         # per-phase MFU / HBM-bandwidth-utilization / tokens-per-
         # dispatch — the compute-vs-bandwidth-bound dial. Phases appear

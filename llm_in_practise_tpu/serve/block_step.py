@@ -359,7 +359,7 @@ class BlockDecoder:
             st.window_begin("decode")
             out = self._pg_block(
                 eng.params, eng.paged.kv,
-                jnp.asarray(eng.paged.gather_idx(W)), jnp.asarray(idxv),
+                jnp.asarray(eng._paged_view_idx(W)), jnp.asarray(idxv),
                 jnp.asarray(eng.paged.scatter_idx(idxv, valid, B)),
                 jnp.asarray(self.tok), jnp.asarray(self.rev), sub,
                 *sampling, jnp.asarray(quota), threshold, dynamic)

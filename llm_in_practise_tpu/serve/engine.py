@@ -61,6 +61,7 @@ from llm_in_practise_tpu.obs.prof import CompileMeter
 from llm_in_practise_tpu.obs.registry import HistogramAccumulator
 from llm_in_practise_tpu.obs.steptrace import StepTrace
 from llm_in_practise_tpu.obs.trace import get_tracer
+from llm_in_practise_tpu.serve import paged_kv
 from llm_in_practise_tpu.serve.mixed_step import (
     batched_chunk,
     batched_chunk_hidden,
@@ -813,6 +814,9 @@ class InferenceEngine:
         # the device computed for them (_note_chunk_rows)
         self.prefill_chunk_rows = 0
         self.prefill_chunk_row_slots = 0
+        # pages the paged views gathered whole (_paged_view_idx; stays 0
+        # for a flat pool, whose views gather rows)
+        self.view_pages_gathered = 0
         # prompts that finished in a chunk, mixed or suffix program, by
         # where their first token was sampled (_note_first_token)
         self.first_tokens = {"program": 0, "host": 0}
@@ -1574,11 +1578,19 @@ class InferenceEngine:
     # buckets). Discarded writes (idle rows, padding past a row's valid
     # window) are routed by the host indices into the reserved trash
     # page, which replaces the contiguous path's clamp-and-overwrite
-    # dead-write reasoning wholesale.
+    # dead-write reasoning wholesale. A pool stored by pages (a row
+    # that is one vector: paged_kv.stored_by_pages) takes the same
+    # arguments through paged_kv's accessors: its view gathers whole
+    # pages from block-table columns, its writes split the host's flat
+    # rows into (page, offset); a flat pool's programs are untouched.
 
     def _paged_view(self, pool, gidx, index_vec):
         """Gather each slot's pages into a contiguous cache view
-        (slots, W, ...) with the per-slot index pinned from the host."""
+        (slots, W, ...) with the per-slot index pinned from the host.
+        ``gidx`` is :meth:`PagedKV.view_idx`'s: pool rows (S, W) for a
+        flat pool, whole pages (S, W / page_size) for one stored by
+        pages."""
+        by_pages = self.paged.form == "pages"
         S, W = gidx.shape
         flat = gidx.reshape(-1)
         view = []
@@ -1586,9 +1598,12 @@ class InferenceEngine:
         # gets its zeroed entries beside each layer's gathered rows
         extra = ([{}] * len(pool) if self.step_stats is None
                  else self.step_stats.view_entries(S))
-        for layer, more in zip(pool, extra):
+        for layer, more, tails in zip(pool, extra, self.paged.tails):
             d = {"index": index_vec.astype(jnp.int32), **more}
             for key, buf in layer.items():
+                if by_pages:
+                    d[key] = paged_kv.take_pages(buf, gidx, *tails[key])
+                    continue
                 # clip, not take's default fill: the host builds every
                 # index inside the pool (unmapped pages read the trash
                 # page), and fill's out-of-bounds select is one more
@@ -1603,6 +1618,7 @@ class InferenceEngine:
         """Scatter each row's freshly written window
         ``[wstart[s], wstart[s] + Wwin)`` from the view back into the
         pool at the host-resolved page rows ``sidx``."""
+        by_pages = self.paged.form == "pages"
         S, Wwin = sidx.shape
         flat = sidx.reshape(-1)
         j = jnp.arange(Wwin)
@@ -1615,6 +1631,10 @@ class InferenceEngine:
                 pos = jnp.clip(wstart[:, None] + j[None, :], 0, W - 1)
                 idx = pos.reshape((S, Wwin) + (1,) * (vb.ndim - 2))
                 rows = jnp.take_along_axis(vb, idx, axis=1)
+                if by_pages:
+                    d[key] = paged_kv.set_page_rows(
+                        buf, flat, rows.reshape(S * Wwin, -1))
+                    continue
                 d[key] = buf.at[flat].set(
                     rows.reshape((S * Wwin,) + vb.shape[2:]).astype(
                         buf.dtype))
@@ -1806,6 +1826,7 @@ class InferenceEngine:
         """Scatter B bucket-width row sets (one-shot prefill output, a
         prefix/handoff entry's rows) into pages; ``rows`` may carry an
         ``index`` key (pool iteration ignores it)."""
+        by_pages = self.paged.form == "pages"
         S, Wb = sidx.shape
         flat = sidx.reshape(-1)
         new = []
@@ -1813,6 +1834,10 @@ class InferenceEngine:
             d = {}
             for key, buf in pl.items():
                 rb = rl[key]
+                if by_pages:
+                    d[key] = paged_kv.set_page_rows(
+                        buf, flat, rb.reshape(S * Wb, -1))
+                    continue
                 d[key] = buf.at[flat].set(
                     rb.reshape((S * Wb,) + rb.shape[2:]).astype(
                         buf.dtype))
@@ -1822,6 +1847,10 @@ class InferenceEngine:
     def _paged_gather_rows_fn(self, pool, gidx):
         """Index-free rows list (1, W, ...) per layer — the page-wise
         twin of ``_slot_rows_fn`` for prefix/handoff entries."""
+        if self.paged.form == "pages":
+            return [{key: paged_kv.take_page_rows(buf, gidx, *tails[key])
+                     for key, buf in layer.items()}
+                    for layer, tails in zip(pool, self.paged.tails)]
         S, W = gidx.shape
         flat = gidx.reshape(-1)
         return [
@@ -1834,6 +1863,9 @@ class InferenceEngine:
     def _paged_page_copy_fn(self, pool, src, dst):
         """Copy one physical page's rows (COW fork: a write would land
         in a page some other reader still maps)."""
+        if self.paged.form == "pages":
+            return [{key: paged_kv.copy_page(buf, src, dst)
+                     for key, buf in layer.items()} for layer in pool]
         P = self.paged.page_size
         new = []
         for layer in pool:
@@ -2010,6 +2042,17 @@ class InferenceEngine:
         peak ROADMAP item 1's in-place paged attention reclaims."""
         self._hbm.pulse("transient_view", self.paged.view_bytes(W, n_slots))
 
+    def _paged_view_idx(self, W: int, slots=None) -> np.ndarray:
+        """:meth:`PagedKV.view_idx` for a view this step gathers, booked
+        where the pool gathers whole pages (``view_pages_gathered``, the
+        step record's ``view_pages``; a flat pool's records have no such
+        field)."""
+        idx = self.paged.view_idx(W, slots)
+        if self.paged.form == "pages":
+            self.view_pages_gathered += idx.size
+            self.steptrace.note_extra(view_pages=idx.size)
+        return idx
+
     def _paged_decode_plan(self, active: list[int], n: int, W: int):
         """Index arguments ``(gidx, index_vec, sidx)`` of a slot-plane
         decode block of ``n`` tokens at view width ``W``: every slot's
@@ -2023,7 +2066,7 @@ class InferenceEngine:
             self._paged_cow_fork(s, int(self.slot_len[s]), n)
         if self.step_stats is not None:
             self.step_stats.note_decode_view(active, n, W)
-        return (jnp.asarray(self.paged.gather_idx(W)), jnp.asarray(idxv),
+        return (jnp.asarray(self._paged_view_idx(W)), jnp.asarray(idxv),
                 jnp.asarray(self.paged.scatter_idx(idxv, valid, n)))
 
     def _paged_decode_dispatch(self, active: list[int], n: int, sub,
@@ -3485,7 +3528,6 @@ class InferenceEngine:
         tok = np.zeros((R, C), np.int32)
         starts = np.zeros((R,), np.int32)
         lens = np.zeros((R,), np.int32)
-        gidx = np.zeros((R, W), np.int32)
         sidx = np.zeros((R, C), np.int32)
         plane_starts = np.zeros((S,), np.int32)
         plane_valid = np.zeros((S,), np.int32)
@@ -3495,7 +3537,9 @@ class InferenceEngine:
             starts[i] = plane_starts[slot] = done
             lens[i] = plane_valid[slot] = len(chunk)
             self._paged_cow_fork(slot, done, len(chunk))
-        gidx[:k] = self.paged.gather_idx(W)[slots[:k]]
+        vidx = self._paged_view_idx(W, slots[:k])
+        gidx = np.zeros((R,) + vidx.shape[1:], np.int32)
+        gidx[:k] = vidx
         sidx[:k] = self.paged.scatter_idx(
             plane_starts, plane_valid, C)[slots[:k]]
         return tuple(jnp.asarray(a) for a in (
@@ -3935,7 +3979,7 @@ class InferenceEngine:
                           else self._pg_spec_masked_lora)
                     out, n_acc, extra, self.paged.kv = fn(
                         self.params, self.paged.kv,
-                        jnp.asarray(self.paged.gather_idx(W)),
+                        jnp.asarray(self._paged_view_idx(W)),
                         jnp.asarray(idxv),
                         jnp.asarray(self.paged.scatter_idx(
                             idxv, valid, k + 1 + m)),
@@ -3946,7 +3990,7 @@ class InferenceEngine:
                           else self._pg_spec_lora)
                     out, n_acc, extra, self.paged.kv = fn(
                         self.params, self.paged.kv,
-                        jnp.asarray(self.paged.gather_idx(W)),
+                        jnp.asarray(self._paged_view_idx(W)),
                         jnp.asarray(idxv),
                         jnp.asarray(self.paged.scatter_idx(idxv, valid,
                                                            k + 1 + m)),

@@ -41,6 +41,13 @@ from (see docs/paged-kv.md for the admission math and the workspace
 caveat; a fused paged-attention Pallas kernel that reads pages in place
 is the follow-up that removes the gather entirely).
 
+Two physical forms, chosen by the ROW's shape (:func:`stored_by_pages`):
+the flat form above suits a row with a head axis, ``(heads, dim)``; a row
+that is ONE vector (a latent cache: ``ckv`` of DeepSeek-V3, 576 wide) is
+stored by pages, ``(num_pages, page_size, width rounded up to whole lane
+tiles)``, gathered a page at a time from the block table itself, and
+sliced back to its width once (docs/paged-kv.md, "A latent page row").
+
 Sharing/refcount protocol (one invariant the churn test pins): a
 physical page's refcount equals the number of slot block tables mapping
 it, plus one if the :class:`~.prefix_cache.PagedPrefixIndex` holds it.
@@ -53,6 +60,8 @@ from __future__ import annotations
 import dataclasses
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from llm_in_practise_tpu.obs.hbm import get_ledger
@@ -85,6 +94,67 @@ def kv_row_bytes(model, dtype) -> int:
                 continue
             total += (buf.size // probe) * buf.dtype.itemsize
     return total
+
+
+#: lanes of the chip's tile: the minor dimension of a buffer the chip keeps
+#: row-major is a whole number of them
+LANE = 128
+
+
+def stored_by_pages(tails) -> bool:
+    """The storage rule, a property of the rows' shapes (``tails``: each
+    pool buffer's per-token row shape). The chip tiles a buffer's two
+    minor dimensions, and keeps a buffer by rows across programs only if
+    the axis the view gathers along is neither of them and the minor one
+    is whole lane tiles. A row with a head axis, ``(heads, dim)``, keeps
+    the gathered axis out of the tile in the flat ``(rows, heads, dim)``
+    form. A row that is one vector does not: flat, its token axis is
+    tiled with a width the chip will not keep minor (576 is 4.5 lane
+    tiles: the compiler stores ``(rows, 576)`` column-major and every
+    program re-lays the whole pool out twice). Such a pool is stored by
+    pages, ``(num_pages, page_size, width up to whole lanes)``: the page
+    axis is gathered, a page is one contiguous block. True when every
+    row is one vector."""
+    tails = list(tails)
+    return bool(tails) and all(len(t) == 1 for t in tails)
+
+
+def lane_whole(width: int) -> int:
+    return -(-int(width) // LANE) * LANE
+
+
+# The traced accessors of a pool buffer stored by pages, ``buf``
+# (num_pages, page_size, lanes); ``width`` is the row's own (the pad
+# columns stay zero: every write pads with zeros).
+
+def take_pages(buf, page_idx, width: int):
+    """The view ``(S, n * page_size, width)`` of the pages ``page_idx``
+    (S, n) names, each taken whole."""
+    S, n = page_idx.shape
+    # clip, not take's default fill: see InferenceEngine._paged_view
+    pages = jnp.take(buf, page_idx.reshape(-1), axis=0, mode="clip")
+    return pages.reshape(S, n * buf.shape[1], buf.shape[2])[..., :width]
+
+
+def take_page_rows(buf, flat_idx, width: int):
+    """Rows ``(*flat_idx.shape, width)`` by their flat pool-row index
+    (``page * page_size + offset``, as the host builders give it)."""
+    page, off = jnp.divmod(flat_idx, buf.shape[1])
+    return buf[page, off, :width]
+
+
+def set_page_rows(buf, flat_idx, rows):
+    """``buf`` with ``rows`` (N, width) written at the flat pool-row
+    indices ``flat_idx`` (N,)."""
+    page, off = jnp.divmod(flat_idx, buf.shape[1])
+    rows = jnp.pad(rows.astype(buf.dtype),
+                   ((0, 0), (0, buf.shape[2] - rows.shape[-1])))
+    return buf.at[page, off].set(rows)
+
+
+def copy_page(buf, src, dst):
+    page = jax.lax.dynamic_slice_in_dim(buf, src, 1, axis=0)
+    return jax.lax.dynamic_update_slice_in_dim(buf, page, dst, axis=0)
 
 
 class PagePoolExhausted(RuntimeError):
@@ -285,17 +355,15 @@ class PagedHit:
 
 
 class PagedKV:
-    """Device-side paged KV state for one engine: per-layer flat pools
-    + per-slot block tables + the host-side index-array builders the
-    jitted paged programs consume.
+    """Device-side paged KV state for one engine: per-layer pools (flat
+    by rows, or by pages: :func:`stored_by_pages`) + per-slot block
+    tables + the host-side index-array builders the jitted paged
+    programs consume.
     """
 
     def __init__(self, model, *, max_slots: int, cache_len: int,
                  page_size: int, pool_tokens: int, dtype,
                  mesh=None):
-        import jax
-        import jax.numpy as jnp
-
         self.page_size = int(page_size)
         self.cache_len = int(cache_len)
         self.max_slots = int(max_slots)
@@ -308,19 +376,25 @@ class PagedKV:
             (max_slots, self.pages_per_slot), np.int32)
         # pages currently mapped per slot (bt[s, :n] are live)
         self.slot_pages_n = np.zeros((max_slots,), np.int32)
-        # flat token-major pools, one dict per layer, index key dropped
-        # (the per-dispatch view carries its own pinned index vector)
+        # one dict of pool buffers per layer, index key dropped (the
+        # per-dispatch view carries its own pinned index vector);
+        # ``tails``: each buffer's per-token row shape as the model has it
         tpl = model.init_cache(1, self.page_size, dtype=dtype)
         self.n_layers = len(tpl)
         pool_rows = num_pages * self.page_size
+        self.tails = [{key: tuple(buf.shape[2:])    # (1, P, *tail)
+                       for key, buf in layer.items() if key != "index"}
+                      for layer in tpl]
+        # the pool's physical form, "rows" | "pages": stored_by_pages
+        self.form = "pages" if stored_by_pages(
+            t for layer in self.tails for t in layer.values()) else "rows"
         kv = []
-        for layer in tpl:
+        for layer, tails in zip(tpl, self.tails):
             bufs = {}
-            for key, buf in layer.items():
-                if key == "index":
-                    continue
-                tail = tuple(buf.shape[2:])   # (1, P, *tail)
-                bufs[key] = jnp.zeros((pool_rows,) + tail, buf.dtype)
+            for key, tail in tails.items():
+                shape = ((num_pages, self.page_size, lane_whole(tail[0]))
+                         if self.form == "pages" else (pool_rows,) + tail)
+                bufs[key] = jnp.zeros(shape, layer[key].dtype)
             kv.append(bufs)
         if mesh is not None:
             kv = jax.device_put(kv, self._pool_shardings(kv, mesh))
@@ -435,6 +509,18 @@ class PagedKV:
         return (self.block_tables[:, lp] * P
                 + (t % P)[None, :]).astype(np.int32)
 
+    def view_idx(self, width: int, slots=None) -> np.ndarray:
+        """The index argument of a view ``width`` tokens wide over every
+        slot (or the listed ``slots``), as this pool's form gathers it: a
+        pool stored by pages takes the block table's first ``width /
+        page_size`` columns (whole pages; unmapped ones are the trash
+        page), a flat one :meth:`gather_idx`'s pool rows."""
+        if self.form != "pages":
+            idx = self.gather_idx(width)
+            return idx if slots is None else idx[slots]
+        bt = self.block_tables if slots is None else self.block_tables[slots]
+        return bt[:, :pages_for(width, self.page_size)].astype(np.int32)
+
     def row_gather_idx(self, slot: int, width: int) -> np.ndarray:
         """(1, width) flat indices over one slot (handoff/offload rows)."""
         P = self.page_size
@@ -505,6 +591,14 @@ class PagedKV:
             "alloc_failures": pool["alloc_failures"],
             "block_table_pages_per_slot": [
                 int(n) for n in self.slot_pages_n],
+            # each buffer's physical form and what one token's row of it
+            # holds in the pool (first layer; a "pages" row is padded to
+            # whole lane tiles)
+            "buffers": {
+                key: {"form": self.form,
+                      "row_bytes": int(buf.nbytes) // (
+                          self.pool.num_pages * self.page_size)}
+                for key, buf in self.kv[0].items()},
             "ledger_account": "kv_pool.pages",
             "page_bytes": self.page_bytes,
             "pool_bytes": self.pool_bytes,

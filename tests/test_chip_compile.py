@@ -161,3 +161,47 @@ def test_mla_prefill_attention_compiles(chip, keys, monkeypatch):
         lambda *a: mla.prefill_attention(*a, rank=512, scale=0.1),
         s((1, 2048, 128, 128)), s((1, 2048, 128, 64)), s((1, keys, 576)),
         s((1,), jnp.int32), s((512, 128, 256)))
+
+
+def test_latent_page_pool_keeps_its_layout(chip):
+    """One layer of ``deepseek-v3.long-doc-qa``'s decode program at the
+    cell's pool shape, through the engine's own accessors: the view
+    gathered a page at a time (``paged_kv.take_pages``), the absorbed
+    attention over it, the 16-row write-back (``paged_kv.set_page_rows``),
+    the pool donated. The compiler must keep the pool ROW-major at the
+    program's entry and lay none of it out again: a pool-shaped ``copy``
+    or ``transpose`` is a pass over 1.68 GB of pools a step (PERF.md
+    section 6, PR 37)."""
+    import re
+
+    from llm_in_practise_tpu.ops import mla_attention as mla
+    from llm_in_practise_tpu.serve import paged_kv
+
+    slots, width, page, heads, rank, rope = 16, 16384, 16, 128, 512, 64
+    row = rank + rope
+    pool = (slots * width // page + 1, page, paged_kv.lane_whole(row))
+    assert paged_kv.stored_by_pages([(row,)]) and pool[2] == 640
+
+    def step(buf, page_idx, sidx, pos, q_nope, q_rope, new, w_kvb):
+        view = paged_kv.take_pages(buf, page_idx, row)
+        view = jax.vmap(lambda v, n, i: jax.lax.dynamic_update_slice(
+            v, n, (i, 0)))(view, new, pos)
+        out = mla.decode_attention(q_nope, q_rope, view, pos, w_kvb,
+                                   rank=rank, scale=0.1)
+        rows = jnp.take_along_axis(view, pos[:, None, None], axis=1)
+        return out, paged_kv.set_page_rows(buf, sidx, rows[:, 0])
+
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    text = jax.jit(step, donate_argnums=(0,)).lower(
+        s(pool), s((slots, width // page), jnp.int32),
+        s((slots,), jnp.int32), s((slots,), jnp.int32),
+        s((slots, 1, heads, 128)), s((slots, 1, heads, rope)),
+        s((slots, 1, row)), s((rank, heads, 256))).compile().as_text()
+    dims = ",".join(map(str, pool))
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text, re.S)
+    layout = re.search(rf"bf16\[{dims}\]\{{([\d,]+)", entry.group(1))
+    assert layout.group(1) == "2,1,0", layout.group(0)
+    assert not re.findall(rf"= bf16\[{dims}\]\S* (?:copy|transpose)\(", text)
+    # the view's gather takes whole pages
+    assert f"slice_sizes={{1,{page},{pool[2]}}}" in text

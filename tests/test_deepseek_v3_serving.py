@@ -14,8 +14,23 @@ import pytest
 from benchmark.reference import deepseek_v3 as ref
 from benchmark.runners import serve_latent_cell as cell
 from llm_in_practise_tpu.models import deepseek_v3 as dsv3
+from llm_in_practise_tpu.serve import paged_kv
 from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
-from llm_in_practise_tpu.serve.paged_kv import PagedKV, kv_row_bytes
+from llm_in_practise_tpu.serve.paged_kv import (
+    PagedKV,
+    kv_row_bytes,
+    lane_whole,
+)
+
+# the two physical forms of a latent pool (paged_kv.stored_by_pages): what
+# the rule gives a one-vector row, and the flat form the tests steer a
+# pool into by replacing the rule (the program has no option for it)
+FORMS = ["pages", "rows"]
+
+
+def _steer(mp, form):
+    if form == "rows":
+        mp.setattr(paged_kv, "stored_by_pages", lambda tails: False)
 
 GREEDY = SamplingParams(temperature=0.0, greedy=True, max_tokens=10)
 
@@ -27,13 +42,16 @@ def _engine(cfg, params, **kw):
     return InferenceEngine(dsv3.DeepSeekV3(cfg), params, **opts)
 
 
-@pytest.fixture(scope="module")
-def served():
-    """One engine; a 40-token prompt decodes while a 70-token one chunks
-    beside it (fused mixed steps)."""
+@pytest.fixture(scope="module", params=FORMS)
+def served(request):
+    """One engine a pool form; a 40-token prompt decodes while a 70-token
+    one chunks beside it (fused mixed steps)."""
     cfg = dsv3.deepseek_v3_config(compute_dtype="float32", experts_held=8)
     params = dsv3.random_params(cfg, 3, jnp.float32)
-    eng = _engine(cfg, params)
+    with pytest.MonkeyPatch.context() as mp:
+        _steer(mp, request.param)
+        eng = _engine(cfg, params)
+    assert eng.paged.form == request.param
     eng.start()
     rng = np.random.default_rng(0)
     prompts = [rng.integers(4, cfg.vocab_size, n).tolist() for n in (40, 70)]
@@ -47,7 +65,8 @@ def served():
         records = eng.steptrace.records(limit=200)
     yield types.SimpleNamespace(cfg=cfg, params=params, eng=eng,
                                 prompts=prompts, tokens=tokens,
-                                captured=captured, records=records)
+                                captured=captured, records=records,
+                                form=request.param)
     eng.stop()
 
 
@@ -105,22 +124,170 @@ def test_step_records_and_counters(served):
     assert dec and all(r["moe_experts_touched"] <= 2 * 8 for r in dec)
     assert served.eng.routing_load is st.load
     assert 0 < st.latent_tokens_attended < st.latent_view_tokens
+    # which form ran: a pool stored by pages gathers whole pages (each
+    # decode view 4 slots wide, each chunk row one), a flat one none
+    gathered = served.eng.view_pages_gathered
+    assert sum(r.get("view_pages", 0) for r in recs) == gathered
+    if served.form == "pages":
+        assert gathered >= st.latent_view_tokens // 16 > 0
+    else:
+        assert gathered == 0 and not any("view_pages" in r for r in recs)
 
 
 def test_latent_pool_bytes_read_right(served):
     cfg, eng = served.cfg, served.eng
-    row = cfg.n_layer * cfg.latent_dim * 4
+    row = cfg.n_layer * cfg.latent_dim * 4     # the model's own row
     assert kv_row_bytes(dsv3.DeepSeekV3(cfg), jnp.float32) == row
     assert kv_row_bytes(dsv3.DeepSeekV3(cfg), jnp.bfloat16) == row // 2
-    assert eng.paged.row_bytes == row
-    assert eng.paged.pool_bytes == row * (eng.paged.pool.capacity + 1) * 16
+    # stored by pages, each layer's row is padded to whole lane tiles
+    width = (lane_whole(cfg.latent_dim) if served.form == "pages"
+             else cfg.latent_dim)
+    n_pages = eng.paged.pool.num_pages
+    assert eng.paged.row_bytes == cfg.n_layer * width * 4
+    assert eng.paged.pool_bytes == eng.paged.row_bytes * n_pages * 16
+    assert eng.paged.kv[0]["ckv"].shape == (
+        (n_pages, 16, width) if served.form == "pages"
+        else (n_pages * 16, width))
     kv = eng.debug_kv()
-    assert kv["page_bytes"] == row * 16
+    assert kv["page_bytes"] == eng.paged.row_bytes * 16
+    assert kv["buffers"] == {"ckv": {"form": served.form,
+                                     "row_bytes": width * 4}}
     # a latent has no head axis: every pool leaf replicates under a mesh
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("model",))
     for layer in PagedKV._pool_shardings(eng.paged.kv, mesh):
         assert all(s.spec == jax.sharding.PartitionSpec()
                    for s in layer.values())
+
+
+@pytest.fixture
+def small(monkeypatch):
+    """``small(form, **engine options)``: a latent engine whose pool is in
+    ``form``, stepped by the test."""
+    cfg = dsv3.deepseek_v3_config(compute_dtype="float32", experts_held=8)
+    params = dsv3.random_params(cfg, 3, jnp.float32)
+
+    def make(form, **kw):
+        _steer(monkeypatch, form)
+        eng = _engine(cfg, params, **kw)
+        assert eng.paged.form == form
+        return types.SimpleNamespace(cfg=cfg, params=params, eng=eng)
+
+    return make
+
+
+def _drain(eng, prompts, sp):
+    handles = [eng.submit(p, sp) for p in prompts]
+    while eng.step():
+        pass
+    return [h.result() for h in handles]
+
+
+def _row_width(eng):
+    return eng.paged.tails[0]["ckv"][0]
+
+
+def _page_rows(eng, layer, page):
+    """One physical page's 16 rows at the model's width, in either form."""
+    buf, (width,) = eng.paged.kv[layer]["ckv"], eng.paged.tails[layer]["ckv"]
+    rows = buf[page] if eng.paged.form == "pages" else buf[page * 16:
+                                                           page * 16 + 16]
+    return np.asarray(rows[:, :width])
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_one_shot_prefill_writes_its_rows(small, form):
+    """No chunking: a prompt's rows reach the pool through
+    ``_paged_write_rows_fn`` (bucket-wide, the padding to the trash page),
+    12 and 37 tokens: inside one page and across two boundaries."""
+    sv = small(form, chunked_prefill=None)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(4, sv.cfg.vocab_size, n).tolist()
+               for n in (12, 37)]
+    got = _drain(sv.eng, prompts, GREEDY)
+    for prompt, tokens in zip(prompts, got):
+        assert tokens == _own_generate(sv.cfg, sv.params, prompt, 10)[0]
+    sv.eng.paged.pool.check_leaks(0)
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_cow_fork_copies_the_page(small, form):
+    """``_paged_page_copy_fn``: a write window on a shared page forks it;
+    the writer's private copy holds the page's rows, the sharer's page is
+    untouched."""
+    eng = small(form).eng
+    pool = eng.paged.pool
+    pages = pool.alloc(2)
+    eng.paged.map_shared(0, list(pages))
+    pool.share(pages)                           # a phantom second reader
+    rows = jnp.arange(32 * _row_width(eng), dtype=jnp.float32).reshape(
+        1, 32, -1) + 1.0
+    eng.paged.kv = eng._pg_write_rows(
+        eng.paged.kv, [{"ckv": rows}] * eng.paged.n_layers,
+        jnp.asarray(eng.paged.rows_scatter_idx([0], [32], 32)))
+    before = _page_rows(eng, 0, pages[1])
+    np.testing.assert_array_equal(before, np.asarray(rows[0, 16:]))
+    eng._paged_cow_fork(0, 20, 4)               # a window inside page 1
+    forked = int(eng.paged.block_tables[0, 1])
+    assert forked != pages[1] and pool.refcount(forked) == 1
+    for layer in range(eng.paged.n_layers):
+        np.testing.assert_array_equal(_page_rows(eng, layer, forked), before)
+        np.testing.assert_array_equal(_page_rows(eng, layer, pages[1]),
+                                      before)
+    eng.paged.release_slot(0)
+    pool.release(pages)
+    pool.check_leaks(0)
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_preemption_and_resume_keep_the_tokens(small, form):
+    """A pool for two of three requests: preemption fires, the requeued
+    request re-prefills over its registered pages, and every stream is the
+    model's own."""
+    sv = small(form, kv_pool_tokens=96, prefix_cache=True)
+    sp = SamplingParams(temperature=0.0, greedy=True, max_tokens=40)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(4, sv.cfg.vocab_size, 20).tolist()
+               for _ in range(3)]
+    got = _drain(sv.eng, prompts, sp)
+    assert sv.eng.preemptions > 0
+    for prompt, tokens in zip(prompts, got):
+        assert tokens == _own_generate(sv.cfg, sv.params, prompt, 40)[0]
+    sv.eng.prefix_cache.clear()
+    sv.eng.paged.pool.check_leaks(0)
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_an_unmapped_page_reads_the_trash_page(small, form):
+    """A view wider than a slot's mapped pages: the pages behind them are
+    the trash page's rows (beyond the row's index, never attended), and
+    the gather index is whole pages only where the pool is stored so."""
+    eng = small(form).eng
+    pg = eng.paged
+    (page,) = pg.pool.alloc(1)
+    pg.map_shared(1, [page])
+    width = _row_width(eng)
+    mark = lambda v: [{"ckv": jnp.full((1, 16, width), v, jnp.float32)}  # noqa: E731
+                      ] * pg.n_layers
+    # the mapped page holds 5s; a discarded write (length 0) leaves 7s in
+    # the trash page
+    pg.kv = eng._pg_write_rows(
+        pg.kv, mark(5.0), jnp.asarray(pg.rows_scatter_idx([1], [16], 16)))
+    pg.kv = eng._pg_write_rows(
+        pg.kv, mark(7.0), jnp.asarray(pg.rows_scatter_idx([1], [0], 16)))
+    idx = pg.view_idx(64)
+    assert idx.shape == ((4, 4) if form == "pages" else (4, 64))
+    if form == "pages":
+        assert idx[1].tolist() == [page, 0, 0, 0]
+    np.testing.assert_array_equal(idx, pg.view_idx(64, slots=np.arange(4)))
+    view = eng._paged_view(pg.kv, jnp.asarray(idx),
+                           jnp.zeros((4,), jnp.int32))
+    for layer in view:
+        assert layer["ckv"].shape == (4, 64, width)
+        got = np.asarray(layer["ckv"])
+        assert (got[1, :16] == 5).all() and (got[1, 16:] == 7).all()
+        assert (got[0] == 7).all()              # a slot with no page at all
+    pg.release_slot(1)
+    pg.pool.check_leaks(0)
 
 
 def test_engine_refuses_what_the_model_cannot_meet():

@@ -23,7 +23,9 @@ from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
 from llm_in_practise_tpu.serve.paged_kv import (
     PagePool,
     PagePoolExhausted,
+    lane_whole,
     pages_for,
+    stored_by_pages,
 )
 from llm_in_practise_tpu.serve.prefix_cache import PagedPrefixIndex
 
@@ -49,6 +51,54 @@ def _engine(model, params, **kw):
 SHORT = ([3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8])
 LONG = [(i * 7 + 3) % 64 for i in range(40)]   # 5 chunks of 8
 PROMPT = [(i * 7 + 5) % 64 for i in range(37)]  # non-page-aligned
+
+
+# --- the pool's physical form -----------------------------------------------
+
+
+@pytest.mark.parametrize("tails,by_pages", [
+    ([(576,)], True),                   # a latent row: one vector
+    ([(16,), (16,)], True),
+    ([(8, 128), (8, 128)], False),      # k / v rows: a head axis
+    ([(2, 16), (2, 16)], False),        # whatever the head's width
+    ([(576,), (8, 128)], False),        # one pool, one form
+    ([], False),
+])
+def test_storage_rule_reads_the_rows_shape(tails, by_pages):
+    assert stored_by_pages(tails) is by_pages
+    assert [lane_whole(w) for w in (1, 128, 576, 640)] == [128, 128, 640,
+                                                           640]
+
+
+def test_kv_pool_keeps_the_flat_form_and_its_indices(model_params):
+    """A ``k`` / ``v`` pool is what it was: flat ``(rows, heads, dim)``
+    buffers, the view's index is ``gather_idx``'s pool rows, nothing
+    counts pages, and step records carry no ``view_pages``."""
+    model, params = model_params
+    e = _engine(model, params, kv_layout="paged")
+    pg = e.paged
+    assert pg.form == "rows"
+    rows = pg.pool.num_pages * 16
+    for layer in pg.kv:
+        assert {k: b.shape for k, b in layer.items()} == {
+            "k": (rows, 2, 16), "v": (rows, 2, 16)}
+    e.generate(PROMPT, SamplingParams(greedy=True, max_tokens=4))
+    pages = pg.pool.alloc(3)
+    pg.map_shared(2, list(pages))
+    want = np.zeros((4, 64), np.int32)          # unmapped: the trash page
+    want[:] = np.arange(64) % 16
+    want[2, :48] = (np.repeat(pages, 16) * 16 + np.tile(np.arange(16), 3))
+    np.testing.assert_array_equal(pg.gather_idx(64), want)
+    np.testing.assert_array_equal(pg.view_idx(64), want)
+    np.testing.assert_array_equal(pg.view_idx(64, slots=[2, 0]),
+                                  want[[2, 0]])
+    assert e.view_pages_gathered == 0
+    assert not any("view_pages" in r for r in e.steptrace.records(limit=50))
+    snap = e.debug_kv()
+    assert snap["buffers"] == {
+        key: {"form": "rows", "row_bytes": 2 * 16 * 4} for key in "kv"}
+    pg.release_slot(2)
+    pg.pool.check_leaks(0)
 
 
 # --- PagePool unit ----------------------------------------------------------
