@@ -127,6 +127,10 @@ def cache_update(buf: jax.Array, new: jax.Array, index: jax.Array) -> jax.Array:
 # [layer passes, held assignments, held experts touched, busiest held
 # expert's load], and the (rows, k) experts each row's last position chose.
 LOAD_KEY, ROUTE_KEY = "moe_load", "moe_route"
+# Entry the serving programs give a layer that owns its cache writes (a
+# sliding-window layer's ring, models/mimo_v2.py): (B,) how many of the
+# call's positions are real for each row (None: all of them).
+VALID_KEY = "valid"
 
 
 # --- a prefill's tail: one row of logits a prompt ---------------------------

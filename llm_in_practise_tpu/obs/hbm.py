@@ -15,6 +15,10 @@ account               booked by
                       and ``quant/io.load_packed(ledger_account=...)``
 ``kv_pool.pages``     ``paged_kv.PagedKV`` pool buffers (alloc at build,
                       free at ``close()``)
+``kv.window_state``   ``paged_kv.PagedKV`` buffers of the layers whose
+                      state is bounded (a sliding-window layer's ring),
+                      held by slot beside the pool: bytes a slot,
+                      whatever the context
 ``kv.contiguous``     contiguous-layout engine cache
 ``kv.draft``          the draft model's contiguous cache — the byte
                       equivalent of ``/debug/kv.draft_kv_reserved_tokens``

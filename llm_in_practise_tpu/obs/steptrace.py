@@ -326,7 +326,7 @@ class StepTrace:
 
     def note_extra(self, **counts: int) -> None:
         """Counts only some models' steps have (serve/step_stats.py: a
-        latent cache's ``latent_tokens_attended`` / ``view_tokens``, a
+        latent cache's ``latent_tokens_attended`` / ``latent_view_tokens``, a
         routed model's ``moe_*``): summed over the step and written into
         its record under their own names; a step without them has no
         such fields."""

@@ -1,0 +1,431 @@
+"""Grouped-query attention whose keys are wider than its values, with an
+optional BAND (a sliding window) and an optional SINK (MiMo-V2's two layer
+kinds, ``models/mimo_v2.py``).
+
+A query at absolute position ``i`` sees the key at ``j`` when ``0 <= j <=
+i`` and, in a window layer, ``i - j < window`` (itself and the ``window -
+1`` before it). A window layer's head ``h`` has a learned sink logit
+``b_h`` that joins the softmax's denominator and adds no value: ``p_ij =
+exp(s_ij) / (exp(b_h) + sum_j' exp(s_ij'))``, in code :func:`join` of the
+keys' partial result with ``(0, b_h)``.
+
+- :func:`flash_partial`: the Pallas kernel, ``ops/mla_attention.py``'s
+  running-softmax kernel (grouped heads, ``Dq != Dv``, absolute starts, a
+  log-sum-exp out) with a static ``window``. With a band the key axis of
+  the grid is only as long as the blocks a query block's band can touch,
+  and the ``kv_map`` names the band's FIRST live block as it names the
+  last, so the blocks under the band are neither computed nor fetched. A
+  sibling of that kernel and not an option of it: DeepSeek-V3's programs
+  keep their lowered text.
+- :func:`prefill_attention`: a stretch of queries over ``[cached keys ‖
+  the stretch's own]``. A global layer's cached keys are the view the
+  stretch was written into; a window layer's are its ring, at most
+  ``window`` rows, put in order and attended by the first ``window``
+  queries only (dense, a ``window x window`` corner), joined with the
+  kernel's result over the stretch's own keys.
+- :func:`decode_attention` / :func:`ring_decode_attention`: one query a
+  row, XLA einsums over the gathered view of FLAT rows (``Hk * 192`` and
+  ``Hk * 128`` wide, as the pool's pages hold them) / over the ring,
+  under :data:`GLOBAL_DECODE_SCOPE` / :data:`WINDOW_DECODE_SCOPE`.
+
+**The ring.** A window layer's cache is ``(B, R, Hk, D)`` with ``R =
+min(max_len, window)``: row ``p mod R`` holds position ``p``. Which row
+holds what follows from the row's index alone: before position ``n`` is
+written, row ``r`` holds ``(n - 1) - ((n - 1 - r) mod R)``, live when that
+is ``>= 0``. A slot's last tenant's rows are masked by position, never
+cleared. :func:`ring_write` writes the last ``R`` REAL positions of a
+stretch (``valid`` of its ``L`` are real; padding writes nothing).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from llm_in_practise_tpu.ops.attention import interpret_default
+from llm_in_practise_tpu.ops.mla_attention import NEG_INF, join
+
+WINDOW_DECODE_SCOPE = "window_decode_attention"
+GLOBAL_DECODE_SCOPE = "global_decode_attention"
+# the kernels' names on the device plane
+WINDOW_KERNEL = "window_prefill_flash"
+GLOBAL_KERNEL = "global_prefill_flash"
+# tiles (tools/swa_bakeoff.py): a global layer's chunk against a view, a
+# window layer's chunk against its own keys
+GLOBAL_BLOCKS = (1024, 1024)
+WINDOW_BLOCKS = (256, 256)
+_LANE, _SUBLANE = 128, 8
+
+
+def band_blocks(block_q: int, block_k: int, window: int) -> int:
+    """Key blocks the band of one query block can touch, whatever the
+    alignment of the two starts: its ``block_q + window - 1`` key
+    positions begin anywhere inside a block."""
+    return (block_q + window - 2) // block_k + 2
+
+
+def _flash_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                  acc_ref, m_ref, l_ref, *, scale, block_q, block_k,
+                  window, n_key_blocks):
+    """Grid (batch, heads or K/V heads, q blocks, key steps), key steps
+    innermost; acc / m / l persist over them. A q tile holds ``block_q``
+    positions of one query head, or of ALL the query heads of one K/V
+    head one after another (``fold`` of them: ``fold * block_q`` rows, the
+    K/V block fetched once for the group). Query row ``r`` of q block
+    ``qi`` is at ``qs[b] + qi * block_q + r % block_q``; key step ``ki``
+    reads key block
+    ``first + ki`` (``first`` = the band's first live block, 0 without a
+    band), whose column ``c`` is at ``ks[b] + (first + ki) * block_k +
+    c``."""
+    b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    q0 = qs_ref[b] + qi * block_q
+    first = 0
+    if window is not None:
+        first = jnp.clip((q0 - (window - 1) - ks_ref[b]) // block_k, 0,
+                         n_key_blocks - 1)
+    kb = first + ki
+    k0 = ks_ref[b] + kb * block_k
+
+    @pl.when(ki == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when((k0 <= q0 + block_q - 1) & (kb < n_key_blocks))
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        tile = q_ref.shape[0]
+        # how far each key lies behind its query: the tile's own part is
+        # the same every step, the blocks' starts a scalar
+        behind = (q0 - k0) + (
+            jax.lax.broadcasted_iota(jnp.int32, (tile, block_k), 0) % block_q
+            - jax.lax.broadcasted_iota(jnp.int32, (tile, block_k), 1))
+        live = behind >= 0
+        if window is not None:
+            live &= behind < window
+        s = jnp.where(live, s, NEG_INF)
+        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # a row whose keys so far are all masked keeps m = NEG_INF: its
+        # masked scores must weigh exp(-huge) = 0, not exp(0)
+        p = jnp.exp(s - jnp.maximum(m_new, 0.1 * NEG_INF))
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:, 0:1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[:, 0:1] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == n_steps - 1)
+    def _():
+        # a row that saw no key of this call keeps m = NEG_INF and l = 0:
+        # its output is 0 and its lse ~ -1e30, which the join ignores
+        l = jnp.maximum(l_ref[:, 0:1], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse = (m_ref[:, 0:1] + jnp.log(l))[:, 0]
+        lse_ref[...] = jnp.broadcast_to(lse[None, :],
+                                        (_SUBLANE, lse.shape[0]))
+
+
+def flash_partial(q, k, v, q_start, k_start, *, scale: float,
+                  window: int | None = None, block_q: int | None = None,
+                  block_k: int | None = None, fold: bool | None = None,
+                  interpret: bool | None = None):
+    """Attention of ``q`` (B, H, Lq, Dq) over ONE stretch of keys ``k``
+    (B, Hk, Lk, Dq) / values ``v`` (B, Hk, Lk, Dv), ``H`` a multiple of
+    ``Hk``; ``q_start`` / ``k_start`` (B,) are the absolute positions of
+    the first query and the first key, neither negative. Returns the
+    stretch's own softmax-normalised output (B, H, Lq, Dv) and its
+    log-sum-exp (B, H, Lq) float32, for :func:`join`.
+
+    ``fold`` (default: under a band): a q tile holds ``block_q`` positions
+    of every query head of one K/V head, so a grid step multiplies
+    ``group * block_q`` rows by one K/V block. A band's live work a
+    (head, q block) is a few small tiles and the grid's steps are what it
+    costs: folding makes them ``group`` times fewer
+    (tools/swa_bakeoff.py)."""
+    b, h, lq, dq = q.shape
+    hk, lk, dv = k.shape[1], k.shape[2], v.shape[-1]
+    group = h // hk
+    fold = (window is not None) if fold is None else fold
+    tiles = GLOBAL_BLOCKS if window is None else WINDOW_BLOCKS
+    block_q = min(block_q or tiles[0], lq)
+    block_k = min(block_k or tiles[1], lk)
+    if lq % block_q or lk % block_k:
+        raise ValueError(f"lengths ({lq}, {lk}) must be multiples of the "
+                         f"tiles ({block_q}, {block_k})")
+    n_q, n_k = lq // block_q, lk // block_k
+    n_steps = n_k if window is None else min(
+        n_k, band_blocks(block_q, block_k, window))
+    per = group if fold else 1      # query heads a q tile holds
+    tile = per * block_q
+    if fold:
+        # (B, Hk, group, n_q, block_q, D) -> tiles of (group, block_q)
+        q = q.reshape(b, hk, group, n_q, block_q, dq).transpose(
+            0, 1, 3, 2, 4, 5).reshape(b, hk, n_q * tile, dq)
+
+    def kv_map(bi, hi, i, j, qs, ks):
+        # key blocks past the last one this q block can see are never
+        # computed: name the last live block again, so they are not
+        # fetched either; under a band the steps start at its first
+        # live block
+        q0 = qs[bi] + i * block_q
+        last = jnp.clip((q0 + block_q - 1 - ks[bi]) // block_k, 0, n_k - 1)
+        first = 0
+        if window is not None:
+            first = jnp.clip((q0 - (window - 1) - ks[bi]) // block_k, 0,
+                             n_k - 1)
+        return (bi, hi if fold else hi // group,
+                jnp.minimum(first + j, last), 0)
+
+    out, lse = pl.pallas_call(
+        functools.partial(_flash_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, window=window, n_key_blocks=n_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, h // per, n_q, n_steps),
+            in_specs=[
+                pl.BlockSpec((None, None, tile, dq),
+                             lambda bi, hi, i, j, qs, ks: (bi, hi, i, 0)),
+                pl.BlockSpec((None, None, block_k, dq), kv_map),
+                pl.BlockSpec((None, None, block_k, dv), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, tile, dv),
+                             lambda bi, hi, i, j, qs, ks: (bi, hi, i, 0)),
+                pl.BlockSpec((None, None, _SUBLANE, tile),
+                             lambda bi, hi, i, j, qs, ks: (bi, hi, 0, i)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((tile, dv), jnp.float32),
+                pltpu.VMEM((tile, _LANE), jnp.float32),
+                pltpu.VMEM((tile, _LANE), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h // per, n_q * tile, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, h // per, _SUBLANE, n_q * tile),
+                                 jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret_default() if interpret is None else interpret,
+        name=GLOBAL_KERNEL if window is None else WINDOW_KERNEL,
+    )(jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (b,)),
+      jnp.broadcast_to(jnp.asarray(k_start, jnp.int32), (b,)), q, k, v)
+    lse = lse[:, :, 0, :]
+    if fold:
+        out = out.reshape(b, hk, n_q, group, block_q, dv).transpose(
+            0, 1, 3, 2, 4, 5).reshape(b, h, lq, dv)
+        lse = lse.reshape(b, hk, n_q, group, block_q).transpose(
+            0, 1, 3, 2, 4).reshape(b, h, lq)
+    return out, lse
+
+
+def key_blocks_visited(q_start: int, k_start: int, lq: int, lk: int, *,
+                       window: int | None, block_q: int,
+                       block_k: int) -> int:
+    """Key blocks :func:`flash_partial` computes for one (batch, head):
+    the host's count of the kernel's ``pl.when``, for tests and the
+    bake-off."""
+    n_k = lk // block_k
+    visited = 0
+    for i in range(lq // block_q):
+        q0 = q_start + i * block_q
+        first, steps = 0, n_k
+        if window is not None:
+            first = min(max((q0 - (window - 1) - k_start) // block_k, 0),
+                        n_k - 1)
+            steps = min(n_k, band_blocks(block_q, block_k, window))
+        for j in range(steps):
+            kb = first + j
+            if kb < n_k and k_start + kb * block_k <= q0 + block_q - 1:
+                visited += 1
+    return visited
+
+
+def _flash_padded(q, k, v, q_start, k_start, *, scale, window):
+    """:func:`flash_partial` for (B, L, H, D) operands of any length:
+    heads first, lengths padded to whole tiles (a padded key lies past
+    every real query; a padded query's row is dropped)."""
+    lq, lk = q.shape[1], k.shape[1]
+    tiles = GLOBAL_BLOCKS if window is None else WINDOW_BLOCKS
+
+    def whole(n, tile):
+        return -(-n // tile) * tile if n > tile else -(-n // 8) * 8
+
+    pq, pk = whole(lq, tiles[0]) - lq, whole(lk, tiles[1]) - lk
+    pad = lambda a, n: jnp.pad(      # noqa: E731
+        a, ((0, 0), (0, n), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    out, lse = flash_partial(pad(q, pq), pad(k, pk), pad(v, pk), q_start,
+                             k_start, scale=scale, window=window)
+    return out[:, :, :lq].astype(jnp.float32), lse[:, :, :lq]
+
+
+def _sink_join(out, lse, sink):
+    """The sink takes mass and adds no value. ``out`` (B, H, L, Dv)
+    float32, ``lse`` (B, H, L), ``sink`` (H,) or None."""
+    if sink is None:
+        return out
+    b_h = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None],
+                           lse.shape)
+    return join(out, lse, jnp.zeros_like(out), b_h)[0]
+
+
+def prefill_attention(q, k, v, q_start, *, scale: float,
+                      window: int | None = None, sink=None,
+                      cached=None):
+    """A stretch of queries ``q`` (B, L, H, Dq) at positions ``q_start``
+    (scalar or (B,)) ``+ 0 .. L - 1``. Returns (B, L, H, Dv).
+
+    Global layer (``window`` None): ``k`` / ``v`` (B, W, Hk, ·) are the
+    WHOLE view from position 0, the stretch's own rows already written
+    into it. Window layer: ``k`` / ``v`` (B, L, Hk, ·) are the stretch's
+    own, and ``cached`` = ``(ring_k, ring_v)`` the layer's ring as it was
+    before the stretch (None: the stretch starts the sequence)."""
+    b, l = q.shape[:2]
+    start = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (b,))
+    if window is None:
+        out, lse = _flash_padded(q, k, v, start, 0, scale=scale,
+                                 window=None)
+        return _sink_join(out, lse, sink).astype(q.dtype).transpose(
+            0, 2, 1, 3)
+    out, lse = _flash_padded(q, k, v, start, start, scale=scale,
+                             window=window)
+    if cached is not None:
+        # only the first window - 1 queries reach back into the ring
+        n = min(l, window)
+        o_r, lse_r = _ring_corner(q[:, :n], *cached, start, scale=scale,
+                                  window=window)
+        head, lse_head = join(out[:, :, :n], lse[:, :, :n], o_r, lse_r)
+        out = jnp.concatenate([head, out[:, :, n:]], axis=2)
+        lse = jnp.concatenate([lse_head, lse[:, :, n:]], axis=2)
+    return _sink_join(out, lse, sink).astype(q.dtype).transpose(0, 2, 1, 3)
+
+
+def ring_positions(n, rows: int):
+    """Position each of a ring's ``rows`` rows holds before position
+    ``n`` (B,) is written: (B, rows), negative where the row holds
+    nothing of this sequence."""
+    last = n[:, None] - 1
+    return last - (last - jnp.arange(rows)[None, :]) % rows
+
+
+def _ring_corner(q, ring_k, ring_v, start, *, scale, window):
+    """The first queries of a stretch (B, n, H, Dq) over the ring as it
+    was before the stretch: normalised partial output (B, H, n, Dv)
+    float32 and log-sum-exp (B, H, n)."""
+    b, n, h, _ = q.shape
+    hk = ring_k.shape[2]
+    pos = ring_positions(start, ring_k.shape[1])            # (B, R)
+    qpos = start[:, None] + jnp.arange(n)[None, :]          # (B, n)
+    live = (pos[:, None, :] >= 0) & (
+        qpos[:, :, None] - pos[:, None, :] < window)        # (B, n, R)
+    qg = q.reshape(b, n, hk, h // hk, -1)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, ring_k.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(live[:, None, None], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(live[:, None, None], jnp.exp(s - m), 0.0)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bgrqk,bkgd->bgrqd",
+                   (p / jnp.maximum(total, 1e-30)).astype(ring_v.dtype),
+                   ring_v, preferred_element_type=jnp.float32)
+    lse = (m + jnp.log(jnp.maximum(total, 1e-30)))[..., 0]
+    return (o.reshape(b, h, n, -1), lse.reshape(b, h, n))
+
+
+def ring_write(ring, new, start, valid):
+    """``ring`` (B, R, ...) after a stretch ``new`` (B, L, ...) at
+    positions ``start + 0 .. L - 1`` of which the first ``valid`` (B,)
+    are real: every row takes the last real position it stands for, if
+    the stretch holds one."""
+    rows, l = ring.shape[1], new.shape[1]
+    new = new.astype(ring.dtype)
+    if l == 1:
+        # one position: the row it lands on, kept as it was where the
+        # position is not real
+        at = start % rows
+        old = jax.vmap(lambda r, i: jax.lax.dynamic_index_in_dim(
+            r, i, 0, keepdims=True))(ring, at)
+        keep = (valid > 0).reshape((-1,) + (1,) * (new.ndim - 1))
+        return jax.vmap(lambda r, n, i: jax.lax.dynamic_update_slice_in_dim(
+            r, n, i, 0))(ring, jnp.where(keep, new, old), at)
+    pos = ring_positions(start + valid, rows)               # (B, R)
+    take = pos >= start[:, None]
+    src = jnp.clip(pos - start[:, None], 0, l - 1)
+    idx = src.reshape(src.shape + (1,) * (new.ndim - 2))
+    picked = jnp.take_along_axis(new, idx, axis=1)
+    return jnp.where(take.reshape(idx.shape), picked, ring)
+
+
+def ring_decode_attention(q, ring_k, ring_v, index, *, scale: float,
+                          window: int, sink=None):
+    """One query a row (B, 1, H, Dq) at position ``index`` (B,) over a
+    ring that already holds it. Returns (B, 1, H, Dv)."""
+    b, _, h, _ = q.shape
+    hk = ring_k.shape[2]
+    with jax.named_scope(WINDOW_DECODE_SCOPE):
+        pos = ring_positions(index + 1, ring_k.shape[1])    # (B, R)
+        live = (pos >= 0) & (index[:, None] - pos < window)
+        qg = q[:, 0].reshape(b, hk, h // hk, -1)
+        s = jnp.einsum("bgrd,bkgd->bgrk", qg, ring_k.astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live[:, None, None, :], s, NEG_INF)
+        out = _softmax_sum(s, ring_v, sink, hk)
+    return out.reshape(b, 1, h, -1).astype(q.dtype)
+
+
+def decode_attention(q, k, v, index, *, scale: float):
+    """One query a row (B, 1, H, Dq) at position ``index`` (B,) over a
+    view of FLAT rows ``k`` (B, W, Hk * Dq) / ``v`` (B, W, Hk * Dv) that
+    already holds it: the keys at or before it. Returns (B, 1, H, Dv).
+
+    The view stays as the pool's pages gave it. Splitting its rows into
+    ``(Hk, 192)`` would re-lay the whole view out (192 is 1.5 lane tiles:
+    the chip pads such a minor pair to (8, 256), 2.7 times the bytes), so
+    the heads are split on the QUERY's side instead: head ``h`` of group
+    ``g`` takes a ``Hk * Dq``-wide query that is zero outside the group's
+    columns, and reads its group's columns of the ``Hk * Dv``-wide sum.
+    ``Hk`` times the multiply-adds of a path that is bound by reading the
+    view once for the scores and once for the sum."""
+    b, _, h, dq = q.shape
+    hk = k.shape[-1] // dq
+    dv = v.shape[-1] // hk
+    with jax.named_scope(GLOBAL_DECODE_SCOPE):
+        own = jnp.eye(hk, dtype=q.dtype)        # (group, group's columns)
+        qg = q[:, 0].reshape(b, hk, h // hk, 1, dq)
+        q_wide = (qg * own[None, :, None, :, None]).reshape(b, h, hk * dq)
+        s = jnp.einsum("bhc,bkc->bhk", q_wide, k.astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        live = jnp.arange(k.shape[1])[None, :] <= index[:, None]
+        s = jnp.where(live[:, None, :], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        wide = jnp.einsum("bhk,bkc->bhc", p, v).reshape(
+            b, hk, h // hk, hk, dv)
+        out = jnp.sum(wide * own[None, :, None, :, None], axis=3)
+    return out.reshape(b, 1, h, dv).astype(q.dtype)
+
+
+def _softmax_sum(s, v, sink, hk):
+    """``softmax(s) v`` for scores (B, Hk, G, K) with the sink's logit in
+    the denominator."""
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        b_h = sink.astype(jnp.float32).reshape(1, hk, -1, 1)
+        m = jnp.maximum(m, b_h)
+    p = jnp.exp(s - m)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        total = total + jnp.exp(b_h - m)
+    p = (p / total).astype(v.dtype)
+    return jnp.einsum("bgrk,bkgd->bgrd", p, v)

@@ -795,23 +795,42 @@ class OpenAIServer:
                                  lambda a=attr: getattr(load, a), doc)
         stats = getattr(eng, "step_stats", None)
         if stats is not None:
-            reg.counter_func(
-                "llm_latent_tokens_attended_total",
-                lambda: stats.latent_tokens_attended,
-                "cache rows the decode steps' attention needed (each "
-                "active row's true length)")
-            reg.counter_func(
-                "llm_latent_view_tokens_total",
-                lambda: stats.latent_view_tokens,
-                "cache rows those steps' gathered views held (slots x "
-                "pow2 width): attended / view is the share read for "
-                "something")
+            # one attended / view pair, under the family name that fits
+            # the model's cache: llm_latent_* or, for a model whose window
+            # layers are held by slot beside its paged global layers,
+            # llm_global_* and the rings' rows (serve/step_stats.py)
+            families = [
+                (stats.attended_key,
+                 "cache rows the decode steps' attention over the paged "
+                 "layers needed (each active row's true length)"),
+                (stats.view_key,
+                 "cache rows those steps' gathered views held (slots x "
+                 "pow2 width): attended / view is the share read for "
+                 "something")]
+            if stats.ring_rows:
+                families.append((
+                    "window_rows_attended",
+                    "ring rows the decode steps' window layers attended "
+                    "(each active row's min(length, window))"))
+            for key, doc in families:
+                reg.counter_func(f"llm_{key}_total",
+                                 lambda a=key: getattr(stats, a), doc)
         if getattr(eng, "paged", None) is not None:
             reg.gauge_func(
                 "llm_kv_row_bytes", lambda: eng.paged.row_bytes,
                 "pool bytes one token position holds over all layers, as "
                 "STORED (k and v heads; or one latent row a layer, padded "
                 "to whole lane tiles where the pool is stored by pages)")
+            reg.gauge_func(
+                "llm_kv_global_pool_bytes", lambda: eng.paged.pool_bytes,
+                "bytes of the page pools: the layers whose cache grows "
+                "with the context (ledger account kv_pool.pages)")
+            reg.gauge_func(
+                "llm_kv_window_state_bytes",
+                lambda: eng.paged.slot_state_bytes,
+                "bytes of the layers held by slot (a sliding-window "
+                "layer's ring: bounded whatever the context; ledger "
+                "account kv.window_state); 0 for a model without them")
             reg.counter_func(
                 "llm_kv_view_pages_gathered_total",
                 lambda: eng.view_pages_gathered,

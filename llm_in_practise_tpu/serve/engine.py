@@ -49,6 +49,7 @@ from llm_in_practise_tpu.infer.sampling import (
     sampler_tier_name,
 )
 from llm_in_practise_tpu.models.layers import (
+    VALID_KEY,
     head_logits,
     last_position_hidden,
     last_position_logits,
@@ -578,6 +579,13 @@ class InferenceEngine:
             if mesh is not None:
                 self.cache = jax.device_put(self.cache,
                                             self._cache_shardings())
+        # layers whose cache state is bounded (a sliding-window layer's
+        # ring) live by slot beside the pool; only a program that tells
+        # them which of its positions are real may write them, so every
+        # prompt of such a model prefills through the paged chunk program
+        # (one-shot admission's bucket-wide rows carry padding)
+        self._slot_state = (self.paged is not None
+                            and any(self.paged.by_slot))
         self.preemptions = 0            # paged pool-pressure preemptions
         self.rejected_too_large = 0     # prompts that can NEVER fit the pool
         self._paged_admit_blocked = False
@@ -1145,6 +1153,8 @@ class InferenceEngine:
 
         core = stats_model(model)
         self.step_stats = None if core is None else StepStats(self, core)
+        if self._slot_state:
+            StepStats.check_engine(self, "a model with layers held by slot")
         # expert load, whoever books it (/metrics llm_moe_*_total)
         self.routing_load = (self.block.routing if self.block is not None
                              else self.step_stats.load
@@ -1584,12 +1594,16 @@ class InferenceEngine:
     # pages from block-table columns, its writes split the host's flat
     # rows into (page, offset); a flat pool's programs are untouched.
 
-    def _paged_view(self, pool, gidx, index_vec):
+    def _paged_view(self, pool, gidx, index_vec, *, slot=None, valid=None):
         """Gather each slot's pages into a contiguous cache view
         (slots, W, ...) with the per-slot index pinned from the host.
         ``gidx`` is :meth:`PagedKV.view_idx`'s: pool rows (S, W) for a
         flat pool, whole pages (S, W / page_size) for one stored by
-        pages."""
+        pages. A layer held by slot (``paged.by_slot``: its state is
+        bounded) is not gathered: the model gets the plane's rows as they
+        are, or the one row of ``slot`` (1,), and ``valid`` (S,), how
+        many of this call's positions are real for each row (a layer
+        that owns its writes must not take padding or a dead row's)."""
         by_pages = self.paged.form == "pages"
         S, W = gidx.shape
         flat = gidx.reshape(-1)
@@ -1598,8 +1612,17 @@ class InferenceEngine:
         # gets its zeroed entries beside each layer's gathered rows
         extra = ([{}] * len(pool) if self.step_stats is None
                  else self.step_stats.view_entries(S))
-        for layer, more, tails in zip(pool, extra, self.paged.tails):
+        for layer, more, tails, bounded in zip(
+                pool, extra, self.paged.tails, self.paged.by_slot):
             d = {"index": index_vec.astype(jnp.int32), **more}
+            if bounded:
+                d[VALID_KEY] = valid
+                for key, buf in layer.items():
+                    d[key] = (buf if slot is None else
+                              jax.lax.dynamic_slice_in_dim(
+                                  buf, slot[0], 1, axis=0))
+                view.append(d)
+                continue
             for key, buf in layer.items():
                 if by_pages:
                     d[key] = paged_kv.take_pages(buf, gidx, *tails[key])
@@ -1614,19 +1637,38 @@ class InferenceEngine:
             view.append(d)
         return view
 
-    def _paged_writeback(self, pool, view, sidx, wstart):
+    def _live_rows(self, sidx):
+        """Keyword arguments of :meth:`_paged_view` for a slot-plane
+        decode: ``valid`` = 1 for the rows whose write-back lands in
+        their own pages, 0 for those the host routed to the trash page
+        (idle and mid-prefill rows). Nothing for a model without layers
+        held by slot: its programs lower as before."""
+        if not self._slot_state:
+            return {}
+        return {"valid": (sidx[:, 0] >= self.paged.page_size).astype(
+            jnp.int32)}
+
+    def _paged_writeback(self, pool, view, sidx, wstart, *, slot=None):
         """Scatter each row's freshly written window
         ``[wstart[s], wstart[s] + Wwin)`` from the view back into the
-        pool at the host-resolved page rows ``sidx``."""
+        pool at the host-resolved page rows ``sidx``. A layer held by
+        slot wrote its own rows (the model's ring): they go back whole,
+        or into ``slot``'s row."""
         by_pages = self.paged.form == "pages"
         S, Wwin = sidx.shape
         flat = sidx.reshape(-1)
         j = jnp.arange(Wwin)
         new = []
-        for pl, vl in zip(pool, view):
+        for pl, vl, bounded in zip(pool, view, self.paged.by_slot):
             d = {}
             for key, buf in pl.items():
                 vb = vl[key]
+                if bounded:
+                    d[key] = (vb.astype(buf.dtype) if slot is None else
+                              jax.lax.dynamic_update_slice_in_dim(
+                                  buf, vb.astype(buf.dtype), slot[0],
+                                  axis=0))
+                    continue
                 W = vb.shape[1]
                 pos = jnp.clip(wstart[:, None] + j[None, :], 0, W - 1)
                 idx = pos.reshape((S, Wwin) + (1,) * (vb.ndim - 2))
@@ -1649,7 +1691,8 @@ class InferenceEngine:
 
     def _paged_decode_fn(self, params, pool, gidx, index_vec, sidx,
                          tokens, rng, temperature, top_k, top_p, greedy):
-        view = self._paged_view(pool, gidx, index_vec)
+        view = self._paged_view(pool, gidx, index_vec,
+                                **self._live_rows(sidx))
         tok, view = self._decode_fn(params, view, tokens, rng,
                                     temperature, top_k, top_p, greedy)
         return (tok, self._paged_writeback(pool, view, sidx, index_vec),
@@ -1658,7 +1701,8 @@ class InferenceEngine:
     def _paged_multi_fn(self, params, pool, gidx, index_vec, sidx,
                         tokens, rng, temperature, top_k, top_p, greedy,
                         *, n):
-        view = self._paged_view(pool, gidx, index_vec)
+        view = self._paged_view(pool, gidx, index_vec,
+                                **self._live_rows(sidx))
         toks, view = decode_scan(self.model, params, view, tokens, rng,
                                  temperature, top_k, top_p, greedy, n=n)
         return (toks, self._paged_writeback(pool, view, sidx, index_vec),
@@ -1716,7 +1760,8 @@ class InferenceEngine:
             slot, r_gidx, r_ids, r_starts, r_lens, r_sidx = (
                 jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)
                 for a in (slots, gidx, chunk_ids, starts, lens, sidx))
-            view = self._paged_view(pool, r_gidx, r_starts)
+            view = self._paged_view(pool, r_gidx, r_starts, slot=slot,
+                                    valid=r_lens)
             # the adapter index rides the SLOT plane: this row's entry
             mine = None if lora is None else dict(lora, idx={
                 rb: jnp.take(ix, slot) for rb, ix in lora["idx"].items()})
@@ -1725,7 +1770,8 @@ class InferenceEngine:
                     self.model, params, view, r_ids, r_starts, r_lens)
             if stats is not None:
                 acc = (stats.add_row(acc[0], stats.of_view(view), slot[0]),)
-            return (self._paged_writeback(pool, view, r_sidx, r_starts),
+            return (self._paged_writeback(pool, view, r_sidx, r_starts,
+                                          slot=slot),
                     jax.lax.dynamic_update_slice_in_dim(
                         out, last, slot[0], axis=0), acc)
 
@@ -1781,7 +1827,8 @@ class InferenceEngine:
         first, chunk_last, pool, *acc = self._paged_chunk_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
             n_rows, finish, first_rng, temperature, top_k, top_p, greedy)
-        view = self._paged_view(pool, gidx, index_vec)
+        view = self._paged_view(pool, gidx, index_vec,
+                                **self._live_rows(sidx))
         toks, view = decode_scan(self.model, params, view, tokens, rng,
                                  temperature, top_k, top_p, greedy, n=n,
                                  gmask=gmask)
@@ -1794,7 +1841,8 @@ class InferenceEngine:
         """Paged twin of ``_decode_masked_fn``: gather → masked decode
         body → window scatter, one dispatch (grammar on, paged layout —
         the 1-dispatch-per-step invariant is layout-independent)."""
-        view = self._paged_view(pool, gidx, index_vec)
+        view = self._paged_view(pool, gidx, index_vec,
+                                **self._live_rows(sidx))
         tok, view = self._decode_masked_fn(
             params, view, tokens, rng, temperature, top_k, top_p,
             greedy, gmask)
@@ -1825,14 +1873,19 @@ class InferenceEngine:
     def _paged_write_rows_fn(self, pool, rows, sidx):
         """Scatter B bucket-width row sets (one-shot prefill output, a
         prefix/handoff entry's rows) into pages; ``rows`` may carry an
-        ``index`` key (pool iteration ignores it)."""
+        ``index`` key (pool iteration ignores it). Layers held by slot
+        take no rows (a model that has them prefills through the chunk
+        program and shares no prefix)."""
         by_pages = self.paged.form == "pages"
         S, Wb = sidx.shape
         flat = sidx.reshape(-1)
         new = []
-        for pl, rl in zip(pool, rows):
+        for pl, rl, bounded in zip(pool, rows, self.paged.by_slot):
             d = {}
             for key, buf in pl.items():
+                if bounded:
+                    d[key] = buf
+                    continue
                 rb = rl[key]
                 if by_pages:
                     d[key] = paged_kv.set_page_rows(
@@ -1846,7 +1899,8 @@ class InferenceEngine:
 
     def _paged_gather_rows_fn(self, pool, gidx):
         """Index-free rows list (1, W, ...) per layer — the page-wise
-        twin of ``_slot_rows_fn`` for prefix/handoff entries."""
+        twin of ``_slot_rows_fn`` for prefix/handoff entries (the paged
+        layers' rows only)."""
         if self.paged.form == "pages":
             return [{key: paged_kv.take_page_rows(buf, gidx, *tails[key])
                      for key, buf in layer.items()}
@@ -1854,10 +1908,11 @@ class InferenceEngine:
         S, W = gidx.shape
         flat = gidx.reshape(-1)
         return [
+            {} if bounded else
             {key: jnp.take(buf, flat, axis=0).reshape(
                 (S, W) + buf.shape[1:])
              for key, buf in layer.items()}
-            for layer in pool
+            for layer, bounded in zip(pool, self.paged.by_slot)
         ]
 
     def _paged_page_copy_fn(self, pool, src, dst):
@@ -1868,9 +1923,12 @@ class InferenceEngine:
                      for key, buf in layer.items()} for layer in pool]
         P = self.paged.page_size
         new = []
-        for layer in pool:
+        for layer, bounded in zip(pool, self.paged.by_slot):
             d = {}
             for key, buf in layer.items():
+                if bounded:
+                    d[key] = buf
+                    continue
                 rows = jax.lax.dynamic_slice_in_dim(buf, src * P, P,
                                                     axis=0)
                 d[key] = jax.lax.dynamic_update_slice_in_dim(
@@ -2552,7 +2610,8 @@ class InferenceEngine:
                         "one); serving continues but this replica is no "
                         "longer interference-free — see "
                         "llm_local_prefills_total")
-            if hit is None and not self._should_chunk(0, plen):
+            if (hit is None and not self._should_chunk(0, plen)
+                    and not self._slot_state):
                 self.slot_req[slot] = req   # reserve; activated post-batch
                 self.slot_adapter[slot] = req.adapter
                 self.slot_ready[slot] = False
@@ -3250,6 +3309,9 @@ class InferenceEngine:
             self._pulse_view(W, 1)
             rows = self._paged_chunk_rows(
                 [(slot, done, suffix)], W, C, n_rows=1)
+            if self.step_stats is not None:
+                self.step_stats.note_chunk_rows(
+                    [(slot, {"done": done}, suffix)])
             tail, sampled = self._tail_key([(slot, req)])
         kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):

@@ -46,7 +46,17 @@ the flat form above suits a row with a head axis, ``(heads, dim)``; a row
 that is ONE vector (a latent cache: ``ckv`` of DeepSeek-V3, 576 wide) is
 stored by pages, ``(num_pages, page_size, width rounded up to whole lane
 tiles)``, gathered a page at a time from the block table itself, and
-sliced back to its width once (docs/paged-kv.md, "A latent page row").
+sliced back to its width once (docs/paged-kv.md, "A latent page row"). A
+model whose heads are 1.5 lane tiles wide (192-wide keys) declares its row
+as one vector of heads x dim, whole tiles, and is stored the same way
+("Keys of 192").
+
+Layers that do not grow (:func:`cache_kinds`): a layer whose cache rows do
+not follow ``max_len`` (a sliding-window layer's ring of ``window`` rows)
+has nothing for a page allocator to share out: every live slot needs the
+same bounded state. Its buffers are held BY SLOT, ``(max_slots, rows,
+...)``, beside the pools; no block table names them, and the engine's
+programs hand the model the slot's rows as they are.
 
 Sharing/refcount protocol (one invariant the churn test pins): a
 physical page's refcount equals the number of slot block tables mapping
@@ -79,20 +89,34 @@ def pages_for(n_tokens: int, page_size: int) -> int:
     return -(-int(n_tokens) // int(page_size))
 
 
+def cache_kinds(model, dtype, max_len: int = 1 << 20):
+    """``(template, by_slot)`` of ``model``'s cache at ``max_len``
+    positions: the abstract per-layer buffers (nothing is allocated), and
+    per layer whether its state is BOUNDED: no buffer's row axis is
+    ``max_len`` long. A property of the cache template alone: no flag
+    and no model name."""
+    tpl = jax.eval_shape(lambda: model.init_cache(1, max_len, dtype=dtype))
+    by_slot = [all(buf.shape[1] != max_len
+                   for key, buf in layer.items() if key != "index")
+               for layer in tpl]
+    return tpl, by_slot
+
+
 def kv_row_bytes(model, dtype) -> int:
-    """HBM bytes one KV-cache ROW (one token position, all layers)
-    costs for ``model`` — the exchange rate the engine uses to express
-    a draft model's contiguous cache in page-pool tokens, so a paged
-    engine with a draft can't over-admit against bytes the draft
-    already spent (ISSUE 9 satellite; docs/paged-kv.md)."""
-    probe = 16
-    tpl = model.init_cache(1, probe, dtype=dtype)
+    """HBM bytes one KV-cache ROW (one token position, all layers that
+    grow with the context) costs for ``model`` — the exchange rate the
+    engine uses to express a draft model's contiguous cache in page-pool
+    tokens, so a paged engine with a draft can't over-admit against bytes
+    the draft already spent (ISSUE 9 satellite; docs/paged-kv.md)."""
+    tpl, by_slot = cache_kinds(model, dtype)
     total = 0
-    for layer in tpl:
+    for layer, bounded in zip(tpl, by_slot):
+        if bounded:
+            continue
         for key, buf in layer.items():
             if key == "index":
                 continue
-            total += (buf.size // probe) * buf.dtype.itemsize
+            total += (buf.size // buf.shape[1]) * buf.dtype.itemsize
     return total
 
 
@@ -385,30 +409,53 @@ class PagedKV:
         self.tails = [{key: tuple(buf.shape[2:])    # (1, P, *tail)
                        for key, buf in layer.items() if key != "index"}
                       for layer in tpl]
+        # layers whose state is bounded are held by slot, not by page
+        # (cache_kinds); their buffers' shapes at this cache length
+        full, self.by_slot = cache_kinds(model, dtype, self.cache_len)
+        paged = [t for t, bounded in zip(self.tails, self.by_slot)
+                 if not bounded]
         # the pool's physical form, "rows" | "pages": stored_by_pages
         self.form = "pages" if stored_by_pages(
-            t for layer in self.tails for t in layer.values()) else "rows"
+            t for layer in paged for t in layer.values()) else "rows"
         kv = []
-        for layer, tails in zip(tpl, self.tails):
+        for layer, tails, whole, bounded in zip(tpl, self.tails, full,
+                                                self.by_slot):
             bufs = {}
             for key, tail in tails.items():
-                shape = ((num_pages, self.page_size, lane_whole(tail[0]))
-                         if self.form == "pages" else (pool_rows,) + tail)
+                if bounded:
+                    shape = (self.max_slots,) + tuple(whole[key].shape[1:])
+                elif self.form == "pages":
+                    shape = (num_pages, self.page_size, lane_whole(tail[0]))
+                else:
+                    shape = (pool_rows,) + tail
                 bufs[key] = jnp.zeros(shape, layer[key].dtype)
             kv.append(bufs)
         if mesh is not None:
             kv = jax.device_put(kv, self._pool_shardings(kv, mesh))
         self.kv = kv
-        # ledger account kv_pool.pages: the flat pools are the one real
-        # device allocation here — page/row rates derive from it so
-        # every page-count figure converts to bytes the same way
-        # everywhere (/debug/kv, /debug/hbm, session pins).
-        self.pool_bytes = sum(int(buf.nbytes) for layer in kv
-                              for buf in layer.values())
+        # ledger account kv_pool.pages: the pools are the one real device
+        # allocation that grows with tokens — page/row rates derive from
+        # it so every page-count figure converts to bytes the same way
+        # everywhere (/debug/kv, /debug/hbm, session pins). The layers
+        # held by slot are a second rate, bytes a SLOT, whatever the
+        # context: account kv.window_state.
+        self.pool_bytes = sum(
+            int(buf.nbytes) for layer, bounded in zip(kv, self.by_slot)
+            if not bounded for buf in layer.values())
+        self.slot_state_bytes = sum(
+            int(buf.nbytes) for layer, bounded in zip(kv, self.by_slot)
+            if bounded for buf in layer.values())
+        self.slot_bytes = self.slot_state_bytes // self.max_slots
+        # rows of the widest ring (0: every layer grows with the context)
+        self.ring_rows = max(
+            (buf.shape[1] for layer, bounded in zip(kv, self.by_slot)
+             if bounded for buf in layer.values()), default=0)
         self.row_bytes = self.pool_bytes // pool_rows if pool_rows else 0
         self.page_bytes = self.row_bytes * self.page_size
         self._ledger_open = True
         get_ledger().book("kv_pool.pages", self.pool_bytes)
+        if self.slot_state_bytes:
+            get_ledger().book("kv.window_state", self.slot_state_bytes)
 
     def close(self) -> None:
         """Release the pool's ledger claim (engine stop). Idempotent —
@@ -416,12 +463,15 @@ class PagedKV:
         if self._ledger_open:
             self._ledger_open = False
             get_ledger().book("kv_pool.pages", -self.pool_bytes)
+            if self.slot_state_bytes:
+                get_ledger().book("kv.window_state", -self.slot_state_bytes)
 
     def view_bytes(self, width: int, n_slots: int | None = None) -> int:
         """Device bytes of one transient gather view: ``n_slots`` rows
         of ``width`` tokens at the pool's per-row rate — what a paged
         dispatch materializes NEXT TO the pool (the coexistence bytes
-        ROADMAP item 1 reclaims)."""
+        ROADMAP item 1 reclaims). Layers held by slot have no view: the
+        programs read their rows where they live."""
         s = self.max_slots if n_slots is None else int(n_slots)
         return int(width) * s * self.row_bytes
 
@@ -598,9 +648,23 @@ class PagedKV:
                 key: {"form": self.form,
                       "row_bytes": int(buf.nbytes) // (
                           self.pool.num_pages * self.page_size)}
-                for key, buf in self.kv[0].items()},
+                for key, buf in self.kv[self.by_slot.index(False)].items()
+            } if not all(self.by_slot) else {},
             "ledger_account": "kv_pool.pages",
             "page_bytes": self.page_bytes,
             "pool_bytes": self.pool_bytes,
             "slot_mapped_bytes": mapped * self.page_bytes,
+            # the second store: layers whose state is bounded (a window
+            # layer's ring), held by slot whatever the context
+            "slot_state": {
+                "layers": sum(self.by_slot),
+                "paged_layers": self.n_layers - sum(self.by_slot),
+                "ledger_account": "kv.window_state",
+                "slot_bytes": self.slot_bytes,
+                "bytes": self.slot_state_bytes,
+                "buffers": {
+                    key: {"shape": list(buf.shape)} for key, buf in
+                    self.kv[self.by_slot.index(True)].items()
+                } if any(self.by_slot) else {},
+            },
         }

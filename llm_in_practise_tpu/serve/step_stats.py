@@ -15,6 +15,11 @@ the host by their tokens alone, for two kinds of model:
 - a LATENT model (a cache row that is one latent, no ``k`` / ``v``): how
   many cache rows a decode step's attention really needed against how
   many the pow2 view made it read.
+- a model with WINDOW layers held by slot beside its paged global layers
+  (``models/mimo_v2.py``; ``PagedKV.by_slot``): the same two numbers for
+  the global layers (one pair of counters, named ``latent_*`` or
+  ``global_*`` after the model's cache), and only here the ring rows its
+  window layers attended and a chunk's (query, key) pairs under the band.
 
 :class:`RoutingLoad` is the one place expert load is booked; the
 block-diffusion decoder (``serve/block_step.py``) books its passes into
@@ -85,10 +90,23 @@ class StepStats:
         tpl = core.step_stats(1)
         self.routed = [i for i, d in enumerate(tpl) if d]
         self.load = RoutingLoad(core.config.held[1])
-        self.latent_tokens_attended = 0
-        self.latent_view_tokens = 0
-        self.prefill_qk_pairs = 0
-        self.prefill_keys_read = 0
+        # rows of a window layer's ring (0: no layer is held by slot)
+        self.ring_rows = engine.paged.ring_rows
+        # one attended / view pair and one count of causal pairs, under
+        # the family name that fits the model's cache: counter, step-record
+        # key and ``/metrics`` family (``llm_<name>_total``) are one name
+        family = "global" if self.ring_rows else "latent"
+        self.attended_key = f"{family}_tokens_attended"
+        self.view_key = f"{family}_view_tokens"
+        self.pairs_key = ("prefill_global_pairs" if self.ring_rows
+                          else "prefill_qk_pairs")
+        for key in (self.attended_key, self.view_key, self.pairs_key,
+                    "prefill_keys_read"):
+            setattr(self, key, 0)
+        if self.ring_rows:      # what only a window layer has
+            self.window_rows_attended = 0
+            self.prefill_band_pairs = 0
+            self.prefill_band_keys_read = 0
         # reference comparisons (tests, the benchmark's check) set this
         # to a list: every booked program then appends {"kind", "uids":
         # {slot: request uid} at the dispatch, "route": per part (routed
@@ -99,16 +117,17 @@ class StepStats:
         self._pending: list[tuple] = []
 
     @staticmethod
-    def check_engine(engine) -> None:
-        """Build-time refusals: what a latent cache and a held share of
-        routed experts cannot meet yet, each by name."""
+    def check_engine(engine, who: str = "latent / routed model") -> None:
+        """Build-time refusals: what a latent cache, a held share of
+        routed experts, or (``who`` says which) layers held by slot
+        cannot meet yet, each by name."""
         def no(what: str, why: str):
-            raise ValueError(f"latent / routed model: {what} is not "
-                             f"supported — {why}")
+            raise ValueError(f"{who}: {what} is not supported — {why}")
 
         if engine.paged is None:
             no("kv_layout='contiguous'",
-               "the step statistics ride the paged programs' view; use "
+               "the step statistics ride the paged programs' view, and a "
+               "layer held by slot exists beside a page pool only; use "
                "kv_layout='paged'")
         if engine.mesh is not None:
             no("a device mesh (tensor parallelism)",
@@ -127,6 +146,11 @@ class StepStats:
         if engine.role != "both" or engine.handoff is not None:
             no("disaggregated prefill/decode",
                "a handed-off entry is k / v rows")
+        if any(engine.paged.by_slot) and engine.prefix_cache is not None:
+            no("the prefix cache (shared pages, copy-on-write forks)",
+               "a layer held by slot keeps only its last rows: a request "
+               "that maps another's prefix pages would have no window "
+               "state at the end of that prefix")
 
     # --- inside the jitted programs ------------------------------------------
 
@@ -153,30 +177,52 @@ class StepStats:
 
     # --- on the host -----------------------------------------------------------
 
+    def _count(self, **counts) -> None:
+        """Add to the lifetime counters and to the step's record."""
+        for key, n in counts.items():
+            setattr(self, key, getattr(self, key) + n)
+        self.eng.steptrace.note_extra(**counts)
+
     def note_decode_view(self, active, n: int, width: int) -> None:
         """A decode (or mixed step's decode half) of ``n`` tokens over
         ``active`` at view width ``width``: the rows its attention needed
-        against the rows of the slot plane's view."""
+        against the rows of the slot plane's view (and, with window
+        layers, the ring rows those layers attended)."""
         eng = self.eng
-        attended = sum(int(eng.slot_len[s]) + n for s in active)
-        view = eng.max_slots * int(width)
-        self.latent_tokens_attended += attended
-        self.latent_view_tokens += view
-        eng.steptrace.note_extra(latent_tokens_attended=attended,
-                                 view_tokens=view)
+        lens = [int(eng.slot_len[s]) + n for s in active]
+        counts = {self.attended_key: sum(lens),
+                  self.view_key: eng.max_slots * int(width)}
+        if self.ring_rows:
+            counts["window_rows_attended"] = sum(
+                min(length, self.ring_rows) for length in lens)
+        self._count(**counts)
 
     def note_chunk_rows(self, entries) -> None:
         """A chunk or mixed dispatch advances ``entries`` ((slot, state,
         chunk) triples): the (query, key) pairs its causal attention
         covers (query ``i`` of a chunk that starts at ``done`` sees
-        ``done + i + 1`` keys) and the cache rows it reads."""
-        pairs = sum(len(c) * st["done"] + len(c) * (len(c) + 1) // 2
-                    for _, st, c in entries)
-        keys = sum(st["done"] + len(c) for _, st, c in entries)
-        self.prefill_qk_pairs += pairs
-        self.prefill_keys_read += keys
-        self.eng.steptrace.note_extra(prefill_qk_pairs=pairs,
-                                      prefill_keys_read=keys)
+        ``done + i + 1`` keys) and the cache rows it reads. With window
+        layers: the causal pairs are the global layers', and a window
+        layer's query sees ``min(done + i + 1, ring rows)``."""
+        counts = {
+            self.pairs_key: sum(
+                len(c) * st["done"] + len(c) * (len(c) + 1) // 2
+                for _, st, c in entries),
+            "prefill_keys_read": sum(st["done"] + len(c)
+                                     for _, st, c in entries)}
+        if self.ring_rows:
+            w = self.ring_rows
+            band = band_keys = 0
+            for _, st, c in entries:
+                # queries whose whole band exists, then the ramp before
+                ramp = min(max(w - 1 - st["done"], 0), len(c))
+                first = st["done"] + 1
+                band += (ramp * (2 * first + ramp - 1) // 2
+                         + (len(c) - ramp) * w)
+                band_keys += len(c) + min(st["done"], w - 1)
+            counts.update(prefill_band_pairs=band,
+                          prefill_band_keys_read=band_keys)
+        self._count(**counts)
 
     def pend(self, kind: str, stats, last=None, finishing=()) -> None:
         """Keep a program's statistics output (device arrays) until the
@@ -187,9 +233,12 @@ class StepStats:
             return      # a program without the output (a masked twin)
         kept = None
         if self.capture is not None:
+            # a prompt admitted through the chunk program holds its slot
+            # only once it is activated: ``finishing`` names it
             kept = (last, [slot for slot, _ in finishing],
-                    {s: r.uid for s, r in enumerate(self.eng.slot_req)
-                     if r is not None})
+                    {**{s: r.uid for s, r in enumerate(self.eng.slot_req)
+                        if r is not None},
+                     **{slot: req.uid for slot, req in finishing}})
         self._pending.append((kind, stats, kept))
 
     def book(self) -> None:

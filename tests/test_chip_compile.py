@@ -205,3 +205,120 @@ def test_latent_page_pool_keeps_its_layout(chip):
     assert not re.findall(rf"= bf16\[{dims}\]\S* (?:copy|transpose)\(", text)
     # the view's gather takes whole pages
     assert f"slice_sizes={{1,{page},{pool[2]}}}" in text
+
+
+@pytest.mark.parametrize("keys", [4096, 32768])
+def test_global_prefill_attention_compiles(chip, keys, monkeypatch):
+    """MiMo-V2.5's global layer: a 2,048-query chunk over a view, 64 heads
+    over 4 K/V heads, keys 192 wide over values 128."""
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    monkeypatch.setattr(swa, "interpret_default", lambda: False)
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    text = _compile(
+        lambda q, k, v, start: swa.prefill_attention(q, k, v, start,
+                                                     scale=0.07),
+        s((1, 2048, 64, 192)), s((1, keys, 4, 192)), s((1, keys, 4, 128)),
+        s((1,), jnp.int32))
+    assert swa.GLOBAL_KERNEL in text
+
+
+def test_window_prefill_attention_compiles(chip, monkeypatch):
+    """Its window layer: the chunk over its own keys under the band (the
+    eight query heads of a K/V head in one q tile), the 128-row ring's
+    corner and the sink."""
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    monkeypatch.setattr(swa, "interpret_default", lambda: False)
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    text = _compile(
+        lambda q, k, v, rk, rv, start, sink: swa.prefill_attention(
+            q, k, v, start, scale=0.07, window=128, sink=sink,
+            cached=(rk, rv)),
+        s((1, 2048, 64, 192)), s((1, 2048, 8, 192)), s((1, 2048, 8, 128)),
+        s((1, 128, 8, 192)), s((1, 128, 8, 128)), s((1,), jnp.int32),
+        s((64,), jnp.float32))
+    assert swa.WINDOW_KERNEL in text
+
+
+def test_global_page_pool_keeps_its_layout(chip):
+    """One global layer of ``mimo-v2.5.agent-context``'s decode program at
+    the cell's pool shapes, through the engine's own accessors: keys of 4
+    x 192 and values of 4 x 128 as ONE vector a row (768 and 512: whole
+    lane tiles), gathered a page at a time, attended flat, the 16-row
+    write-back, the pools donated. Row-major at the entry, no pool-shaped
+    ``copy`` / ``transpose``, whole pages gathered (PERF.md section 6, PR
+    39: a ``(4, 192)`` row is stored token-minor and copied twice)."""
+    import re
+
+    from llm_in_practise_tpu.ops import swa_attention as swa
+    from llm_in_practise_tpu.serve import paged_kv
+
+    slots, width, page, heads, hk = 16, 32768, 16, 64, 4
+    rows = {"k": hk * 192, "v": hk * 128}
+    assert paged_kv.stored_by_pages([(w,) for w in rows.values()])
+    pools = {key: (slots * width // page + 1, page, paged_kv.lane_whole(w))
+             for key, w in rows.items()}
+    assert pools["k"][2] == 768 and pools["v"][2] == 512
+
+    def step(k_buf, v_buf, page_idx, sidx, pos, q, k_new, v_new):
+        views = []
+        for buf, new, w in ((k_buf, k_new, rows["k"]),
+                            (v_buf, v_new, rows["v"])):
+            view = paged_kv.take_pages(buf, page_idx, w)
+            views.append(jax.vmap(
+                lambda v, n, i: jax.lax.dynamic_update_slice(
+                    v, n, (i, 0)))(view, new, pos))
+        out = swa.decode_attention(q, *views, pos, scale=0.07)
+        back = [paged_kv.set_page_rows(
+            buf, sidx, jnp.take_along_axis(
+                view, pos[:, None, None], axis=1)[:, 0])
+            for buf, view in zip((k_buf, v_buf), views)]
+        return out, back
+
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(
+        s(pools["k"]), s(pools["v"]), s((slots, width // page), jnp.int32),
+        s((slots,), jnp.int32), s((slots,), jnp.int32),
+        s((slots, 1, heads, 192)), s((slots, 1, rows["k"])),
+        s((slots, 1, rows["v"]))).compile().as_text()
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text, re.S)
+    for pool in pools.values():
+        dims = ",".join(map(str, pool))
+        layout = re.search(rf"bf16\[{dims}\]\{{([\d,]+)", entry.group(1))
+        assert layout.group(1) == "2,1,0", layout.group(0)
+        assert not re.findall(
+            rf"= bf16\[{dims}\]\S* (?:copy|transpose)\(", text)
+        assert f"slice_sizes={{1,{page},{pool[2]}}}" in text
+
+
+def test_the_older_pools_keep_their_shapes():
+    """What PR 39 added to the storage rule leaves the 8B, SDAR and latent
+    pools as they were: rows of (8, 128) and (4, 128) flat and unpadded, a
+    latent row of 576 by pages of 640."""
+    from llm_in_practise_tpu.models import deepseek_v3 as dsv3
+    from llm_in_practise_tpu.models import qwen3
+    from llm_in_practise_tpu.serve import paged_kv
+
+    for hk in (8, 4):
+        cfg = qwen3.qwen3_config(vocab_size=64, hidden_size=64, n_layer=1,
+                                 n_head=8, n_kv_head=hk, head_dim=128)
+        pg = paged_kv.PagedKV(qwen3.Qwen3(cfg), max_slots=2, cache_len=64,
+                              page_size=16, pool_tokens=128,
+                              dtype=jnp.bfloat16)
+        assert (pg.form, pg.by_slot) == ("rows", [False])
+        assert {k: v.shape for k, v in pg.kv[0].items()} == {
+            "k": (144, hk, 128), "v": (144, hk, 128)}
+        assert pg.row_bytes == 2 * hk * 128 * 2 and pg.slot_state_bytes == 0
+        pg.close()
+    cfg = dsv3.deepseek_v3_config(kv_lora_rank=512, qk_rope_head_dim=64,
+                                  n_layer=2)
+    pg = paged_kv.PagedKV(dsv3.DeepSeekV3(cfg), max_slots=2, cache_len=64,
+                          page_size=16, pool_tokens=128, dtype=jnp.bfloat16)
+    assert (pg.form, pg.by_slot) == ("pages", [False, False])
+    assert pg.kv[0]["ckv"].shape == (9, 16, 640)
+    assert pg.row_bytes == 2 * 640 * 2
+    pg.close()

@@ -108,7 +108,7 @@ def test_step_records_and_counters(served):
     st = served.eng.step_stats
     recs = served.records
     for key, total in (("latent_tokens_attended", st.latent_tokens_attended),
-                       ("view_tokens", st.latent_view_tokens),
+                       ("latent_view_tokens", st.latent_view_tokens),
                        ("moe_assignments_held", st.load.assignments),
                        ("moe_experts_touched", st.load.experts_touched),
                        ("moe_max_expert_load", st.load.max_load),

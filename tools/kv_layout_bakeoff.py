@@ -26,6 +26,19 @@ For the record only, the 8B geometry (16 x 1,024, 36 layers of k and v, 8
 heads of 128): ``kv.rows`` (rows, 8, 128) row gather, what ships, against
 ``kv.pages`` (pages, 16, 8, 128) page gather.
 
+The geometry ``mimo-v2.5.agent-context`` runs (PR 39: 16 slots x 32,768,
+2 global layers of 4 K/V heads, keys 192 over values 128, pool 32,769
+pages of 16), for a row with a head axis whose minor dimension is 1.5 lane
+tiles: ``hyb.rows192`` (rows, 4, 192) / (rows, 4, 128), what ``init_cache``
+gives the parent's code; ``hyb.flat768`` (rows, 768) / (rows, 512),
+reshaped to heads after the gather; ``hyb.pages768`` (pages, 16, 768) /
+(pages, 16, 512), a page at a time; ``hyb.rows256`` (rows, 4, 256) sliced
+to 192 after the gather / (rows, 4, 128) (not built: no model needs it); ``hyb.pages768.flat``, the same pools as
+``hyb.pages768`` attended as they are, FLAT (the heads split on the query's
+side, ``ops/swa_attention.py::decode_attention``): what ships, the model's
+row being one vector; and ``hyb.window_rows192`` (rows, 8, 192) / (rows, 8, 128), a window
+layer held like a global one (ONE layer of it: five, 12.5 GB, do not fit).
+
 Prints one JSON line a form and view: the entry layout the compiler gives
 the pool, the pool-shaped ``copy`` / ``transpose`` instructions in the
 compiled text, the gather's ``slice_sizes``, cost-analysis bytes, and on a
@@ -196,6 +209,104 @@ def kv_program(form: str, width: int):
     return fn, args, shape
 
 
+HYB_PAGES, HYB_DQ, HYB_DV, HYB_Q = S * 32768 // P + 1, 192, 128, 64
+
+
+def hybrid_program(form: str, width: int):
+    """MiMo-V2.5's geometry: key and value pools of a layer kind, a
+    grouped score / sum pair with a (16, 64, 192) query over 192-wide keys
+    and 128-wide values, the 16-row write-back."""
+    hk, layers = (8, 1) if form == "hyb.window_rows192" else (4, 2)
+    rows = HYB_PAGES * P
+    pad = paged_kv.lane_whole(HYB_DQ)
+    shapes = {
+        "hyb.rows192": ((rows, hk, HYB_DQ), (rows, hk, HYB_DV)),
+        "hyb.window_rows192": ((rows, hk, HYB_DQ), (rows, hk, HYB_DV)),
+        "hyb.flat768": ((rows, hk * HYB_DQ), (rows, hk * HYB_DV)),
+        "hyb.pages768": ((HYB_PAGES, P, hk * HYB_DQ),
+                         (HYB_PAGES, P, hk * HYB_DV)),
+        "hyb.pages768.flat": ((HYB_PAGES, P, hk * HYB_DQ),
+                              (HYB_PAGES, P, hk * HYB_DV)),
+        "hyb.rows256": ((rows, hk, pad), (rows, hk, HYB_DV)),
+    }[form]
+    by_pages = form.startswith("hyb.pages768")
+    if form == "hyb.pages768.flat":
+        return _hybrid_flat(shapes, layers, hk, width)
+
+    def fn(pools, idx, flat, pos, q, new_k, new_v):
+        out, acc = [], 0.0
+        for k_buf, v_buf in pools:
+            views = []
+            for buf, new, dim in ((k_buf, new_k, HYB_DQ),
+                                  (v_buf, new_v, HYB_DV)):
+                got = jnp.take(buf, idx.reshape(-1), axis=0, mode="clip")
+                view = got.reshape(S, width, hk, -1)[..., :dim]
+                views.append(jax.vmap(
+                    lambda v, n, i: jax.lax.dynamic_update_slice(
+                        v, n[None], (i, 0, 0)))(view, new, pos))
+            k, v = views
+            s = jnp.einsum("bgrd,bkgd->bgrk",
+                           q.reshape(S, hk, HYB_Q // hk, HYB_DQ), k,
+                           preferred_element_type=jnp.float32)
+            p = jax.nn.softmax(s, axis=-1).astype(k.dtype)
+            acc = acc + jnp.einsum("bgrk,bkgd->bgrd", p, v)
+            pair = []
+            for buf, view in zip((k_buf, v_buf), views):
+                rows_new = _rows_of(view, pos)
+                if buf.shape[-1] == pad and form == "hyb.rows256":
+                    rows_new = jnp.pad(rows_new, ((0, 0), (0, 0),
+                                                  (0, pad - HYB_DQ)))
+                rows_new = rows_new.reshape((S,) + buf.shape[
+                    2 if by_pages else 1:])
+                if by_pages:
+                    page, off = _page_idx(flat)
+                    pair.append(buf.at[page, off].set(rows_new))
+                else:
+                    pair.append(buf.at[flat].set(rows_new))
+            out.append(tuple(pair))
+        return out, acc
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    args = ([tuple(jax.ShapeDtypeStruct(sh, bf) for sh in shapes)] * layers,
+            jax.ShapeDtypeStruct((S, width // P if by_pages else width), i32),
+            jax.ShapeDtypeStruct((S,), i32), jax.ShapeDtypeStruct((S,), i32),
+            jax.ShapeDtypeStruct((S, HYB_Q, HYB_DQ), bf),
+            jax.ShapeDtypeStruct((S, hk, HYB_DQ), bf),
+            jax.ShapeDtypeStruct((S, hk, HYB_DV), bf))
+    return fn, args, shapes[0]
+
+
+def _hybrid_flat(shapes, layers, hk, width):
+    """The shipped path: ``paged_kv.take_pages`` / ``set_page_rows`` and
+    ``swa_attention.decode_attention`` over the flat view."""
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    def fn(pools, idx, flat, pos, q, new_k, new_v):
+        out, acc = [], 0.0
+        for k_buf, v_buf in pools:
+            views = []
+            for buf, new in ((k_buf, new_k), (v_buf, new_v)):
+                view = paged_kv.take_pages(buf, idx, buf.shape[-1])
+                views.append(jax.vmap(
+                    lambda v, n, i: jax.lax.dynamic_update_slice(
+                        v, n.reshape(1, -1), (i, 0)))(view, new, pos))
+            acc = acc + swa.decode_attention(q[:, None], *views, pos,
+                                             scale=0.07)
+            out.append(tuple(
+                paged_kv.set_page_rows(buf, flat, _rows_of(view, pos))
+                for buf, view in zip((k_buf, v_buf), views)))
+        return out, acc
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    args = ([tuple(jax.ShapeDtypeStruct(sh, bf) for sh in shapes)] * layers,
+            jax.ShapeDtypeStruct((S, width // P), i32),
+            jax.ShapeDtypeStruct((S,), i32), jax.ShapeDtypeStruct((S,), i32),
+            jax.ShapeDtypeStruct((S, HYB_Q, HYB_DQ), bf),
+            jax.ShapeDtypeStruct((S, hk, HYB_DQ), bf),
+            jax.ShapeDtypeStruct((S, hk, HYB_DV), bf))
+    return fn, args, shapes[0]
+
+
 def read_text(text: str, shape: tuple) -> dict:
     """What the compiled text says of a pool of ``shape``: its entry
     layout, the ``copy`` / ``transpose`` instructions whose result is
@@ -222,7 +333,7 @@ def timed(compiled, args, width: int, n_pages: int, reps: int = 7) -> float:
     """Median ms of ``reps`` runs on the attached chip, the pools fed
     back (donated) from run to run; indices spread over the whole pool."""
     rng = np.random.default_rng(0)
-    pools, idx, flat, pos, q, new = args
+    pools, idx, flat, pos, q, *new = args
     kind_pages = idx.shape[1] != width
     pages = rng.permutation(n_pages - 1)[:S * width // P].reshape(S, -1) + 1
     rows = (pages[:, :, None] * P + np.arange(P)).reshape(S, width)
@@ -231,7 +342,7 @@ def timed(compiled, args, width: int, n_pages: int, reps: int = 7) -> float:
             jnp.asarray(pages if kind_pages else rows, jnp.int32),
             jnp.asarray(rows[np.arange(S), at], jnp.int32), jnp.asarray(at),
             *(jax.random.normal(jax.random.PRNGKey(i), a.shape, a.dtype)
-              for i, a in enumerate((q, new)))]
+              for i, a in enumerate((q, *new)))]
     out = []
     for _ in range(reps + 1):
         t = time.perf_counter()
@@ -258,7 +369,11 @@ def main() -> int:
     cases = ([(latent_program, f, w, PAGES) for w in (8192, 16384)
               for f in _forms()]
              + [(kv_program, f, 1024, KV_PAGES)
-                for f in ("kv.rows", "kv.pages")])
+                for f in ("kv.rows", "kv.pages")]
+             + [(hybrid_program, f, w, HYB_PAGES) for w in (8192, 32768)
+                for f in ("hyb.rows192", "hyb.flat768", "hyb.pages768",
+                          "hyb.pages768.flat", "hyb.rows256",
+                          "hyb.window_rows192")])
     for program, form, width, n_pages in cases:
         fn, args, shape = program(form, width)
         line = {"form": form, "view": width, "pool": list(shape),
