@@ -25,6 +25,20 @@ on Python" from a hope into a gated, regression-tested quantity:
   their sum: an UPPER bound on device-busy time on the host's clock,
   not a device measurement. ``issue_s`` / ``wait_s`` say how much of it
   the host spent before the device could start.
+- **Two programs in flight** (the engine issues step n+1 before it reads
+  step n, serve/engine.py): windows are kept in issue order and closed
+  oldest first. A record's window lane then TILES: while a dispatch is
+  being prepared the lane reads ``issue:<its phase>``, otherwise
+  ``wait:<phase of the oldest unread program>``; a lane segment ends where
+  the next begins, at the step's end at the latest, and goes on at the next
+  step's begin. ``device_s`` is that lane's length: the part of the step's
+  wall during which at least one issued program was unread (``issue_s`` +
+  ``wait_s`` still), so ``wall_s - device_s`` reads "wall with nothing in
+  flight". Host scopes count only the time outside the lane (what they
+  did under an unread program was hidden by it; their gross spans are in
+  ``segments``). :meth:`window_end` returns a window from the later of
+  its begin and the previous window's end, so the windows booked to a
+  request never overlap either.
 - **One timeline.** Every record carries ``segments``: ``(name, t0,
   t1)`` for each scope and each window part on the ``time.time()`` axis
   of ``start_s`` (one ``perf_counter`` read per edge plus the step's
@@ -36,9 +50,9 @@ on Python" from a hope into a gated, regression-tested quantity:
   profiler's own clock above the device ops.
 - **Scopes nest**: entering an inner scope pauses the enclosing one, so
   ``index_build`` inside ``admit`` is attributed once, not twice.
-  A window closed mid-scope is deducted from the surrounding host
-  activity (the ``dispatch_wait`` leftover is what the scope holds
-  outside the window: cost-model arithmetic, booking).
+  The window lane's time is no scope's (the ``dispatch_wait`` leftover
+  is what the scope holds outside the lane: cost-model arithmetic,
+  booking).
 - **Single-writer**: every mutation happens on the engine thread.
   Scrape threads read :meth:`snapshot` — an atomically swapped dict
   rebuilt once per step — so ``/metrics`` callbacks can never see a
@@ -164,7 +178,8 @@ class StepTrace:
         self._lock = threading.Lock()
         # --- engine-thread state (single writer, no lock) ---
         self._scopes = {name: _Scope(self, name) for name in ACTIVITIES}
-        # [name, last_perf, acc, deduct, enter_perf, annotation]
+        # [name, last reading of the uncovered clock, acc, enter_perf,
+        # annotation]
         self._stack: list[list] = []
         self._step_t0: float | None = None
         self._step_wall0 = 0.0
@@ -182,13 +197,20 @@ class StepTrace:
         self._sampler_tier: str | None = None
         self._first_tokens = {"program": 0, "host": 0}
         self._extra: dict[str, int] = {}
+        self._ahead = False
+        self._drain: str | None = None
+        self._discarded = 0
         self._last_end: float | None = None   # previous step_end, perf
-        # the open dispatch window (``_win_t0`` None: none open); the
-        # annotation is that of the part under way, issue then wait
-        self._win_phase = ""
-        self._win_t0: float | None = None
-        self._win_issued: float | None = None
-        self._win_ann = None
+        # the open dispatch windows, oldest first: [phase, t0, issued]
+        # (issued None: its dispatch is still being prepared), and the
+        # end of the window closed last
+        self._open: deque = deque()
+        self._win_closed = float("-inf")
+        # the window lane's segment under way (``_lane`` None: nothing
+        # in flight, or no step open): its name, begin and annotation
+        self._lane: str | None = None
+        self._lane_t0 = 0.0
+        self._lane_ann = None
         self._seq = 0
         # --- cumulative totals (engine-thread writes; scrapes read the
         # swapped snapshot, never these) ---
@@ -198,6 +220,9 @@ class StepTrace:
         self._device_seconds_total = 0.0
         self._issue_seconds_total = 0.0
         self._sampler_steps: dict[str, int] = {}
+        self._steps_ahead = 0
+        self._step_drains: dict[str, int] = {}
+        self._tokens_discarded = 0
         # rolling fractions over the last `window` steps (cached floats,
         # same convention as DispatchMeter.per_step)
         self._window = window
@@ -217,22 +242,33 @@ class StepTrace:
             return _NOOP_SCOPE
         return self._scopes[name]
 
+    def _uncovered(self, now: float) -> float:
+        """The clock host scopes run on: ``now`` less the window lane's
+        length so far in this step, so that a scope counts only what no
+        unread program covered."""
+        covered = self._device_s
+        if self._lane is not None:
+            covered += now - self._lane_t0
+        return now - covered
+
     def _enter(self, name: str) -> None:
         now = time.perf_counter()
+        u = self._uncovered(now)
         if self._stack:
             top = self._stack[-1]
-            top[2] += now - top[1]
-        self._stack.append([name, now, 0.0, 0.0, now,
+            top[2] += u - top[1]
+        self._stack.append([name, u, 0.0, now,
                             TraceAnnotation("engine:" + name)])
 
     def _exit(self) -> None:
         now = time.perf_counter()
-        name, last, acc, deduct, entered, ann = self._stack.pop()
+        u = self._uncovered(now)
+        name, last, acc, entered, ann = self._stack.pop()
         _close(ann)
-        host = max(0.0, acc + (now - last) - deduct)
+        host = max(0.0, acc + (u - last))
         self._acts[name] = self._acts.get(name, 0.0) + host
         if self._stack:
-            self._stack[-1][1] = now
+            self._stack[-1][1] = u
         # GROSS span (enter → exit): nesting shows as containment, like
         # any flame chart; the net seconds are in ``activities``
         self._segment(name, entered, now)
@@ -241,60 +277,76 @@ class StepTrace:
         if len(self._segments) < _MAX_SEGMENTS_PER_STEP:
             self._segments.append((name, t0, t1))
 
+    def _lane_to(self, name: str | None, now: float) -> None:
+        """End the window lane's segment under way at ``now`` (booked
+        into the open record) and begin ``name``'s, if any."""
+        if self._lane is not None:
+            dt = now - self._lane_t0
+            self._device_s += dt
+            if self._lane.startswith("issue:"):
+                self._issue_s += dt
+            self._segment(self._lane, self._lane_t0, now)
+            if self._lane_ann is not None:
+                _close(self._lane_ann)
+                self._lane_ann = None
+        self._lane = name if self._recording else None
+        if self._lane is not None:
+            self._lane_t0 = now
+            self._lane_ann = TraceAnnotation("engine:" + name)
+
+    def _lane_now(self) -> str | None:
+        """What the window lane reads: the dispatch being prepared,
+        else the oldest unread program, else nothing."""
+        if not self._open:
+            return None
+        if self._open[-1][2] is None:
+            return "issue:" + self._open[-1][0]
+        return "wait:" + self._open[0][0]
+
     def window_begin(self, phase: str) -> None:
         """Open a dispatch window: the first line that prepares the
         dispatch. Always stamps (the engine needs the durations for its
         own books); records and annotates only inside a recorded step."""
-        self._win_phase = phase
-        self._win_ann = (TraceAnnotation("engine:issue:" + phase)
-                         if self._recording else None)
-        self._win_issued = None
-        self._win_t0 = time.perf_counter()
+        now = time.perf_counter()
+        self._open.append([phase, now, None])
+        if self._recording:
+            self._dispatches += 1
+        self._lane_to(self._lane_now(), now)
 
     def window_issued(self) -> None:
-        """The jitted call(s) have returned: everything after is the
-        fetch the code already blocks on. No synchronisation here."""
-        self._win_issued = time.perf_counter()
-        if self._win_ann is not None:
-            _close(self._win_ann)
-            self._win_ann = TraceAnnotation(
-                "engine:wait:" + self._win_phase)
+        """The jitted call(s) of the window opened last have returned:
+        it is in flight until :meth:`window_end` reads it. No
+        synchronisation here."""
+        now = time.perf_counter()
+        self._open[-1][2] = now
+        self._lane_to(self._lane_now(), now)
 
     def window_end(self) -> tuple[float, float]:
-        """Results are on the host. Returns ``(window_s, issue_s)`` and
-        books the window (see :meth:`note_device`)."""
+        """The OLDEST open window's results are on the host. Returns
+        ``(window_s, issue_s)`` from the later of the window's begin and
+        the previous window's end (windows booked to a request tile)."""
         now = time.perf_counter()
-        t0, self._win_t0 = self._win_t0, None
-        issued = now if self._win_issued is None else self._win_issued
-        if self._win_ann is not None:
-            _close(self._win_ann)
-            self._win_ann = None
-        if self._recording:
-            self._book_window(self._win_phase, t0, issued, now)
-        return now - t0, issued - t0
+        _, t0, issued = self._open.popleft()
+        issued = now if issued is None else issued
+        t0 = max(t0, self._win_closed)
+        self._win_closed = now
+        self._lane_to(self._lane_now(), now)
+        return now - t0, max(0.0, issued - t0)
 
     def note_device(self, duration_s: float, phase: str = "dispatch",
                     issue_s: float = 0.0) -> None:
         """Book a dispatch window of ``duration_s`` that ended now (its
-        first ``issue_s`` seconds the issue part) and deduct it from the
-        current host activity: the window is measured inside a host
-        scope, so without the deduction the same wall clock would count
-        twice."""
+        first ``issue_s`` seconds the issue part), outside the lane: the
+        scope it sits in does not count it."""
         if not self._recording:
             return
         now = time.perf_counter()
         t0 = now - float(duration_s)
-        self._book_window(phase, t0, t0 + float(issue_s), now)
-
-    def _book_window(self, phase: str, t0: float, issued: float,
-                     t1: float) -> None:
-        self._device_s += t1 - t0
-        self._issue_s += issued - t0
+        self._device_s += now - t0
+        self._issue_s += float(issue_s)
         self._dispatches += 1
-        if self._stack:
-            self._stack[-1][3] += t1 - t0
-        self._segment("issue:" + phase, t0, issued)
-        self._segment("wait:" + phase, issued, t1)
+        self._segment("issue:" + phase, t0, t0 + float(issue_s))
+        self._segment("wait:" + phase, t0 + float(issue_s), now)
 
     def note_chunk_rows(self, rows: int, row_slots: int) -> None:
         """A chunk dispatch of this step advanced ``rows`` prompts and
@@ -342,6 +394,25 @@ class StepTrace:
         if self._recording:
             self._first_tokens[path] += 1
 
+    def note_ahead(self) -> None:
+        """This step's dispatch was issued while the previous one was
+        unread: the record's ``ahead``."""
+        if self._recording:
+            self._ahead = True
+
+    def note_drain(self, reason: str) -> None:
+        """This step did not run ahead, and why (the engine's name for
+        the state that forbade it; ``idle``: nothing was in flight): the
+        record's ``drain``. The first reason of a step stands."""
+        if self._recording and self._drain is None and not self._ahead:
+            self._drain = reason
+
+    def note_discarded(self, tokens: int) -> None:
+        """Tokens of rows a program ran past their EOS, dropped when it
+        was read: the record's ``tokens_discarded``."""
+        if self._recording:
+            self._discarded += tokens
+
     def step_begin(self, *, lock_wait_s: float = 0.0) -> None:
         """Open a step record. ``lock_wait_s``: what the caller waited
         for the engine's step lock before this call (a record field, not
@@ -361,18 +432,27 @@ class StepTrace:
         self._sampler_tier = None
         self._first_tokens = {"program": 0, "host": 0}
         self._extra = {}
+        self._ahead = False
+        self._drain = None
+        self._discarded = 0
         self._acts = {}
         self._device_s = 0.0
         self._issue_s = 0.0
         self._dispatches = 0
         self._stack = []
         self._segments = []
+        # a program the last step left unread covers this one from its
+        # first instant
+        self._lane_to(self._lane_now(), self._step_t0)
 
     def _close_open(self) -> None:
         """Close what an exception left open, so that neither a scope's
-        time nor an annotation leaks out of the step."""
-        if self._win_t0 is not None:
-            self.window_end()
+        time nor an annotation leaks out of the step: a window whose
+        dispatch never returned is dropped (one in flight stays open:
+        the next step reads it), the lane's segment ends here."""
+        if self._open and self._open[-1][2] is None:
+            self._open.pop()
+        self._lane_to(None, time.perf_counter())
         while self._stack:
             self._exit()
 
@@ -422,6 +502,9 @@ class StepTrace:
             "sampler_tier": self._sampler_tier,
             "first_tokens_program": self._first_tokens["program"],
             "first_tokens_host": self._first_tokens["host"],
+            "ahead": self._ahead,
+            "drain": self._drain,
+            "tokens_discarded": self._discarded,
             **self._extra,
             "activities": dict(self._acts),
             "segments": [(name, t0 + off, t1 + off)
@@ -438,6 +521,11 @@ class StepTrace:
         if self._sampler_tier is not None:
             self._sampler_steps[self._sampler_tier] = (
                 self._sampler_steps.get(self._sampler_tier, 0) + 1)
+        self._steps_ahead += self._ahead
+        if self._drain is not None:
+            self._step_drains[self._drain] = (
+                self._step_drains.get(self._drain, 0) + 1)
+        self._tokens_discarded += self._discarded
         self._busy_roll.append((wall, self._device_s))
         self._step_t0 = None
         self._last_end = end
@@ -465,6 +553,12 @@ class StepTrace:
             "dispatch_wait_seconds_total": dev - self._issue_seconds_total,
             "host_seconds": dict(self._host_seconds),
             "sampler_steps": dict(self._sampler_steps),
+            # one step of lookahead (serve/engine.py): steps whose
+            # dispatch was issued while the previous one was unread,
+            # the others by why not, and tokens of rows run past their EOS
+            "steps_ahead": self._steps_ahead,
+            "step_drains": dict(self._step_drains),
+            "tokens_discarded": self._tokens_discarded,
             # rolling over the last `window` steps — the live dial. A
             # recorder that measured nothing (fresh, idle, or disabled)
             # reports 0 host gap, NOT 1 − busy = 1.0: "the chip waits

@@ -51,7 +51,11 @@ from llm_in_practise_tpu.obs.hbm import (
 from llm_in_practise_tpu.obs.registry import Registry
 from llm_in_practise_tpu.obs.trace import get_tracer, parse_traceparent
 from llm_in_practise_tpu.serve import constrain, schemas
-from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
+from llm_in_practise_tpu.serve.engine import (
+    DRAIN_REASONS,
+    InferenceEngine,
+    SamplingParams,
+)
 from llm_in_practise_tpu.serve.http_util import (
     JsonHandler,
     serve_obs_get,
@@ -983,6 +987,24 @@ class OpenAIServer:
             "the sampler, by the body its live rows' flags chose (argmax: "
             "all greedy; plain: temperature only; filtered: the "
             "full-vocabulary sort for top-k / top-p)")
+        reg.counter_func(
+            "llm_steps_ahead_total",
+            lambda: stp.snapshot()["steps_ahead"],
+            "engine steps whose program was issued while the one before "
+            "it was unread (one step of lookahead: the host's share of a "
+            "step runs while the device computes)")
+        reg.counter_func(
+            "llm_step_drains_total",
+            lambda: [({"reason": r}, stp.snapshot()["step_drains"].get(r, 0))
+                     for r in DRAIN_REASONS],
+            "engine steps that did not run ahead, by why not (idle: "
+            "nothing was in flight; else the state that made the engine "
+            "read the program in flight first)")
+        reg.counter_func(
+            "llm_tokens_discarded_total",
+            lambda: stp.snapshot()["tokens_discarded"],
+            "tokens of rows a program ran past their EOS (issued before "
+            "the EOS was read), dropped when the program was read")
         reg.counter_func(
             "llm_dispatch_issue_seconds_total",
             lambda: stp.snapshot()["dispatch_issue_seconds_total"],

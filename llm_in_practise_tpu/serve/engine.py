@@ -116,6 +116,21 @@ _FINISH = object()  # sentinel closing a request's token queue
 # whatever chunks, so there every mid-prefill row advances.
 CHUNK_TOKENS_PER_STEP = 2048
 
+# Why a step's program was NOT issued while the one before it was unread
+# (InferenceEngine._drain; a step record's ``drain``,
+# ``llm_step_drains_total{reason}``). ``idle``: nothing was in flight and
+# nothing forbade it (the engine had been idle, or the step before read
+# its own program). The rest are states of the engine, observed step by
+# step: the layout or model (``contiguous``, ``block``), a ready row
+# (``grammar``, ``speculative``, ``session``), an admission that
+# dispatches or reads values (``oneshot_prefill``, ``kv_pull``,
+# ``host_first_token``), a step of more than one program
+# (``two_dispatch``), a page reservation that must preempt or finish a
+# row (``preempt``).
+DRAIN_REASONS = ("idle", "contiguous", "block", "grammar", "speculative",
+                 "session", "oneshot_prefill", "kv_pull",
+                 "host_first_token", "two_dispatch", "preempt")
+
 # Per-request critical-path segments (ISSUE 11): every finished
 # request's wall time decomposes into these bins — surfaced per request
 # at GET /debug/requests and aggregated into
@@ -341,6 +356,41 @@ class Request:
         if self.finish_time is None or self.n_generated < 2:
             return None
         return (self.finish_time - self.first_token_time) / (self.n_generated - 1)
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A paged decode / mixed / chunk program between its issue and its
+    retire: the device outputs nobody has read yet, and the host-side
+    plan that produced them (everything here was known at issue)."""
+
+    kind: str                       # "decode" | "mixed" | "chunk"
+    n: int = 0                      # the decode block's length
+    # rows that decode: (slot, request, tokens of the block it takes,
+    # why it ends with the last of them or None)
+    rows: list = dataclasses.field(default_factory=list)
+    toks: Any = None                # (max_slots, n) sampled tokens
+    # rows that chunk: (slot, state, chunk) as dispatched
+    entries: list = dataclasses.field(default_factory=list)
+    # prompts this program ends whose first token it sampled: (slot,
+    # request, state, why the stream ends with that token or None); the
+    # slot is live from issue on
+    finished: list = dataclasses.field(default_factory=list)
+    first: Any = None               # (max_slots,) first tokens by slot
+    # a prompt it ends samples its first token on the host: read at once
+    host_first: bool = False
+    # requests that held a slot at issue (the window is booked to them)
+    holders: list = dataclasses.field(default_factory=list)
+    stats: Any = None               # StepStats' pending entry
+    book: dict = dataclasses.field(default_factory=dict)
+
+    def decodes(self, slot: int) -> bool:
+        return any(row[0] == slot for row in self.rows)
+
+
+# what a planning pass returns after it had to read the program in flight
+# (a page reservation that must preempt): the step plans again
+_REPLAN = object()
 
 
 class EngineStats:
@@ -604,6 +654,45 @@ class InferenceEngine:
         self.chunked_prefill = chunked_prefill
         self.slot_prefill: dict[int, dict] = {}
         self.slot_last_token = np.zeros((max_slots,), np.int32)
+        # One step of lookahead (paged layout;
+        # docs/tutorials/08_serving_internals.md §5c). ``_flight``: the
+        # program issued and not yet read. The last tokens live ON THE
+        # DEVICE: every paged decode / multi /
+        # mixed / chunk program takes the plane ``_tokens_dev`` and
+        # returns it updated, so the next program can be issued before
+        # this one's tokens are on the host; ``slot_last_token`` above is
+        # the host's mirror, filled when a program is read. A token only
+        # the host knows (a one-shot prefill's, a resumed stream's, a
+        # speculative round's) waits in ``_tokens_fix`` (-1: none) and
+        # the next program puts it into the plane.
+        self._flight: _Flight | None = None
+        self._ahead: _Flight | None = None  # issued past the one being read
+        self._tokens_dev = None
+        if self.paged is not None:
+            plane = np.zeros((max_slots,), np.int32)
+            if mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                # the form the programs return it in (one jit-cache
+                # entry a program, whoever built the plane)
+                self._tokens_dev = jax.device_put(
+                    plane, NamedSharding(mesh, PartitionSpec()))
+            else:
+                self._tokens_dev = jnp.asarray(plane)
+        self._tokens_fix = np.full((max_slots,), -1, np.int32)
+        # why a row that took its last deterministic step (budget, cache
+        # room) ends when its program is read: it is left out of every
+        # program issued until then; and the rows whose stream ended in
+        # EOS while a later program that writes their pages was unread:
+        # their slot and pages go when that program is read
+        self.slot_closing: list[str | None] = [None] * max_slots
+        self._zombies: set[int] = set()
+        # why this step does not run ahead (None: nothing forbids it),
+        # and the reason the previous step left for this one
+        self._step_why: str | None = None
+        self._next_why: str | None = None
+        self._flew = False              # this step issued a program
+        self._read = False              # ... found one unread
         self.slot_len = np.zeros((max_slots,), np.int64)
         self.slot_budget = np.zeros((max_slots,), np.int64)  # tokens remaining
         self._temperature = np.ones((max_slots,), np.float32)
@@ -1689,23 +1778,47 @@ class InferenceEngine:
         return (() if self.step_stats is None
                 else (self.step_stats.of_view(view),))
 
+    # The last-token plane. Every program below takes ``tokens``
+    # (max_slots,), the plane as the program before it returned it, and
+    # ``fix`` (max_slots,), the tokens only the host knew (-1: none),
+    # and returns the plane with its own tokens in: a row that decoded
+    # holds the block's last token, a row whose prompt the program ended
+    # its first, every other row what it held. The next program can so be
+    # issued before anyone has read this one's tokens.
+
+    @staticmethod
+    def _plane_in(tokens, fix):
+        return jnp.where(fix >= 0, fix, tokens)
+
+    def _plane_out(self, tokens, new, sidx):
+        """``tokens`` with ``new`` in the rows that decoded: those whose
+        write-back lands in their own pages (the host routes every other
+        row's to the trash page, :meth:`_live_rows`' rule)."""
+        return jnp.where(sidx[:, 0] >= self.paged.page_size,
+                         new.astype(tokens.dtype), tokens)
+
     def _paged_decode_fn(self, params, pool, gidx, index_vec, sidx,
-                         tokens, rng, temperature, top_k, top_p, greedy):
+                         tokens, fix, rng, temperature, top_k, top_p,
+                         greedy):
+        tokens = self._plane_in(tokens, fix)
         view = self._paged_view(pool, gidx, index_vec,
                                 **self._live_rows(sidx))
         tok, view = self._decode_fn(params, view, tokens, rng,
                                     temperature, top_k, top_p, greedy)
-        return (tok, self._paged_writeback(pool, view, sidx, index_vec),
+        return (tok, self._plane_out(tokens, tok, sidx),
+                self._paged_writeback(pool, view, sidx, index_vec),
                 *self._view_stats(view))
 
     def _paged_multi_fn(self, params, pool, gidx, index_vec, sidx,
-                        tokens, rng, temperature, top_k, top_p, greedy,
-                        *, n):
+                        tokens, fix, rng, temperature, top_k, top_p,
+                        greedy, *, n):
+        tokens = self._plane_in(tokens, fix)
         view = self._paged_view(pool, gidx, index_vec,
                                 **self._live_rows(sidx))
         toks, view = decode_scan(self.model, params, view, tokens, rng,
                                  temperature, top_k, top_p, greedy, n=n)
-        return (toks, self._paged_writeback(pool, view, sidx, index_vec),
+        return (toks, self._plane_out(tokens, toks[:, -1], sidx),
+                self._paged_writeback(pool, view, sidx, index_vec),
                 *self._view_stats(view))
 
     def _paged_spec_fn(self, params, pool, gidx, index_vec, sidx, tokens,
@@ -1724,8 +1837,8 @@ class InferenceEngine:
             pool, view, sidx, index_vec)
 
     def _paged_chunk_fn(self, params, pool, slots, gidx, chunk_ids,
-                        starts, lens, sidx, n_rows, finish, rng,
-                        temperature, top_k, top_p, greedy):
+                        starts, lens, sidx, n_rows, ends, rng, tokens,
+                        fix, temperature, top_k, top_p, greedy):
         """Advance the listed mid-prefill ROWS one chunk each against
         the page pool, and end the prompts that finish here in their
         first token: the device work follows the number of rows that
@@ -1745,13 +1858,16 @@ class InferenceEngine:
         PR 28). A trip stops BEFORE the output head: the loop carries
         each row's last-position hidden state by slot, and
         :meth:`_prefill_tail` runs the head once over that plane, and
-        the sampler on its logits, when ``finish`` says some row's
-        prompt ends in this program (``rng`` and the slot plane's
-        sampling arrays are the sampler's, as in the decode programs).
-        Returns ``((max_slots,) first tokens, (max_slots, vocab)
-        last-position logits, pool)``, both by slot, meaningful for the
-        rows that finish, zeros when none does (and, behind the pool, a
-        part of step statistics for a model that counts them)."""
+        the sampler on its logits, when ``ends`` (max_slots,) says some
+        row's prompt ends in this program (1: the host samples its first
+        token from the logits, 2: the program's sample is its first
+        token and goes into the last-token plane; ``rng`` and the slot
+        plane's sampling arrays are the sampler's, as in the decode
+        programs). Returns ``((max_slots,) first tokens, (max_slots,
+        vocab) last-position logits, the last-token plane, pool)``, the
+        first two by slot, meaningful for the rows that finish, zeros
+        when none does (and, behind the pool, a part of step statistics
+        for a model that counts them)."""
         lora = current_lora()
         stats = self.step_stats
 
@@ -1783,8 +1899,10 @@ class InferenceEngine:
         pool, out, acc = jax.lax.fori_loop(0, n_rows, trip,
                                            (pool, out, acc))
         first, last = self._prefill_tail(
-            params, out, finish, rng, temperature, top_k, top_p, greedy)
-        return first, last, pool, *acc
+            params, out, jnp.any(ends > 0), rng, temperature, top_k, top_p,
+            greedy)
+        plane = jnp.where(ends == 2, first, self._plane_in(tokens, fix))
+        return first, last, plane, pool, *acc
 
     def _prefill_tail(self, params, hidden, finish, rng, temperature,
                       top_k, top_p, greedy):
@@ -1812,9 +1930,10 @@ class InferenceEngine:
             hidden)
 
     def _paged_mixed_fn(self, params, pool, slots, pgidx, chunk_ids,
-                        starts, lens, psidx, n_rows, finish, first_rng,
-                        gidx, index_vec, sidx, tokens, rng, temperature,
-                        top_k, top_p, greedy, *, n, gmask=None):
+                        starts, lens, psidx, n_rows, ends, first_rng,
+                        gidx, index_vec, sidx, tokens, fix, rng,
+                        temperature, top_k, top_p, greedy, *, n,
+                        gmask=None):
         """The paged fused mixed step, ONE dispatch: the prefill half
         is :meth:`_paged_chunk_fn`'s loop over the rows that chunk and
         its tail (the slot plane's sampling arrays serve both halves;
@@ -1822,31 +1941,37 @@ class InferenceEngine:
         the decode half is :meth:`_paged_multi_fn`'s body over the slot
         plane (mid-prefill and idle rows decode garbage into the trash
         page). No decode row receives a chunk write. Returns ``(first
-        tokens, last-position logits, (max_slots, n) decode tokens,
-        pool)``."""
-        first, chunk_last, pool, *acc = self._paged_chunk_fn(
+        tokens, last-position logits, (max_slots, n) decode tokens, the
+        last-token plane, pool)``: no row is in both halves, so the
+        plane takes the first tokens and the block's last ones."""
+        first, chunk_last, tokens, pool, *acc = self._paged_chunk_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
-            n_rows, finish, first_rng, temperature, top_k, top_p, greedy)
+            n_rows, ends, first_rng, tokens, fix, temperature, top_k,
+            top_p, greedy)
         view = self._paged_view(pool, gidx, index_vec,
                                 **self._live_rows(sidx))
         toks, view = decode_scan(self.model, params, view, tokens, rng,
                                  temperature, top_k, top_p, greedy, n=n,
                                  gmask=gmask)
-        return (first, chunk_last, toks, self._paged_writeback(
-            pool, view, sidx, index_vec), *acc, *self._view_stats(view))
+        return (first, chunk_last, toks,
+                self._plane_out(tokens, toks[:, -1], sidx),
+                self._paged_writeback(pool, view, sidx, index_vec),
+                *acc, *self._view_stats(view))
 
     def _paged_decode_masked_fn(self, params, pool, gidx, index_vec,
-                                sidx, tokens, rng, temperature, top_k,
-                                top_p, greedy, gmask):
+                                sidx, tokens, fix, rng, temperature,
+                                top_k, top_p, greedy, gmask):
         """Paged twin of ``_decode_masked_fn``: gather → masked decode
         body → window scatter, one dispatch (grammar on, paged layout —
         the 1-dispatch-per-step invariant is layout-independent)."""
+        tokens = self._plane_in(tokens, fix)
         view = self._paged_view(pool, gidx, index_vec,
                                 **self._live_rows(sidx))
         tok, view = self._decode_masked_fn(
             params, view, tokens, rng, temperature, top_k, top_p,
             greedy, gmask)
-        return tok, self._paged_writeback(pool, view, sidx, index_vec)
+        return (tok, self._plane_out(tokens, tok, sidx),
+                self._paged_writeback(pool, view, sidx, index_vec))
 
     def _paged_spec_masked_fn(self, params, pool, gidx, index_vec, sidx,
                               tokens, mask, gmasks, *, m):
@@ -1859,16 +1984,16 @@ class InferenceEngine:
 
     def _paged_mixed_masked_fn(self, params, pool, slots, pgidx,
                                chunk_ids, starts, lens, psidx, n_rows,
-                               finish, first_rng, gidx, index_vec, sidx,
-                               tokens, rng, temperature, top_k, top_p,
-                               greedy, gmask, *, n):
+                               ends, first_rng, gidx, index_vec, sidx,
+                               tokens, fix, rng, temperature, top_k,
+                               top_p, greedy, gmask, *, n):
         """Grammar-masked twin of :meth:`_paged_mixed_fn` (the mask
         applies to the decode half only): a separate program, so
         unconstrained steps never carry the mask."""
         return self._paged_mixed_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
-            n_rows, finish, first_rng, gidx, index_vec, sidx, tokens, rng,
-            temperature, top_k, top_p, greedy, n=n, gmask=gmask)
+            n_rows, ends, first_rng, gidx, index_vec, sidx, tokens, fix,
+            rng, temperature, top_k, top_p, greedy, n=n, gmask=gmask)
 
     def _paged_write_rows_fn(self, pool, rows, sidx):
         """Scatter B bucket-width row sets (one-shot prefill output, a
@@ -1993,6 +2118,8 @@ class InferenceEngine:
                 # pool dry mid-fork: apply preemption pressure until a
                 # page frees, exactly like the reserve loops — a single
                 # victim whose pages are all still shared frees nothing
+                # (a victim is picked with no program unread)
+                self._drain("preempt")
                 victim = self._paged_pick_victim(exclude=slot)
                 if victim is None:
                     raise RuntimeError(
@@ -2042,6 +2169,7 @@ class InferenceEngine:
         self.slot_req[slot] = None
         self.slot_ready[slot] = False
         self.slot_budget[slot] = 0
+        self.slot_closing[slot] = None
         self.slot_hist[slot] = None
         # the adapter pin rides the requeue (req.adapter_ref stays
         # held); only the SLOT's stamp clears
@@ -2127,8 +2255,8 @@ class InferenceEngine:
         return (jnp.asarray(self._paged_view_idx(W)), jnp.asarray(idxv),
                 jnp.asarray(self.paged.scatter_idx(idxv, valid, n)))
 
-    def _paged_decode_dispatch(self, active: list[int], n: int, sub,
-                               gmask=None, lora=None):
+    def _paged_decode_dispatch(self, f: _Flight, active: list[int], n: int,
+                               sub, gmask=None, lora=None) -> None:
         """Issue one paged decode dispatch (single-token via the
         ``_decode_fn`` body at n==1 so the rng use matches the
         contiguous program exactly; an n-step scan block otherwise).
@@ -2136,13 +2264,12 @@ class InferenceEngine:
         (constrained decoding) routes to the masked twin — the planner
         guarantees n == 1 then. ``lora`` (multi-LoRA) routes to the
         adapter twin of whichever program would run; both compose.
-        Returns the sampled tokens, shape (max_slots, n)."""
+        The sampled tokens, shape (max_slots, n), are ``f.toks``."""
         W = self._paged_width(
             max(int(self.slot_len[s]) for s in active) + n)
         self._pulse_view(W)
         gidx, idxv, sidx = self._paged_decode_plan(active, n, W)
-        tokens = jnp.asarray(self.slot_last_token)
-        args = self._sampling_args(active)
+        args = (*self._plane_args(), sub, *self._sampling_args(active))
         kw = {} if lora is None else {"lora": lora}
         if gmask is not None:
             if n != 1:
@@ -2150,24 +2277,31 @@ class InferenceEngine:
                     f"grammar-masked paged decode must be n=1, got {n}")
             fn = (self._pg_decode_masked if lora is None
                   else self._pg_decode_masked_lora)
-            tok, self.paged.kv = fn(
-                self.params, self.paged.kv, gidx, idxv, sidx, tokens,
-                sub, *args, jnp.asarray(gmask), **kw)
-            return tok[:, None]
+            tok, self._tokens_dev, self.paged.kv = fn(
+                self.params, self.paged.kv, gidx, idxv, sidx, *args,
+                jnp.asarray(gmask), **kw)
+            f.toks = tok[:, None]
+            return
         if n == 1:
             fn = self._pg_decode if lora is None else self._pg_decode_lora
-            tok, self.paged.kv, *counted = fn(
-                self.params, self.paged.kv, gidx, idxv, sidx, tokens,
-                sub, *args, **kw)
-            toks = tok[:, None]
+            tok, self._tokens_dev, self.paged.kv, *counted = fn(
+                self.params, self.paged.kv, gidx, idxv, sidx, *args, **kw)
+            f.toks = tok[:, None]
         else:
             fn = self._pg_multi if lora is None else self._pg_multi_lora
-            toks, self.paged.kv, *counted = fn(
-                self.params, self.paged.kv, gidx, idxv, sidx, tokens, sub,
-                *args, n=n, **kw)
+            f.toks, self._tokens_dev, self.paged.kv, *counted = fn(
+                self.params, self.paged.kv, gidx, idxv, sidx, *args, n=n,
+                **kw)
         if self.step_stats is not None:
-            self.step_stats.pend("decode", counted)
-        return toks
+            f.stats = self.step_stats.pend("decode", counted)
+
+    def _plane_args(self) -> tuple:
+        """``(tokens, fix)`` of a paged program: the device's last-token
+        plane and the tokens only the host knows, which this program
+        puts into it (so they are handed over once)."""
+        fix, self._tokens_fix = self._tokens_fix, np.full(
+            (self.max_slots,), -1, np.int32)
+        return self._tokens_dev, jnp.asarray(fix)
 
     def _paged_register_pages(self, token_ids, slot: int,
                               adapter: str | None = None) -> None:
@@ -2464,14 +2598,18 @@ class InferenceEngine:
                 reg.note_tokens(req.adapter, req.n_generated)
         self.finished.append(req)
 
-    def _window_close(self, kind: str, own=()) -> tuple[float, float]:
-        """Close the dispatch window ``steptrace.window_begin`` opened:
-        its results are on the host. Books the window in the step record
-        and to EVERY request holding a slot (the rule above
-        CP_SEGMENTS). ``kind``: ``"prefill"`` — the window advanced the
-        prompts of the ``own`` requests (one-shot, chunk, or the fused
-        mixed step) — or ``"decode"`` — a plain decode window the
-        ``own`` requests rode. Returns ``(window_s, issue_s)``."""
+    def _window_close(self, kind: str, own=(),
+                      holders=None) -> tuple[float, float]:
+        """Close the oldest dispatch window ``steptrace.window_begin``
+        opened: its results are on the host. Books the window in the
+        step record and to EVERY request holding a slot (the rule above
+        CP_SEGMENTS; ``holders``: those that held one when the program
+        was issued, where it was read a step later). ``kind``:
+        ``"prefill"`` — the window advanced the prompts of the ``own``
+        requests (one-shot, chunk, or the fused mixed step) — or
+        ``"decode"`` — a plain decode window the ``own`` requests rode.
+        Returns ``(window_s, issue_s)``, from the previous window's end
+        where the two overlapped: a request's windows tile."""
         dt, issue_s = self.steptrace.window_end()
         mine, other = (("prefill_dispatch", "prefill_stall")
                        if kind == "prefill"
@@ -2480,8 +2618,11 @@ class InferenceEngine:
         for req in own:
             req.cp_window(mine, dt, issue_s)
             booked.add(req.uid)
-        for req in self.slot_req:
-            if req is not None and req.uid not in booked:
+        for req in (self.slot_req if holders is None else holders):
+            # (a holder at issue whose stream has ended since sat
+            # through nothing more)
+            if (req is not None and req.uid not in booked
+                    and req.finish_time is None):
                 req.cp_window(other, dt, issue_s)
                 booked.add(req.uid)
         return dt, issue_s
@@ -2672,6 +2813,7 @@ class InferenceEngine:
         power-of-two sub-batches (compiled variants bounded at
         log2(max_slots) per bucket), sample every first token in ONE
         batched call."""
+        self._drain("oneshot_prefill")
         if self.paged is not None:
             # page-granular admission: reserve ACTUAL prompt pages (+1
             # decode token) per member; a dry pool requeues the member
@@ -2830,6 +2972,7 @@ class InferenceEngine:
             self.slot_req[slot] = None
             self.slot_ready[slot] = False
             self.slot_budget[slot] = 0
+            self.slot_closing[slot] = None
             self.slot_hist[slot] = None
             self.slot_adapter[slot] = None
             if not self._publishers:
@@ -2958,37 +3101,67 @@ class InferenceEngine:
             # before the preempt — no sampling, no rng split (the
             # stream must not fork from what the client saw)
             return self._activate_with_token(slot, req, plen, 0)
+        on_device = first is not None
         if first is None:
+            self._drain("host_first_token")
             first = int(np.asarray(
                 self._first_tokens([req], last_logits))[0])
-        self._activate_with_token(slot, req, plen, first)
+        self._activate_with_token(slot, req, plen, first,
+                                  on_device=on_device)
 
     def _activate_with_token(self, slot: int, req: Request, plen: int,
-                             first_id: int):
+                             first_id: int, *, on_device: bool = False):
+        """Both halves of an activation at once: the slot's state, then
+        the first token's (``on_device``: a paged program sampled it and
+        the last-token plane holds it)."""
+        why = self._activate_slot(slot, req, plen)
+        self._first_token_out(slot, req, first_id, why, on_device=on_device)
+
+    def _activate_slot(self, slot: int, req: Request, plen: int):
+        """The half of an activation that needs no token VALUE: the slot
+        decodes from ``plen`` on. Known when the program that ends the
+        prompt is issued. Returns why the stream ends with its first
+        token (:meth:`_closing_reason`), or None."""
+        resumed = req.resume_last is not None
+        self.slot_req[slot] = req
+        self.slot_ready[slot] = True
+        self.slot_len[slot] = plen
+        # preemption resume (paged layout): the prompt now IS the full
+        # emitted history minus the resume token, whose KV is the next
+        # decode's to write
+        self.slot_budget[slot] = (req.resume_budget if resumed
+                                  else req.params.max_tokens - 1)
+        self._slot_sampling(slot, req.params)
+        # constrained decoding: install the request's grammar cursor
+        # (resume keeps the preempt-time position — already advanced
+        # over everything the client saw, including the resume token)
+        self.slot_constraint[slot] = self._ensure_constraint(req)
+        # a resumed stream emits nothing here, so nothing ends it here
+        why = self.slot_closing[slot] = (None if resumed
+                                         else self._closing_reason(slot))
+        return why
+
+    def _first_token_out(self, slot: int, req: Request, first_id: int,
+                         why: str | None, *, on_device: bool) -> None:
+        """The half of an activation that needs the first token's value:
+        the stream's first item, the history, the TTFT stamp. Done when
+        the program that sampled it is read; ``why``: what
+        :meth:`_activate_slot` found then (``slot_closing`` may by now
+        hold a later program's verdict)."""
         resumed = req.resume_last is not None
         if resumed:
-            # preemption resume (paged layout): the prompt now IS the
-            # full emitted history minus the resume token, whose KV is
-            # the next decode's to write. Nothing is emitted here and
-            # the TTFT stamp is the original one.
+            # nothing is emitted and the TTFT stamp is the original one
             first_id = req.resume_last
             req.resume_last = None
         else:
             req.first_token_time = time.monotonic()
-        self.slot_req[slot] = req
-        self.slot_ready[slot] = True
         self.slot_last_token[slot] = first_id
-        self.slot_len[slot] = plen
-        self.slot_budget[slot] = (req.resume_budget if resumed
-                                  else req.params.max_tokens - 1)
-        self._slot_sampling(slot, req.params)
+        if not on_device or resumed:
+            self._tokens_fix[slot] = first_id
         self.slot_hist[slot] = list(req.prompt_ids) + [first_id]
-        # constrained decoding: install the request's grammar cursor
-        # (resume keeps the preempt-time position — already advanced
-        # over everything the client saw, including the resume token)
-        cs = self.slot_constraint[slot] = self._ensure_constraint(req)
         if not resumed:
-            self._emit(slot, first_id)
+            cs = self.slot_constraint[slot]
+            self._emit(slot, first_id, why)
             self._constraint_commit(slot, cs, first_id)
 
     def _slot_sampling(self, slot: int, params: SamplingParams) -> None:
@@ -3262,6 +3435,7 @@ class InferenceEngine:
             return
         done = hit.length if hit is not None else 0
         if hit is not None and hit.entry is not None:
+            self._drain("kv_pull")
             self._paged_insert_entry(slot, hit.entry, hit.length)
             # promote the tier hit into the page index: the next
             # request with this prefix shares pages instead of
@@ -3296,6 +3470,7 @@ class InferenceEngine:
         (:meth:`_first_from_program`). Returns ``(first token or None,
         the last-position logits row or None)``; the dispatch is booked
         into the request's critical-path breakdown."""
+        self._drain("oneshot_prefill")
         C = self._bucket_for(len(suffix))
         # a ONE-row call of the paged chunk program: it gathers the
         # owning slot's pages, not a W-wide view of every slot, which is
@@ -3317,17 +3492,20 @@ class InferenceEngine:
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("prefill")
             fn = self._pg_chunk if lora is None else self._pg_chunk_lora
-            first, last, self.paged.kv, *counted = fn(
+            first, last, self._tokens_dev, self.paged.kv, *counted = fn(
                 self.params, self.paged.kv, *rows, *tail,
-                *self._sampling_args(sampled), **kw)
-            if self.step_stats is not None:
-                self.step_stats.pend("chunk", counted, last,
-                                     [(slot, req)])
+                *self._plane_args(), *self._sampling_args(sampled), **kw)
+            stats = self.step_stats
+            pended = stats and stats.pend("chunk", counted, last,
+                                          [(slot, req)])
             self.steptrace.window_issued()
             # force before the window closes, exactly like
             # _prefill_into_slot
-            first = np.asarray(first)
+            first, parts = jax.device_get(
+                (first, stats and stats.counted(pended)))
             dt, _ = self._window_close("prefill", (req,))
+            if stats is not None:
+                stats.book(pended, parts)
             keys = CostModel.chunk_keys(len(suffix), done)
             self._note_device_phase(
                 "prefill", tokens=len(suffix), attended_keys=keys,
@@ -3337,18 +3515,24 @@ class InferenceEngine:
                 else None)
 
     def _tail_key(self, finishing) -> tuple:
-        """``(finish, key)`` of a paged chunk program's tail
+        """``(ends, key)`` of a paged chunk program's tail
         (:meth:`_prefill_tail`) for the rows ``finishing`` = ``[(slot,
-        request), ...]`` whose prompt ends in it, and the slots among
-        them whose first token the program samples. The key is split
-        off the engine's only then: nothing is drawn from it
+        request), ...]`` whose prompt ends in it (``ends`` by slot: 1,
+        or 2 where the program samples the row's first token), and the
+        slots among them whose first token the program samples. The key
+        is split off the engine's only then: nothing is drawn from it
         otherwise."""
-        sampled = [slot for slot, req in finishing
-                   if self._first_from_program(req)]
+        ends = np.zeros((self.max_slots,), np.int32)
+        sampled = []
+        for slot, req in finishing:
+            ends[slot] = 1
+            if self._first_from_program(req):
+                ends[slot] = 2
+                sampled.append(slot)
         key = self.rng
         if sampled:
             self.rng, key = jax.random.split(self.rng)
-        return (jnp.asarray(bool(finishing)), key), sampled
+        return (jnp.asarray(ends), key), sampled
 
     def _activate_prefilled(self, slot: int, req: Request, plen: int,
                             last_logits, *, first: int | None,
@@ -3441,31 +3625,31 @@ class InferenceEngine:
         no longer serialize per slot — while a single prefill keeps
         the 1-slot program (and, with budget > 1, gets several chunks
         per step, so ``prefill_budget`` still bounds a lone prompt's
-        TTFT at ~chunks/budget steps)."""
+        TTFT at ~chunks/budget steps). The paged layout's chunk program
+        is issued and read at once here (:meth:`_issue_chunk`,
+        :meth:`_retire`): this is the path of a step that dispatches
+        more than one program."""
         progressed = False
+        if self.paged is not None:
+            while budget > 0 and self.slot_prefill:
+                self._retire(self._issue_chunk())
+                budget -= 1
+                progressed = True
+            return progressed
         while budget > 0 and self.slot_prefill:
-            # paged layout: no per-chunk page reservation is needed —
-            # admission reserved the WHOLE prompt's pages (+1 decode
-            # token) before the slot entered slot_prefill, so every
-            # chunk write is already covered; only decode GROWTH
-            # allocates on demand (_paged_reserve_active)
             with self.steptrace.scope("index_build"):
                 entries = self._chunk_entries()
                 C = self.chunked_prefill
                 # whole-cache batching needs every row's C-wide write window
                 # inside cache_len — a clamped scatter on a near-full ACTIVE
                 # row would overwrite attended KV. Rare tail case: fall back
-                # to sequential single-slot chunks. (The paged layout is
-                # always batchable: discarded writes are routed to the
-                # trash page by the host-built scatter indices, so there is
-                # no clamp hazard to dodge.)
-                batchable = self.paged is not None or (
-                    len(entries) > 1 and all(
-                        int(self.slot_len[s]) + C <= self.cache_len
-                        for s in range(self.max_slots)
-                        if s not in self.slot_prefill
-                        and self.slot_req[s] is not None  # free rows are dead
-                    ))
+                # to sequential single-slot chunks.
+                batchable = len(entries) > 1 and all(
+                    int(self.slot_len[s]) + C <= self.cache_len
+                    for s in range(self.max_slots)
+                    if s not in self.slot_prefill
+                    and self.slot_req[s] is not None  # free rows are dead
+                )
                 # device-plane accounting reads each chunk's pre-advance
                 # context; compute before the branches mutate st["done"]
                 pf_tokens = sum(len(c) for _, _, c in entries)
@@ -3475,10 +3659,7 @@ class InferenceEngine:
                 kw = {} if lora is None else {"lora": lora}
             with self.steptrace.scope("dispatch_wait"):
                 self.steptrace.window_begin("prefill")
-                first = None
-                if self.paged is not None:
-                    first = self._paged_chunk_dispatch(entries, lora=lora)
-                elif batchable:
+                if batchable:
                     tok, starts, lens = self._chunk_batch_rows(entries)
                     self._note_chunk_rows(len(entries), self.max_slots)
                     fn = (self._chunk_batch if lora is None
@@ -3513,26 +3694,20 @@ class InferenceEngine:
                 # ~device-time/dispatch-time-fold (the decode and fused
                 # paths force every dispatch the same way). KV writes
                 # land in the same program, so this waits only for work
-                # the next chunk depends on anyway. The paged program's
-                # first tokens are the fetch.
-                if first is not None:
-                    first = np.asarray(first)
-                else:
-                    jax.block_until_ready(last)
+                # the next chunk depends on anyway.
+                jax.block_until_ready(last)
                 # every mid-prefill request waited the whole dispatch
                 dt, issue_s = self._window_close(
                     "prefill", [st["req"] for _, st, _ in entries])
                 self._trace_chunks(entries, dt, issue_s, batched=batchable)
                 self._note_device_phase(
                     "prefill", tokens=pf_tokens, attended_keys=pf_keys,
-                    # the paged loop streams the weights once a row
-                    weight_passes=(1 if batchable and self.paged is None
-                                   else len(entries)),
+                    weight_passes=1 if batchable else len(entries),
                     kv_read_tokens=pf_keys, dt=dt)
             budget -= 1
             progressed = True
             with self.steptrace.scope("sample_commit"):
-                self._finalize_prefills(first)
+                self._finalize_prefills()
         return progressed
 
     def _trace_chunks(self, entries, dt: float, issue_s: float, *,
@@ -3627,32 +3802,78 @@ class InferenceEngine:
         self.prefill_chunk_row_slots += row_slots
         self.steptrace.note_chunk_rows(rows, row_slots)
 
-    def _paged_chunk_dispatch(self, entries, lora=None):
-        """Advance ``entries``' rows one chunk against the PAGE
-        POOL in a single dispatch: the program gathers one chunking
-        row's pages at a time, runs the shared ``batched_chunk_hidden``
-        body on that view and scatters the row's real chunk window back
-        to its pages. Rows that do not chunk cost nothing. Returns the
-        program's first tokens by slot (a device array), which
-        :meth:`_finalize_prefills` reads for the rows that finish."""
-        C = self.chunked_prefill
-        W = self._paged_width(
-            max(st["done"] for _, st, _ in entries) + C)
-        self._pulse_view(W, 1)
-        kw = {} if lora is None else {"lora": lora}
-        fn = self._pg_chunk if lora is None else self._pg_chunk_lora
-        # a statement of its own: building the rows may fork a shared
-        # page, which REBINDS the (donated) pool read below
-        rows = self._paged_entry_rows(entries, W)
-        finishing = self._finishing(entries)
-        tail, sampled = self._tail_key(finishing)
-        first, last, self.paged.kv, *counted = fn(
-            self.params, self.paged.kv, *rows, *tail,
-            *self._sampling_args(sampled, runs=bool(finishing)), **kw)
+    def _issue_chunk(self) -> _Flight:
+        """Issue the paged chunk program: the mid-prefill rows
+        (:meth:`_chunk_entries`) advance one chunk against the PAGE POOL
+        in a single dispatch: the program gathers one chunking row's
+        pages at a time, runs the shared ``batched_chunk_hidden`` body
+        on that view and scatters the row's real chunk window back to
+        its pages. Rows that do not chunk cost nothing. No per-chunk
+        page reservation is needed: admission reserved the WHOLE
+        prompt's pages (+1 decode token) before the slot entered
+        ``slot_prefill``. Returns the program in flight; its first
+        tokens by slot are read in :meth:`_retire`."""
+        with self.steptrace.scope("index_build"):
+            entries = self._chunk_entries()
+            C = self.chunked_prefill
+            # device-plane accounting reads each chunk's pre-advance
+            # context; compute before the chunk is booked
+            pf_keys = sum(CostModel.chunk_keys(len(c), st["done"])
+                          for _, st, c in entries)
+            book = {"pf_tokens": sum(len(c) for _, _, c in entries),
+                    "pf_keys": pf_keys}
+            lora = self._lora_args()   # slot-plane (batched chunk rows)
+            kw = {} if lora is None else {"lora": lora}
+        with self.steptrace.scope("dispatch_wait"):
+            f = self._window_open("prefill", _Flight("chunk", book=book))
+            W = self._paged_width(
+                max(st["done"] for _, st, _ in entries) + C)
+            self._pulse_view(W, 1)
+            fn = self._pg_chunk if lora is None else self._pg_chunk_lora
+            # a statement of its own: building the rows may fork a shared
+            # page, which REBINDS the (donated) pool read below
+            rows = self._paged_entry_rows(entries, W)
+            finishing = self._finishing(entries)
+            tail, sampled = self._tail_key(finishing)
+            f.first, last, self._tokens_dev, self.paged.kv, *counted = fn(
+                self.params, self.paged.kv, *rows, *tail,
+                *self._plane_args(),
+                *self._sampling_args(sampled, runs=bool(finishing)), **kw)
+            self._prompts_issued(f, "chunk", entries, finishing, last,
+                                 counted)
+            self.steptrace.window_issued()
+        return f
+
+    def _window_open(self, phase: str, f: _Flight) -> _Flight:
+        """Open ``f``'s dispatch window, book whether it is issued ahead
+        of an unread program (where it is not, the step's end books
+        why), and note who holds a slot now."""
+        self.steptrace.window_begin(phase)
+        if self._flight is not None:
+            self.steptrace.note_ahead()
+        f.holders = [r for r in self.slot_req if r is not None]
+        return f
+
+    def _prompts_issued(self, f: _Flight, kind: str, entries, finishing,
+                        last, counted) -> None:
+        """The host's half of a chunk dispatch that needs no token
+        VALUE, done at issue: the chunks are booked as fed, and a prompt
+        the program ends in a first token of its own sampling is live
+        from here on (the next program decodes it). One whose first
+        token the host samples stays in ``slot_prefill`` for
+        :meth:`_finalize_prefills`, and makes ``f`` a program that is
+        read at once."""
         if self.step_stats is not None:
-            self.step_stats.pend("chunk", counted, last, finishing)
+            f.stats = self.step_stats.pend(kind, counted, last, finishing)
+        f.entries = entries
         self._chunks_done(entries, last)
-        return first
+        for slot, req in finishing:
+            if not self._first_from_program(req):
+                f.host_first = True
+                continue
+            st = self.slot_prefill.pop(slot)
+            f.finished.append(
+                (slot, req, st, self._activate_slot(slot, req, st["plen"])))
 
     @staticmethod
     def _finishing(entries) -> list:
@@ -3673,13 +3894,13 @@ class InferenceEngine:
         for _, st, chunk in entries:
             st["done"] += len(chunk)
 
-    def _finalize_prefills(self, first=None) -> None:
-        """Activate every chunked prefill whose prompt is fully fed —
-        shared tail of the sequential and fused mixed-step paths.
-        ``first``: the paged program's first tokens by slot, ON THE HOST
-        (the step's one fetch); a row takes its token from there where
-        the program sampled it (:meth:`_first_from_program`), and
-        nothing is dispatched for it here."""
+    def _finalize_prefills(self) -> None:
+        """Activate every chunked prefill whose prompt is fully fed and
+        whose first token the HOST samples from the prefill logits —
+        shared tail of the sequential and fused mixed-step paths (a
+        prompt that a paged program ended in a first token of its own
+        was activated when the program was issued, and its token is read
+        in :meth:`_retire`)."""
         for slot in list(self.slot_prefill):
             st = self.slot_prefill[slot]
             if st["done"] < st["plen"]:
@@ -3699,12 +3920,10 @@ class InferenceEngine:
                 self._store_prefix(req, plen, rows,
                                    st["last_logits"],
                                    rows_ready=True)
-            token = (int(first[slot]) if first is not None
-                     and self._first_from_program(req) else None)
             # the gathered rows ride through to the handoff path so a
             # chunked handoff doesn't pay the gather dispatch twice
             self._activate_prefilled(slot, req, plen, st["last_logits"],
-                                     first=token, rows=rows)
+                                     first=None, rows=rows)
 
     def _paged_store_prefix(self, req: Request, plen: int, slot: int,
                             last_logits) -> None:
@@ -3811,7 +4030,14 @@ class InferenceEngine:
         the slot's full pages are registered for sharing on the way out
         (a follow-up turn reuses the whole conversation's KV) and the
         block table releases its references — the churn test pins that
-        this leaks nothing."""
+        this leaks nothing.
+
+        A row whose stream ends (in EOS) while a program issued AFTER the
+        one being read still decodes it finishes towards the client
+        here, and keeps its slot and pages until that program is read
+        (:meth:`_retire`): no page is freed, registered for sharing or
+        handed to another request while a program that can write it is
+        in flight."""
         req = self.slot_req[slot]
         req.finish_time = time.monotonic()
         req.finish_reason = reason
@@ -3827,6 +4053,36 @@ class InferenceEngine:
                 # the issue parts of every window booked to the request,
                 # as /debug/requests shows them under dispatch_issue
                 issue_s=req.cp.get("dispatch_issue", 0.0))
+        held = self._ahead is not None and self._ahead.decodes(slot)
+        if not held:
+            self._release_pages(slot, req)
+        # breakdown finalized BEFORE _FINISH is released: a consumer
+        # that saw the stream end must find the request in the
+        # /debug/requests ring (same ordering rule as the decode span)
+        self._record_finished(req)
+        req.tokens.put(_FINISH)
+        self.stats.observe_finished(req)
+        if held:
+            self.slot_ready[slot] = False
+            self.slot_constraint[slot] = None
+            self._zombies.add(slot)
+        else:
+            self._clear_slot(slot)
+
+    def _clear_slot(self, slot: int) -> None:
+        """``slot`` is free: nothing of its request is left in it."""
+        self.slot_req[slot] = None
+        self.slot_ready[slot] = False
+        self.slot_budget[slot] = 0
+        self.slot_closing[slot] = None
+        self.slot_constraint[slot] = None
+        self.slot_adapter[slot] = None
+        self._zombies.discard(slot)
+
+    def _release_pages(self, slot: int, req: Request) -> None:
+        """The pages' half of a finish: the paged layout registers the
+        slot's full pages for sharing and drops its references; a
+        session's store hears of the turn."""
         if self.paged is not None:
             hist = self.slot_hist[slot]
             if hist:
@@ -3848,17 +4104,6 @@ class InferenceEngine:
                 req.session_id, hist[:-1] if hist else req.prompt_ids,
                 [], adapter=req.adapter,
                 cache_outcome=req.cache_outcome)
-        # breakdown finalized BEFORE _FINISH is released: a consumer
-        # that saw the stream end must find the request in the
-        # /debug/requests ring (same ordering rule as the decode span)
-        self._record_finished(req)
-        req.tokens.put(_FINISH)
-        self.stats.observe_finished(req)
-        self.slot_req[slot] = None
-        self.slot_ready[slot] = False
-        self.slot_budget[slot] = 0
-        self.slot_constraint[slot] = None
-        self.slot_adapter[slot] = None
 
     def _session_note_finish(self, slot: int, req: Request,
                              token_ids) -> None:
@@ -3885,18 +4130,29 @@ class InferenceEngine:
             self.session_store.publish(
                 req.session_id, token_ids[:nfull * P], entry)
 
-    def _emit(self, slot: int, token_id: int):
+    def _closing_reason(self, slot: int) -> str | None:
+        """Why ``slot``'s stream ends with the token it just took, as
+        far as the host can tell without the token: no budget left, or
+        (cache_len guard) the emitted token's write, the next decode,
+        would not fit."""
+        if self.slot_budget[slot] <= 0:
+            return "length"
+        if self.slot_len[slot] + 1 >= self.cache_len:
+            return "cache"
+        return None
+
+    def _emit(self, slot: int, token_id: int, why: str | None):
+        """Stream one token out; end the stream on EOS, or where the
+        row's deterministic end falls on this token (``why``:
+        :meth:`_closing_reason` as it stood when the token's program was
+        issued)."""
         req = self.slot_req[slot]
-        budget_left = self.slot_budget[slot] > 0
         hit_eos = self.eos_id is not None and token_id == self.eos_id
-        # cache_len guard: the emitted token's write (next decode) must fit.
-        room = self.slot_len[slot] + 1 < self.cache_len
         if not hit_eos:
             req.tokens.put(token_id)
             req.n_generated += 1
-        if hit_eos or not budget_left or not room:
-            self._finish_slot(slot, "stop" if hit_eos else
-                              ("length" if not budget_left else "cache"))
+        if hit_eos or why is not None:
+            self._finish_slot(slot, "stop" if hit_eos else why)
 
     def _draft(self, hist: list[int], k: int) -> list[int] | None:
         """Prompt-lookup draft: find the most recent earlier occurrence of
@@ -4123,12 +4379,34 @@ class InferenceEngine:
         return True
 
     def _commit_token(self, slot: int, tok: int) -> None:
-        """Book one generated token into a slot: budget/length/last-token
-        tracking, spec history, grammar advance, and emission (which may
-        finish the slot). The single, speculative, and multi-step paths
-        all commit here."""
-        self.slot_budget[slot] -= 1
-        self.slot_len[slot] += 1
+        """Book one generated token into a slot, both halves at once
+        (the paths that read a program before they issue the next: the
+        speculative round, the contiguous layout). The token is the
+        host's: the next paged program puts it into the plane."""
+        _, why = self._advance_row(slot, 1)
+        self._tokens_fix[slot] = tok
+        self._commit_value(slot, tok, why)
+
+    def _advance_row(self, slot: int, n: int) -> tuple:
+        """The half of a commit that needs no token VALUE, for a block
+        of ``n``: budget and length move by the tokens the row takes of
+        it (it stops where its budget or its cache room ends; that is
+        then why it closes, and no later program takes the row). Done
+        when the block is issued. Returns ``(tokens taken, why the row
+        ends with the last of them, or None)``."""
+        for taken in range(1, n + 1):
+            self.slot_budget[slot] -= 1
+            self.slot_len[slot] += 1
+            why = self.slot_closing[slot] = self._closing_reason(slot)
+            if why is not None:
+                break
+        return taken, why
+
+    def _commit_value(self, slot: int, tok: int, why: str | None) -> None:
+        """The half of a commit that needs the token: last-token mirror,
+        spec history, grammar advance, and emission (which may finish
+        the slot, on EOS or for the deterministic ``why``). Done when
+        the program is read."""
         self.slot_last_token[slot] = tok
         if self.slot_hist[slot] is not None:
             self.slot_hist[slot].append(tok)
@@ -4137,7 +4415,7 @@ class InferenceEngine:
         # lives on the request and the stream's last token is part of
         # the grammar position a preempt-resume would continue from)
         cs = self.slot_constraint[slot]
-        self._emit(slot, tok)
+        self._emit(slot, tok, why)
         self._constraint_commit(slot, cs, tok)
 
     def _update_active_stats(self) -> None:
@@ -4146,8 +4424,12 @@ class InferenceEngine:
                 r is not None for r in self.slot_req)
 
     def _ready_slots(self) -> list[int]:
+        """The rows the next decode takes: ready, and not at their
+        deterministic end (such a row only waits for its last program to
+        be read)."""
         return [s for s, r in enumerate(self.slot_req)
-                if r is not None and self.slot_ready[s]]
+                if r is not None and self.slot_ready[s]
+                and self.slot_closing[s] is None]
 
     # --- grammar (constrained decoding, serve/constrain.py) ------------------
 
@@ -4340,16 +4622,20 @@ class InferenceEngine:
                     f"> cache_len {self.cache_len}")
         return True, ""
 
-    def _mixed_dispatch(self, active: list[int], n: int) -> bool:
+    def _mixed_dispatch(self, active: list[int], n: int):
         """Issue the fused mixed-batch program: the step's mid-prefill
         rows (:meth:`_chunk_entries`) advance one chunk AND every ready
         row decodes an ``n``-block,
         in ONE device dispatch (serve/mixed_step.py). Host bookkeeping
         mirrors the sequential paths exactly: chunk results feed
         ``slot_prefill``/finalization, block tokens commit per slot.
+        The paged program is issued here and read in :meth:`_retire`
+        (a step later where nothing forbids it: :meth:`_fly`).
         Returns False (nothing dispatched) only when paged page
         reservation drained either half — the caller falls through to
-        the sequential paths for this step."""
+        the sequential paths for this step — and ``_REPLAN`` where the
+        reservation would have to preempt or finish a row while a
+        program is unread (it has been read now: plan again)."""
         C = self.chunked_prefill
         if self.paged is not None:
             # reserve the decode half's writes: n rows per ready slot
@@ -4358,6 +4644,8 @@ class InferenceEngine:
             # scan's garbage rows above each prefill watermark scatter
             # to the trash page.
             with self.steptrace.scope("admit"):
+                if not self._reserve_ahead(active, n):
+                    return _REPLAN
                 active = self._paged_reserve_active(active, n)
             if not active or not self.slot_prefill:
                 return False
@@ -4383,12 +4671,19 @@ class InferenceEngine:
             # to each half's FLOPs (token-count fallback without a cost
             # model) — arxiv 2311.03687's phase dissection must survive the
             # fusion that merged the phases into one program
-            pf_tokens = sum(len(c) for _, _, c in entries)
-            pf_keys = sum(CostModel.chunk_keys(len(c), st["done"])
-                          for _, st, c in entries)
-            dc_tokens = n * len(active)
-            dc_keys = sum(CostModel.block_keys(n, int(self.slot_len[s]))
-                          for s in active)
+            book = {
+                "pf_tokens": sum(len(c) for _, _, c in entries),
+                "pf_keys": sum(CostModel.chunk_keys(len(c), st["done"])
+                               for _, st, c in entries),
+                "dc_tokens": n * len(active),
+                "dc_keys": sum(
+                    CostModel.block_keys(n, int(self.slot_len[s]))
+                    for s in active)}
+        self.mixed_blocks += 1
+        if self.paged is not None:
+            self._fly(self._issue_mixed(active, n, entries, gmask, lora,
+                                        book))
+            return True
         # one scope spans through the two note_device_phase calls below
         # (their dt shares must land inside it so the device deduction
         # balances) — and the dispatch calls themselves, so a raising
@@ -4396,92 +4691,111 @@ class InferenceEngine:
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("mixed")
             self.rng, sub = jax.random.split(self.rng)
-            # the paged program samples the first token of the rows
-            # whose prompt it ends: they choose the sampler's body with
-            # the rows that decode
-            tail, sampled = ((), [])
-            if self.paged is not None:
-                tail, sampled = self._tail_key(self._finishing(entries))
             sampling = (jnp.asarray(self.slot_last_token), sub,
-                        *self._sampling_args(active + sampled))
+                        *self._sampling_args(active))
             if gmask is not None:
                 sampling += (jnp.asarray(gmask),)
-            first = None
-            if self.paged is not None:
-                # ONE view width for both halves: each prefill row's
-                # chunk + the block (done+C+n), and each occupied decode
-                # row's len+C, capped at cache_len — no decode row
-                # receives a chunk write any more, but the warm-up
-                # builds the widths THIS rule gives (narrower decode
-                # views are a change of their own)
-                need = max(
-                    [st["done"] + C + n for _, st, _ in entries]
-                    + [min(int(self.slot_len[s]) + C, self.cache_len)
-                       for s in range(self.max_slots)
-                       if s not in self.slot_prefill
-                       and self.slot_req[s] is not None] + [C + n])
-                W = self._paged_width(need)
-                self._pulse_view(W)
-                if gmask is not None:
-                    fn = (self._pg_mixed_masked if lora is None
-                          else self._pg_mixed_masked_lora)
-                else:
-                    fn = (self._pg_mixed if lora is None
-                          else self._pg_mixed_lora)
-                # statements of their own: either may fork a shared
-                # page, which REBINDS the (donated) pool read below
-                rows = self._paged_entry_rows(entries, W)
-                plan = self._paged_decode_plan(active, n, W)
-                first, chunk_last, toks, self.paged.kv, *counted = fn(
-                    self.params, self.paged.kv, *rows, *tail, *plan,
-                    *sampling, n=n, **kw)
-                if self.step_stats is not None:
-                    self.step_stats.pend("mixed", counted, chunk_last,
-                                         self._finishing(entries))
+            self._note_chunk_rows(len(entries), self.max_slots)
+            if gmask is not None:
+                fn = (self._mixed_masked if lora is None
+                      else self._mixed_masked_lora)
             else:
-                self._note_chunk_rows(len(entries), self.max_slots)
-                if gmask is not None:
-                    fn = (self._mixed_masked if lora is None
-                          else self._mixed_masked_lora)
-                else:
-                    fn = self._mixed if lora is None else self._mixed_lora
-                chunk_last, toks, self.cache = fn(
-                    self.params, self.cache, jnp.asarray(tok),
-                    jnp.asarray(starts), jnp.asarray(lens),
-                    jnp.asarray(advance), *sampling, n=n, **kw)
+                fn = self._mixed if lora is None else self._mixed_lora
+            chunk_last, toks, self.cache = fn(
+                self.params, self.cache, jnp.asarray(tok),
+                jnp.asarray(starts), jnp.asarray(lens),
+                jnp.asarray(advance), *sampling, n=n, **kw)
             self.steptrace.window_issued()
-            # ONE fetch forces the dispatch's results: the decode
-            # block's tokens and the finished prompts' first tokens
-            first, toks_host = jax.device_get((first, toks))
+            # ONE fetch forces the dispatch's results
+            toks_host = np.asarray(toks)
             # the window advanced the mid-prefill rows' prompts; every
             # decode member sat through the whole fused dispatch for
             # them (prefill_stall, not decode_dispatch)
             dt, issue_s = self._window_close(
                 "prefill", [st["req"] for _, st, _ in entries])
-            self.mixed_blocks += 1
             self._chunks_done(entries, chunk_last)
             self._trace_chunks(entries, dt, issue_s, batched=True,
                                fused=True)
-            cm = self.cost_model
-            if cm is not None:
-                pf, df = (cm.step_flops(pf_tokens, pf_keys),
-                          cm.step_flops(dc_tokens, dc_keys))
-                share = pf / (pf + df) if pf + df > 0 else 0.5
-            else:
-                share = pf_tokens / max(pf_tokens + dc_tokens, 1)
-            self._note_device_phase(
-                "prefill", tokens=pf_tokens, attended_keys=pf_keys,
-                # the paged loop streams the weights once a row
-                weight_passes=1 if self.paged is None else len(entries),
-                kv_read_tokens=pf_keys, dt=dt * share)
-            self._note_device_phase(
-                "decode", tokens=dc_tokens, attended_keys=dc_keys,
-                weight_passes=n, kv_read_tokens=dc_keys,
-                dt=dt * (1 - share))
+            self._note_mixed_phases(book, n, dt, passes=1)
         with self.steptrace.scope("sample_commit"):
-            self._finalize_prefills(first)
+            self._finalize_prefills()
             self._commit_block(active, toks_host, n)
         return True
+
+    def _note_mixed_phases(self, book: dict, n: int, dt: float, *,
+                           passes: int) -> None:
+        """Book a fused dispatch's window ``dt`` to the two phases in
+        proportion to each half's FLOPs (``book``: the halves' tokens
+        and attended keys as issued)."""
+        cm = self.cost_model
+        if cm is not None:
+            pf, df = (cm.step_flops(book["pf_tokens"], book["pf_keys"]),
+                      cm.step_flops(book["dc_tokens"], book["dc_keys"]))
+            share = pf / (pf + df) if pf + df > 0 else 0.5
+        else:
+            share = book["pf_tokens"] / max(
+                book["pf_tokens"] + book["dc_tokens"], 1)
+        self._note_device_phase(
+            "prefill", tokens=book["pf_tokens"],
+            attended_keys=book["pf_keys"], weight_passes=passes,
+            kv_read_tokens=book["pf_keys"], dt=dt * share)
+        self._note_device_phase(
+            "decode", tokens=book["dc_tokens"],
+            attended_keys=book["dc_keys"], weight_passes=n,
+            kv_read_tokens=book["dc_keys"], dt=dt * (1 - share))
+
+    def _issue_mixed(self, active: list[int], n: int, entries, gmask, lora,
+                     book: dict) -> _Flight:
+        """Issue the PAGED fused mixed program (pages reserved, rows
+        planned by :meth:`_mixed_dispatch`): the rows that decode take
+        the last-token plane, the rows that chunk are the host's; the
+        plane comes back with the block's tokens and the finished
+        prompts' first tokens in it."""
+        C = self.chunked_prefill
+        kw = {} if lora is None else {"lora": lora}
+        with self.steptrace.scope("dispatch_wait"):
+            f = self._window_open("mixed", _Flight("mixed", n=n, book=book))
+            self.rng, sub = jax.random.split(self.rng)
+            # the program samples the first token of the rows whose
+            # prompt it ends: they choose the sampler's body with the
+            # rows that decode
+            finishing = self._finishing(entries)
+            tail, sampled = self._tail_key(finishing)
+            sampling = (*self._plane_args(), sub,
+                        *self._sampling_args(active + sampled))
+            if gmask is not None:
+                sampling += (jnp.asarray(gmask),)
+            # ONE view width for both halves: each prefill row's
+            # chunk + the block (done+C+n), and each occupied decode
+            # row's len+C, capped at cache_len — no decode row
+            # receives a chunk write any more, but the warm-up
+            # builds the widths THIS rule gives (narrower decode
+            # views are a change of their own)
+            need = max(
+                [st["done"] + C + n for _, st, _ in entries]
+                + [min(int(self.slot_len[s]) + C, self.cache_len)
+                   for s in self._ready_slots()] + [C + n])
+            W = self._paged_width(need)
+            self._pulse_view(W)
+            if gmask is not None:
+                fn = (self._pg_mixed_masked if lora is None
+                      else self._pg_mixed_masked_lora)
+            else:
+                fn = (self._pg_mixed if lora is None
+                      else self._pg_mixed_lora)
+            # statements of their own: either may fork a shared
+            # page, which REBINDS the (donated) pool read below
+            rows = self._paged_entry_rows(entries, W)
+            plan = self._paged_decode_plan(active, n, W)
+            (f.first, chunk_last, f.toks, self._tokens_dev, self.paged.kv,
+             *counted) = fn(self.params, self.paged.kv, *rows, *tail,
+                            *plan, *sampling, n=n, **kw)
+            self._prompts_issued(f, "mixed", entries, finishing,
+                                 chunk_last, counted)
+            f.rows = [(s, self.slot_req[s], *self._advance_row(s, n))
+                      for s in active if self.slot_req[s] is not None]
+            self.steptrace.window_issued()
+        return f
 
     def _commit_block(self, active: list[int], toks_host, n: int) -> None:
         """Book an ``n``-step decode block's tokens ((B, n) host array)
@@ -4496,6 +4810,173 @@ class InferenceEngine:
                 if self.slot_req[slot] is None:
                     break                 # finished mid-block (eos/len)
                 self._commit_token(slot, int(toks_host[slot, j]))
+
+    # --- issue and retire (one step of lookahead) ----------------------------
+    #
+    # A device step has two halves. ISSUE: admit, plan, reserve pages,
+    # build indices, dispatch; it needs no token VALUE of the program
+    # before it, because the last tokens stay on the device (the plane)
+    # and lengths, budgets, pages and readiness are deterministic.
+    # RETIRE: fetch the program's tokens (the step's one forcing point),
+    # emit and finish, book. The loop is issue(n+1); retire(n): the host's
+    # share of a step runs while the device computes. Where the next
+    # plan does need values, the engine DRAINS (retires what is in
+    # flight) and steps as it always did: issue, then retire at once.
+
+    def _ahead_blocker(self) -> str | None:
+        """Why this step's program may not be issued before the one in
+        flight is read, from what the engine observes of itself; None:
+        nothing forbids it. (Further reasons are found on the way and
+        drain where they are found: an admission that dispatches, a
+        page reservation that must preempt, a step of two programs.)"""
+        if self.paged is None:
+            return "contiguous"     # its programs take the host's tokens
+        if self.block is not None:
+            return "block"          # a pass's reveal decides the next
+        ready = self._ready_slots()
+        if self._constrained_active(ready):
+            return "grammar"        # the mask is a function of the token
+        if self._spec_applicable(ready):
+            return "speculative"    # drafts come from the history
+        if self.session_store is not None and any(
+                self.slot_req[s].session_id is not None for s in ready):
+            return "session"        # its turn is pinned before _FINISH
+        return None
+
+    def _drain(self, why: str) -> None:
+        """Retire what is in flight: the caller needs the engine's state
+        as the serial loop has it (every token on the host, no row at a
+        pending end, no page held for an unread program). ``why`` is the
+        step's reason for not running ahead (the first one stands),
+        whether or not a program was in flight."""
+        if self._step_why is None:
+            self._step_why = why
+        f, self._flight = self._flight, None
+        if f is not None:
+            self._retire(f)
+
+    def _reserve_ahead(self, active: list[int], n: int) -> bool:
+        """May the step reserve ``n`` more positions a ready row while a
+        program is unread? Only if no reservation has to preempt or
+        finish a row; else the program is read first (False: plan the
+        step again, as the serial loop would)."""
+        if self._flight is None or all(
+                self.paged.extend(s, int(self.slot_len[s]) + n)
+                for s in active):
+            return True
+        self._drain("preempt")
+        return False
+
+    def _fly(self, f: _Flight) -> None:
+        """``f`` was just issued: read the program before it (this is
+        the host work the device no longer waits for), and leave ``f``
+        in flight for the next step, unless that step's plan needs a
+        value only ``f``'s reading gives (a first token the host
+        samples)."""
+        self._flew = True
+        old, self._flight = self._flight, None
+        if old is not None:
+            self._ahead = f
+            try:
+                self._retire(old)
+            finally:
+                self._ahead = None
+        if f.host_first:
+            self._next_why = "host_first_token"
+            self._retire(f)
+        else:
+            self._flight = f
+
+    def _retire(self, f: _Flight) -> None:
+        """Read the program ``f``: ONE fetch (its tokens, the first
+        tokens of the prompts it ended, what it counted) forces its
+        results, then the halves of activation and commit that need the
+        values run: emission, EOS, finishes, the statistics' booking."""
+        chunked = [st["req"] for _, st, _ in f.entries]
+        with self.steptrace.scope("dispatch_wait"):
+            stats = self.step_stats
+            first, toks, parts = jax.device_get(
+                (f.first, f.toks, stats and stats.counted(f.stats)))
+            # a window that advanced prompts is theirs (every decode
+            # member sat through it for them: prefill_stall), else the
+            # decode rows'
+            dt, issue_s = self._window_close(
+                "prefill" if chunked else "decode",
+                chunked or [row[1] for row in f.rows], f.holders)
+            book = f.book
+            if f.kind == "mixed":
+                self._trace_chunks(f.entries, dt, issue_s, batched=True,
+                                   fused=True)
+                # the paged loop streams the weights once a row
+                self._note_mixed_phases(book, f.n, dt,
+                                        passes=len(f.entries))
+            elif f.kind == "chunk":
+                self._trace_chunks(f.entries, dt, issue_s, batched=True)
+                self._note_device_phase(
+                    "prefill", tokens=book["pf_tokens"],
+                    attended_keys=book["pf_keys"],
+                    weight_passes=len(f.entries),
+                    kv_read_tokens=book["pf_keys"], dt=dt)
+            else:
+                self._note_device_phase(
+                    "decode", tokens=book["dc_tokens"],
+                    attended_keys=book["dc_keys"], weight_passes=f.n,
+                    kv_read_tokens=book["dc_keys"], dt=dt)
+        with self.steptrace.scope("sample_commit"):
+            for slot, req, st, why in f.finished:
+                # rows are already in the slot; store the prefix entry
+                # from them
+                self._paged_store_prefix(req, st["plen"], slot,
+                                         st["last_logits"])
+                self._note_first_token("program")
+                self._first_token_out(slot, req, int(first[slot]), why,
+                                      on_device=True)
+            if f.host_first:
+                self._finalize_prefills()
+            if f.n > 1:
+                self.multi_blocks += 1
+                self.multi_steps_total += f.n
+            for slot, req, taken, why in f.rows:
+                if slot in self._zombies:
+                    # its stream ended in EOS when the program before
+                    # this one was read: the tokens go nowhere, and the
+                    # slot and its pages are free from here on
+                    self.steptrace.note_discarded(taken)
+                    self._release_pages(slot, req)
+                    self._clear_slot(slot)
+                    continue
+                for j in range(taken):
+                    self._commit_value(slot, int(toks[slot, j]),
+                                       why if j == taken - 1 else None)
+                    if not self.slot_ready[slot]:
+                        break             # finished mid-block (eos)
+            if stats is not None:
+                stats.book(f.stats, parts)
+        self._update_active_stats()
+
+    def _decode_paged(self, active: list[int], n: int, sub):
+        """Issue the paged decode program for the ready rows ``active``
+        (pages reserved by the caller): a single token through the
+        ``_decode_fn`` body, an ``n``-block through the scan. The rows
+        take their deterministic step here; their tokens are read in
+        :meth:`_retire`."""
+        # constrained decoding: per-slot grammar mask rows, applied by
+        # the masked twin program in the SAME single dispatch
+        with self.steptrace.scope("index_build"):
+            gmask = self._grammar_masks(active) if n == 1 else None
+            lora = self._lora_args()
+        with self.steptrace.scope("dispatch_wait"):
+            f = self._window_open("decode", _Flight("decode", n=n, book={
+                "dc_tokens": n * len(active),
+                "dc_keys": sum(
+                    CostModel.block_keys(n, int(self.slot_len[s]))
+                    for s in active)}))
+            self._paged_decode_dispatch(f, active, n, sub, gmask=gmask,
+                                        lora=lora)
+            f.rows = [(s, self.slot_req[s], *self._advance_row(s, n))
+                      for s in active if self.slot_req[s] is not None]
+            self.steptrace.window_issued()
+        self._fly(f)
 
     def step(self) -> bool:
         """One engine iteration. Returns False when fully idle."""
@@ -4518,19 +4999,38 @@ class InferenceEngine:
                 # per-step rolling mean decays to 0 on any bursty
                 # server and the metric stops meaning anything (the
                 # steptrace ring follows the same rule)
-                if busy or spent:
+                if busy or spent or self._read:
                     with self.steptrace.scope("sample_commit"):
                         self.dispatch_meter.note_step(spent)
-                        if self.step_stats is not None:
-                            self.step_stats.book()
                     self.steptrace.step_end(self.tracer)
                 else:
                     self.steptrace.step_abort()
 
     def _step_locked(self) -> bool:
+        self._step_why, self._next_why = self._next_why, None
+        self._flew = False
+        self._read = self._flight is not None   # this step reads a program
         with self.steptrace.scope("admit"):
             self._admit()
+        busy = self._plan_step()
+        if busy is _REPLAN:
+            # a reservation had to read the program in flight: the plan
+            # was made on rows that may have ended; make it again
+            busy = self._plan_step()
+        if not self._flew:
+            # nothing was issued: what is in flight is all there is to do
+            self._drain("idle")
+        self.steptrace.note_drain(self._step_why or "idle")
+        # a program left unread is work (the next step reads it), and so
+        # is a request that waited for the slot this step's reading freed
+        return (bool(busy) or self._flight is not None
+                or (self._read and self.pending.qsize() > 0))
+
+    def _plan_step(self):
         budget = self.prefill_budget
+        why = self._ahead_blocker()
+        if why is not None:
+            self._drain(why)
         if self.block is not None:
             # block-diffusion model: chunks and block rows take two
             # dispatches (no fused mixed block step yet), and one pass
@@ -4590,6 +5090,7 @@ class InferenceEngine:
                 # re-snapshot the ready set, since a prompt finishing
                 # its last chunk here activates and must join this
                 # step's decode block (sequential-path parity)
+                self._drain("two_dispatch")
                 pre_progress = self._advance_prefills(budget - 1)
                 budget = 1
                 with self.steptrace.scope("plan"):
@@ -4617,7 +5118,10 @@ class InferenceEngine:
                             "outputs are unchanged — spec is lossless); "
                             "speculation resumes when no prefill is in "
                             "flight")
-                    if self._mixed_dispatch(active, n):
+                    issued = self._mixed_dispatch(active, n)
+                    if issued is _REPLAN:
+                        return _REPLAN
+                    if issued:
                         self._update_active_stats()
                         return True
                     # paged page reservation drained one half of the
@@ -4633,6 +5137,17 @@ class InferenceEngine:
                         self._log.info(
                             "fused mixed step fell back to sequential "
                             "dispatches: %s", why)
+        if self.slot_prefill:
+            if (self.paged is not None and budget == 1
+                    and not self._ready_slots()):
+                # nothing decodes: the chunk program is the step's one
+                # program, and the next (chunk, mixed or decode) takes
+                # the first tokens it samples from the plane
+                self._fly(self._issue_chunk())
+                return True
+            # chunks, then the decode block: two programs, the second
+            # planned on what the first one's reading activates
+            self._drain("two_dispatch")
         progressed = self._advance_prefills(budget) or pre_progress
         with self.steptrace.scope("plan"):
             active = self._ready_slots()
@@ -4641,9 +5156,6 @@ class InferenceEngine:
         if self._try_speculative(active):
             self._update_active_stats()
             return True
-        with self.steptrace.scope("index_build"):
-            # the step's sampling key: a small eager device program
-            self.rng, sub = jax.random.split(self.rng)
         with self.steptrace.scope("plan"):
             n = self._plan_block(active)
             use_multi = (
@@ -4656,30 +5168,36 @@ class InferenceEngine:
                 and all(self.slot_len[s] + n <= self.cache_len
                         for s in active)
             )
+            if not use_multi:
+                n = 1
+        if self.paged is not None:
+            with self.steptrace.scope("admit"):
+                if not self._reserve_ahead(active, n):
+                    return _REPLAN
+                active = self._paged_reserve_active(active, n)
+            if not active:
+                return True  # reservation finished/preempted them all
+        with self.steptrace.scope("index_build"):
+            # the step's sampling key: a small eager device program
+            self.rng, sub = jax.random.split(self.rng)
+        if self.paged is not None:
+            self._decode_paged(active, n, sub)
+            return True
         if use_multi:
-            if self.paged is not None:
-                with self.steptrace.scope("admit"):
-                    active = self._paged_reserve_active(active, n)
-                if not active:
-                    return True  # reservation finished/preempted them all
             with self.steptrace.scope("index_build"):
                 lora = self._lora_args()
                 kw = {} if lora is None else {"lora": lora}
             with self.steptrace.scope("dispatch_wait"):
                 self.steptrace.window_begin("decode")
-                if self.paged is not None:
-                    toks = self._paged_decode_dispatch(active, n, sub,
-                                                       lora=lora)
-                else:
-                    fn = (self._decode_multi if lora is None
-                          else self._decode_multi_lora)
-                    toks, self.cache = fn(
-                        self.params, self.cache,
-                        jnp.asarray(self.slot_last_token),
-                        sub,
-                        *self._sampling_args(active),
-                        n=n, **kw,
-                    )
+                fn = (self._decode_multi if lora is None
+                      else self._decode_multi_lora)
+                toks, self.cache = fn(
+                    self.params, self.cache,
+                    jnp.asarray(self.slot_last_token),
+                    sub,
+                    *self._sampling_args(active),
+                    n=n, **kw,
+                )
                 self.steptrace.window_issued()
                 toks_host = np.asarray(toks)
                 dt, _ = self._window_close(
@@ -4693,11 +5211,6 @@ class InferenceEngine:
                 self._commit_block(active, toks_host, n)
             self._update_active_stats()
             return True
-        if self.paged is not None:
-            with self.steptrace.scope("admit"):
-                active = self._paged_reserve_active(active, 1)
-            if not active:
-                return True
         # constrained decoding: per-slot grammar mask rows, applied by
         # the masked twin program in the SAME single dispatch
         with self.steptrace.scope("index_build"):
@@ -4706,12 +5219,7 @@ class InferenceEngine:
             kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
             self.steptrace.window_begin("decode")
-            if self.paged is not None:
-                next_tok = self._paged_decode_dispatch(active, 1, sub,
-                                                       gmask=gmask,
-                                                       lora=lora)
-                next_tok = next_tok[:, 0]
-            elif gmask is not None:
+            if gmask is not None:
                 fn = (self._decode_masked if lora is None
                       else self._decode_masked_lora)
                 next_tok, self.cache = fn(

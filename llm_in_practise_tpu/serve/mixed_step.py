@@ -56,7 +56,11 @@ number of chunking rows, not ``max_slots``, and no decode row
 receives a chunk write: idle and mid-prefill rows' decode garbage
 goes to the trash page through the host-built scatter indices. The
 functions built by :func:`make_mixed_step` serve the contiguous
-layout alone.
+layout alone. The paged programs also take and return the device's
+last-token plane (PR 40): part (b) decodes from it, part (a) puts the
+first tokens it samples into it, so the engine can issue the step
+after a fused one (and the fused step after a decode) before it has
+read either's tokens (``engine._fly``).
 
 What a prefill body returns (PR 32). Every body stops the forward at
 the final norm and takes each row's state at its last real position

@@ -114,7 +114,6 @@ class StepStats:
         # "last_logits": {slot: (vocab,)} of the prompts a chunk or mixed
         # program finished}. None: nothing kept.
         self.capture = None
-        self._pending: list[tuple] = []
 
     @staticmethod
     def check_engine(engine, who: str = "latent / routed model") -> None:
@@ -224,49 +223,59 @@ class StepStats:
                           prefill_band_keys_read=band_keys)
         self._count(**counts)
 
-    def pend(self, kind: str, stats, last=None, finishing=()) -> None:
-        """Keep a program's statistics output (device arrays) until the
-        step's end; ``last`` / ``finishing``: a chunk or mixed program's
-        last-position logits and the (slot, request) pairs it finished,
-        read only under ``capture``."""
+    def pend(self, kind: str, stats, last=None, finishing=()):
+        """A program's statistics output (device arrays) as issued, for
+        :meth:`book` once the program is read; ``last`` / ``finishing``:
+        a chunk or mixed program's last-position logits and the (slot,
+        request) pairs it finished, read only under ``capture``. The
+        requests named are those that take part in the program, as the
+        slots stand at ISSUE: a row at its deterministic end, or one whose
+        stream ended while a later program still ran it, is left out."""
         if not stats:
-            return      # a program without the output (a masked twin)
+            return None     # a program without the output (a masked twin)
         kept = None
         if self.capture is not None:
+            eng = self.eng
             # a prompt admitted through the chunk program holds its slot
             # only once it is activated: ``finishing`` names it
             kept = (last, [slot for slot, _ in finishing],
-                    {**{s: r.uid for s, r in enumerate(self.eng.slot_req)
-                        if r is not None},
+                    {**{s: r.uid for s, r in enumerate(eng.slot_req)
+                        if r is not None and eng.slot_closing[s] is None
+                        and s not in eng._zombies},
                      **{slot: req.uid for slot, req in finishing}})
-        self._pending.append((kind, stats, kept))
+        return kind, stats, kept
 
-    def book(self) -> None:
-        """End of step: fetch what its programs counted (they have
-        completed: the step fetched their tokens) and book it."""
-        if not self._pending:
+    @staticmethod
+    def counted(pended):
+        """What :meth:`book` wants fetched of ``pended`` (the caller's
+        one fetch takes it along with the program's tokens)."""
+        return None if pended is None else pended[1]
+
+    def book(self, pended, parts) -> None:
+        """``pended``'s program has been read, and ``parts`` is what it
+        counted, on the host: book it, in the locked step that emits
+        the program's tokens."""
+        if pended is None:
             return
-        pending, self._pending = self._pending, []
-        fetched = jax.device_get([p[1] for p in pending])   # graftlint: disable=host-sync
-        for (kind, _, kept), parts in zip(pending, fetched):
-            # parts: one per trunk the program ran (a mixed program's
-            # chunk rows, then its decode half), each a list of the
-            # routed layers' entries
-            loads = np.sum([layer[LOAD_KEY] for part in parts
-                            for layer in part], axis=0)
-            self.load.book(*(int(v) for v in loads))
-            self.eng.steptrace.note_extra(
-                moe_layer_passes=int(loads[0]),
-                moe_assignments_held=int(loads[1]),
-                moe_experts_touched=int(loads[2]),
-                moe_max_expert_load=int(loads[3]))
-            if kept is not None and self.capture is not None:
-                last, slots, uids = kept
-                self.capture.append({
-                    "kind": kind, "uids": uids,
-                    "route": [np.stack([layer[ROUTE_KEY] for layer in part])
-                              for part in parts],
-                    # reference comparisons only
-                    "last_logits": {
-                        s: np.asarray(last[s])  # graftlint: disable=host-sync
-                        for s in slots}})
+        kind, _, kept = pended
+        # parts: one per trunk the program ran (a mixed program's chunk
+        # rows, then its decode half), each a list of the routed layers'
+        # entries
+        loads = np.sum([layer[LOAD_KEY] for part in parts
+                        for layer in part], axis=0)
+        self.load.book(*(int(v) for v in loads))
+        self.eng.steptrace.note_extra(
+            moe_layer_passes=int(loads[0]),
+            moe_assignments_held=int(loads[1]),
+            moe_experts_touched=int(loads[2]),
+            moe_max_expert_load=int(loads[3]))
+        if kept is not None and self.capture is not None:
+            last, slots, uids = kept
+            self.capture.append({
+                "kind": kind, "uids": uids,
+                "route": [np.stack([layer[ROUTE_KEY] for layer in part])
+                          for part in parts],
+                # reference comparisons only
+                "last_logits": {
+                    s: np.asarray(last[s])  # graftlint: disable=host-sync
+                    for s in slots}})

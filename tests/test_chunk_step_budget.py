@@ -58,8 +58,12 @@ def burst(model_params, monkeypatch, layout, budget, decoder):
         eng.step()
     first = len(reqs)
     reqs += [eng.submit(p, greedy) for p in PROMPTS]
-    order, step = {}, 0
-    while eng.step():
+    # a paged program is read in the step AFTER the one that issued it
+    # (the engine runs one dispatch ahead, PR 40): the step that gave a
+    # first token is the one before the step that saw it
+    order, step, busy = {}, 0 if layout == "contiguous" else -1, True
+    while busy:
+        busy = eng.step()       # (the last call reads the last program)
         step += 1
         for i, r in enumerate(reqs[first:]):
             if r.first_token_time is not None:

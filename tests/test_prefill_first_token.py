@@ -383,18 +383,22 @@ def test_finalisation_dispatches_nothing_on_the_program_path(
     eng = engine_of(world, layout, decode_steps=4, **CHUNKED)
     mixed_load(eng, False)                 # compile everything first
     spent = []
-    inner = eng._finalize_prefills
+    # where a finished prompt is finalised: the read of the paged program
+    # that ended it (``_retire``: its first token is the program's), the
+    # host fallback of the contiguous layout (``_finalize_prefills``)
+    name = "_retire" if layout == "paged" else "_finalize_prefills"
+    inner = getattr(eng, name)
 
-    def metered(first=None):
+    def metered(*flight):
         before = (eng.dispatch_meter.total, eng.compile_meter.compile_events)
-        finishing = sum(st["done"] >= st["plen"]
-                        for st in eng.slot_prefill.values())
-        inner(first)
+        finishing = (len(flight[0].finished) if flight else sum(
+            st["done"] >= st["plen"] for st in eng.slot_prefill.values()))
+        inner(*flight)
         spent.append((finishing,
                       eng.dispatch_meter.total - before[0],
                       eng.compile_meter.compile_events - before[1]))
 
-    eng._finalize_prefills = metered
+    setattr(eng, name, metered)
     mixed_load(eng, False)
     assert sum(f for f, _, _ in spent) == 1
     for finishing, dispatches, compiles in spent:

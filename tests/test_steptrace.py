@@ -313,6 +313,159 @@ def test_annotations_follow_the_edges(clock, annotations):
     assert len(annotations.opened) == n and dt >= issue >= 0.0
 
 
+def test_two_programs_in_flight_windows_tile(clock):
+    """The engine issues step n+1 before it reads step n (PR 40): a
+    window opens before the one before it has closed. The lane tiles
+    (issue:<new> while a dispatch is prepared, else wait:<oldest>), a
+    program left unread covers the next step from its first instant,
+    issue + wait = device <= wall, the scopes count only what no unread
+    program covered, and a window comes back from the later of its begin
+    and the previous window's end."""
+    st = StepTrace(enabled=True)
+    # step 1: nothing in flight; program A is issued and left unread
+    st.step_begin()
+    with st.scope("admit"):
+        clock.tick(0.002)
+    with st.scope("dispatch_wait"):
+        st.window_begin("decode")             # A
+        clock.tick(0.003)
+        st.window_issued()
+        clock.tick(0.001)
+    st.note_drain("idle")
+    r1 = st.step_end()
+    assert (r1["ahead"], r1["drain"]) == (False, "idle")
+    assert r1["issue_s"] == pytest.approx(0.003)
+    assert r1["wait_s"] == pytest.approx(0.001)   # clipped to the step
+    assert r1["device_s"] == pytest.approx(0.004)
+    assert r1["device_s"] <= r1["wall_s"]
+    assert r1["activities"]["admit"] == pytest.approx(0.002)
+    assert r1["activities"]["dispatch_wait"] == pytest.approx(0.0)
+    clock.tick(0.0005)                        # between steps: no record's
+    # step 2: A unread from the first instant; B issued, then A read
+    st.step_begin()
+    with st.scope("admit"):
+        clock.tick(0.002)                     # hidden by A
+    with st.scope("dispatch_wait"):
+        st.window_begin("mixed")              # B
+        st.note_ahead()
+        clock.tick(0.004)
+        st.window_issued()
+    with st.scope("dispatch_wait"):
+        clock.tick(0.010)                     # the fetch of A
+        dt_a, issue_a = st.window_end()
+    with st.scope("sample_commit"):
+        clock.tick(0.003)                     # hidden by B
+    st.note_drain("idle")                     # ahead: no reason is kept
+    st.note_discarded(2)
+    r2 = st.step_end()
+    assert (r2["ahead"], r2["drain"]) == (True, None)
+    assert r2["tokens_discarded"] == 2 and r2["dispatches"] == 1
+    # A's window: begin 0.002 into step 1 .. its read, 0.016 into step 2
+    assert dt_a == pytest.approx(0.004 + 0.0005 + 0.016)
+    assert issue_a == pytest.approx(0.003)
+    assert r2["device_s"] == pytest.approx(r2["wall_s"])
+    assert r2["wall_s"] == pytest.approx(0.019)
+    assert r2["issue_s"] == pytest.approx(0.004)
+    assert r2["issue_s"] + r2["wait_s"] == pytest.approx(r2["device_s"])
+    assert sum(r2["activities"].values()) == pytest.approx(0.0)
+    lane = [(n, t1 - t0) for n, t0, t1 in r2["segments"]
+            if n.startswith(("issue:", "wait:"))]
+    assert [n for n, _ in lane] == ["wait:decode", "issue:mixed",
+                                    "wait:decode", "wait:mixed"]
+    assert [d for _, d in lane] == pytest.approx(
+        [0.002, 0.004, 0.010, 0.003])
+    spans = sorted((t0, t1) for n, t0, t1 in r2["segments"]
+                   if n.startswith(("issue:", "wait:")))
+    assert all(b[0] == pytest.approx(a[1]) for a, b in zip(spans, spans[1:]))
+    # step 3: nothing to issue; B is read: booked from A's read on, its
+    # issue part (all before that) is no part of it
+    st.step_begin()
+    with st.scope("dispatch_wait"):
+        clock.tick(0.006)
+        dt_b, issue_b = st.window_end()
+    with st.scope("sample_commit"):
+        clock.tick(0.002)                     # nothing in flight: host
+    st.note_drain("idle")
+    r3 = st.step_end()
+    assert dt_b == pytest.approx(0.003 + 0.006) and issue_b == 0.0
+    assert r3["device_s"] == pytest.approx(0.006)
+    assert r3["activities"]["sample_commit"] == pytest.approx(0.002)
+    assert r3["dispatches"] == 0 and r3["drain"] == "idle"
+    snap = st.snapshot()
+    assert snap["steps_ahead"] == 1
+    assert snap["step_drains"] == {"idle": 2}
+    assert snap["tokens_discarded"] == 2
+    assert snap["device_seconds_total"] == pytest.approx(0.029)
+    assert snap["device_seconds_total"] <= snap["step_wall_seconds_total"]
+
+
+def test_in_flight_annotations_follow_the_lane(clock, annotations):
+    st = StepTrace(enabled=True)
+    st.step_begin()
+    st.window_begin("decode")
+    st.window_issued()
+    st.step_end()
+    assert annotations.open_now == []         # the lane ends with the step
+    st.step_begin()
+    assert annotations.open_now[-1] == "engine:wait:decode"
+    st.window_begin("decode")
+    assert annotations.open_now[-1] == "engine:issue:decode"
+    st.window_issued()
+    st.window_end()
+    assert annotations.open_now[-1] == "engine:wait:decode"
+    st.window_end()
+    assert annotations.open_now == ["engine_step"]
+    st.step_end()
+    assert annotations.open_now == []
+
+
+def test_lookahead_records_and_request_windows_live(model_params):
+    """A paged engine under mixed load: steps run ahead, every record
+    says ``ahead`` or why not and keeps issue + wait = device <= wall, a
+    request's windows tile (host_gap >= 0, segments + residual = wall),
+    and the three families strict-parse on /metrics."""
+    from llm_in_practise_tpu.serve.api import OpenAIServer
+    from llm_in_practise_tpu.serve.engine import DRAIN_REASONS
+
+    model, params = model_params
+    eng = _engine(model, params, kv_layout="paged")
+    handles = _run_mixed_load(eng)
+    recs = eng.steptrace.records()
+    assert sum(r["ahead"] for r in recs) >= len(recs) // 2
+    for r in recs:
+        assert r["ahead"] != (r["drain"] is not None)
+        assert r["issue_s"] + r["wait_s"] == pytest.approx(r["device_s"])
+        assert r["device_s"] <= r["wall_s"] + 1e-9
+        assert sum(r["activities"].values()) + r["device_s"] == \
+            pytest.approx(r["wall_s"], abs=1e-6)
+    for req in eng.finished:
+        wall = req.finish_time - req.submit_time
+        parts = sum(v for k, v in req.cp.items() if k not in CP_OVERLAYS)
+        assert req.cp["host_gap"] >= 0.0
+        assert parts == pytest.approx(wall, abs=1e-6)
+    assert handles
+
+    class _Tok:
+        def encode(self, t):
+            return [b % 64 for b in t.encode()][:32]
+
+        def decode(self, ids):
+            return " ".join(map(str, ids))
+
+    fams = parse_exposition(
+        OpenAIServer(eng, _Tok(), model_name="ahead").metrics_text())
+    snap = eng.steptrace.snapshot()
+    assert next(iter(fams["llm_steps_ahead_total"].samples.values())) \
+        == snap["steps_ahead"] > 0
+    drains = {dict(k[1])["reason"]: v
+              for k, v in fams["llm_step_drains_total"].samples.items()}
+    assert set(drains) == set(DRAIN_REASONS)
+    assert drains["oneshot_prefill"] >= 1
+    assert snap["steps_ahead"] + sum(drains.values()) == snap["steps"]
+    assert next(iter(
+        fams["llm_tokens_discarded_total"].samples.values())) == 0
+
+
 # --- live engine integration -------------------------------------------------
 
 
