@@ -31,11 +31,11 @@ _CKPT_RE = re.compile(r"^(?P<prefix>.+)_(?P<step>\d{8})\.msgpack$")
 
 def _host_pytree(tree):
     """Bring a (possibly sharded) pytree fully addressable on host."""
-    def fetch(x):
+    def to_host(x):
         if isinstance(x, jax.Array):
             return np.asarray(jax.device_get(x))
         return x
-    return jax.tree_util.tree_map(fetch, tree)
+    return jax.tree_util.tree_map(to_host, tree)
 
 
 def save_checkpoint(

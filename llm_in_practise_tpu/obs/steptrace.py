@@ -48,6 +48,20 @@ on Python" from a hope into a gated, regression-tested quantity:
   ``engine:<activity>``, ``engine:issue:<phase>``,
   ``engine:wait:<phase>``), so any profile holds them on the
   profiler's own clock above the device ops.
+- **By thread state** (the partition no cover bends): ``step_begin`` /
+  ``step_end`` read ``time.thread_time()`` beside the wall clock, and the
+  engine puts :meth:`fetch` around every call in which it FORCES a device
+  value (``jax.device_get``, ``block_until_ready``, ``np.asarray`` of a
+  device array). A record's ``wall_s`` is ``cpu_s`` (the engine thread's
+  CPU seconds outside fetches: host work, covered or not) + ``blocked_s``
+  (wall inside fetches: waiting for the device; ``blocked_s <= wait_s``)
+  + ``stalled_s`` (neither: waiting to get the GIL back from a handler
+  thread, descheduled, asleep in a lock). A fetch is a segment
+  ``fetch:<phase of the oldest unread window>`` inside that window's
+  ``wait:`` lane segment and an ``engine:fetch:<phase>`` annotation: in a
+  profile, an idle gap that ends where a fetch begins is the host
+  arriving late, one inside a fetch is the device's own. ``read_seq``
+  names the step that issued the program read.
 - **Scopes nest**: entering an inner scope pauses the enclosing one, so
   ``index_build`` inside ``admit`` is attributed once, not twice.
   The window lane's time is no scope's (the ``dispatch_wait`` leftover
@@ -147,6 +161,43 @@ class _Scope:
         return False
 
 
+class _Fetch:
+    """Reusable context manager around a call that forces a device
+    value (see :meth:`StepTrace.fetch`). Allocated once a recorder;
+    engine thread only, never nested."""
+
+    __slots__ = ("_st", "_t0", "_cpu0", "_name", "_ann")
+
+    def __init__(self, st: "StepTrace"):
+        self._st = st
+        self._ann = None
+
+    def __enter__(self):
+        st = self._st
+        if st._recording:
+            phase, _, _, seq = st._open[0] if st._open else ("none",) * 4
+            self._name = "fetch:" + phase
+            if st._read_seq is None and st._open:
+                st._read_seq = seq
+            # (an annotation under way is what says "recording" at exit)
+            self._ann = TraceAnnotation("engine:" + self._name)
+        self._t0 = time.perf_counter()
+        self._cpu0 = time.thread_time()
+        return self
+
+    def __exit__(self, *exc):
+        cpu = time.thread_time() - self._cpu0
+        now = time.perf_counter()
+        if self._ann is not None:
+            _close(self._ann)
+            self._ann = None
+            st = self._st
+            st._blocked_s += now - self._t0
+            st._fetch_cpu_s += cpu
+            st._segment(self._name, self._t0, now)
+        return False
+
+
 class _NoopScope:
     __slots__ = ()
 
@@ -170,7 +221,7 @@ class StepTrace:
     scrape threads read without locks.
     """
 
-    def __init__(self, capacity: int = 2048, *, enabled: bool | None = None,
+    def __init__(self, capacity: int = 4096, *, enabled: bool | None = None,
                  window: int = 50):
         self.enabled = _enabled_from_env() if enabled is None else enabled
         self.capacity = capacity
@@ -178,6 +229,7 @@ class StepTrace:
         self._lock = threading.Lock()
         # --- engine-thread state (single writer, no lock) ---
         self._scopes = {name: _Scope(self, name) for name in ACTIVITIES}
+        self._fetch = _Fetch(self)
         # [name, last reading of the uncovered clock, acc, enter_perf,
         # annotation]
         self._stack: list[list] = []
@@ -189,6 +241,14 @@ class StepTrace:
         self._issue_s = 0.0
         self._dispatches = 0
         self._segments: list[tuple] = []   # (name, t0, t1) on perf_counter
+        # the thread-state partition: thread_time at the step's begin,
+        # wall and CPU seconds inside fetches, the step that issued the
+        # program read first
+        self._step_cpu0 = 0.0
+        self._cpu_owed_s = 0.0
+        self._blocked_s = 0.0
+        self._fetch_cpu_s = 0.0
+        self._read_seq: int | None = None
         self._lock_wait_s = 0.0
         self._gap_before_s = 0.0
         self._chunk_rows = 0
@@ -201,9 +261,9 @@ class StepTrace:
         self._drain: str | None = None
         self._discarded = 0
         self._last_end: float | None = None   # previous step_end, perf
-        # the open dispatch windows, oldest first: [phase, t0, issued]
-        # (issued None: its dispatch is still being prepared), and the
-        # end of the window closed last
+        # the open dispatch windows, oldest first: [phase, t0, issued,
+        # seq of the step that opened it] (issued None: its dispatch is
+        # still being prepared), and the end of the window closed last
         self._open: deque = deque()
         self._win_closed = float("-inf")
         # the window lane's segment under way (``_lane`` None: nothing
@@ -219,6 +279,8 @@ class StepTrace:
         self._step_wall_total = 0.0
         self._device_seconds_total = 0.0
         self._issue_seconds_total = 0.0
+        self._thread_seconds = {"cpu": 0.0, "device_wait": 0.0,
+                                "stalled": 0.0}
         self._sampler_steps: dict[str, int] = {}
         self._steps_ahead = 0
         self._step_drains: dict[str, int] = {}
@@ -308,8 +370,10 @@ class StepTrace:
         dispatch. Always stamps (the engine needs the durations for its
         own books); records and annotates only inside a recorded step."""
         now = time.perf_counter()
-        self._open.append([phase, now, None])
-        if self._recording:
+        recording = self._recording
+        self._open.append([phase, now, None,
+                           self._seq + 1 if recording else None])
+        if recording:
             self._dispatches += 1
         self._lane_to(self._lane_now(), now)
 
@@ -326,27 +390,47 @@ class StepTrace:
         ``(window_s, issue_s)`` from the later of the window's begin and
         the previous window's end (windows booked to a request tile)."""
         now = time.perf_counter()
-        _, t0, issued = self._open.popleft()
+        _, t0, issued, _ = self._open.popleft()
         issued = now if issued is None else issued
         t0 = max(t0, self._win_closed)
         self._win_closed = now
         self._lane_to(self._lane_now(), now)
         return now - t0, max(0.0, issued - t0)
 
-    def note_device(self, duration_s: float, phase: str = "dispatch",
-                    issue_s: float = 0.0) -> None:
-        """Book a dispatch window of ``duration_s`` that ended now (its
-        first ``issue_s`` seconds the issue part), outside the lane: the
-        scope it sits in does not count it."""
+    def fetch(self):
+        """``with st.fetch():`` around a call that FORCES a device value
+        between a :meth:`window_issued` and the :meth:`window_end` that
+        follows. Its wall is the record's ``blocked_s`` (whether or not
+        another program is in flight), its span a segment and an
+        annotation named after the OLDEST unread window (the program
+        being read), and the step that issued that program the record's
+        ``read_seq``. Always stamps; records only inside a recorded
+        step; the no-op scope with the recorder off."""
+        return self._fetch if self.enabled else _NOOP_SCOPE
+
+    def thread_states(self) -> tuple[float, float, float, float] | None:
+        """``(wall, cpu, blocked, stalled)`` seconds of the open step so
+        far (not inside a fetch), ``cpu + blocked + stalled == wall``:
+        what the engine thread did as a thread. None outside a recorded
+        step."""
         if not self._recording:
-            return
-        now = time.perf_counter()
-        t0 = now - float(duration_s)
-        self._device_s += now - t0
-        self._issue_s += float(issue_s)
-        self._dispatches += 1
-        self._segment("issue:" + phase, t0, t0 + float(issue_s))
-        self._segment("wait:" + phase, t0 + float(issue_s), now)
+            return None
+        return self._thread_states(time.perf_counter(),
+                                   time.thread_time())[0]
+
+    def _thread_states(self, now: float, cpu_now: float):
+        """The open step's wall so far by state, and the CPU seconds the
+        thread's clock has shown that do not fit this step's wall outside
+        fetches: that clock may tick far coarser than the wall clock (10
+        ms on a TPU v5e host, where a 17 ms step then reads 0 or 10), so
+        what does not fit is owed to the next step, not dropped; a
+        window's SUM is what to read there."""
+        wall = now - self._step_t0
+        blocked = min(self._blocked_s, wall)
+        seen = self._cpu_owed_s + max(
+            0.0, cpu_now - self._step_cpu0 - self._fetch_cpu_s)
+        cpu = min(seen, wall - blocked)
+        return (wall, cpu, blocked, wall - blocked - cpu), seen - cpu
 
     def note_chunk_rows(self, rows: int, row_slots: int) -> None:
         """A chunk dispatch of this step advanced ``rows`` prompts and
@@ -422,7 +506,11 @@ class StepTrace:
             return
         self._step_ann = TraceAnnotation("engine_step")
         self._step_t0 = time.perf_counter()
+        self._step_cpu0 = time.thread_time()
         self._step_wall0 = time.time()
+        self._blocked_s = 0.0
+        self._fetch_cpu_s = 0.0
+        self._read_seq = None
         self._lock_wait_s = float(lock_wait_s)
         self._gap_before_s = (self._step_t0 - self._last_end
                               if self._last_end is not None else 0.0)
@@ -475,8 +563,9 @@ class StepTrace:
             return None
         self._close_open()
         end = time.perf_counter()
+        (wall, cpu, blocked, stalled), self._cpu_owed_s = \
+            self._thread_states(end, time.thread_time())
         _close(self._step_ann)
-        wall = end - self._step_t0
         attributed = sum(self._acts.values()) + self._device_s
         other = max(0.0, wall - attributed)
         self._acts["other"] = self._acts.get("other", 0.0) + other
@@ -490,6 +579,10 @@ class StepTrace:
             "device_s": self._device_s,
             "issue_s": self._issue_s,
             "wait_s": self._device_s - self._issue_s,
+            "cpu_s": cpu,
+            "blocked_s": blocked,
+            "stalled_s": stalled,
+            "read_seq": self._read_seq,
             "lock_wait_s": self._lock_wait_s,
             "gap_before_s": self._gap_before_s,
             "dispatches": self._dispatches,
@@ -518,6 +611,9 @@ class StepTrace:
         self._step_wall_total += wall
         self._device_seconds_total += self._device_s
         self._issue_seconds_total += self._issue_s
+        for state, dt in (("cpu", cpu), ("device_wait", blocked),
+                          ("stalled", stalled)):
+            self._thread_seconds[state] += dt
         if self._sampler_tier is not None:
             self._sampler_steps[self._sampler_tier] = (
                 self._sampler_steps.get(self._sampler_tier, 0) + 1)
@@ -551,6 +647,9 @@ class StepTrace:
             # the dispatch windows' two parts (issue + wait = device)
             "dispatch_issue_seconds_total": self._issue_seconds_total,
             "dispatch_wait_seconds_total": dev - self._issue_seconds_total,
+            # the engine thread's wall by state (cpu + device_wait +
+            # stalled = step_wall_seconds_total), whatever covers it
+            "thread_seconds": dict(self._thread_seconds),
             "host_seconds": dict(self._host_seconds),
             "sampler_steps": dict(self._sampler_steps),
             # one step of lookahead (serve/engine.py): steps whose

@@ -976,6 +976,15 @@ class OpenAIServer:
             lambda: stp.snapshot()["step_wall_seconds_total"],
             "cumulative engine step() wall seconds (non-idle steps)")
         reg.counter_func(
+            "llm_engine_thread_seconds_total",
+            lambda: [({"state": st}, v) for st, v in
+                     sorted(stp.snapshot()["thread_seconds"].items())],
+            "the engine thread's step wall seconds by what it did as a "
+            "thread, covered by a running program or not: cpu (it ran), "
+            "device_wait (blocked fetching a program's results), stalled "
+            "(neither: no GIL, descheduled, asleep in a lock); the three "
+            "sum to llm_step_wall_seconds_total")
+        reg.counter_func(
             "llm_engine_steps_total",
             lambda: stp.snapshot()["steps"],
             "non-idle engine step() iterations recorded")

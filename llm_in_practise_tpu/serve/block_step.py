@@ -366,10 +366,11 @@ class BlockDecoder:
             new_tok, new_rev, experts, logits, eng.paged.kv = out
             st.window_issued()
             # the pass's result: the one fetch the step blocks on
-            new_tok, new_rev, experts = jax.device_get(  # graftlint: disable=host-sync
-                (new_tok, new_rev, experts))
-            if capture:     # reference comparisons only
-                logits = np.asarray(logits)  # graftlint: disable=host-sync
+            with st.fetch():
+                new_tok, new_rev, experts = jax.device_get(  # graftlint: disable=host-sync
+                    (new_tok, new_rev, experts))
+                if capture:     # reference comparisons only
+                    logits = np.asarray(logits)  # graftlint: disable=host-sync
             dt, _ = eng._window_close(
                 "decode", [eng.slot_req[s] for s in active])
             eng.dispatch_meter.note_phase(

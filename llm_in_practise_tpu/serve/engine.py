@@ -154,14 +154,24 @@ DRAIN_REASONS = ("idle", "contiguous", "block", "grammar", "speculative",
 # (HTTP body read → the request entering ``submit``: JSON, chat
 # template, BPE; before the wall clock starts) and ``api_first_flush``
 # (first token on the engine thread → first SSE event handed to the
-# socket).
+# socket), and the engine thread's time BY STATE while the request held
+# a slot: ``engine_wall`` (the wall of every recorded step it held a
+# slot in; of the step it finished in, the part before its finish) =
+# ``engine_cpu`` (the thread ran: host work, covered by a running
+# program or not) + ``engine_blocked`` (it waited for the device inside
+# ``StepTrace.fetch``) + ``engine_stalled`` (neither: the GIL was a
+# handler thread's, the thread was descheduled or slept in a lock).
+# All four are written together, a 0.0 included.
+CP_THREAD_STATES = ("engine_wall", "engine_cpu", "engine_blocked",
+                    "engine_stalled")
 CP_SEGMENTS = ("queue_wait", "admission", "prefill_dispatch",
                "decode_dispatch", "prefill_stall", "decode_interleave",
                "host_gap", "handoff_wire", "preempt_recompute",
                "stream_flush", "dispatch_issue", "api_pre_submit",
-               "api_first_flush")
+               "api_first_flush", *CP_THREAD_STATES)
 CP_OVERLAYS = frozenset(("stream_flush", "dispatch_issue",
-                         "api_pre_submit", "api_first_flush"))
+                         "api_pre_submit", "api_first_flush",
+                         *CP_THREAD_STATES))
 # re-admission after a page-pool preemption re-pays these segments; the
 # re-pay is charged to preempt_recompute so a preempted request's
 # breakdown says "recompute", not "a second mysterious prefill"
@@ -308,6 +318,13 @@ class Request:
         if self.requeue_time is not None and seg in _CP_RECOMPUTE_SEGS:
             seg = "preempt_recompute"
         self.cp[seg] = self.cp.get(seg, 0.0) + float(dt)
+
+    def cp_thread_states(self, states) -> None:
+        """Book ``(wall, cpu, blocked, stalled)`` seconds of an engine
+        step this request held a slot in (CP_THREAD_STATES)."""
+        cp = self.cp
+        for key, dt in zip(CP_THREAD_STATES, states):
+            cp[key] = cp.get(key, 0.0) + dt
 
     def cp_window(self, seg: str, dt: float, issue_s: float) -> None:
         """Book one dispatch window this request sat through (see
@@ -687,6 +704,11 @@ class InferenceEngine:
         # their slot and pages go when that program is read
         self.slot_closing: list[str | None] = [None] * max_slots
         self._zombies: set[int] = set()
+        # who held a slot at the open step's begin, by uid: the step's
+        # end books its thread states to them and to the holders then
+        # (CP_THREAD_STATES); one that finishes on the way is booked
+        # there and leaves
+        self._step_held: dict[int, Request] = {}
         # why this step does not run ahead (None: nothing forbids it),
         # and the reason the previous step left for this one
         self._step_why: str | None = None
@@ -2884,12 +2906,13 @@ class InferenceEngine:
                         first = self._first_tokens(
                             [r for _, r, _ in part], last)
                     self.steptrace.window_issued()
-                    if first is None:
-                        # nothing is sampled from a block-diffusion
-                        # prefill: the first block opens all-mask
-                        jax.block_until_ready(last)
-                    else:
-                        first = np.asarray(first)   # forces the chain
+                    with self.steptrace.fetch():
+                        if first is None:
+                            # nothing is sampled from a block-diffusion
+                            # prefill: the first block opens all-mask
+                            jax.block_until_ready(last)
+                        else:
+                            first = np.asarray(first)   # forces the chain
                     # every member waited the whole batched dispatch
                     dt, _ = self._window_close(
                         "prefill", [r for _, r, _ in part])
@@ -2982,6 +3005,7 @@ class InferenceEngine:
                     for _ in range(self._n_publishers)]
                 for t in self._publishers:
                     t.start()
+            self._book_thread_states(req)
             self._publish_queue.put((req, plen, entry))
 
     def _run_publisher(self) -> None:
@@ -3501,8 +3525,9 @@ class InferenceEngine:
             self.steptrace.window_issued()
             # force before the window closes, exactly like
             # _prefill_into_slot
-            first, parts = jax.device_get(
-                (first, stats and stats.counted(pended)))
+            with self.steptrace.fetch():
+                first, parts = jax.device_get(
+                    (first, stats and stats.counted(pended)))
             dt, _ = self._window_close("prefill", (req,))
             if stats is not None:
                 stats.book(pended, parts)
@@ -3695,7 +3720,8 @@ class InferenceEngine:
                 # paths force every dispatch the same way). KV writes
                 # land in the same program, so this waits only for work
                 # the next chunk depends on anyway.
-                jax.block_until_ready(last)
+                with self.steptrace.fetch():
+                    jax.block_until_ready(last)
                 # every mid-prefill request waited the whole dispatch
                 dt, issue_s = self._window_close(
                     "prefill", [st["req"] for _, st, _ in entries])
@@ -4014,7 +4040,8 @@ class InferenceEngine:
         # boundary as the chunked/fused paths (async-backend honesty —
         # see _advance_prefills); the logits feed the first-token
         # sample on this same call path anyway
-        jax.block_until_ready(last_logits)
+        with self.steptrace.fetch():
+            jax.block_until_ready(last_logits)
         dt, _ = self._window_close("prefill", (req,))
         keys = CostModel.chunk_keys(new, start)
         self._note_device_phase(
@@ -4056,6 +4083,7 @@ class InferenceEngine:
         held = self._ahead is not None and self._ahead.decodes(slot)
         if not held:
             self._release_pages(slot, req)
+        self._book_thread_states(req)
         # breakdown finalized BEFORE _FINISH is released: a consumer
         # that saw the stream end must find the request in the
         # /debug/requests ring (same ordering rule as the decode span)
@@ -4068,6 +4096,16 @@ class InferenceEngine:
             self._zombies.add(slot)
         else:
             self._clear_slot(slot)
+
+    def _book_thread_states(self, req: Request) -> None:
+        """``req`` leaves the engine thread's hands inside this step (it
+        finishes, or goes to the publisher): book the step's thread
+        states SO FAR to it now, before its breakdown is final, and not
+        again at the step's end."""
+        states = self.steptrace.thread_states()
+        if states is not None:
+            req.cp_thread_states(states)
+            self._step_held.pop(req.uid, None)
 
     def _clear_slot(self, slot: int) -> None:
         """``slot`` is free: nothing of its request is left in it."""
@@ -4338,9 +4376,10 @@ class InferenceEngine:
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(base), jnp.asarray(mask), m=m, **kw)
             self.steptrace.window_issued()
-            out_host = np.asarray(out)
-            acc_host = np.asarray(n_acc)
-            extra_host = np.asarray(extra)
+            with self.steptrace.fetch():
+                out_host = np.asarray(out)
+                acc_host = np.asarray(n_acc)
+                extra_host = np.asarray(extra)
             dt, _ = self._window_close(
                 "decode", [self.slot_req[s] for s in active])
             # the verify is ONE wide forward over k+1 positions per slot
@@ -4707,7 +4746,8 @@ class InferenceEngine:
                 jnp.asarray(advance), *sampling, n=n, **kw)
             self.steptrace.window_issued()
             # ONE fetch forces the dispatch's results
-            toks_host = np.asarray(toks)
+            with self.steptrace.fetch():
+                toks_host = np.asarray(toks)
             # the window advanced the mid-prefill rows' prompts; every
             # decode member sat through the whole fused dispatch for
             # them (prefill_stall, not decode_dispatch)
@@ -4895,8 +4935,9 @@ class InferenceEngine:
         chunked = [st["req"] for _, st, _ in f.entries]
         with self.steptrace.scope("dispatch_wait"):
             stats = self.step_stats
-            first, toks, parts = jax.device_get(
-                (f.first, f.toks, stats and stats.counted(f.stats)))
+            with self.steptrace.fetch():
+                first, toks, parts = jax.device_get(
+                    (f.first, f.toks, stats and stats.counted(f.stats)))
             # a window that advanced prompts is theirs (every decode
             # member sat through it for them: prefill_stall), else the
             # decode rows'
@@ -4988,6 +5029,10 @@ class InferenceEngine:
             # lies before the record and is a field of it
             self.steptrace.step_begin(
                 lock_wait_s=time.perf_counter() - t_lock)
+            if self.steptrace.enabled:
+                self._step_held = {
+                    r.uid: r for r in self.slot_req
+                    if r is not None and r.finish_time is None}
             busy = False
             try:
                 busy = self._step_locked()
@@ -5002,9 +5047,24 @@ class InferenceEngine:
                 if busy or spent or self._read:
                     with self.steptrace.scope("sample_commit"):
                         self.dispatch_meter.note_step(spent)
-                    self.steptrace.step_end(self.tracer)
+                    self._book_step(self.steptrace.step_end(self.tracer))
                 else:
                     self.steptrace.step_abort()
+
+    def _book_step(self, rec: dict | None) -> None:
+        """The step's record is closed: its wall by thread state goes to
+        every request that held a slot during the step and is still the
+        engine's (CP_THREAD_STATES)."""
+        if rec is None:
+            return
+        held = self._step_held
+        for r in self.slot_req:
+            if r is not None and r.finish_time is None:
+                held[r.uid] = r
+        states = (rec["wall_s"], rec["cpu_s"], rec["blocked_s"],
+                  rec["stalled_s"])
+        for r in held.values():
+            r.cp_thread_states(states)
 
     def _step_locked(self) -> bool:
         self._step_why, self._next_why = self._next_why, None
@@ -5028,7 +5088,8 @@ class InferenceEngine:
 
     def _plan_step(self):
         budget = self.prefill_budget
-        why = self._ahead_blocker()
+        with self.steptrace.scope("plan"):
+            why = self._ahead_blocker()
         if why is not None:
             self._drain(why)
         if self.block is not None:
@@ -5199,7 +5260,8 @@ class InferenceEngine:
                     n=n, **kw,
                 )
                 self.steptrace.window_issued()
-                toks_host = np.asarray(toks)
+                with self.steptrace.fetch():
+                    toks_host = np.asarray(toks)
                 dt, _ = self._window_close(
                     "decode", [self.slot_req[s] for s in active])
                 keys = sum(CostModel.block_keys(n, int(self.slot_len[s]))
@@ -5239,7 +5301,8 @@ class InferenceEngine:
                     **kw,
                 )
             self.steptrace.window_issued()
-            next_host = np.asarray(next_tok)
+            with self.steptrace.fetch():
+                next_host = np.asarray(next_tok)
             dt, _ = self._window_close(
                 "decode", [self.slot_req[s] for s in active])
             keys = sum(CostModel.block_keys(1, int(self.slot_len[s]))

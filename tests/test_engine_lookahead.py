@@ -46,6 +46,11 @@ from llm_in_practise_tpu.serve.engine import (
     InferenceEngine,
     SamplingParams,
 )
+from tests.thread_state_checks import (
+    check_records,
+    check_requests,
+    spy_window_closes,
+)
 
 OPTS = dict(max_slots=4, cache_len=128, kv_layout="paged",
             chunked_prefill=16, cache_dtype=jnp.float32)
@@ -127,6 +132,7 @@ def both_ways(eng, run):
     patched to "never". Returns each pass's outcome and what the recorder
     and the compile meter read for it."""
     passes = {}
+    closed = spy_window_closes(eng)
     for name in ("ahead", "serial"):
         if name == "serial":
             eng._ahead_blocker = lambda: "never"
@@ -147,7 +153,7 @@ def both_ways(eng, run):
             discarded=(after["tokens_discarded"]
                        - before["tokens_discarded"]),
             compiles=eng.compile_meter.compile_events - compiles,
-            finished=list(eng.finished)[done:])
+            finished=list(eng.finished)[done:], closed=closed)
     return passes
 
 
@@ -234,6 +240,36 @@ def test_request_windows_tile(pair):
         assert booked <= wall + 1e-6
         assert req.cp["host_gap"] >= 0.0
         assert req.cp.get("dispatch_issue", 0.0) <= booked + 1e-9
+
+
+def test_thread_states_partition_every_step_and_every_request(pair):
+    """PR 41: a record's wall by what the engine thread did as a thread
+    (cpu + blocked + stalled = wall, whatever a running program covers),
+    one ``fetch:`` segment a window closed, in the record that read it
+    and inside that window's ``wait:`` lane segment; a record that ran
+    ahead reads the program the step BEFORE it issued; every finished
+    request carries the four ``engine_*`` overlays, they sum,
+    and ``host_gap`` is the parent's residual."""
+    _, passes = pair
+    for name, run in passes.items():
+        check_records(run["records"], run["closed"])
+        check_requests(run["finished"])
+        assert len(run["finished"]) == 5
+        read = [r for r in run["records"] if r["read_seq"] is not None]
+        assert sum(r["dispatches"] for r in run["records"]) == sum(
+            n.startswith("fetch:") for r in run["records"]
+            for n, _, _ in r["segments"])
+        if name == "ahead":
+            # (the serial engine reads at its next step's begin too, but
+            # before it issues: under no other program)
+            behind = [r for r in read if r["read_seq"] == r["seq"] - 1]
+            assert len(behind) >= 0.6 * len(read)
+            assert sum(r["ahead"] for r in behind) >= 0.6 * len(read)
+    # the time of a request's steps is the time of the records it held a
+    # slot in: no more than the records' in all
+    run = passes["ahead"]
+    assert sum(r.cp["engine_wall"] for r in run["finished"]) <= (
+        4 * sum(r["wall_s"] for r in run["records"]) + 1e-6)
 
 
 # -------------------------------------------------- EOS with a program unread

@@ -469,6 +469,40 @@ def test_counters_add_up(model, params):
     assert all(r.cp.get("decode_dispatch", 0.0) > 0 for r in reqs)
 
 
+def test_a_pass_blocks_in_one_fetch_and_requests_carry_the_thread_states(
+        model, params):
+    """PR 41: the block step drains, so every pass reads the program it
+    issued (``read_seq`` = its own ``seq``) inside ONE ``fetch:decode``
+    segment, the capture's logits included; a record's wall is cpu +
+    blocked + stalled, and every finished request carries the four
+    ``engine_*`` overlays, which sum."""
+    from tests.thread_state_checks import (
+        check_records,
+        check_requests,
+        spy_window_closes,
+    )
+
+    engine = make_engine(model, params)
+    engine.block.capture = []
+    closed = spy_window_closes(engine)
+    sp = SamplingParams(max_tokens=10, **GREEDY)
+    reqs = [engine.submit(prompt_of(n, seed=n), sp) for n in (7, 12, 18, 21)]
+    drain(engine)
+    records = engine.steptrace.records()
+    check_records(records, closed)
+    check_requests(list(engine.finished))
+    assert len(engine.finished) == len(reqs)
+    passes = [r for r in records if r["block_rows"]]
+    assert len(passes) == engine.block.passes
+    for r in passes:
+        assert r["read_seq"] == r["seq"] and not r["ahead"]
+        assert [n for n, _, _ in r["segments"]
+                if n.startswith("fetch:")][-1] == "fetch:decode"
+    snap = engine.steptrace.snapshot()
+    assert sum(snap["thread_seconds"].values()) == pytest.approx(
+        snap["step_wall_seconds_total"])
+
+
 def test_sse_streams_blocks_and_metrics_render(model, params):
     from llm_in_practise_tpu.serve.api import OpenAIServer
     from tests.test_serve_api import ByteTokenizer
