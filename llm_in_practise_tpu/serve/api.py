@@ -766,6 +766,10 @@ class OpenAIServer:
                      "dispatches of the block-diffusion pass program"),
                     ("llm_block_row_passes_total", "row_passes",
                      "rows that really advanced in those passes"),
+                    ("llm_block_row_passes_discarded_total",
+                     "row_passes_discarded",
+                     "rows a pass issued ahead ran past their stream's "
+                     "EOS, dropped when it was read"),
                     ("llm_blocks_committed_total", "blocks_committed",
                      "blocks whose K/V were stored and tokens streamed"),
                     ("llm_block_tokens_committed_total", "tokens_committed",

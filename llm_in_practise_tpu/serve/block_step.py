@@ -19,10 +19,48 @@ committed".
 model: the engine keeps admission, buckets, the page pool, one-shot and
 chunked prefill (over the prompt's WHOLE blocks; the ``P mod B``
 remaining prompt tokens open the first block as already-revealed
-positions) and the finish funnel; this class owns the per-slot block
-state and the one jitted program of a dispatch (``_paged_block_fn``).
-Rows are at independent phases: in one dispatch some rows commit and the
-others reveal.
+positions), the finish funnel and the loop that issues and retires
+programs; this class owns the per-slot block state and the one jitted
+program of a dispatch (``_paged_block_fn``). Rows are at independent
+phases: in one dispatch some rows commit and the others reveal.
+
+**A pass is issued before the pass before it is read** (the engine's
+"issue and retire", ``InferenceEngine._fly`` / ``_retire``; a
+``_Flight`` of kind ``block``):
+
+- **The block plane stays on the device.** The program returns every
+  row's block after the pass, ``(tokens, revealed)``, and the next pass
+  takes them as they are. Where the HOST knows a row's block better (one
+  opened at activation with the prompt's remainder revealed; the
+  all-mask block that follows a commit) its values ride in as a ``fix``:
+  a flag a slot and the ``(slots, B)`` planes, always passed (columns of
+  the pass's ``plan``, the ONE array the host sends a pass) and applied
+  by a ``where`` at the program's head, so the program has one form. An
+  idle row keeps what the device holds (its quota is 0: it comes back
+  unchanged and writes to the trash page).
+- **The host keeps the schedule, not the values.** Under the static rule
+  a pass reveals exactly ``min(quota, masked)`` positions a row, so a
+  per-slot COUNT of revealed positions says which rows commit in which
+  pass; lengths, block numbers, budgets and the ``length`` / ``cache``
+  ends follow from it. That half of a pass runs at ISSUE
+  (:meth:`BlockDecoder.issue`), with no value of the pass before.
+- **RETIRE** (:meth:`BlockDecoder.retire`) reads pass n after pass n + 1
+  was issued: ONE ``device_get`` (tokens, revealed flags, expert ids;
+  the logits too for a reference comparison), then the half that needs
+  values: the reveal log, a committed block's stream up to EOS, the
+  routing load, the counters. A row whose stream ends in EOS there has
+  already run in pass n + 1: a denoise pass over the next block whose
+  K/V went to the trash page. That pass is discarded and counted, and
+  the slot and its pages are released when it is read (a zombie, as in
+  ``InferenceEngine._retire``).
+- **What drains**: a step in which any ready row decodes under
+  ``low_confidence_dynamic`` (how many positions clear the threshold is
+  a device value, so the next pass's commit set is not the host's to
+  know: ``block_dynamic``), an admission's one-shot prefill
+  (``oneshot_prefill``) and a chunk beside a pass (``two_dispatch``).
+  The same two halves then run with nothing in between: the program is
+  read before the next is planned, as the serial step did. No option
+  chooses: the rows' own ``remasking`` does.
 
 The K/V of a denoise pass are used inside the pass and not kept: the
 host routes their write-back to the trash page (``valid`` = 0), so only a
@@ -109,10 +147,22 @@ class BlockDecoder:
             getattr(cfg, "confidence_threshold", 0.9))
         self.n_experts = int(getattr(cfg, "n_experts", 0))  # 0: no routing
         S, B = engine.max_slots, self.B
-        self.tok = np.zeros((S, B), np.int32)
-        self.rev = np.zeros((S, B), bool)
+        # the block plane: every row's block after the last ISSUED pass,
+        # device arrays (a pass's outputs are the next pass's inputs)
+        self._plane = (jnp.zeros((S, B), jnp.int32), jnp.zeros((S, B), bool))
+        # rows whose block the host knows better, handed to the next pass
+        # (:meth:`_plan`)
+        self._fix = self._no_fix()
+        # the schedule, as of the last ISSUED pass: revealed positions a
+        # row (under the dynamic rule a device value: written when the
+        # pass is read, and the engine drains), denoise passes its block
+        # has had, blocks it has committed
+        self.n_rev = np.zeros((S,), np.int32)
         self.passes_in_block = np.zeros((S,), np.int32)
         self.block_no = np.zeros((S,), np.int32)
+        # the revealed flags as of the last pass READ: what a pass newly
+        # revealed is told against them
+        self.rev = np.zeros((S, B), bool)
         # leading positions of the slot's FIRST block that are prompt
         # remainder: revealed from the start and never emitted
         self.keep = np.zeros((S,), np.int32)
@@ -121,18 +171,21 @@ class BlockDecoder:
         self.threshold = np.full((S,), self.default_threshold, np.float32)
         # lifetime counters (engine-thread writes, scrape-side reads of
         # monotone numbers: the spec_* counter convention)
+        # (booked when a pass is read)
         self.passes = 0             # dispatches of the block program
         self.row_passes = 0         # rows that really advanced in them
+        # ... rows a pass ran past their stream's EOS, dropped unread
+        self.row_passes_discarded = 0
         self.blocks_committed = 0
         self.tokens_committed = 0   # tokens streamed out of commits
         self.tokens_revealed = 0
         self.routing = RoutingLoad(self.n_experts)
         # reference comparisons (tests, the benchmark's check) set this
-        # to a list: the step then also FETCHES the pass's logits (the
-        # one program returns them always, as a device array nothing else
-        # reads), and each advancing row appends {uid, block, pass,
-        # commit, logits (B, vocab), experts (layers, B, k) | None}.
-        # None: nothing kept.
+        # to a list: a pass issued from then on keeps its logits (the one
+        # program returns them always, as a device array nothing else
+        # reads), its reading FETCHES them with the rest, and each
+        # advancing row appends {uid, block, pass, commit, logits
+        # (B, vocab), experts (layers, B, k) | None}. None: nothing kept.
         self.capture = None
         # device copies of the per-row sampling and schedule arrays: they
         # change at activation only, not every pass
@@ -234,24 +287,35 @@ class BlockDecoder:
 
     # --- the jitted program ---------------------------------------------------
 
-    def _paged_block_fn(self, params, pool, gidx, index_vec, sidx, tokens,
-                        revealed, rng, temperature, top_k, top_p, greedy,
-                        quota, threshold, dynamic):
-        """One pass over the slot plane, ONE dispatch: gather every
-        slot's pages into a view pinned at its committed length (always a
-        multiple of B), forward the (slots, B) blocks (unrevealed
-        positions fed as the mask id), sample a candidate and its
-        confidence at every position, apply the reveal rule, and write
-        the B-wide window back — to the slot's pages for rows that commit,
-        to the trash page for all others (the host built ``sidx`` so).
-        Returns ``(tokens, revealed, experts, logits, pool)``: the
-        blocks after the pass, the experts every position of the plane
-        chose in every layer, ``(layers, slots * B, k)``, and the float32
-        logits ``(slots, B, vocab)``, which stay on the device unless a
-        reference comparison fetches them (``capture``): there is ONE
-        program, so what is compared is what serves."""
+    def _paged_block_fn(self, params, pool, plan, tokens, revealed, rng,
+                        temperature, top_k, top_p, greedy, threshold,
+                        dynamic):
+        """One pass over the slot plane, ONE dispatch: take the host's
+        word for the rows it ``fix``es (``tokens`` / ``revealed`` are the
+        pass before's outputs), gather every slot's pages into a view
+        pinned at its committed length (always a multiple of B), forward
+        the (slots, B) blocks (unrevealed positions fed as the mask id),
+        sample a candidate and its confidence at every position, apply
+        the reveal rule, and write the B-wide window back — to the slot's
+        pages for rows that commit, to the trash page for all others (the
+        host built ``sidx`` so). ``plan`` is what the host decided for
+        this pass, a row a slot and one transfer (:meth:`_plan_row`);
+        the engine's key goes in and comes back split, so the host
+        dispatches nothing else. Returns ``(tokens, revealed, experts,
+        logits, pool, rng)``: the blocks after the pass, the experts
+        every position of the plane chose in every layer, ``(layers,
+        slots * B, k)``, and the float32 logits ``(slots, B, vocab)``,
+        which stay on the device unless a reference comparison fetches
+        them (``capture``): there is ONE program, so what is compared is
+        what serves."""
         eng = self.eng
         S, B = tokens.shape
+        rng, sub = jax.random.split(rng)
+        gidx, index_vec, sidx, quota, fix, fix_tokens, fix_revealed = \
+            jnp.split(plan, self._plan_cuts(plan.shape[1]), axis=1)
+        index_vec, quota, fix = index_vec[:, 0], quota[:, 0], fix != 0
+        tokens = jnp.where(fix, fix_tokens, tokens)
+        revealed = jnp.where(fix, fix_revealed != 0, revealed)
         view = eng._paged_view(pool, gidx, index_vec)
         ids = jnp.where(revealed, tokens, self.mask_id)
         (logits, view), aux = eng.model.apply(
@@ -264,7 +328,7 @@ class BlockDecoder:
         # in their flags must not choose the sampler's body, and when every
         # LIVE row is greedy the pass skips the full-vocabulary sort
         cand = sample_token_batched(
-            rng, flat, temperature=rep(temperature), top_k=rep(top_k),
+            sub, flat, temperature=rep(temperature), top_k=rep(top_k),
             top_p=rep(top_p), greedy=rep(greedy | (quota == 0)),
         ).astype(jnp.int32).reshape(S, B)
         # confidence: the candidate's probability under softmax(logits)
@@ -279,7 +343,24 @@ class BlockDecoder:
         experts = (jnp.stack(chosen) if chosen
                    else jnp.zeros((0, S * B, 1), jnp.int32))
         pool = eng._paged_writeback(pool, view, sidx, index_vec)
-        return new_tok, new_rev, experts, logits, pool
+        return new_tok, new_rev, experts, logits, pool, rng
+
+    def _plan_cuts(self, width: int) -> list[int]:
+        """Where a plan row ``width`` wide splits into its seven parts."""
+        B = self.B
+        return np.cumsum([width - 3 - 3 * B, 1, B, 1, 1, B]).tolist()
+
+    def _plan(self, gidx, index_vec, sidx, quota) -> np.ndarray:
+        """The host's decisions for one pass as ONE int32 array, a row a
+        slot: the view's gather indices, the pinned cache index, the
+        write-back targets, the reveal quota (0: an idle row), and the
+        rows whose block the host opened since the last pass (``fix``
+        and its planes, handed over once)."""
+        fix, tokens, revealed = self._fix
+        self._fix = self._no_fix()
+        return np.concatenate(
+            [gidx, index_vec[:, None], sidx, quota[:, None], fix[:, None],
+             tokens, revealed], axis=1, dtype=np.int32)
 
     # --- slot life cycle ------------------------------------------------------
 
@@ -307,118 +388,175 @@ class BlockDecoder:
         self.threshold[slot] = thr
         self._row_params = None
         self.block_no[slot] = 0
-        self._open_block(slot, req.block_open)
+        # no pass has run on the row: the block as opened is the last read
+        self.rev[slot] = self._open_block(slot, req.block_open)
 
-    def _open_block(self, slot: int, opening=()) -> None:
+    def _no_fix(self) -> tuple:
+        S, B = self.eng.max_slots, self.B
+        return (np.zeros((S,), bool), np.zeros((S, B), np.int32),
+                np.zeros((S, B), bool))
+
+    def _open_block(self, slot: int, opening=()) -> np.ndarray:
         """A fresh block: ``opening`` tokens revealed at its head (the
-        prompt's remainder, never emitted), the rest masked."""
+        prompt's remainder, never emitted), the rest masked. The device
+        hears of it through the next pass's ``fix``. Returns the block's
+        revealed flags."""
         r = len(opening)
-        self.tok[slot] = 0
-        self.tok[slot, :r] = opening
-        self.rev[slot] = np.arange(self.B) < r
+        fix, tok, rev = self._fix
+        fix[slot] = True
+        tok[slot] = 0
+        tok[slot, :r] = opening
+        rev[slot] = np.arange(self.B) < r
         self.keep[slot] = r
+        self.n_rev[slot] = r
         self.passes_in_block[slot] = 0
+        return rev[slot]
 
-    # --- the step ---------------------------------------------------------------
+    # --- the step: issue, then retire -----------------------------------------
 
-    def step(self, active: list[int]) -> None:
-        """One block pass over every ready slot (the caller holds the
-        engine's step lock and has advanced the prefills)."""
+    def issue(self, active: list[int], f) -> None:
+        """ISSUE one block pass over the ready rows ``active`` into the
+        flight ``f`` (the caller holds the engine's step lock, has
+        advanced the prefills and reserved the rows' pages): indices,
+        the dispatch, and the half of the pass that needs none of its
+        values (:meth:`_advance_row`). Nothing here reads the pass
+        before."""
         eng, B = self.eng, self.B
         st = eng.steptrace
-        with st.scope("admit"):
-            for s in list(active):
-                if int(eng.slot_len[s]) + B > eng.cache_len:
-                    eng._finish_slot(s, "cache")    # no room for a block
-                    active.remove(s)
-            active = eng._paged_reserve_active(active, B)
-        if not active:
-            return
         with st.scope("index_build"):
-            eng.rng, sub = jax.random.split(eng.rng)
             W = eng._paged_width(
                 max(int(eng.slot_len[s]) for s in active) + B)
             eng._pulse_view(W)
             idxv = eng._paged_index_vec(W, B)
-            commits = [s for s in active if self.rev[s].all()]
             valid = np.zeros((eng.max_slots,), np.int32)
-            valid[commits] = B
             quota = np.zeros((eng.max_slots,), np.int32)    # 0: idle row
             for s in active:
+                if self.n_rev[s] == B:
+                    valid[s] = B                            # a commit pass
                 quota[s] = reveal_quota(B, int(self.steps[s]),
                                         int(self.passes_in_block[s]))
-            capture = self.capture is not None
+            plan = self._plan(eng._paged_view_idx(W), idxv,
+                              eng.paged.scatter_idx(idxv, valid, B), quota)
             if self._row_params is None:
                 self._row_params = tuple(jnp.asarray(a) for a in (
                     eng._temperature, eng._top_k, eng._top_p, eng._greedy,
                     self.threshold, self.dynamic))
-            *sampling, threshold, dynamic = self._row_params
             st.note_sampler_tier(sampler_tier_name(
                 eng._greedy | (quota == 0), eng._top_k, eng._top_p))
         with st.scope("dispatch_wait"):
-            st.window_begin("decode")
-            out = self._pg_block(
-                eng.params, eng.paged.kv,
-                jnp.asarray(eng._paged_view_idx(W)), jnp.asarray(idxv),
-                jnp.asarray(eng.paged.scatter_idx(idxv, valid, B)),
-                jnp.asarray(self.tok), jnp.asarray(self.rev), sub,
-                *sampling, jnp.asarray(quota), threshold, dynamic)
-            new_tok, new_rev, experts, logits, eng.paged.kv = out
+            eng._window_open("decode", f)
+            (f.toks, f.rev, f.experts, logits, eng.paged.kv,
+             eng.rng) = self._pg_block(
+                eng.params, eng.paged.kv, jnp.asarray(plan), *self._plane,
+                eng.rng, *self._row_params)
+            self._plane = (f.toks, f.rev)
+            if self.capture is not None:    # reference comparisons only
+                f.logits = logits
+            f.rows = [self._advance_row(s, int(quota[s])) for s in active]
             st.window_issued()
+
+    def _advance_row(self, slot: int, quota: int) -> tuple:
+        """The half of a row's pass that needs no VALUE, done when the
+        pass is issued. A commit pass: the slot grows by B, the budget
+        pays for the tokens the block will stream (fewer only if an EOS
+        cuts it: the reading finds that), the row closes where the budget
+        or the cache ends, else it opens an all-mask block. A denoise
+        pass: the static rule reveals ``min(quota, masked)`` positions.
+        Returns the flight's row: ``(slot, request, tokens the commit
+        streams, why the stream ends with them or None, block, pass,
+        is it a commit, the block's leading prompt tokens)``."""
+        eng, B = self.eng, self.B
+        keep = int(self.keep[slot])
+        row = (slot, eng.slot_req[slot])
+        at = (int(self.block_no[slot]), int(self.passes_in_block[slot]))
+        if self.n_rev[slot] < B:
+            if not self.dynamic[slot]:
+                self.n_rev[slot] += min(quota, B - int(self.n_rev[slot]))
+            self.passes_in_block[slot] += 1
+            return (*row, 0, None, *at, False, keep)
+        eng.slot_len[slot] += B
+        taken = min(B - keep, int(eng.slot_budget[slot]))
+        eng.slot_budget[slot] -= taken
+        why = ("length" if eng.slot_budget[slot] <= 0
+               else "cache" if int(eng.slot_len[slot]) + B > eng.cache_len
+               else None)
+        # a closing row is in no later pass; it ends when this one is read
+        eng.slot_closing[slot] = why
+        if why is None:
+            self.block_no[slot] += 1
+            self._open_block(slot)
+        return (*row, taken, why, *at, True, keep)
+
+    def retire(self, f) -> None:
+        """Read the pass ``f``: ONE fetch forces its results, then the
+        half that needs the values runs, a row at a time and in the
+        order the serial step had: the capture, a commit's stream (up to
+        EOS) or a denoise pass's reveal log, the routing load, the
+        counters."""
+        eng, B = self.eng, self.B
+        st = eng.steptrace
+        with st.scope("dispatch_wait"):
             # the pass's result: the one fetch the step blocks on
             with st.fetch():
-                new_tok, new_rev, experts = jax.device_get(  # graftlint: disable=host-sync
-                    (new_tok, new_rev, experts))
-                if capture:     # reference comparisons only
-                    logits = np.asarray(logits)  # graftlint: disable=host-sync
+                tok, rev, experts, logits = jax.device_get(  # graftlint: disable=host-sync
+                    (f.toks, f.rev, f.experts, f.logits))
             dt, _ = eng._window_close(
-                "decode", [eng.slot_req[s] for s in active])
+                "decode", [row[1] for row in f.rows], f.holders)
             eng.dispatch_meter.note_phase(
-                "block", tokens=B * len(active), duration_s=dt, mfu=None,
+                "block", tokens=B * len(f.rows), duration_s=dt, mfu=None,
                 hbm_bw_util=None)
         with st.scope("sample_commit"):
             self._book_routing(experts)
-            n_rev = n_out = 0
-            for s in active:
-                req = eng.slot_req[s]
-                req.block_passes += 1
-                if capture:
-                    self.capture.append({
-                        "uid": req.uid, "block": int(self.block_no[s]),
-                        "pass": int(self.passes_in_block[s]),
-                        "commit": s in commits,
-                        "logits": logits[s].copy(),
-                        "experts": (experts[:, s * B:(s + 1) * B].copy()
-                                    if experts.shape[0] else None)})
-                if s in commits:
-                    n_out += self._commit(s)
+            capture = self.capture if logits is not None else None
+            newly = rev & ~self.rev     # what the pass revealed, a row
+            rows = commits = n_rev = n_out = 0
+            for slot, req, taken, why, block, pas, commit, keep in f.rows:
+                if slot in eng._zombies:
+                    # its stream ended in EOS when the pass before this
+                    # one was read: what this one revealed goes nowhere,
+                    # and the slot and its pages are free from here on
+                    st.note_discarded(int(newly[slot].sum()))
+                    self.row_passes_discarded += 1
+                    eng._release_pages(slot, req)
+                    eng._clear_slot(slot)
                     continue
-                newly = new_rev[s] & ~self.rev[s]
-                for j in np.flatnonzero(newly):
-                    req.reveal_log.append(
-                        (int(self.block_no[s]), int(self.passes_in_block[s]),
-                         int(j), int(new_tok[s, j])))
-                n_rev += int(newly.sum())
-                self.tok[s] = new_tok[s]
-                self.rev[s] = new_rev[s]
-                self.passes_in_block[s] += 1
+                rows += 1
+                req.block_passes += 1
+                if capture is not None:
+                    capture.append({
+                        "uid": req.uid, "block": block, "pass": pas,
+                        "commit": commit, "logits": logits[slot].copy(),
+                        "experts": (experts[:, slot * B:(slot + 1) * B].copy()
+                                    if experts.shape[0] else None)})
+                if commit:
+                    commits += 1
+                    self.rev[slot] = False      # the block it opened
+                    n_out += self._stream(slot, req, tok[slot, keep:],
+                                          taken, why)
+                    continue
+                hits = np.flatnonzero(newly[slot])
+                req.reveal_log.extend(
+                    (block, pas, int(j), int(tok[slot, j])) for j in hits)
+                n_rev += len(hits)
+                self.rev[slot] = rev[slot]
+                if self.dynamic[slot]:
+                    self.n_rev[slot] = int(rev[slot].sum())
             self.passes += 1
-            self.row_passes += len(active)
-            self.blocks_committed += len(commits)
+            self.row_passes += rows
+            self.blocks_committed += commits
             self.tokens_revealed += n_rev
             self.tokens_committed += n_out
-            st.note_block_pass(len(active), len(commits), n_rev, n_out)
+            st.note_block_pass(rows, commits, n_rev, n_out)
 
-    def _commit(self, slot: int) -> int:
-        """The slot's finished block has its K/V stored: stream its
-        tokens (not the prompt's remainder), up to ``max_tokens`` or EOS,
-        then open the next block. Returns the tokens streamed."""
-        eng, B = self.eng, self.B
-        req = eng.slot_req[slot]
-        eng.slot_len[slot] += B
-        sent = 0
-        for j in range(int(self.keep[slot]), B):
-            tok = int(self.tok[slot, j])
+    def _stream(self, slot: int, req, tokens, taken: int,
+                why: str | None) -> int:
+        """The slot's finished block has its K/V stored: stream the
+        ``taken`` tokens its commit paid for (not the prompt's
+        remainder), up to an EOS, and end the stream there or for the
+        deterministic ``why``. Returns the tokens streamed."""
+        eng = self.eng
+        for sent in range(taken):
+            tok = int(tokens[sent])
             if eng.eos_id is not None and tok == eng.eos_id:
                 eng._finish_slot(slot, "stop")
                 return sent
@@ -426,17 +564,9 @@ class BlockDecoder:
                 req.first_token_time = time.monotonic()
             req.tokens.put(tok)
             req.n_generated += 1
-            sent += 1
-            eng.slot_budget[slot] -= 1
-            if eng.slot_budget[slot] <= 0:
-                eng._finish_slot(slot, "length")
-                return sent
-        if int(eng.slot_len[slot]) + B > eng.cache_len:
-            eng._finish_slot(slot, "cache")
-            return sent
-        self.block_no[slot] += 1
-        self._open_block(slot)
-        return sent
+        if why is not None:
+            eng._finish_slot(slot, why)
+        return taken
 
     def _book_routing(self, experts: np.ndarray) -> None:
         """Expert load of one pass, over the WHOLE plane the device
@@ -455,6 +585,7 @@ class BlockDecoder:
         return {
             "block_passes": self.passes,
             "block_row_passes": self.row_passes,
+            "block_row_passes_discarded": self.row_passes_discarded,
             "blocks_committed": self.blocks_committed,
             "block_tokens_committed": self.tokens_committed,
             "block_tokens_revealed": self.tokens_revealed,

@@ -121,14 +121,18 @@ CHUNK_TOKENS_PER_STEP = 2048
 # ``llm_step_drains_total{reason}``). ``idle``: nothing was in flight and
 # nothing forbade it (the engine had been idle, or the step before read
 # its own program). The rest are states of the engine, observed step by
-# step: the layout or model (``contiguous``, ``block``), a ready row
-# (``grammar``, ``speculative``, ``session``), an admission that
-# dispatches or reads values (``oneshot_prefill``, ``kv_pull``,
-# ``host_first_token``), a step of more than one program
-# (``two_dispatch``), a page reservation that must preempt or finish a
-# row (``preempt``).
-DRAIN_REASONS = ("idle", "contiguous", "block", "grammar", "speculative",
-                 "session", "oneshot_prefill", "kv_pull",
+# step: the layout (``contiguous``), a ready row (``grammar``,
+# ``speculative``, ``session``; ``block_dynamic``: a block-diffusion row
+# under ``low_confidence_dynamic``, whose pass reveals as many positions
+# as clear its threshold, so which rows commit next is a device value; a
+# block engine whose ready rows are all static runs its passes ahead,
+# serve/block_step.py), an admission that dispatches or reads values
+# (``oneshot_prefill``, ``kv_pull``, ``host_first_token``), a step of
+# more than one program (``two_dispatch``: also a block engine's chunk
+# beside a pass), a page reservation that must preempt or finish a row
+# (``preempt``).
+DRAIN_REASONS = ("idle", "contiguous", "block_dynamic", "grammar",
+                 "speculative", "session", "oneshot_prefill", "kv_pull",
                  "host_first_token", "two_dispatch", "preempt")
 
 # Per-request critical-path segments (ISSUE 11): every finished
@@ -377,16 +381,24 @@ class Request:
 
 @dataclasses.dataclass
 class _Flight:
-    """A paged decode / mixed / chunk program between its issue and its
-    retire: the device outputs nobody has read yet, and the host-side
-    plan that produced them (everything here was known at issue)."""
+    """A paged decode / mixed / chunk / block program between its issue
+    and its retire: the device outputs nobody has read yet, and the
+    host-side plan that produced them (everything here was known at
+    issue)."""
 
-    kind: str                       # "decode" | "mixed" | "chunk"
+    kind: str                       # "decode" | "mixed" | "chunk" | "block"
     n: int = 0                      # the decode block's length
     # rows that decode: (slot, request, tokens of the block it takes,
-    # why it ends with the last of them or None)
+    # why it ends with the last of them or None); a block-diffusion
+    # pass's rows carry more (BlockDecoder._advance_row)
     rows: list = dataclasses.field(default_factory=list)
-    toks: Any = None                # (max_slots, n) sampled tokens
+    # (max_slots, n) sampled tokens; a block pass: its (max_slots, B)
+    # blocks, their revealed flags, the experts the plane chose and, for
+    # a reference comparison, its logits
+    toks: Any = None
+    rev: Any = None
+    experts: Any = None
+    logits: Any = None
     # rows that chunk: (slot, state, chunk) as dispatched
     entries: list = dataclasses.field(default_factory=list)
     # prompts this program ends whose first token it sampled: (slot,
@@ -4862,6 +4874,9 @@ class InferenceEngine:
     # share of a step runs while the device computes. Where the next
     # plan does need values, the engine DRAINS (retires what is in
     # flight) and steps as it always did: issue, then retire at once.
+    # A block-diffusion pass has the same two halves (its plane is the
+    # rows' blocks, its schedule a count of revealed positions a row:
+    # BlockDecoder.issue / .retire) and flies through the same loop.
 
     def _ahead_blocker(self) -> str | None:
         """Why this step's program may not be issued before the one in
@@ -4871,9 +4886,9 @@ class InferenceEngine:
         page reservation that must preempt, a step of two programs.)"""
         if self.paged is None:
             return "contiguous"     # its programs take the host's tokens
-        if self.block is not None:
-            return "block"          # a pass's reveal decides the next
         ready = self._ready_slots()
+        if self.block is not None and self.block.dynamic[ready].any():
+            return "block_dynamic"  # a pass's reveal decides the next
         if self._constrained_active(ready):
             return "grammar"        # the mask is a function of the token
         if self._spec_applicable(ready):
@@ -4932,6 +4947,9 @@ class InferenceEngine:
         tokens of the prompts it ended, what it counted) forces its
         results, then the halves of activation and commit that need the
         values run: emission, EOS, finishes, the statistics' booking."""
+        if f.kind == "block":
+            self.block.retire(f)
+            return self._update_active_stats()
         chunked = [st["req"] for _, st, _ in f.entries]
         with self.steptrace.scope("dispatch_wait"):
             stats = self.step_stats
@@ -5019,6 +5037,29 @@ class InferenceEngine:
             self.steptrace.window_issued()
         self._fly(f)
 
+    def _block_pass(self, active: list[int]):
+        """Issue one block-diffusion pass over the ready rows ``active``
+        (serve/block_step.py): reserve a block's pages a row, then the
+        pass; it is read in :meth:`_retire`."""
+        B = self.block.B
+        with self.steptrace.scope("admit"):
+            for s in list(active):
+                if int(self.slot_len[s]) + B > self.cache_len:
+                    # a fresh row with no room for a block (one that
+                    # committed its last closed when that pass was issued)
+                    self._finish_slot(s, "cache")
+                    active.remove(s)
+            if not self._reserve_ahead(active, B):
+                return _REPLAN
+            active = self._paged_reserve_active(active, B)
+        if active:
+            f = _Flight("block")
+            self.block.issue(active, f)
+            self._fly(f)
+        else:
+            self._update_active_stats()     # the rows ended here
+        return True
+
     def step(self) -> bool:
         """One engine iteration. Returns False when fully idle."""
         t_lock = time.perf_counter()
@@ -5096,14 +5137,14 @@ class InferenceEngine:
             # block-diffusion model: chunks and block rows take two
             # dispatches (no fused mixed block step yet), and one pass
             # over the ready rows replaces the decode families below
+            if self.slot_prefill:
+                self._drain("two_dispatch")
             progressed = self._advance_prefills(budget)
             with self.steptrace.scope("plan"):
                 active = self._ready_slots()
             if not active:
                 return progressed or bool(self.slot_prefill)
-            self.block.step(active)
-            self._update_active_stats()
-            return True
+            return self._block_pass(active)
         # A speculative engine at decode_steps=1 keeps speculating
         # while prompts prefill (the r5 composition): its verify step
         # yields 1+accepted tokens per dispatch, strictly more than the

@@ -386,16 +386,24 @@ def test_a_speculative_engine_drains_while_a_round_can_run(dense):
     assert len(greedy.result()) == 8
 
 
-def test_a_block_diffusion_engine_drains():
+@pytest.mark.parametrize("rule", ["low_confidence_dynamic",
+                                  "low_confidence_static"])
+def test_a_block_diffusion_engine_drains(rule):
+    """While a ready row decodes under the dynamic rule (how many
+    positions a pass reveals is a device value); under the static rule
+    its passes run ahead (``tests/test_block_lookahead.py``)."""
     model = SDARMoE(sdar_moe_config())
     eng = InferenceEngine(model, model.init_params(jax.random.PRNGKey(0)),
                           **{**OPTS, "chunked_prefill": None})
-    req = eng.submit([5, 6, 7, 8, 9], SamplingParams(greedy=True,
-                                                     max_tokens=8))
+    req = eng.submit([5, 6, 7, 8, 9], SamplingParams(
+        greedy=True, max_tokens=8, remasking=rule))
     drain(eng)
     assert len(req.result()) == 8
-    assert _drains(eng)["block"] > 0
-    assert eng.steptrace.snapshot()["steps_ahead"] == 0
+    ahead = eng.steptrace.snapshot()["steps_ahead"]
+    if rule == "low_confidence_dynamic":
+        assert _drains(eng)["block_dynamic"] > 0 and ahead == 0
+    else:
+        assert "block_dynamic" not in _drains(eng) and ahead > 0
 
 
 VOCAB = 128

@@ -471,10 +471,10 @@ def test_counters_add_up(model, params):
 
 def test_a_pass_blocks_in_one_fetch_and_requests_carry_the_thread_states(
         model, params):
-    """PR 41: the block step drains, so every pass reads the program it
-    issued (``read_seq`` = its own ``seq``) inside ONE ``fetch:decode``
-    segment, the capture's logits included; a record's wall is cpu +
-    blocked + stalled, and every finished request carries the four
+    """PR 41, PR 42: every pass is read inside ONE ``fetch:decode``
+    segment, the capture's logits included, by the step that issued it
+    or (a pass that ran ahead: most) by the one after; a record's wall is
+    cpu + blocked + stalled, and every finished request carries the four
     ``engine_*`` overlays, which sum."""
     from tests.thread_state_checks import (
         check_records,
@@ -495,9 +495,10 @@ def test_a_pass_blocks_in_one_fetch_and_requests_carry_the_thread_states(
     passes = [r for r in records if r["block_rows"]]
     assert len(passes) == engine.block.passes
     for r in passes:
-        assert r["read_seq"] == r["seq"] and not r["ahead"]
-        assert [n for n, _, _ in r["segments"]
-                if n.startswith("fetch:")][-1] == "fetch:decode"
+        assert r["read_seq"] in (r["seq"], r["seq"] - 1)
+        assert [n for n, _, _ in r["segments"]].count("fetch:decode") == 1
+    behind = [r for r in passes if r["read_seq"] == r["seq"] - 1]
+    assert sum(r["ahead"] for r in behind) >= 0.6 * len(passes)
     snap = engine.steptrace.snapshot()
     assert sum(snap["thread_seconds"].values()) == pytest.approx(
         snap["step_wall_seconds_total"])
