@@ -22,7 +22,13 @@ keys' partial result with ``(0, b_h)``.
   stretch was written into; a window layer's are its ring, at most
   ``window`` rows, put in order and attended by the first ``window``
   queries only (dense, a ``window x window`` corner), joined with the
-  kernel's result over the stretch's own keys.
+  kernel's result over the stretch's own keys. That corner is dense
+  float32 and stays only where it is small (:data:`RING_CORNER_MAX`
+  scores a head, read off the shapes): a ring LONGER than a tile (a
+  window of 4,096 over 2,048-token chunks, ``models/afmoe.py``) goes
+  through the kernel, ``[the ring's live rows in position order ‖ the
+  stretch's own keys]`` as ONE stretch of keys under the band
+  (:func:`ring_stretch`), the blocks outside the band skipped as ever.
 - :func:`decode_attention` / :func:`ring_decode_attention`: one query a
   row, XLA einsums over the gathered view of FLAT rows (``Hk * 192`` and
   ``Hk * 128`` wide, as the pool's pages hold them) / over the ring,
@@ -58,7 +64,26 @@ GLOBAL_KERNEL = "global_prefill_flash"
 # window layer's chunk against its own keys
 GLOBAL_BLOCKS = (1024, 1024)
 WINDOW_BLOCKS = (256, 256)
+# a window layer's chunk over [its ring ‖ its own keys] (a call of its
+# own on the device plane), and the tiles of a band LONGER than a tile of
+# WINDOW_BLOCKS: a band of 128 half fills a (256, 256) tile, a band of
+# 4,096 is 16 of them, and wider key blocks are fewer grid steps
+# (tools/swa_bakeoff.py --geometry trinity: 2,048 queries under a band of
+# 4,096 over 6,144 keys, folded: 6.48 ms at (256, 256), 4.20 at (256,
+# 512), 3.63 at (512, 512), 3.10 at (256, 1024); (512, 1024) does not fit
+# the chip's fast memory)
+WINDOW_RING_KERNEL = "window_ring_prefill_flash"
+LONG_BAND_BLOCKS = (256, 1024)
+# the most scores a head that the dense ring corner may hold (min(L,
+# window) x ring rows, float32, every head at once): a tile. MiMo-V2's
+# 128 x 128 lies under it; 2,048 x 4,096 would be 1.6 GB a layer
+RING_CORNER_MAX = 256 * 256
 _LANE, _SUBLANE = 128, 8
+
+
+def window_blocks(window: int) -> tuple[int, int]:
+    """The band kernel's tiles by the band's length."""
+    return WINDOW_BLOCKS if window <= WINDOW_BLOCKS[1] else LONG_BAND_BLOCKS
 
 
 def band_blocks(block_q: int, block_k: int, window: int) -> int:
@@ -138,7 +163,7 @@ def _flash_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def flash_partial(q, k, v, q_start, k_start, *, scale: float,
                   window: int | None = None, block_q: int | None = None,
                   block_k: int | None = None, fold: bool | None = None,
-                  interpret: bool | None = None):
+                  interpret: bool | None = None, name: str | None = None):
     """Attention of ``q`` (B, H, Lq, Dq) over ONE stretch of keys ``k``
     (B, Hk, Lk, Dq) / values ``v`` (B, Hk, Lk, Dv), ``H`` a multiple of
     ``Hk``; ``q_start`` / ``k_start`` (B,) are the absolute positions of
@@ -156,7 +181,7 @@ def flash_partial(q, k, v, q_start, k_start, *, scale: float,
     hk, lk, dv = k.shape[1], k.shape[2], v.shape[-1]
     group = h // hk
     fold = (window is not None) if fold is None else fold
-    tiles = GLOBAL_BLOCKS if window is None else WINDOW_BLOCKS
+    tiles = GLOBAL_BLOCKS if window is None else window_blocks(window)
     block_q = min(block_q or tiles[0], lq)
     block_k = min(block_k or tiles[1], lk)
     if lq % block_q or lk % block_k:
@@ -219,7 +244,7 @@ def flash_partial(q, k, v, q_start, k_start, *, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret_default() if interpret is None else interpret,
-        name=GLOBAL_KERNEL if window is None else WINDOW_KERNEL,
+        name=name or (GLOBAL_KERNEL if window is None else WINDOW_KERNEL),
     )(jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (b,)),
       jnp.broadcast_to(jnp.asarray(k_start, jnp.int32), (b,)), q, k, v)
     lse = lse[:, :, 0, :]
@@ -253,12 +278,12 @@ def key_blocks_visited(q_start: int, k_start: int, lq: int, lk: int, *,
     return visited
 
 
-def _flash_padded(q, k, v, q_start, k_start, *, scale, window):
+def _flash_padded(q, k, v, q_start, k_start, *, scale, window, name=None):
     """:func:`flash_partial` for (B, L, H, D) operands of any length:
     heads first, lengths padded to whole tiles (a padded key lies past
     every real query; a padded query's row is dropped)."""
     lq, lk = q.shape[1], k.shape[1]
-    tiles = GLOBAL_BLOCKS if window is None else WINDOW_BLOCKS
+    tiles = GLOBAL_BLOCKS if window is None else window_blocks(window)
 
     def whole(n, tile):
         return -(-n // tile) * tile if n > tile else -(-n // 8) * 8
@@ -267,7 +292,7 @@ def _flash_padded(q, k, v, q_start, k_start, *, scale, window):
     pad = lambda a, n: jnp.pad(      # noqa: E731
         a, ((0, 0), (0, n), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     out, lse = flash_partial(pad(q, pq), pad(k, pk), pad(v, pk), q_start,
-                             k_start, scale=scale, window=window)
+                             k_start, scale=scale, window=window, name=name)
     return out[:, :, :lq].astype(jnp.float32), lse[:, :, :lq]
 
 
@@ -299,6 +324,16 @@ def prefill_attention(q, k, v, q_start, *, scale: float,
                                  window=None)
         return _sink_join(out, lse, sink).astype(q.dtype).transpose(
             0, 2, 1, 3)
+    if cached is not None and (min(l, window) * cached[0].shape[1]
+                               > RING_CORNER_MAX):
+        # a ring too long for the dense corner: one call over the ring's
+        # live rows and the stretch's own, in position order
+        keys, k0 = ring_stretch(cached[0], k, start)
+        vals, _ = ring_stretch(cached[1], v, start)
+        out, lse = _flash_padded(q, keys, vals, start, k0, scale=scale,
+                                 window=window, name=WINDOW_RING_KERNEL)
+        return _sink_join(out, lse, sink).astype(q.dtype).transpose(
+            0, 2, 1, 3)
     out, lse = _flash_padded(q, k, v, start, start, scale=scale,
                              window=window)
     if cached is not None:
@@ -320,6 +355,25 @@ def ring_positions(n, rows: int):
     return last - (last - jnp.arange(rows)[None, :]) % rows
 
 
+def ring_stretch(ring, new, start):
+    """``ring`` (B, R, ...) as it was before a stretch ``new`` (B, L, ...)
+    at positions ``start`` (B,) ``+ 0 .. L - 1``, and the stretch, as ONE
+    run of ``R + L`` rows in position order from ``k0 = max(start - R,
+    0)`` (returned beside it, (B,)): a position before ``start`` comes
+    from the ring (all of those are this sequence's: none is negative,
+    none older than the ring holds), one from ``start`` on from the
+    stretch, and the rows past the stretch's end repeat its last (they
+    lie after every query)."""
+    rows, l = ring.shape[1], new.shape[1]
+    k0 = jnp.maximum(start - rows, 0)
+    pos = k0[:, None] + jnp.arange(rows + l)[None, :]       # (B, R + L)
+    own = pos - start[:, None]
+    src = jnp.where(own >= 0, rows + jnp.clip(own, 0, l - 1), pos % rows)
+    both = jnp.concatenate([ring.astype(new.dtype), new], axis=1)
+    idx = src.reshape(src.shape + (1,) * (new.ndim - 2))
+    return jnp.take_along_axis(both, idx, axis=1), k0
+
+
 def _ring_corner(q, ring_k, ring_v, start, *, scale, window):
     """The first queries of a stretch (B, n, H, Dq) over the ring as it
     was before the stretch: normalised partial output (B, H, n, Dv)
@@ -338,8 +392,8 @@ def _ring_corner(q, ring_k, ring_v, start, *, scale, window):
     p = jnp.where(live[:, None, None], jnp.exp(s - m), 0.0)
     total = jnp.sum(p, axis=-1, keepdims=True)
     o = jnp.einsum("bgrqk,bkgd->bgrqd",
-                   (p / jnp.maximum(total, 1e-30)).astype(ring_v.dtype),
-                   ring_v, preferred_element_type=jnp.float32)
+                   (p / jnp.maximum(total, 1e-30)).astype(q.dtype),
+                   ring_v.astype(q.dtype), preferred_element_type=jnp.float32)
     lse = (m + jnp.log(jnp.maximum(total, 1e-30)))[..., 0]
     return (o.reshape(b, h, n, -1), lse.reshape(b, h, n))
 
@@ -381,7 +435,9 @@ def ring_decode_attention(q, ring_k, ring_v, index, *, scale: float,
         s = jnp.einsum("bgrd,bkgd->bgrk", qg, ring_k.astype(q.dtype),
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(live[:, None, None, :], s, NEG_INF)
-        out = _softmax_sum(s, ring_v, sink, hk)
+        # values, like keys, in the query's precision: a probability of
+        # 1 / 4,096 cast to an fp8 ring's dtype would be zero
+        out = _softmax_sum(s, ring_v.astype(q.dtype), sink, hk)
     return out.reshape(b, 1, h, -1).astype(q.dtype)
 
 
@@ -409,8 +465,8 @@ def decode_attention(q, k, v, index, *, scale: float):
                        preferred_element_type=jnp.float32) * scale
         live = jnp.arange(k.shape[1])[None, :] <= index[:, None]
         s = jnp.where(live[:, None, :], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        wide = jnp.einsum("bhk,bkc->bhc", p, v).reshape(
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        wide = jnp.einsum("bhk,bkc->bhc", p, v.astype(q.dtype)).reshape(
             b, hk, h // hk, hk, dv)
         out = jnp.sum(wide * own[None, :, None, :, None], axis=3)
     return out.reshape(b, 1, h, dv).astype(q.dtype)

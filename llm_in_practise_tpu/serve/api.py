@@ -814,12 +814,22 @@ class OpenAIServer:
                 (stats.view_key,
                  "cache rows those steps' gathered views held (slots x "
                  "pow2 width): attended / view is the share read for "
-                 "something")]
+                 "something"),
+                ("prefill_chunk_tokens",
+                 "real prompt tokens the chunk and mixed programs' rows "
+                 "advanced"),
+                ("prefill_chunk_capacity",
+                 "those rows' trips x the chunk's width: tokens / "
+                 "capacity is how full a chunk-wide trip ran")]
             if stats.ring_rows:
                 families.append((
                     "window_rows_attended",
                     "ring rows the decode steps' window layers attended "
                     "(each active row's min(length, window))"))
+                families.append((
+                    "window_ring_rows_read",
+                    "ring rows those layers read: every slot's whole "
+                    "ring, idle slots too"))
             for key, doc in families:
                 reg.counter_func(f"llm_{key}_total",
                                  lambda a=key: getattr(stats, a), doc)

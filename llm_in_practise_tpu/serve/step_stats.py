@@ -19,7 +19,14 @@ the host by their tokens alone, for two kinds of model:
   (``models/mimo_v2.py``; ``PagedKV.by_slot``): the same two numbers for
   the global layers (one pair of counters, named ``latent_*`` or
   ``global_*`` after the model's cache), and only here the ring rows its
-  window layers attended and a chunk's (query, key) pairs under the band.
+  window layers attended against the ring rows they READ (every slot's
+  whole ring, idle slots too: ``models/afmoe.py``'s is 4,096 rows) and a
+  chunk's (query, key) pairs under the band.
+
+Every model here sends its prompts through the chunk program, one
+chunk-wide trip a row: ``prefill_chunk_tokens`` (real prompt tokens)
+against ``prefill_chunk_capacity`` (trips x the chunk's width) is what a
+prompt shorter than a chunk pays.
 
 :class:`RoutingLoad` is the one place expert load is booked; the
 block-diffusion decoder (``serve/block_step.py``) books its passes into
@@ -101,10 +108,12 @@ class StepStats:
         self.pairs_key = ("prefill_global_pairs" if self.ring_rows
                           else "prefill_qk_pairs")
         for key in (self.attended_key, self.view_key, self.pairs_key,
-                    "prefill_keys_read"):
+                    "prefill_keys_read", "prefill_chunk_tokens",
+                    "prefill_chunk_capacity"):
             setattr(self, key, 0)
         if self.ring_rows:      # what only a window layer has
             self.window_rows_attended = 0
+            self.window_ring_rows_read = 0
             self.prefill_band_pairs = 0
             self.prefill_band_keys_read = 0
         # reference comparisons (tests, the benchmark's check) set this
@@ -186,7 +195,8 @@ class StepStats:
         """A decode (or mixed step's decode half) of ``n`` tokens over
         ``active`` at view width ``width``: the rows its attention needed
         against the rows of the slot plane's view (and, with window
-        layers, the ring rows those layers attended)."""
+        layers, the ring rows those layers attended against the rows
+        they read: every slot's whole ring)."""
         eng = self.eng
         lens = [int(eng.slot_len[s]) + n for s in active]
         counts = {self.attended_key: sum(lens),
@@ -194,6 +204,7 @@ class StepStats:
         if self.ring_rows:
             counts["window_rows_attended"] = sum(
                 min(length, self.ring_rows) for length in lens)
+            counts["window_ring_rows_read"] = eng.max_slots * self.ring_rows
         self._count(**counts)
 
     def note_chunk_rows(self, entries) -> None:
@@ -202,8 +213,13 @@ class StepStats:
         covers (query ``i`` of a chunk that starts at ``done`` sees
         ``done + i + 1`` keys) and the cache rows it reads. With window
         layers: the causal pairs are the global layers', and a window
-        layer's query sees ``min(done + i + 1, ring rows)``."""
+        layer's query sees ``min(done + i + 1, ring rows)``. A row is
+        one trip of the chunk's width, however short its chunk."""
+        entries = list(entries)
+        width = self.eng.chunked_prefill or max(len(c) for _, _, c in entries)
         counts = {
+            "prefill_chunk_tokens": sum(len(c) for _, _, c in entries),
+            "prefill_chunk_capacity": len(entries) * int(width),
             self.pairs_key: sum(
                 len(c) * st["done"] + len(c) * (len(c) + 1) // 2
                 for _, st, c in entries),

@@ -243,6 +243,33 @@ def test_window_prefill_attention_compiles(chip, monkeypatch):
     assert swa.WINDOW_KERNEL in text
 
 
+def test_a_ring_longer_than_a_chunk_goes_through_the_kernel(chip,
+                                                            monkeypatch):
+    """Trinity-Large's window layer (48 heads over 8 K/V heads of 128, a
+    window of 4,096 over a 2,048-token chunk): ONE kernel call over [the
+    ring ‖ the chunk's own keys], and no dense (queries x ring) score
+    tensor in the program (the corner above would be 48 x 2,048 x 4,096
+    float32); MiMo-V2's 128-row ring keeps the other path."""
+    import re
+
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    monkeypatch.setattr(swa, "interpret_default", lambda: False)
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=chip)
+    text = _compile(
+        lambda q, k, v, rk, rv, start: swa.prefill_attention(
+            q, k, v, start, scale=128 ** -0.5, window=4096,
+            cached=(rk, rv)),
+        s((1, 2048, 48, 128)), s((1, 2048, 8, 128)), s((1, 2048, 8, 128)),
+        s((1, 4096, 8, 128)), s((1, 4096, 8, 128)), s((1,), jnp.int32))
+    assert swa.WINDOW_RING_KERNEL in text
+    assert swa.WINDOW_KERNEL + "." not in text.replace(
+        swa.WINDOW_RING_KERNEL, "")
+    assert not re.search(r"f32\[1,48,2048,4096\]|f32\[1,8,6,2048,4096\]",
+                         text)
+
+
 def test_global_page_pool_keeps_its_layout(chip):
     """One global layer of ``mimo-v2.5.agent-context``'s decode program at
     the cell's pool shapes, through the engine's own accessors: keys of 4

@@ -39,6 +39,16 @@ side, ``ops/swa_attention.py::decode_attention``): what ships, the model's
 row being one vector; and ``hyb.window_rows192`` (rows, 8, 192) / (rows, 8, 128), a window
 layer held like a global one (ONE layer of it: five, 12.5 GB, do not fit).
 
+The geometry ``trinity-large.mixed-lengths`` runs (PR 44: 16 slots x
+32,768, ONE global layer of 8 K/V heads, keys and values 128, 4,096 B a
+row, 48 query heads; a row is whole lane tiles either way, so what is left
+to choose is the gather's unit and where the heads split): ``tri.rows8x128``
+(rows, 8, 128), row gather, the Qwen3 pools' form; ``tri.pages1024.heads``
+(pages, 16, 1024), page gather, the view reshaped to heads for a grouped
+einsum (a page's rows written back as vectors); ``tri.pages1024.flat``, the same pools attended FLAT through the
+shipped accessors and ``decode_attention`` (what ships). ``--forms tri``
+runs these alone.
+
 Prints one JSON line a form and view: the entry layout the compiler gives
 the pool, the pool-shaped ``copy`` / ``transpose`` instructions in the
 compiled text, the gather's ``slice_sizes``, cost-analysis bytes, and on a
@@ -210,35 +220,48 @@ def kv_program(form: str, width: int):
 
 
 HYB_PAGES, HYB_DQ, HYB_DV, HYB_Q = S * 32768 // P + 1, 192, 128, 64
+TRI_HK, TRI_D, TRI_Q = 8, 128, 48
 
 
 def hybrid_program(form: str, width: int):
-    """MiMo-V2.5's geometry: key and value pools of a layer kind, a
-    grouped score / sum pair with a (16, 64, 192) query over 192-wide keys
-    and 128-wide values, the 16-row write-back."""
-    hk, layers = (8, 1) if form == "hyb.window_rows192" else (4, 2)
+    """MiMo-V2.5's geometry (``hyb.*``: key and value pools of a layer
+    kind, a grouped score / sum pair with a (16, 64, 192) query over
+    192-wide keys and 128-wide values) or Trinity-Large's (``tri.*``: ONE
+    global layer, 8 K/V heads of 128, a (16, 48, 128) query): the gather,
+    the new row's write, the pair of einsums, the 16-row write-back."""
+    if form.startswith("tri."):
+        hk, layers, dq, dv, n_q = TRI_HK, 1, TRI_D, TRI_D, TRI_Q
+    else:
+        hk, layers = (8, 1) if form == "hyb.window_rows192" else (4, 2)
+        dq, dv, n_q = HYB_DQ, HYB_DV, HYB_Q
     rows = HYB_PAGES * P
-    pad = paged_kv.lane_whole(HYB_DQ)
+    pad = paged_kv.lane_whole(dq)
+    by_rows = ((rows, hk, dq), (rows, hk, dv))
+    by_page = ((HYB_PAGES, P, hk * dq), (HYB_PAGES, P, hk * dv))
     shapes = {
-        "hyb.rows192": ((rows, hk, HYB_DQ), (rows, hk, HYB_DV)),
-        "hyb.window_rows192": ((rows, hk, HYB_DQ), (rows, hk, HYB_DV)),
-        "hyb.flat768": ((rows, hk * HYB_DQ), (rows, hk * HYB_DV)),
-        "hyb.pages768": ((HYB_PAGES, P, hk * HYB_DQ),
-                         (HYB_PAGES, P, hk * HYB_DV)),
-        "hyb.pages768.flat": ((HYB_PAGES, P, hk * HYB_DQ),
-                              (HYB_PAGES, P, hk * HYB_DV)),
-        "hyb.rows256": ((rows, hk, pad), (rows, hk, HYB_DV)),
+        "hyb.rows192": by_rows, "hyb.window_rows192": by_rows,
+        "hyb.flat768": ((rows, hk * dq), (rows, hk * dv)),
+        "hyb.pages768": by_page, "hyb.pages768.flat": by_page,
+        "hyb.rows256": ((rows, hk, pad), (rows, hk, dv)),
+        "tri.rows8x128": by_rows, "tri.pages1024.heads": by_page,
+        "tri.pages1024.flat": by_page,
     }[form]
-    by_pages = form.startswith("hyb.pages768")
-    if form == "hyb.pages768.flat":
-        return _hybrid_flat(shapes, layers, hk, width)
+    by_pages = shapes is by_page
+    bf, i32 = jnp.bfloat16, jnp.int32
+    args = ([tuple(jax.ShapeDtypeStruct(sh, bf) for sh in shapes)] * layers,
+            jax.ShapeDtypeStruct((S, width // P if by_pages else width), i32),
+            jax.ShapeDtypeStruct((S,), i32), jax.ShapeDtypeStruct((S,), i32),
+            jax.ShapeDtypeStruct((S, n_q, dq), bf),
+            jax.ShapeDtypeStruct((S, hk, dq), bf),
+            jax.ShapeDtypeStruct((S, hk, dv), bf))
+    if form.endswith(".flat"):
+        return _hybrid_flat, args, shapes[0]
 
     def fn(pools, idx, flat, pos, q, new_k, new_v):
         out, acc = [], 0.0
         for k_buf, v_buf in pools:
             views = []
-            for buf, new, dim in ((k_buf, new_k, HYB_DQ),
-                                  (v_buf, new_v, HYB_DV)):
+            for buf, new, dim in ((k_buf, new_k, dq), (v_buf, new_v, dv)):
                 got = jnp.take(buf, idx.reshape(-1), axis=0, mode="clip")
                 view = got.reshape(S, width, hk, -1)[..., :dim]
                 views.append(jax.vmap(
@@ -246,7 +269,7 @@ def hybrid_program(form: str, width: int):
                         v, n[None], (i, 0, 0)))(view, new, pos))
             k, v = views
             s = jnp.einsum("bgrd,bkgd->bgrk",
-                           q.reshape(S, hk, HYB_Q // hk, HYB_DQ), k,
+                           q.reshape(S, hk, n_q // hk, dq), k,
                            preferred_element_type=jnp.float32)
             p = jax.nn.softmax(s, axis=-1).astype(k.dtype)
             acc = acc + jnp.einsum("bgrk,bkgd->bgrd", p, v)
@@ -255,7 +278,7 @@ def hybrid_program(form: str, width: int):
                 rows_new = _rows_of(view, pos)
                 if buf.shape[-1] == pad and form == "hyb.rows256":
                     rows_new = jnp.pad(rows_new, ((0, 0), (0, 0),
-                                                  (0, pad - HYB_DQ)))
+                                                  (0, pad - dq)))
                 rows_new = rows_new.reshape((S,) + buf.shape[
                     2 if by_pages else 1:])
                 if by_pages:
@@ -266,45 +289,28 @@ def hybrid_program(form: str, width: int):
             out.append(tuple(pair))
         return out, acc
 
-    bf, i32 = jnp.bfloat16, jnp.int32
-    args = ([tuple(jax.ShapeDtypeStruct(sh, bf) for sh in shapes)] * layers,
-            jax.ShapeDtypeStruct((S, width // P if by_pages else width), i32),
-            jax.ShapeDtypeStruct((S,), i32), jax.ShapeDtypeStruct((S,), i32),
-            jax.ShapeDtypeStruct((S, HYB_Q, HYB_DQ), bf),
-            jax.ShapeDtypeStruct((S, hk, HYB_DQ), bf),
-            jax.ShapeDtypeStruct((S, hk, HYB_DV), bf))
     return fn, args, shapes[0]
 
 
-def _hybrid_flat(shapes, layers, hk, width):
+def _hybrid_flat(pools, idx, flat, pos, q, new_k, new_v):
     """The shipped path: ``paged_kv.take_pages`` / ``set_page_rows`` and
     ``swa_attention.decode_attention`` over the flat view."""
     from llm_in_practise_tpu.ops import swa_attention as swa
 
-    def fn(pools, idx, flat, pos, q, new_k, new_v):
-        out, acc = [], 0.0
-        for k_buf, v_buf in pools:
-            views = []
-            for buf, new in ((k_buf, new_k), (v_buf, new_v)):
-                view = paged_kv.take_pages(buf, idx, buf.shape[-1])
-                views.append(jax.vmap(
-                    lambda v, n, i: jax.lax.dynamic_update_slice(
-                        v, n.reshape(1, -1), (i, 0)))(view, new, pos))
-            acc = acc + swa.decode_attention(q[:, None], *views, pos,
-                                             scale=0.07)
-            out.append(tuple(
-                paged_kv.set_page_rows(buf, flat, _rows_of(view, pos))
-                for buf, view in zip((k_buf, v_buf), views)))
-        return out, acc
-
-    bf, i32 = jnp.bfloat16, jnp.int32
-    args = ([tuple(jax.ShapeDtypeStruct(sh, bf) for sh in shapes)] * layers,
-            jax.ShapeDtypeStruct((S, width // P), i32),
-            jax.ShapeDtypeStruct((S,), i32), jax.ShapeDtypeStruct((S,), i32),
-            jax.ShapeDtypeStruct((S, HYB_Q, HYB_DQ), bf),
-            jax.ShapeDtypeStruct((S, hk, HYB_DQ), bf),
-            jax.ShapeDtypeStruct((S, hk, HYB_DV), bf))
-    return fn, args, shapes[0]
+    out, acc = [], 0.0
+    for k_buf, v_buf in pools:
+        views = []
+        for buf, new in ((k_buf, new_k), (v_buf, new_v)):
+            view = paged_kv.take_pages(buf, idx, buf.shape[-1])
+            views.append(jax.vmap(
+                lambda v, n, i: jax.lax.dynamic_update_slice(
+                    v, n.reshape(1, -1), (i, 0)))(view, new, pos))
+        acc = acc + swa.decode_attention(q[:, None], *views, pos,
+                                         scale=0.07)
+        out.append(tuple(
+            paged_kv.set_page_rows(buf, flat, _rows_of(view, pos))
+            for buf, view in zip((k_buf, v_buf), views)))
+    return out, acc
 
 
 def read_text(text: str, shape: tuple) -> dict:
@@ -373,7 +379,12 @@ def main() -> int:
              + [(hybrid_program, f, w, HYB_PAGES) for w in (8192, 32768)
                 for f in ("hyb.rows192", "hyb.flat768", "hyb.pages768",
                           "hyb.pages768.flat", "hyb.rows256",
-                          "hyb.window_rows192")])
+                          "hyb.window_rows192")]
+             + [(hybrid_program, f, w, HYB_PAGES) for w in (8192, 32768)
+                for f in ("tri.rows8x128", "tri.pages1024.heads",
+                          "tri.pages1024.flat")])
+    if sys.argv[1:2] == ["--forms"]:
+        cases = [c for c in cases if c[1].startswith(sys.argv[2])]
     for program, form, width, n_pages in cases:
         fn, args, shape = program(form, width)
         line = {"form": form, "view": width, "pool": list(shape),

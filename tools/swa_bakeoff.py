@@ -19,6 +19,16 @@ global layer, 8 and a window of 128 in a window layer), for the shapes
   (a global layer: 768 and 512 wide, the heads split on the query's side)
   and over the ring (a window layer), XLA einsums.
 
+``--geometry trinity`` (PR 44) runs :func:`long_band` instead, at
+Trinity-Large's widths (48 query heads on 8 K/V heads, keys and values
+128, a window of 4,096) for the shapes ``trinity-large.mixed-lengths``
+runs: the global chunk over 4,096 / 16,384 / 32,768 keys; a window
+layer's chunk of 2,048 queries under a band of 4,096 over 6,144 keys
+(``[the ring ‖ its own keys]``) over the tiles, and the whole
+``prefill_attention`` of such a layer (the ring put in order + the
+kernel); decode over flat views against a view with a head axis, and
+over 16 rings of 4,096 rows.
+
 Prints one JSON line a variant (median milliseconds, the key blocks a
 (batch, head) visits, the share of the form's own least time) and writes
 them to ``chiprun_out/swa_bakeoff.json``. Refuses to run without a TPU.
@@ -52,6 +62,117 @@ def timed(fn, *args, reps: int = 5) -> float:
     return 1e3 * float(np.median(out))
 
 
+def long_band(emit, peak_flops: float, peak_bw: float) -> None:
+    """Trinity-Large's geometry (module docstring)."""
+    from benchmark import flops_swa
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    h, hk, d, window, lq = 48, 8, 128, 4096, 2048
+    bf = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    rnd = lambda i, shape: jax.random.normal(  # noqa: E731
+        keys[i], shape).astype(bf)
+    scale = d ** -0.5
+
+    def least_ms(pairs, rows):
+        fl, by = flops_swa.attention_cost(pairs, rows, 1, h, hk, d, d)
+        return 1e3 * max(fl / peak_flops, by / peak_bw)
+
+    flash = jax.jit(swa.flash_partial, static_argnames=(
+        "scale", "window", "block_q", "block_k", "fold", "name"))
+    q = rnd(0, (1, h, lq, d))
+    zero = jnp.zeros((1,), jnp.int32)
+    for n_keys in (4096, 16384, 32768):
+        k, v = rnd(1, (1, hk, n_keys, d)), rnd(2, (1, hk, n_keys, d))
+        start = jnp.asarray([n_keys - lq], jnp.int32)
+        least = least_ms(flops_swa.causal_pairs(n_keys - lq, lq), n_keys)
+        for bq, bk in ((512, 512), (512, 1024), (1024, 512), (1024, 1024),
+                       (2048, 512), (1024, 2048)):
+            try:
+                ms = timed(lambda *a, _kw=dict(  # noqa: E731
+                    scale=scale, block_q=bq, block_k=bk): flash(*a, **_kw),
+                    q, k, v, start, zero)
+                emit(what="global_chunk", keys=n_keys, block_q=bq,
+                     block_k=bk, ms=ms, least_ms=least,
+                     roofline_pct=100 * least / ms)
+            except Exception as e:      # a tile the compiler refuses
+                emit(what="global_chunk", keys=n_keys, block_q=bq,
+                     block_k=bk, error=str(e)[:300])
+
+    # a chunk at 12,288: the ring holds 8,192 .. 12,287, 6,144 keys in all
+    n_keys, at = window + lq, 12288
+    k, v = rnd(3, (1, hk, n_keys, d)), rnd(4, (1, hk, n_keys, d))
+    start, k0 = (jnp.asarray([p], jnp.int32) for p in (at, at - window))
+    least = least_ms(flops_swa.band_pairs(at, lq, window),
+                     flops_swa.band_keys(at, lq, window))
+    for fold, tiles in (
+            (True, ((128, 512), (128, 1024), (256, 256), (256, 512),
+                    (256, 1024), (512, 512))),
+            (False, ((512, 512), (512, 1024), (1024, 512), (1024, 1024)))):
+        for bq, bk in tiles:
+            try:
+                ms = timed(lambda *a, _kw=dict(  # noqa: E731
+                    scale=scale, window=window, block_q=bq, block_k=bk,
+                    fold=fold, name=swa.WINDOW_RING_KERNEL): flash(
+                        *a, **_kw), q, k, v, start, k0)
+                emit(what="ring_chunk", fold=fold, block_q=bq, block_k=bk,
+                     ms=ms, band_least_ms=least,
+                     roofline_pct=100 * least / ms,
+                     key_blocks=swa.key_blocks_visited(
+                         at, at - window, lq, n_keys, window=window,
+                         block_q=bq, block_k=bk))
+            except Exception as e:
+                emit(what="ring_chunk", fold=fold, block_q=bq, block_k=bk,
+                     error=str(e)[:300])
+    whole = jax.jit(lambda q, k, v, rk, rv, st: swa.prefill_attention(
+        q, k, v, st, scale=scale, window=window, cached=(rk, rv)))
+    order = jax.jit(lambda rk, k, st: swa.ring_stretch(rk, k, st)[0])
+    own_k, own_v = rnd(5, (1, lq, hk, d)), rnd(6, (1, lq, hk, d))
+    ring_k, ring_v = rnd(1, (1, window, hk, d)), rnd(2, (1, window, hk, d))
+    emit(what="window_layer_chunk", ms=timed(
+        whole, q.transpose(0, 2, 1, 3), own_k, own_v, ring_k, ring_v,
+        start), ring_in_order_ms=timed(order, ring_k, own_k, start),
+        band_least_ms=least, tiles=list(swa.window_blocks(window)))
+
+    decode = jax.jit(swa.decode_attention, static_argnames=("scale",))
+    ring = jax.jit(swa.ring_decode_attention,
+                   static_argnames=("scale", "window"))
+
+    @jax.jit
+    def by_heads(q1, k, v, index):
+        """The same query over a view WITH a head axis (B, W, Hk, 128):
+        no widened query, the view re-laid out if the chip wants it."""
+        b = q1.shape[0]
+        qg = q1[:, 0].reshape(b, hk, h // hk, d)
+        s = jnp.einsum("bgrd,bkgd->bgrk", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        live = jnp.arange(k.shape[1])[None, :] <= index[:, None]
+        p = jax.nn.softmax(jnp.where(live[:, None, None], s, -1e30),
+                           axis=-1).astype(v.dtype)
+        return jnp.einsum("bgrk,bkgd->bgrd", p, v).reshape(b, 1, h, d)
+
+    q1 = rnd(7, (16, 1, h, d))
+    for width in (8192, 32768):
+        k, v = rnd(1, (16, width, hk * d)), rnd(2, (16, width, hk * d))
+        index = jnp.full((16,), width * 3 // 4, jnp.int32)
+        rows = 16 * (width * 3 // 4 + 1)
+        least = least_ms(rows, rows)
+        for form, fn, args in (
+                ("flat", lambda *a: decode(*a, scale=scale), (k, v)),
+                ("heads", by_heads, (k.reshape(16, width, hk, d),
+                                     v.reshape(16, width, hk, d)))):
+            ms = timed(fn, q1, *args, index)
+            emit(what="global_decode", form=form, rows=16, view=width,
+                 ms=ms, least_ms=least, roofline_pct=100 * least / ms,
+                 view_read_ms=1e3 * (k.size + v.size) * 2 / peak_bw)
+    k, v = rnd(3, (16, window, hk, d)), rnd(4, (16, window, hk, d))
+    ms = timed(lambda *a: ring(*a, scale=scale, window=window), q1, k, v,
+               jnp.full((16,), 20000, jnp.int32))
+    emit(what="window_decode", rows=16, ring_rows=window, ms=ms,
+         ring_read_ms=1e3 * (k.size + v.size) * 2 / peak_bw,
+         roofline_pct=100 * least_ms(16 * window, 16 * window) / ms)
+
+
 def main() -> int:
     from benchmark import device, flops_swa
     from llm_in_practise_tpu.core.mesh import require_tpu
@@ -59,16 +180,27 @@ def main() -> int:
 
     require_tpu()
     peak_flops, peak_bw = device.peaks(jax.devices()[0].device_kind)
-    bf = jnp.bfloat16
-    keys = jax.random.split(jax.random.PRNGKey(0), 8)
-    rnd = lambda i, shape: jax.random.normal(  # noqa: E731
-        keys[i], shape).astype(bf)
-    scale = DQ ** -0.5
     lines = []
 
     def emit(**kw):
         lines.append(kw)
         print(json.dumps(kw), flush=True)
+
+    def write(name: str) -> int:
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(ROOT, "chiprun_out", name), "w",
+                  encoding="utf-8") as f:
+            json.dump(lines, f, indent=1)
+        return 0
+
+    if sys.argv[1:] == ["--geometry", "trinity"]:
+        long_band(emit, peak_flops, peak_bw)
+        return write("swa_bakeoff_trinity.json")
+    bf = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    rnd = lambda i, shape: jax.random.normal(  # noqa: E731
+        keys[i], shape).astype(bf)
+    scale = DQ ** -0.5
 
     def least_ms(pairs, rows, hk):
         fl, by = flops_swa.attention_cost(pairs, rows, 1, H, hk, DQ, DV)
@@ -156,11 +288,7 @@ def main() -> int:
         jnp.full((16,), 20000, jnp.int32)),
         ring_read_ms=1e3 * (k.size + v.size) * 2 / peak_bw)
 
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "swa_bakeoff.json"), "w",
-              encoding="utf-8") as f:
-        json.dump(lines, f, indent=1)
-    return 0
+    return write("swa_bakeoff.json")
 
 
 if __name__ == "__main__":
