@@ -212,8 +212,8 @@ class Qwen3Attention(nn.Module):
         # GQA: k/v go in with their n_kv_head heads — the dense path
         # contracts against them grouped (no broadcast ever exists in
         # HBM; a materialized jnp.repeat here measured ~256 MB/layer/step
-        # at 8B decode, docs/perf.md Finding 14), and the flash path
-        # repeats internally only when actually taken.
+        # at 8B decode, docs/perf.md Finding 14), and the flash kernel
+        # finds a query head's K/V head in its index maps.
         out = dot_product_attention(
             q, k, v,
             causal=True, q_offset=q_offset,

@@ -182,14 +182,6 @@ def dot_product_attention(
                 and k.shape[:2] == q.shape[:2]
                 and k.shape[3] == q.shape[3]
                 and q.shape[2] % k.shape[2] == 0):
-            if k.shape[2] != q.shape[2]:
-                # the kernel wants equal heads; materializing the GQA
-                # broadcast is fine HERE — flash only wins at training
-                # lengths where the repeat is amortized over the whole
-                # sequence (decode takes the grouped dense path)
-                g = q.shape[2] // k.shape[2]
-                k = jnp.repeat(k, g, axis=2)
-                v = jnp.repeat(v, g, axis=2)
             return fa.flash_attention(q, k, v, causal=causal, scale=scale)
         impl = "dense"  # flash kernel doesn't cover these yet
     return dense_attention(
@@ -227,16 +219,22 @@ def _pick_impl(q, k, bias, kv_length, dropout_rate, causal=True) -> str:
     ):
         return "dense"
     batch, q_len, n_head, head_dim = q.shape
-    # Measured on one v5e chip (GPTLike 6L/512d training step): XLA's
-    # fused dense attention beats the Pallas kernel on short sequences —
-    # 357K vs 253K tok/s at L=256, +23% at L=512 — the kernel's tiling
-    # overhead dominates small (L, L) score blocks. The flip side is the
+    # Measured on one v5e chip (GPTLike 6L/512d training step, 8 heads of
+    # 64) against the kernel of its day (128 x 128 tiles, float32 MXU
+    # operands): XLA's fused dense attention won on short sequences —
+    # 357K vs 253K tok/s at L=256, +23% at L=512. The flip side is the
     # dense path's f32 score materialization, B·H·L² bytes ×2 held for
     # the backward: at L=1024 training batches it no longer compiles.
-    # Gate dense on BOTH the measured length crossover (the 512..1K
-    # region is unmeasured — 512 is the last point dense provably wins)
-    # and an absolute score-memory bound so wide-and-batchy shapes at
-    # L<=512 don't trade the kernel's O(L) memory for an HBM blowup.
+    # The kernel since re-measured (tools/flash_bakeoff.py, docs/perf.md
+    # Finding 3; bf16, B 8, 40 / 8 heads of 128, forward + backward of one
+    # call): at L=1024 3.0 ms where that older kernel took 32.3 and dense,
+    # alone, 17.7; at 768 2.0 against dense's 9.5; at 512 1.3 against 4.6;
+    # at 256 dense wins, 0.56 against 0.72. The crossover stays at 512:
+    # the shape that set it has 64-wide heads, which take the kernel's
+    # heads-in-front layout, and that side is not re-measured.
+    # Gate dense on BOTH that length crossover and an absolute
+    # score-memory bound so wide-and-batchy shapes at L<=512 don't trade
+    # the kernel's O(L) memory for an HBM blowup.
     score_bytes = 4 * batch * n_head * q_len * q_len
     # 2 GiB inclusive: the measured dense win at L=512/B=256/H=8 sits
     # exactly at the bound (and compiled + ran), so it stays admitted

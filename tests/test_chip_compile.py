@@ -87,8 +87,17 @@ def test_int4_matmul_compiles(chip):
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_flash_attention_compiles(chip, grad):
-    q = jax.ShapeDtypeStruct((1, 2048, 32, 128), jnp.bfloat16, sharding=chip)
+@pytest.mark.parametrize("shape,kv_heads,dtype", [
+    ((1, 2048, 32, 128), 32, jnp.bfloat16),   # two 1,024 tiles a side
+    ((8, 1024, 40, 128), 8, jnp.bfloat16),    # qwen3-14b-qlora.sft-1k
+    ((1, 1152, 8, 128), 2, jnp.bfloat16),     # 384 tiles: 9 x 128
+    ((2, 1024, 8, 64), 8, jnp.bfloat16),      # heads in front: 64 lanes
+    ((2, 1024, 8, 256), 2, jnp.float32),      # a 256-wide float32 head
+], ids=["mha-2k", "qlora-cell", "l1152", "d64", "d256-f32"])
+def test_flash_attention_compiles(chip, grad, shape, kv_heads, dtype):
+    b, length, _, d = shape
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    k = jax.ShapeDtypeStruct((b, length, kv_heads, d), dtype, sharding=chip)
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, interpret=False)
@@ -98,8 +107,8 @@ def test_flash_attention_compiles(chip, grad):
             lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    text = _compile(bwd if grad else fwd, q, q, q)
-    # forward + dK/dV + dQ kernels in the backward program
+    text = _compile(bwd if grad else fwd, q, k, k)
+    # forward + dQ + dK/dV kernels in the backward program
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
 
 
