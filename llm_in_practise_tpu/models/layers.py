@@ -131,6 +131,11 @@ LOAD_KEY, ROUTE_KEY = "moe_load", "moe_route"
 # sliding-window layer's ring, models/mimo_v2.py): (B,) how many of the
 # call's positions are real for each row (None: all of them).
 VALID_KEY = "valid"
+# Entry the chunk program gives such a layer of a model that declares
+# ``reads_finish`` (models/phi4flash.py): (B,) bool, whether the row's
+# prompt ENDS in this call. A model whose later layers only read (a
+# cross-decoder) runs them at a prompt's last position and nowhere else.
+FINISH_KEY = "finish"
 
 
 # --- a prefill's tail: one row of logits a prompt ---------------------------

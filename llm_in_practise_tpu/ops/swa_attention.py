@@ -163,13 +163,16 @@ def _flash_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def flash_partial(q, k, v, q_start, k_start, *, scale: float,
                   window: int | None = None, block_q: int | None = None,
                   block_k: int | None = None, fold: bool | None = None,
-                  interpret: bool | None = None, name: str | None = None):
+                  interpret: bool | None = None, name: str | None = None,
+                  out_dtype=None):
     """Attention of ``q`` (B, H, Lq, Dq) over ONE stretch of keys ``k``
     (B, Hk, Lk, Dq) / values ``v`` (B, Hk, Lk, Dv), ``H`` a multiple of
     ``Hk``; ``q_start`` / ``k_start`` (B,) are the absolute positions of
     the first query and the first key, neither negative. Returns the
     stretch's own softmax-normalised output (B, H, Lq, Dv) and its
-    log-sum-exp (B, H, Lq) float32, for :func:`join`.
+    log-sum-exp (B, H, Lq) float32, for :func:`join`. The output is in
+    ``out_dtype`` (default: the queries'; a caller that SUBTRACTS two
+    outputs asks for float32: the difference amplifies the rounding).
 
     ``fold`` (default: under a band): a q tile holds ``block_q`` positions
     of every query head of one K/V head, so a grid step multiplies
@@ -236,7 +239,8 @@ def flash_partial(q, k, v, q_start, k_start, *, scale: float,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h // per, n_q * tile, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, h // per, n_q * tile, dv),
+                                 out_dtype or q.dtype),
             jax.ShapeDtypeStruct((b, h // per, _SUBLANE, n_q * tile),
                                  jnp.float32),
         ],
@@ -278,7 +282,8 @@ def key_blocks_visited(q_start: int, k_start: int, lq: int, lk: int, *,
     return visited
 
 
-def _flash_padded(q, k, v, q_start, k_start, *, scale, window, name=None):
+def _flash_padded(q, k, v, q_start, k_start, *, scale, window, name=None,
+                  out_dtype=None):
     """:func:`flash_partial` for (B, L, H, D) operands of any length:
     heads first, lengths padded to whole tiles (a padded key lies past
     every real query; a padded query's row is dropped)."""
@@ -292,7 +297,8 @@ def _flash_padded(q, k, v, q_start, k_start, *, scale, window, name=None):
     pad = lambda a, n: jnp.pad(      # noqa: E731
         a, ((0, 0), (0, n), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     out, lse = flash_partial(pad(q, pq), pad(k, pk), pad(v, pk), q_start,
-                             k_start, scale=scale, window=window, name=name)
+                             k_start, scale=scale, window=window, name=name,
+                             out_dtype=out_dtype)
     return out[:, :, :lq].astype(jnp.float32), lse[:, :, :lq]
 
 
@@ -308,7 +314,7 @@ def _sink_join(out, lse, sink):
 
 def prefill_attention(q, k, v, q_start, *, scale: float,
                       window: int | None = None, sink=None,
-                      cached=None):
+                      cached=None, out_dtype=None):
     """A stretch of queries ``q`` (B, L, H, Dq) at positions ``q_start``
     (scalar or (B,)) ``+ 0 .. L - 1``. Returns (B, L, H, Dv).
 
@@ -316,14 +322,15 @@ def prefill_attention(q, k, v, q_start, *, scale: float,
     WHOLE view from position 0, the stretch's own rows already written
     into it. Window layer: ``k`` / ``v`` (B, L, Hk, ·) are the stretch's
     own, and ``cached`` = ``(ring_k, ring_v)`` the layer's ring as it was
-    before the stretch (None: the stretch starts the sequence)."""
+    before the stretch (None: the stretch starts the sequence). The result
+    is in ``out_dtype`` (default: the queries')."""
     b, l = q.shape[:2]
     start = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (b,))
+    to = out_dtype or q.dtype
     if window is None:
         out, lse = _flash_padded(q, k, v, start, 0, scale=scale,
-                                 window=None)
-        return _sink_join(out, lse, sink).astype(q.dtype).transpose(
-            0, 2, 1, 3)
+                                 window=None, out_dtype=out_dtype)
+        return _sink_join(out, lse, sink).astype(to).transpose(0, 2, 1, 3)
     if cached is not None and (min(l, window) * cached[0].shape[1]
                                > RING_CORNER_MAX):
         # a ring too long for the dense corner: one call over the ring's
@@ -331,11 +338,11 @@ def prefill_attention(q, k, v, q_start, *, scale: float,
         keys, k0 = ring_stretch(cached[0], k, start)
         vals, _ = ring_stretch(cached[1], v, start)
         out, lse = _flash_padded(q, keys, vals, start, k0, scale=scale,
-                                 window=window, name=WINDOW_RING_KERNEL)
-        return _sink_join(out, lse, sink).astype(q.dtype).transpose(
-            0, 2, 1, 3)
+                                 window=window, name=WINDOW_RING_KERNEL,
+                                 out_dtype=out_dtype)
+        return _sink_join(out, lse, sink).astype(to).transpose(0, 2, 1, 3)
     out, lse = _flash_padded(q, k, v, start, start, scale=scale,
-                             window=window)
+                             window=window, out_dtype=out_dtype)
     if cached is not None:
         # only the first window - 1 queries reach back into the ring
         n = min(l, window)
@@ -344,7 +351,7 @@ def prefill_attention(q, k, v, q_start, *, scale: float,
         head, lse_head = join(out[:, :, :n], lse[:, :, :n], o_r, lse_r)
         out = jnp.concatenate([head, out[:, :, n:]], axis=2)
         lse = jnp.concatenate([lse_head, lse[:, :, n:]], axis=2)
-    return _sink_join(out, lse, sink).astype(q.dtype).transpose(0, 2, 1, 3)
+    return _sink_join(out, lse, sink).astype(to).transpose(0, 2, 1, 3)
 
 
 def ring_positions(n, rows: int):
@@ -470,6 +477,63 @@ def decode_attention(q, k, v, index, *, scale: float):
             b, hk, h // hk, hk, dv)
         out = jnp.sum(wide * own[None, :, None, :, None], axis=3)
     return out.reshape(b, 1, h, dv).astype(q.dtype)
+
+
+def paired_decode_attention(qs, ks, v, index, *, scale: float):
+    """:func:`decode_attention` for SEVERAL softmaxes that share one view
+    of values (differential attention, ``models/phi4flash.py``): query
+    ``qs[i]`` (B, 1, H, Dq) reads its own flat keys ``ks[i]`` (B, W, Hk *
+    Dq), and all of them the one ``v`` (B, W, Hk * Dv), which is read ONCE
+    for their stacked probabilities. Returns a tuple of (B, 1, H, Dv)
+    float32: the caller subtracts them."""
+    b, _, h, dq = qs[0].shape
+    hk = ks[0].shape[-1] // dq
+    dv = v.shape[-1] // hk
+    n = len(qs)
+    with jax.named_scope(GLOBAL_DECODE_SCOPE):
+        own = jnp.eye(hk, dtype=qs[0].dtype)
+        live = jnp.arange(v.shape[1])[None, :] <= index[:, None]
+        ps = []
+        for q, k in zip(qs, ks):
+            qg = q[:, 0].reshape(b, hk, h // hk, 1, dq)
+            q_wide = (qg * own[None, :, None, :, None]).reshape(
+                b, h, hk * dq)
+            s = jnp.einsum("bhc,bkc->bhk", q_wide, k.astype(q.dtype),
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(live[:, None, :], s, NEG_INF)
+            ps.append(jax.nn.softmax(s, axis=-1).astype(q.dtype))
+        wide = jnp.einsum("bhk,bkc->bhc", jnp.concatenate(ps, axis=1),
+                          v.astype(qs[0].dtype),
+                          preferred_element_type=jnp.float32).reshape(
+            b, n, hk, h // hk, hk, dv)
+        out = jnp.sum(wide * own.astype(jnp.float32)[
+            None, None, :, None, :, None], axis=4)
+    return tuple(out[:, i].reshape(b, 1, h, dv) for i in range(n))
+
+
+def paired_ring_decode_attention(qs, ring_ks, ring_v, index, *,
+                                 scale: float, window: int):
+    """:func:`ring_decode_attention` for several softmaxes over their own
+    key rings ``ring_ks[i]`` (B, R, Hk, Dq) and ONE ring of values (B, R,
+    Hk, Dv), read once. Returns a tuple of (B, 1, H, Dv) float32."""
+    b, _, h, _ = qs[0].shape
+    hk, n = ring_v.shape[2], len(qs)
+    group = h // hk
+    with jax.named_scope(WINDOW_DECODE_SCOPE):
+        pos = ring_positions(index + 1, ring_v.shape[1])    # (B, R)
+        live = (pos >= 0) & (index[:, None] - pos < window)
+        ps = []
+        for q, ring_k in zip(qs, ring_ks):
+            qg = q[:, 0].reshape(b, hk, group, -1)
+            s = jnp.einsum("bgrd,bkgd->bgrk", qg, ring_k.astype(q.dtype),
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(live[:, None, None, :], s, NEG_INF)
+            ps.append(jax.nn.softmax(s, axis=-1).astype(q.dtype))
+        out = jnp.einsum("bgrk,bkgd->bgrd", jnp.concatenate(ps, axis=2),
+                         ring_v.astype(qs[0].dtype),
+                         preferred_element_type=jnp.float32)
+    return tuple(out[:, :, i * group:(i + 1) * group].reshape(b, 1, h, -1)
+                 for i in range(n))
 
 
 def _softmax_sum(s, v, sink, hk):

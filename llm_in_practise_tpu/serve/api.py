@@ -830,6 +830,33 @@ class OpenAIServer:
                     "window_ring_rows_read",
                     "ring rows those layers read: every slot's whole "
                     "ring, idle slots too"))
+            if stats.census:
+                # recurrent layers and a cross-decoder
+                # (models/phi4flash.py), booked by the host
+                families += [
+                    ("ssm_scan_tokens",
+                     "real prompt positions the chunk rows' recurrent "
+                     "layers scanned (a layer each)"),
+                    ("ssm_state_rows_advanced",
+                     "decode-plane rows x steps whose recurrent state "
+                     "moved (the live rows)"),
+                    ("ssm_state_rows_held",
+                     "decode-plane rows x steps the state was held for: "
+                     "every slot, idle and mid-prefill ones unchanged"),
+                    ("self_decoder_rows",
+                     "positions that passed the self-decoder: chunk "
+                     "tokens and the decode plane's rows"),
+                    ("cross_decoder_rows",
+                     "positions that passed the cross-decoder: the "
+                     "decode plane's rows and ONE of each prompt, its "
+                     "last"),
+                    ("cross_decoder_prefill_rows",
+                     "prompt positions that passed the cross-decoder "
+                     "(one a prompt: a chunk's other positions skip it)"),
+                    ("shared_kv_rows_attended",
+                     "rows of the one paged layer's view the decode "
+                     "steps' readers attended: true lengths x the layers "
+                     "that read it")]
             for key, doc in families:
                 reg.counter_func(f"llm_{key}_total",
                                  lambda a=key: getattr(stats, a), doc)
@@ -849,6 +876,13 @@ class OpenAIServer:
                 "bytes of the layers held by slot (a sliding-window "
                 "layer's ring: bounded whatever the context; ledger "
                 "account kv.window_state); 0 for a model without them")
+            reg.gauge_func(
+                "llm_kv_recurrent_state_bytes",
+                lambda: eng.paged.recurrent_state_bytes,
+                "bytes, of llm_kv_window_state_bytes, that are a "
+                "recurrent layer's state: replaced at every position, "
+                "never appended, in a dtype of its own; 0 for a model "
+                "without such layers")
             reg.counter_func(
                 "llm_kv_view_pages_gathered_total",
                 lambda: eng.view_pages_gathered,

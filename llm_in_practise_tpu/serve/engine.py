@@ -49,6 +49,7 @@ from llm_in_practise_tpu.infer.sampling import (
     sampler_tier_name,
 )
 from llm_in_practise_tpu.models.layers import (
+    FINISH_KEY,
     VALID_KEY,
     head_logits,
     last_position_hidden,
@@ -665,6 +666,11 @@ class InferenceEngine:
         # (one-shot admission's bucket-wide rows carry padding)
         self._slot_state = (self.paged is not None
                             and any(self.paged.by_slot))
+        # a model whose later layers only read (a cross-decoder) runs them
+        # at a prompt's last position alone: the chunk program tells it
+        # which rows' prompts end (models/layers.py FINISH_KEY)
+        self._reads_finish = self._slot_state and bool(getattr(
+            getattr(model, "inner", model), "reads_finish", False))
         self.preemptions = 0            # paged pool-pressure preemptions
         self.rejected_too_large = 0     # prompts that can NEVER fit the pool
         self._paged_admit_blocked = False
@@ -1717,7 +1723,8 @@ class InferenceEngine:
     # pages from block-table columns, its writes split the host's flat
     # rows into (page, offset); a flat pool's programs are untouched.
 
-    def _paged_view(self, pool, gidx, index_vec, *, slot=None, valid=None):
+    def _paged_view(self, pool, gidx, index_vec, *, slot=None, valid=None,
+                    finish=None):
         """Gather each slot's pages into a contiguous cache view
         (slots, W, ...) with the per-slot index pinned from the host.
         ``gidx`` is :meth:`PagedKV.view_idx`'s: pool rows (S, W) for a
@@ -1726,7 +1733,9 @@ class InferenceEngine:
         bounded) is not gathered: the model gets the plane's rows as they
         are, or the one row of ``slot`` (1,), and ``valid`` (S,), how
         many of this call's positions are real for each row (a layer
-        that owns its writes must not take padding or a dead row's)."""
+        that owns its writes must not take padding or a dead row's), and,
+        for a model that asks (``reads_finish``), ``finish`` (S,) bool:
+        whether the row's prompt ends in this call."""
         by_pages = self.paged.form == "pages"
         S, W = gidx.shape
         flat = gidx.reshape(-1)
@@ -1740,6 +1749,8 @@ class InferenceEngine:
             d = {"index": index_vec.astype(jnp.int32), **more}
             if bounded:
                 d[VALID_KEY] = valid
+                if finish is not None:
+                    d[FINISH_KEY] = finish
                 for key, buf in layer.items():
                     d[key] = (buf if slot is None else
                               jax.lax.dynamic_slice_in_dim(
@@ -1910,8 +1921,10 @@ class InferenceEngine:
             slot, r_gidx, r_ids, r_starts, r_lens, r_sidx = (
                 jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)
                 for a in (slots, gidx, chunk_ids, starts, lens, sidx))
-            view = self._paged_view(pool, r_gidx, r_starts, slot=slot,
-                                    valid=r_lens)
+            view = self._paged_view(
+                pool, r_gidx, r_starts, slot=slot, valid=r_lens,
+                **({"finish": jnp.take(ends, slot) > 0}
+                   if self._reads_finish else {}))
             # the adapter index rides the SLOT plane: this row's entry
             mine = None if lora is None else dict(lora, idx={
                 rb: jnp.take(ix, slot) for rb, ix in lora["idx"].items()})
@@ -2078,8 +2091,11 @@ class InferenceEngine:
         """Copy one physical page's rows (COW fork: a write would land
         in a page some other reader still maps)."""
         if self.paged.form == "pages":
-            return [{key: paged_kv.copy_page(buf, src, dst)
-                     for key, buf in layer.items()} for layer in pool]
+            # a layer held by slot has no pages: its first axis is slots
+            return [layer if bounded else
+                    {key: paged_kv.copy_page(buf, src, dst)
+                     for key, buf in layer.items()}
+                    for layer, bounded in zip(pool, self.paged.by_slot)]
         P = self.paged.page_size
         new = []
         for layer, bounded in zip(pool, self.paged.by_slot):
@@ -3522,7 +3538,7 @@ class InferenceEngine:
                 [(slot, done, suffix)], W, C, n_rows=1)
             if self.step_stats is not None:
                 self.step_stats.note_chunk_rows(
-                    [(slot, {"done": done}, suffix)])
+                    [(slot, {"done": done}, suffix)], 1)
             tail, sampled = self._tail_key([(slot, req)])
         kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
@@ -3826,7 +3842,8 @@ class InferenceEngine:
         (the device computes exactly the rows that chunk)."""
         self._note_chunk_rows(len(entries), len(entries))
         if self.step_stats is not None:
-            self.step_stats.note_chunk_rows(entries)
+            self.step_stats.note_chunk_rows(
+                entries, len(self._finishing(entries)))
         return self._paged_chunk_rows(
             [(slot, st["done"], chunk) for slot, st, chunk in entries],
             W, self.chunked_prefill)

@@ -56,7 +56,12 @@ not follow ``max_len`` (a sliding-window layer's ring of ``window`` rows)
 has nothing for a page allocator to share out: every live slot needs the
 same bounded state. Its buffers are held BY SLOT, ``(max_slots, rows,
 ...)``, beside the pools; no block table names them, and the engine's
-programs hand the model the slot's rows as they are.
+programs hand the model the slot's rows as they are. A RECURRENT layer's
+state is held the same way (docs/paged-kv.md, "A state that is replaced";
+:func:`cache_kinds`'s ``recurrent``: buffers that are not rows at all, in
+a dtype of their own, replaced at every position; the model masks a dead
+row's update and zeroes a sequence's start). An entry of the template may
+hold the stacked buffers of several layers of one kind.
 
 Sharing/refcount protocol (one invariant the churn test pins): a
 physical page's refcount equals the number of slot block tables mapping
@@ -90,16 +95,26 @@ def pages_for(n_tokens: int, page_size: int) -> int:
 
 
 def cache_kinds(model, dtype, max_len: int = 1 << 20):
-    """``(template, by_slot)`` of ``model``'s cache at ``max_len``
-    positions: the abstract per-layer buffers (nothing is allocated), and
-    per layer whether its state is BOUNDED: no buffer's row axis is
-    ``max_len`` long. A property of the cache template alone: no flag
-    and no model name."""
-    tpl = jax.eval_shape(lambda: model.init_cache(1, max_len, dtype=dtype))
-    by_slot = [all(buf.shape[1] != max_len
-                   for key, buf in layer.items() if key != "index")
-               for layer in tpl]
-    return tpl, by_slot
+    """``(template, by_slot, recurrent)`` of ``model``'s cache at
+    ``max_len`` (> 1) positions: the abstract per-layer buffers (nothing
+    is allocated); per layer whether its state is BOUNDED: no buffer's row
+    axis is ``max_len`` long; and per layer whether it is a STATE that is
+    replaced and not rows that are appended: it has buffers and none of
+    them changes shape with ``max_len`` at all (a ring's rows are
+    ``min(max_len, window)``: they do). Such a layer is bounded whatever
+    an axis of it happens to measure (a state of 16 is not a cache of 16
+    positions). Properties of the cache template alone: no flag and no
+    model name."""
+    short, tpl = (jax.eval_shape(
+        lambda n=n: model.init_cache(1, n, dtype=dtype))
+        for n in (1, max_len))
+    recurrent = [len(a) > 1 and all(a[key].shape == b[key].shape
+                                    for key in a)
+                 for a, b in zip(short, tpl)]
+    by_slot = [still or all(buf.shape[1] != max_len
+                            for key, buf in layer.items() if key != "index")
+               for layer, still in zip(tpl, recurrent)]
+    return tpl, by_slot, recurrent
 
 
 def kv_row_bytes(model, dtype) -> int:
@@ -108,7 +123,7 @@ def kv_row_bytes(model, dtype) -> int:
     engine uses to express a draft model's contiguous cache in page-pool
     tokens, so a paged engine with a draft can't over-admit against bytes
     the draft already spent (ISSUE 9 satellite; docs/paged-kv.md)."""
-    tpl, by_slot = cache_kinds(model, dtype)
+    tpl, by_slot, _ = cache_kinds(model, dtype)
     total = 0
     for layer, bounded in zip(tpl, by_slot):
         if bounded:
@@ -411,7 +426,8 @@ class PagedKV:
                       for layer in tpl]
         # layers whose state is bounded are held by slot, not by page
         # (cache_kinds); their buffers' shapes at this cache length
-        full, self.by_slot = cache_kinds(model, dtype, self.cache_len)
+        full, self.by_slot, self.recurrent = cache_kinds(
+            model, dtype, self.cache_len)
         paged = [t for t, bounded in zip(self.tails, self.by_slot)
                  if not bounded]
         # the pool's physical form, "rows" | "pages": stored_by_pages
@@ -446,10 +462,16 @@ class PagedKV:
             int(buf.nbytes) for layer, bounded in zip(kv, self.by_slot)
             if bounded for buf in layer.values())
         self.slot_bytes = self.slot_state_bytes // self.max_slots
-        # rows of the widest ring (0: every layer grows with the context)
+        # the bytes of the layers held by slot that are a recurrent state
+        # (no rows)
+        self.recurrent_state_bytes = sum(
+            int(buf.nbytes) for layer, still in zip(kv, self.recurrent)
+            if still for buf in layer.values())
+        # rows of the widest ring (0: no layer is a ring)
         self.ring_rows = max(
-            (buf.shape[1] for layer, bounded in zip(kv, self.by_slot)
-             if bounded for buf in layer.values()), default=0)
+            (buf.shape[1] for layer, bounded, still in zip(
+                kv, self.by_slot, self.recurrent)
+             if bounded and not still for buf in layer.values()), default=0)
         self.row_bytes = self.pool_bytes // pool_rows if pool_rows else 0
         self.page_bytes = self.row_bytes * self.page_size
         self._ledger_open = True
@@ -659,12 +681,17 @@ class PagedKV:
             "slot_state": {
                 "layers": sum(self.by_slot),
                 "paged_layers": self.n_layers - sum(self.by_slot),
+                # of the layers held by slot: a recurrent state (replaced,
+                # not appended)
+                "recurrent_layers": sum(self.recurrent),
+                "recurrent_bytes": self.recurrent_state_bytes,
                 "ledger_account": "kv.window_state",
                 "slot_bytes": self.slot_bytes,
                 "bytes": self.slot_state_bytes,
                 "buffers": {
                     key: {"shape": list(buf.shape)} for key, buf in
-                    self.kv[self.by_slot.index(True)].items()
-                } if any(self.by_slot) else {},
+                    next((layer for layer, bounded in zip(
+                        self.kv, self.by_slot) if bounded and layer),
+                        {}).items()},
             },
         }

@@ -23,6 +23,16 @@ the host by their tokens alone, for two kinds of model:
   whole ring, idle slots too: ``models/afmoe.py``'s is 4,096 rows) and a
   chunk's (query, key) pairs under the band.
 
+- a model with RECURRENT layers and a CROSS-DECODER (``models/phi4flash.py``;
+  the model's ``census()`` gives how many layers read the shared view): the positions its chunk
+  rows scanned, the decode-plane rows whose state moved against those it
+  was held for, the rows that passed the self-decoder and the cross-decoder
+  (a prompt's chunk passes the second at ONE position, the one it ends in:
+  ``cross_decoder_prefill_rows``), and the true lengths the layers that
+  read the one paged layer's view attended, times those readers. All booked
+  by the host from what it dispatched; such a model counts nothing on the
+  device and routes nothing (``load`` is None).
+
 Every model here sends its prompts through the chunk program, one
 chunk-wide trip a row: ``prefill_chunk_tokens`` (real prompt tokens)
 against ``prefill_chunk_capacity`` (trips x the chunk's width) is what a
@@ -96,7 +106,8 @@ class StepStats:
         self.check_engine(engine)
         tpl = core.step_stats(1)
         self.routed = [i for i, d in enumerate(tpl) if d]
-        self.load = RoutingLoad(core.config.held[1])
+        self.load = (RoutingLoad(core.config.held[1]) if self.routed
+                     else None)
         # rows of a window layer's ring (0: no layer is held by slot)
         self.ring_rows = engine.paged.ring_rows
         # one attended / view pair and one count of causal pairs, under
@@ -116,6 +127,14 @@ class StepStats:
             self.window_ring_rows_read = 0
             self.prefill_band_pairs = 0
             self.prefill_band_keys_read = 0
+        # recurrent layers and a cross-decoder: the model's layer counts
+        self.census = core.census() if hasattr(core, "census") else None
+        if self.census:
+            for key in ("ssm_scan_tokens", "ssm_state_rows_advanced",
+                        "ssm_state_rows_held", "self_decoder_rows",
+                        "cross_decoder_rows", "cross_decoder_prefill_rows",
+                        "shared_kv_rows_attended"):
+                setattr(self, key, 0)
         # reference comparisons (tests, the benchmark's check) set this
         # to a list: every booked program then appends {"kind", "uids":
         # {slot: request uid} at the dispatch, "route": per part (routed
@@ -145,7 +164,8 @@ class StepStats:
         if engine.speculative_k is not None or engine.draft_model is not None:
             no("speculative decoding",
                "the speculative round's programs return no routing "
-               "statistics, and no self-draft layer is built (ROADMAP M5)")
+               "statistics, no self-draft layer is built (ROADMAP M5), and "
+               "a rejected draft would have to roll a recurrent state back")
         if engine.adapter_registry is not None:
             no("multi-LoRA", "the adapter twins know dense projections only")
         if engine.kv_pool is not None or engine.session_store is not None:
@@ -205,9 +225,20 @@ class StepStats:
             counts["window_rows_attended"] = sum(
                 min(length, self.ring_rows) for length in lens)
             counts["window_ring_rows_read"] = eng.max_slots * self.ring_rows
+        if self.census:
+            # every row of the plane passes both decoders; only the live
+            # rows' states move
+            counts.update(
+                ssm_state_rows_advanced=len(lens) * n,
+                ssm_state_rows_held=eng.max_slots * n,
+                self_decoder_rows=eng.max_slots * n,
+                cross_decoder_rows=eng.max_slots * n,
+                shared_kv_rows_attended=sum(
+                    length - i for length in lens for i in range(n))
+                * self.census["shared_readers"])
         self._count(**counts)
 
-    def note_chunk_rows(self, entries) -> None:
+    def note_chunk_rows(self, entries, finishing: int = 0) -> None:
         """A chunk or mixed dispatch advances ``entries`` ((slot, state,
         chunk) triples): the (query, key) pairs its causal attention
         covers (query ``i`` of a chunk that starts at ``done`` sees
@@ -237,6 +268,13 @@ class StepStats:
                 band_keys += len(c) + min(st["done"], w - 1)
             counts.update(prefill_band_pairs=band,
                           prefill_band_keys_read=band_keys)
+        if self.census:
+            # a chunk's positions pass the self-decoder; the cross-decoder
+            # sees one position of each prompt that ENDS here
+            tokens = counts["prefill_chunk_tokens"]
+            counts.update(ssm_scan_tokens=tokens, self_decoder_rows=tokens,
+                          cross_decoder_rows=int(finishing),
+                          cross_decoder_prefill_rows=int(finishing))
         self._count(**counts)
 
     def pend(self, kind: str, stats, last=None, finishing=()):
@@ -277,20 +315,21 @@ class StepStats:
         # parts: one per trunk the program ran (a mixed program's chunk
         # rows, then its decode half), each a list of the routed layers'
         # entries
-        loads = np.sum([layer[LOAD_KEY] for part in parts
-                        for layer in part], axis=0)
-        self.load.book(*(int(v) for v in loads))
-        self.eng.steptrace.note_extra(
-            moe_layer_passes=int(loads[0]),
-            moe_assignments_held=int(loads[1]),
-            moe_experts_touched=int(loads[2]),
-            moe_max_expert_load=int(loads[3]))
+        if self.load is not None:
+            loads = np.sum([layer[LOAD_KEY] for part in parts
+                            for layer in part], axis=0)
+            self.load.book(*(int(v) for v in loads))
+            self.eng.steptrace.note_extra(
+                moe_layer_passes=int(loads[0]),
+                moe_assignments_held=int(loads[1]),
+                moe_experts_touched=int(loads[2]),
+                moe_max_expert_load=int(loads[3]))
         if kept is not None and self.capture is not None:
             last, slots, uids = kept
             self.capture.append({
                 "kind": kind, "uids": uids,
                 "route": [np.stack([layer[ROUTE_KEY] for layer in part])
-                          for part in parts],
+                          for part in parts] if self.routed else [],
                 # reference comparisons only
                 "last_logits": {
                     s: np.asarray(last[s])  # graftlint: disable=host-sync

@@ -358,3 +358,19 @@ def test_the_older_pools_keep_their_shapes():
     assert pg.kv[0]["ckv"].shape == (9, 16, 640)
     assert pg.row_bytes == 2 * 640 * 2
     pg.close()
+
+
+@pytest.mark.parametrize("length,block_t", [(2048, None), (2048, 128)])
+def test_ssm_chunk_scan_compiles(chip, length, block_t):
+    """The selective scan at Phi-4-mini-flash's widths (5,120 channels x
+    16 states, a 2,048-token chunk): B and C in scalar memory (a time
+    block of 512 would not fit it)."""
+    from llm_in_practise_tpu.ops import selective_scan as ssm
+
+    f32 = jnp.float32
+    shapes = ((1, length, 5120), (1, length, 5120), (1, length, 16),
+              (1, length, 16), (16, 5120), (5120,), (1, 16, 5120))
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=chip) for s in shapes]
+    text = _compile(lambda *a: ssm.chunk_scan(*a, block_t=block_t,
+                                              interpret=False), *args)
+    assert ssm.CHUNK_KERNEL in text

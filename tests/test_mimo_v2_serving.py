@@ -125,6 +125,7 @@ def test_metrics_and_debug_name_both_stores(served):
     snap = eng.debug_kv()
     assert snap["slot_state"] == {
         "layers": 2, "paged_layers": 2,
+        "recurrent_layers": 0, "recurrent_bytes": 0,
         "ledger_account": "kv.window_state",
         "slot_bytes": 2 * 8 * 4 * (24 + 16) * 4,
         "bytes": 4 * 2 * 8 * 4 * (24 + 16) * 4,
