@@ -136,6 +136,30 @@ VALID_KEY = "valid"
 # prompt ENDS in this call. A model whose later layers only read (a
 # cross-decoder) runs them at a prompt's last position and nowhere else.
 FINISH_KEY = "finish"
+# Entry the decode programs give the PAGED layer of a model that declares
+# ``reads_pages`` (models/phi4flash.py) in place of a gathered view: the
+# layer's buffers are then the pool's own, ``(pages, page rows, width up to
+# whole lanes)`` as serve/paged_kv.py stores them by pages, and this entry
+# ``(B, pages a slot)`` int32 each row's block table: logical page ->
+# physical page, page 0 (the pool's trash page) where nothing is mapped. The
+# layer writes its new row into the pool itself (``page_row_write``: a row
+# whose ``VALID_KEY`` is 0 writes into the trash page) and attends the pages
+# where they lie, to each row's true length.
+PAGES_KEY = "pages"
+
+
+def page_row_write(buf, table, index, valid, rows):
+    """``buf`` (pages, page rows, lanes), a pool stored by pages, with
+    ``rows`` (B, width) written at position ``index`` (B,) of each row's
+    pages ``table`` (B, pages a slot); a row whose ``valid`` is 0 (idle,
+    mid-prefill) writes into page 0, the trash page."""
+    size = buf.shape[1]
+    page = jnp.take_along_axis(
+        table, jnp.clip(index // size, 0, table.shape[1] - 1)[:, None],
+        axis=1)[:, 0]
+    rows = jnp.pad(rows.astype(buf.dtype),
+                   ((0, 0), (0, buf.shape[2] - rows.shape[-1])))
+    return buf.at[jnp.where(valid > 0, page, 0), index % size].set(rows)
 
 
 # --- a prefill's tail: one row of logits a prompt ---------------------------

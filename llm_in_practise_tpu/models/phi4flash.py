@@ -61,7 +61,14 @@ min(max_len, window)``; the full layer's FLAT rows that follow
 ``max_len``, ``{"k1", "k2": (B, max_len, pairs * head_dim), "v": (B,
 max_len, pairs * 2 head_dim)}``. A GMU or cross layer holds nothing. The
 two keys of a pair are stored apart, so each half's softmax reads its own
-buffer whole, and the values once for both. The state's dtype is the
+buffer whole, and the values once for both. **In a decode program the full
+layer's entry is the page pool itself** (the model declares
+``reads_pages``): the three buffers as ``serve/paged_kv.py`` stores them by
+pages and each row's block table under ``layers.PAGES_KEY``; the layer
+writes its new row into its page and all eight readers walk the pages
+where they lie, to each row's true length
+(``swa.paged_paired_decode_attention``). A chunk row (``L > 1``) keeps the
+gathered view. The state's dtype is the
 configuration's (float32), not the cache's. State discipline: a row whose
 ``valid`` is 0 keeps ring, tail and state bit for bit (wherever its index
 points); padding does not advance them; a live call that starts at
@@ -97,6 +104,7 @@ from llm_in_practise_tpu.ops import swa_attention as swa
 
 Cache = dict[str, Any]
 VALID_KEY, FINISH_KEY = layers.VALID_KEY, layers.FINISH_KEY
+PAGES_KEY = layers.PAGES_KEY
 MAMBA, WINDOW, FULL, GMU, CROSS = "mamba", "window", "full", "gmu", "cross"
 SHARED_DECODE_SCOPE = "shared_kv_decode_attention"
 GMU_SCOPE = "gmu"
@@ -400,7 +408,10 @@ def attention_mixer(cfg, p, h, cache, layer, kind, shared=None):
     """One differential-attention layer. ``shared``: what the FULL layer
     hands the cross layers, ``(k1, k2, v)``: the keys and values a query
     may read, flat rows of a cache view or ``(B, L, pairs, ·)`` of a call
-    without a cache. Returns ``(out, cache, shared)``."""
+    without a cache; or ``(k1, k2, v, pages)``: the pool's buffers as they
+    are stored by pages and the keywords that name each row's
+    (``swa.paged_paired_decode_attention``: block table, lengths, work
+    list). Returns ``(out, cache, shared)``."""
     compute, f32 = jnp.dtype(cfg.compute_dtype), jnp.float32
     b, l, _ = h.shape
     hd = cfg.head_dim
@@ -425,10 +436,15 @@ def attention_mixer(cfg, p, h, cache, layer, kind, shared=None):
     start, valid = _rows(b, cache, l)
     if kind == CROSS:
         # one query a row, at the position the full layer just wrote
-        k1, k2, v = shared
+        k1, k2, v, *pages = shared
         with jax.named_scope(SHARED_DECODE_SCOPE):
-            a1, a2 = swa.paired_decode_attention(
-                (q1, q2), (k1, k2), v, start, scale=scale)
+            if pages:
+                a1, a2 = swa.paged_paired_decode_attention(
+                    (q1, q2), (k1, k2), v, scale=scale, kv_heads=kp,
+                    **pages[0])
+            else:
+                a1, a2 = swa.paired_decode_attention(
+                    (q1, q2), (k1, k2), v, start, scale=scale)
         return _differential(cfg, p, layer, a1, a2), cache, shared
     if kind == WINDOW:
         live = jnp.full((b,), l, jnp.int32) if valid is None else valid
@@ -445,6 +461,26 @@ def attention_mixer(cfg, p, h, cache, layer, kind, shared=None):
                 for q, k, key in ((q1, k1, "k1"), (q2, k2, "k2")))
         cache = dict(cache, **rings, index=cache["index"] + l)
         return _differential(cfg, p, layer, a1, a2), cache, None
+    if PAGES_KEY in cache:
+        # the full layer over the pool's PAGES (a decode program, l == 1):
+        # the new row goes into its page and every reader walks the
+        # row's pages to its true length; a row that is not live (the
+        # programs always say which: ``valid``) writes into the trash page
+        # and reads nothing
+        table = cache[PAGES_KEY]
+        pool = {key: layers.page_row_write(cache[key], table, start, valid,
+                                           new.reshape(b, -1))
+                for key, new in (("k1", k1), ("k2", k2), ("v", v))}
+        lengths = jnp.where(valid > 0, start + 1, 0)
+        pages = dict(table=table, lengths=lengths, work=swa.paged_decode_work(
+            lengths, pool["v"].shape[1], table.shape[1]))
+        with jax.named_scope(SHARED_DECODE_SCOPE):
+            a1, a2 = swa.paged_paired_decode_attention(
+                (q1, q2), (pool["k1"], pool["k2"]), pool["v"], scale=scale,
+                kv_heads=kp, **pages)
+        cache = dict(cache, **pool, index=cache["index"] + l)
+        return (_differential(cfg, p, layer, a1, a2), cache,
+                (pool["k1"], pool["k2"], pool["v"], pages))
     # the full layer: FLAT rows, stored by pages, attended as they lie
     rows = {key: layers.cache_update(cache[key], new.reshape(b, l, -1),
                                      cache["index"])
@@ -681,6 +717,10 @@ class Phi4Flash(nn.Module):
     #: the serving programs tell this model whether a prompt ends in a
     #: chunk (``layers.FINISH_KEY``): its cross-decoder runs only then
     reads_finish = True
+    #: a decode program hands the paged layer the pool's pages as they are
+    #: stored and each row's block table (``layers.PAGES_KEY``), not a
+    #: gathered view: its eight readers walk the pages where they lie
+    reads_pages = True
 
     def step_stats(self, rows: int) -> list[dict]:
         """No cache entry counts anything on the device
@@ -692,7 +732,10 @@ class Phi4Flash(nn.Module):
         """For the step statistics (``serve/step_stats.py``): a model with
         recurrent layers and a cross-decoder; ``shared_readers`` layers
         attend the one paged layer's view."""
-        return {"shared_readers": 1 + self.cfg.kinds.count(CROSS)}
+        return {"shared_readers": 1 + self.cfg.kinds.count(CROSS),
+                # pages a reader copies at once: a row's pages read are
+                # its length up to whole blocks of them
+                "page_block": swa.PAGED_DECODE_PAGES}
 
 
 def random_params(cfg: Phi4FlashConfig, seed: int, dtype=jnp.bfloat16,

@@ -79,6 +79,7 @@ LONG_BAND_BLOCKS = (256, 1024)
 # 128 x 128 lies under it; 2,048 x 4,096 would be 1.6 GB a layer
 RING_CORNER_MAX = 256 * 256
 _LANE, _SUBLANE = 128, 8
+_PAGED_VMEM = 32 * 1024 * 1024
 
 
 def window_blocks(window: int) -> tuple[int, int]:
@@ -509,6 +510,222 @@ def paired_decode_attention(qs, ks, v, index, *, scale: float):
         out = jnp.sum(wide * own.astype(jnp.float32)[
             None, None, :, None, :, None], axis=4)
     return tuple(out[:, i].reshape(b, 1, h, dv) for i in range(n))
+
+
+# --- one query a row over the pages where they lie ----------------------------
+# The in-place twin of :func:`paired_decode_attention`: no gathered view. The
+# pool stays as ``serve/paged_kv.py`` stores it by pages, ``(pages, page
+# rows, Hk * D up to whole lanes)``, and a slot's block-table row names its
+# pages in position order. The kernel copies ``PAGED_DECODE_PAGES`` pages of
+# each buffer into a block of fast memory itself (a grid step a page would
+# be ~7,000 steps a reader), the next block's copies in flight while this
+# one is computed, along a flat list of (row, block) pairs that holds a
+# row's blocks up to its true length and nothing of a row of length 0.
+
+PAGED_DECODE_KERNEL = "shared_kv_paged_decode"
+# pages a compute block (tools/paged_decode_bakeoff.py; PERF.md, PR 48)
+PAGED_DECODE_PAGES = 32
+
+
+def paged_block_pages(max_pages: int, pages_per_block: int | None = None):
+    """Pages the kernel copies at once for slots of ``max_pages`` pages."""
+    return min(pages_per_block or PAGED_DECODE_PAGES, max_pages)
+
+
+def paged_decode_work(lengths, page_size: int, max_pages: int,
+                      pages_per_block: int | None = None):
+    """The kernel's flat work list for rows of ``lengths`` (B,): ``(row,
+    block, total)``, int32 ``(B * blocks a slot,)`` twice and ``(1,)``.
+    Item ``i < total`` is block ``block[i]`` of row ``row[i]``: rows in
+    order, each row's blocks from 0 to the last that holds a live key.
+    One list serves every layer that reads the same lengths."""
+    b = lengths.shape[0]
+    ppb = paged_block_pages(max_pages, pages_per_block)
+    n = -(-lengths.astype(jnp.int32) // (page_size * ppb))
+    ends = jnp.cumsum(n)
+    i = jnp.arange(b * -(-max_pages // ppb), dtype=jnp.int32)
+    # the row whose run of blocks holds item i: the rows that end at or
+    # before it
+    row = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1,
+                              dtype=jnp.int32), b - 1)
+    return row, i - (ends - n)[row], ends[-1:]
+
+
+def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
+                         q_ref, k1_hbm, k2_hbm, v_hbm, o_ref,
+                         k1_buf, k2_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+                         *, scale, pairs, group, kv_pairs):
+    """ONE invocation walks the whole list. ``q_ref`` (B, 2, Hp, C): both
+    halves' queries of every query pair (``Hp``: the pairs up to whole
+    sublane tiles), each zero outside its K/V pair's columns of a key
+    row; ``table_ref`` (B * pages a slot,), flat; ``o_ref`` (B, pairs, 2
+    * Dv) float32, ``[a1 ‖ a2]`` of each pair. The halves' scores stack
+    to ``(2 Hp, rows)``: the value block is multiplied once."""
+    _, ppb, page, _ = k1_buf.shape
+    rows = ppb * page
+    hp, dv = q_ref.shape[2], o_ref.shape[-1] // 2
+    max_pages = table_ref.shape[0] // q_ref.shape[0]
+    total = total_ref[0]
+    pools = ((k1_hbm, k1_buf), (k2_hbm, k2_buf), (v_hbm, v_buf))
+
+    def copy(slot, p, at, n):
+        hbm, buf = pools[n]
+        return pltpu.make_async_copy(hbm.at[at], buf.at[slot, p],
+                                     sems.at[slot, n])
+
+    # a loop a page, not 3 * ppb copies spelled out: the program that
+    # holds the kernel is traced and lowered in a fraction of the time
+    def start(i, slot):
+        base = row_ref[i] * max_pages + blk_ref[i] * ppb
+
+        def page_copies(p, carry):
+            for n in range(len(pools)):
+                copy(slot, p, table_ref[base + p], n).start()
+            return carry
+
+        jax.lax.fori_loop(0, ppb, page_copies, None)
+
+    def wait(slot):
+        def page_copies(p, carry):
+            for n in range(len(pools)):
+                copy(slot, p, 0, n).wait()      # any page: its bytes count
+            return carry
+
+        jax.lax.fori_loop(0, ppb, page_copies, None)
+
+    # a row of length 0 is in no item: zeros
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(total > 0)
+    def _():
+        start(0, 0)
+
+    def block(i, carry):
+        slot = jax.lax.rem(i, 2)
+        b, j = row_ref[i], blk_ref[i]
+        length = len_ref[b]
+
+        @pl.when(i + 1 < total)
+        def _():
+            start(i + 1, 1 - slot)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        wait(slot)
+        q = q_ref[b]                                        # (2, Hp, C)
+        s = jnp.concatenate([
+            jax.lax.dot_general(
+                q[n], buf[slot].reshape(rows, -1).astype(q.dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            for n, buf in enumerate((k1_buf, k2_buf))], axis=0) * scale
+        live = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) < length
+        # a block of the list holds a live key (j * rows < length): the
+        # running maximum is a real score from a row's first block on
+        s = jnp.where(live, s, NEG_INF)
+        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:, 0:1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[:, 0:1] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p.astype(q.dtype), v_buf[slot].reshape(rows, -1).astype(q.dtype),
+            preferred_element_type=jnp.float32)
+
+        @pl.when((j + 1) * rows >= length)
+        def _():
+            # row r of a half reads K/V pair r // group: its Dv columns
+            # of the sum over whole value rows
+            mine = jax.lax.broadcasted_iota(
+                jnp.int32, (2 * hp, dv), 0) % hp // group
+            out = jnp.zeros((2 * hp, dv), jnp.float32)
+            for g in range(kv_pairs):
+                out = jnp.where(mine == g,
+                                acc_ref[:, g * dv:(g + 1) * dv], out)
+            out = out / l_ref[:, 0:1]
+            o_ref[b] = jnp.concatenate(
+                [out[:pairs], out[hp:hp + pairs]], axis=-1)
+        return carry
+
+    jax.lax.fori_loop(0, total, block, None)
+
+
+# jitted in its own right: a program's readers (the full layer, the scanned
+# cross layers) and every program of a process share ONE trace of the kernel
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "kv_heads", "pages_per_block", "interpret"))
+def paged_paired_decode_attention(qs, ks, v, table, lengths, *, scale: float,
+                                  kv_heads: int, work=None,
+                                  pages_per_block: int | None = None,
+                                  interpret: bool | None = None):
+    """:func:`paired_decode_attention` over the pool's PAGES where they
+    lie, to each row's true length. ``qs`` = ``(q1, q2)`` (B, 1, H, Dq);
+    ``ks`` = the two key pools (pages, page rows, Hk * Dq up to whole
+    lanes) and ``v`` the value pool (pages, page rows, Hk * Dv up to whole
+    lanes; ``Hk`` = ``kv_heads``, ``Dv`` = 2 ``Dq``); ``table`` (B, pages a
+    slot) int32 names each row's pages in position order (past the row's
+    own: any page of the pool, its rows are masked); ``lengths`` (B,): row
+    ``b`` attends positions ``0 .. lengths[b] - 1``, its own included; at
+    0 it reads nothing and gets zeros. ``work``: :func:`paged_decode_work` of these
+    lengths, from a caller with several readers. Returns ``(a1, a2)`` (B,
+    1, H, Dv) float32."""
+    b, _, h, dq = qs[0].shape
+    dtype = qs[0].dtype
+    page, ck = ks[0].shape[1:]
+    cv, dv, hk = v.shape[2], 2 * dq, kv_heads
+    group = h // hk
+    ppb = paged_block_pages(table.shape[1], pages_per_block)
+    if work is None:
+        work = paged_decode_work(lengths, page, table.shape[1], ppb)
+    # whole blocks of the table, so that a row's last block names pages
+    table = jnp.pad(table.astype(jnp.int32),
+                    ((0, 0), (0, -table.shape[1] % ppb)))
+    sub = 32 // dtype.itemsize
+    hp = -(-h // sub) * sub
+    with jax.named_scope(GLOBAL_DECODE_SCOPE):
+        own = jnp.eye(hk, dtype=dtype)
+        wide = jnp.stack([
+            (q[:, 0].reshape(b, hk, group, 1, dq)
+             * own[None, :, None, :, None]).reshape(b, h, hk * dq)
+            for q in qs], axis=1)                           # (B, 2, H, C)
+        wide = jnp.pad(wide, ((0, 0), (0, 0), (0, hp - h),
+                              (0, ck - hk * dq)))
+        whole = lambda shape: pl.BlockSpec(       # noqa: E731
+            shape, lambda i, *_: (0,) * len(shape))
+        out = pl.pallas_call(
+            functools.partial(_paged_decode_kernel, scale=scale, pairs=h,
+                              group=group, kv_pairs=hk),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(1,),
+                in_specs=[whole(wide.shape)]
+                + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
+                out_specs=whole((b, h, 2 * dv)),
+                scratch_shapes=[
+                    pltpu.VMEM((2, ppb, page, ck), ks[0].dtype),
+                    pltpu.VMEM((2, ppb, page, ck), ks[1].dtype),
+                    pltpu.VMEM((2, ppb, page, cv), v.dtype),
+                    pltpu.SemaphoreType.DMA((2, 3)),
+                    pltpu.VMEM((2 * hp, _LANE), jnp.float32),
+                    pltpu.VMEM((2 * hp, _LANE), jnp.float32),
+                    pltpu.VMEM((2 * hp, cv), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, h, 2 * dv), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_PAGED_VMEM),
+            interpret=interpret_default() if interpret is None else interpret,
+            name=PAGED_DECODE_KERNEL,
+        )(*work, lengths.astype(jnp.int32), table.reshape(-1), wide,
+          ks[0], ks[1], v)
+    return tuple(out[:, None, :, i * dv:(i + 1) * dv] for i in range(2))
 
 
 def paired_ring_decode_attention(qs, ring_ks, ring_v, index, *,

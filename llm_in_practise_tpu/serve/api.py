@@ -856,7 +856,12 @@ class OpenAIServer:
                     ("shared_kv_rows_attended",
                      "rows of the one paged layer's view the decode "
                      "steps' readers attended: true lengths x the layers "
-                     "that read it")]
+                     "that read it"),
+                    ("shared_kv_pages_read",
+                     "pages of the one paged layer the decode steps' "
+                     "readers copied where they lie: live rows' lengths "
+                     "up to whole blocks x the layers that read it (0 "
+                     "while the layer is gathered into a view)")]
             for key, doc in families:
                 reg.counter_func(f"llm_{key}_total",
                                  lambda a=key: getattr(stats, a), doc)

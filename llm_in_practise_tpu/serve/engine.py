@@ -50,6 +50,7 @@ from llm_in_practise_tpu.infer.sampling import (
 )
 from llm_in_practise_tpu.models.layers import (
     FINISH_KEY,
+    PAGES_KEY,
     VALID_KEY,
     head_logits,
     last_position_hidden,
@@ -671,6 +672,11 @@ class InferenceEngine:
         # which rows' prompts end (models/layers.py FINISH_KEY)
         self._reads_finish = self._slot_state and bool(getattr(
             getattr(model, "inner", model), "reads_finish", False))
+        # a model that walks its paged layer's pages where they lie
+        # (``reads_pages``: paged_kv.PagedKV.in_place): a decode program
+        # gathers no view of that layer and has no view width
+        self._reads_pages = (self.paged is not None
+                             and any(self.paged.in_place))
         self.preemptions = 0            # paged pool-pressure preemptions
         self.rejected_too_large = 0     # prompts that can NEVER fit the pool
         self._paged_admit_blocked = False
@@ -1724,7 +1730,7 @@ class InferenceEngine:
     # rows into (page, offset); a flat pool's programs are untouched.
 
     def _paged_view(self, pool, gidx, index_vec, *, slot=None, valid=None,
-                    finish=None):
+                    finish=None, in_place=False):
         """Gather each slot's pages into a contiguous cache view
         (slots, W, ...) with the per-slot index pinned from the host.
         ``gidx`` is :meth:`PagedKV.view_idx`'s: pool rows (S, W) for a
@@ -1735,7 +1741,12 @@ class InferenceEngine:
         many of this call's positions are real for each row (a layer
         that owns its writes must not take padding or a dead row's), and,
         for a model that asks (``reads_finish``), ``finish`` (S,) bool:
-        whether the row's prompt ends in this call."""
+        whether the row's prompt ends in this call. ``in_place`` (a
+        slot-plane decode of a model that ``reads_pages``): ``gidx`` is
+        the whole block table (S, pages a slot), and a layer of
+        ``paged.in_place`` is not gathered either: the model gets the
+        pool's buffers as they are stored, the table under
+        ``PAGES_KEY`` and ``valid``."""
         by_pages = self.paged.form == "pages"
         S, W = gidx.shape
         flat = gidx.reshape(-1)
@@ -1744,9 +1755,14 @@ class InferenceEngine:
         # gets its zeroed entries beside each layer's gathered rows
         extra = ([{}] * len(pool) if self.step_stats is None
                  else self.step_stats.view_entries(S))
-        for layer, more, tails, bounded in zip(
-                pool, extra, self.paged.tails, self.paged.by_slot):
+        for layer, more, tails, bounded, pages in zip(
+                pool, extra, self.paged.tails, self.paged.by_slot,
+                self.paged.in_place):
             d = {"index": index_vec.astype(jnp.int32), **more}
+            if in_place and pages:
+                view.append({**d, **layer, VALID_KEY: valid,
+                             PAGES_KEY: gidx})
+                continue
             if bounded:
                 d[VALID_KEY] = valid
                 if finish is not None:
@@ -1780,20 +1796,24 @@ class InferenceEngine:
         if not self._slot_state:
             return {}
         return {"valid": (sidx[:, 0] >= self.paged.page_size).astype(
-            jnp.int32)}
+            jnp.int32), "in_place": self._reads_pages}
 
     def _paged_writeback(self, pool, view, sidx, wstart, *, slot=None):
         """Scatter each row's freshly written window
         ``[wstart[s], wstart[s] + Wwin)`` from the view back into the
         pool at the host-resolved page rows ``sidx``. A layer held by
         slot wrote its own rows (the model's ring): they go back whole,
-        or into ``slot``'s row."""
+        or into ``slot``'s row. A layer handed over in place
+        (``PAGES_KEY``) comes back as the pool it is: no pass follows."""
         by_pages = self.paged.form == "pages"
         S, Wwin = sidx.shape
         flat = sidx.reshape(-1)
         j = jnp.arange(Wwin)
         new = []
         for pl, vl, bounded in zip(pool, view, self.paged.by_slot):
+            if PAGES_KEY in vl:     # the pool itself: the model wrote it
+                new.append({key: vl[key] for key in pl})
+                continue
             d = {}
             for key, buf in pl.items():
                 vb = vl[key]
@@ -2294,7 +2314,12 @@ class InferenceEngine:
         decode block of ``n`` tokens at view width ``W``: every slot's
         pages gathered, ``active`` rows' ``n`` new rows scattered back
         (everything else to the trash page). Forks shared pages the
-        writes would touch."""
+        writes would touch. A model that reads its pages in place
+        (``_reads_pages``) gets the whole block table for ``gidx``,
+        whatever ``W`` and the lengths: nothing is gathered (the step's
+        record says ``view_pages`` 0 for this plane)."""
+        if self._reads_pages:
+            W = self.cache_len
         idxv = self._paged_index_vec(W, n)
         valid = np.zeros((self.max_slots,), np.int32)
         for s in active:
@@ -2302,7 +2327,12 @@ class InferenceEngine:
             self._paged_cow_fork(s, int(self.slot_len[s]), n)
         if self.step_stats is not None:
             self.step_stats.note_decode_view(active, n, W)
-        return (jnp.asarray(self._paged_view_idx(W)), jnp.asarray(idxv),
+        if self._reads_pages:
+            self.steptrace.note_extra(view_pages=0)
+            gidx = self.paged.block_tables.astype(np.int32)     # a copy
+        else:
+            gidx = self._paged_view_idx(W)
+        return (jnp.asarray(gidx), jnp.asarray(idxv),
                 jnp.asarray(self.paged.scatter_idx(idxv, valid, n)))
 
     def _paged_decode_dispatch(self, f: _Flight, active: list[int], n: int,
@@ -2315,9 +2345,11 @@ class InferenceEngine:
         guarantees n == 1 then. ``lora`` (multi-LoRA) routes to the
         adapter twin of whichever program would run; both compose.
         The sampled tokens, shape (max_slots, n), are ``f.toks``."""
-        W = self._paged_width(
-            max(int(self.slot_len[s]) for s in active) + n)
-        self._pulse_view(W)
+        W = self.cache_len
+        if not self._reads_pages:       # else: no view, and no width
+            W = self._paged_width(
+                max(int(self.slot_len[s]) for s in active) + n)
+            self._pulse_view(W)
         gidx, idxv, sidx = self._paged_decode_plan(active, n, W)
         args = (*self._plane_args(), sub, *self._sampling_args(active))
         kw = {} if lora is None else {"lora": lora}
@@ -4840,12 +4872,19 @@ class InferenceEngine:
             # receives a chunk write any more, but the warm-up
             # builds the widths THIS rule gives (narrower decode
             # views are a change of their own)
-            need = max(
-                [st["done"] + C + n for _, st, _ in entries]
-                + [min(int(self.slot_len[s]) + C, self.cache_len)
-                   for s in self._ready_slots()] + [C + n])
-            W = self._paged_width(need)
-            self._pulse_view(W)
+            if self._reads_pages:
+                # no view of the decode plane: the width is the chunk
+                # rows' alone, each gathered for its own trip
+                W = self._paged_width(
+                    max(st["done"] for _, st, _ in entries) + C)
+                self._pulse_view(W, 1)
+            else:
+                need = max(
+                    [st["done"] + C + n for _, st, _ in entries]
+                    + [min(int(self.slot_len[s]) + C, self.cache_len)
+                       for s in self._ready_slots()] + [C + n])
+                W = self._paged_width(need)
+                self._pulse_view(W)
             if gmask is not None:
                 fn = (self._pg_mixed_masked if lora is None
                       else self._pg_mixed_masked_lora)

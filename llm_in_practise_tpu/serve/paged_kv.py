@@ -433,6 +433,13 @@ class PagedKV:
         # the pool's physical form, "rows" | "pages": stored_by_pages
         self.form = "pages" if stored_by_pages(
             t for layer in paged for t in layer.values()) else "rows"
+        # paged layers a decode program does NOT gather: the model asked
+        # for the pool's pages as they are stored and walks them itself
+        # (``reads_pages``; models/layers.py PAGES_KEY). Per layer; only a
+        # pool stored by pages has pages to hand over.
+        reads = self.form == "pages" and bool(getattr(
+            getattr(model, "inner", model), "reads_pages", False))
+        self.in_place = [reads and not bounded for bounded in self.by_slot]
         kv = []
         for layer, tails, whole, bounded in zip(tpl, self.tails, full,
                                                 self.by_slot):

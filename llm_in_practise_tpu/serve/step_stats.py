@@ -29,7 +29,10 @@ the host by their tokens alone, for two kinds of model:
   was held for, the rows that passed the self-decoder and the cross-decoder
   (a prompt's chunk passes the second at ONE position, the one it ends in:
   ``cross_decoder_prefill_rows``), and the true lengths the layers that
-  read the one paged layer's view attended, times those readers. All booked
+  read the one paged layer attended, times those readers, and, where the
+  decode reads that layer's pages in place, the pages those readers copied
+  (``shared_kv_pages_read``; ``global_view_tokens`` is then the rows one
+  reader copied: live rows' lengths up to whole blocks). All booked
   by the host from what it dispatched; such a model counts nothing on the
   device and routes nothing (``load`` is None).
 
@@ -133,8 +136,15 @@ class StepStats:
             for key in ("ssm_scan_tokens", "ssm_state_rows_advanced",
                         "ssm_state_rows_held", "self_decoder_rows",
                         "cross_decoder_rows", "cross_decoder_prefill_rows",
-                        "shared_kv_rows_attended"):
+                        "shared_kv_rows_attended", "shared_kv_pages_read"):
                 setattr(self, key, 0)
+        # a model whose decode reads its paged layer's pages in place
+        # (``PagedKV.in_place``): the pages its readers copy at once (the
+        # census' ``page_block``, at most a slot's pages), else 0
+        self.page_block = min(
+            (self.census or {}).get("page_block", 0),
+            engine.paged.pages_per_slot) if any(engine.paged.in_place) else 0
+        self.block_rows = self.page_block * engine.paged.page_size
         # reference comparisons (tests, the benchmark's check) set this
         # to a list: every booked program then appends {"kind", "uids":
         # {slot: request uid} at the dispatch, "route": per part (routed
@@ -214,13 +224,22 @@ class StepStats:
     def note_decode_view(self, active, n: int, width: int) -> None:
         """A decode (or mixed step's decode half) of ``n`` tokens over
         ``active`` at view width ``width``: the rows its attention needed
-        against the rows of the slot plane's view (and, with window
+        against the rows of the slot plane's view, or against the rows a
+        decode that reads pages in place copied (and, with window
         layers, the ring rows those layers attended against the rows
         they read: every slot's whole ring)."""
         eng = self.eng
         lens = [int(eng.slot_len[s]) + n for s in active]
         counts = {self.attended_key: sum(lens),
                   self.view_key: eng.max_slots * int(width)}
+        if self.page_block:
+            # pages read where they lie: each live row's length up to the
+            # whole blocks its readers copy, nothing for an idle row
+            pages = sum(-(-length // self.block_rows) for length in lens
+                        ) * self.page_block
+            counts[self.view_key] = pages * eng.paged.page_size
+            counts["shared_kv_pages_read"] = (
+                pages * self.census["shared_readers"])
         if self.ring_rows:
             counts["window_rows_attended"] = sum(
                 min(length, self.ring_rows) for length in lens)

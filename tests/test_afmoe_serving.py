@@ -120,6 +120,11 @@ def test_the_new_counters_by_hand(served):
                        and 0 < r["window_rows_attended"]
                        <= r["window_ring_rows_read"] for r in dec)
     assert st.window_ring_rows_read == len(dec) * SLOTS * RING
+    # the global layer is GATHERED (no ``reads_pages`` here): every slot x
+    # the pow2 view width, whatever the rows' lengths
+    assert not any(served.eng.paged.in_place)
+    assert all(r["global_view_tokens"] in (SLOTS * 32, SLOTS * 64)
+               and "shared_kv_pages_read" not in r for r in dec)
     # 11 tokens each after the first: a row at length n attends min(n, 24)
     assert st.window_rows_attended == sum(
         min(n + t, RING) for n in lens for t in range(1, 12))

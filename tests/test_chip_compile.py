@@ -374,3 +374,28 @@ def test_ssm_chunk_scan_compiles(chip, length, block_t):
     text = _compile(lambda *a: ssm.chunk_scan(*a, block_t=block_t,
                                               interpret=False), *args)
     assert ssm.CHUNK_KERNEL in text
+
+
+def test_paged_decode_attention_compiles(chip):
+    """One reader of Phi-4-mini-flash's paged layer at the cell's shape:
+    16 slots of 1,024 pages of 16 rows, a pool of 16,385 pages in three
+    by-pages buffers, 20 query pairs on 10 K/V pairs of 64 / 128; the
+    pages copied where they lie, 32 a block."""
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def reader(q1, q2, k1, k2, v, table, lengths):
+        return swa.paged_paired_decode_attention(
+            (q1, q2), (k1, k2), v, table, lengths, scale=0.125, kv_heads=10,
+            interpret=False)
+
+    text = _compile(reader, arg((16, 1, 20, 64)), arg((16, 1, 20, 64)),
+                    arg((16385, 16, 640)), arg((16385, 16, 640)),
+                    arg((16385, 16, 1280)), arg((16, 1024), jnp.int32),
+                    arg((16,), jnp.int32))
+    # by its name, and by the result the cell's reader finds it by
+    assert swa.PAGED_DECODE_KERNEL in text and "f32[16,20,256]" in text
+    # no view: nothing of (16, n, 640 | 1280) is built around the kernel
+    assert "[16,16384,640]" not in text and "[16,16384,1280]" not in text

@@ -231,6 +231,32 @@ def test_engine_books_on_build_and_restores_baseline_on_stop(model_params):
     assert led.leaked_since(base) == {}
 
 
+def test_a_decode_that_reads_pages_in_place_pulses_no_view():
+    """A model that walks its paged layer's pages where they lie
+    (``reads_pages``: Phi-4-mini-flash): its decode steps gather no view
+    and pulse nothing; the chunk trip that prefills the prompt still
+    pulses its ONE-row view."""
+    from llm_in_practise_tpu.models import phi4flash as pf
+
+    cfg = pf.phi4flash_config(compute_dtype="float32")
+    eng = InferenceEngine(
+        pf.Phi4Flash(cfg), pf.random_params(cfg, 3, jnp.float32, std=0.1),
+        max_slots=2, cache_len=64, kv_layout="paged", kv_page_size=8,
+        chunked_prefill=16, cache_dtype=jnp.float32)
+    led = get_ledger()
+    before = led.snapshot()["accounts"].get(
+        "transient_view", {}).get("pulses", 0)
+    out = eng.generate(list(range(4, 13)),
+                       SamplingParams(greedy=True, max_tokens=5))
+    assert len(out) == 5
+    steps = eng.steptrace.records(limit=50)
+    assert sum("shared_kv_pages_read" in r for r in steps) >= 4   # decodes
+    tv = led.snapshot()["accounts"]["transient_view"]
+    assert tv["pulses"] - before == 1
+    assert tv["last_pulse_bytes"] == eng.paged.view_bytes(16, 1)
+    eng.stop()
+
+
 def test_contiguous_engine_books_kv_contiguous(model_params):
     model, params = model_params
     led = get_ledger()

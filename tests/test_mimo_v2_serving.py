@@ -116,6 +116,11 @@ def test_step_records_count_both_kinds_of_layer(served):
     # one attended / view pair, under the name that fits the cache
     assert st.load.layer_passes > 0 and not hasattr(st, "latent_view_tokens")
     assert st.global_view_tokens >= sum(r["global_view_tokens"] for r in dec)
+    # gathered views (no ``reads_pages`` here): every slot x a pow2 width
+    assert not any(eng.paged.in_place)
+    widths = {r["global_view_tokens"] // eng.max_slots for r in dec}
+    assert all(r["global_view_tokens"] % eng.max_slots == 0 for r in dec)
+    assert all(w & (w - 1) == 0 and w <= eng.cache_len for w in widths)
 
 
 def test_metrics_and_debug_name_both_stores(served):

@@ -1,0 +1,81 @@
+"""``swa.paged_paired_decode_attention``: one query a row over the pool's
+PAGES where they lie, to each row's true length, against
+``swa.paired_decode_attention`` over the gathered view of the same pages
+(interpret mode, tiny shapes: pages of 8 rows, blocks of 2 pages, 8 pages a
+slot)."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llm_in_practise_tpu.ops import swa_attention as swa
+from llm_in_practise_tpu.serve import paged_kv
+
+PAGE, PER_SLOT, BLOCK, DQ = 8, 8, 2, 16
+CACHE, EDGE = PAGE * PER_SLOT, PAGE * BLOCK
+
+
+def _pools(rng, n_pages, kv_pairs, dtype):
+    """``(k1, k2, v)`` by pages, each row's pad columns zero as the pool's
+    writers leave them."""
+    out = []
+    for width in (kv_pairs * DQ, kv_pairs * DQ, kv_pairs * 2 * DQ):
+        buf = np.zeros((n_pages, PAGE, paged_kv.lane_whole(width)),
+                       np.float32)
+        buf[..., :width] = rng.normal(size=(n_pages, PAGE, width))
+        out.append((jnp.asarray(buf, dtype), width))
+    return out
+
+
+@pytest.mark.parametrize("lengths, pairs, kv_pairs, dtype", [
+    # idle, one key, a page's edge and its neighbours
+    ((0, 1, 15, 16, 17), 4, 2, jnp.float32),
+    # a block's edge and its neighbours, the whole cache
+    ((EDGE - 1, EDGE, EDGE + 1, CACHE), 4, 2, jnp.float32),
+    ((EDGE + 1, 0, CACHE, 3 * EDGE - 1), 2, 2, jnp.float32),   # group 1
+    ((CACHE, 0, 0, 0), 4, 1, jnp.float32),                     # one live row
+    ((0, 0, 0), 4, 2, jnp.float32),                            # none
+    ((0, 1, 17, EDGE, CACHE), 4, 2, jnp.bfloat16),
+    ((EDGE - 1, CACHE, 5), 2, 2, jnp.bfloat16),
+], ids=["page-edges", "block-edges", "group-1", "one-live", "all-idle",
+        "bf16", "bf16-group-1"])
+def test_pages_in_place_are_the_gathered_view(lengths, pairs, kv_pairs,
+                                              dtype):
+    rng = np.random.default_rng(len(lengths) + pairs)
+    b = len(lengths)
+    n_pages = b * PER_SLOT + 1
+    pools = _pools(rng, n_pages, kv_pairs, dtype)
+    # scattered, non-monotone tables; past a row's own pages the table
+    # names the trash page (0), as an unmapped logical page does
+    table = rng.permutation(np.arange(1, n_pages)).reshape(b, PER_SLOT)
+    for row, n in enumerate(lengths):
+        table[row, paged_kv.pages_for(n, PAGE):] = paged_kv.TRASH_PAGE
+    table = jnp.asarray(table, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    qs = tuple(jnp.asarray(rng.normal(size=(b, 1, pairs, DQ)), dtype)
+               for _ in range(2))
+    (k1, _), (k2, _), (v, _) = pools
+    got = swa.paged_paired_decode_attention(
+        qs, (k1, k2), v, table, lengths, scale=DQ ** -0.5, kv_heads=kv_pairs,
+        pages_per_block=BLOCK, interpret=True)
+    view = [paged_kv.take_pages(buf, table, width) for buf, width in pools]
+    want = swa.paired_decode_attention(
+        qs, view[:2], view[2], jnp.maximum(lengths - 1, 0), scale=DQ ** -0.5)
+    live = np.asarray(lengths) > 0
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for a, r in zip(got, want):
+        assert a.shape == r.shape and a.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(a)[live], np.asarray(r)[live],
+                                   rtol=tol, atol=tol)
+        # a row of length 0 reads nothing: zeros, not the trash page
+        assert not np.asarray(a)[~live].any()
+
+
+def test_the_work_list_holds_live_blocks_only():
+    lengths = jnp.asarray([0, 1, EDGE, EDGE + 1, 0, CACHE], jnp.int32)
+    row, blk, total = swa.paged_decode_work(lengths, PAGE, PER_SLOT, BLOCK)
+    n = int(total[0])
+    assert n == 0 + 1 + 1 + 2 + 0 + PER_SLOT // BLOCK
+    assert row[:n].tolist() == [1, 2, 3, 3] + [5] * 4
+    assert blk[:n].tolist() == [0, 0, 0, 1, 0, 1, 2, 3]
+    assert row.shape == blk.shape == (6 * PER_SLOT // BLOCK,)

@@ -1,6 +1,6 @@
 """Phi-4-mini-flash through the serving engine: recurrent layers' state
-held by slot beside window rings (8 rows here), ONE paged layer whose view
-the cross layers read, chunked prefill beside a decoding row in fused mixed
+held by slot beside window rings (8 rows here), ONE paged layer whose pages
+the cross layers read where they lie, chunked prefill beside a decoding row in fused mixed
 steps against the plain reference, the state's discipline (idle slots, a
 reused slot, preemption), the cross-decoder's skip counted and exact, the
 byte rates, and what the engine refuses; tiny sizes on the CPU."""
@@ -126,11 +126,73 @@ def test_cross_decoder_skip_is_counted(served):
     chunks = [r for r in rec if "cross_decoder_prefill_rows" in r]
     assert all(r["cross_decoder_prefill_rows"] <= 2 for r in chunks)
     assert any(r["cross_decoder_prefill_rows"] == 0 for r in chunks)
-    # true lengths x the 2 layers that read the view (full + 1 cross)
+    # true lengths x the 2 layers that read the pages (full + 1 cross)
     dec = [r for r in rec if "shared_kv_rows_attended" in r]
     assert all(r["shared_kv_rows_attended"]
                == 2 * r["global_tokens_attended"] for r in dec)
     assert st.load is None and served.eng.routing_load is None
+
+
+def _jitted(fn):
+    """The ``jax.jit`` under the engine's meters."""
+    while not hasattr(fn, "_cache_size"):
+        fn = fn.__wrapped__
+    return fn
+
+
+def test_decode_reads_the_pages_where_they_lie(served):
+    """No view of the paged layer in a decode step: the step books the
+    rows its readers COPIED (each live row's length up to whole blocks:
+    a slot's 16 pages here, one block), the pages x 2 readers, and
+    gathers nothing; one decode executable served every length; a mixed
+    step still gathers its chunk row's one-row view."""
+    eng, st = served.eng, served.eng.step_stats
+    pg = eng.paged
+    assert pg.in_place == [False, False, True] and eng._reads_pages
+    assert st.page_block == pg.pages_per_slot == 16
+    dec = [r for r in served.records if "shared_kv_rows_attended" in r]
+    lengths = {r["global_tokens_attended"] for r in dec}
+    assert len(lengths) > 8         # steps at many lengths
+    for r in dec:
+        live = r["ssm_state_rows_advanced"]         # one token a step
+        assert r["global_view_tokens"] == live * 16 * pg.page_size
+        assert r["shared_kv_pages_read"] == live * 16 * 2
+        chunked = r.get("prefill_chunk_capacity", 0) // 16
+        # a chunk row's one-row view, whole pages; nothing for the plane
+        assert (r["view_pages"] > 0) == (chunked > 0)
+    assert st.global_view_tokens >= sum(r["global_view_tokens"] for r in dec)
+    assert _jitted(eng._pg_decode)._cache_size() == 1
+    assert _jitted(eng._pg_multi)._cache_size() == 0
+
+
+def test_a_decode_row_lands_in_its_page_and_nowhere_else(served):
+    """The decode program itself, on a copy of the pool: slot 1 decodes
+    at position 9 (its second page, row 1); slot 2 is mid-prefill and
+    slots 0 and 3 idle: their rows go to the trash page. No write-back
+    pass follows, so anything else that changed would show."""
+    eng, pg = served.eng, served.eng.paged
+    size = pg.page_size
+    table = np.zeros((SLOTS, pg.pages_per_slot), np.int32)
+    table[1, :2] = (5, 9)
+    table[2, :1] = 7
+    index = np.array([0, 9, 3, 0], np.int32)
+    sidx = np.array([[0], [9 * size + 1], [3], [0]], np.int32)
+    with eng._lock:
+        before = {k: np.asarray(v) for k, v in pg.kv[2].items()}
+        pool = jax.tree.map(jnp.copy, pg.kv)        # the program donates
+        built = _jitted(eng._pg_decode)._cache_size()
+        _, _, new, *_ = eng._pg_decode(
+            eng.params, pool, jnp.asarray(table), jnp.asarray(index),
+            jnp.asarray(sidx), jnp.zeros((SLOTS,), jnp.int32),
+            jnp.full((SLOTS,), -1, jnp.int32), jax.random.PRNGKey(0),
+            jnp.asarray(eng._temperature), jnp.asarray(eng._top_k),
+            jnp.asarray(eng._top_p), jnp.ones((SLOTS,), bool))
+        assert _jitted(eng._pg_decode)._cache_size() == built
+    trash = {(0, r) for r in range(size)}
+    for key, old in before.items():
+        changed = {tuple(at) for at in np.argwhere(
+            (np.asarray(new[2][key]) != old).any(axis=-1))}
+        assert (9, 1) in changed and changed <= trash | {(9, 1)}, key
 
 
 def test_stores_bytes_and_metrics(served):
@@ -159,6 +221,7 @@ def test_stores_bytes_and_metrics(served):
                  "llm_cross_decoder_rows_total",
                  "llm_cross_decoder_prefill_rows_total",
                  "llm_shared_kv_rows_attended_total",
+                 "llm_shared_kv_pages_read_total",
                  "llm_window_rows_attended_total",
                  "llm_global_view_tokens_total"):
         assert f"\n{name}" in text, name
