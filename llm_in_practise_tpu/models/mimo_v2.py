@@ -37,7 +37,14 @@ follow ``max_len``, which is how ``serve/paged_kv.py`` tells a layer that
 grows with the context from one whose state is bounded. A window layer given
 ``cache["valid"]`` (B,) takes only the first ``valid`` of the call's
 positions for real (a chunk's padding, a decode row that is not live,
-write nothing into the ring).
+write nothing into the ring). A decode program of the serving engine hands a
+global layer no view at all (the class declares ``reads_pages``): the pool's
+two buffers as they are stored by pages and each row's block table under
+``layers.PAGES_KEY``; the layer writes its new row into its page
+(``layers.page_row_write``) and attends the pages where they lie, to each
+row's true length (``swa.paged_decode_attention``), both global layers along
+ONE list of the rows' blocks. A chunk row (``L > 1``) keeps its gathered
+one-row view.
 
 **Left out**: the multi-token-prediction layers and the vision / audio
 encoders (the configuration holds no key of theirs). **Refused by name**
@@ -66,7 +73,7 @@ from llm_in_practise_tpu.ops.grouped_experts import (
 
 Cache = dict[str, Any]
 LOAD_KEY, ROUTE_KEY = layers.LOAD_KEY, layers.ROUTE_KEY
-VALID_KEY = layers.VALID_KEY
+VALID_KEY, PAGES_KEY = layers.VALID_KEY, layers.PAGES_KEY
 
 
 @dataclasses.dataclass(frozen=True)
@@ -242,7 +249,7 @@ class HybridAttention(nn.Module):
     window: bool
 
     @nn.compact
-    def __call__(self, x, *, cache=None, positions=None):
+    def __call__(self, x, *, cache=None, positions=None, pages=None):
         cfg = self.cfg
         b, l, _ = x.shape
         h, dq, dv = cfg.n_head, cfg.head_dim, cfg.v_head_dim
@@ -297,6 +304,20 @@ class HybridAttention(nn.Module):
                     sink=sink, cached=(cache["k"], cache["v"]))
             cache = dict(cache, k=ring_k, v=ring_v,
                          index=cache["index"] + l)
+        elif PAGES_KEY in cache:
+            # a global layer over the pool's PAGES (a decode program, l ==
+            # 1): the new row goes into its page, and the query walks the
+            # row's pages to its true length (``pages``: the model's one
+            # ``swa.paged_rows`` a program); a row that is not live writes
+            # into the trash page and reads nothing
+            pool_k, pool_v = (
+                layers.page_row_write(cache[key], pages["table"], start,
+                                      cache[VALID_KEY], new.reshape(b, -1))
+                for key, new in (("k", k), ("v", v)))
+            out = swa.paged_decode_attention(
+                q, pool_k, pool_v, scale=scale, kv_heads=hk, v_dim=dv,
+                **pages)
+            cache = dict(cache, k=pool_k, v=pool_v, index=cache["index"] + l)
         else:
             # a global layer's rows are FLAT (init_cache): one vector of
             # Hk * 192 / Hk * 128, whole lane tiles
@@ -372,11 +393,11 @@ class MiMoV2Block(nn.Module):
     routed: bool
 
     @nn.compact
-    def __call__(self, x, *, cache=None, positions=None):
+    def __call__(self, x, *, cache=None, positions=None, pages=None):
         cfg = self.cfg
         a, cache = HybridAttention(cfg, self.window, name="attn")(
             RMSNorm(cfg.rms_norm_eps, name="ln1")(x), cache=cache,
-            positions=positions)
+            positions=positions, pages=pages)
         x = x + a
         v = RMSNorm(cfg.rms_norm_eps, name="ln2")(x)
         if not self.routed:
@@ -421,11 +442,16 @@ class MiMoV2(nn.Module):
                          name="tok_embed")
         x = embed(idx).astype(compute)
         new_caches = [] if cache is not None else None
+        # a decode program whose global layers read their pages in place:
+        # ONE walk of the rows' pages (they all read the same rows)
+        pages = next((swa.paged_rows(c[PAGES_KEY], c["index"], c[VALID_KEY],
+                                     c["v"].shape[1])
+                      for c in cache or () if PAGES_KEY in c), None)
         for i in range(cfg.n_layer):
             x, layer_cache = MiMoV2Block(
                 cfg, cfg.is_window(i), cfg.is_routed(i), name=f"block_{i}")(
                 x, cache=cache[i] if cache is not None else None,
-                positions=positions)
+                positions=positions, pages=pages)
             if new_caches is not None:
                 new_caches.append(layer_cache)
         x = RMSNorm(cfg.rms_norm_eps, name="ln_f")(x)
@@ -466,6 +492,11 @@ class MiMoV2(nn.Module):
     @property
     def cache_slot_axis(self) -> int:
         return 0
+
+    #: a decode program hands the global layers the pool's pages as they
+    #: are stored and each row's block table (``layers.PAGES_KEY``), not a
+    #: gathered view
+    reads_pages = True
 
     def step_stats(self, rows: int) -> list[dict]:
         """Zeroed per-layer statistics entries for a serving program's

@@ -471,9 +471,7 @@ def attention_mixer(cfg, p, h, cache, layer, kind, shared=None):
         pool = {key: layers.page_row_write(cache[key], table, start, valid,
                                            new.reshape(b, -1))
                 for key, new in (("k1", k1), ("k2", k2), ("v", v))}
-        lengths = jnp.where(valid > 0, start + 1, 0)
-        pages = dict(table=table, lengths=lengths, work=swa.paged_decode_work(
-            lengths, pool["v"].shape[1], table.shape[1]))
+        pages = swa.paged_rows(table, start, valid, pool["v"].shape[1])
         with jax.named_scope(SHARED_DECODE_SCOPE):
             a1, a2 = swa.paged_paired_decode_attention(
                 (q1, q2), (pool["k1"], pool["k2"]), pool["v"], scale=scale,
@@ -732,10 +730,7 @@ class Phi4Flash(nn.Module):
         """For the step statistics (``serve/step_stats.py``): a model with
         recurrent layers and a cross-decoder; ``shared_readers`` layers
         attend the one paged layer's view."""
-        return {"shared_readers": 1 + self.cfg.kinds.count(CROSS),
-                # pages a reader copies at once: a row's pages read are
-                # its length up to whole blocks of them
-                "page_block": swa.PAGED_DECODE_PAGES}
+        return {"shared_readers": 1 + self.cfg.kinds.count(CROSS)}
 
 
 def random_params(cfg: Phi4FlashConfig, seed: int, dtype=jnp.bfloat16,

@@ -33,6 +33,9 @@ keys' partial result with ``(0, b_h)``.
   row, XLA einsums over the gathered view of FLAT rows (``Hk * 192`` and
   ``Hk * 128`` wide, as the pool's pages hold them) / over the ring,
   under :data:`GLOBAL_DECODE_SCOPE` / :data:`WINDOW_DECODE_SCOPE`.
+- :func:`paged_decode_attention`: the same one query a row with NO view:
+  a Pallas kernel that walks the pool's pages where they lie, to each
+  row's true length (a decode program of a model that ``reads_pages``).
 
 **The ring.** A window layer's cache is ``(B, R, Hk, D)`` with ``R =
 min(max_len, window)``: row ``p mod R`` holds position ``p``. Which row
@@ -46,6 +49,7 @@ stretch (``valid`` of its ``L`` are real; padding writes nothing).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -513,17 +517,24 @@ def paired_decode_attention(qs, ks, v, index, *, scale: float):
 
 
 # --- one query a row over the pages where they lie ----------------------------
-# The in-place twin of :func:`paired_decode_attention`: no gathered view. The
-# pool stays as ``serve/paged_kv.py`` stores it by pages, ``(pages, page
-# rows, Hk * D up to whole lanes)``, and a slot's block-table row names its
-# pages in position order. The kernel copies ``PAGED_DECODE_PAGES`` pages of
-# each buffer into a block of fast memory itself (a grid step a page would
-# be ~7,000 steps a reader), the next block's copies in flight while this
-# one is computed, along a flat list of (row, block) pairs that holds a
-# row's blocks up to its true length and nothing of a row of length 0.
+# The in-place twins of :func:`decode_attention` and
+# :func:`paired_decode_attention`: no gathered view. The pool stays as
+# ``serve/paged_kv.py`` stores it by pages, ``(pages, page rows, Hk * D up to
+# whole lanes)``, and a slot's block-table row names its pages in position
+# order. The kernel copies ``PAGED_DECODE_PAGES`` pages of each buffer into a
+# block of fast memory itself (a grid step a page would be ~7,000 steps a
+# reader), the next block's copies in flight while this one is computed,
+# along a flat list of (row, block) pairs that holds a row's blocks up to its
+# true length and nothing of a row of length 0. ONE kernel for one softmax a
+# query head (a global layer of ``models/mimo_v2.py``) and for two that share
+# a read of the values (``models/phi4flash.py``): how many key pools stand
+# beside the value pool, and every row width, are static and read off the
+# operands.
 
+# the kernels' names on the device plane, by the softmaxes a query head takes
+GLOBAL_PAGED_KERNEL = "global_paged_decode"
 PAGED_DECODE_KERNEL = "shared_kv_paged_decode"
-# pages a compute block (tools/paged_decode_bakeoff.py; PERF.md, PR 48)
+# pages a compute block (tools/paged_decode_bakeoff.py; PERF.md, PR 48, PR 49)
 PAGED_DECODE_PAGES = 32
 
 
@@ -551,47 +562,67 @@ def paged_decode_work(lengths, page_size: int, max_pages: int,
     return row, i - (ends - n)[row], ends[-1:]
 
 
+def paged_rows(table, start, valid, page_size: int) -> dict:
+    """What a decode program's readers of one pool share: the keywords
+    ``table``, ``lengths`` and ``work`` of the two entries below for rows
+    whose new key lands at ``start`` (B,); a row whose ``valid`` is 0 (idle,
+    mid-prefill) reads nothing."""
+    lengths = jnp.where(valid > 0, start + 1, 0)
+    return dict(table=table, lengths=lengths, work=paged_decode_work(
+        lengths, page_size, table.shape[1]))
+
+
 def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
-                         q_ref, k1_hbm, k2_hbm, v_hbm, o_ref,
-                         k1_buf, k2_buf, v_buf, sems, m_ref, l_ref, acc_ref,
-                         *, scale, pairs, group, kv_pairs):
-    """ONE invocation walks the whole list. ``q_ref`` (B, 2, Hp, C): both
-    halves' queries of every query pair (``Hp``: the pairs up to whole
-    sublane tiles), each zero outside its K/V pair's columns of a key
-    row; ``table_ref`` (B * pages a slot,), flat; ``o_ref`` (B, pairs, 2
-    * Dv) float32, ``[a1 ‖ a2]`` of each pair. The halves' scores stack
-    to ``(2 Hp, rows)``: the value block is multiplied once."""
-    _, ppb, page, _ = k1_buf.shape
+                         q_ref, *refs, scale, n, heads, group, kv_heads):
+    """ONE invocation walks the whole list. ``n`` softmaxes a query head,
+    each over a key pool of its own, all over one value pool. ``q_ref`` (B,
+    n, Hp, C): every head's query for each softmax (``Hp``: the ``heads``
+    up to whole sublane tiles), zero outside its K/V head's columns of a
+    key row; ``table_ref`` (B * pages a slot,), flat; ``refs``: the ``n``
+    key pools and the value pool as they lie, ``o_ref`` (B, heads, n * Dv)
+    float32, a head's ``n`` results side by side, then a two-block buffer
+    a pool, the copies' semaphores, and the running maximum, denominator
+    and sum. The softmaxes' scores stack to ``(n Hp, rows)``: the value
+    block is multiplied once."""
+    hbm, o_ref = refs[:n + 1], refs[n + 1]
+    bufs = refs[n + 2:2 * n + 3]
+    sems, m_ref, l_ref, acc_ref = refs[2 * n + 3:]
+    _, ppb, page, _ = bufs[0].shape
     rows = ppb * page
-    hp, dv = q_ref.shape[2], o_ref.shape[-1] // 2
+    hp, dv = q_ref.shape[2], o_ref.shape[-1] // n
     max_pages = table_ref.shape[0] // q_ref.shape[0]
     total = total_ref[0]
-    pools = ((k1_hbm, k1_buf), (k2_hbm, k2_buf), (v_hbm, v_buf))
 
-    def copy(slot, p, at, n):
-        hbm, buf = pools[n]
-        return pltpu.make_async_copy(hbm.at[at], buf.at[slot, p],
-                                     sems.at[slot, n])
+    def copy(slot, p, at, pool):
+        return pltpu.make_async_copy(hbm[pool].at[at], bufs[pool].at[slot, p],
+                                     sems.at[slot, pool])
 
-    # a loop a page, not 3 * ppb copies spelled out: the program that
-    # holds the kernel is traced and lowered in a fraction of the time
+    # the copies are issued by the scalar core, ~37 ns each: a block of 16
+    # KB pages is bound by that and not by the bytes (PERF.md, PR 49), so
+    # the issue loop is unrolled by 8 (a loop all the same, not (n + 1) *
+    # ppb copies spelled out: the program that holds the kernel is traced
+    # and lowered in a fraction of the time) ...
+    unroll = math.gcd(ppb, 8)
+
     def start(i, slot):
         base = row_ref[i] * max_pages + blk_ref[i] * ppb
 
         def page_copies(p, carry):
-            for n in range(len(pools)):
-                copy(slot, p, table_ref[base + p], n).start()
+            for at in (p * unroll + d for d in range(unroll)):
+                for pool in range(n + 1):
+                    copy(slot, at, table_ref[base + at], pool).start()
             return carry
 
-        jax.lax.fori_loop(0, ppb, page_copies, None)
+        jax.lax.fori_loop(0, ppb // unroll, page_copies, None)
 
+    # ... and a pool's ``ppb`` copies are awaited at ONCE: a semaphore
+    # counts bytes, and this descriptor (any pages: it is never started)
+    # stands for a whole block of them
     def wait(slot):
-        def page_copies(p, carry):
-            for n in range(len(pools)):
-                copy(slot, p, 0, n).wait()      # any page: its bytes count
-            return carry
-
-        jax.lax.fori_loop(0, ppb, page_copies, None)
+        for pool in range(n + 1):
+            pltpu.make_async_copy(hbm[pool].at[pl.ds(0, ppb)],
+                                  bufs[pool].at[slot],
+                                  sems.at[slot, pool]).wait()
 
     # a row of length 0 is in no item: zeros
     o_ref[...] = jnp.zeros_like(o_ref)
@@ -616,13 +647,13 @@ def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
             l_ref[...] = jnp.zeros_like(l_ref)
 
         wait(slot)
-        q = q_ref[b]                                        # (2, Hp, C)
+        q = q_ref[b]                                        # (n, Hp, C)
         s = jnp.concatenate([
             jax.lax.dot_general(
-                q[n], buf[slot].reshape(rows, -1).astype(q.dtype),
+                q[x], bufs[x][slot].reshape(rows, -1).astype(q.dtype),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            for n, buf in enumerate((k1_buf, k2_buf))], axis=0) * scale
+            for x in range(n)], axis=0) * scale
         live = j * rows + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1) < length
         # a block of the list holds a live key (j * rows < length): the
@@ -635,51 +666,42 @@ def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
         l_ref[:, 0:1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[:, 0:1] = m_new
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(q.dtype), v_buf[slot].reshape(rows, -1).astype(q.dtype),
+            p.astype(q.dtype),
+            bufs[n][slot].reshape(rows, -1).astype(q.dtype),
             preferred_element_type=jnp.float32)
 
         @pl.when((j + 1) * rows >= length)
         def _():
-            # row r of a half reads K/V pair r // group: its Dv columns
+            # row r of a softmax reads K/V head r // group: its Dv columns
             # of the sum over whole value rows
             mine = jax.lax.broadcasted_iota(
-                jnp.int32, (2 * hp, dv), 0) % hp // group
-            out = jnp.zeros((2 * hp, dv), jnp.float32)
-            for g in range(kv_pairs):
+                jnp.int32, (n * hp, dv), 0) % hp // group
+            out = jnp.zeros((n * hp, dv), jnp.float32)
+            for g in range(kv_heads):
                 out = jnp.where(mine == g,
                                 acc_ref[:, g * dv:(g + 1) * dv], out)
             out = out / l_ref[:, 0:1]
             o_ref[b] = jnp.concatenate(
-                [out[:pairs], out[hp:hp + pairs]], axis=-1)
+                [out[x * hp:x * hp + heads] for x in range(n)], axis=-1)
         return carry
 
     jax.lax.fori_loop(0, total, block, None)
 
 
-# jitted in its own right: a program's readers (the full layer, the scanned
-# cross layers) and every program of a process share ONE trace of the kernel
+# jitted in its own right: a program's readers (every global layer; the full
+# layer and the scanned cross layers) and every program of a process share
+# ONE trace of the kernel
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "kv_heads", "pages_per_block", "interpret"))
-def paged_paired_decode_attention(qs, ks, v, table, lengths, *, scale: float,
-                                  kv_heads: int, work=None,
-                                  pages_per_block: int | None = None,
-                                  interpret: bool | None = None):
-    """:func:`paired_decode_attention` over the pool's PAGES where they
-    lie, to each row's true length. ``qs`` = ``(q1, q2)`` (B, 1, H, Dq);
-    ``ks`` = the two key pools (pages, page rows, Hk * Dq up to whole
-    lanes) and ``v`` the value pool (pages, page rows, Hk * Dv up to whole
-    lanes; ``Hk`` = ``kv_heads``, ``Dv`` = 2 ``Dq``); ``table`` (B, pages a
-    slot) int32 names each row's pages in position order (past the row's
-    own: any page of the pool, its rows are masked); ``lengths`` (B,): row
-    ``b`` attends positions ``0 .. lengths[b] - 1``, its own included; at
-    0 it reads nothing and gets zeros. ``work``: :func:`paged_decode_work` of these
-    lengths, from a caller with several readers. Returns ``(a1, a2)`` (B,
-    1, H, Dv) float32."""
+    "scale", "kv_heads", "v_dim", "name", "pages_per_block", "interpret"))
+def _paged_attention(qs, ks, v, table, lengths, *, scale, kv_heads, v_dim,
+                     name, work=None, pages_per_block=None, interpret=None):
+    """``len(qs)`` softmaxes a query head over the pool's pages: ``(B,
+    H, len(qs) * v_dim)`` float32, the kernel's own result."""
     b, _, h, dq = qs[0].shape
     dtype = qs[0].dtype
+    n, hk, dv = len(qs), kv_heads, v_dim
     page, ck = ks[0].shape[1:]
-    cv, dv, hk = v.shape[2], 2 * dq, kv_heads
-    group = h // hk
+    cv = v.shape[2]
     ppb = paged_block_pages(table.shape[1], pages_per_block)
     if work is None:
         work = paged_decode_work(lengths, page, table.shape[1], ppb)
@@ -691,40 +713,75 @@ def paged_paired_decode_attention(qs, ks, v, table, lengths, *, scale: float,
     with jax.named_scope(GLOBAL_DECODE_SCOPE):
         own = jnp.eye(hk, dtype=dtype)
         wide = jnp.stack([
-            (q[:, 0].reshape(b, hk, group, 1, dq)
+            (q[:, 0].reshape(b, hk, h // hk, 1, dq)
              * own[None, :, None, :, None]).reshape(b, h, hk * dq)
-            for q in qs], axis=1)                           # (B, 2, H, C)
+            for q in qs], axis=1)                           # (B, n, H, C)
         wide = jnp.pad(wide, ((0, 0), (0, 0), (0, hp - h),
                               (0, ck - hk * dq)))
         whole = lambda shape: pl.BlockSpec(       # noqa: E731
             shape, lambda i, *_: (0,) * len(shape))
-        out = pl.pallas_call(
-            functools.partial(_paged_decode_kernel, scale=scale, pairs=h,
-                              group=group, kv_pairs=hk),
+        return pl.pallas_call(
+            functools.partial(_paged_decode_kernel, scale=scale, n=n,
+                              heads=h, group=h // hk, kv_heads=hk),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=(1,),
                 in_specs=[whole(wide.shape)]
-                + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
-                out_specs=whole((b, h, 2 * dv)),
+                + [pl.BlockSpec(memory_space=pl.ANY)] * (n + 1),
+                out_specs=whole((b, h, n * dv)),
                 scratch_shapes=[
-                    pltpu.VMEM((2, ppb, page, ck), ks[0].dtype),
-                    pltpu.VMEM((2, ppb, page, ck), ks[1].dtype),
+                    *(pltpu.VMEM((2, ppb, page, ck), k.dtype) for k in ks),
                     pltpu.VMEM((2, ppb, page, cv), v.dtype),
-                    pltpu.SemaphoreType.DMA((2, 3)),
-                    pltpu.VMEM((2 * hp, _LANE), jnp.float32),
-                    pltpu.VMEM((2 * hp, _LANE), jnp.float32),
-                    pltpu.VMEM((2 * hp, cv), jnp.float32),
+                    pltpu.SemaphoreType.DMA((2, n + 1)),
+                    pltpu.VMEM((n * hp, _LANE), jnp.float32),
+                    pltpu.VMEM((n * hp, _LANE), jnp.float32),
+                    pltpu.VMEM((n * hp, cv), jnp.float32),
                 ],
             ),
-            out_shape=jax.ShapeDtypeStruct((b, h, 2 * dv), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((b, h, n * dv), jnp.float32),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=_PAGED_VMEM),
             interpret=interpret_default() if interpret is None else interpret,
-            name=PAGED_DECODE_KERNEL,
-        )(*work, lengths.astype(jnp.int32), table.reshape(-1), wide,
-          ks[0], ks[1], v)
+            name=name,
+        )(*work, lengths.astype(jnp.int32), table.reshape(-1), wide, *ks, v)
+
+
+def paged_decode_attention(q, k, v, table, lengths, *, scale: float,
+                           kv_heads: int, v_dim: int, work=None,
+                           pages_per_block: int | None = None,
+                           interpret: bool | None = None):
+    """:func:`decode_attention` over the pool's PAGES where they lie, to
+    each row's true length. ``q`` (B, 1, H, Dq); ``k`` / ``v`` the pools
+    (pages, page rows, Hk * Dq | Hk * Dv up to whole lanes; ``Hk`` =
+    ``kv_heads``, ``Dv`` = ``v_dim``: a pool's rows are padded to whole
+    lanes, so their width does not say it); ``table`` (B, pages a slot)
+    int32 names each row's pages in position order (past the row's own:
+    any page of the pool, its rows are masked); ``lengths`` (B,): row ``b``
+    attends positions ``0 .. lengths[b] - 1``, its own included; at 0 it
+    reads nothing and gets zeros. ``work``: :func:`paged_decode_work` of
+    these lengths, from a caller with several readers
+    (:func:`paged_rows`). Returns (B, 1, H, Dv) in the query's dtype."""
+    out = _paged_attention(
+        (q,), (k,), v, table, lengths, scale=scale, kv_heads=kv_heads,
+        v_dim=v_dim, name=GLOBAL_PAGED_KERNEL, work=work,
+        pages_per_block=pages_per_block, interpret=interpret)
+    return out[:, None].astype(q.dtype)
+
+
+def paged_paired_decode_attention(qs, ks, v, table, lengths, *, scale: float,
+                                  kv_heads: int, work=None,
+                                  pages_per_block: int | None = None,
+                                  interpret: bool | None = None):
+    """:func:`paired_decode_attention` over the pool's pages, as
+    :func:`paged_decode_attention` reads them: ``qs`` = ``(q1, q2)`` (B, 1,
+    H, Dq), ``ks`` = the two key pools, ``v`` the one value pool, read once
+    (``Dv`` = 2 ``Dq``). Returns ``(a1, a2)`` (B, 1, H, Dv) float32."""
+    dv = 2 * qs[0].shape[-1]
+    out = _paged_attention(
+        tuple(qs), tuple(ks), v, table, lengths, scale=scale,
+        kv_heads=kv_heads, v_dim=dv, name=PAGED_DECODE_KERNEL, work=work,
+        pages_per_block=pages_per_block, interpret=interpret)
     return tuple(out[:, None, :, i * dv:(i + 1) * dv] for i in range(2))
 
 

@@ -830,6 +830,13 @@ class OpenAIServer:
                     "window_ring_rows_read",
                     "ring rows those layers read: every slot's whole "
                     "ring, idle slots too"))
+            if stats.page_block and not stats.census:
+                families.append((
+                    "global_pages_read",
+                    "pages of the global layers the decode steps' readers "
+                    "copied where they lie (a model that declares "
+                    "reads_pages: no gathered view): live rows' lengths "
+                    "up to whole blocks x the layers that read them"))
             if stats.census:
                 # recurrent layers and a cross-decoder
                 # (models/phi4flash.py), booked by the host

@@ -4874,9 +4874,17 @@ class InferenceEngine:
             # views are a change of their own)
             if self._reads_pages:
                 # no view of the decode plane: the width is the chunk
-                # rows' alone, each gathered for its own trip
-                W = self._paged_width(
-                    max(st["done"] for _, st, _ in entries) + C)
+                # rows' alone, each gathered for its own trip, and no
+                # narrower than the SHORTEST row that decodes beside
+                # them (its length after this block) would make the
+                # rule below: a prompt's first chunks then build no
+                # mixed program that a gathered engine would not
+                # (PERF.md, PR 49: two of five in the agents' cell, 23 s
+                # of a 150 s set-up)
+                floor = min(int(self.slot_len[s]) for s in active) + n + C
+                W = self._paged_width(max(
+                    max(st["done"] for _, st, _ in entries) + C,
+                    min(floor, self.cache_len)))
                 self._pulse_view(W, 1)
             else:
                 need = max(

@@ -21,7 +21,11 @@ the host by their tokens alone, for two kinds of model:
   ``global_*`` after the model's cache), and only here the ring rows its
   window layers attended against the ring rows they READ (every slot's
   whole ring, idle slots too: ``models/afmoe.py``'s is 4,096 rows) and a
-  chunk's (query, key) pairs under the band.
+  chunk's (query, key) pairs under the band. Where the decode reads the
+  global layers' pages in place (the model declares ``reads_pages``), the
+  view's rows are the rows one layer's reader copied (live rows' lengths
+  up to whole blocks of pages) and ``global_pages_read`` the pages all of
+  them copied.
 
 - a model with RECURRENT layers and a CROSS-DECODER (``models/phi4flash.py``;
   the model's ``census()`` gives how many layers read the shared view): the positions its chunk
@@ -52,6 +56,7 @@ import jax
 import numpy as np
 
 from llm_in_practise_tpu.models.layers import LOAD_KEY, ROUTE_KEY
+from llm_in_practise_tpu.ops import swa_attention as swa
 
 
 class RoutingLoad:
@@ -136,15 +141,21 @@ class StepStats:
             for key in ("ssm_scan_tokens", "ssm_state_rows_advanced",
                         "ssm_state_rows_held", "self_decoder_rows",
                         "cross_decoder_rows", "cross_decoder_prefill_rows",
-                        "shared_kv_rows_attended", "shared_kv_pages_read"):
+                        "shared_kv_rows_attended"):
                 setattr(self, key, 0)
-        # a model whose decode reads its paged layer's pages in place
-        # (``PagedKV.in_place``): the pages its readers copy at once (the
-        # census' ``page_block``, at most a slot's pages), else 0
-        self.page_block = min(
-            (self.census or {}).get("page_block", 0),
+        # a model whose decode reads its paged layers' pages in place
+        # (``PagedKV.in_place``): the pages a reader copies at once (the
+        # kernel's block, at most a slot's pages), else 0; how many layers
+        # read them (a cross-decoder's all read ONE layer's), and the
+        # family name their copies are counted under
+        self.page_block = swa.paged_block_pages(
             engine.paged.pages_per_slot) if any(engine.paged.in_place) else 0
         self.block_rows = self.page_block * engine.paged.page_size
+        self.page_readers = (self.census["shared_readers"] if self.census
+                             else sum(engine.paged.in_place))
+        self.pages_key = ("shared_kv_pages_read" if self.census
+                          else "global_pages_read")
+        setattr(self, self.pages_key, 0)
         # reference comparisons (tests, the benchmark's check) set this
         # to a list: every booked program then appends {"kind", "uids":
         # {slot: request uid} at the dispatch, "route": per part (routed
@@ -238,8 +249,7 @@ class StepStats:
             pages = sum(-(-length // self.block_rows) for length in lens
                         ) * self.page_block
             counts[self.view_key] = pages * eng.paged.page_size
-            counts["shared_kv_pages_read"] = (
-                pages * self.census["shared_readers"])
+            counts[self.pages_key] = pages * self.page_readers
         if self.ring_rows:
             counts["window_rows_attended"] = sum(
                 min(length, self.ring_rows) for length in lens)

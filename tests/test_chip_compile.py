@@ -399,3 +399,36 @@ def test_paged_decode_attention_compiles(chip):
     assert swa.PAGED_DECODE_KERNEL in text and "f32[16,20,256]" in text
     # no view: nothing of (16, n, 640 | 1280) is built around the kernel
     assert "[16,16384,640]" not in text and "[16,16384,1280]" not in text
+
+
+@pytest.mark.parametrize("heads, kv_heads, dq, dv, pool", [
+    (64, 4, 192, 128, jnp.bfloat16), (64, 4, 192, 128, jnp.float8_e4m3fn),
+], ids=["mimo", "mimo-fp8"])
+def test_global_paged_decode_attention_compiles(chip, heads, kv_heads, dq,
+                                                dv, pool):
+    """One global layer's reader of MiMo-V2.5 at its cell's shapes: 16
+    slots of 2,048 pages of 16 rows over a pool of 32,769 pages, key rows
+    of 768 lanes beside value rows of 512; ONE softmax a head, the same
+    kernel as the paired reader's. The fp8 pool is
+    ``tools/swa_check_control.py``'s control; Trinity's shapes (1,024 /
+    1,024 lanes; its model still gathers: PERF.md, PR 49) are what
+    ``tools/paged_decode_bakeoff.py`` measures."""
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def reader(q, k, v, table, lengths):
+        return swa.paged_decode_attention(
+            q, k, v, table, lengths, scale=dq ** -0.5, kv_heads=kv_heads,
+            v_dim=dv, interpret=False)
+
+    text = _compile(reader, arg((16, 1, heads, dq)),
+                    arg((32769, 16, kv_heads * dq), pool),
+                    arg((32769, 16, kv_heads * dv), pool),
+                    arg((16, 2048), jnp.int32), arg((16,), jnp.int32))
+    # by its name, and by the (slots, query heads, n) result the cells'
+    # readers find the global decode attention by
+    assert swa.GLOBAL_PAGED_KERNEL in text
+    assert f"f32[16,{heads},{dv}]" in text
+    assert swa.GLOBAL_KERNEL not in text

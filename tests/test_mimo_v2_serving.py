@@ -116,11 +116,106 @@ def test_step_records_count_both_kinds_of_layer(served):
     # one attended / view pair, under the name that fits the cache
     assert st.load.layer_passes > 0 and not hasattr(st, "latent_view_tokens")
     assert st.global_view_tokens >= sum(r["global_view_tokens"] for r in dec)
-    # gathered views (no ``reads_pages`` here): every slot x a pow2 width
-    assert not any(eng.paged.in_place)
-    widths = {r["global_view_tokens"] // eng.max_slots for r in dec}
-    assert all(r["global_view_tokens"] % eng.max_slots == 0 for r in dec)
-    assert all(w & (w - 1) == 0 and w <= eng.cache_len for w in widths)
+
+
+def _jitted(fn):
+    """The ``jax.jit`` under the engine's meters."""
+    while not hasattr(fn, "_cache_size"):
+        fn = fn.__wrapped__
+    return fn
+
+
+def test_decode_reads_the_global_layers_pages_where_they_lie(served):
+    """The class declares ``reads_pages``: no view of the two global
+    layers in a decode step. The step books the rows ONE layer's reader
+    copied (each live row's length up to whole blocks: a slot's 16 pages
+    here, one block) and the pages x 2 layers, and gathers nothing; ONE
+    decode executable served every length; a mixed step still gathers its
+    chunk row's one-row view, and only a chunk row's dispatch pulses the
+    ledger's ``transient_view``."""
+    eng, st = served.eng, served.eng.step_stats
+    pg = eng.paged
+    assert pg.in_place == [True, False, False, True] and eng._reads_pages
+    assert st.page_block == pg.pages_per_slot == 16
+    dec = [r for r in served.records if "window_rows_attended" in r]
+    assert len({r["global_tokens_attended"] for r in dec}) > 8
+    for r in dec:
+        live, rest = divmod(r["global_view_tokens"], 16 * pg.page_size)
+        assert 1 <= live <= eng.max_slots and rest == 0
+        assert r["global_tokens_attended"] <= r["global_view_tokens"]
+        assert r["global_pages_read"] == live * 16 * 2
+        assert "shared_kv_pages_read" not in r
+        chunked = r.get("prefill_chunk_capacity", 0) // 16
+        # a chunk row's one-row view, whole pages; nothing for the plane
+        assert (r["view_pages"] > 0) == (chunked > 0)
+    assert st.global_pages_read == sum(r["global_pages_read"] for r in dec)
+    assert _jitted(eng._pg_decode)._cache_size() == 1
+    assert _jitted(eng._pg_multi)._cache_size() == 0
+    # one more short prompt: its ONE chunk trip pulses the ledger's
+    # transient view, its decode steps do not
+    pulses = lambda: get_ledger().snapshot()["accounts"][  # noqa: E731
+        "transient_view"]["pulses"]
+    before = pulses()
+    assert len(eng.submit(served.prompts[2], dataclasses.replace(
+        GREEDY, max_tokens=5)).result()) == 5
+    assert pulses() - before == 1
+
+
+def test_a_chunk_rows_view_is_no_narrower_than_a_gathered_engines(served):
+    """The 70-token prompt chunks beside the 37-token one alone: its view
+    in a mixed step is its own ``done`` + a chunk up to a power of two AND
+    no narrower than the decoding row's length + a chunk gives (64), the
+    narrowest a gathered engine builds there, so its first chunks (16 and
+    32 wide by themselves) build no mixed program of their own."""
+    eng = served.eng
+    with eng._lock:
+        seen = eng.steptrace.records(limit=1)[-1]["seq"]
+    lead = eng.submit(served.prompts[0], GREEDY)
+    lead.next_item()
+    eng.submit(served.prompts[1],
+               dataclasses.replace(GREEDY, max_tokens=2)).result()
+    lead.result()
+    with eng._lock:
+        mixed = [r for r in eng.steptrace.records(limit=60)
+                 if r["seq"] > seen and "global_pages_read" in r
+                 and r["view_pages"]]
+    # 70 tokens: chunks at done = 0 .. 64; one row's view of 8-row pages
+    assert [r["view_pages"] * 8 for r in mixed] == [64, 64, 64, 64, 128]
+
+
+class _Gathered(mm.MiMoV2):
+    """The same model on the gathered path: a decode program gets a pow2
+    view of every global layer."""
+    reads_pages = False
+
+
+def test_pages_in_place_give_the_gathered_paths_tokens(served):
+    """The 9-token prompt again, alone, through an engine whose decode
+    GATHERS (``reads_pages`` False): the same greedy tokens and the same
+    last-position logits as the rows read in place gave."""
+    twin = InferenceEngine(_Gathered(served.cfg), served.params, max_slots=4,
+                           cache_len=128, kv_layout="paged", kv_page_size=8,
+                           chunked_prefill=16, cache_dtype=jnp.float32)
+    assert not any(twin.paged.in_place) and not twin._reads_pages
+    twin.step_stats.capture = []
+    with jax.default_matmul_precision("highest"):
+        handle = twin.submit(served.prompts[2], GREEDY)
+        while twin.step():
+            pass
+    assert handle.result() == served.tokens[2]
+    (want,) = [c["last_logits"] for c in twin.step_stats.capture
+               if c["last_logits"]]
+    first = min(u for c in served.captured for u in c["uids"].values())
+    (got,) = [logits for c in served.captured
+              for slot, logits in c["last_logits"].items()
+              if c["uids"][slot] == first + 2]
+    np.testing.assert_allclose(got, next(iter(want.values())), atol=1e-4)
+    # a gathered decode's view is every slot x a pow2 width
+    dec = [r for r in twin.steptrace.records(limit=50)
+           if "window_rows_attended" in r]
+    assert dec and all(r["global_view_tokens"] in (4 * 16, 4 * 32)
+                       and "global_pages_read" not in r for r in dec)
+    twin.stop()
 
 
 def test_metrics_and_debug_name_both_stores(served):
@@ -145,6 +240,7 @@ def test_metrics_and_debug_name_both_stores(served):
                  "llm_window_rows_attended_total",
                  "llm_global_tokens_attended_total",
                  "llm_global_view_tokens_total",
+                 "llm_global_pages_read_total",
                  "llm_moe_layer_passes_total"):
         assert f"\n{name}" in text, name
     assert "llm_latent_tokens_attended_total" not in text

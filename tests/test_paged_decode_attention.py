@@ -1,8 +1,8 @@
-"""``swa.paged_paired_decode_attention``: one query a row over the pool's
-PAGES where they lie, to each row's true length, against
-``swa.paired_decode_attention`` over the gathered view of the same pages
-(interpret mode, tiny shapes: pages of 8 rows, blocks of 2 pages, 8 pages a
-slot)."""
+"""``swa.paged_decode_attention`` / ``swa.paged_paired_decode_attention``:
+one query a row over the pool's PAGES where they lie, to each row's true
+length, against ``swa.decode_attention`` / ``swa.paired_decode_attention``
+over the gathered view of the same pages (interpret mode, tiny shapes: pages
+of 8 rows, blocks of 2 pages, 8 pages a slot)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -27,6 +27,18 @@ def _pools(rng, n_pages, kv_pairs, dtype):
     return out
 
 
+def _scattered(rng, lengths):
+    """Scattered, non-monotone tables over a pool of the rows' pages and
+    the trash page (0), which a table names past a row's own pages, as an
+    unmapped logical page does."""
+    b = len(lengths)
+    n_pages = b * PER_SLOT + 1
+    table = rng.permutation(np.arange(1, n_pages)).reshape(b, PER_SLOT)
+    for row, n in enumerate(lengths):
+        table[row, paged_kv.pages_for(n, PAGE):] = paged_kv.TRASH_PAGE
+    return n_pages, jnp.asarray(table, jnp.int32)
+
+
 @pytest.mark.parametrize("lengths, pairs, kv_pairs, dtype", [
     # idle, one key, a page's edge and its neighbours
     ((0, 1, 15, 16, 17), 4, 2, jnp.float32),
@@ -43,14 +55,8 @@ def test_pages_in_place_are_the_gathered_view(lengths, pairs, kv_pairs,
                                               dtype):
     rng = np.random.default_rng(len(lengths) + pairs)
     b = len(lengths)
-    n_pages = b * PER_SLOT + 1
+    n_pages, table = _scattered(rng, lengths)
     pools = _pools(rng, n_pages, kv_pairs, dtype)
-    # scattered, non-monotone tables; past a row's own pages the table
-    # names the trash page (0), as an unmapped logical page does
-    table = rng.permutation(np.arange(1, n_pages)).reshape(b, PER_SLOT)
-    for row, n in enumerate(lengths):
-        table[row, paged_kv.pages_for(n, PAGE):] = paged_kv.TRASH_PAGE
-    table = jnp.asarray(table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     qs = tuple(jnp.asarray(rng.normal(size=(b, 1, pairs, DQ)), dtype)
                for _ in range(2))
@@ -69,6 +75,56 @@ def test_pages_in_place_are_the_gathered_view(lengths, pairs, kv_pairs,
                                    rtol=tol, atol=tol)
         # a row of length 0 reads nothing: zeros, not the trash page
         assert not np.asarray(a)[~live].any()
+
+
+@pytest.mark.parametrize("lengths, heads, kv_heads, dq, dv, dtype, pool", [
+    # MiMo-V2's global layer: keys of 192 over values of 128, 4 K/V heads
+    ((0, 1, EDGE - 1, EDGE, EDGE + 1, CACHE), 8, 4, 192, 128, jnp.float32,
+     jnp.float32),
+    # Trinity's: 128 / 128 over 8
+    ((CACHE, 0, EDGE + 1, 1), 16, 8, 128, 128, jnp.float32, jnp.float32),
+    ((0, 0, 0), 8, 4, 192, 128, jnp.float32, jnp.float32),
+    # rows narrower than a lane tile: the value width is the caller's
+    ((17, CACHE, 0, EDGE), 4, 2, 24, 16, jnp.float32, jnp.float32),
+    ((5, CACHE, 0, EDGE), 8, 4, 192, 128, jnp.bfloat16, jnp.bfloat16),
+    ((CACHE, 3, EDGE + 1), 16, 8, 128, 128, jnp.bfloat16,
+     jnp.float8_e4m3fn),
+], ids=["192-over-128", "128-over-128", "all-idle", "padded-rows", "bf16",
+        "fp8-pages"])
+def test_one_softmax_over_pages_in_place_is_the_gathered_view(
+        lengths, heads, kv_heads, dq, dv, dtype, pool):
+    """Two layers' pools read along ONE work list, as a program with
+    several global layers reads them."""
+    rng = np.random.default_rng(len(lengths) + heads)
+    b = len(lengths)
+    n_pages, table = _scattered(rng, lengths)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    work = swa.paged_decode_work(lengths, PAGE, PER_SLOT, BLOCK)
+    live = np.asarray(lengths) > 0
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for _ in range(2):
+        bufs = []
+        for width in (kv_heads * dq, kv_heads * dv):
+            buf = np.zeros((n_pages, PAGE, paged_kv.lane_whole(width)),
+                           np.float32)
+            buf[..., :width] = rng.normal(size=(n_pages, PAGE, width))
+            bufs.append((jnp.asarray(buf, pool), width))
+        q = jnp.asarray(rng.normal(size=(b, 1, heads, dq)), dtype)
+        (k, _), (v, _) = bufs
+        got = swa.paged_decode_attention(
+            q, k, v, table, lengths, scale=dq ** -0.5, kv_heads=kv_heads,
+            v_dim=dv, work=work, pages_per_block=BLOCK, interpret=True)
+        view_k, view_v = (paged_kv.take_pages(buf, table, width).astype(dtype)
+                          for buf, width in bufs)
+        want = swa.decode_attention(q, view_k, view_v,
+                                    jnp.maximum(lengths - 1, 0),
+                                    scale=dq ** -0.5)
+        assert got.shape == want.shape == (b, 1, heads, dv)
+        assert got.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live],
+            np.asarray(want, np.float32)[live], rtol=tol, atol=tol)
+        assert not np.asarray(got, np.float32)[~live].any()
 
 
 def test_the_work_list_holds_live_blocks_only():
