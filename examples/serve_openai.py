@@ -169,23 +169,16 @@ def build_parser() -> argparse.ArgumentParser:
                    help="ngram/prompt-lookup speculative decoding: draft K "
                         "tokens per step, verify in one forward (lossless "
                         "for greedy; vLLM ngram speculator parity). The "
-                        "fused spec round verifies the K drafts AND runs "
-                        "the rest of the --decode-steps block in ONE "
+                        "fused spec round verifies the K drafts in ONE "
                         "dispatch. DEFAULT ON for --role decode replicas "
                         "(K=4) — pass --speculative 0 to disable there")
-    p.add_argument("--decode-steps", dest="decode_steps", type=int,
-                   default=1, metavar="N",
-                   help="decode N tokens per jitted dispatch (vLLM "
-                        "multi-step scheduling parity) — the lever when "
-                        "host dispatch latency rivals the decode step")
     p.add_argument("--no-mixed-step", dest="mixed_step",
                    action="store_false", default=True,
                    help="disable the fused mixed-batch step (default ON: "
                         "while prompts chunk-prefill AND slots decode, one "
-                        "dispatch advances every prefill chunk and runs "
-                        "the full decode block — mixed-load steps cost 1 "
-                        "dispatch instead of 2 and decoders keep their "
-                        "--decode-steps amortization)")
+                        "dispatch advances every prefill chunk and "
+                        "decodes every ready row — mixed-load steps cost "
+                        "1 dispatch instead of 2)")
     p.add_argument("--draft-model-path", dest="draft_model_path",
                    default=None,
                    help="checkpoint of a SMALLER model for draft-model "
@@ -395,7 +388,6 @@ def build_server(args, tok, load_model, error) -> OpenAIServer:
         prefix_cache=args.prefix_caching,
         chunked_prefill=args.chunked_prefill, mesh=mesh,
         speculative_k=args.speculative,
-        decode_steps=args.decode_steps,
         mixed_step=args.mixed_step,
         max_queue=args.max_queue,
         queue_timeout_s=args.queue_timeout,

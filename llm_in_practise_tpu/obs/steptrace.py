@@ -89,7 +89,7 @@ Activity glossary (docs/observability.md "Host timeline"):
 ``queue_drain``    pending-queue scans: timeout sheds + dequeues
 ``admit``          admission bookkeeping — prefix lookup, page reservation,
                    slot setup (inner segments excluded)
-``plan``           decode-block/spec-extension planning + fusibility checks
+``plan``           the step's plan: run-ahead, speculation, fusibility checks
 ``index_build``    host assembly of dispatch inputs (token, index,
                    gather/scatter arrays)
 ``draft_propose``  speculative drafting on the host (ngram scan or

@@ -712,7 +712,6 @@ class OpenAIServer:
             "cache_len": eng.cache_len,
             "kv_layout": "paged" if eng.paged is not None else "dense",
             "speculative_k": getattr(eng, "speculative_k", 0),
-            "decode_steps": getattr(eng, "decode_steps", 1),
             "adapters": sorted(self.adapters),
         })
         reg.counter_func("llm_requests_total",
@@ -1214,7 +1213,7 @@ class OpenAIServer:
         if eng.speculative_k is not None:
             # speculation plane (ISSUE 9): proposed/accepted drafted
             # tokens, fused verify dispatches, the tokens those
-            # dispatches committed (accepted + bonus + extension), and
+            # dispatches committed (accepted + bonus), and
             # a ready-made acceptance-rate gauge — the live "is the
             # spec bet paying" dial next to llm_dispatch_hbm_bw_util
             reg.counter_func("llm_spec_proposed_total",
@@ -1229,7 +1228,7 @@ class OpenAIServer:
             reg.counter_func("llm_spec_round_tokens_total",
                              lambda: eng.spec_round_tokens,
                              "tokens committed by spec dispatches "
-                             "(accepted + bonus + block extension)")
+                             "(accepted + bonus)")
 
             def _acceptance():
                 proposed = eng.spec_proposed     # snapshot: torn reads
@@ -1241,11 +1240,6 @@ class OpenAIServer:
             reg.gauge_func("llm_spec_acceptance_rate", _acceptance,
                            "lifetime accepted/proposed drafted tokens "
                            "(no samples until the first draft)")
-        if getattr(eng, "decode_steps", 1) > 1:
-            # operators tuning --decode-steps need to see whether blocks
-            # actually run (the gate silently falls back to single-step)
-            reg.counter_func("llm_multi_decode_blocks_total",
-                             lambda: eng.multi_blocks)
         # structured output (serve/constrain.py, ISSUE 12): registered
         # unconditionally — zeros until the first constrained request,
         # so dashboards and the metric-docs census see one stable set

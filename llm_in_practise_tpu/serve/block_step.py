@@ -217,10 +217,6 @@ class BlockDecoder:
             no("speculative decoding",
                "a draft verifies next-token guesses; a block pass reveals "
                "positions of a block")
-        if engine.decode_steps != 1:
-            no(f"decode_steps={engine.decode_steps}",
-               "several passes under one lax.scan is future work "
-               "(PERF.md, Open questions)")
         if engine.prefix_cache is not None or engine.kv_pool is not None:
             no("prefix caching / tiered KV",
                "pages are shared per 16 causal positions; block-causal "

@@ -84,8 +84,7 @@ def default_speculative_k(role: str, requested: int | None) -> int | None:
 
     ``--role decode`` replicas default speculation ON
     (:data:`DECODE_DEFAULT_SPEC_K`, the ngram proposer — no extra
-    weights, lossless under greedy, and the fused verify rides the
-    multi-step dispatch so it composes with ``--decode-steps``).
+    weights, lossless under greedy, one dispatch a round).
     An explicit ``--speculative 0`` opts out; any positive value is
     passed through; other roles keep speculation opt-in.
     """

@@ -72,8 +72,6 @@ from llm_in_practise_tpu.serve.mixed_step import (
     make_masked_mixed_step,
     make_mixed_step,
     pin_index,
-    plan_decode_block,
-    plan_spec_extension,
     spec_verify_block,
 )
 from llm_in_practise_tpu.serve.multi_lora import current_lora, lora_context
@@ -389,12 +387,11 @@ class _Flight:
     issue)."""
 
     kind: str                       # "decode" | "mixed" | "chunk" | "block"
-    n: int = 0                      # the decode block's length
-    # rows that decode: (slot, request, tokens of the block it takes,
-    # why it ends with the last of them or None); a block-diffusion
-    # pass's rows carry more (BlockDecoder._advance_row)
+    # rows that decode: (slot, request, why it ends with this token or
+    # None); a block-diffusion pass's rows carry more
+    # (BlockDecoder._advance_row)
     rows: list = dataclasses.field(default_factory=list)
-    # (max_slots, n) sampled tokens; a block pass: its (max_slots, B)
+    # (max_slots, 1) sampled tokens; a block pass: its (max_slots, B)
     # blocks, their revealed flags, the experts the plane chose and, for
     # a reference comparison, its logits
     toks: Any = None
@@ -526,7 +523,6 @@ class InferenceEngine:
         kv_pool=None,
         speculative_k: int | None = None,
         speculative_ngram: int = 3,
-        decode_steps: int = 1,
         prefill_budget: int = 1,
         mixed_step: bool = True,
         max_queue: int | None = None,
@@ -696,7 +692,7 @@ class InferenceEngine:
         self.slot_prefill: dict[int, dict] = {}
         self.slot_last_token = np.zeros((max_slots,), np.int32)
         # One step of lookahead (paged layout;
-        # docs/tutorials/08_serving_internals.md §5c). ``_flight``: the
+        # docs/tutorials/08_serving_internals.md §5b). ``_flight``: the
         # program issued and not yet read. The last tokens live ON THE
         # DEVICE: every paged decode / multi /
         # mixed / chunk program takes the plane ``_tokens_dev`` and
@@ -746,10 +742,9 @@ class InferenceEngine:
         self._top_p = np.ones((max_slots,), np.float32)
         self._greedy = np.zeros((max_slots,), bool)
         # Constrained decoding (serve/constrain.py, ISSUE 12): per-slot
-        # grammar cursor (None = unconstrained). The planner caps the
-        # decode block at 1 while any READY slot is constrained (the
-        # mask encodes one automaton state per slot), the mask is built
-        # on the host as part of the dispatch plan, and the masked twin
+        # grammar cursor (None = unconstrained). The mask (one
+        # automaton state per slot) is built on the host as part of
+        # the dispatch plan, and the masked twin
         # programs apply it in-dispatch — 1 dispatch/step holds with
         # grammar on, on both KV layouts. Engine-thread only.
         self.slot_constraint: list = [None] * max_slots
@@ -867,8 +862,8 @@ class InferenceEngine:
         self.spec_accepted = 0
         # fused spec-round accounting (the BENCH_SPEC_LADDER evidence):
         # rounds = spec-verify dispatches issued; round_tokens = tokens
-        # those dispatches actually committed (accepted + bonus +
-        # extension) — tokens/dispatch on the spec path in two ints
+        # those dispatches actually committed (accepted + bonus) —
+        # tokens/dispatch on the spec path in two ints
         self.spec_rounds = 0
         self.spec_round_tokens = 0
         # Draft-MODEL speculation (vLLM draft-model / Eagle-style
@@ -919,37 +914,13 @@ class InferenceEngine:
             self._draft_sync = np.zeros((max_slots,), np.int64)
             self._draft_uid = np.full((max_slots,), -1, np.int64)
             # catch-up window: biggest normal re-sync is a fully
-            # accepted FUSED round — k+1 verify tokens plus the
-            # decode_steps-1 extension (spec_verify_block) — or a
-            # plain decode_steps block
+            # accepted fused round's k+1 verify tokens
             self._draft_window = max(
-                16, 1 << (speculative_k + decode_steps
-                          - 1).bit_length())
-        # Multi-step decode (vLLM multi-step scheduling parity): run
-        # ``decode_steps`` decode iterations inside ONE jitted call
-        # (a lax.scan), paying host-dispatch overhead once per block.
-        # This is the lever when dispatch latency rivals step time
-        # (weak hosts); on a fast local host 1 is fine. Block length is planned per step by
-        # :func:`llm_in_practise_tpu.serve.mixed_step.plan_decode_block`
-        # (soonest-completion cap under queueing, chunk-window caps while
-        # prompts prefill); a speculative engine rides the SAME plan —
-        # the fused spec round (serve/mixed_step.spec_verify_block)
-        # verifies the k drafts and decodes the block's remaining n-1
-        # steps in one dispatch. Slots that finish mid-block
-        # waste their remaining rows; the freed slot's rows/index are
-        # reset on reuse by the insert path (the same contract the
-        # speculative burst relies on).
-        if decode_steps < 1:
-            raise ValueError(f"decode_steps must be >= 1, got {decode_steps}")
-        self.decode_steps = decode_steps
-        self.multi_blocks = 0
-        self.multi_steps_total = 0  # decode iterations spent inside blocks
+                16, 1 << speculative_k.bit_length())
         # Fused mixed-batch step (r6): while prompts are mid-chunked-
         # prefill AND slots are decoding, ONE jitted program advances
-        # every prefill row a chunk and runs the decode block — mixed-
-        # load steps cost 1 dispatch instead of 2, and decoders keep
-        # their n>1 amortization instead of degrading to single-token
-        # dispatches (the r5 long-context TPOT collapse; see
+        # every prefill row a chunk and decodes every ready row a
+        # token — mixed-load steps cost 1 dispatch instead of 2 (see
         # serve/mixed_step.py and docs/perf.md Finding 17).
         self.mixed_step = bool(mixed_step)
         self.mixed_blocks = 0
@@ -980,7 +951,6 @@ class InferenceEngine:
         # append/iteration are GIL-atomic; HTTP readers snapshot with
         # list() (same contract as the slot_prefill .get reads).
         self.finished: deque = deque(maxlen=128)
-        self._spec_suspended_logged = False
         self._mixed_fallbacks_logged: set[str] = set()
         # Guaranteed chunked-prefill budget: every engine step runs up to
         # this many prefill chunks BEFORE any decode work, so decode load
@@ -1114,12 +1084,8 @@ class InferenceEngine:
         _c = lambda fn: self.dispatch_meter.wrap(  # noqa: E731
             self.compile_meter.wrap(fn))
         self._decode = _c(jax.jit(self._decode_fn, donate_argnums=(1,)))
-        self._decode_multi = _c(jax.jit(self._decode_multi_fn,
-                                        donate_argnums=(1,),
-                                        static_argnames=("n",)))
         self._decode_spec = _c(jax.jit(self._decode_spec_fn,
-                                       donate_argnums=(1,),
-                                       static_argnames=("m",)))
+                                       donate_argnums=(1,)))
         self._prefill = _c(jax.jit(self._prefill_fn))
         self._prefill_suffix = _c(jax.jit(self._prefill_suffix_fn))
         self._sample_first = _c(jax.jit(self._sample_first_fn))
@@ -1138,8 +1104,7 @@ class InferenceEngine:
                                      static_argnames=("bucket",)))
         self._mixed_raw = make_mixed_step(model)
         self._mixed = _c(jax.jit(self._mixed_raw,
-                                 donate_argnums=(1,),
-                                 static_argnames=("n",)))
+                                 donate_argnums=(1,)))
         # Grammar-masked twins (serve/constrain.py): SEPARATE compiled
         # programs with a trailing additive-mask argument, not a flag
         # on the unmasked ones — unconstrained steps keep the exact
@@ -1150,12 +1115,10 @@ class InferenceEngine:
         self._decode_masked = _c(jax.jit(self._decode_masked_fn,
                                          donate_argnums=(1,)))
         self._decode_spec_masked = _c(jax.jit(
-            self._decode_spec_masked_fn, donate_argnums=(1,),
-            static_argnames=("m",)))
+            self._decode_spec_masked_fn, donate_argnums=(1,)))
         self._mixed_masked_raw = make_masked_mixed_step(model)
         self._mixed_masked = _c(jax.jit(self._mixed_masked_raw,
-                                        donate_argnums=(1,),
-                                        static_argnames=("n",)))
+                                        donate_argnums=(1,)))
         if self.paged is not None:
             # Paged twins of the engine programs: same RAW bodies (the
             # math that pins golden parity) between a page gather and a
@@ -1165,25 +1128,18 @@ class InferenceEngine:
             # frees between dispatches.
             self._pg_decode = _c(jax.jit(self._paged_decode_fn,
                                          donate_argnums=(1,)))
-            self._pg_multi = _c(jax.jit(self._paged_multi_fn,
-                                        donate_argnums=(1,),
-                                        static_argnames=("n",)))
             self._pg_spec = _c(jax.jit(self._paged_spec_fn,
-                                       donate_argnums=(1,),
-                                       static_argnames=("m",)))
+                                       donate_argnums=(1,)))
             self._pg_chunk = _c(jax.jit(self._paged_chunk_fn,
                                         donate_argnums=(1,)))
             self._pg_mixed = _c(jax.jit(self._paged_mixed_fn,
-                                        donate_argnums=(1,),
-                                        static_argnames=("n",)))
+                                        donate_argnums=(1,)))
             self._pg_decode_masked = _c(jax.jit(
                 self._paged_decode_masked_fn, donate_argnums=(1,)))
             self._pg_spec_masked = _c(jax.jit(
-                self._paged_spec_masked_fn, donate_argnums=(1,),
-                static_argnames=("m",)))
+                self._paged_spec_masked_fn, donate_argnums=(1,)))
             self._pg_mixed_masked = _c(jax.jit(
-                self._paged_mixed_masked_fn, donate_argnums=(1,),
-                static_argnames=("n",)))
+                self._paged_mixed_masked_fn, donate_argnums=(1,)))
             self._pg_write_rows = _c(jax.jit(self._paged_write_rows_fn,
                                              donate_argnums=(0,)))
             self._pg_gather_rows = _c(jax.jit(self._paged_gather_rows_fn))
@@ -1213,12 +1169,8 @@ class InferenceEngine:
 
             self._decode_lora = _c(jax.jit(
                 lora_wrap(self._decode_fn), donate_argnums=(1,)))
-            self._decode_multi_lora = _c(jax.jit(
-                lora_wrap(self._decode_multi_fn), donate_argnums=(1,),
-                static_argnames=("n",)))
             self._decode_spec_lora = _c(jax.jit(
-                lora_wrap(self._decode_spec_fn), donate_argnums=(1,),
-                static_argnames=("m",)))
+                lora_wrap(self._decode_spec_fn), donate_argnums=(1,)))
             self._prefill_lora = _c(jax.jit(
                 lora_wrap(self._prefill_fn)))
             self._prefill_suffix_lora = _c(jax.jit(
@@ -1228,40 +1180,33 @@ class InferenceEngine:
             self._chunk_batch_lora = _c(jax.jit(
                 lora_wrap(self._chunk_batch_fn), donate_argnums=(1,)))
             self._mixed_lora = _c(jax.jit(
-                lora_wrap(self._mixed_raw), donate_argnums=(1,),
-                static_argnames=("n",)))
+                lora_wrap(self._mixed_raw), donate_argnums=(1,)))
             self._decode_masked_lora = _c(jax.jit(
                 lora_wrap(self._decode_masked_fn), donate_argnums=(1,)))
             self._decode_spec_masked_lora = _c(jax.jit(
                 lora_wrap(self._decode_spec_masked_fn),
-                donate_argnums=(1,), static_argnames=("m",)))
+                donate_argnums=(1,)))
             self._mixed_masked_lora = _c(jax.jit(
-                lora_wrap(self._mixed_masked_raw), donate_argnums=(1,),
-                static_argnames=("n",)))
+                lora_wrap(self._mixed_masked_raw), donate_argnums=(1,)))
             if self.paged is not None:
                 self._pg_decode_lora = _c(jax.jit(
                     lora_wrap(self._paged_decode_fn),
                     donate_argnums=(1,)))
-                self._pg_multi_lora = _c(jax.jit(
-                    lora_wrap(self._paged_multi_fn), donate_argnums=(1,),
-                    static_argnames=("n",)))
                 self._pg_spec_lora = _c(jax.jit(
-                    lora_wrap(self._paged_spec_fn), donate_argnums=(1,),
-                    static_argnames=("m",)))
+                    lora_wrap(self._paged_spec_fn), donate_argnums=(1,)))
                 self._pg_chunk_lora = _c(jax.jit(
                     lora_wrap(self._paged_chunk_fn), donate_argnums=(1,)))
                 self._pg_mixed_lora = _c(jax.jit(
-                    lora_wrap(self._paged_mixed_fn), donate_argnums=(1,),
-                    static_argnames=("n",)))
+                    lora_wrap(self._paged_mixed_fn), donate_argnums=(1,)))
                 self._pg_decode_masked_lora = _c(jax.jit(
                     lora_wrap(self._paged_decode_masked_fn),
                     donate_argnums=(1,)))
                 self._pg_spec_masked_lora = _c(jax.jit(
                     lora_wrap(self._paged_spec_masked_fn),
-                    donate_argnums=(1,), static_argnames=("m",)))
+                    donate_argnums=(1,)))
                 self._pg_mixed_masked_lora = _c(jax.jit(
                     lora_wrap(self._paged_mixed_masked_fn),
-                    donate_argnums=(1,), static_argnames=("n",)))
+                    donate_argnums=(1,)))
         # Block-diffusion decoding (serve/block_step.py): a model with
         # block_length > 1 reveals part of a block a pass instead of
         # committing one token. The engine keeps admission, prefill and
@@ -1364,24 +1309,12 @@ class InferenceEngine:
         )
         return next_tok.astype(jnp.int32), cache
 
-    def _decode_multi_fn(self, params, cache, tokens, rng, temperature,
-                         top_k, top_p, greedy, *, n):
-        """``n`` single-token decodes under one lax.scan — one compiled
-        program, one dispatch. Returns ((B, n) tokens, cache). ``n`` is
-        static (≤ ``decode_steps`` distinct compilations): blocks shrink
-        when a slot is about to finish and requests are waiting. Body
-        shared with the fused mixed step (serve/mixed_step.py)."""
-        return decode_scan(self.model, params, cache, tokens, rng,
-                           temperature, top_k, top_p, greedy, n=n)
-
-    def _decode_spec_fn(self, params, cache, tokens, base, mask, *, m):
+    def _decode_spec_fn(self, params, cache, tokens, base, mask):
         """Fused speculative round (serve/mixed_step.spec_verify_block):
-        verify the (B, K+1) proposed tokens, accept on DEVICE, fix the
-        per-slot index (the work of the old separate ``_rewind``
-        dispatch), and decode the planned block's remaining ``m`` steps
-        — one dispatch per spec round, however long the block."""
+        verify the (B, K+1) proposed tokens, accept on DEVICE and fix
+        the per-slot index — one dispatch per spec round."""
         return spec_verify_block(self.model, params, cache, tokens,
-                                 base, mask, m=m)
+                                 base, mask)
 
     def _decode_masked_fn(self, params, cache, tokens, rng, temperature,
                           top_k, top_p, greedy, gmask):
@@ -1401,15 +1334,13 @@ class InferenceEngine:
         return next_tok.astype(jnp.int32), cache
 
     def _decode_spec_masked_fn(self, params, cache, tokens, base, mask,
-                               gmasks, *, m):
+                               gmasks):
         """Grammar-masked fused spec round: (B, K+1, vocab) staged
         masks — position ``j`` carries the automaton state after the
         first ``j`` drafts, so a grammar-forbidden draft truncates the
-        on-device acceptance cumprod exactly like an argmax mismatch.
-        Constrained rounds run at ``m == 0`` (the extension's tokens
-        have no host-stageable grammar state)."""
+        on-device acceptance cumprod exactly like an argmax mismatch."""
         return spec_verify_block(self.model, params, cache, tokens,
-                                 base, mask, m=m, gmasks=gmasks)
+                                 base, mask, gmasks=gmasks)
 
     def _prefill_fn(self, params, prompt_ids, length):
         """prompt_ids: (B, bucket), length: (B,). Returns per-request
@@ -1847,7 +1778,7 @@ class InferenceEngine:
     # (max_slots,), the plane as the program before it returned it, and
     # ``fix`` (max_slots,), the tokens only the host knew (-1: none),
     # and returns the plane with its own tokens in: a row that decoded
-    # holds the block's last token, a row whose prompt the program ended
+    # holds the token it sampled, a row whose prompt the program ended
     # its first, every other row what it held. The next program can so be
     # issued before anyone has read this one's tokens.
 
@@ -1874,31 +1805,17 @@ class InferenceEngine:
                 self._paged_writeback(pool, view, sidx, index_vec),
                 *self._view_stats(view))
 
-    def _paged_multi_fn(self, params, pool, gidx, index_vec, sidx,
-                        tokens, fix, rng, temperature, top_k, top_p,
-                        greedy, *, n):
-        tokens = self._plane_in(tokens, fix)
-        view = self._paged_view(pool, gidx, index_vec,
-                                **self._live_rows(sidx))
-        toks, view = decode_scan(self.model, params, view, tokens, rng,
-                                 temperature, top_k, top_p, greedy, n=n)
-        return (toks, self._plane_out(tokens, toks[:, -1], sidx),
-                self._paged_writeback(pool, view, sidx, index_vec),
-                *self._view_stats(view))
-
     def _paged_spec_fn(self, params, pool, gidx, index_vec, sidx, tokens,
-                       mask, *, m):
+                       mask):
         view = self._paged_view(pool, gidx, index_vec)
-        # base = the pinned per-dispatch index; the block body's index
-        # fixup matters only within the view (the pool derives each
-        # dispatch's index from host slot_len), but the ACCEPTANCE and
-        # the m-step extension run on device exactly like the
-        # contiguous twin — rejected rows' page contents are either
-        # overwritten by the extension in order or by the next real
-        # write
-        out, n_acc, extra, view = spec_verify_block(
-            self.model, params, view, tokens, index_vec, mask, m=m)
-        return out, n_acc, extra, self._paged_writeback(
+        # base = the pinned per-dispatch index; the body's index fixup
+        # matters only within the view (the pool derives each
+        # dispatch's index from host slot_len), but the ACCEPTANCE runs
+        # on device exactly like the contiguous twin — rejected rows'
+        # page contents are overwritten by the next real write
+        out, n_acc, view = spec_verify_block(
+            self.model, params, view, tokens, index_vec, mask)
+        return out, n_acc, self._paged_writeback(
             pool, view, sidx, index_vec)
 
     def _paged_chunk_fn(self, params, pool, slots, gidx, chunk_ids,
@@ -1999,18 +1916,18 @@ class InferenceEngine:
     def _paged_mixed_fn(self, params, pool, slots, pgidx, chunk_ids,
                         starts, lens, psidx, n_rows, ends, first_rng,
                         gidx, index_vec, sidx, tokens, fix, rng,
-                        temperature, top_k, top_p, greedy, *, n,
+                        temperature, top_k, top_p, greedy, *,
                         gmask=None):
         """The paged fused mixed step, ONE dispatch: the prefill half
         is :meth:`_paged_chunk_fn`'s loop over the rows that chunk and
         its tail (the slot plane's sampling arrays serve both halves;
-        ``first_rng`` is the tail's key, ``rng`` the decode block's);
-        the decode half is :meth:`_paged_multi_fn`'s body over the slot
-        plane (mid-prefill and idle rows decode garbage into the trash
+        ``first_rng`` is the tail's key, ``rng`` the decode's); the
+        decode half is :func:`decode_scan` over the slot plane
+        (mid-prefill and idle rows decode garbage into the trash
         page). No decode row receives a chunk write. Returns ``(first
-        tokens, last-position logits, (max_slots, n) decode tokens, the
+        tokens, last-position logits, (max_slots, 1) decode tokens, the
         last-token plane, pool)``: no row is in both halves, so the
-        plane takes the first tokens and the block's last ones."""
+        plane takes the first tokens and the decoded ones."""
         first, chunk_last, tokens, pool, *acc = self._paged_chunk_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
             n_rows, ends, first_rng, tokens, fix, temperature, top_k,
@@ -2018,7 +1935,7 @@ class InferenceEngine:
         view = self._paged_view(pool, gidx, index_vec,
                                 **self._live_rows(sidx))
         toks, view = decode_scan(self.model, params, view, tokens, rng,
-                                 temperature, top_k, top_p, greedy, n=n,
+                                 temperature, top_k, top_p, greedy,
                                  gmask=gmask)
         return (first, chunk_last, toks,
                 self._plane_out(tokens, toks[:, -1], sidx),
@@ -2041,26 +1958,26 @@ class InferenceEngine:
                 self._paged_writeback(pool, view, sidx, index_vec))
 
     def _paged_spec_masked_fn(self, params, pool, gidx, index_vec, sidx,
-                              tokens, mask, gmasks, *, m):
+                              tokens, mask, gmasks):
         view = self._paged_view(pool, gidx, index_vec)
-        out, n_acc, extra, view = spec_verify_block(
-            self.model, params, view, tokens, index_vec, mask, m=m,
+        out, n_acc, view = spec_verify_block(
+            self.model, params, view, tokens, index_vec, mask,
             gmasks=gmasks)
-        return out, n_acc, extra, self._paged_writeback(
+        return out, n_acc, self._paged_writeback(
             pool, view, sidx, index_vec)
 
     def _paged_mixed_masked_fn(self, params, pool, slots, pgidx,
                                chunk_ids, starts, lens, psidx, n_rows,
                                ends, first_rng, gidx, index_vec, sidx,
                                tokens, fix, rng, temperature, top_k,
-                               top_p, greedy, gmask, *, n):
+                               top_p, greedy, gmask):
         """Grammar-masked twin of :meth:`_paged_mixed_fn` (the mask
         applies to the decode half only): a separate program, so
         unconstrained steps never carry the mask."""
         return self._paged_mixed_fn(
             params, pool, slots, pgidx, chunk_ids, starts, lens, psidx,
             n_rows, ends, first_rng, gidx, index_vec, sidx, tokens, fix,
-            rng, temperature, top_k, top_p, greedy, n=n, gmask=gmask)
+            rng, temperature, top_k, top_p, greedy, gmask=gmask)
 
     def _paged_write_rows_fn(self, pool, rows, sidx):
         """Scatter B bucket-width row sets (one-shot prefill output, a
@@ -2309,54 +2226,50 @@ class InferenceEngine:
             self.steptrace.note_extra(view_pages=idx.size)
         return idx
 
-    def _paged_decode_plan(self, active: list[int], n: int, W: int):
+    def _paged_decode_plan(self, active: list[int], W: int):
         """Index arguments ``(gidx, index_vec, sidx)`` of a slot-plane
-        decode block of ``n`` tokens at view width ``W``: every slot's
-        pages gathered, ``active`` rows' ``n`` new rows scattered back
-        (everything else to the trash page). Forks shared pages the
-        writes would touch. A model that reads its pages in place
-        (``_reads_pages``) gets the whole block table for ``gidx``,
-        whatever ``W`` and the lengths: nothing is gathered (the step's
-        record says ``view_pages`` 0 for this plane)."""
+        decode at view width ``W``: every slot's pages gathered, each
+        ``active`` row's new row scattered back (everything else to
+        the trash page). Forks shared pages the writes would touch. A
+        model that reads its pages in place (``_reads_pages``) gets the
+        whole block table for ``gidx``, whatever ``W`` and the lengths:
+        nothing is gathered (the step's record says ``view_pages`` 0
+        for this plane)."""
         if self._reads_pages:
             W = self.cache_len
-        idxv = self._paged_index_vec(W, n)
+        idxv = self._paged_index_vec(W, 1)
         valid = np.zeros((self.max_slots,), np.int32)
         for s in active:
-            valid[s] = n
-            self._paged_cow_fork(s, int(self.slot_len[s]), n)
+            valid[s] = 1
+            self._paged_cow_fork(s, int(self.slot_len[s]), 1)
         if self.step_stats is not None:
-            self.step_stats.note_decode_view(active, n, W)
+            self.step_stats.note_decode_view(active, W)
         if self._reads_pages:
             self.steptrace.note_extra(view_pages=0)
             gidx = self.paged.block_tables.astype(np.int32)     # a copy
         else:
             gidx = self._paged_view_idx(W)
         return (jnp.asarray(gidx), jnp.asarray(idxv),
-                jnp.asarray(self.paged.scatter_idx(idxv, valid, n)))
+                jnp.asarray(self.paged.scatter_idx(idxv, valid, 1)))
 
-    def _paged_decode_dispatch(self, f: _Flight, active: list[int], n: int,
+    def _paged_decode_dispatch(self, f: _Flight, active: list[int],
                                sub, gmask=None, lora=None) -> None:
-        """Issue one paged decode dispatch (single-token via the
-        ``_decode_fn`` body at n==1 so the rng use matches the
-        contiguous program exactly; an n-step scan block otherwise).
-        Pages for the writes were reserved by the caller. ``gmask``
-        (constrained decoding) routes to the masked twin — the planner
-        guarantees n == 1 then. ``lora`` (multi-LoRA) routes to the
-        adapter twin of whichever program would run; both compose.
-        The sampled tokens, shape (max_slots, n), are ``f.toks``."""
+        """Issue one paged decode dispatch (the ``_decode_fn`` body, so
+        the rng use matches the contiguous program exactly). Pages for
+        the writes were reserved by the caller. ``gmask`` (constrained
+        decoding) routes to the masked twin. ``lora`` (multi-LoRA)
+        routes to the adapter twin of whichever program would run;
+        both compose. The sampled tokens, shape (max_slots, 1), are
+        ``f.toks``."""
         W = self.cache_len
         if not self._reads_pages:       # else: no view, and no width
             W = self._paged_width(
-                max(int(self.slot_len[s]) for s in active) + n)
+                max(int(self.slot_len[s]) for s in active) + 1)
             self._pulse_view(W)
-        gidx, idxv, sidx = self._paged_decode_plan(active, n, W)
+        gidx, idxv, sidx = self._paged_decode_plan(active, W)
         args = (*self._plane_args(), sub, *self._sampling_args(active))
         kw = {} if lora is None else {"lora": lora}
         if gmask is not None:
-            if n != 1:
-                raise AssertionError(
-                    f"grammar-masked paged decode must be n=1, got {n}")
             fn = (self._pg_decode_masked if lora is None
                   else self._pg_decode_masked_lora)
             tok, self._tokens_dev, self.paged.kv = fn(
@@ -2364,16 +2277,10 @@ class InferenceEngine:
                 jnp.asarray(gmask), **kw)
             f.toks = tok[:, None]
             return
-        if n == 1:
-            fn = self._pg_decode if lora is None else self._pg_decode_lora
-            tok, self._tokens_dev, self.paged.kv, *counted = fn(
-                self.params, self.paged.kv, gidx, idxv, sidx, *args, **kw)
-            f.toks = tok[:, None]
-        else:
-            fn = self._pg_multi if lora is None else self._pg_multi_lora
-            f.toks, self._tokens_dev, self.paged.kv, *counted = fn(
-                self.params, self.paged.kv, gidx, idxv, sidx, *args, n=n,
-                **kw)
+        fn = self._pg_decode if lora is None else self._pg_decode_lora
+        tok, self._tokens_dev, self.paged.kv, *counted = fn(
+            self.params, self.paged.kv, gidx, idxv, sidx, *args, **kw)
+        f.toks = tok[:, None]
         if self.step_stats is not None:
             f.stats = self.step_stats.pend("decode", counted)
 
@@ -4299,44 +4206,22 @@ class InferenceEngine:
                 and all(st["done"] + k + 1 <= self.cache_len
                         for st in self.slot_prefill.values()))
 
-    def _spec_headroom(self, active: list[int]) -> int:
-        """Cache rows available for the spec extension ABOVE the k+1
-        verify rows — min over decoding and mid-prefill rows (their
-        dead write windows widen with the extension too)."""
-        k = self.speculative_k
-        lens = [int(self.slot_len[s]) for s in active]
-        lens += [st["done"] for st in self.slot_prefill.values()]
-        return self.cache_len - (k + 1) - (max(lens) if lens else 0)
-
     def _try_speculative(self, active: list[int]) -> bool:
-        """One FUSED speculative round (the ROADMAP item 4 tentpole):
-        draft k tokens per slot (ngram or draft model), then verify +
-        accept + decode the planned block's remaining steps inside ONE
-        jitted dispatch (serve/mixed_step.spec_verify_block) — the old
-        path paid a second ``_rewind`` dispatch on the contiguous
-        layout and capped every round at ``decode_steps=1`` economics.
-        Returns False when the spec path doesn't apply this step
-        (caller falls back to plain decode)."""
+        """One FUSED speculative round: draft k tokens per slot (ngram
+        or draft model), then verify + accept inside ONE jitted
+        dispatch (serve/mixed_step.spec_verify_block). Returns False
+        when the spec path doesn't apply this step (caller falls back
+        to plain decode)."""
         k = self.speculative_k
         with self.steptrace.scope("plan"):
             applicable = self._spec_applicable(active)
-            if applicable:
-                # the extension m rides the SAME token-budget plan as a
-                # plain block (soonest-finish cap under queueing, chunk
-                # caps while prefilling): one fused dispatch spans
-                # verify + m greedy steps, so acceptance-count is part
-                # of the dispatch plan and the compile set stays
-                # pow2-bounded
-                m = plan_spec_extension(
-                    block=self._plan_block(active), k=k,
-                    headroom=self._spec_headroom(active))
         if not applicable:
             return False
         # draft BEFORE touching the page pool: drafting needs no pool
         # pages (ngram is host-side; the draft model's cache is its own
         # contiguous buffer), so a draft-miss step returns to the plain
         # path without having preempted or cache-finished anybody for a
-        # k+1+m reservation that would never be used
+        # k+1 reservation that would never be used
         with self.steptrace.scope("draft_propose"):
             if self.draft_model is not None:
                 drafts = self._draft_model_propose(active, k)
@@ -4349,12 +4234,12 @@ class InferenceEngine:
         if not drafts:
             return False                      # nothing to verify; plain step
         if self.paged is not None:
-            # the fused round writes k+1+m rows per slot: reserve the
+            # the fused round writes k+1 rows per slot: reserve the
             # pages up front (preempting youngest slots if dry) — the
             # speculative watermark of any preempted slot is reset in
             # _paged_preempt, so a recycled draft cache re-syncs
             with self.steptrace.scope("admit"):
-                active = self._paged_reserve_active(active, k + 1 + m)
+                active = self._paged_reserve_active(active, k + 1)
             if not active:
                 return True
             drafts = {s: d for s, d in drafts.items() if s in active}
@@ -4369,8 +4254,6 @@ class InferenceEngine:
         # by tentatively advancing each constrained slot's automaton
         # over its drafts — the on-device acceptance cumprod then
         # rejects grammar-forbidden drafts like argmax mismatches.
-        # (_plan_block capped the block at 1 for constrained actives,
-        # so m == 0 here whenever gmasks is not None.)
         with self.steptrace.scope("index_build"):
             gmasks = self._grammar_spec_masks(active, tokens, k, drafts)
             # multi-LoRA: the verify IS the target forward, so the
@@ -4382,82 +4265,76 @@ class InferenceEngine:
             self.steptrace.window_begin("decode")
             if self.paged is not None:
                 W = self._paged_width(
-                    max(int(self.slot_len[s]) for s in active)
-                    + k + 1 + m)
+                    max(int(self.slot_len[s]) for s in active) + k + 1)
                 self._pulse_view(W)
-                idxv = self._paged_index_vec(W, k + 1 + m)
+                idxv = self._paged_index_vec(W, k + 1)
                 valid = np.zeros((self.max_slots,), np.int32)
                 for s in active:
-                    valid[s] = k + 1 + m
-                    self._paged_cow_fork(s, int(self.slot_len[s]),
-                                         k + 1 + m)
+                    valid[s] = k + 1
+                    self._paged_cow_fork(s, int(self.slot_len[s]), k + 1)
                 if gmasks is not None:
                     fn = (self._pg_spec_masked if lora is None
                           else self._pg_spec_masked_lora)
-                    out, n_acc, extra, self.paged.kv = fn(
+                    out, n_acc, self.paged.kv = fn(
                         self.params, self.paged.kv,
                         jnp.asarray(self._paged_view_idx(W)),
                         jnp.asarray(idxv),
                         jnp.asarray(self.paged.scatter_idx(
-                            idxv, valid, k + 1 + m)),
+                            idxv, valid, k + 1)),
                         jnp.asarray(tokens), jnp.asarray(mask),
-                        jnp.asarray(gmasks), m=m, **kw)
+                        jnp.asarray(gmasks), **kw)
                 else:
                     fn = (self._pg_spec if lora is None
                           else self._pg_spec_lora)
-                    out, n_acc, extra, self.paged.kv = fn(
+                    out, n_acc, self.paged.kv = fn(
                         self.params, self.paged.kv,
                         jnp.asarray(self._paged_view_idx(W)),
                         jnp.asarray(idxv),
                         jnp.asarray(self.paged.scatter_idx(idxv, valid,
-                                                           k + 1 + m)),
-                        jnp.asarray(tokens), jnp.asarray(mask), m=m,
-                        **kw)
+                                                           k + 1)),
+                        jnp.asarray(tokens), jnp.asarray(mask), **kw)
             elif gmasks is not None:
                 fn = (self._decode_spec_masked if lora is None
                       else self._decode_spec_masked_lora)
-                base = self._paged_index_vec(self.cache_len, k + 1 + m)
-                out, n_acc, extra, self.cache = fn(
+                base = self._paged_index_vec(self.cache_len, k + 1)
+                out, n_acc, self.cache = fn(
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(base), jnp.asarray(mask),
-                    jnp.asarray(gmasks), m=m, **kw)
+                    jnp.asarray(gmasks), **kw)
             else:
                 # per-row pinned index: the slot-state → index
                 # convention lives in ONE place (_paged_index_vec reads
                 # only host slot state — nothing paged about it); here
                 # the "view" is the whole contiguous cache, so
-                # W = cache_len. Free rows' dead k+1+m write window is
+                # W = cache_len. Free rows' dead k+1 write window is
                 # clamped inside the cache; live rows already fit
-                # (_spec_applicable + the headroom cap on m), so their
-                # clamp is a no-op.
-                base = self._paged_index_vec(self.cache_len, k + 1 + m)
+                # (_spec_applicable), so their clamp is a no-op.
+                base = self._paged_index_vec(self.cache_len, k + 1)
                 fn = (self._decode_spec if lora is None
                       else self._decode_spec_lora)
-                out, n_acc, extra, self.cache = fn(
+                out, n_acc, self.cache = fn(
                     self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(base), jnp.asarray(mask), m=m, **kw)
+                    jnp.asarray(base), jnp.asarray(mask), **kw)
             self.steptrace.window_issued()
             with self.steptrace.fetch():
                 out_host = np.asarray(out)
                 acc_host = np.asarray(n_acc)
-                extra_host = np.asarray(extra)
             dt, _ = self._window_close(
                 "decode", [self.slot_req[s] for s in active])
             # the verify is ONE wide forward over k+1 positions per slot
-            # plus m single-token extension passes (that width
-            # amortizing the weight read is the whole spec bet — the
-            # decode MFU gauge shows it paying off or not). Useful
-            # positions only: an undrafted/short-draft slot's zero
+            # (that width amortizing the weight read is the whole spec
+            # bet — the decode MFU gauge shows it paying off or not).
+            # Useful positions only: an undrafted/short-draft slot's zero
             # padding is wasted work and must read as lost MFU, same
             # convention as the spec_proposed/spec_accepted counters
             # below.
-            useful = {s: len(drafts.get(s, ())) + 1 + m for s in active}
+            useful = {s: len(drafts.get(s, ())) + 1 for s in active}
             keys = sum(CostModel.block_keys(useful[s],
                                             int(self.slot_len[s]))
                        for s in active)
             self._note_device_phase(
                 "decode", tokens=sum(useful.values()),
-                attended_keys=keys, weight_passes=1 + m,
+                attended_keys=keys, weight_passes=1,
                 kv_read_tokens=keys, dt=dt)
         self.spec_rounds += 1
         with self.steptrace.scope("sample_commit"):
@@ -4469,12 +4346,10 @@ class InferenceEngine:
                 n_drafted = len(drafts.get(s, ()))
                 self.spec_proposed += n_drafted
                 self.spec_accepted += min(n_acc_s, n_drafted)
-                burst = [int(out_host[s, j]) for j in range(n_acc_s + 1)]
-                burst += [int(extra_host[s, j]) for j in range(m)]
-                for tok in burst:
+                for j in range(n_acc_s + 1):
                     if self.slot_req[s] is None:
                         break                 # finished mid-burst (eos/len)
-                    self._commit_token(s, tok)
+                    self._commit_token(s, int(out_host[s, j]))
                     self.spec_round_tokens += 1
         return True
 
@@ -4483,24 +4358,20 @@ class InferenceEngine:
         (the paths that read a program before they issue the next: the
         speculative round, the contiguous layout). The token is the
         host's: the next paged program puts it into the plane."""
-        _, why = self._advance_row(slot, 1)
+        why = self._advance_row(slot)
         self._tokens_fix[slot] = tok
         self._commit_value(slot, tok, why)
 
-    def _advance_row(self, slot: int, n: int) -> tuple:
-        """The half of a commit that needs no token VALUE, for a block
-        of ``n``: budget and length move by the tokens the row takes of
-        it (it stops where its budget or its cache room ends; that is
-        then why it closes, and no later program takes the row). Done
-        when the block is issued. Returns ``(tokens taken, why the row
-        ends with the last of them, or None)``."""
-        for taken in range(1, n + 1):
-            self.slot_budget[slot] -= 1
-            self.slot_len[slot] += 1
-            why = self.slot_closing[slot] = self._closing_reason(slot)
-            if why is not None:
-                break
-        return taken, why
+    def _advance_row(self, slot: int) -> str | None:
+        """The half of a commit that needs no token VALUE: budget and
+        length move by the token the row takes (where its budget or its
+        cache room ends with it, that is why it closes, and no later
+        program takes the row). Done when the program is issued.
+        Returns why the row ends with this token, or None."""
+        self.slot_budget[slot] -= 1
+        self.slot_len[slot] += 1
+        why = self.slot_closing[slot] = self._closing_reason(slot)
+        return why
 
     def _commit_value(self, slot: int, tok: int, why: str | None) -> None:
         """The half of a commit that needs the token: last-token mirror,
@@ -4637,76 +4508,27 @@ class InferenceEngine:
         if cs.advance(tok) and self.slot_req[slot] is not None:
             self._finish_slot(slot, "stop")
 
-    def _plan_block(self, active: list[int]) -> int:
-        """Token-budget plan for this step's decode block length: the
-        soonest-completion cap under queueing plus (while prompts are
-        mid-prefill) the chunk-window caps — policy in
-        :func:`llm_in_practise_tpu.serve.mixed_step.plan_decode_block`.
-
-        Constrained decoding caps the block at 1 whenever a READY slot
-        carries a grammar: the per-slot mask encodes exactly one
-        automaton state, and tokens 2..n of a block would sample
-        unmasked (the fused spec round is the multi-token path for
-        constrained slots — drafts are host-known, so k+1 states can be
-        staged). This also drives ``plan_spec_extension`` to m=0."""
-        if self._constrained_active(active):
-            return 1
-        soonest = None
-        if active and self.pending.qsize() > 0:
-            # Requests are waiting on a slot: cap the block at the
-            # soonest *deterministic* completion among active slots
-            # (token budget or cache room, whichever bites first), so
-            # the freed slot refills at the very next step instead of
-            # idling out the tail of a fixed-length block. This is the
-            # TTFT half of multi-step scheduling: full blocks when
-            # nobody waits, shortest-useful blocks under queueing.
-            soonest = int(min(
-                min(self.slot_budget[s],
-                    self.cache_len - 1 - self.slot_len[s])
-                for s in active
-            ))
-        chunk = headroom = None
-        if self.slot_prefill:
-            chunk = self.chunked_prefill
-            headroom = min(
-                self.cache_len - chunk - st["done"]
-                for st in self.slot_prefill.values())
-        return plan_decode_block(
-            decode_steps=self.decode_steps,
-            queue_depth=self.pending.qsize(),
-            soonest_finish=soonest,
-            chunk=chunk,
-            prefill_headroom=headroom,
-        )
-
-    def _mixed_feasible(self, active: list[int], n: int) -> tuple[bool, str]:
+    def _mixed_feasible(self, active: list[int]) -> tuple[bool, str]:
         """Can this step run as ONE fused dispatch? The bounds are the
         scatter-clamp invariants documented in serve/mixed_step.py; a
         miss falls back to the sequential two-dispatch path (rare tail:
         rows butting against the cache end)."""
         C = self.chunked_prefill
-        if n > C:
-            # the scan's garbage rows above each prefill watermark must
-            # be covered by the next chunk's padded write; the planner
-            # already caps n <= chunk, this keeps the invariant local
-            return False, (
-                f"block length exceeds the chunk window: n {n} > "
-                f"chunk {C}")
         for slot, st in self.slot_prefill.items():
-            if st["done"] + C + n > self.cache_len:
+            if st["done"] + C + 1 > self.cache_len:
                 return False, (
                     "prefill row near the cache end: "
                     f"slot {slot} done {st['done']} + chunk {C} + "
-                    f"block {n} > cache_len {self.cache_len}")
+                    f"1 > cache_len {self.cache_len}")
         if self.paged is not None:
-            # no decode row receives a chunk write in this layout; the
-            # block's own n rows must fit
+            # no decode row receives a chunk write in this layout; its
+            # own new row must fit
             for s in active:
-                if int(self.slot_len[s]) + n > self.cache_len:
+                if int(self.slot_len[s]) + 1 > self.cache_len:
                     return False, (
-                        "decode row lacks the block's write window: "
-                        f"slot {s} len {int(self.slot_len[s])} + block "
-                        f"{n} > cache_len {self.cache_len}")
+                        "decode row lacks its write window: "
+                        f"slot {s} len {int(self.slot_len[s])} + 1 "
+                        f"> cache_len {self.cache_len}")
             return True, ""
         for s in range(self.max_slots):
             # contiguous layout: every occupied non-prefill row receives
@@ -4722,13 +4544,13 @@ class InferenceEngine:
                     f"> cache_len {self.cache_len}")
         return True, ""
 
-    def _mixed_dispatch(self, active: list[int], n: int):
+    def _mixed_dispatch(self, active: list[int]):
         """Issue the fused mixed-batch program: the step's mid-prefill
         rows (:meth:`_chunk_entries`) advance one chunk AND every ready
-        row decodes an ``n``-block,
-        in ONE device dispatch (serve/mixed_step.py). Host bookkeeping
-        mirrors the sequential paths exactly: chunk results feed
-        ``slot_prefill``/finalization, block tokens commit per slot.
+        row decodes a token, in ONE device dispatch
+        (serve/mixed_step.py). Host bookkeeping mirrors the sequential
+        paths exactly: chunk results feed ``slot_prefill``/finalization,
+        decode tokens commit per slot.
         The paged program is issued here and read in :meth:`_retire`
         (a step later where nothing forbids it: :meth:`_fly`).
         Returns False (nothing dispatched) only when paged page
@@ -4738,15 +4560,15 @@ class InferenceEngine:
         program is unread (it has been read now: plan again)."""
         C = self.chunked_prefill
         if self.paged is not None:
-            # reserve the decode half's writes: n rows per ready slot
+            # reserve the decode half's writes: one row per ready slot
             # (may preempt youngest). The prefill half needs nothing —
             # admission reserved every prompt page up front, and the
-            # scan's garbage rows above each prefill watermark scatter
-            # to the trash page.
+            # decode's garbage row above each prefill watermark
+            # scatters to the trash page.
             with self.steptrace.scope("admit"):
-                if not self._reserve_ahead(active, n):
+                if not self._reserve_ahead(active, 1):
                     return _REPLAN
-                active = self._paged_reserve_active(active, n)
+                active = self._paged_reserve_active(active, 1)
             if not active or not self.slot_prefill:
                 return False
         with self.steptrace.scope("index_build"):
@@ -4754,12 +4576,12 @@ class InferenceEngine:
             if self.paged is None:
                 tok, starts, lens = self._chunk_batch_rows(entries)
                 advance = np.zeros((self.max_slots,), np.int32)
-                advance[active] = n
+                advance[active] = 1
             # constrained decoding: the decode half of the fused step masks
-            # each grammar slot's logits (n == 1 then, by _plan_block);
-            # mid-prefill rows need nothing — a constrained row's first
-            # token samples at finalization, where the host applies the
-            # start-state mask (_first_from_program)
+            # each grammar slot's logits; mid-prefill rows need nothing —
+            # a constrained row's first token samples at finalization,
+            # where the host applies the start-state mask
+            # (_first_from_program)
             gmask = self._grammar_masks(active)
             # multi-LoRA: slot-plane adapter rows cover BOTH halves of the
             # fused program (the paged prefill half picks its rows' out
@@ -4775,14 +4597,13 @@ class InferenceEngine:
                 "pf_tokens": sum(len(c) for _, _, c in entries),
                 "pf_keys": sum(CostModel.chunk_keys(len(c), st["done"])
                                for _, st, c in entries),
-                "dc_tokens": n * len(active),
+                "dc_tokens": len(active),
                 "dc_keys": sum(
-                    CostModel.block_keys(n, int(self.slot_len[s]))
+                    CostModel.block_keys(1, int(self.slot_len[s]))
                     for s in active)}
         self.mixed_blocks += 1
         if self.paged is not None:
-            self._fly(self._issue_mixed(active, n, entries, gmask, lora,
-                                        book))
+            self._fly(self._issue_mixed(active, entries, gmask, lora, book))
             return True
         # one scope spans through the two note_device_phase calls below
         # (their dt shares must land inside it so the device deduction
@@ -4804,7 +4625,7 @@ class InferenceEngine:
             chunk_last, toks, self.cache = fn(
                 self.params, self.cache, jnp.asarray(tok),
                 jnp.asarray(starts), jnp.asarray(lens),
-                jnp.asarray(advance), *sampling, n=n, **kw)
+                jnp.asarray(advance), *sampling, **kw)
             self.steptrace.window_issued()
             # ONE fetch forces the dispatch's results
             with self.steptrace.fetch():
@@ -4817,13 +4638,15 @@ class InferenceEngine:
             self._chunks_done(entries, chunk_last)
             self._trace_chunks(entries, dt, issue_s, batched=True,
                                fused=True)
-            self._note_mixed_phases(book, n, dt, passes=1)
+            self._note_mixed_phases(book, dt, passes=1)
         with self.steptrace.scope("sample_commit"):
             self._finalize_prefills()
-            self._commit_block(active, toks_host, n)
+            for slot in active:
+                if self.slot_req[slot] is not None:
+                    self._commit_token(slot, int(toks_host[slot, 0]))
         return True
 
-    def _note_mixed_phases(self, book: dict, n: int, dt: float, *,
+    def _note_mixed_phases(self, book: dict, dt: float, *,
                            passes: int) -> None:
         """Book a fused dispatch's window ``dt`` to the two phases in
         proportion to each half's FLOPs (``book``: the halves' tokens
@@ -4842,20 +4665,20 @@ class InferenceEngine:
             kv_read_tokens=book["pf_keys"], dt=dt * share)
         self._note_device_phase(
             "decode", tokens=book["dc_tokens"],
-            attended_keys=book["dc_keys"], weight_passes=n,
+            attended_keys=book["dc_keys"], weight_passes=1,
             kv_read_tokens=book["dc_keys"], dt=dt * (1 - share))
 
-    def _issue_mixed(self, active: list[int], n: int, entries, gmask, lora,
+    def _issue_mixed(self, active: list[int], entries, gmask, lora,
                      book: dict) -> _Flight:
         """Issue the PAGED fused mixed program (pages reserved, rows
         planned by :meth:`_mixed_dispatch`): the rows that decode take
         the last-token plane, the rows that chunk are the host's; the
-        plane comes back with the block's tokens and the finished
+        plane comes back with the decoded tokens and the finished
         prompts' first tokens in it."""
         C = self.chunked_prefill
         kw = {} if lora is None else {"lora": lora}
         with self.steptrace.scope("dispatch_wait"):
-            f = self._window_open("mixed", _Flight("mixed", n=n, book=book))
+            f = self._window_open("mixed", _Flight("mixed", book=book))
             self.rng, sub = jax.random.split(self.rng)
             # the program samples the first token of the rows whose
             # prompt it ends: they choose the sampler's body with the
@@ -4866,8 +4689,8 @@ class InferenceEngine:
                         *self._sampling_args(active + sampled))
             if gmask is not None:
                 sampling += (jnp.asarray(gmask),)
-            # ONE view width for both halves: each prefill row's
-            # chunk + the block (done+C+n), and each occupied decode
+            # ONE view width for both halves: each prefill row's chunk
+            # + the decode's row (done+C+1), and each occupied decode
             # row's len+C, capped at cache_len — no decode row
             # receives a chunk write any more, but the warm-up
             # builds the widths THIS rule gives (narrower decode
@@ -4876,21 +4699,21 @@ class InferenceEngine:
                 # no view of the decode plane: the width is the chunk
                 # rows' alone, each gathered for its own trip, and no
                 # narrower than the SHORTEST row that decodes beside
-                # them (its length after this block) would make the
+                # them (its length after this step) would make the
                 # rule below: a prompt's first chunks then build no
                 # mixed program that a gathered engine would not
                 # (PERF.md, PR 49: two of five in the agents' cell, 23 s
                 # of a 150 s set-up)
-                floor = min(int(self.slot_len[s]) for s in active) + n + C
+                floor = min(int(self.slot_len[s]) for s in active) + 1 + C
                 W = self._paged_width(max(
                     max(st["done"] for _, st, _ in entries) + C,
                     min(floor, self.cache_len)))
                 self._pulse_view(W, 1)
             else:
                 need = max(
-                    [st["done"] + C + n for _, st, _ in entries]
+                    [st["done"] + C + 1 for _, st, _ in entries]
                     + [min(int(self.slot_len[s]) + C, self.cache_len)
-                       for s in self._ready_slots()] + [C + n])
+                       for s in self._ready_slots()] + [C + 1])
                 W = self._paged_width(need)
                 self._pulse_view(W)
             if gmask is not None:
@@ -4902,30 +4725,16 @@ class InferenceEngine:
             # statements of their own: either may fork a shared
             # page, which REBINDS the (donated) pool read below
             rows = self._paged_entry_rows(entries, W)
-            plan = self._paged_decode_plan(active, n, W)
+            plan = self._paged_decode_plan(active, W)
             (f.first, chunk_last, f.toks, self._tokens_dev, self.paged.kv,
              *counted) = fn(self.params, self.paged.kv, *rows, *tail,
-                            *plan, *sampling, n=n, **kw)
+                            *plan, *sampling, **kw)
             self._prompts_issued(f, "mixed", entries, finishing,
                                  chunk_last, counted)
-            f.rows = [(s, self.slot_req[s], *self._advance_row(s, n))
+            f.rows = [(s, self.slot_req[s], self._advance_row(s))
                       for s in active if self.slot_req[s] is not None]
             self.steptrace.window_issued()
         return f
-
-    def _commit_block(self, active: list[int], toks_host, n: int) -> None:
-        """Book an ``n``-step decode block's tokens ((B, n) host array)
-        into every active slot — shared by the fused mixed step and the
-        sequential multi-step path, so the two dispatch modes commit
-        (and stop at mid-block finishes) identically."""
-        if n > 1:
-            self.multi_blocks += 1
-            self.multi_steps_total += n
-        for slot in active:
-            for j in range(n):
-                if self.slot_req[slot] is None:
-                    break                 # finished mid-block (eos/len)
-                self._commit_token(slot, int(toks_host[slot, j]))
 
     # --- issue and retire (one step of lookahead) ----------------------------
     #
@@ -5031,8 +4840,7 @@ class InferenceEngine:
                 self._trace_chunks(f.entries, dt, issue_s, batched=True,
                                    fused=True)
                 # the paged loop streams the weights once a row
-                self._note_mixed_phases(book, f.n, dt,
-                                        passes=len(f.entries))
+                self._note_mixed_phases(book, dt, passes=len(f.entries))
             elif f.kind == "chunk":
                 self._trace_chunks(f.entries, dt, issue_s, batched=True)
                 self._note_device_phase(
@@ -5043,7 +4851,7 @@ class InferenceEngine:
             else:
                 self._note_device_phase(
                     "decode", tokens=book["dc_tokens"],
-                    attended_keys=book["dc_keys"], weight_passes=f.n,
+                    attended_keys=book["dc_keys"], weight_passes=1,
                     kv_read_tokens=book["dc_keys"], dt=dt)
         with self.steptrace.scope("sample_commit"):
             for slot, req, st, why in f.finished:
@@ -5056,47 +4864,39 @@ class InferenceEngine:
                                       on_device=True)
             if f.host_first:
                 self._finalize_prefills()
-            if f.n > 1:
-                self.multi_blocks += 1
-                self.multi_steps_total += f.n
-            for slot, req, taken, why in f.rows:
+            for slot, req, why in f.rows:
                 if slot in self._zombies:
                     # its stream ended in EOS when the program before
-                    # this one was read: the tokens go nowhere, and the
+                    # this one was read: the token goes nowhere, and the
                     # slot and its pages are free from here on
-                    self.steptrace.note_discarded(taken)
+                    self.steptrace.note_discarded(1)
                     self._release_pages(slot, req)
                     self._clear_slot(slot)
                     continue
-                for j in range(taken):
-                    self._commit_value(slot, int(toks[slot, j]),
-                                       why if j == taken - 1 else None)
-                    if not self.slot_ready[slot]:
-                        break             # finished mid-block (eos)
+                self._commit_value(slot, int(toks[slot, 0]), why)
             if stats is not None:
                 stats.book(f.stats, parts)
         self._update_active_stats()
 
-    def _decode_paged(self, active: list[int], n: int, sub):
+    def _decode_paged(self, active: list[int], sub):
         """Issue the paged decode program for the ready rows ``active``
-        (pages reserved by the caller): a single token through the
-        ``_decode_fn`` body, an ``n``-block through the scan. The rows
-        take their deterministic step here; their tokens are read in
+        (pages reserved by the caller). The rows take their
+        deterministic step here; their tokens are read in
         :meth:`_retire`."""
         # constrained decoding: per-slot grammar mask rows, applied by
         # the masked twin program in the SAME single dispatch
         with self.steptrace.scope("index_build"):
-            gmask = self._grammar_masks(active) if n == 1 else None
+            gmask = self._grammar_masks(active)
             lora = self._lora_args()
         with self.steptrace.scope("dispatch_wait"):
-            f = self._window_open("decode", _Flight("decode", n=n, book={
-                "dc_tokens": n * len(active),
+            f = self._window_open("decode", _Flight("decode", book={
+                "dc_tokens": len(active),
                 "dc_keys": sum(
-                    CostModel.block_keys(n, int(self.slot_len[s]))
+                    CostModel.block_keys(1, int(self.slot_len[s]))
                     for s in active)}))
-            self._paged_decode_dispatch(f, active, n, sub, gmask=gmask,
+            self._paged_decode_dispatch(f, active, sub, gmask=gmask,
                                         lora=lora)
-            f.rows = [(s, self.slot_req[s], *self._advance_row(s, n))
+            f.rows = [(s, self.slot_req[s], self._advance_row(s))
                       for s in active if self.slot_req[s] is not None]
             self.steptrace.window_issued()
         self._fly(f)
@@ -5209,27 +5009,17 @@ class InferenceEngine:
             if not active:
                 return progressed or bool(self.slot_prefill)
             return self._block_pass(active)
-        # A speculative engine at decode_steps=1 keeps speculating
-        # while prompts prefill (the r5 composition): its verify step
-        # yields 1+accepted tokens per dispatch, strictly more than the
-        # fused step's single token at n=1 — suspending it there would
-        # REGRESS mixed-load TPOT on accepting workloads. On a
-        # ``--role decode`` replica speculation NEVER suspends (ISSUE 9
-        # / ROADMAP item 4): prefill on such a replica is the rare
-        # degraded local-re-prefill path, and the fused spec round
-        # (verify + the block's remaining steps in one dispatch) beats
-        # the plain fused block at every decode_steps. Mixed
-        # (``--role both``) replicas with decode_steps>1 keep the
-        # documented suspend-during-prefill behavior: there the fused
-        # mixed step's chunk+block amortization wins. Composition only
-        # applies when speculation actually CAN run this step —
-        # non-greedy traffic on a spec engine must not lose the fused
-        # step too.
+        # A speculative engine keeps speculating while prompts prefill:
+        # its verify step yields 1+accepted tokens per dispatch,
+        # strictly more than the fused step's single token —
+        # suspending it there would REGRESS mixed-load TPOT on
+        # accepting workloads. Composition only applies when
+        # speculation actually CAN run this step — non-greedy traffic
+        # on a spec engine must not lose the fused step too.
         with self.steptrace.scope("plan"):
             active = self._ready_slots()
             spec_composes = (
-                (self.decode_steps == 1 or self.role == "decode")
-                and self._spec_applicable(active)
+                self._spec_applicable(active)
                 # the verify runs AFTER this step's chunks advance each
                 # prefill row (by up to budget chunks) — account for
                 # that movement here, or near the cache tail the
@@ -5244,10 +5034,8 @@ class InferenceEngine:
         pre_progress = False
         if (self.mixed_step and self.slot_prefill and active
                 and not spec_composes):
-            # Fused mixed-batch step: prefill chunks + the decode block
-            # in ONE dispatch, so decoders keep their n>1 amortization
-            # while prompts prefill (r5: forcing n=1 here collapsed
-            # conc-4 long-context TPOT p99 from ~67 ms to 315 ms).
+            # Fused mixed-batch step: prefill chunks + the decode in
+            # ONE dispatch.
             if budget > 1:
                 # the fused program carries ONE chunk per dispatch;
                 # spend the rest of the guaranteed prefill budget
@@ -5255,7 +5043,7 @@ class InferenceEngine:
                 # (ceil(chunks/budget) steps) still holds — and
                 # re-snapshot the ready set, since a prompt finishing
                 # its last chunk here activates and must join this
-                # step's decode block (sequential-path parity)
+                # step's decode (sequential-path parity)
                 self._drain("two_dispatch")
                 pre_progress = self._advance_prefills(budget - 1)
                 budget = 1
@@ -5263,28 +5051,9 @@ class InferenceEngine:
                     active = self._ready_slots()
             if self.slot_prefill and active:
                 with self.steptrace.scope("plan"):
-                    n = self._plan_block(active)
-                    ok, why = self._mixed_feasible(active, n)
+                    ok, why = self._mixed_feasible(active)
                 if ok:
-                    # the decode-replica suspension gate is GONE
-                    # (ISSUE 9 satellite): on role="decode" the branch
-                    # above composes speculation whenever it can run at
-                    # all, so reaching here means spec was inapplicable
-                    # (non-greedy traffic / cache tail) — logging
-                    # "suspended" would be noise. Only mixed replicas
-                    # still suspend by policy, and only they log it.
-                    if (self.speculative_k is not None
-                            and self.role != "decode"
-                            and not self._spec_suspended_logged):
-                        self._spec_suspended_logged = True
-                        self._log.info(
-                            "speculative decoding suspended while a "
-                            "prompt is mid-prefill: the fused mixed "
-                            "step runs plain decode blocks (greedy "
-                            "outputs are unchanged — spec is lossless); "
-                            "speculation resumes when no prefill is in "
-                            "flight")
-                    issued = self._mixed_dispatch(active, n)
+                    issued = self._mixed_dispatch(active)
                     if issued is _REPLAN:
                         return _REPLAN
                     if issued:
@@ -5311,7 +5080,7 @@ class InferenceEngine:
                 # the first tokens it samples from the plane
                 self._fly(self._issue_chunk())
                 return True
-            # chunks, then the decode block: two programs, the second
+            # chunks, then the decode: two programs, the second
             # planned on what the first one's reading activates
             self._drain("two_dispatch")
         progressed = self._advance_prefills(budget) or pre_progress
@@ -5322,61 +5091,18 @@ class InferenceEngine:
         if self._try_speculative(active):
             self._update_active_stats()
             return True
-        with self.steptrace.scope("plan"):
-            n = self._plan_block(active)
-            use_multi = (
-                n > 1
-                # (a spec engine reaching here DIDN'T speculate this
-                # step — draft miss / non-greedy — and must not also
-                # forfeit the block amortization; the fused spec round
-                # otherwise spans the same plan itself)
-                # every row the block writes must land inside the cache
-                and all(self.slot_len[s] + n <= self.cache_len
-                        for s in active)
-            )
-            if not use_multi:
-                n = 1
         if self.paged is not None:
             with self.steptrace.scope("admit"):
-                if not self._reserve_ahead(active, n):
+                if not self._reserve_ahead(active, 1):
                     return _REPLAN
-                active = self._paged_reserve_active(active, n)
+                active = self._paged_reserve_active(active, 1)
             if not active:
                 return True  # reservation finished/preempted them all
         with self.steptrace.scope("index_build"):
             # the step's sampling key: a small eager device program
             self.rng, sub = jax.random.split(self.rng)
         if self.paged is not None:
-            self._decode_paged(active, n, sub)
-            return True
-        if use_multi:
-            with self.steptrace.scope("index_build"):
-                lora = self._lora_args()
-                kw = {} if lora is None else {"lora": lora}
-            with self.steptrace.scope("dispatch_wait"):
-                self.steptrace.window_begin("decode")
-                fn = (self._decode_multi if lora is None
-                      else self._decode_multi_lora)
-                toks, self.cache = fn(
-                    self.params, self.cache,
-                    jnp.asarray(self.slot_last_token),
-                    sub,
-                    *self._sampling_args(active),
-                    n=n, **kw,
-                )
-                self.steptrace.window_issued()
-                with self.steptrace.fetch():
-                    toks_host = np.asarray(toks)
-                dt, _ = self._window_close(
-                    "decode", [self.slot_req[s] for s in active])
-                keys = sum(CostModel.block_keys(n, int(self.slot_len[s]))
-                           for s in active)
-                self._note_device_phase(
-                    "decode", tokens=n * len(active), attended_keys=keys,
-                    weight_passes=n, kv_read_tokens=keys, dt=dt)
-            with self.steptrace.scope("sample_commit"):
-                self._commit_block(active, toks_host, n)
-            self._update_active_stats()
+            self._decode_paged(active, sub)
             return True
         # constrained decoding: per-slot grammar mask rows, applied by
         # the masked twin program in the SAME single dispatch

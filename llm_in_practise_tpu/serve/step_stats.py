@@ -232,15 +232,15 @@ class StepStats:
             setattr(self, key, getattr(self, key) + n)
         self.eng.steptrace.note_extra(**counts)
 
-    def note_decode_view(self, active, n: int, width: int) -> None:
-        """A decode (or mixed step's decode half) of ``n`` tokens over
+    def note_decode_view(self, active, width: int) -> None:
+        """A decode (or mixed step's decode half) of one token a row over
         ``active`` at view width ``width``: the rows its attention needed
         against the rows of the slot plane's view, or against the rows a
         decode that reads pages in place copied (and, with window
         layers, the ring rows those layers attended against the rows
         they read: every slot's whole ring)."""
         eng = self.eng
-        lens = [int(eng.slot_len[s]) + n for s in active]
+        lens = [int(eng.slot_len[s]) + 1 for s in active]
         counts = {self.attended_key: sum(lens),
                   self.view_key: eng.max_slots * int(width)}
         if self.page_block:
@@ -258,12 +258,11 @@ class StepStats:
             # every row of the plane passes both decoders; only the live
             # rows' states move
             counts.update(
-                ssm_state_rows_advanced=len(lens) * n,
-                ssm_state_rows_held=eng.max_slots * n,
-                self_decoder_rows=eng.max_slots * n,
-                cross_decoder_rows=eng.max_slots * n,
-                shared_kv_rows_attended=sum(
-                    length - i for length in lens for i in range(n))
+                ssm_state_rows_advanced=len(lens),
+                ssm_state_rows_held=eng.max_slots,
+                self_decoder_rows=eng.max_slots,
+                cross_decoder_rows=eng.max_slots,
+                shared_kv_rows_attended=sum(lens)
                 * self.census["shared_readers"])
         self._count(**counts)
 

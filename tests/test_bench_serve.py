@@ -19,7 +19,7 @@ def engine():
     params = model.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
     eng = InferenceEngine(model, params, max_slots=4, cache_len=128,
-                          cache_dtype=jnp.float32, decode_steps=4)
+                          cache_dtype=jnp.float32)
     eng.start()
     yield eng
     eng.stop()

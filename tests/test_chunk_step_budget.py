@@ -8,7 +8,8 @@ first and no step stalls its decode rows for the whole burst. The
 contiguous layout computes the whole slot plane whatever chunks, so there
 every mid-prefill row advances, as before. These tests pin the rows a step
 takes, the order the prompts finish in, and that the tokens are those of
-the unbounded schedule.
+the unbounded schedule. Beside them: ``prefill_budget`` chunks a step are
+a mid-prefill prompt's whatever decodes beside it.
 
 CPU, small vocabulary, seconds."""
 
@@ -109,3 +110,24 @@ def test_the_contiguous_layout_advances_every_prompt(
     _, _, paged = burst(model_params, monkeypatch, "paged", 2 * CHUNK,
                         decoder)
     assert tokens == paged
+
+
+@pytest.mark.parametrize("budget, bound", [(1, 6), (3, 2)],
+                         ids=["one_chunk_a_step", "three_chunks_a_step"])
+def test_decode_load_cannot_starve_a_prefill(model_params, budget, bound):
+    """A mid-prefill prompt advances ``prefill_budget`` chunks EVERY engine
+    step while another slot decodes, so its TTFT is bounded by
+    ceil(chunks / budget) engine steps (admission shares the first)."""
+    model, params = model_params
+    eng = InferenceEngine(model, params, max_slots=2, cache_len=128,
+                          cache_dtype=jnp.float32, chunked_prefill=CHUNK,
+                          prefill_budget=budget)
+    eng.submit(DECODER, SamplingParams(greedy=True, max_tokens=64))
+    eng.step()  # admit + activate the decode-load request
+    b = eng.submit(list(range(1, 41)),       # 40 tokens -> 5 chunks of 8
+                   SamplingParams(greedy=True, max_tokens=4))
+    steps = 0
+    while b.first_token_time is None and steps < 12:
+        eng.step()
+        steps += 1
+    assert b.first_token_time is not None and steps <= bound

@@ -262,7 +262,7 @@ def device_server():
                         jnp.ones((1, 8), jnp.int32))["params"]
     engine = InferenceEngine(model, params, max_slots=2, cache_len=256,
                              cache_dtype=jnp.float32,
-                             chunked_prefill=64, decode_steps=2,
+                             chunked_prefill=64,
                              ttft_slo_s=120.0, tpot_slo_s=60.0)
     srv = OpenAIServer(engine, _ByteTok(), model_name="device-plane")
     port = srv.serve(host="127.0.0.1", port=0, background=True)
@@ -496,7 +496,7 @@ def test_mixed_step_records_both_phases():
                         jnp.ones((1, 8), jnp.int32))["params"]
     engine = InferenceEngine(model, params, max_slots=2, cache_len=512,
                              cache_dtype=jnp.float32, chunked_prefill=32,
-                             decode_steps=4, mixed_step=True)
+                             mixed_step=True)
     rng = np.random.default_rng(0)
     # one decoding slot + one long prompt mid-prefill → fused steps
     r1 = engine.submit(list(map(int, rng.integers(0, 256, 8))),

@@ -201,14 +201,14 @@ def test_decode_replica_zero_mixed_blocks_under_concurrent_load(
     decode block ever shares a dispatch with prefill work
     (``mixed_blocks``/``llm_mixed_blocks_total`` == 0) — on an engine
     configured so that local prefills WOULD trigger the fused mixed
-    path (chunked_prefill + decode_steps, the Finding 17 machinery)."""
+    path (chunked_prefill, the Finding 17 machinery)."""
     model, params = model_params
     ref = ref_outputs
     # this config DOES produce mixed blocks when prompts prefill
     # locally — tests/test_mixed_step.py pins that (fused.mixed_blocks
-    # > 0 under the same chunked_prefill+decode_steps mixed load), so
+    # > 0 under the same chunked_prefill mixed load), so
     # the 0 below is a meaningful absence, not a disabled path
-    mixed_kw = dict(chunked_prefill=8, decode_steps=4)
+    mixed_kw = dict(chunked_prefill=8)
 
     store = LocalHandoff()
     pre = _engine(model, params, role="prefill", handoff=store, **mixed_kw)

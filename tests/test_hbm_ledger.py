@@ -278,8 +278,7 @@ def test_draft_cache_is_a_first_class_account(model_params):
     led = get_ledger()
     base = led.baseline()
     eng = _engine(model, params, kv_layout="paged", kv_pool_tokens=1024,
-                  speculative_k=3, decode_steps=4,
-                  draft_model=model, draft_params=params)
+                  speculative_k=3, draft_model=model, draft_params=params)
     grown = led.leaked_since(base)
     assert grown.get("kv.draft") == tree_bytes(eng.draft_cache)
     assert grown.get("weights/draft_model") == tree_bytes(params)

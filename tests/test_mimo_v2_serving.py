@@ -150,7 +150,6 @@ def test_decode_reads_the_global_layers_pages_where_they_lie(served):
         assert (r["view_pages"] > 0) == (chunked > 0)
     assert st.global_pages_read == sum(r["global_pages_read"] for r in dec)
     assert _jitted(eng._pg_decode)._cache_size() == 1
-    assert _jitted(eng._pg_multi)._cache_size() == 0
     # one more short prompt: its ONE chunk trip pulses the ledger's
     # transient view, its decode steps do not
     pulses = lambda: get_ledger().snapshot()["accounts"][  # noqa: E731

@@ -1,24 +1,18 @@
 """Fused mixed-batch engine step (serve/mixed_step.py).
 
-The r5 long-context bench showed mixed-load steps paying TWO device
-dispatches (chunk + decode) with multi-step decode force-disabled —
-the conc-4 TPOT p99 collapse. The fused step runs the prefill chunk
-and the n-step decode block in ONE dispatch. These tests pin:
+Run apart, a mixed-load step pays TWO device dispatches (chunk +
+decode). The fused step runs the prefill chunk and the decode in ONE
+dispatch. These tests pin:
 
 - token-exactness: fused vs. sequential (``mixed_step=False``) produce
   identical greedy tokens AND identical cache contents mid-flight;
 - dispatch accounting: exactly 1 engine-program dispatch per ``step()``
   under simultaneous prefill+decode (the new ``DispatchMeter``), vs.
   >= 2 on the sequential path;
-- the decode block keeps n>1 while ``slot_prefill`` is non-empty —
-  the deleted ``use_multi`` gate stays deleted;
-- speculative engines suspend (with a logged reason) rather than
-  silently changing outputs;
-- the ``plan_decode_block`` policy (pow2 quantization, soonest-finish
-  and chunk-window caps).
+- every active decoder gains its token in the step that chunks;
+- a speculative engine keeps speculating beside a prefill, outputs
+  unchanged.
 """
-
-import logging
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +21,6 @@ import pytest
 
 from llm_in_practise_tpu.models.gpt import GPT, GPTConfig
 from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
-from llm_in_practise_tpu.serve.mixed_step import plan_decode_block
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +38,6 @@ def _engine(model, params, **kw):
     kw.setdefault("cache_len", 192)
     kw.setdefault("cache_dtype", jnp.float32)
     kw.setdefault("chunked_prefill", 8)
-    kw.setdefault("decode_steps", 4)
     return InferenceEngine(model, params, **kw)
 
 
@@ -58,7 +50,7 @@ def _run_mixed_load(eng):
     decode while a long prompt chunk-prefills."""
     sp = SamplingParams(greedy=True, max_tokens=24)
     h = [eng.submit(p, sp) for p in SHORT]
-    eng.step()                      # admit both, first decode block
+    eng.step()                      # admit both, first decode
     hl = eng.submit(LONG, SamplingParams(greedy=True, max_tokens=8))
     while eng.step():
         pass
@@ -116,30 +108,27 @@ def test_fused_matches_sequential_cache_contents(model_params):
 
 def test_one_dispatch_per_step_under_mixed_load(model_params):
     """The acceptance bar: 1 long prompt mid-chunked-prefill + 2 active
-    decoders => exactly ONE device dispatch per step(), with the decode
-    block still n>1 while slot_prefill is non-empty."""
+    decoders => exactly ONE device dispatch per step(), and every
+    decoder takes its token in it."""
     model, params = model_params
     eng = _engine(model, params)
     sp = SamplingParams(greedy=True, max_tokens=64)
     h = [eng.submit(p, sp) for p in SHORT]
-    eng.step()                                # admission + first block
+    eng.step()                                # admission + first decode
     assert all(r.first_token_time is not None for r in h)
     hl = eng.submit(LONG, SamplingParams(greedy=True, max_tokens=8))
     steps_mixed = 0
     while hl.first_token_time is None:
         gen_before = [r.n_generated for r in h]
-        blocks_before = eng.multi_blocks
         eng.step()
         steps_mixed += 1
         assert steps_mixed < 12, "long prompt never activated"
         if eng.slot_prefill:                  # still mid-prefill after step
-            # ONE dispatch covered chunk + decode block
+            # ONE dispatch covered chunk + decode
             assert eng.dispatch_meter.last_step == 1
-            # decode kept its multi-step amortization: n>1 block ran and
-            # every active decoder gained decode_steps tokens this step
-            assert eng.multi_blocks == blocks_before + 1
+            # every active decoder gained its token this step
             assert [r.n_generated for r in h] \
-                == [g + eng.decode_steps for g in gen_before]
+                == [g + 1 for g in gen_before]
     assert steps_mixed >= 2                   # prefill really interleaved
     assert eng.mixed_blocks >= steps_mixed - 1
 
@@ -158,40 +147,23 @@ def test_sequential_path_pays_two_dispatches(model_params):
     assert eng.dispatch_meter.last_step >= 2
 
 
-def test_decode_only_multistep_is_one_dispatch(model_params):
-    """Sanity on the meter itself: a pure-decode multi-step block is one
-    dispatch; the fused path adds prefill without adding a second."""
+def test_decode_only_step_is_one_dispatch(model_params):
+    """Sanity on the meter itself: a pure-decode step is one dispatch;
+    the fused path adds prefill without adding a second."""
     model, params = model_params
     eng = _engine(model, params)
     eng.submit(SHORT[0], SamplingParams(greedy=True, max_tokens=64))
     eng.step()                                # admit (prefill dispatches)
-    eng.step()                                # pure decode block
+    eng.step()                                # pure decode
     assert eng.dispatch_meter.last_step == 1
     assert eng.dispatch_meter.total > 1       # admission was counted too
 
 
-def test_speculative_suspends_with_logged_reason(model_params, caplog):
-    """A speculative engine with decode_steps>1 under mixed load must
-    fall back to the fused plain-decode step with an explicit log line —
-    greedy outputs exactly match the non-spec engine's (spec is
-    lossless), never silently changed."""
-    model, params = model_params
-    ref = _engine(model, params, decode_steps=4)
-    out_ref = _run_mixed_load(ref)
-    spec = _engine(model, params, decode_steps=4, speculative_k=3)
-    with caplog.at_level(logging.INFO, logger="serve.engine"):
-        out_spec = _run_mixed_load(spec)
-    assert out_spec == out_ref
-    assert any("speculative decoding suspended" in r.message
-               for r in caplog.records)
-    assert spec.mixed_blocks > 0
-
-
-def test_speculative_composes_at_single_step(model_params):
-    """With decode_steps=1 a verify step yields 1+accepted tokens per
-    dispatch — strictly more than a fused n=1 block — so speculation
-    keeps running while prompts prefill (the r5 composition) and the
-    fused path stays out of the way. Outputs stay exact."""
+def test_speculative_composes_with_a_prefill(model_params):
+    """A verify step yields 1+accepted tokens per dispatch — strictly
+    more than the fused step's one — so speculation keeps running while
+    prompts prefill and the fused path stays out of the way. Outputs
+    stay exact."""
     model, params = model_params
 
     def run(eng):
@@ -204,31 +176,28 @@ def test_speculative_composes_at_single_step(model_params):
             pass
         return [h.result(), hl.result()]
 
-    ref = _engine(model, params, decode_steps=1)
+    ref = _engine(model, params)
     out_ref = run(ref)
-    spec = _engine(model, params, decode_steps=1, speculative_k=3)
+    spec = _engine(model, params, speculative_k=3)
     out_spec = run(spec)
     assert out_spec == out_ref
     assert spec.mixed_blocks == 0            # fused path never engaged
     assert spec.spec_proposed > 0            # spec really ran
 
 
-def test_spec_draft_miss_keeps_multi_step_block(model_params):
-    """ISSUE 9 regression: a speculative engine whose drafter finds
-    nothing this step (no repeating structure) must still run the
-    n-step block — the old ``use_multi`` gate forced it to one-token
-    dispatches whenever ``speculative_k`` was set. Outputs stay exact
-    vs the plain multi-step engine."""
+def test_spec_draft_miss_falls_through_to_plain_decode(model_params):
+    """A speculative engine whose drafter finds nothing this step (no
+    repeating structure) runs the plain decode in its place. Outputs
+    stay exact vs the plain engine."""
     model, params = model_params
     prompt = [7, 23, 41, 3, 58, 11, 30, 9, 44, 17]   # no n-grams repeat
     sp = SamplingParams(greedy=True, max_tokens=20)
-    ref = _engine(model, params, chunked_prefill=None,
-                  decode_steps=4).generate(prompt, sp)
-    spec = _engine(model, params, chunked_prefill=None,
-                   decode_steps=4, speculative_k=3)
+    ref = _engine(model, params, chunked_prefill=None).generate(prompt, sp)
+    spec = _engine(model, params, chunked_prefill=None, speculative_k=3)
     assert spec.generate(prompt, sp) == ref
-    # draft misses fell through to real blocks, not n=1 dispatches
-    assert spec.multi_blocks > 0
+    # draft misses fell through to plain decodes: fewer verify rounds
+    # than tokens
+    assert spec.spec_rounds < len(ref)
 
 
 def test_mixed_step_respects_cache_tail_fallback(model_params):
@@ -245,10 +214,10 @@ def test_mixed_step_respects_cache_tail_fallback(model_params):
         a = eng.submit(SHORT[0], SamplingParams(greedy=True,
                                                 max_tokens=100))
         guard = 0
-        while a.n_generated < 44:             # ride slot_len toward 64
+        while a.n_generated < 50:             # ride slot_len to 64 - 8
             eng.step()
             guard += 1
-            assert guard < 40
+            assert guard < 60
         b = eng.submit(LONG[:20], SamplingParams(greedy=True,
                                                  max_tokens=4))
         while eng.step():
@@ -260,43 +229,6 @@ def test_mixed_step_respects_cache_tail_fallback(model_params):
     assert engines[True]._mixed_fallbacks_logged
 
 
-def test_plan_decode_block_policy():
-    # full block when nobody waits and nothing prefills
-    assert plan_decode_block(decode_steps=8, queue_depth=0,
-                             soonest_finish=None, chunk=None,
-                             prefill_headroom=None) == 8
-    # the CONFIGURED length is never quantized — non-pow2 decode_steps
-    # runs at full value when no cap bites (one known compiled variant)
-    assert plan_decode_block(decode_steps=6, queue_depth=0,
-                             soonest_finish=None, chunk=None,
-                             prefill_headroom=None) == 6
-    assert plan_decode_block(decode_steps=6, queue_depth=0,
-                             soonest_finish=None, chunk=16,
-                             prefill_headroom=100) == 6
-    # soonest-completion cap under queueing, pow2-quantized DOWN
-    assert plan_decode_block(decode_steps=8, queue_depth=1,
-                             soonest_finish=5, chunk=None,
-                             prefill_headroom=None) == 4
-    assert plan_decode_block(decode_steps=8, queue_depth=1,
-                             soonest_finish=1, chunk=None,
-                             prefill_headroom=None) == 1
-    # chunk window caps the block while a prompt prefills
-    assert plan_decode_block(decode_steps=16, queue_depth=0,
-                             soonest_finish=None, chunk=8,
-                             prefill_headroom=100) == 8
-    # prefill rows near the cache end shrink the block, floor 1
-    assert plan_decode_block(decode_steps=8, queue_depth=0,
-                             soonest_finish=None, chunk=8,
-                             prefill_headroom=3) == 2
-    assert plan_decode_block(decode_steps=8, queue_depth=0,
-                             soonest_finish=None, chunk=8,
-                             prefill_headroom=-5) == 1
-    # decode_steps=1 never grows
-    assert plan_decode_block(decode_steps=1, queue_depth=3,
-                             soonest_finish=9, chunk=4,
-                             prefill_headroom=9) == 1
-
-
 # --- paged layout: the prefill half computes only the rows that chunk -------
 #
 # (PR 28) ``_paged_chunk_fn`` loops over the mid-prefill rows, one a
@@ -306,7 +238,7 @@ def test_plan_decode_block_policy():
 # ``batched_chunk`` body and a separate decode dispatch, code this
 # change does not touch.
 
-PAGED = dict(max_slots=8, chunked_prefill=8, decode_steps=1)
+PAGED = dict(max_slots=8, chunked_prefill=8)
 SEED_PROMPT = [(i * 5 + 2) % 64 for i in range(40)]   # 2 full pages of 16
 
 
@@ -467,7 +399,7 @@ def test_decode_row_near_cache_end(model_params, layout):
     outs, engines = [], {}
     for mixed in (False, True):
         eng = engines[mixed] = _engine(
-            model, params, cache_len=64, mixed_step=mixed, decode_steps=1,
+            model, params, cache_len=64, mixed_step=mixed,
             kv_layout=layout)
         a = eng.submit(SHORT[0], SamplingParams(greedy=True,
                                                 max_tokens=100))

@@ -120,7 +120,7 @@ def test_mixed_adapter_parity(world, refs, layout, spec):
     base_ref, m1_ref, m2_ref = refs
     kw = dict(kv_layout=layout)
     if spec == "ngram":
-        kw.update(speculative_k=3, decode_steps=4)
+        kw.update(speculative_k=3)
     eng = _engine(model, params, adapter_registry=_registry(world), **kw)
     r0 = eng.submit(P0, SP)
     r1 = eng.submit(P0, SP, adapter="t1")

@@ -162,7 +162,6 @@ def test_decode_reads_the_pages_where_they_lie(served):
         assert (r["view_pages"] > 0) == (chunked > 0)
     assert st.global_view_tokens >= sum(r["global_view_tokens"] for r in dec)
     assert _jitted(eng._pg_decode)._cache_size() == 1
-    assert _jitted(eng._pg_multi)._cache_size() == 0
 
 
 def test_a_decode_row_lands_in_its_page_and_nowhere_else(served):

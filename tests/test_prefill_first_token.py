@@ -239,10 +239,9 @@ SCENARIOS = {
     "chunk4": lambda w, lay, lo: alone(
         engine_of(w, lay, lo, **CHUNKED), LONG4, lo),
     "fused": lambda w, lay, lo: mixed_load(
-        engine_of(w, lay, lo, decode_steps=4, **CHUNKED), lo),
+        engine_of(w, lay, lo, **CHUNKED), lo),
     "two_dispatch": lambda w, lay, lo: mixed_load(
-        engine_of(w, lay, lo, decode_steps=4, mixed_step=False, **CHUNKED),
-        lo),
+        engine_of(w, lay, lo, mixed_step=False, **CHUNKED), lo),
 }
 
 
@@ -267,7 +266,7 @@ def counts(eng):
 
 @pytest.mark.parametrize("mode", ["fused", "chunk_only"])
 def test_unconstrained_chunked_prompt_takes_the_programs_token(world, mode):
-    eng = engine_of(world, decode_steps=4, **CHUNKED)
+    eng = engine_of(world, **CHUNKED)
     if mode == "fused":
         mixed_load(eng, False)
         assert eng.mixed_blocks > 0
@@ -277,7 +276,7 @@ def test_unconstrained_chunked_prompt_takes_the_programs_token(world, mode):
 
 
 def test_contiguous_layout_keeps_host_sampling(world):
-    eng = engine_of(world, "contiguous", decode_steps=4, **CHUNKED)
+    eng = engine_of(world, "contiguous", **CHUNKED)
     mixed_load(eng, False)
     assert counts(eng) == (0, 1)
 
@@ -306,7 +305,7 @@ VOCAB_STRS = [chr(i) for i in range(64)]
 def test_constrained_chunked_prompt_obeys_its_start_state_on_the_host(world):
     auto = constrain.TokenAutomaton(
         constrain.compile_regex("7[0-9]+"), VOCAB_STRS, eos_id=None)
-    eng = engine_of(world, decode_steps=4, **CHUNKED)
+    eng = engine_of(world, **CHUNKED)
     free = eng.generate(prompt(LONG4), SamplingParams(greedy=True,
                                                       max_tokens=4))
     assert free[0] != ord("7")            # the grammar really steers
@@ -380,7 +379,7 @@ def test_sampled_first_tokens_follow_the_filtered_softmax(world, layout):
 @pytest.mark.parametrize("layout,programs", [("paged", 0), ("contiguous", 1)])
 def test_finalisation_dispatches_nothing_on_the_program_path(
         world, layout, programs):
-    eng = engine_of(world, layout, decode_steps=4, **CHUNKED)
+    eng = engine_of(world, layout, **CHUNKED)
     mixed_load(eng, False)                 # compile everything first
     spent = []
     # where a finished prompt is finalised: the read of the paged program

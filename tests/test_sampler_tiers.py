@@ -181,10 +181,10 @@ SHORT = ([3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8])
 LONG = [(i * 7 + 3) % 64 for i in range(40)]   # 5 chunks of 8
 MODES = {
     # one token a dispatch: engine._decode_fn / the paged decode program
-    "decode": dict(decode_steps=1, chunked_prefill=None),
-    # a long prompt chunks while the others decode blocks of 4: the fused
-    # mixed step, the sampler inside decode_scan's scan body
-    "mixed": dict(decode_steps=4, chunked_prefill=8),
+    "decode": dict(chunked_prefill=None),
+    # a long prompt chunks while the others decode: the fused mixed
+    # step, the sampler inside decode_scan's scan body
+    "mixed": dict(chunked_prefill=8),
 }
 
 

@@ -406,7 +406,6 @@ class _Store:
 @pytest.mark.parametrize("kw,match", [
     ({"kv_layout": "contiguous"}, "kv_layout='contiguous'"),
     ({"speculative_k": 2}, "speculative decoding"),
-    ({"decode_steps": 2}, "decode_steps=2"),
     ({"prefix_cache": True}, "prefix caching"),
     ({"session_store": _Store()}, "session store"),
     ({"kv_pool_tokens": 128}, "a page pool of 128 tokens"),

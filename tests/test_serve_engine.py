@@ -4,6 +4,7 @@ single-request greedy decoding exactly (per-slot cache positions + masks)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from llm_in_practise_tpu.infer.generate import generate
 from llm_in_practise_tpu.models.gpt import GPT, GPTConfig
@@ -47,21 +48,37 @@ def test_fp8_kv_cache_serves(rng):
     assert engine.cache[0]["k"].nbytes * 4 == ref.cache[0]["k"].nbytes
 
 
-def test_single_request_matches_generate(rng):
+# every decode path the engine has: a row's token a step, or a verified
+# burst of drafts, against either cache
+PATHS = pytest.mark.parametrize("layout, spec_k", [
+    ("contiguous", None), ("contiguous", 3), ("paged", None), ("paged", 3)])
+
+
+@pytest.mark.parametrize("layout, cache_len, reason", [
+    ("contiguous", 128, "length"),
+    # 4 prompt rows + 8 tokens' rows fill a cache of 12: the row ends there
+    ("contiguous", 12, "cache"), ("paged", 12, "cache")])
+def test_single_request_matches_generate(rng, layout, cache_len, reason):
     model, params = _tiny_model(rng)
     engine = InferenceEngine(
-        model, params, max_slots=4, cache_len=128, cache_dtype=jnp.float32
+        model, params, max_slots=4, cache_len=cache_len,
+        cache_dtype=jnp.float32, kv_layout=layout, kv_page_size=4,
     )
     prompt = [1, 5, 9, 13]
-    got = engine.generate(prompt, SamplingParams(greedy=True, max_tokens=10))
+    req = engine.submit(prompt, SamplingParams(greedy=True, max_tokens=10))
+    while engine.step():
+        pass
     ref = _ref_greedy(model, params, prompt, 10)
-    assert got == ref, (got, ref)
+    assert req.finish_reason == reason
+    assert req.result() == ref[:cache_len - len(prompt)], (req.result(), ref)
 
 
-def test_interleaved_requests_match_isolated(rng):
+@PATHS
+def test_interleaved_requests_match_isolated(rng, layout, spec_k):
     model, params = _tiny_model(rng)
     engine = InferenceEngine(
-        model, params, max_slots=4, cache_len=128, cache_dtype=jnp.float32
+        model, params, max_slots=4, cache_len=128, cache_dtype=jnp.float32,
+        kv_layout=layout, speculative_k=spec_k,
     )
     prompts = [[1, 2, 3], [7, 8, 9, 10, 11], [20], [30, 31]]
     reqs = [
@@ -78,19 +95,27 @@ def test_interleaved_requests_match_isolated(rng):
         assert r.ttft_s is not None
 
 
-def test_slot_reuse_after_finish(rng):
-    """More requests than slots: later requests recycle freed slots cleanly."""
+@PATHS
+def test_slot_reuse_after_finish(rng, layout, spec_k):
+    """More requests than slots: later requests recycle freed slots
+    cleanly, and a slot free while a request waits is taken by the very
+    next step."""
     model, params = _tiny_model(rng)
     engine = InferenceEngine(
-        model, params, max_slots=2, cache_len=128, cache_dtype=jnp.float32
+        model, params, max_slots=2, cache_len=128, cache_dtype=jnp.float32,
+        kv_layout=layout, speculative_k=spec_k,
     )
     prompts = [[i, i + 1, i + 2] for i in range(1, 11, 2)]  # 5 requests, 2 slots
     reqs = [
         engine.submit(p, SamplingParams(greedy=True, max_tokens=6))
         for p in prompts
     ]
-    while engine.step():
-        pass
+    busy = True
+    while busy:
+        free = [s for s in range(2) if engine.slot_req[s] is None]
+        owed = min(len(free), engine.pending.qsize())
+        busy = engine.step()
+        assert sum(engine.slot_req[s] is not None for s in free) >= owed
     for p, r in zip(prompts, reqs):
         assert r.result() == _ref_greedy(model, params, p, 6), p
 
@@ -128,19 +153,31 @@ def test_qwen3_serves_on_engine(rng):
     assert got == ref
 
 
-def test_eos_stops_generation(rng):
+@PATHS
+def test_eos_stops_generation(rng, layout, spec_k):
+    """EOS ends a row mid-stream, and the request waiting for its slot
+    holds it two steps after the stream closed at the latest (a paged
+    row's last program is read a step after it was issued)."""
     model, params = _tiny_model(rng)
     ref = _ref_greedy(model, params, [1, 2, 3], 10)
     eos = ref[3]  # force eos at the 4th generated token
     engine = InferenceEngine(
-        model, params, max_slots=2, cache_len=128, cache_dtype=jnp.float32,
-        eos_id=eos,
+        model, params, max_slots=1, cache_len=128, cache_dtype=jnp.float32,
+        eos_id=eos, kv_layout=layout, speculative_k=spec_k,
     )
     req = engine.submit([1, 2, 3], SamplingParams(greedy=True, max_tokens=10))
-    while engine.step():
-        pass
+    nxt = engine.submit([7, 8, 9], SamplingParams(greedy=True, max_tokens=2))
+    while req.finish_time is None:
+        engine.step()
     assert req.result() == ref[:3]
     assert req.finish_reason == "stop"
+    engine.step()
+    engine.step()
+    assert engine.slot_req[0] is nxt or nxt.finish_time is not None
+    while engine.step():
+        pass
+    want = _ref_greedy(model, params, [7, 8, 9], 2)
+    assert nxt.result() == (want[:want.index(eos)] if eos in want else want)
 
 
 def test_prefix_cache_exactness_and_hits(rng):

@@ -1,19 +1,13 @@
 """Fused speculative decode (ISSUE 9 / ROADMAP item 4).
 
-The engine verifies the k drafted tokens AND decodes the planned
-block's remaining steps inside ONE jitted dispatch
+The engine verifies the k drafted tokens inside ONE jitted dispatch
 (``serve/mixed_step.spec_verify_block``): acceptance is computed on
-device, the index fixup that used to be a second ``_rewind`` dispatch
-is folded in, and ``decode_steps > 1`` no longer collapses a spec
-engine to one-round-per-dispatch economics. These tests pin:
+device and the index fixup is folded in. These tests pin:
 
 - golden-token parity: fused spec ≡ plain greedy across
-  {contiguous, paged} × {ngram, draft-model}, at ``decode_steps > 1``;
-- dispatch accounting: a (ngram) spec round is ONE dispatch with > 1
-  accepted tokens committed per dispatch;
-- the decode-replica suspension gate is GONE: a ``role="decode"``
-  engine keeps speculating while a (degraded) local prefill is in
-  flight, and never logs the mixed-replica "suspended" line;
+  {contiguous, paged} × {ngram, draft-model};
+- a ``role="decode"`` engine keeps speculating while a (degraded)
+  local prefill is in flight;
 - preemption-mid-burst (paged): pool-pressure preemption between spec
   rounds still yields byte-identical streams;
 - draft-cache admission math (paged): an explicit page budget is
@@ -22,8 +16,6 @@ engine to one-round-per-dispatch economics. These tests pin:
 - the spec-ladder bench's CPU smoke
   (``tools/spec_ladder_bench.run_ladder``).
 """
-
-import logging
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +29,6 @@ from llm_in_practise_tpu.serve.disagg import (
     new_handoff_id,
 )
 from llm_in_practise_tpu.serve.engine import InferenceEngine, SamplingParams
-from llm_in_practise_tpu.serve.mixed_step import plan_spec_extension
 
 
 @pytest.fixture(scope="module")
@@ -62,20 +53,20 @@ LONG = [(i * 7 + 3) % 64 for i in range(40)]
 SP = SamplingParams(greedy=True, max_tokens=40)
 
 
-# --- golden parity: fused verify at decode_steps > 1 ------------------------
+# --- golden parity -----------------------------------------------------------
 
 
 @pytest.mark.parametrize("layout", ["contiguous", "paged"])
 @pytest.mark.parametrize("proposer", ["ngram", "draft"])
 def test_fused_spec_parity(model_params, layout, proposer):
-    """Spec on ≡ spec off (greedy), both KV layouts, both proposers,
-    with the verify riding the decode_steps=4 block. The draft leg
+    """Spec on ≡ spec off (greedy), both KV layouts, both proposers.
+    The draft leg
     uses the target itself as draft — every proposal is the exact
     greedy continuation, so acceptance is total and the fused commit
     path is exercised at full width deterministically."""
     model, params = model_params
     ref = _engine(model, params).generate(REPETITIVE, SP)
-    kw = dict(speculative_k=4, decode_steps=4)
+    kw = dict(speculative_k=4)
     if layout == "paged":
         kw["kv_layout"] = "paged"
     if proposer == "draft":
@@ -83,8 +74,8 @@ def test_fused_spec_parity(model_params, layout, proposer):
     spec = _engine(model, params, **kw)
     assert spec.generate(REPETITIVE, SP) == ref
     assert spec.spec_rounds > 0
-    # the fused round spans the block plan: committed tokens per spec
-    # dispatch strictly beat one-token dispatches
+    # committed tokens per spec dispatch strictly beat one-token
+    # dispatches
     assert spec.spec_round_tokens / spec.spec_rounds > 1.0
     if proposer == "draft":
         # target-as-draft: every drafted token is accepted
@@ -96,8 +87,8 @@ def test_fused_spec_parity(model_params, layout, proposer):
 
 
 def test_fused_spec_parity_interleaved_slots(model_params):
-    """Several greedy streams over fewer slots, ngram + paged +
-    decode_steps=4: every stream equals its isolated plain run."""
+    """Several greedy streams over fewer slots, ngram + paged: every
+    stream equals its isolated plain run."""
     model, params = model_params
     prompts = [REPETITIVE, [2, 9] * 10, LONG[:20]]
     plain = _engine(model, params, max_slots=1)
@@ -107,7 +98,7 @@ def test_fused_spec_parity_interleaved_slots(model_params):
         refs.append(plain.submit(p, SP).result())
     plain.stop()
     spec = _engine(model, params, max_slots=2, kv_layout="paged",
-                   speculative_k=3, decode_steps=4)
+                   speculative_k=3)
     spec.start()
     outs = [h.result() for h in
             [spec.submit(p, SP) for p in prompts]]
@@ -115,51 +106,13 @@ def test_fused_spec_parity_interleaved_slots(model_params):
     assert outs == refs
 
 
-# --- dispatch accounting -----------------------------------------------------
+# --- speculation beside a local prefill -------------------------------------
 
 
-def test_spec_round_is_one_dispatch_many_tokens(model_params):
-    """The satellite's DispatchMeter bar: an ngram spec round is ONE
-    dispatch per step (the old contiguous path paid verify + rewind =
-    2) committing > 1 token — with target-as-draft economics pinned
-    exactly: k accepted + bonus + (decode_steps - 1) extension."""
-    model, params = model_params
-    eng = _engine(model, params, speculative_k=4, decode_steps=4,
-                  draft_model=model, draft_params=params)
-    h = eng.submit(REPETITIVE, SamplingParams(greedy=True, max_tokens=30))
-    eng.step()                      # admit + first token
-    gen0, rounds0 = h.n_generated, eng.spec_rounds
-    eng.step()                      # one fused spec round
-    assert eng.spec_rounds == rounds0 + 1
-    # draft-model rounds cost 2 dispatches (draft roll + fused verify);
-    # the verify itself absorbed the rewind, so the step is exactly 2
-    assert eng.dispatch_meter.last_step == 2
-    assert h.n_generated - gen0 == 4 + 1 + 3   # k + bonus + extension
-
-    ngram = _engine(model, params, speculative_k=3, decode_steps=4)
-    h = ngram.submit(REPETITIVE, SamplingParams(greedy=True, max_tokens=30))
-    ngram.step()                    # admit
-    gen0, guard = h.n_generated, 0
-    while ngram.spec_rounds == 0 and h.finish_reason is None:
-        gen0 = h.n_generated
-        ngram.step()                # plain blocks until a draft lands
-        guard += 1
-        assert guard < 30, "ngram drafter never fired"
-    assert ngram.spec_rounds >= 1
-    # ngram drafting is host-side: the whole round is ONE dispatch
-    # (the old contiguous path paid 2 — verify + rewind)
-    assert ngram.dispatch_meter.last_step == 1
-    assert h.n_generated - gen0 > 1
-
-
-# --- decode-replica gate removal --------------------------------------------
-
-
-def test_decode_role_never_suspends_speculation(model_params, caplog):
-    """On role='decode' the suspension gate is gone: spec rounds keep
-    landing WHILE a degraded local prefill is in flight (decode_steps>1
-    used to suspend), the mixed-replica 'suspended' line never fires,
-    and outputs equal the plain decode-role engine's."""
+def test_decode_role_speculates_beside_a_local_prefill(model_params):
+    """On role='decode' spec rounds keep landing WHILE a degraded local
+    prefill is in flight, and outputs equal the plain decode-role
+    engine's."""
     model, params = model_params
 
     def run(eng):
@@ -178,39 +131,15 @@ def test_decode_role_never_suspends_speculation(model_params, caplog):
         return [h.result(), hl.result()], mid_prefill_rounds
 
     ref, _ = run(_engine(model, params, role="decode",
-                         chunked_prefill=8, decode_steps=4))
+                         chunked_prefill=8))
     # target-as-draft: proposals flow EVERY round, so the while-prefill
     # composition is observed deterministically
     spec = _engine(model, params, role="decode", chunked_prefill=8,
-                   decode_steps=4, speculative_k=3,
-                   draft_model=model, draft_params=params)
-    with caplog.at_level(logging.INFO, logger="serve.engine"):
-        out, mid_rounds = run(spec)
+                   speculative_k=3, draft_model=model, draft_params=params)
+    out, mid_rounds = run(spec)
     assert out == ref
     assert mid_rounds > 0                    # spec ran DURING prefill
     assert spec.spec_rounds > 0
-    assert not spec._spec_suspended_logged
-    assert not any("speculative decoding suspended" in r.message
-                   for r in caplog.records)
-
-
-def test_both_role_still_suspends_at_multi_step(model_params, caplog):
-    """The documented mixed-replica behavior is unchanged: role='both'
-    at decode_steps>1 suspends during prefill with the logged reason
-    (tests/test_mixed_step.py pins the parity half)."""
-    model, params = model_params
-    eng = _engine(model, params, chunked_prefill=8, decode_steps=4,
-                  speculative_k=3)
-    sp = SamplingParams(greedy=True, max_tokens=24)
-    eng.submit(REPETITIVE, sp)
-    eng.step()
-    eng.submit(LONG, SamplingParams(greedy=True, max_tokens=8))
-    with caplog.at_level(logging.INFO, logger="serve.engine"):
-        while eng.step():
-            pass
-    assert eng.mixed_blocks > 0
-    assert any("speculative decoding suspended" in r.message
-               for r in caplog.records)
 
 
 # --- preemption mid-burst (paged) -------------------------------------------
@@ -218,7 +147,7 @@ def test_both_role_still_suspends_at_multi_step(model_params, caplog):
 
 def test_preemption_mid_spec_burst_exact_streams(model_params):
     """Pool sized for ~2 of 3 requests while fused spec rounds write
-    k+1+m rows per reservation: preemption must fire BETWEEN rounds
+    k+1 rows per reservation: preemption must fire BETWEEN rounds
     and every stream still equals the unconstrained plain run (the
     recompute-resume path neither drops nor re-samples, and the
     preempted slot's draft watermark resets)."""
@@ -228,7 +157,7 @@ def test_preemption_mid_spec_burst_exact_streams(model_params):
     # the same pressure regime as test_paged_kv's preemption test, with
     # the draft deduction (this PR's admission satellite) in the loop
     t = _engine(model, params, kv_layout="paged", kv_pool_tokens=864,
-                prefix_cache=True, speculative_k=3, decode_steps=4,
+                prefix_cache=True, speculative_k=3,
                 draft_model=model, draft_params=params)
     rs = [t.submit(p, SP) for p in prompts]
     while t.step():
@@ -295,7 +224,7 @@ def test_handoff_to_speculating_decode_replica(model_params):
     store = LocalHandoff()
     pre = _engine(model, params, role="prefill", handoff=store)
     dec = _engine(model, params, role="decode", speculative_k=4,
-                  decode_steps=4, kv_layout="paged")
+                  kv_layout="paged")
     hid = new_handoff_id()
     h = pre.submit(prompt, SP, handoff_id=hid)
     while pre.step():
@@ -311,7 +240,7 @@ def test_handoff_to_speculating_decode_replica(model_params):
     assert dec.local_prefills == 0
 
 
-# --- CLI default + planners --------------------------------------------------
+# --- CLI default -------------------------------------------------------------
 
 
 def test_default_speculative_k_policy():
@@ -321,19 +250,6 @@ def test_default_speculative_k_policy():
     assert default_speculative_k("both", None) is None
     assert default_speculative_k("prefill", None) is None
     assert default_speculative_k("both", 0) is None
-
-
-def test_plan_spec_extension_policy():
-    # the extension spans the block plan: m = block - 1
-    assert plan_spec_extension(block=4, k=4, headroom=100) == 3
-    assert plan_spec_extension(block=8, k=2, headroom=100) == 7
-    # decode_steps=1 economics unchanged
-    assert plan_spec_extension(block=1, k=4, headroom=100) == 0
-    # headroom shrinks, pow2-quantized DOWN (compile-set bound)
-    assert plan_spec_extension(block=8, k=2, headroom=5) == 4
-    assert plan_spec_extension(block=8, k=2, headroom=1) == 1
-    assert plan_spec_extension(block=8, k=2, headroom=0) == 0
-    assert plan_spec_extension(block=8, k=2, headroom=-3) == 0
 
 
 # --- spec ladder bench smoke -------------------------------------------------
@@ -346,7 +262,7 @@ def test_spec_ladder_smoke(tmp_path):
     from tools.spec_ladder_bench import run_ladder
 
     artifact = run_ladder(train_steps=40, n_requests=6, max_tokens=24,
-                          decode_steps=4, concurrencies=(1,),
+                          concurrencies=(1,),
                           out_path=str(tmp_path / "ladder.json"))
     assert set(artifact["legs"]) == {"off", "ngram", "draft"}
     assert artifact["legs"]["off"]["spec_rounds"] == 0

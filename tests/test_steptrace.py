@@ -75,7 +75,6 @@ def _engine(model, params, **kw):
     kw.setdefault("cache_len", 192)
     kw.setdefault("cache_dtype", jnp.float32)
     kw.setdefault("chunked_prefill", 8)
-    kw.setdefault("decode_steps", 4)
     eng = InferenceEngine(model, params, **kw)
     _ENGINES.append(eng)
     return eng
@@ -708,8 +707,7 @@ def test_activity_sums_match_step_wall(model_params, kv_layout):
 
 def test_spec_round_records_draft_propose(model_params):
     model, params = model_params
-    eng = _engine(model, params, speculative_k=3, decode_steps=1,
-                  chunked_prefill=None)
+    eng = _engine(model, params, speculative_k=3, chunked_prefill=None)
     sp = SamplingParams(greedy=True, max_tokens=24)
     req = eng.submit([5, 9, 2, 6, 5, 9, 2, 6, 5, 9, 2, 6], sp)
     while eng.step():

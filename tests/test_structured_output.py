@@ -205,13 +205,13 @@ def test_composition_matrix_golden_and_one_dispatch(model_params,
     model, params = model_params
     auto = _automaton()
     eng = _engine(model, params, kv_layout=kv_layout,
-                  speculative_k=spec_k, decode_steps=2,
-                  chunked_prefill=16, mixed_step=True)
+                  speculative_k=spec_k, chunked_prefill=16,
+                  mixed_step=True)
     sp = SamplingParams(greedy=True, max_tokens=150, constraint=auto)
     r_con = eng.submit(PROMPT, sp)
     r_plain = eng.submit(TOK.encode("hello there friend"),
                          SamplingParams(greedy=True, max_tokens=24))
-    decode_steps_seen = []
+    steady_dispatches = []
     while True:
         # a step that ENDS a chunked prompt is not steady decode: in the
         # contiguous layout it also runs the host's jitted first-token
@@ -222,14 +222,14 @@ def test_composition_matrix_golden_and_one_dispatch(model_params,
         if (not prefilling and not eng.slot_prefill
                 and any(eng.slot_ready[s] for s in range(eng.max_slots)
                         if eng.slot_req[s] is not None)):
-            decode_steps_seen.append(eng.dispatch_meter.last_step)
+            steady_dispatches.append(eng.dispatch_meter.last_step)
     out_con, out_plain = r_con.result(), r_plain.result()
     assert r_plain.finish_reason in ("stop", "length", "cache")
     value = json.loads(TOK.decode(out_con))
     assert constrain.validate_instance(value, SCHEMA)
     # steady decode (no prefill in flight) is one dispatch per step —
     # grammar on, every layout, spec on or off
-    assert decode_steps_seen and all(d == 1 for d in decode_steps_seen)
+    assert steady_dispatches and all(d == 1 for d in steady_dispatches)
     # the grammar work was booked, not hidden
     assert eng.grammar_mask_seconds_total > 0
     snap = eng.steptrace.snapshot()

@@ -108,7 +108,7 @@ def test_tp_golden_parity(model_params, ref_tokens, tp, layout, spec):
     model, params = model_params
     kw = dict(kv_layout=layout)
     if spec == "ngram":
-        kw.update(speculative_k=3, decode_steps=4)
+        kw.update(speculative_k=3)
     eng = _tp_engine(model, params, tp, **kw)
     assert eng.tp == tp
     kernel = eng.params["block_0"]["attn"]["q_proj"]["kernel"]
@@ -127,8 +127,8 @@ def test_tp_draft_model_speculation(model_params, ref_tokens):
     acceptance total, tokens stay byte-identical."""
     model, params = model_params
     eng = _tp_engine(model, params, 2, kv_layout="paged",
-                     speculative_k=3, decode_steps=4,
-                     draft_model=model, draft_params=params)
+                     speculative_k=3, draft_model=model,
+                     draft_params=params)
     # the draft tree is REPLICATED over the mesh, not committed to one
     # device next to the sharded target
     leaf = jax.tree_util.tree_leaves(eng.draft_params)[0]
@@ -189,7 +189,7 @@ def test_tp_disagg_handoff(model_params, ref_tokens, direction):
     if direction == "one_to_many":
         pre = _tp_engine(model, params, 1, role="prefill", handoff=store)
         dec = _tp_engine(model, params, 2, kv_layout="paged",
-                         speculative_k=3, decode_steps=4, role="decode")
+                         speculative_k=3, role="decode")
     else:
         pre = _tp_engine(model, params, 2, role="prefill", handoff=store)
         dec = _tp_engine(model, params, 1, role="decode")
@@ -219,7 +219,7 @@ def test_tp_one_dispatch_per_step_under_mixed_load(model_params):
     step."""
     model, params = model_params
     eng = _tp_engine(model, params, 2, kv_layout="paged",
-                     chunked_prefill=16, decode_steps=4)
+                     chunked_prefill=16)
     # decoder prompt < chunk so it one-shot admits and is DECODING
     # while the long prompt chunks (the test_mixed_step idiom — a
     # prompt finishing its own prefill then decoding is legitimately
@@ -402,7 +402,7 @@ def test_tp_ladder_smoke(tmp_path):
     from tools.tp_ladder_bench import run_ladder
 
     artifact = run_ladder(train_steps=40, n_requests=6, max_tokens=24,
-                          decode_steps=4, legs=(1, 2),
+                          legs=(1, 2),
                           concurrencies=(1,), quantized_leg=False,
                           out_path=str(tmp_path / "ladder.json"))
     assert set(artifact["legs"]) == {"tp1", "tp2"}

@@ -152,13 +152,11 @@ def collect_registered() -> frozenset[str]:
     model = GPT(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
-    # every conditional family ON: prefix cache, speculation,
-    # multi-step decode, paged KV — their metric families must be
-    # documented too
+    # every conditional family ON: prefix cache, speculation, paged
+    # KV — their metric families must be documented too
     engine = InferenceEngine(model, params, max_slots=2, cache_len=64,
                              cache_dtype=jnp.float32, prefix_cache=True,
-                             speculative_k=2, decode_steps=2,
-                             kv_layout="paged")
+                             speculative_k=2, kv_layout="paged")
     owners = [
         OpenAIServer(engine, _Tok(), model_name="census"),
         Gateway(Router([Upstream("http://127.0.0.1:1", "census",

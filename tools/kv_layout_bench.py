@@ -74,7 +74,7 @@ def prompts():
 
 def run_leg(layout: str, model, params, prompt_ids, token_bytes):
     kw = dict(cache_len=CACHE_LEN, cache_dtype=jnp.float32,
-              chunked_prefill=64, decode_steps=4)
+              chunked_prefill=64)
     if layout == "paged":
         eng = InferenceEngine(model, params, max_slots=PAGED_SLOTS,
                               kv_layout="paged", kv_page_size=PAGE_SIZE,

@@ -104,7 +104,7 @@ def _engine(model, params, registry=None):
 
     return InferenceEngine(
         model, params, max_slots=8, cache_len=256,
-        cache_dtype=jnp.float32, chunked_prefill=32, decode_steps=4,
+        cache_dtype=jnp.float32, chunked_prefill=32,
         prefix_cache=True, kv_layout="paged",
         adapter_registry=registry)
 
@@ -141,10 +141,10 @@ def _dispatch_probe(engine, adapters) -> dict:
     handles = [engine.submit(PROBE, sp)]
     handles += [engine.submit(PROBE, sp, adapter=n) for n in names]
     engine.step()                      # admission (prefill dispatches)
-    decode_steps = mixed_steps = 0
+    n_decode = mixed_steps = 0
     while engine.step():
         if not engine.slot_prefill:
-            decode_steps += 1
+            n_decode += 1
             if any(engine.slot_adapter):
                 mixed_steps += 1
                 assert engine.dispatch_meter.last_step == 1, (
@@ -153,7 +153,7 @@ def _dispatch_probe(engine, adapters) -> dict:
     for h in handles:
         h.result()
     assert mixed_steps > 0, "probe never hit a mixed decode step"
-    return {"slots": len(handles), "decode_steps": decode_steps,
+    return {"slots": len(handles), "decode_only_steps": n_decode,
             "mixed_adapter_steps": mixed_steps, "dispatches_per_step": 1}
 
 

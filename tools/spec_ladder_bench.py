@@ -1,10 +1,10 @@
 """Speculation ladder A/B — the ROADMAP item 4 acceptance artifact.
 
 Three legs on the SAME engine config (a decode replica's production
-setup: ``decode_steps > 1``, paged KV, greedy traffic):
+setup: paged KV, greedy traffic):
 
-- **off**   — plain multi-step decode (the baseline the fused spec
-  round must beat);
+- **off**   — plain decode (the baseline the fused spec round must
+  beat);
 - **ngram** — prompt-lookup speculation (no extra weights);
 - **draft** — draft-MODEL speculation (a smaller trained model
   proposes).
@@ -30,8 +30,8 @@ layouts — this artifact is the perf half.
 
 Run: ``python tools/spec_ladder_bench.py``. Writes
 ``BENCH_SPEC_LADDER_r07.json`` at the repo root. Env knobs:
-``SPEC_BENCH_DECODE_STEPS`` (default 4), ``SPEC_BENCH_KV_LAYOUT``
-(default paged), ``SPEC_BENCH_TRAIN_STEPS``, ``SPEC_BENCH_REQUESTS``.
+``SPEC_BENCH_KV_LAYOUT`` (default paged), ``SPEC_BENCH_TRAIN_STEPS``,
+``SPEC_BENCH_REQUESTS``.
 """
 
 from __future__ import annotations
@@ -103,8 +103,8 @@ def _prompts(n: int = 8):
 
 
 def run_ladder(*, train_steps: int = 300, n_requests: int = 24,
-               max_tokens: int = 48, decode_steps: int = 4,
-               kv_layout: str = "paged", spec_k: int = 4,
+               max_tokens: int = 48, kv_layout: str = "paged",
+               spec_k: int = 4,
                concurrencies=(1, 4), out_path: str | None = None) -> dict:
     """Build the trained pair, run the three legs, return (and
     optionally write) the artifact dict. The smoke test calls this
@@ -121,7 +121,7 @@ def run_ladder(*, train_steps: int = 300, n_requests: int = 24,
 
     base_kw = dict(max_slots=4, cache_len=CACHE_LEN,
                    cache_dtype=jnp.float32, chunked_prefill=64,
-                   decode_steps=decode_steps, kv_layout=kv_layout)
+                   kv_layout=kv_layout)
     legs = {}
     for leg in ("off", "ngram", "draft"):
         kw = dict(base_kw)
@@ -217,7 +217,6 @@ def main() -> None:
     artifact = run_ladder(
         train_steps=int(os.environ.get("SPEC_BENCH_TRAIN_STEPS", "300")),
         n_requests=int(os.environ.get("SPEC_BENCH_REQUESTS", "24")),
-        decode_steps=int(os.environ.get("SPEC_BENCH_DECODE_STEPS", "4")),
         kv_layout=os.environ.get("SPEC_BENCH_KV_LAYOUT", "paged"),
         out_path=OUT,
     )

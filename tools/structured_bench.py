@@ -83,7 +83,7 @@ def _build(kv_layout: str, spec: bool):
                         jnp.ones((1, 8), jnp.int32))["params"]
     return InferenceEngine(
         model, params, max_slots=8, cache_len=256,
-        cache_dtype=jnp.float32, chunked_prefill=32, decode_steps=4,
+        cache_dtype=jnp.float32, chunked_prefill=32,
         prefix_cache=True, kv_layout=kv_layout,
         speculative_k=4 if spec else None)
 

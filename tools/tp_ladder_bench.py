@@ -4,7 +4,7 @@ Three legs, tp ∈ {1, 2, 4}, on the SAME trained gptlike pair the spec
 ladder uses (``tools/spec_ladder_bench._train_gpt`` — a memorized
 corpus so ngram speculation has real acceptance), each leg the full
 decode-replica composition: paged KV pool sharded over the mesh,
-``decode_steps > 1``, ngram speculation, greedy traffic.
+ngram speculation, greedy traffic.
 
 What the artifact pins per leg:
 
@@ -29,9 +29,8 @@ expected bandwidth multiplication).
 
 Run: ``python tools/tp_ladder_bench.py``. Writes
 ``BENCH_TP_LADDER_r08.json`` at the repo root. Env knobs:
-``TP_BENCH_TRAIN_STEPS``, ``TP_BENCH_REQUESTS``,
-``TP_BENCH_DECODE_STEPS`` (default 4), ``TP_BENCH_LEGS`` (default
-"1,2,4"). The CLI runs an int8-quantized-collective sub-leg at the
+``TP_BENCH_TRAIN_STEPS``, ``TP_BENCH_REQUESTS``, ``TP_BENCH_LEGS``
+(default "1,2,4"). The CLI runs an int8-quantized-collective sub-leg at the
 largest tp by DEFAULT (it is part of the published artifact);
 ``TP_BENCH_QUANTIZED_COLLECTIVES=0`` drops it. (Library callers —
 the tier-1 smoke — get ``quantized_leg=False`` unless they ask.)
@@ -73,8 +72,7 @@ class _Tok:
 
 
 def run_ladder(*, train_steps: int = 300, n_requests: int = 24,
-               max_tokens: int = 48, decode_steps: int = 4,
-               spec_k: int = 4, legs=(1, 2, 4),
+               max_tokens: int = 48, spec_k: int = 4, legs=(1, 2, 4),
                concurrencies=(1, 4), quantized_leg: bool = False,
                out_path: str | None = None) -> dict:
     """Build the trained gptlike target, run one engine per tp leg,
@@ -98,7 +96,7 @@ def run_ladder(*, train_steps: int = 300, n_requests: int = 24,
 
     base_kw = dict(max_slots=4, cache_len=CACHE_LEN,
                    cache_dtype=jnp.float32, chunked_prefill=64,
-                   decode_steps=decode_steps, kv_layout="paged",
+                   kv_layout="paged",
                    speculative_k=spec_k)
 
     def build(tp: int, quantized_collectives: bool = False):
@@ -213,7 +211,6 @@ def main() -> None:
     artifact = run_ladder(
         train_steps=int(os.environ.get("TP_BENCH_TRAIN_STEPS", "300")),
         n_requests=int(os.environ.get("TP_BENCH_REQUESTS", "24")),
-        decode_steps=int(os.environ.get("TP_BENCH_DECODE_STEPS", "4")),
         legs=legs,
         quantized_leg=os.environ.get(
             "TP_BENCH_QUANTIZED_COLLECTIVES", "1") != "0",

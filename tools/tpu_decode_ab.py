@@ -4,14 +4,13 @@ The 8B serving ladder (BENCH_SERVE_QWEN3_r03.json) measured ~140-157 ms
 TPOT at 16 slots. Weights-bound decode on paper is ~7 ms (4.5 GiB NF4 +
 1.2 GiB bf16 embed at ~800 GB/s), so something is ~18x off. Suspects:
 the fused NF4 Pallas kernel's thin-activation tiling at d4096, the f32
-151936-vocab lm_head, the scan overhead, and the per-dispatch host
-cost. This tool times a single 16-slot decode step through each path
-and shape variant and writes ``DECODE_AB_8B.json``:
+151936-vocab lm_head, and the per-dispatch host cost. This tool times a
+single 16-slot decode step through each path and shape variant and
+writes ``DECODE_AB_8B.json``:
 
 - fused kernels vs XLA dequant (``use_kernels``) — which serves better
   at this scale decides ``QuantizedModel``'s default
 - with vs without the lm_head (``return_hidden=True``) — the head's share
-- decode_steps=8 multi-step to amortize the dispatch out of the numbers
 
 Run: ``python tools/tpu_decode_ab.py`` (env ``AB_GEOM=small|8b``).
 """
@@ -41,7 +40,6 @@ GEOMS = {
                n_head=32, n_kv_head=8, head_dim=128),
 }
 SLOTS = 16
-STEPS = 8
 
 
 def timeit(fn, n=5):
@@ -96,23 +94,6 @@ def main() -> None:
         f = jax.jit(step)
         return lambda: f(qparams, cache0)
 
-    def multi_step(use_kernels):
-        def run(qp, cache, t):
-            def body(carry, _):
-                tt, c = carry
-                logits, c = fused_quant_apply(
-                    model, qp, tt, compute_dtype=jnp.bfloat16,
-                    use_kernels=use_kernels, cache=c)
-                nt = jnp.argmax(
-                    logits[:, -1].astype(jnp.float32), -1
-                )[:, None].astype(jnp.int32)
-                return (nt, c), nt
-            (_, cache), toks = jax.lax.scan(
-                body, (t, cache), None, length=STEPS)
-            return toks
-        f = jax.jit(run)
-        return lambda: f(qparams, cache0, tok)
-
     for name, fn in [
         ("fused_full", decode_path(True, head=True)),
         ("fused_no_head", decode_path(True, head=False)),
@@ -124,17 +105,6 @@ def main() -> None:
             results[name + "_ms"] = round(dt * 1e3, 1)
             print(f"{name}: {dt*1e3:.1f} ms/step", flush=True)
         except Exception as e:  # record, keep going
-            results[name + "_error"] = f"{type(e).__name__}: {str(e)[:200]}"
-            print(f"{name}: FAILED {e}", flush=True)
-        flush()
-
-    for name, k in [("fused_multi8", True), ("xla_multi8", False)]:
-        try:
-            dt = timeit(multi_step(k), n=3)
-            results[name + "_ms_per_tok"] = round(dt * 1e3 / STEPS, 1)
-            print(f"{name}: {dt*1e3/STEPS:.1f} ms/token "
-                  f"({dt*1e3:.0f} ms / {STEPS} steps)", flush=True)
-        except Exception as e:
             results[name + "_error"] = f"{type(e).__name__}: {str(e)[:200]}"
             print(f"{name}: FAILED {e}", flush=True)
         flush()
@@ -171,14 +141,6 @@ def main() -> None:
             results[name + "_error"] = f"{type(e).__name__}: {str(e)[:200]}"
             print(f"{name}: FAILED {e}", flush=True)
         flush()
-    try:
-        dt = timeit(multi_step(True), n=3)
-        results["int8_fused_multi8_ms_per_tok"] = round(dt * 1e3 / STEPS, 1)
-        print(f"int8_fused_multi8: {dt*1e3/STEPS:.1f} ms/token", flush=True)
-    except Exception as e:
-        results["int8_fused_multi8_error"] = (
-            f"{type(e).__name__}: {str(e)[:200]}")
-        print(f"int8_fused_multi8: FAILED {e}", flush=True)
 
     flush(final=True)
     print("wrote", OUT)

@@ -72,7 +72,6 @@ def main() -> None:
     model = GPT(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
-    decode_steps = int(os.environ.get("SERVE_DECODE_STEPS", "8"))
     mixed_step = os.environ.get("SERVE_MIXED_STEP", "1") != "0"
     # --kv-layout A/B leg (docs/paged-kv.md): SERVE_KV_LAYOUT=paged
     # serves the ladder off the block-table page pool (optionally
@@ -85,7 +84,7 @@ def main() -> None:
     # the prompt-lookup proposer, SERVE_SPEC=draft a SELF-speculative
     # draft — the target's first SERVE_SPEC_DRAFT_LAYERS blocks sharing
     # the stem/head. Either way the fused spec round verifies the k
-    # drafts inside the decode-steps block's dispatch; the dedicated
+    # drafts in one dispatch; the dedicated
     # cross-leg A/B artifact is tools/spec_ladder_bench.py
     # (BENCH_SPEC_LADDER_r07.json).
     spec_mode = os.environ.get("SERVE_SPEC", "off")
@@ -128,7 +127,7 @@ def main() -> None:
         model, params, max_slots=MAX_SLOTS, cache_len=1024,
         chunked_prefill=256, speculative_k=spec_k,
         draft_model=draft_model, draft_params=draft_params,
-        decode_steps=decode_steps, mixed_step=mixed_step,
+        mixed_step=mixed_step,
         kv_layout=kv_layout, mesh=mesh,
         kv_pool_tokens=(int(kv_pool_tokens) if kv_pool_tokens else None),
     )
@@ -136,7 +135,7 @@ def main() -> None:
     tok = ByteTokenizer()
     prompt_ids = [tok.encode(p) for p in PROMPTS]
     print(f"device {jax.devices()[0].device_kind} | slots {MAX_SLOTS} | "
-          f"decode_steps {decode_steps} | mixed_step {mixed_step} | "
+          f"mixed_step {mixed_step} | "
           f"spec {spec_mode} | tp {serve_tp}",
           flush=True)
 
@@ -235,7 +234,6 @@ def main() -> None:
         "model": "GPTLike 6L/512d bf16 (~36M params) — NOT 8B; see header",
         "engine": {"max_slots": MAX_SLOTS, "cache_len": 1024,
                    "chunked_prefill": 256,
-                   "decode_steps": decode_steps,
                    "mixed_step": mixed_step,
                    "speculation": {
                        "mode": spec_mode, "k": spec_k,
