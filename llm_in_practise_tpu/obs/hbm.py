@@ -19,6 +19,9 @@ account               booked by
                       state is bounded (a sliding-window layer's ring),
                       held by slot beside the pool: bytes a slot,
                       whatever the context
+``kv.recurrent_state``  the layers held by slot whose buffers are a STATE
+                      that is replaced every token (a selective scan's, a
+                      short convolution's tail), in a dtype of their own
 ``kv.contiguous``     contiguous-layout engine cache
 ``kv.draft``          the draft model's contiguous cache — the byte
                       equivalent of ``/debug/kv.draft_kv_reserved_tokens``

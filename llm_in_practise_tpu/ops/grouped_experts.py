@@ -49,7 +49,7 @@ def route(x: jax.Array, w_router: jax.Array, top_k: int, *,
           norm_topk: bool = True, scoring: str = "softmax",
           bias: jax.Array | None = None, n_group: int = 1,
           topk_group: int = 1, scale: float = 1.0,
-          ) -> tuple[jax.Array, jax.Array]:
+          norm_eps: float = 1e-20) -> tuple[jax.Array, jax.Array]:
     """Top-``top_k`` routing of ``x`` (N, hidden) over ``w_router``
     (hidden, n_experts), the scores in float32 (the matmul at ``highest``
     precision: a bf16 pass flips near-tied experts). Returns ``(ids (N,
@@ -64,7 +64,10 @@ def route(x: jax.Array, w_router: jax.Array, top_k: int, *,
     its two largest biased scores, and only the ``topk_group`` best
     groups stay eligible; the ``top_k`` largest biased scores among them
     are chosen; the weights are the UNBIASED ``s`` of the chosen,
-    renormalised when ``norm_topk``, times ``scale``."""
+    renormalised when ``norm_topk`` (``s_e / (sum of the chosen s +
+    norm_eps)``: ``1e-20`` as DeepSeek-V3 publishes it, ``1e-6`` in
+    ``models/lfm2_moe.py``'s family), times ``scale``. The softmax form
+    divides by the bare sum."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring == "softmax":
@@ -91,7 +94,7 @@ def route(x: jax.Array, w_router: jax.Array, top_k: int, *,
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if norm_topk:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + 1e-20)
+                             + norm_eps)
     return ids.astype(jnp.int32), weights * scale
 
 

@@ -829,26 +829,30 @@ class OpenAIServer:
                     "window_ring_rows_read",
                     "ring rows those layers read: every slot's whole "
                     "ring, idle slots too"))
-            if stats.page_block and not stats.census:
+            if stats.page_block and not stats.shared:
                 families.append((
                     "global_pages_read",
                     "pages of the global layers the decode steps' readers "
                     "copied where they lie (a model that declares "
                     "reads_pages: no gathered view): live rows' lengths "
                     "up to whole blocks x the layers that read them"))
-            if stats.census:
-                # recurrent layers and a cross-decoder
-                # (models/phi4flash.py), booked by the host
+            if stats.state:
+                # recurrent layers, under the state's own name (ssm: a
+                # selective scan's; conv: a short convolution's tail),
+                # booked by the host
+                families += [
+                    (stats.advanced_key,
+                     "decode-plane rows x steps whose recurrent state "
+                     "moved (the live rows)"),
+                    (stats.held_key,
+                     "decode-plane rows x steps the state was held for: "
+                     "every slot, idle and mid-prefill ones unchanged")]
+            if stats.shared:
+                # a cross-decoder (models/phi4flash.py), booked by the host
                 families += [
                     ("ssm_scan_tokens",
                      "real prompt positions the chunk rows' recurrent "
                      "layers scanned (a layer each)"),
-                    ("ssm_state_rows_advanced",
-                     "decode-plane rows x steps whose recurrent state "
-                     "moved (the live rows)"),
-                    ("ssm_state_rows_held",
-                     "decode-plane rows x steps the state was held for: "
-                     "every slot, idle and mid-prefill ones unchanged"),
                     ("self_decoder_rows",
                      "positions that passed the self-decoder: chunk "
                      "tokens and the decode plane's rows"),

@@ -461,7 +461,8 @@ class PagedKV:
         # it so every page-count figure converts to bytes the same way
         # everywhere (/debug/kv, /debug/hbm, session pins). The layers
         # held by slot are a second rate, bytes a SLOT, whatever the
-        # context: account kv.window_state.
+        # context: account kv.window_state for the rings,
+        # kv.recurrent_state for the states that are replaced.
         self.pool_bytes = sum(
             int(buf.nbytes) for layer, bounded in zip(kv, self.by_slot)
             if not bounded for buf in layer.values())
@@ -482,18 +483,25 @@ class PagedKV:
         self.row_bytes = self.pool_bytes // pool_rows if pool_rows else 0
         self.page_bytes = self.row_bytes * self.page_size
         self._ledger_open = True
-        get_ledger().book("kv_pool.pages", self.pool_bytes)
-        if self.slot_state_bytes:
-            get_ledger().book("kv.window_state", self.slot_state_bytes)
+        self._book_ledger(1)
+
+    def _book_ledger(self, sign: int) -> None:
+        """The pool, the rings held by slot, and the recurrent states held
+        by slot: three accounts, booked at build and freed at close."""
+        rings = self.slot_state_bytes - self.recurrent_state_bytes
+        for account, size in (("kv_pool.pages", self.pool_bytes),
+                              ("kv.window_state", rings),
+                              ("kv.recurrent_state",
+                               self.recurrent_state_bytes)):
+            if size:
+                get_ledger().book(account, sign * size)
 
     def close(self) -> None:
         """Release the pool's ledger claim (engine stop). Idempotent —
         a double stop must not double-free the account."""
         if self._ledger_open:
             self._ledger_open = False
-            get_ledger().book("kv_pool.pages", -self.pool_bytes)
-            if self.slot_state_bytes:
-                get_ledger().book("kv.window_state", -self.slot_state_bytes)
+            self._book_ledger(-1)
 
     def view_bytes(self, width: int, n_slots: int | None = None) -> int:
         """Device bytes of one transient gather view: ``n_slots`` rows
@@ -692,6 +700,7 @@ class PagedKV:
                 # not appended)
                 "recurrent_layers": sum(self.recurrent),
                 "recurrent_bytes": self.recurrent_state_bytes,
+                "recurrent_ledger_account": "kv.recurrent_state",
                 "ledger_account": "kv.window_state",
                 "slot_bytes": self.slot_bytes,
                 "bytes": self.slot_state_bytes,

@@ -39,6 +39,20 @@ the host by their tokens alone, for two kinds of model:
   reader copied: live rows' lengths up to whole blocks). All booked
   by the host from what it dispatched; such a model counts nothing on the
   device and routes nothing (``load`` is None).
+- a model whose RECURRENT layers hold a state of another name
+  (``models/lfm2_moe.py``: a short convolution's two-row tail, ``conv``;
+  routed besides, no ring, no cross-decoder): the decode-plane rows whose
+  state moved against those it was held for, ``conv_state_rows_advanced``
+  / ``conv_state_rows_held``, and the state's bytes, ``conv_state_bytes``.
+
+**Every name follows from the cache template** (what
+``paged_kv.cache_kinds`` reads), never from which model it is: the paged
+layers' pair of counters is ``latent_*`` where a paged row is ONE buffer (a
+latent, no ``k`` beside a ``v``) and ``global_*`` otherwise; the recurrent
+counters carry the state's own name (``ssm`` where a recurrent layer holds a
+buffer of that name, else its first buffer's); rings' counters exist where
+a ring does; a cross-decoder's where the model's ``census()`` names its
+``shared_readers``.
 
 Every model here sends its prompts through the chunk program, one
 chunk-wide trip a row: ``prefill_chunk_tokens`` (real prompt tokens)
@@ -120,12 +134,17 @@ class StepStats:
         self.ring_rows = engine.paged.ring_rows
         # one attended / view pair and one count of causal pairs, under
         # the family name that fits the model's cache: counter, step-record
-        # key and ``/metrics`` family (``llm_<name>_total``) are one name
-        family = "global" if self.ring_rows else "latent"
+        # key and ``/metrics`` family (``llm_<name>_total``) are one name.
+        # A paged row that is ONE buffer is a latent; rows of keys beside
+        # values are a global layer's
+        paged = engine.paged
+        latent = all(len(tails) == 1 for tails, bounded in zip(
+            paged.tails, paged.by_slot) if not bounded)
+        family = "latent" if latent else "global"
         self.attended_key = f"{family}_tokens_attended"
         self.view_key = f"{family}_view_tokens"
-        self.pairs_key = ("prefill_global_pairs" if self.ring_rows
-                          else "prefill_qk_pairs")
+        self.pairs_key = ("prefill_qk_pairs" if latent
+                          else "prefill_global_pairs")
         for key in (self.attended_key, self.view_key, self.pairs_key,
                     "prefill_keys_read", "prefill_chunk_tokens",
                     "prefill_chunk_capacity"):
@@ -135,11 +154,24 @@ class StepStats:
             self.window_ring_rows_read = 0
             self.prefill_band_pairs = 0
             self.prefill_band_keys_read = 0
-        # recurrent layers and a cross-decoder: the model's layer counts
-        self.census = core.census() if hasattr(core, "census") else None
-        if self.census:
-            for key in ("ssm_scan_tokens", "ssm_state_rows_advanced",
-                        "ssm_state_rows_held", "self_decoder_rows",
+        # recurrent layers: counted under the state's own name, read off
+        # the template (None: the model has none)
+        names = [key for layer, still in zip(paged.kv, paged.recurrent)
+                 if still for key in layer]
+        self.state = next((n for n in ("ssm", *names) if n in names), None)
+        if self.state:
+            self.advanced_key = f"{self.state}_state_rows_advanced"
+            self.held_key = f"{self.state}_state_rows_held"
+            setattr(self, self.advanced_key, 0)
+            setattr(self, self.held_key, 0)
+            setattr(self, f"{self.state}_state_bytes",
+                    paged.recurrent_state_bytes)
+        # a cross-decoder: how many layers read the ONE paged layer's view
+        # (the model's census; 0: none)
+        self.shared = (core.census()["shared_readers"]
+                       if hasattr(core, "census") else 0)
+        if self.shared:
+            for key in ("ssm_scan_tokens", "self_decoder_rows",
                         "cross_decoder_rows", "cross_decoder_prefill_rows",
                         "shared_kv_rows_attended"):
                 setattr(self, key, 0)
@@ -151,9 +183,8 @@ class StepStats:
         self.page_block = swa.paged_block_pages(
             engine.paged.pages_per_slot) if any(engine.paged.in_place) else 0
         self.block_rows = self.page_block * engine.paged.page_size
-        self.page_readers = (self.census["shared_readers"] if self.census
-                             else sum(engine.paged.in_place))
-        self.pages_key = ("shared_kv_pages_read" if self.census
+        self.page_readers = self.shared or sum(engine.paged.in_place)
+        self.pages_key = ("shared_kv_pages_read" if self.shared
                           else "global_pages_read")
         setattr(self, self.pages_key, 0)
         # reference comparisons (tests, the benchmark's check) set this
@@ -254,16 +285,17 @@ class StepStats:
             counts["window_rows_attended"] = sum(
                 min(length, self.ring_rows) for length in lens)
             counts["window_ring_rows_read"] = eng.max_slots * self.ring_rows
-        if self.census:
-            # every row of the plane passes both decoders; only the live
-            # rows' states move
+        if self.state:
+            # the state is held for every row of the plane; only the live
+            # rows' moves
+            counts[self.advanced_key] = len(lens)
+            counts[self.held_key] = eng.max_slots
+        if self.shared:
+            # every row of the plane passes both decoders
             counts.update(
-                ssm_state_rows_advanced=len(lens),
-                ssm_state_rows_held=eng.max_slots,
                 self_decoder_rows=eng.max_slots,
                 cross_decoder_rows=eng.max_slots,
-                shared_kv_rows_attended=sum(lens)
-                * self.census["shared_readers"])
+                shared_kv_rows_attended=sum(lens) * self.shared)
         self._count(**counts)
 
     def note_chunk_rows(self, entries, finishing: int = 0) -> None:
@@ -296,7 +328,7 @@ class StepStats:
                 band_keys += len(c) + min(st["done"], w - 1)
             counts.update(prefill_band_pairs=band,
                           prefill_band_keys_read=band_keys)
-        if self.census:
+        if self.shared:
             # a chunk's positions pass the self-decoder; the cross-decoder
             # sees one position of each prompt that ENDS here
             tokens = counts["prefill_chunk_tokens"]

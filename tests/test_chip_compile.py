@@ -432,3 +432,60 @@ def test_global_paged_decode_attention_compiles(chip, heads, kv_heads, dq,
     assert swa.GLOBAL_PAGED_KERNEL in text
     assert f"f32[16,{heads},{dv}]" in text
     assert swa.GLOBAL_KERNEL not in text
+
+
+def test_lfm2_moe_decode_step_compiles(chip, monkeypatch):
+    """The decode trunk of the LFM2-24B-A2B cut at its cell's shapes (32
+    slots of 512 pages of 16 rows; 10 layers at the published widths, 64
+    experts of 2,048 x 1,536 a routed layer, STACKED by run): both attention
+    layers read their pages where they lie (64-wide heads, 512-lane rows),
+    each routed layer's three grouped matmuls take the run's whole stack
+    (a 2,048 x 1,536 expert matrix is over ``TILE_ELEMENTS``: split tiles),
+    and no layer's experts are copied out of the stack."""
+    import json
+    import re
+
+    from llm_in_practise_tpu.models import layers
+    from llm_in_practise_tpu.models import lfm2_moe as lm
+    from llm_in_practise_tpu.ops import grouped_experts as ge
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    monkeypatch.setattr(ge, "interpret_default", lambda: False)
+    monkeypatch.setattr(swa, "interpret_default", lambda: False)
+    assert ge._tile(2048, 1536) == (2048, 768)
+    assert ge._tile(1536, 2048) == (1536, 1024)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-24b-a2b-bf16-serve.json")) as f:
+        cfg = lm.Lfm2MoeConfig.from_hf_config(json.load(f))
+    model = lm.Lfm2Moe(cfg)
+    slots, per_slot, page = 32, 512, 16
+    pages = slots * per_slot + 1
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def decode(params, ids, index, valid, table, pools, tails):
+        pools, tails = iter(pools), iter(tails)
+        cache = []
+        for kind, stats in zip(cfg.layer_types, model.step_stats(slots)):
+            entry = {"index": index, layers.VALID_KEY: valid, **stats}
+            if kind == lm.CONV:
+                entry["conv"] = next(tails)
+            else:
+                k, v = next(pools)
+                entry.update(k=k, v=v, **{layers.PAGES_KEY: table})
+            cache.append(entry)
+        return model.apply({"params": params}, ids, cache=cache)
+
+    params = _shapes(jax.eval_shape(lambda: lm.random_params(cfg, 0)), chip)
+    text = _compile(
+        decode, params, arg((slots, 1), jnp.int32), arg((slots,), jnp.int32),
+        arg((slots,), jnp.int32), arg((slots, per_slot), jnp.int32),
+        [(arg((pages, page, 512)), arg((pages, page, 512)))] * 2,
+        [arg((slots, 2, 2048))] * 8)
+    # 2 paged readers + 3 grouped matmuls x (2 attention layers + 2 traced
+    # bodies of three conv layers)
+    assert text.count("tpu_custom_call") == 14
+    assert swa.GLOBAL_PAGED_KERNEL in text
+    assert not re.search(r" copy\([^\n]*\[(?:64|192),(?:2048|1536),", text)

@@ -225,6 +225,7 @@ def test_metrics_and_debug_name_both_stores(served):
     assert snap["slot_state"] == {
         "layers": 2, "paged_layers": 2,
         "recurrent_layers": 0, "recurrent_bytes": 0,
+        "recurrent_ledger_account": "kv.recurrent_state",
         "ledger_account": "kv.window_state",
         "slot_bytes": 2 * 8 * 4 * (24 + 16) * 4,
         "bytes": 4 * 2 * 8 * 4 * (24 + 16) * 4,

@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
 """The control behind the limits of ``benchmark/reference/mimo_v2.py``
-(PR 39), ``benchmark/reference/afmoe.py`` (PR 44) and
-``benchmark/reference/phi4flash.py`` (PR 47): a window / global cell's own
+(PR 39), ``benchmark/reference/afmoe.py`` (PR 44),
+``benchmark/reference/phi4flash.py`` (PR 47) and
+``benchmark/reference/lfm2_moe.py`` (PR 52): a cell's own
 ``check`` (the probes, the reference, the limits:
 ``benchmark/runners/serve_hybrid_cell.py`` for
 ``mimo-v2.5.agent-context``, ``serve_window_ring_cell.py`` for ``--cell
 trinity-large.mixed-lengths``, ``serve_recurrent_cell.py`` for ``--cell
-phi-4-mini-flash.grounded-reasoning``) on a server built as the cell builds
+phi-4-mini-flash.grounded-reasoning``, ``serve_conv_moe_cell.py`` for
+``--cell lfm2-24b-a2b.chat-concurrent``) on a server built as the cell builds
 it, but for ONE store kept in the nearest precision below the
 configuration's: the K/V pages and the window rings (``--kv-cache-dtype
 fp8``, e4m3), or, for a cell with recurrent layers, their state
@@ -18,12 +20,21 @@ router and logits are as the configuration has them.
     chiprun -- python tools/swa_check_control.py --cell trinity-large.mixed-lengths --seeds 4413000001
     chiprun -- python tools/swa_check_control.py --cell phi-4-mini-flash.grounded-reasoning --ssm-state-dtype bfloat16 --kv-cache-dtype bfloat16 --seeds 4713000001
     chiprun -- python tools/swa_check_control.py --cell phi-4-mini-flash.grounded-reasoning --kv-cache-dtype bfloat16 --expect ok --seeds 4713100001 --probe-seeds 8 --faults
+    chiprun -- python tools/swa_check_control.py --cell lfm2-24b-a2b.chat-concurrent --kv-cache-dtype bfloat16 --expect ok --seeds 5213100001 --probe-seeds 3 --faults
 
-``--probe-seeds n`` (the recurrent cell): n sets of probes at seeds of
-their own on each server (one build, n comparisons). ``--faults`` (the
-recurrent cell, a SOUND server): after a seed's first comparison the same
-observation is judged against the reference with ONE form left out at a
-time (``benchmark/reference/phi4flash.py``'s forms as data: the learned
+``--probe-seeds n`` (the cells with ``probe`` and ``judge``): n sets of
+probes at seeds of their own on each server (one build, n comparisons).
+For ``lfm2-24b-a2b.chat-concurrent`` the server is WARMED as the cell warms
+it before the first probes (thirty-two requests at once on programs not yet
+built did not come back in twenty minutes on the chip: PR 52), its lower
+precision is the REFERENCE's (``faults()`` holds the K/V rows in e4m3 beside
+the three planted faults: no second server to build and compile), every
+set of probes is judged against every fault, and two readings that are held
+to nothing follow each set: a bfloat16 router, and the reference left to
+its own routed sets at the judged positions too (what the routing's flips
+cost there). ``--faults`` (a SOUND server): after a seed's first comparison
+the same observation is judged against the reference with ONE form left out
+at a time (``benchmark/reference/phi4flash.py``'s forms as data: the learned
 lambda, the sub-norm, the GMU's memory, the memory of the token before,
 the D skip, the window, a convolution tail dropped at a chunk's boundary)
 and with the two probes' slots crossed: each must come out NOT ok, and its
@@ -61,6 +72,9 @@ CELLS = {
         cell.REHEARSAL_PROBES if rehearse else cell.PROBES),
     "phi-4-mini-flash.grounded-reasoning": (
         "serve_recurrent_cell", lambda cell, workload, rehearse:
+        cell.REHEARSAL_PROBES if rehearse else cell.PROBES),
+    "lfm2-24b-a2b.chat-concurrent": (
+        "serve_conv_moe_cell", lambda cell, workload, rehearse:
         cell.REHEARSAL_PROBES if rehearse else cell.PROBES),
 }
 
@@ -120,9 +134,10 @@ def main() -> int:
     args[args.index("--kv-cache-dtype") + 1] = opts.kv_cache_dtype
     config = dict(config, layout=dict(config["layout"], serve_args=args))
 
-    recurrent = runner == "serve_recurrent_cell"
+    conv_moe = runner == "serve_conv_moe_cell"
+    recurrent = runner == "serve_recurrent_cell" or conv_moe
     if (opts.faults or opts.probe_seeds > 1) and not recurrent:
-        ap.error("--faults / --probe-seeds: the recurrent cell's")
+        ap.error("--faults / --probe-seeds: a cell with probe and judge")
     probes = probes_of(cell, workload, opts.rehearse)
     extra = ((cell.REHEARSAL_FILLERS,) if recurrent and opts.rehearse
              else ())
@@ -146,6 +161,11 @@ def main() -> int:
                  else {"ssm_state_dtype": opts.ssm_state_dtype})
         sv = cell.build(config, seed, not opts.rehearse, **lower)
         try:
+            if conv_moe:
+                from benchmark import serving
+
+                print(json.dumps({"warm_up": serving.warm(
+                    sv, workload, seed)}), flush=True)
             for i in range(opts.probe_seeds):
                 at = seed + 1000 * i
                 if not recurrent:
@@ -157,14 +177,27 @@ def main() -> int:
                 out = cell.judge(sv, seen, slack=slack)
                 report(at, out, probe_s=t1 - t0,
                        judge_s=time.monotonic() - t1)
-                if opts.faults and i == 0 and "why" not in seen:
-                    for form, value in cell.faults(sv).items():
-                        report(at, cell.judge(
+                if not opts.faults or "why" in seen or (i and not conv_moe):
+                    continue
+                for form, value in cell.faults(sv).items():
+                    report(at, cell.judge(
+                        sv, seen, dict(sv.geom, **{form: value}),
+                        slack=slack), fault=f"{form}={value}")
+                report(at, cell.judge(sv, seen, crossed=True, slack=slack),
+                       fault="slots_crossed")
+                if not conv_moe:
+                    continue
+                # held to nothing (module docstring)
+                for reading, out in (
+                        *((f"{form}={value}", cell.judge(
                             sv, seen, dict(sv.geom, **{form: value}),
-                            slack=slack), fault=f"{form}={value}")
-                    report(at, cell.judge(sv, seen, crossed=True,
-                                          slack=slack),
-                           fault="slots_crossed")
+                            slack=slack))
+                          for form, value in cell.READINGS.items()),
+                        ("routes_free", cell.judge(
+                            sv, seen, slack=slack, forced=False))):
+                    print(json.dumps({"cell": opts.cell, "seed": at,
+                                      "reading": reading, **out}),
+                          flush=True)
         finally:
             sv.close()
         del sv
