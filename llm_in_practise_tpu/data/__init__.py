@@ -17,6 +17,7 @@ from llm_in_practise_tpu.data.sft import (
     self_cognition_records,
     tokenize_for_sft,
 )
+from llm_in_practise_tpu.data.stream_decode import StreamDecoder
 
 __all__ = [
     "BPETokenizer",
@@ -24,6 +25,7 @@ __all__ = [
     "HFTokenizerAdapter",
     "IGNORE_INDEX",
     "SFTBatch",
+    "StreamDecoder",
     "batch_iterator",
     "block_chunk",
     "build_sft_dataset",

@@ -42,6 +42,7 @@ from http.server import ThreadingHTTPServer
 import numpy as np
 
 from llm_in_practise_tpu.data.sft import IM_START, render_chatml
+from llm_in_practise_tpu.data.stream_decode import StreamDecoder
 from llm_in_practise_tpu.infer.sampling import SAMPLER_TIERS
 from llm_in_practise_tpu.obs.hbm import (
     get_ledger,
@@ -128,6 +129,13 @@ class OpenAIServer:
         # lock-free (monotone counters — the spec_* convention)
         self._structured_counts = {"json_object": 0, "json_schema": 0,
                                    "tool_call": 0}  # guarded-by: _structured_lock
+        # the SSE handlers' incremental detokenisation
+        # (data/stream_decode.py): llm_stream_detokenize_seconds_total
+        # and llm_stream_token_events_total{text=…}, per-stream sums a
+        # handler books at its stream's end (scrapes read lock-free)
+        self._stream_lock = threading.Lock()
+        self._stream_detokenize_s = 0.0  # guarded-by: _stream_lock
+        self._stream_token_events = {"yes": 0, "held": 0}  # guarded-by: _stream_lock
         # unified metrics registry (obs/registry.py): scrape-time
         # callbacks over the live engine/meter counters — the ONE
         # exposition renderer, replacing the hand-formatted text block
@@ -176,6 +184,15 @@ class OpenAIServer:
         with self._structured_lock:
             self._structured_counts[kind] = (
                 self._structured_counts.get(kind, 0) + 1)
+
+    def _note_stream_decode(self, seconds: float, n_text: int,
+                            n_held: int) -> None:
+        """A finished stream's detokeniser wall and token events (a
+        handler thread, once a stream)."""
+        with self._stream_lock:
+            self._stream_detokenize_s += seconds
+            self._stream_token_events["yes"] += n_text
+            self._stream_token_events["held"] += n_held
 
     def engine_for(self, model: str | None) -> InferenceEngine | None:
         if model in (None, "", self.model_name):
@@ -560,6 +577,12 @@ class OpenAIServer:
                     # engine.decode in the per-phase breakdown
                     flush_s = 0.0
                     n_chunks = 0
+                    # the detokeniser's wall and the token events by
+                    # outcome (text sent / held back), summed here and
+                    # booked once, at the stream's end
+                    dec = StreamDecoder(self.tokenizer)
+                    detok_s = 0.0
+                    n_text = n_held = 0
                     try:
                         t = time.monotonic()
                         yield schemas.chat_completion_chunk(
@@ -573,7 +596,6 @@ class OpenAIServer:
                                 now - handle.first_token_time)
                         flush_s += now - t
                         n_chunks += 1
-                        tokens, prev_text = [], ""
 
                         def stream_toks():
                             # mid-stream liveness: headers are out, so a dead
@@ -585,17 +607,34 @@ class OpenAIServer:
                                 yield tok
                                 tok = handle.next_item()
                         for tok in stream_toks():
-                            tokens.append(tok)
-                            text = self.tokenizer.decode(tokens)
-                            delta, prev_text = text[len(prev_text):], text
+                            # a short window through decode, not the
+                            # stream's whole list: an event costs the
+                            # same at any length, and carries whole
+                            # characters (data/stream_decode.py)
+                            t0 = time.monotonic()
+                            delta = dec.push(tok)
+                            t = time.monotonic()
+                            detok_s += t - t0
                             if delta:
-                                t = time.monotonic()
+                                n_text += 1
                                 yield schemas.chat_completion_chunk(
                                     req_id=req_id, model=req.model, delta=delta
                                 )
                                 flush_s += time.monotonic() - t
                                 n_chunks += 1
+                            else:
+                                n_held += 1
+                        # what the stream ended on without completing
+                        # (an open character, as decode renders it)
+                        t0 = time.monotonic()
+                        delta = dec.finish()
                         t = time.monotonic()
+                        detok_s += t - t0
+                        if delta:
+                            yield schemas.chat_completion_chunk(
+                                req_id=req_id, model=req.model, delta=delta
+                            )
+                            n_chunks += 1
                         yield schemas.chat_completion_chunk(
                             req_id=req_id, model=req.model, delta=None,
                             finish_reason=handle.finish_reason or "stop",
@@ -608,7 +647,8 @@ class OpenAIServer:
                         self.tracer.record(
                             "api.stream_flush", span,
                             duration_s=flush_s,
-                            chunks=n_chunks)
+                            chunks=n_chunks,
+                            detokenize_s=detok_s, held=n_held)
                         exc = sys.exc_info()[1]
                         # critical-path: the stream tail joins the
                         # request's /debug/requests breakdown and the
@@ -634,6 +674,7 @@ class OpenAIServer:
                                     + flush_s,
                             }
                         engine.stats.note_stream_flush(flush_s)
+                        self._note_stream_decode(detok_s, n_text, n_held)
                         # headers already went out as 200, but the span
                         # must say how the stream actually ended: a mid-
                         # flight engine death surfaces as an in-band
@@ -1253,6 +1294,23 @@ class OpenAIServer:
             lambda: [({"kind": k}, v) for k, v in sorted(sc.items())],
             "requests that carried a grammar constraint, by kind "
             "(json_object / json_schema / tool_call)")
+        # streaming (handle_chat's chunks()): what the detokeniser
+        # costs and how often a token's text is held back; booked at a
+        # stream's END, so a scrape mid-stream reads finished streams
+        ev = self._stream_token_events  # graftlint: disable=guarded-by — monotone counters, read lock-free at scrape (the spec_* convention)
+        reg.counter_func(
+            "llm_stream_detokenize_seconds_total",
+            lambda: self._stream_detokenize_s,  # graftlint: disable=guarded-by — monotone float, GIL-atomic read at scrape
+            "handler-thread seconds inside the SSE streams' incremental "
+            "detokeniser (StreamDecoder.push / finish), summed a stream "
+            "and booked at its end")
+        reg.counter_func(
+            "llm_stream_token_events_total",
+            lambda: [({"text": k}, v) for k, v in sorted(ev.items())],
+            "tokens handed to streams' detokenisers, by whether text "
+            "went out with them (yes) or was held back (held: an open "
+            "character, a skipped special token); booked at a "
+            "stream's end")
         reg.counter_func(
             "llm_grammar_mask_seconds_total",
             lambda: eng.grammar_mask_seconds_total,

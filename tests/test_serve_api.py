@@ -23,7 +23,7 @@ class ByteTokenizer:
 
 
 @pytest.fixture(scope="module")
-def server(request):
+def srv():
     import jax
 
     cfg = GPTConfig(vocab_size=256, seq_len=256, n_layer=2, n_head=2,
@@ -33,9 +33,15 @@ def server(request):
     engine = InferenceEngine(model, params, max_slots=2, cache_len=256,
                              cache_dtype=jnp.float32)
     srv = OpenAIServer(engine, ByteTokenizer(), model_name="tiny-test")
-    port = srv.serve(host="127.0.0.1", port=0, background=True)
-    yield ("127.0.0.1", port)
+    srv.addr = ("127.0.0.1",
+                srv.serve(host="127.0.0.1", port=0, background=True))
+    yield srv
     srv.shutdown()
+
+
+@pytest.fixture(scope="module")
+def server(srv):
+    return srv.addr
 
 
 def _post(addr, path, payload):
@@ -117,6 +123,105 @@ def test_streaming_sse(server):
     assert parsed[-1]["choices"][0]["finish_reason"] in ("stop", "length", "cache")
     text = "".join(p["choices"][0]["delta"].get("content", "") for p in parsed)
     assert isinstance(text, str)
+
+
+def _stream(addr, content, max_tokens):
+    """A streamed chat's ``content`` deltas, in order, and its finish
+    reason; the handler is done when the body has been read to its end."""
+    conn = http.client.HTTPConnection(*addr, timeout=60)
+    conn.request("POST", "/v1/chat/completions", json.dumps({
+        "model": "tiny-test",
+        "messages": [{"role": "user", "content": content}],
+        "max_tokens": max_tokens, "temperature": 0.0, "stream": True,
+    }), {"Content-Type": "application/json"})
+    resp = conn.getresponse()
+    assert resp.status == 200
+    raw = resp.read().decode()
+    conn.close()
+    events = [line[6:] for line in raw.split("\n")
+              if line.startswith("data: ")]
+    assert events[-1] == "[DONE]"
+    choices = [json.loads(e)["choices"][0] for e in events[:-1]]
+    deltas = [c["delta"]["content"] for c in choices
+              if "content" in c["delta"]]
+    return deltas, choices[-1]["finish_reason"]
+
+
+@pytest.mark.parametrize("content,max_tokens", [
+    ("stream please", 48), ("日本語 😀", 64), ("x", 1)])
+def test_streamed_content_equals_non_streamed(server, content, max_tokens):
+    """Greedy, the same prompt: the stream's deltas concatenate to the
+    non-streamed ``content`` (a seeded toy model's bytes are mostly NOT
+    characters, so ``decode`` puts U+FFFD and the handler has to hold
+    and release text exactly as ``decode`` of the whole list renders
+    it)."""
+    status, body = _post(server, "/v1/chat/completions", {
+        "model": "tiny-test",
+        "messages": [{"role": "user", "content": content}],
+        "max_tokens": max_tokens, "temperature": 0.0,
+    })
+    assert status == 200, body
+    want = json.loads(body)["choices"][0]
+    deltas, finish = _stream(server, content, max_tokens)
+    assert "".join(deltas) == want["message"]["content"]
+    assert finish == want["finish_reason"]
+    assert all(deltas)      # an event with content carries some
+
+
+def test_stream_sends_a_split_character_once_and_whole(srv, monkeypatch):
+    """The ids of a stream are scripted (one byte a token: two, three and
+    four tokens a character): each character is ONE event, sent with the
+    token that completes it, and a stream that ends inside a character
+    sends ``decode``'s rendering of the tail before the finish event."""
+    from llm_in_practise_tpu.serve.engine import _FINISH
+
+    text = "é日😀!"
+    script = list(text.encode()) + list("本".encode()[:2])
+    submit = srv.engine.submit
+
+    def scripted_submit(*args, **kwargs):
+        handle = submit(*args, **kwargs)
+        next_item, ids = handle.next_item, iter(script)
+
+        def scripted_next():
+            item = next_item()
+            return item if item is _FINISH else next(ids)
+        handle.next_item = scripted_next
+        return handle
+
+    monkeypatch.setattr(srv.engine, "submit", scripted_submit)
+    deltas, _ = _stream(srv.addr, "anything", len(script))
+    assert deltas == ["é", "日", "😀", "!", "\ufffd"]
+    assert "".join(deltas) == srv.tokenizer.decode(script)
+
+
+def test_stream_decode_metrics(server):
+    """The two families of the streams' detokeniser: token events (text
+    sent or held back) sum to the tokens the streams generated, booked at
+    a stream's end."""
+    from promparse import parse_exposition
+
+    def scrape():
+        fams = parse_exposition(_get(server, "/metrics")[1].decode())
+        events = fams["llm_stream_token_events_total"].samples
+        assert {dict(k[1])["text"] for k in events} == {"yes", "held"}
+        (detok,) = fams["llm_stream_detokenize_seconds_total"].samples.values()
+        (tokens,) = fams["llm_tokens_generated_total"].samples.values()
+        return sum(events.values()), detok, tokens
+
+    events0, detok0, tokens0 = scrape()
+    _stream(server, "count my tokens", 24)
+    _stream(server, "and mine", 7)
+    events1, detok1, tokens1 = scrape()
+    assert tokens1 - tokens0 > 0
+    assert events1 - events0 == tokens1 - tokens0
+    assert detok1 > detok0
+    # the same two readings a stream, on its api.stream_flush span
+    traces = json.loads(_get(server, "/debug/traces")[1])["traces"]
+    flushes = [s["attrs"] for t in traces for s in t["spans"]
+               if s["name"] == "api.stream_flush"]
+    assert flushes and all(
+        a["detokenize_s"] > 0 and a["held"] >= 0 for a in flushes)
 
 
 def test_metrics_exposition(server):
