@@ -22,6 +22,23 @@ length of 1 against a cache runs the absorbed form on the latent rows
 (``ops/mla_attention.py::decode_attention``), anything longer the naive
 form over key blocks (``prefill_attention``).
 
+**The cache contract.** A layer's ``cache`` is ``{"ckv", "index"}``. As
+the engines' contiguous cache and as a paged program's gathered view,
+``ckv`` is ``(B, W, 576)`` rows from position 0: the layer writes its new
+rows at ``index`` (``layers.cache_update``) and the program scatters them
+back to the pool. The serving engine's DECODE programs (one token a row)
+give a model that declares ``reads_pages`` no view: ``ckv`` is then the
+pool as ``serve/paged_kv.py`` stores it by pages, ``(pages, page rows, 576
+up to whole lanes)``, beside each row's block table under
+``layers.PAGES_KEY`` and ``layers.VALID_KEY`` (1: the row decodes; 0: idle
+or mid-prefill). THE LAYER writes the row into its page
+(``layers.page_row_write``; a row that is not valid writes into the trash
+page) and attends the pages where they lie, to each row's true length
+(``mla_attention.paged_decode_attention``); the pool comes back under
+``ckv`` and the engine writes nothing after it. The model's ``__call__``
+makes the rows' work list once (``swa_attention.paged_rows``) for all its
+layers.
+
 **RoPE lanes: interleaved.** Rope dimension pair ``(2i, 2i+1)`` rotates
 by frequency ``f_i``. The published modeling code de-interleaves ``q_pe``
 / ``k_pe`` (``view(.., d/2, 2).transpose``) and then rotates halves,
@@ -72,6 +89,7 @@ from llm_in_practise_tpu.models import layers
 from llm_in_practise_tpu.models.qwen3 import RMSNorm
 from llm_in_practise_tpu.ops import mla_attention
 from llm_in_practise_tpu.ops import rope as rope_ops
+from llm_in_practise_tpu.ops import swa_attention as swa
 from llm_in_practise_tpu.ops.grouped_experts import (
     grouped_expert_ffn,
     held_counts,
@@ -80,6 +98,7 @@ from llm_in_practise_tpu.ops.grouped_experts import (
 
 Cache = dict[str, Any]
 LOAD_KEY, ROUTE_KEY = layers.LOAD_KEY, layers.ROUTE_KEY
+VALID_KEY, PAGES_KEY = layers.VALID_KEY, layers.PAGES_KEY
 
 
 @dataclasses.dataclass(frozen=True)
@@ -254,7 +273,8 @@ class MLAttention(nn.Module):
     cfg: DeepSeekV3Config
 
     @nn.compact
-    def __call__(self, x, tables, *, cache=None, positions=None):
+    def __call__(self, x, tables, *, cache=None, positions=None,
+                 pages=None):
         cfg = self.cfg
         b, l, _ = x.shape
         h, dn, dr = cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -277,18 +297,33 @@ class MLAttention(nn.Module):
         q_nope, q_rope = q[..., :dn], rot(q[..., dn:])
         k_rope = rot(kv[..., None, rank:])[:, :, 0]
         row = jnp.concatenate([c_kv.astype(compute), k_rope], axis=-1)
-        start = 0
-        latent = row
-        if cache is not None:
+        if cache is not None and PAGES_KEY in cache:
+            # the pool's PAGES (a decode program, l == 1): the new row
+            # goes into its page, and the query walks the row's pages to
+            # its true length (``pages``: the model's one
+            # ``swa.paged_rows`` a program); a row that is not live writes
+            # into the trash page and reads nothing
             start = cache["index"]
-            stored = layers.cache_update(cache["ckv"], row, start)
-            cache = dict(cache, ckv=stored, index=start + l)
-            latent = stored.astype(compute)
-        attend = (mla_attention.decode_attention
-                  if l == 1 and cache is not None
-                  else mla_attention.prefill_attention)
-        out = attend(q_nope, q_rope, latent, start, w_kvb, rank=rank,
-                     scale=cfg.attention_scale)
+            pool = layers.page_row_write(
+                cache["ckv"], pages["table"], start, cache[VALID_KEY],
+                row[:, 0])
+            out = mla_attention.paged_decode_attention(
+                q_nope, q_rope, pool, w_kvb, rank=rank,
+                scale=cfg.attention_scale, **pages)
+            cache = dict(cache, ckv=pool, index=start + l)
+        else:
+            start = 0
+            latent = row
+            if cache is not None:
+                start = cache["index"]
+                stored = layers.cache_update(cache["ckv"], row, start)
+                cache = dict(cache, ckv=stored, index=start + l)
+                latent = stored.astype(compute)
+            attend = (mla_attention.decode_attention
+                      if l == 1 and cache is not None
+                      else mla_attention.prefill_attention)
+            out = attend(q_nope, q_rope, latent, start, w_kvb, rank=rank,
+                         scale=cfg.attention_scale)
         return _dense(cfg, cfg.hidden_size, "o_proj")(
             out.reshape(b, l, h * dv)), cache
 
@@ -346,11 +381,12 @@ class DeepSeekV3Block(nn.Module):
     routed: bool
 
     @nn.compact
-    def __call__(self, x, tables, *, cache=None, positions=None):
+    def __call__(self, x, tables, *, cache=None, positions=None,
+                 pages=None):
         cfg = self.cfg
         a, cache = MLAttention(cfg, name="attn")(
             RMSNorm(cfg.rms_norm_eps, name="ln1")(x), tables, cache=cache,
-            positions=positions)
+            positions=positions, pages=pages)
         x = x + a
         v = RMSNorm(cfg.rms_norm_eps, name="ln2")(x)
         if not self.routed:
@@ -396,11 +432,16 @@ class DeepSeekV3(nn.Module):
         x = embed(idx).astype(compute)
         tables = rope_tables(cfg)
         new_caches = [] if cache is not None else None
+        # a decode program whose layers read their pages in place: ONE
+        # walk of the rows' pages (they all read the same rows)
+        pages = next((swa.paged_rows(c[PAGES_KEY], c["index"], c[VALID_KEY],
+                                     c["ckv"].shape[1])
+                      for c in cache or () if PAGES_KEY in c), None)
         for i in range(cfg.n_layer):
             x, layer_cache = DeepSeekV3Block(
                 cfg, cfg.is_routed(i), name=f"block_{i}")(
                 x, tables, cache=cache[i] if cache is not None else None,
-                positions=positions)
+                positions=positions, pages=pages)
             if new_caches is not None:
                 new_caches.append(layer_cache)
         x = RMSNorm(cfg.rms_norm_eps, name="ln_f")(x)
@@ -431,6 +472,11 @@ class DeepSeekV3(nn.Module):
     @property
     def cache_slot_axis(self) -> int:
         return 0
+
+    #: a decode program hands every layer the latent pool's pages as they
+    #: are stored and each row's block table (``layers.PAGES_KEY``), not a
+    #: gathered view
+    reads_pages = True
 
     def step_stats(self, rows: int) -> list[dict]:
         """Zeroed per-layer statistics entries for a serving program's

@@ -3,15 +3,24 @@
 A token's cache row in a layer is ``[c_kv (rank) | k_rope (rope_dim)]``:
 the RMS-normed compressed key/value latent and ONE rotated rope key shared
 by every head. ``w_kvb`` (rank, heads, nope_dim + v_dim) decompresses a
-latent into each head's ``[k_nope | v]``. Two forms of the same
-attention, equal in exact arithmetic, one a phase:
+latent into each head's ``[k_nope | v]``. Three forms of the same
+attention, equal in exact arithmetic, by the program that runs them:
 
-- :func:`decode_attention`, the ABSORBED form (query length 1). ``W^K``
-  is folded into the query (``q~ = (W^K_h)^T q_nope``, rank wide) and
-  ``W^V`` applied after the sum, so attention runs on the latent rows as
-  the cache holds them: every head reads the same ``(keys, rank +
-  rope_dim)`` view once for the scores and once for the sum, and no key
-  or value is ever decompressed. A decode step is bound by that read.
+- :func:`decode_attention`, the ABSORBED form (query length 1) over a
+  gathered VIEW: a decode against a contiguous cache, or against a paged
+  one whose model does not read its pages (tests, the benchmark's
+  reference). ``W^K`` is folded into the query (``q~ = (W^K_h)^T q_nope``,
+  rank wide) and ``W^V`` applied after the sum, so attention runs on the
+  latent rows as the cache holds them: every head reads the same ``(keys,
+  rank + rope_dim)`` view once for the scores and once for the sum, and no
+  key or value is ever decompressed. A decode step is bound by that read.
+- :func:`paged_decode_attention`, the same absorbed form with NO view (the
+  serving engine's decode program and a mixed step's decode half, for a
+  model that declares ``reads_pages``): ``ops/swa_attention.py``'s paged
+  reader walks the latent pool's pages where they lie, to each row's true
+  length. A latent row is key AND value at once, so the reader gets ONE
+  pool and copies each page once: all heads score the row's whole width
+  and sum its first ``rank`` columns.
 - :func:`prefill_attention`, the NAIVE form over key blocks (query length
   of a chunk or a prompt). ``KEY_BLOCK`` keys at a time are decompressed
   to per-head keys (``[k_nope | k_rope]``, 192 wide at the published
@@ -46,10 +55,21 @@ from llm_in_practise_tpu.ops.attention import interpret_default
 DECODE_SCOPE = "mla_decode_attention"
 PREFILL_SCOPE = "mla_prefill_attention"
 KERNEL_NAME = "mla_prefill_flash"   # the kernel's name on the device plane
+PAGED_KERNEL = "mla_paged_decode"   # the in-place decode reader's
 NEG_INF = -1e30
 KEY_BLOCK = 4096        # keys decompressed at a time by the prefill form
 BLOCK_Q, BLOCK_K = 1024, 1024   # the kernel's tiles (tools/mla_bakeoff.py)
 _LANE, _SUBLANE = 128, 8
+
+
+def _absorb(q_nope, q_rope, w_kvb):
+    """``W^K`` folded into one query a row: ``([q~ | q_rope]`` (B, H, rank
+    + dr), ``W^V`` (rank, H, dv)) of ``q_nope`` (B, 1, H, dn), ``q_rope``
+    (B, 1, H, dr) and ``w_kvb`` (rank, H, dn + dv)."""
+    dn = q_nope.shape[-1]
+    q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_kvb[..., :dn])
+    return (jnp.concatenate([q_lat, q_rope[:, 0].astype(q_lat.dtype)],
+                            axis=-1), w_kvb[..., dn:])
 
 
 def decode_attention(q_nope, q_rope, latent, index, w_kvb, *, rank: int,
@@ -59,13 +79,9 @@ def decode_attention(q_nope, q_rope, latent, index, w_kvb, *, rank: int,
     the query's own row already written at ``index`` (scalar or (B,):
     the query's absolute position; keys beyond it are not attended),
     ``w_kvb`` (rank, H, dn + dv). Returns (B, 1, H, dv)."""
-    dn = q_nope.shape[-1]
     b, w, _ = latent.shape
     with jax.named_scope(DECODE_SCOPE):
-        w_k, w_v = w_kvb[..., :dn], w_kvb[..., dn:]
-        q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_k)
-        q_all = jnp.concatenate([q_lat, q_rope[:, 0].astype(q_lat.dtype)],
-                                axis=-1)
+        q_all, w_v = _absorb(q_nope, q_rope, w_kvb)
         s = jnp.einsum("bhc,bkc->bhk", q_all, latent,
                        preferred_element_type=jnp.float32) * scale
         pos = jnp.broadcast_to(jnp.asarray(index), (b,))
@@ -77,6 +93,32 @@ def decode_attention(q_nope, q_rope, latent, index, w_kvb, *, rank: int,
         # dropped after
         o_lat = jnp.einsum("bhk,bkc->bhc", p, latent)[..., :rank]
         out = jnp.einsum("bhc,chd->bhd", o_lat, w_v)
+    return out[:, None]
+
+
+def paged_decode_attention(q_nope, q_rope, pool, w_kvb, *, rank: int,
+                           scale: float, table, lengths, work=None,
+                           pages_per_block: int | None = None,
+                           interpret: bool | None = None):
+    """:func:`decode_attention` over the latent pool's PAGES where they
+    lie, to each row's true length. ``pool`` (pages, page rows, rank + dr
+    up to whole lanes) as ``serve/paged_kv.py`` stores it by pages, the
+    queries' own rows already written; ``table`` (B, pages a slot),
+    ``lengths`` (B,) and ``work`` as ``swa_attention.paged_rows`` gives
+    them (a row of length 0 reads nothing and gets zeros). Returns (B, 1,
+    H, dv)."""
+    # here and not at the top: that module takes ``join`` from this one
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    with jax.named_scope(DECODE_SCOPE):
+        q_all, w_v = _absorb(q_nope, q_rope, w_kvb)
+        # the latent is the one K/V head of every query head: its whole
+        # row the key, its first ``rank`` columns the value
+        o_lat = swa._paged_attention(
+            (q_all[:, None],), (pool,), None, table, lengths, scale=scale,
+            kv_heads=1, v_dim=rank, name=PAGED_KERNEL, work=work,
+            pages_per_block=pages_per_block, interpret=interpret)
+        out = jnp.einsum("bhc,chd->bhd", o_lat.astype(q_all.dtype), w_v)
     return out[:, None]
 
 

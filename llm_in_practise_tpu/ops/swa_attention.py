@@ -573,20 +573,24 @@ def paged_rows(table, start, valid, page_size: int) -> dict:
 
 
 def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
-                         q_ref, *refs, scale, n, heads, group, kv_heads):
+                         q_ref, *refs, scale, n, heads, group, kv_heads,
+                         pools):
     """ONE invocation walks the whole list. ``n`` softmaxes a query head,
     each over a key pool of its own, all over one value pool. ``q_ref`` (B,
     n, Hp, C): every head's query for each softmax (``Hp``: the ``heads``
     up to whole sublane tiles), zero outside its K/V head's columns of a
-    key row; ``table_ref`` (B * pages a slot,), flat; ``refs``: the ``n``
-    key pools and the value pool as they lie, ``o_ref`` (B, heads, n * Dv)
-    float32, a head's ``n`` results side by side, then a two-block buffer
-    a pool, the copies' semaphores, and the running maximum, denominator
-    and sum. The softmaxes' scores stack to ``(n Hp, rows)``: the value
-    block is multiplied once."""
-    hbm, o_ref = refs[:n + 1], refs[n + 1]
-    bufs = refs[n + 2:2 * n + 3]
-    sems, m_ref, l_ref, acc_ref = refs[2 * n + 3:]
+    key row; ``table_ref`` (B * pages a slot,), flat; ``refs``: the
+    ``pools`` pools as they lie, the ``n`` key pools and then the value
+    pool (``pools`` = ``n + 1``) or the key pools alone (``pools`` = ``n``:
+    the LAST key pool's rows are the values too, their first columns, and
+    are copied once), ``o_ref`` (B, heads, n * Dv) float32, a head's ``n``
+    results side by side, then a two-block buffer a pool, the copies'
+    semaphores, and the running maximum, denominator and sum. The
+    softmaxes' scores stack to ``(n Hp, rows)``: the value block is
+    multiplied once."""
+    hbm, o_ref = refs[:pools], refs[pools]
+    bufs = refs[pools + 1:2 * pools + 1]
+    sems, m_ref, l_ref, acc_ref = refs[2 * pools + 1:]
     _, ppb, page, _ = bufs[0].shape
     rows = ppb * page
     hp, dv = q_ref.shape[2], o_ref.shape[-1] // n
@@ -609,7 +613,7 @@ def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
 
         def page_copies(p, carry):
             for at in (p * unroll + d for d in range(unroll)):
-                for pool in range(n + 1):
+                for pool in range(pools):
                     copy(slot, at, table_ref[base + at], pool).start()
             return carry
 
@@ -619,7 +623,7 @@ def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
     # counts bytes, and this descriptor (any pages: it is never started)
     # stands for a whole block of them
     def wait(slot):
-        for pool in range(n + 1):
+        for pool in range(pools):
             pltpu.make_async_copy(hbm[pool].at[pl.ds(0, ppb)],
                                   bufs[pool].at[slot],
                                   sems.at[slot, pool]).wait()
@@ -667,7 +671,7 @@ def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
         m_ref[:, 0:1] = m_new
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
             p.astype(q.dtype),
-            bufs[n][slot].reshape(rows, -1).astype(q.dtype),
+            bufs[pools - 1][slot].reshape(rows, -1).astype(q.dtype),
             preferred_element_type=jnp.float32)
 
         @pl.when((j + 1) * rows >= length)
@@ -696,12 +700,15 @@ def _paged_decode_kernel(row_ref, blk_ref, total_ref, len_ref, table_ref,
 def _paged_attention(qs, ks, v, table, lengths, *, scale, kv_heads, v_dim,
                      name, work=None, pages_per_block=None, interpret=None):
     """``len(qs)`` softmaxes a query head over the pool's pages: ``(B,
-    H, len(qs) * v_dim)`` float32, the kernel's own result."""
+    H, len(qs) * v_dim)`` float32, the kernel's own result. ``v`` None: the
+    last key pool's rows are the values too (their first ``kv_heads *
+    v_dim`` columns), and the kernel copies that pool once."""
     b, _, h, dq = qs[0].shape
     dtype = qs[0].dtype
     n, hk, dv = len(qs), kv_heads, v_dim
     page, ck = ks[0].shape[1:]
-    cv = v.shape[2]
+    pools = ks if v is None else (*ks, v)
+    cv = pools[-1].shape[2]
     ppb = paged_block_pages(table.shape[1], pages_per_block)
     if work is None:
         work = paged_decode_work(lengths, page, table.shape[1], ppb)
@@ -722,17 +729,18 @@ def _paged_attention(qs, ks, v, table, lengths, *, scale, kv_heads, v_dim,
             shape, lambda i, *_: (0,) * len(shape))
         return pl.pallas_call(
             functools.partial(_paged_decode_kernel, scale=scale, n=n,
-                              heads=h, group=h // hk, kv_heads=hk),
+                              heads=h, group=h // hk, kv_heads=hk,
+                              pools=len(pools)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=(1,),
                 in_specs=[whole(wide.shape)]
-                + [pl.BlockSpec(memory_space=pl.ANY)] * (n + 1),
+                + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
                 out_specs=whole((b, h, n * dv)),
                 scratch_shapes=[
-                    *(pltpu.VMEM((2, ppb, page, ck), k.dtype) for k in ks),
-                    pltpu.VMEM((2, ppb, page, cv), v.dtype),
-                    pltpu.SemaphoreType.DMA((2, n + 1)),
+                    *(pltpu.VMEM((2, ppb, page, pool.shape[2]), pool.dtype)
+                      for pool in pools),
+                    pltpu.SemaphoreType.DMA((2, len(pools))),
                     pltpu.VMEM((n * hp, _LANE), jnp.float32),
                     pltpu.VMEM((n * hp, _LANE), jnp.float32),
                     pltpu.VMEM((n * hp, cv), jnp.float32),
@@ -744,7 +752,7 @@ def _paged_attention(qs, ks, v, table, lengths, *, scale, kv_heads, v_dim,
                 vmem_limit_bytes=_PAGED_VMEM),
             interpret=interpret_default() if interpret is None else interpret,
             name=name,
-        )(*work, lengths.astype(jnp.int32), table.reshape(-1), wide, *ks, v)
+        )(*work, lengths.astype(jnp.int32), table.reshape(-1), wide, *pools)
 
 
 def paged_decode_attention(q, k, v, table, lengths, *, scale: float,

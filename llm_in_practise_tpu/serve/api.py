@@ -873,7 +873,8 @@ class OpenAIServer:
             if stats.page_block and not stats.shared:
                 families.append((
                     "global_pages_read",
-                    "pages of the global layers the decode steps' readers "
+                    "pages of the paged layers (a model's global layers, a "
+                    "latent model's every layer) the decode steps' readers "
                     "copied where they lie (a model that declares "
                     "reads_pages: no gathered view): live rows' lengths "
                     "up to whole blocks x the layers that read them"))

@@ -1723,8 +1723,9 @@ class InferenceEngine:
         decode: ``valid`` = 1 for the rows whose write-back lands in
         their own pages, 0 for those the host routed to the trash page
         (idle and mid-prefill rows). Nothing for a model without layers
-        held by slot: its programs lower as before."""
-        if not self._slot_state:
+        held by slot that reads no pages in place either: its programs
+        lower as before."""
+        if not (self._slot_state or self._reads_pages):
             return {}
         return {"valid": (sidx[:, 0] >= self.paged.page_size).astype(
             jnp.int32), "in_place": self._reads_pages}

@@ -14,7 +14,10 @@ the host by their tokens alone, for two kinds of model:
   gets none of this: its programs lower exactly as before.
 - a LATENT model (a cache row that is one latent, no ``k`` / ``v``): how
   many cache rows a decode step's attention really needed against how
-  many the pow2 view made it read.
+  many the pow2 view made it read or, where the decode reads the latent
+  pages in place (``models/deepseek_v3.py`` declares ``reads_pages``),
+  against the rows one layer's reader copied, with ``global_pages_read``
+  the pages all its layers copied, as below.
 - a model with WINDOW layers held by slot beside its paged global layers
   (``models/mimo_v2.py``; ``PagedKV.by_slot``): the same two numbers for
   the global layers (one pair of counters, named ``latent_*`` or

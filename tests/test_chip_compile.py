@@ -434,6 +434,43 @@ def test_global_paged_decode_attention_compiles(chip, heads, kv_heads, dq,
     assert swa.GLOBAL_KERNEL not in text
 
 
+def test_latent_paged_decode_attention_compiles(chip):
+    """One layer of DeepSeek-V3's decode program at its cell's shapes: 16
+    slots of 1,024 pages of 16 rows over a pool of 16,385 pages whose
+    640-lane row (512 + 64 up to whole lanes) is key AND value, 128 heads.
+    The row's write into its page (the pool donated), then ONE pool
+    operand to the same kernel as the other readers', the absorb and
+    ``W^V`` around it; no view is built and the pool is not laid out
+    again."""
+    import re
+
+    from llm_in_practise_tpu.models import layers
+    from llm_in_practise_tpu.ops import mla_attention as mla
+    from llm_in_practise_tpu.ops import swa_attention as swa
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(pool, q_nope, q_rope, row, w_kvb, table, start, valid):
+        pool = layers.page_row_write(pool, table, start, valid, row)
+        out = mla.paged_decode_attention(
+            q_nope, q_rope, pool, w_kvb, rank=512, scale=0.1,
+            interpret=False, **swa.paged_rows(table, start, valid, 16))
+        return out, pool
+
+    text = jax.jit(step, donate_argnums=(0,)).lower(
+        arg((16385, 16, 640)), arg((16, 1, 128, 128)),
+        arg((16, 1, 128, 64)), arg((16, 576)), arg((512, 128, 256)),
+        arg((16, 1024), jnp.int32), arg((16,), jnp.int32),
+        arg((16,), jnp.int32)).compile().as_text()
+    # by its name, and by the (slots, heads, n) result the cell's reader
+    # finds the decode attention by
+    assert mla.PAGED_KERNEL in text and "f32[16,128,512]" in text
+    assert "[16,16384,640]" not in text and "[16,16384,576]" not in text
+    assert not re.findall(
+        r"= bf16\[16385,16,640\]\S* (?:copy|transpose)\(", text)
+
+
 def test_lfm2_moe_decode_step_compiles(chip, monkeypatch):
     """The decode trunk of the LFM2-24B-A2B cut at its cell's shapes (32
     slots of 512 pages of 16 rows; 10 layers at the published widths, 64

@@ -124,14 +124,138 @@ def test_step_records_and_counters(served):
     assert dec and all(r["moe_experts_touched"] <= 2 * 8 for r in dec)
     assert served.eng.routing_load is st.load
     assert 0 < st.latent_tokens_attended < st.latent_view_tokens
-    # which form ran: a pool stored by pages gathers whole pages (each
-    # decode view 4 slots wide, each chunk row one), a flat one none
+    # which form ran: a pool stored by pages gathers whole pages for a
+    # chunk row's one-row view (its decode reads the pages in place and
+    # gathers none), a flat one none
     gathered = served.eng.view_pages_gathered
     assert sum(r.get("view_pages", 0) for r in recs) == gathered
     if served.form == "pages":
-        assert gathered >= st.latent_view_tokens // 16 > 0
+        chunks = sum(r.get("prefill_chunk_capacity", 0) // 16 for r in recs)
+        assert gathered >= chunks > 0
     else:
         assert gathered == 0 and not any("view_pages" in r for r in recs)
+
+
+def _jitted(fn):
+    """The ``jax.jit`` under the engine's meters."""
+    while not hasattr(fn, "_cache_size"):
+        fn = fn.__wrapped__
+    return fn
+
+
+def test_decode_reads_the_latent_pages_where_they_lie(served):
+    """The class declares ``reads_pages``: a pool stored by pages gives a
+    decode step no view of any layer. The step books the rows ONE layer's
+    reader copied (each live row's length up to whole blocks: a slot's 8
+    pages here, one block) and the pages x 3 layers, and gathers nothing;
+    ONE decode executable served every length; a mixed step still gathers
+    its chunk row's one-row view. A flat pool has no pages to hand over:
+    its decode gathers a pow2 view as ever."""
+    eng, st = served.eng, served.eng.step_stats
+    pg = eng.paged
+    dec = [r for r in served.records if "latent_tokens_attended" in r]
+    assert len({r["latent_tokens_attended"] for r in dec}) > 8
+    if served.form == "rows":
+        assert not any(pg.in_place) and not eng._reads_pages
+        assert st.page_block == 0 and st.global_pages_read == 0
+        assert all(r["latent_view_tokens"] in (4 * 64, 4 * 128)
+                   and "global_pages_read" not in r for r in dec)
+        return
+    assert pg.in_place == [True] * 3 and eng._reads_pages
+    assert st.page_block == pg.pages_per_slot == 8
+    for r in dec:
+        live, rest = divmod(r["latent_view_tokens"], 8 * pg.page_size)
+        assert 1 <= live <= eng.max_slots and rest == 0
+        assert r["latent_tokens_attended"] <= r["latent_view_tokens"]
+        assert r["global_pages_read"] == live * 8 * 3
+        chunked = r.get("prefill_chunk_capacity", 0) // 16
+        # a chunk row's one-row view, whole pages; nothing for the plane
+        assert (r["view_pages"] > 0) == (chunked > 0)
+    assert st.global_pages_read == sum(r["global_pages_read"] for r in dec)
+    assert _jitted(eng._pg_decode)._cache_size() == 1
+
+
+def test_a_chunk_rows_view_beside_rows_read_in_place(served):
+    """A 70-token prompt chunks beside a 30-token one that decodes: its
+    view in a mixed step is its own ``done`` + a chunk up to a power of two
+    AND no narrower than the decoding row's length + a chunk gives (64),
+    the narrowest a gathered engine builds there, so its first chunks (16
+    and 32 wide by themselves) build no mixed program of their own."""
+    if served.form == "rows":
+        pytest.skip("a flat pool has no pages to read in place")
+    eng = served.eng
+    with eng._lock:
+        seen = eng.steptrace.records(limit=1)[-1]["seq"]
+    lead = eng.submit(served.prompts[0][:30], GREEDY)
+    lead.next_item()
+    eng.submit(served.prompts[1],
+               dataclasses.replace(GREEDY, max_tokens=2)).result()
+    lead.result()
+    with eng._lock:
+        mixed = [r for r in eng.steptrace.records(limit=60)
+                 if r["seq"] > seen and "global_pages_read" in r
+                 and r["view_pages"]]
+    # 70 tokens: chunks at done = 0 .. 64; one row's view of 16-row pages
+    assert [r["view_pages"] * 16 for r in mixed] == [64, 64, 64, 64, 128]
+
+
+class _Gathered(dsv3.DeepSeekV3):
+    """The same model on the gathered path: a decode program gets a pow2
+    view of every layer."""
+    reads_pages = False
+
+
+def test_pages_in_place_give_the_gathered_paths_tokens(served):
+    """The 40-token prompt again, alone, through an engine whose decode
+    GATHERS (``reads_pages`` False): the same greedy tokens and the same
+    last-position logits as the rows read in place gave."""
+    if served.form == "rows":
+        pytest.skip("a flat pool gathers already")
+    twin = InferenceEngine(_Gathered(served.cfg), served.params, max_slots=4,
+                           cache_len=128, kv_layout="paged",
+                           chunked_prefill=16, cache_dtype=jnp.float32)
+    assert twin.paged.form == "pages"
+    assert not any(twin.paged.in_place) and not twin._reads_pages
+    twin.step_stats.capture = []
+    handle = twin.submit(served.prompts[0], GREEDY)
+    while twin.step():
+        pass
+    assert handle.result() == served.tokens[0]
+    (want,) = [c["last_logits"] for c in twin.step_stats.capture
+               if c["last_logits"]]
+    (got,) = [c["last_logits"] for c in served.captured
+              if c["kind"] == "chunk" and c["last_logits"]]
+    np.testing.assert_allclose(next(iter(got.values())),
+                               next(iter(want.values())), atol=1e-5)
+    # a gathered decode's view is every slot x a pow2 width
+    dec = [r for r in twin.steptrace.records(limit=50)
+           if "latent_tokens_attended" in r]
+    assert dec and all(r["latent_view_tokens"] == 4 * 64
+                       and "global_pages_read" not in r for r in dec)
+    twin.stop()
+
+
+def test_rows_read_in_place_are_booked_up_to_whole_blocks():
+    """Five layers over slots longer than a block (32 pages of 16 rows):
+    ``latent_view_tokens`` is the live rows' lengths up to whole blocks of
+    ONE reader, ``global_pages_read`` those pages x the five layers that
+    read them; an idle slot reads nothing."""
+    cfg = dsv3.deepseek_v3_config(compute_dtype="float32", experts_held=8,
+                                  n_layer=5, max_seq_len=2048)
+    eng = _engine(cfg, dsv3.random_params(cfg, 3, jnp.float32),
+                  cache_len=2048)
+    st = eng.step_stats
+    assert st.page_block == 32 and st.block_rows == 512
+    assert st.page_readers == 5
+    # slots 0, 1 and 3 decode at lengths 512, 513 and 1,301 (their new
+    # row included); slot 2 is idle
+    eng.slot_len[:] = [511, 512, 0, 1300]
+    st.note_decode_view([0, 1, 3], eng.cache_len)
+    blocks = 1 + 2 + 3
+    assert st.latent_tokens_attended == 512 + 513 + 1301
+    assert st.latent_view_tokens == blocks * 512
+    assert st.global_pages_read == blocks * 32 * 5
+    eng.stop()
 
 
 def test_latent_pool_bytes_read_right(served):

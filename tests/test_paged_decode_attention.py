@@ -2,12 +2,15 @@
 one query a row over the pool's PAGES where they lie, to each row's true
 length, against ``swa.decode_attention`` / ``swa.paired_decode_attention``
 over the gathered view of the same pages (interpret mode, tiny shapes: pages
-of 8 rows, blocks of 2 pages, 8 pages a slot)."""
+of 8 rows, blocks of 2 pages, 8 pages a slot); and
+``mla.paged_decode_attention``, the same kernel over ONE pool whose row is
+key and value at once, against ``mla.decode_attention`` over the view."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_in_practise_tpu.ops import mla_attention as mla
 from llm_in_practise_tpu.ops import swa_attention as swa
 from llm_in_practise_tpu.serve import paged_kv
 
@@ -119,6 +122,57 @@ def test_one_softmax_over_pages_in_place_is_the_gathered_view(
         want = swa.decode_attention(q, view_k, view_v,
                                     jnp.maximum(lengths - 1, 0),
                                     scale=dq ** -0.5)
+        assert got.shape == want.shape == (b, 1, heads, dv)
+        assert got.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live],
+            np.asarray(want, np.float32)[live], rtol=tol, atol=tol)
+        assert not np.asarray(got, np.float32)[~live].any()
+
+
+@pytest.mark.parametrize("lengths, heads, rank, dr, dn, dv, dtype", [
+    # DeepSeek-V3's row form: 512 + 64 of 640 lanes, 128 heads; a row of
+    # length 0, lengths that end mid-page and mid-block, a whole slot
+    ((0, 1, PAGE + 3, EDGE - 1, EDGE + 5, CACHE), 128, 512, 64, 16, 16,
+     jnp.bfloat16),
+    ((EDGE + 1, 0, CACHE, 3 * EDGE - 1), 128, 512, 64, 8, 8, jnp.float32),
+    # a toy's row, narrower than a lane tile, heads short of a sublane tile
+    ((0, 1, EDGE, EDGE + 1, CACHE), 4, 16, 8, 16, 16, jnp.float32),
+    ((5, CACHE, 0, EDGE), 4, 16, 8, 16, 16, jnp.bfloat16),
+    ((0, 0, 0), 4, 16, 8, 16, 16, jnp.float32),
+], ids=["cell-row-bf16", "cell-row", "toy-row", "toy-row-bf16", "all-idle"])
+def test_a_latent_pool_in_place_is_the_gathered_view(
+        lengths, heads, rank, dr, dn, dv, dtype):
+    """ONE pool, key and value at once (the key its whole row, the value
+    its first ``rank`` columns), two layers along one work list, the
+    tables scattered; an idle row (``valid`` 0) reads nothing."""
+    rng = np.random.default_rng(len(lengths) + heads)
+    b, width = len(lengths), rank + dr
+    n_pages, table = _scattered(rng, lengths)
+    start = jnp.asarray(np.maximum(np.asarray(lengths) - 1, 0), jnp.int32)
+    valid = jnp.asarray(np.asarray(lengths) > 0, jnp.int32)
+    pages = swa.paged_rows(table, start, valid, PAGE)
+    assert pages["lengths"].tolist() == list(lengths)
+    pages["work"] = swa.paged_decode_work(pages["lengths"], PAGE, PER_SLOT,
+                                          BLOCK)
+    live = np.asarray(lengths) > 0
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    scale = (dn + dr) ** -0.5
+    for _ in range(2):
+        buf = np.zeros((n_pages, PAGE, paged_kv.lane_whole(width)),
+                       np.float32)
+        buf[..., :width] = rng.normal(size=(n_pages, PAGE, width))
+        pool = jnp.asarray(buf, dtype)
+        q_nope = jnp.asarray(rng.normal(size=(b, 1, heads, dn)), dtype)
+        q_rope = jnp.asarray(rng.normal(size=(b, 1, heads, dr)), dtype)
+        w_kvb = jnp.asarray(
+            rng.normal(size=(rank, heads, dn + dv)) * rank ** -0.5, dtype)
+        got = mla.paged_decode_attention(
+            q_nope, q_rope, pool, w_kvb, rank=rank, scale=scale,
+            pages_per_block=BLOCK, interpret=True, **pages)
+        want = mla.decode_attention(
+            q_nope, q_rope, paged_kv.take_pages(pool, table, width), start,
+            w_kvb, rank=rank, scale=scale)
         assert got.shape == want.shape == (b, 1, heads, dv)
         assert got.dtype == dtype
         np.testing.assert_allclose(

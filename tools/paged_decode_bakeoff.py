@@ -4,7 +4,8 @@ decode reads the pool's pages where they lie, at the shapes its cell runs:
 16 slots of pages of 16 rows, a pool of (16 x pages a slot + 1) pages in
 by-pages buffers, block tables scattered over the pool.
 
-    chiprun -- python tools/paged_decode_bakeoff.py [--geometry phi4 mimo trinity]
+    chiprun -- python tools/paged_decode_bakeoff.py \
+        [--geometry phi4 mimo trinity deepseek]
 
 - ``phi4`` (``phi-4-mini-flash.grounded-reasoning``): the full layer or a
   cross layer, two softmaxes of 20 query pairs over their own key pages
@@ -14,23 +15,28 @@ by-pages buffers, block tables scattered over the pool.
   heads over 4 K/V heads, keys of 192 over values of 128 (``bf16[32769, 16,
   768]`` and ``[.., 512]``); slots of 2,048 pages;
 - ``trinity`` (``trinity-large.mixed-lengths``): a global layer, 48 heads
-  over 8 K/V heads of 128 (``bf16[32769, 16, 1024]`` twice).
+  over 8 K/V heads of 128 (``bf16[32769, 16, 1024]`` twice);
+- ``deepseek`` (``deepseek-v3.long-doc-qa``, PR 54): a latent layer, the
+  absorbed form of 128 heads over ONE pool whose 576-of-640-lane row is key
+  and value at once (``bf16[16385, 16, 640]``, copied once a page); slots
+  of 1,024 pages; the absorb and ``W^V`` einsums are in both paths' times.
 
 For each:
 
 - ``gathered``: what the decode program did before: the view's gather at the
   pow2 width that holds the longest row (``paged_kv.take_pages``, once a
-  step) and ``swa.decode_attention`` / ``swa.paired_decode_attention`` over
-  it, each a program of its own;
+  step) and ``swa.decode_attention`` / ``swa.paired_decode_attention`` /
+  ``mla.decode_attention`` over it, each a program of its own;
 - ``in_place``: ``swa.paged_decode_attention`` /
-  ``swa.paged_paired_decode_attention`` at several numbers of pages a block,
+  ``swa.paged_paired_decode_attention`` / ``mla.paged_decode_attention`` at
+  several numbers of pages a block,
   the flat work list's making inside the program (a step makes it once for
   all its readers).
 
 Three sets of lengths: ``traffic`` (16 decoding rows as the cell's workload
 file draws them: a prompt plus a uniform share of its answer), ``full``
-(every row at the longest a request may be: 16,384; 24,576) and ``quarter``
-(4 of 16 rows live). Times are DEVICE times from a profiler trace
+(every row at the longest a request may be: 16,384; 24,576; 14,848) and
+``quarter`` (4 of 16 rows live). Times are DEVICE times from a profiler trace
 (``benchmark/trace.py``): the median execution of the candidate's program.
 ``floor_ms``: the attended rows' bytes (a row of every buffer) over the
 chip's bandwidth. Prints one JSON line a candidate and writes them to
@@ -58,7 +64,9 @@ import numpy as np
 SLOTS, PAGE = 16, 16
 REPS = 5
 # name -> (cell, query heads a softmax, K/V heads, key and value widths a
-# head, softmaxes a head, a slot's rows, the longest row, pages a block)
+# head, softmaxes a head, a slot's rows, the longest row, pages a block);
+# 0 softmaxes: a LATENT pool, its one row the key (rank + rope wide) and,
+# its first ``value width`` columns, the value, under absorbed queries
 GEOMETRY = {
     "phi4": ("phi-4-mini-flash.grounded-reasoning", 20, 10, 64, 128, 2,
              16384, 16384, (8, 16, 32, 64)),
@@ -66,6 +74,8 @@ GEOMETRY = {
              (16, 32, 64)),
     "trinity": ("trinity-large.mixed-lengths", 48, 8, 128, 128, 1, 32768,
                 24576, (16, 32, 64)),
+    "deepseek": ("deepseek-v3.long-doc-qa", 128, 1, 576, 512, 0, 16384,
+                 14848, (16, 32, 64)),
 }
 
 
@@ -93,6 +103,7 @@ def main() -> int:
     args = ap.parse_args()
 
     from benchmark import device, trace
+    from llm_in_practise_tpu.ops import mla_attention as mla
     from llm_in_practise_tpu.ops import swa_attention as swa
     from llm_in_practise_tpu.serve import paged_kv
 
@@ -127,21 +138,39 @@ def main() -> int:
     for geometry in args.geometry:
         cell, heads, kv_heads, dq, dv, n, cache, longest, blocks = (
             GEOMETRY[geometry])
-        slots = SLOTS
+        slots, latent = SLOTS, n == 0
         if args.rehearse:
             slots, heads, kv_heads, dq, dv, cache, longest, blocks = (
                 4, 4, 2, 16, 32, 256, 256, (2, 4))
+            if latent:
+                kv_heads, dq, dv = 1, 24, 16
         scale = dq ** -0.5
         per_slot = cache // PAGE
         n_pages = slots * per_slot + 1
-        widths = (kv_heads * dq,) * n + (kv_heads * dv,)
-        keys = jax.random.split(jax.random.PRNGKey(0), 2 * n + 1)
+        widths = ((kv_heads * dq,) if latent
+                  else (kv_heads * dq,) * n + (kv_heads * dv,))
+        keys = jax.random.split(jax.random.PRNGKey(0),
+                                2 * n + 1 if n else 4)
         pools = [jnp.pad(
             jax.random.normal(k, (n_pages, PAGE, w), jnp.bfloat16),
             ((0, 0), (0, 0), (0, paged_kv.lane_whole(w) - w)))
             for k, w in zip(keys, widths)]
-        qs = [jax.random.normal(k, (slots, 1, heads, dq), jnp.bfloat16)
-              for k in keys[n + 1:]]
+        if latent:
+            # q_nope, q_rope and W_kvb (rank, heads, nope + v), the heads'
+            # nope and value widths a quarter of the rank as published
+            dn, rope = dv // 4, dq - dv
+            scale = (dn + rope) ** -0.5
+            qs = [jax.random.normal(keys[1], (slots, 1, heads, dn),
+                                    jnp.bfloat16),
+                  jax.random.normal(keys[2], (slots, 1, heads, rope),
+                                    jnp.bfloat16),
+                  (jax.random.normal(keys[3], (dv, heads, 2 * dn),
+                                     jnp.float32) * dv ** -0.5).astype(
+                      jnp.bfloat16)]
+        else:
+            qs = [jax.random.normal(k, (slots, 1, heads, dq), jnp.bfloat16)
+                  for k in keys[n + 1:]]
+        nq = len(qs)
         rng = np.random.default_rng(48)
         # every slot's pages scattered over the pool, as a long run leaves
         # them
@@ -154,7 +183,11 @@ def main() -> int:
                          for buf, w in zip(bufs, widths))
 
         def reader(*xs):
-            q, (*k, v, index) = xs[:n], xs[n:]
+            q, (*k, v, index) = xs[:nq], xs[nq:]
+            if latent:
+                return (mla.decode_attention(
+                    q[0], q[1], v, index, q[2], rank=dv,
+                    scale=scale),)
             if n == 1:
                 return (swa.decode_attention(q[0], k[0], v, index,
                                              scale=scale),)
@@ -162,7 +195,12 @@ def main() -> int:
 
         def walker(ppb):
             def in_place(*xs):
-                q, (*k, v, table, lengths) = xs[:n], xs[n:]
+                q, (*k, v, table, lengths) = xs[:nq], xs[nq:]
+                if latent:
+                    return (mla.paged_decode_attention(
+                        q[0], q[1], v, q[2], rank=dv, scale=scale,
+                        table=table, lengths=lengths,
+                        pages_per_block=ppb),)
                 kw = dict(scale=scale, kv_heads=kv_heads,
                           pages_per_block=ppb)
                 if n == 1:
